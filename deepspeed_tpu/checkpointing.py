@@ -128,15 +128,10 @@ model_parallel_cuda_manual_seed = model_parallel_seed
 def _offload_supported():
     global _OFFLOAD_SUPPORTED
     if _OFFLOAD_SUPPORTED is None:
-        try:
-            dev = jax.devices()[0]
-            _OFFLOAD_SUPPORTED = "pinned_host" in getattr(
-                dev, "addressable_memories", lambda: []
-            )() or any(
-                m.kind == "pinned_host" for m in dev.addressable_memories()
-            )
-        except Exception:
-            _OFFLOAD_SUPPORTED = False
+        _OFFLOAD_SUPPORTED = any(
+            m.kind == "pinned_host"
+            for m in jax.devices()[0].addressable_memories()
+        )
     return _OFFLOAD_SUPPORTED
 
 

@@ -198,10 +198,10 @@ ZERO_MASTER_WEIGHTS = "master_weights"
 ZERO_MASTER_WEIGHTS_DEFAULT = True
 # ZeRO-Offload analog (later-DeepSpeed surface): keep fp32 master +
 # moments on the HOST; the accelerator holds compute-dtype params and
-# grads only. On tunneled TPU setups host<->device bandwidth makes this
-# slow (prefer data_types.master_dtype="compensated" — docs/memory.md);
-# on locally-attached hosts it trades step time for ~12 bytes/param of
-# HBM. {"device": "cpu"} enables; {"device": "none"} (default) disables.
+# grads only. It trades step time (per-step d2h grads + h2d params over
+# the host link; not measured on a v5e host) for ~12 bytes/param of HBM;
+# data_types.master_dtype="compensated" keeps the state on the chip
+# instead (docs/memory.md). {"device": "cpu"} enables; {"device": "none"} (default) disables.
 ZERO_OFFLOAD_OPTIMIZER = "offload_optimizer"
 ZERO_OFFLOAD_DEVICE = "device"
 ZERO_OFFLOAD_DEVICE_DEFAULT = "none"
@@ -318,8 +318,8 @@ TELEMETRY_OUTPUT_PATH_DEFAULT = ""
 TELEMETRY_JOB_NAME = "job_name"
 TELEMETRY_JOB_NAME_DEFAULT = "DeepSpeedJobName"
 # Export (and device-value materialization — one host sync) cadence, in
-# accumulation windows. Raise it on remote-tunneled platforms where a
-# per-window sync would throttle the async loop.
+# accumulation windows. Each export blocks on the window's scalars and
+# drains the dispatch queue; raise it to let the host run ahead.
 TELEMETRY_INTERVAL = "interval"
 TELEMETRY_INTERVAL_DEFAULT = 1
 TELEMETRY_EXPORTERS = "exporters"
@@ -475,9 +475,10 @@ DATA_PIPELINE_STAGE_TO_DEVICE = "stage_to_device"
 DATA_PIPELINE_STAGE_TO_DEVICE_DEFAULT = True
 
 # Persistent XLA compilation cache (deepspeed_tpu/runtime/compile_cache.py):
-# armed at initialize() so post-preemption restarts reuse compiled programs
-# instead of paying minutes of recompiles. cache_dir "" =>
-# ~/.cache/deepspeed_tpu/jax_cache.
+# armed at initialize()/init_inference() so restarts reuse compiled
+# programs. cache_dir "" => <checkout>/.jax_cache; with
+# JAX_COMPILATION_CACHE_DIR set the variable wins and no directory is set
+# in code.
 COMPILE_CACHE = "compile_cache"
 COMPILE_CACHE_ENABLED = "enabled"
 COMPILE_CACHE_ENABLED_DEFAULT = False

@@ -133,6 +133,11 @@ class InferenceEngine:
             )
         self.config = DeepSpeedConfig(None, param_dict=raw, world_size=1)
         cfg = self.config
+        # one persistent compile cache for train and serve, armed before
+        # any engine compile (runtime/compile_cache.py)
+        from ..runtime.compile_cache import configure_compile_cache
+
+        configure_compile_cache(cfg)
 
         # ---- geometry -------------------------------------------------
         self.max_seq_len = cfg.inference_max_seq_len or mcfg.n_positions

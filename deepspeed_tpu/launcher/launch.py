@@ -65,23 +65,6 @@ def resolve_node_rank(args, world_info):
     )
 
 
-def _autodetect_tpu_host(env):
-    """Will an unpinned (``JAX_PLATFORMS`` unset) child process pick the
-    TPU backend? Probed WITHOUT initializing jax in the launcher: a TPU
-    runtime must be importable (libtpu wheel or ``TPU_LIBRARY_PATH``)
-    AND TPU device nodes must exist — dev images ship a stub libtpu
-    wheel that registers none of the ``xla_tpu_*`` flags, and XLA
-    fatally aborts on unknown ``XLA_FLAGS``."""
-    import glob
-    import importlib.util
-
-    has_runtime = bool(
-        importlib.util.find_spec("libtpu") or env.get("TPU_LIBRARY_PATH")
-    )
-    has_devices = bool(glob.glob("/dev/accel*") or glob.glob("/dev/vfio/*"))
-    return has_runtime and has_devices
-
-
 def build_env(args, world_info, node_rank):
     env = os.environ.copy()
     num_processes = max(len(world_info), 1)
@@ -106,30 +89,13 @@ def build_env(args, world_info, node_rank):
     ):
         # ZeRO-3 collective/compute overlap (runtime/overlap.py): export
         # the latency-hiding scheduler flags BEFORE the training process
-        # loads its XLA backend — the only point they are guaranteed to
-        # take effect. XLA aborts on unknown XLA_FLAGS, so never export
-        # TPU-only flags into a process that will not load the TPU
-        # backend: a JAX_PLATFORMS pin without tpu skips outright, and
-        # the autodetect case (unset) must look like a real TPU host.
-        jax_platforms = env.get("JAX_PLATFORMS", "").strip().lower()
-        if jax_platforms:
-            tpu_bound = "tpu" in jax_platforms.split(",")
-        else:
-            tpu_bound = _autodetect_tpu_host(env)
-        if not tpu_bound:
-            logger.warning(
-                "DS_TPU_LATENCY_HIDING is set but this launch will not "
-                "load the TPU backend (JAX_PLATFORMS=%r); skipping the "
-                "latency-hiding XLA flags (unknown XLA_FLAGS are fatal "
-                "off TPU) — pin JAX_PLATFORMS=tpu to force arming",
-                jax_platforms or "<unset>",
-            )
-        else:
-            from ..runtime.overlap import append_latency_hiding_flags
+        # loads its TPU backend — the only point they are guaranteed to
+        # take effect. They go in libtpu's own variable, never XLA_FLAGS
+        # (jaxlib aborts on entries it does not register); a child that
+        # never loads libtpu never reads it.
+        from ..runtime.overlap import FLAGS_ENV, append_latency_hiding_flags
 
-            env["XLA_FLAGS"] = append_latency_hiding_flags(
-                env.get("XLA_FLAGS", "")
-            )
+        env[FLAGS_ENV] = append_latency_hiding_flags(env.get(FLAGS_ENV, ""))
     return env
 
 

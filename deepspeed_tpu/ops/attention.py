@@ -35,14 +35,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..utils import device
+from ..utils.logging import warn_once
+
 NEG_INF = -1e30
 
-
-def _on_tpu():
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
 
 
 # ---------------------------------------------------------------------------
@@ -371,7 +368,7 @@ def _flash_fwd_impl(q, k, v, kv_mask, seed, causal, sm_scale, dropout_rate, bloc
     sk = k.shape[2]
     nq, nk = sq // block_q, sk // block_k
     diag_offset = sk - sq
-    interpret = not _on_tpu()
+    interpret = not device.on_tpu()
     use_mask = kv_mask is not None
 
     q3, k3, v3 = _reshape_bh(q), _reshape_bh(k), _reshape_bh(v)
@@ -412,6 +409,7 @@ def _flash_fwd_impl(q, k, v, kv_mask, seed, causal, sm_scale, dropout_rate, bloc
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(seed_arr, q3, k3, v3, kvm)
     return out.reshape(b, h, sq, d), lse
 
@@ -442,7 +440,7 @@ def _flash_bwd(causal, sm_scale, dropout_rate, block_q, block_k, residuals, g):
     sk = k.shape[2]
     nq, nk = sq // block_q, sk // block_k
     diag_offset = sk - sq
-    interpret = not _on_tpu()
+    interpret = not device.on_tpu()
     use_mask = kv_mask is not None
 
     # delta_i = rowsum(dO * O): cheap elementwise reduction, leave to XLA;
@@ -485,6 +483,7 @@ def _flash_bwd(causal, sm_scale, dropout_rate, block_q, block_k, residuals, g):
         out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(seed_arr, q3, k3, v3, kvm, do3, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -513,6 +512,7 @@ def _flash_bwd(causal, sm_scale, dropout_rate, block_q, block_k, residuals, g):
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(seed_arr, q3, k3, v3, kvm, do3, lse, delta)
 
     dq = dq.reshape(b, h, sq, d)
@@ -579,10 +579,9 @@ def flash_attention(
 
 
 # Flash dispatch mode:
-#   "auto"   — flash on a single device; XLA path under a multi-device mesh
-#              (a pallas_call inside plain GSPMD jit is not partitioned — XLA
-#              would all-gather its operands; multi-device flash goes through
-#              shard_map, see parallel/sequence.py)
+#   "auto"   — flash where _flash_route finds a way (one device, or
+#              per-shard via shard_map over a data/model mesh); otherwise
+#              the XLA path, logged once on a TPU
 #   "always" — force flash (caller guarantees per-device operands, e.g.
 #              inside shard_map)
 #   "never"  — XLA reference path
@@ -615,7 +614,6 @@ def flash_attention_sharded(
     from jax.sharding import PartitionSpec as P
 
     from ..config.constants import DATA_AXIS, MODEL_AXIS
-    from ..runtime.dist import shard_map
 
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)
@@ -641,36 +639,50 @@ def flash_attention_sharded(
             float(sm_scale), float(dropout_rate), int(block_q), int(block_k),
         )
 
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(qspec, qspec, qspec, P(DATA_AXIS, None) if use_mask else P(), P()),
         out_specs=qspec,
-        check=False,
+        check_vma=False,
     )(q, k, v, kv_mask if use_mask else jnp.zeros((), jnp.int32), seed)
 
 
-def _mesh_can_shard_flash(mesh, q, k):
-    """True when flash can run per-shard over (data, model) for these
-    operands: batch/head dims divide their mesh axes and no sequence axis
-    sharding is requested here (the caller has already validated the mask
-    and block tiling via its can_flash gate)."""
-    if mesh is None:
-        return False
+def _flash_route(mesh, q, k):
+    """How flash can run for these operands: ``"sharded"`` (per-shard
+    over the mesh's data/model axes via ``shard_map``), ``"local"`` (the
+    operands live on one device), or a reason string when it cannot (the
+    caller has already validated mask and block tiling via its can_flash
+    gate). A bare ``pallas_call`` inside a GSPMD-jitted program over
+    several devices is not partitioned — XLA would all-gather its
+    operands — so anything else goes to the XLA path."""
     from ..config.constants import DATA_AXIS, MODEL_AXIS, SEQUENCE_AXIS
 
+    if mesh is None:
+        n = jax.device_count()
+        if n == 1:
+            return "local"
+        return (
+            f"{n} devices and no mesh plumbed into the model config "
+            "(initialize()/init_inference() set config.mesh)"
+        )
+    if mesh.size == 1:
+        return "local"
     shape = dict(mesh.shape)
     if DATA_AXIS not in shape or MODEL_AXIS not in shape:
-        return False  # shard_map specs name both axes
-    dp = shape.get(DATA_AXIS, 1)
-    mp = shape.get(MODEL_AXIS, 1)
-    sp = shape.get(SEQUENCE_AXIS, 1)
-    if sp > 1:
-        return False  # sequence parallelism is handled in parallel/sequence.py
+        return f"mesh axes {tuple(shape)} lack {DATA_AXIS!r}/{MODEL_AXIS!r}"
+    dp, mp = shape[DATA_AXIS], shape[MODEL_AXIS]
+    if shape.get(SEQUENCE_AXIS, 1) > 1:
+        return "sequence-parallel mesh (handled in parallel/sequence.py)"
     if dp * mp <= 1:
-        return False
+        return f"mesh {shape} shards over neither data nor model"
     b, h = q.shape[0], q.shape[1]
-    return b % dp == 0 and h % mp == 0
+    if b % dp or h % mp:
+        return (
+            f"batch {b} / heads {h} do not divide the mesh's "
+            f"data={dp} / model={mp} axes"
+        )
+    return "sharded"
 
 
 def attention(
@@ -680,8 +692,9 @@ def attention(
     """Dispatcher: flash kernel when shapes tile cleanly and the mask is a
     padding mask; XLA reference otherwise (incl. learned additive biases,
     which need exact mask gradients). With ``mesh`` supplied and a
-    data/model-parallel layout, flash runs per-shard via ``shard_map``
-    instead of silently falling back to the O(S^2) path."""
+    data/model-parallel layout, flash runs per-shard via ``shard_map``;
+    where several devices leave no way to run it (``_flash_route``), the
+    O(S^2) path runs and, on a TPU, says why once."""
     sq, sk = q.shape[2], k.shape[2]
     bq = pick_block(sq, DEFAULT_BLOCK_Q)
     bk = pick_block(sk, DEFAULT_BLOCK_K)
@@ -695,7 +708,7 @@ def attention(
         and (mask is None or kv_mask is not None)
     )
     # interpret-mode PRNG is not available off-TPU; route dropout to XLA there
-    if dropout_rate > 0.0 and not _on_tpu():
+    if dropout_rate > 0.0 and not device.on_tpu():
         can_flash = False
     if FLASH_MODE == "never":
         can_flash = False
@@ -703,20 +716,32 @@ def attention(
         can_flash = False
 
     if can_flash:
+        route = _flash_route(mesh, q, k)
+        if FLASH_MODE == "always" and route != "sharded":
+            route = "local"  # caller guarantees per-device operands
         seed = jnp.asarray(0, jnp.int32)
         if dropout_rate > 0.0:
             seed = jax.random.randint(dropout_rng, (), 0, 2**31 - 1)
-        if _mesh_can_shard_flash(mesh, q, k):
+        if route == "sharded":
             return flash_attention_sharded(
                 q, k, v, mesh, kv_mask=kv_mask, causal=causal,
                 sm_scale=sm_scale, dropout_rate=dropout_rate,
                 dropout_seed=seed, block_q=bq, block_k=bk,
             )
-        if FLASH_MODE == "always" or jax.device_count() == 1:
+        if route == "local":
             return flash_attention(
                 q, k, v, kv_mask=kv_mask, causal=causal, sm_scale=sm_scale,
                 dropout_rate=dropout_rate, dropout_seed=seed,
                 block_q=bq, block_k=bk,
+            )
+        if device.on_tpu():
+            # a shape the kernel could have served is about to pay
+            # O(S^2) HBM on the chip: say so, once per reason
+            warn_once(
+                f"flash-gave-way:{route}",
+                "attention: flash kernel not used for q%s k%s — %s; "
+                "running the O(S^2) XLA path",
+                tuple(q.shape), tuple(k.shape), route,
             )
     return mha_reference(
         q, k, v, mask=mask, causal=causal, sm_scale=sm_scale,

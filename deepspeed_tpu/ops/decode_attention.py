@@ -53,14 +53,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..utils import device
 from .attention import NEG_INF
-
-
-def _on_tpu():
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover
-        return False
 
 
 def _flash_decode_kernel(
@@ -76,60 +70,58 @@ def _flash_decode_kernel(
     resolved through the block table. Scratch carries the online-softmax
     state (running max ``m``, normalizer ``l``, weighted-V accumulator)
     across the slot's pages; the final page writes ``acc / l``.
+
+    One query row per head leaves the MXU nothing to do, and Mosaic
+    refuses a ``dot_general`` batched over a middle axis (heads sits
+    between tokens and hd in the page). So the two contractions are VPU
+    multiplies with a lane reduction (q.k over hd) and a reduction over
+    the leading token axis (p.v): no transposes, every intermediate
+    keeps the page's ``[token, head, lane]`` layout, and the kernel
+    stays bytes-bound on the page stream as decode attention should be.
     """
     b, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
 
     pos = positions_ref[b]
     phys = tables_ref[b * max_blocks + j]
     # live-page early-out: the null page (dead slots, unallocated table
-    # tails) and pages starting beyond the slot's position never touch
-    # the VPU/MXU — the whole point of fusing the gather
+    # tails) and pages starting beyond the slot's position do no work —
+    # the whole point of fusing the gather
     run = (phys != 0) & (j * block_size <= pos)
 
     @pl.when(run)
     def _body():
-        q = q_ref[0]  # [heads, hd]
-        k = k_ref[0]  # [block_size, heads, hd]
-        v = v_ref[0]
-        # scores per head over this page's tokens: contract hd, batch
-        # heads -> [heads, block_size]; storage-dtype operands, f32
-        # accumulate (the MXU discipline of ops/attention.py)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32,
-        ) * sm_scale
+        q = q_ref[0].astype(jnp.float32)  # [heads, hd]
+        k = k_ref[0].astype(jnp.float32)  # [block_size, heads, hd]
+        v = v_ref[0].astype(jnp.float32)
+        # scores per (token, head): contract hd on the lanes
+        s = jnp.sum(k * q[None], axis=-1, keepdims=True) * sm_scale
         # validity within the page: token index j*bs + t <= pos
         tok = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1
+            jnp.int32, s.shape, 0
         )
-        s = jnp.where(tok <= pos, s, NEG_INF)
+        s = jnp.where(tok <= pos, s, NEG_INF)  # [block_size, heads, 1]
 
-        m_prev = m_scr[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
+        m_prev = m_scr[...]  # [heads, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
+        p = jnp.exp(s - m_new[None])
         p = jnp.where(s > NEG_INF / 2, p, 0.0)
         alpha = jnp.exp(m_prev - m_new)
-        l_new = alpha * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True)
-        # [heads, bs] x [bs, heads, hd] -> [heads, hd] (batch heads)
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32,
-        )
-        acc_scr[:] = acc_scr[:] * alpha + pv
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=0)
+        # p broadcasts along the lanes; the token axis reduces away
+        acc_scr[...] = acc_scr[...] * alpha + jnp.sum(p * v, axis=0)
+        m_scr[...] = m_new
 
     @pl.when(j == max_blocks - 1)
     def _finalize():
-        l = l_scr[:, :1]
+        l = l_scr[...]
         l = jnp.where(l == 0.0, 1.0, l)  # dead slots -> exact zeros
-        out_ref[0] = (acc_scr[:] / l).astype(out_ref.dtype)
+        out_ref[0] = (acc_scr[...] / l).astype(out_ref.dtype)
 
 
 def paged_flash_decode(q, k_pool, v_pool, block_tables, positions,
@@ -149,7 +141,7 @@ def paged_flash_decode(q, k_pool, v_pool, block_tables, positions,
     other cached key). Off-TPU the kernel runs in interpret mode.
     """
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not device.on_tpu()
     b, heads, hd = q.shape
     block_size = k_pool.shape[1]
     max_blocks = block_tables.shape[1]
@@ -187,8 +179,8 @@ def paged_flash_decode(q, k_pool, v_pool, block_tables, positions,
             (1, heads, hd), lambda b, j, tables, pos: (b, 0, 0)
         ),
         scratch_shapes=[
-            pltpu.VMEM((heads, 128), jnp.float32),
-            pltpu.VMEM((heads, 128), jnp.float32),
+            pltpu.VMEM((heads, 1), jnp.float32),
+            pltpu.VMEM((heads, 1), jnp.float32),
             pltpu.VMEM((heads, hd), jnp.float32),
         ],
     )
@@ -197,6 +189,7 @@ def paged_flash_decode(q, k_pool, v_pool, block_tables, positions,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, heads, hd), v_pool.dtype),
         interpret=interpret,
+        name="paged_flash_decode",
     )(tables_flat, positions, q, k_pool, v_pool)
 
 
@@ -207,12 +200,12 @@ def _sgmv_kernel(ids_ref, x_ref, a_ref, b_ref, out_ref):
     """One slot's LoRA delta: ``x @ A[id] @ B[id]`` with the pool rows
     resolved by the BlockSpec index_map from the prefetched ids — the
     per-slot weight gather never materializes."""
-    x = x_ref[...]  # [1, in]
+    x = x_ref[0]  # [1, in]
     t = jax.lax.dot_general(
         x, a_ref[0], (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )  # [1, r]
-    out_ref[...] = jax.lax.dot_general(
+    out_ref[0] = jax.lax.dot_general(
         t.astype(b_ref.dtype), b_ref[0], (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     ).astype(out_ref.dtype)  # [1, out]
@@ -237,25 +230,30 @@ def lora_sgmv(x, a_pool, b_pool, ids, interpret=None):
     indirection trick again).
     """
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not device.on_tpu()
     b, din = x.shape
-    rows, _, r = a_pool.shape
+    r = a_pool.shape[2]
     dout = b_pool.shape[2]
     ids = ids.astype(jnp.int32)
 
+    # x/out ride as [B, 1, d] so each slot's (1, 1, d) block has its last
+    # two dims equal to the array's — a (1, d) block over [B, d] breaks
+    # the TPU's (8, 128) block rule
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, din), lambda i, ids: (i, 0)),
+            pl.BlockSpec((1, 1, din), lambda i, ids: (i, 0, 0)),
             pl.BlockSpec((1, din, r), lambda i, ids: (ids[i], 0, 0)),
             pl.BlockSpec((1, r, dout), lambda i, ids: (ids[i], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, dout), lambda i, ids: (i, 0)),
+        out_specs=pl.BlockSpec((1, 1, dout), lambda i, ids: (i, 0, 0)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _sgmv_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, dout), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, 1, dout), jnp.float32),
         interpret=interpret,
-    )(ids, x, a_pool, b_pool)
+        name="lora_sgmv",
+    )(ids, x[:, None, :], a_pool, b_pool)
+    return out[:, 0, :]

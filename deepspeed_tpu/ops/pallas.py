@@ -44,6 +44,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..utils import device
 from .optimizers import Lamb, _f32
 
 
@@ -56,12 +57,6 @@ BLOCK_ROWS = 256
 LANES = 128
 BLOCK = BLOCK_ROWS * LANES
 
-
-def _on_tpu():
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover
-        return False
 
 
 def _lamb_phase1_kernel(
@@ -101,7 +96,7 @@ def lamb_leaf_update(
     """Fused LAMB update of ONE flattened leaf. Returns
     (p_new, m_new, v_new, trust_ratio)."""
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not device.on_tpu()
     n = p.size
     nblk = max(1, -(-n // BLOCK))
     padded = nblk * BLOCK
@@ -138,6 +133,7 @@ def lamb_leaf_update(
             jax.ShapeDtypeStruct((nblk, 8, LANES), jnp.float32),
         ],
         interpret=interpret,
+        name="lamb_phase1",
     )(scal, p2, g2, m2, v2)
 
     # phase 2: cross-block reduction (fused_lamb_cuda_kernel.cu:233-250)
@@ -186,7 +182,7 @@ def lamb_multi_tensor_update(
     import numpy as np
 
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not device.on_tpu()
     nblks = [max(1, -(-p.size // BLOCK)) for p in ps]
     offsets = np.cumsum([0] + nblks)
     nblk_total = int(offsets[-1])
@@ -227,6 +223,7 @@ def lamb_multi_tensor_update(
             jax.ShapeDtypeStruct((nblk_total, 8, LANES), jnp.float32),
         ],
         interpret=interpret,
+        name="lamb_phase1",
     )(scal, p2, g2, m2, v2)
 
     # phase 2: per-SEGMENT (= per-leaf) reduction of the block partials

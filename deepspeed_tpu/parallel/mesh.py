@@ -76,9 +76,11 @@ def build_mesh(
 ) -> Mesh:
     """Create the global device mesh.
 
-    Uses ``jax.experimental.mesh_utils`` on real TPU so axis order maps onto
-    the physical torus (model innermost => fastest ICI); plain reshape on the
-    host-platform fallback used in tests.
+    On a TPU ``mesh_utils.create_device_mesh`` maps the axis order onto
+    the physical torus (model innermost => fastest ICI); a failure there
+    is raised, never papered over with an arbitrary device order. Other
+    platforms (the virtual CPU devices of the tests) have no topology,
+    so a plain reshape is the mesh.
     """
     if devices is None:
         devices = jax.devices()
@@ -86,14 +88,11 @@ def build_mesh(
         topology = resolve_topology(len(devices), **topo_kwargs)
     shape = (topology.pipe, topology.data, topology.sequence, topology.model)
     if devices[0].platform == "tpu":
-        try:
-            from jax.experimental import mesh_utils
+        from jax.experimental import mesh_utils
 
-            mesh_devices = mesh_utils.create_device_mesh(shape, devices=devices)
-            return Mesh(mesh_devices, MESH_AXES)
-        except Exception:
-            pass
-    mesh_devices = np.asarray(devices).reshape(shape)
+        mesh_devices = mesh_utils.create_device_mesh(shape, devices=devices)
+    else:
+        mesh_devices = np.asarray(devices).reshape(shape)
     return Mesh(mesh_devices, MESH_AXES)
 
 

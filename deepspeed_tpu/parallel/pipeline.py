@@ -38,12 +38,7 @@ from . import mesh as mesh_lib
 def _pvary(x, axis_name):
     """Mark ``x`` as device-varying over ``axis_name`` (VMA typing for the
     scan carry, which starts replicated but becomes stage-dependent)."""
-    if hasattr(jax.lax, "pcast"):
-        try:
-            return jax.lax.pcast(x, to="varying", axis_name=axis_name)
-        except TypeError:
-            pass
-    return jax.lax.pvary(x, axis_name)
+    return jax.lax.pcast(x, axis_name, to="varying")
 
 
 def pipeline_stages(mesh):
@@ -157,9 +152,7 @@ def gpipe_spmd(stage_fn, stage_params, microbatches, mesh,
         )
         return out
 
-    from ..runtime.dist import shard_map
-
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(pipe_axis), P(), P()),

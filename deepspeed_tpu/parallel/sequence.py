@@ -193,11 +193,21 @@ def ulysses_attention_local(
         dropout_rng = jax.random.fold_in(dropout_rng, jax.lax.axis_index(axis_name))
 
     S = Sl * sp
-    on_tpu = jax.default_backend() == "tpu"
     from ..ops.attention import DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q, pick_block
+    from ..utils import device
+    from ..utils.logging import warn_once
 
     bq, bk = pick_block(S, DEFAULT_BLOCK_Q), pick_block(S, DEFAULT_BLOCK_K)
+    # off-TPU the XLA path is the design (the interpreter is no faster);
+    # on the chip a refused shape is named once
+    on_tpu = device.on_tpu()
     can_flash = use_flash and on_tpu and bq > 0 and bk > 0
+    if use_flash and on_tpu and not can_flash:
+        warn_once(
+            f"ulysses-flash-gave-way:{S}",
+            "ulysses attention: no flash block divides the gathered "
+            "sequence %d; running the O(S^2) XLA path", S,
+        )
     if can_flash:
         seed = jnp.asarray(0, jnp.int32)
         if use_dropout:
@@ -247,14 +257,12 @@ def _shard_mapped(local_fn, mesh, have_valid, have_rng, seq_axis, batch_axis, he
         in_specs.append(kvv_spec)
     if have_rng:
         in_specs.append(P())
-    from ..runtime.dist import shard_map
-
-    return shard_map(
+    return jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=qkv_spec,
-        check=False,
+        check_vma=False,
     )
 
 

@@ -505,10 +505,12 @@ def _apply_checkpoint(
     import jax.numpy as jnp
 
     sc = state["loss_scaler"]
-    engine.loss_scale_state = engine.loss_scale_state._replace(
-        loss_scale=jnp.float32(sc["loss_scale"]),
-        good_steps=jnp.int32(sc["good_steps"]),
-        hysteresis=jnp.int32(sc["hysteresis"]),
+    engine.loss_scale_state = engine._place_scaler(
+        engine.loss_scale_state._replace(
+            loss_scale=jnp.float32(sc["loss_scale"]),
+            good_steps=jnp.int32(sc["good_steps"]),
+            hysteresis=jnp.int32(sc["hysteresis"]),
+        )
     )
     # RNG key chain (absent on pre-PR5 checkpoints: the engine keeps its
     # current chain and only replay bitwiseness is lost)

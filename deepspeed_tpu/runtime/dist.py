@@ -23,43 +23,6 @@ from ..telemetry.registry import count_suppressed
 _INITIALIZED = False
 
 
-def shard_map(f, mesh, in_specs, out_specs, check=None, axis_names=None):
-    """Version-compat ``shard_map``: the top-level ``jax.shard_map`` exists
-    only on newer jax; 0.4.x ships it as
-    ``jax.experimental.shard_map.shard_map`` with a different keyword
-    surface. Every in-tree caller routes through this shim so the repo runs
-    on both.
-
-    ``check``: replication checking — maps to ``check_vma`` (new API) /
-    ``check_rep`` (experimental API). ``axis_names``: the set of mesh axes
-    the body is manual over (new API); translated to the experimental API's
-    complementary ``auto`` set. ``None`` means manual over every axis.
-    """
-    import jax
-
-    impl = getattr(jax, "shard_map", None)
-    kwargs = {}
-    if impl is not None:
-        if check is not None:
-            kwargs["check_vma"] = check
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-    else:
-        from jax.experimental.shard_map import shard_map as impl
-
-        if check is not None:
-            kwargs["check_rep"] = check
-        if axis_names is not None:
-            # experimental API: ``auto`` is the complement — axes NOT manual
-            auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-            if auto:
-                kwargs["auto"] = auto
-                # 0.4.x shard_map rejects partial-auto with replication
-                # checking on (NotImplementedError); callers opting into
-                # axis_names get it off unless they asked otherwise
-                kwargs.setdefault("check_rep", False)
-    return impl(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
-
 COORD_ENV = "DS_TPU_COORDINATOR_ADDRESS"
 NPROC_ENV = "DS_TPU_NUM_PROCESSES"
 PID_ENV = "DS_TPU_PROCESS_ID"

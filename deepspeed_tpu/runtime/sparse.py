@@ -103,19 +103,17 @@ def sparse_all_reduce(csr: CSRTensor, mesh, axis_name=C.DATA_AXIS):
     over ``axis_name``) from per-rank CSRTensors."""
     from jax.sharding import PartitionSpec as P
 
-    from .dist import shard_map
-
     def local_fn(idx, val):
         return sparse_all_reduce_local(
             idx, val, csr.dense_size, axis_name=axis_name
         )
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(P(axis_name), P(axis_name)),
         out_specs=P(),
-        check=False,
+        check_vma=False,
     )
     # stack per-rank csr onto a leading axis outside; here indices/values
     # are already global arrays whose leading dim is sharded over the axis

@@ -12,7 +12,9 @@ loss-scale as RAW device values and only materializes them (one host sync)
 every ``interval`` windows, at the export boundary. With telemetry
 disabled no hook touches a device value, so the engine's async fast path
 is unchanged; with it enabled, the sync cost is one blocked float per
-export — size ``interval`` accordingly on remote-tunneled platforms.
+export, which drains the dispatch queue: raise ``interval`` where the
+host should keep running ahead of the device (cost per export on a
+locally attached chip: not measured).
 """
 
 import atexit
@@ -281,19 +283,27 @@ def register_inference_metrics(registry):
 
 
 def hbm_peak_bytes():
-    """Per-chip HBM high-water (device ``memory_stats`` peak), or None
-    where the platform reports no memory stats (CPU). The single probe
-    behind the ``train/hbm_peak_bytes`` gauge and bench.py's per-attempt
+    """Per-chip HBM high-water (device ``memory_stats`` peak) — the
+    LARGEST peak over this process's local devices, so a mesh that piles
+    state on one chip shows it. None where the platform keeps no memory
+    stats (the CPU backend answers None); a TPU that answers None is
+    reported as an error, not as "no data". The single probe behind the
+    ``train/hbm_peak_bytes`` gauge and bench.py's per-attempt
     ``hbm_peak_bytes`` extra."""
-    try:
-        import jax
+    import jax
 
-        stats = jax.local_devices()[0].memory_stats()
-    except Exception:
-        return None
-    if not stats:
-        return None
-    return int(stats.get("peak_bytes_in_use", 0))
+    peaks = []
+    for dev in jax.local_devices():
+        stats = dev.memory_stats()
+        if not stats:
+            if dev.platform == "tpu":
+                raise RuntimeError(
+                    f"{dev} reports no memory_stats(); the HBM peak "
+                    "cannot be read on this runtime"
+                )
+            return None
+        peaks.append(int(stats.get("peak_bytes_in_use", 0)))
+    return max(peaks)
 
 
 class Telemetry:
@@ -586,12 +596,9 @@ class Telemetry:
         self._set_memory_gauges()
 
     def _set_memory_gauges(self):
-        try:
-            import jax
+        import jax
 
-            stats = jax.local_devices()[0].memory_stats()
-        except Exception:
-            stats = None
+        stats = jax.local_devices()[0].memory_stats()
         if not stats:
             return  # gauges stay 0 (CPU backends report no memory_stats)
         self.registry.gauge("device/bytes_in_use").set(

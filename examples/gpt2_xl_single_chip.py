@@ -48,7 +48,9 @@ def main():
     import dataclasses
 
     init_model = GPT2LMHeadModel(dataclasses.replace(cfg, use_flash=False))
-    with jax.default_device(jax.devices("cpu")[0]):
+    from deepspeed_tpu.utils.device import host_cpu_device
+
+    with jax.default_device(host_cpu_device()):
         params = init_model.init(
             {"params": jax.random.PRNGKey(0), "dropout": jax.random.PRNGKey(1)},
             jnp.asarray(ids[:1]), jnp.asarray(ids[:1]),

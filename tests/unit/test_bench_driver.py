@@ -180,3 +180,126 @@ def test_worker_attempt_timeout_capped_by_budget(bench, monkeypatch):
     assert bench._run_attempt({"kind": "bert"}) is None
     # grace window (~60s) past the exhausted budget, floored at 120s
     assert seen["timeout"] <= 121.0
+
+
+# ---------------------------------------------------------------------------
+# no hidden fallbacks (PR 21): only an OOM may move down a ladder, and the
+# training ladder measures a TPU or nothing
+# ---------------------------------------------------------------------------
+def _fake_worker(bench, monkeypatch, returncode, stderr=""):
+    class FakeProc:
+        stdout = ""
+
+    FakeProc.returncode = returncode
+    FakeProc.stderr = stderr
+    monkeypatch.setattr(
+        bench.subprocess, "run", lambda *a, **k: FakeProc()
+    )
+
+
+def test_oom_worker_moves_down_the_ladder(bench, monkeypatch):
+    _fake_worker(bench, monkeypatch, bench.OOM_EXIT)
+    assert bench._run_attempt({"kind": "gpt2"}) is None
+
+
+def test_non_oom_worker_death_fails_the_run(bench, monkeypatch):
+    _fake_worker(
+        bench, monkeypatch, 1, stderr="Traceback\nValueError: bad shape\n"
+    )
+    with pytest.raises(SystemExit) as exc:
+        bench._run_attempt({"kind": "gpt2"})
+    # non-zero, and the worker's own words reach the operator
+    assert exc.value.code not in (0, None)
+    assert "ValueError: bad shape" in str(exc.value.code)
+    assert "not OOM" in str(exc.value.code)
+
+
+def test_worker_that_finds_no_tpu_fails_the_run(bench, monkeypatch):
+    _fake_worker(bench, monkeypatch, bench.NOT_TPU_EXIT)
+    with pytest.raises(SystemExit) as exc:
+        bench._run_attempt({"kind": "bert"})
+    assert "no TPU" in str(exc.value.code)
+
+
+def test_worker_timeout_fails_the_run(bench, monkeypatch):
+    def expire(cmd, timeout=None, **kw):
+        raise bench.subprocess.TimeoutExpired(cmd, timeout)
+
+    monkeypatch.setattr(bench.subprocess, "run", expire)
+    with pytest.raises(SystemExit) as exc:
+        bench._run_attempt({"kind": "squad"})
+    assert "timed out" in str(exc.value.code)
+
+
+def test_training_ladder_main_exits_nonzero_on_worker_crash(
+    bench, monkeypatch
+):
+    """End to end through main(): the first section's crash ends the run;
+    no later, weaker rung gets to print a number."""
+    calls = []
+
+    def crashing(cmd, **kw):
+        calls.append(cmd)
+
+        class P:
+            returncode, stdout, stderr = 134, "", "Fatal Python error"
+
+        return P()
+
+    monkeypatch.setattr(bench.subprocess, "run", crashing)
+    monkeypatch.setattr(sys, "argv", ["bench.py"])
+    with pytest.raises(SystemExit) as exc:
+        bench.main()
+    assert exc.value.code not in (0, None)
+    assert len(calls) == 1
+
+
+def test_worker_refuses_a_non_tpu_platform(bench, monkeypatch):
+    """The worker is the process that holds the chip: on this CPU it must
+    leave with its own exit code before building anything."""
+    monkeypatch.setenv(
+        "BENCH_WORKER", json.dumps({"kind": "bert", "policy": "full",
+                                    "micro": 1, "total": 1})
+    )
+    monkeypatch.setattr(
+        bench, "bert_attempt",
+        lambda *a, **k: pytest.fail("attempt ran on a CPU"),
+    )
+    with pytest.raises(SystemExit) as exc:
+        bench._worker_main()
+    assert exc.value.code == bench.NOT_TPU_EXIT
+
+
+@pytest.mark.parametrize(
+    "text,oom",
+    [
+        ("RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out of "
+         "memory in memory space hbm. Used 18.00G of 15.75G hbm.", True),
+        ("RESOURCE_EXHAUSTED: Error allocating device buffer", True),
+        ("Out of memory while trying to allocate 1073741824 bytes", True),
+        # the bare substring "OOM" used to match all of these
+        ("KeyError: 'ZOOM_LEVEL'", False),
+        ("cannot open BLOOM checkpoint", False),
+        ("ValueError: flash_attention found no block size", False),
+    ],
+)
+def test_is_oom_matches_only_xla_memory_errors(bench, text, oom):
+    assert bench._is_oom(RuntimeError(text)) is oom
+
+
+def test_every_result_line_names_its_device(bench):
+    line = json.loads(bench._result_json({"metric": "m", "value": 1.0}))
+    assert line["device"] == {"platform": "cpu", "kind": "cpu", "count": 8}
+    assert line["metric"] == "m"
+
+
+def test_compile_cache_block_carries_no_path(bench, monkeypatch):
+    """One rule for where the cache lives (runtime/compile_cache.py);
+    bench.py neither reads BENCH_CACHE_DIR nor names a directory."""
+    monkeypatch.setenv("BENCH_CACHE_DIR", "/nonexistent/ignored")
+    import importlib
+
+    importlib.reload(bench)
+    assert "cache_dir" not in bench.COMPILE_CACHE_BLOCK
+    assert bench.COMPILE_CACHE_BLOCK["enabled"] is True
+    assert not hasattr(bench, "CACHE_DIR")

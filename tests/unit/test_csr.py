@@ -7,7 +7,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from deepspeed_tpu.parallel.mesh import build_mesh
-from deepspeed_tpu.runtime.dist import shard_map
 from deepspeed_tpu.runtime.sparse import (
     CSRTensor,
     sparse_all_reduce_local,
@@ -80,12 +79,12 @@ def test_sparse_all_reduce_local_inside_jit():
     from jax.sharding import PartitionSpec as P
 
     fn = jax.jit(
-        shard_map(
+        jax.shard_map(
             lambda i, v: sparse_all_reduce_local(i, v, csr.dense_size),
             mesh=mesh,
             in_specs=(P("data"), P("data")),
             out_specs=P(),
-            check=False,
+            check_vma=False,
         )
     )
     out = fn(idx, val)

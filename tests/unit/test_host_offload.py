@@ -1,8 +1,8 @@
 """ZeRO-Offload analog: fp32 master + moments on the host cpu device
 (`zero_optimization.offload_optimizer: {"device": "cpu"}`).
 
-On tunneled TPU setups this trades step time for HBM (docs/memory.md
-recommends compensated masters there); the SEMANTICS pinned here: state
+It trades step time for HBM (docs/memory.md sets it beside compensated
+masters); the SEMANTICS pinned here: state
 placement on the cpu device, numerics identical to the on-accelerator
 master path, exact checkpoint resume, overflow-skip intact.
 """

@@ -91,15 +91,30 @@ def test_prefill_logits_bitwise_match_full_forward():
 
 
 def test_right_padded_prefill_matches_unpadded_rows():
-    """Causality makes the fixed prefill window's padding columns inert:
-    every real row's logits are bitwise-identical to the unpadded run."""
+    """Causality makes the fixed prefill window's padding columns inert.
+
+    Two pins. WITHIN one program shape the property is exact: whatever
+    sits in the padding columns, every real row's logits are bitwise the
+    same. ACROSS shapes ([1,6] vs [1,16]) the two runs are different XLA
+    programs, and XLA is free to tile and order their reductions
+    differently (jax 0.9.0 does: 1 element of 768 off by 6e-8, two ulps
+    at its magnitude), so that comparison gets four ulps of the largest
+    logit — a bitwise pin there tests the compiler, not the padding."""
     cfg, model, params = _small_model()
     prompt = _prompt(6)
     jit_pre = jax.jit(lambda p, t: gpt2_prefill(cfg, p, t))
     plain, _, _ = jit_pre(params, jnp.asarray([prompt], jnp.int32))
     padded, _, _ = jit_pre(params, jnp.asarray([prompt + [0] * 10], jnp.int32))
+    other, _, _ = jit_pre(
+        params, jnp.asarray([prompt + _prompt(10, seed=9)], jnp.int32)
+    )
     np.testing.assert_array_equal(
-        np.asarray(padded[:, :6]), np.asarray(plain)
+        np.asarray(padded[:, :6]), np.asarray(other[:, :6])
+    )
+    plain = np.asarray(plain)
+    few_ulps = 4 * np.spacing(np.abs(plain).max())
+    np.testing.assert_allclose(
+        np.asarray(padded[:, :6]), plain, rtol=0, atol=few_ulps
     )
 
 
