@@ -24,6 +24,7 @@ from .config import constants as _constants
 from .ops.optimizers import Adam, Lamb, Lion, Optimizer, SGD
 from .ops.transformer import DeepSpeedTransformerConfig, DeepSpeedTransformerLayer
 from .runtime.engine import DeepSpeedEngine
+from .telemetry.registry import install_recompile_hook
 from .version import __version__
 from . import adapters, checkpointing
 
@@ -53,6 +54,7 @@ def initialize(
     """
     from .runtime.engine import EngineOptimizerFacade
 
+    install_recompile_hook()  # compile seconds by phase, telemetry or not
     engine = DeepSpeedEngine(
         args=args,
         model=model,
@@ -99,6 +101,7 @@ def init_inference(
     """
     from .inference.engine import init_inference as _init_inference
 
+    install_recompile_hook()  # compile seconds by phase, telemetry or not
     return _init_inference(
         model=model,
         config=config,

@@ -25,7 +25,6 @@ the training engine's streams — and onto a private registry otherwise
 (counting is cheap; tests and the bench smoke read it either way).
 """
 
-import time
 
 import numpy as np
 
@@ -43,6 +42,7 @@ from ..models.gpt2 import (
 from ..parallel import mesh as mesh_lib
 from ..telemetry.manager import build_telemetry, register_inference_metrics
 from ..telemetry.registry import MetricsRegistry
+from ..telemetry.tracing import phase
 from ..utils.logging import log_dist, logger
 from .decode import (
     gpt2_decode_step,
@@ -556,25 +556,29 @@ class InferenceEngine:
         # engine therefore traces the EXACT pre-adapter programs (the
         # adapter-off bitwise-parity contract, tests/unit/test_adapters).
         lora_kw = dict(lora_scale=self.adapter_scale)
+        # The jitted programs are named for what they serve (a trace's
+        # module event is jit_<function name>): readers of a profiler
+        # trace find serve_decode / serve_prefill / serve_prefill_suffix /
+        # serve_first_token by these names, so keep them.
 
         def _split_ad(ad):
             # (adapters, adapter_ids) from the trailing args, or Nones
             return ad if ad else (None, None)
 
-        def prefill_fn(p, toks, *ad):
+        def serve_prefill(p, toks, *ad):
             apool, aids = _split_ad(ad)
             return gpt2_prefill(
                 mcfg, p, toks, adapters=apool, adapter_ids=aids, **lora_kw
             )
 
-        self._jit_prefill = jax.jit(prefill_fn)
+        self._jit_prefill = jax.jit(serve_prefill)
         if self.paged:
             self._jit_write_prefill = jax.jit(
                 write_prefill_to_pool,
                 donate_argnums=(0,) if donate_cache else (),
             )
 
-            def decode_fn(p, toks, pos, temps, key, pool, tables, *ad):
+            def serve_decode(p, toks, pos, temps, key, pool, tables, *ad):
                 return self._decode_and_sample_paged(
                     p, toks, pos, temps, key, pool, tables, *_split_ad(ad)
                 )
@@ -583,7 +587,7 @@ class InferenceEngine:
             # specializes on the padded suffix shape); start_pos stays a
             # traced array so every prefix length shares the bucket's
             # program
-            def suffix_fn(p, suf, sp, pool, bt, *ad):
+            def serve_prefill_suffix(p, suf, sp, pool, bt, *ad):
                 apool, aids = _split_ad(ad)
                 return gpt2_prefill_suffix(
                     mcfg, p, suf, sp, pool, bt, adapters=apool,
@@ -591,7 +595,7 @@ class InferenceEngine:
                 )
 
             self._jit_prefill_suffix = jax.jit(
-                suffix_fn, donate_argnums=(3,) if donate_cache else ()
+                serve_prefill_suffix, donate_argnums=(3,) if donate_cache else ()
             )
         else:
             self._jit_write_prefill = jax.jit(
@@ -599,24 +603,25 @@ class InferenceEngine:
                 donate_argnums=(0,) if donate_cache else (),
             )
 
-            def decode_fn(p, toks, pos, temps, key, cache, *ad):
+            def serve_decode(p, toks, pos, temps, key, cache, *ad):
                 return self._decode_and_sample(
                     p, toks, pos, temps, key, cache, *_split_ad(ad)
                 )
 
         self._jit_decode = jax.jit(
-            decode_fn, donate_argnums=(5,) if donate_cache else ()
+            serve_decode, donate_argnums=(5,) if donate_cache else ()
         )
         # first token rides a traced last-prompt-row index so every prompt
         # length reuses ONE compiled program (an eager logits[:, plen-1]
         # slice would compile per distinct length and trip the
         # no-recompile pin)
-        self._jit_first_token = jax.jit(
-            lambda logits, idx, key, temp: sample_tokens(
+        def serve_first_token(logits, idx, key, temp):
+            return sample_tokens(
                 jax.lax.dynamic_slice_in_dim(logits, idx, 1, axis=1)[:, 0, :],
                 key, temp, **self._sampling_statics,
             )
-        )
+
+        self._jit_first_token = jax.jit(serve_first_token)
         if self.host_tier is not None:
             # host-tier copy programs, all with TRACED indices so the
             # thousandth spill/promotion compiles nothing new:
@@ -706,16 +711,16 @@ class InferenceEngine:
             draft_vocab = int(dcfg.vocab_size)
             spec_k = self.spec_k
 
-            def draft_prefill_fn(dp, toks):
+            def serve_draft_prefill(dp, toks):
                 return gpt2_prefill(dcfg, dp, toks)
 
-            self._jit_draft_prefill = jax.jit(draft_prefill_fn)
+            self._jit_draft_prefill = jax.jit(serve_draft_prefill)
             self._jit_draft_write = jax.jit(
                 write_prefill_to_cache,
                 donate_argnums=(0,) if donate_cache else (),
             )
 
-            def propose_fn(dp, prev_tokens, tokens, positions, cache):
+            def serve_draft_propose(dp, prev_tokens, tokens, positions, cache):
                 """One sync step + k greedy draft steps under one
                 program: proposals [slots, k]. k is STATIC (the scan
                 length) — acceptance is data, so no steady-state
@@ -755,10 +760,10 @@ class InferenceEngine:
                 return jnp.transpose(props), cache  # [slots, k]
 
             self._jit_draft_propose = jax.jit(
-                propose_fn, donate_argnums=(4,) if donate_cache else ()
+                serve_draft_propose, donate_argnums=(4,) if donate_cache else ()
             )
 
-            def verify_fn(p, toks, start, pool, tables, *ad):
+            def serve_spec_verify(p, toks, start, pool, tables, *ad):
                 """ONE fixed-shape batched target step over the k+1
                 verify tokens [last, d_1..d_k] per slot: suffix-prefill
                 arithmetic against the paged cache (k/v written through
@@ -783,12 +788,8 @@ class InferenceEngine:
                 return greedy, pool
 
             self._jit_spec_verify = jax.jit(
-                verify_fn, donate_argnums=(3,) if donate_cache else ()
+                serve_spec_verify, donate_argnums=(3,) if donate_cache else ()
             )
-        # per-step draft/verify/commit phase stats, read by the
-        # scheduler's sched.spec_* span recording (None when the last
-        # step was not speculative)
-        self.spec_step_stats = None
 
         # ---- KV metric streams ----------------------------------------
         self._kv_occupancy = self.metrics.gauge("infer/kv_pool_occupancy")
@@ -1714,20 +1715,21 @@ class InferenceEngine:
                 prompt_tokens, self._slot_blocks[slot],
                 hashes=self._slot_hashes.get(slot),
             )
-        if self.tracer.enabled:
-            attrs = {
-                "prompt_tokens": plen,
-                "prefix_hit": prefix_len > 0,
-                "prefix_len": int(prefix_len),
-            }
-            if prefix_len > 0:
-                attrs["suffix_bucket"] = self._suffix_bucket(
-                    plen - prefix_len, prefix_len
-                )
-            adapter = self._slot_adapter_names.get(slot)
-            if adapter is not None:
-                attrs["adapter"] = adapter
-            self._slot_trace_attrs[slot] = attrs
+        # what the scheduler's sched.prefill phase says of this prefill
+        # (the profiler's annotation takes it with telemetry off too)
+        attrs = {
+            "prompt_tokens": plen,
+            "prefix_hit": prefix_len > 0,
+            "prefix_len": int(prefix_len),
+        }
+        if prefix_len > 0:
+            attrs["suffix_bucket"] = self._suffix_bucket(
+                plen - prefix_len, prefix_len
+            )
+        adapter = self._slot_adapter_names.get(slot)
+        if adapter is not None:
+            attrs["adapter"] = adapter
+        self._slot_trace_attrs[slot] = attrs
         self._lengths[slot] = plen
         self._last_tokens[slot] = first
         self._temps[slot] = temperature
@@ -1901,74 +1903,66 @@ class InferenceEngine:
         verify (target) / propose (draft) overwrites those same rows —
         the dead-slot ride-along argument applied forward in time."""
         k = self.spec_k
-        t0 = time.monotonic()
-        props, self._draft_cache = self._jit_draft_propose(
-            self._draft_params,
-            jnp.asarray(self._spec_prev_tokens),
-            jnp.asarray(self._last_tokens),
-            jnp.asarray(self._lengths),
-            self._draft_cache,
-        )
-        props = np.asarray(props)  # [slots, k]
-        t1 = time.monotonic()
-        # verify tokens per slot: [last, d_1 .. d_k] — row i's argmax is
-        # the target's next token after consuming verify token i
-        verify_tokens = np.concatenate(
-            [self._last_tokens[:, None], props], axis=1
-        ).astype(np.int32)
-        args = (
-            self.params,
-            jnp.asarray(verify_tokens),
-            jnp.asarray(self._lengths),
-            self._cache,
-            jnp.asarray(self._block_tables),
-        )
-        if self.multi_lora:
-            args = args + (
-                self._adapter_pool, jnp.asarray(self._slot_adapters),
+        proposed = k * len(active_slots)
+        with phase("sched.spec_draft", proposed=proposed):
+            props, self._draft_cache = self._jit_draft_propose(
+                self._draft_params,
+                jnp.asarray(self._spec_prev_tokens),
+                jnp.asarray(self._last_tokens),
+                jnp.asarray(self._lengths),
+                self._draft_cache,
             )
-        greedy, self._cache = self._jit_spec_verify(*args)
-        greedy = np.asarray(greedy)  # [slots, k+1]
-        t2 = time.monotonic()
+            props = np.asarray(props)  # [slots, k]
+        with phase("sched.spec_verify", proposed=proposed):
+            # verify tokens per slot: [last, d_1 .. d_k] — row i's argmax
+            # is the target's next token after consuming verify token i
+            verify_tokens = np.concatenate(
+                [self._last_tokens[:, None], props], axis=1
+            ).astype(np.int32)
+            args = (
+                self.params,
+                jnp.asarray(verify_tokens),
+                jnp.asarray(self._lengths),
+                self._cache,
+                jnp.asarray(self._block_tables),
+            )
+            if self.multi_lora:
+                args = args + (
+                    self._adapter_pool, jnp.asarray(self._slot_adapters),
+                )
+            greedy, self._cache = self._jit_spec_verify(*args)
+            greedy = np.asarray(greedy)  # [slots, k+1]
         out = []
-        proposed = accepted = committed = 0
-        for slot in active_slots:
-            g, pr = greedy[slot], props[slot]
-            j = 0
-            while j < k and pr[j] == g[j]:
-                j += 1
-            # d_1..d_j matched the target's own choices; g[j] is the
-            # target's token at the first divergence (the BONUS token
-            # when everything matched)
-            toks = [int(t) for t in pr[:j]] + [int(g[j])]
-            self._lengths[slot] += len(toks)
-            # token at the new index lengths-1: the burst's second-to-
-            # last commit, or the previous last for a 1-token burst —
-            # what the next propose's sync step re-feeds
-            self._spec_prev_tokens[slot] = (
-                toks[-2] if len(toks) >= 2 else self._last_tokens[slot]
-            )
-            self._last_tokens[slot] = toks[-1]
-            proposed += k
-            accepted += j
-            committed += len(toks)
-            out.append(toks)
-        t3 = time.monotonic()
+        accepted = committed = 0
+        with phase("sched.spec_commit") as commit:
+            for slot in active_slots:
+                g, pr = greedy[slot], props[slot]
+                j = 0
+                while j < k and pr[j] == g[j]:
+                    j += 1
+                # d_1..d_j matched the target's own choices; g[j] is the
+                # target's token at the first divergence (the BONUS token
+                # when everything matched)
+                toks = [int(t) for t in pr[:j]] + [int(g[j])]
+                self._lengths[slot] += len(toks)
+                # token at the new index lengths-1: the burst's second-
+                # to-last commit, or the previous last for a 1-token
+                # burst — what the next propose's sync step re-feeds
+                self._spec_prev_tokens[slot] = (
+                    toks[-2] if len(toks) >= 2 else self._last_tokens[slot]
+                )
+                self._last_tokens[slot] = toks[-1]
+                accepted += j
+                committed += len(toks)
+                out.append(toks)
+            commit.set_attr("accepted", accepted)
+            commit.set_attr("committed", committed)
         self._spec_proposed.inc(proposed)
         self._spec_accepted.inc(accepted)
         total = self._spec_proposed.value
         self._spec_rate.set(
             self._spec_accepted.value / total if total else 0.0
         )
-        # phase stats for the scheduler's sched.spec_* spans — the
-        # draft/verify/commit attribution the flight recorder dumps
-        self.spec_step_stats = {
-            "draft_t0": t0, "draft_t1": t1,
-            "verify_t0": t1, "verify_t1": t2,
-            "commit_t0": t2, "commit_t1": t3,
-            "proposed": proposed, "accepted": accepted,
-            "committed": committed,
-        }
         return out
 
     # -- serving API ----------------------------------------------------

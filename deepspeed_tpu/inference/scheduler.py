@@ -44,7 +44,7 @@ import uuid
 
 from ..adapters.pool import AdapterPoolFull
 from ..telemetry.registry import DEFAULT_TIME_BUCKETS_MS, histogram_quantile
-from ..telemetry.tracing import NOOP_TRACER, TraceContext
+from ..telemetry.tracing import NOOP_TRACER, TraceContext, phase
 from ..utils.logging import logger
 from .paging import PoolExhausted
 
@@ -87,6 +87,7 @@ _FINISH_LENGTH = "length"
 _FINISH_CANCELLED = "cancelled"
 _FINISH_DEADLINE = "deadline"
 _FINISH_ERROR = "error"
+_DEFERRED = object()  # _join_and_prefill: rows or pages came up short
 
 # infer/health_state gauge values (docs/observability.md)
 HEALTH_HEALTHY = 0
@@ -297,18 +298,12 @@ class ContinuousBatchingScheduler:
         ctx = req.trace_ctx
         return ctx.trace_id if ctx is not None and ctx.sampled else None
 
-    def _trace_phase(self, req, name, t0, t1, attrs=None):
-        """Record one request-phase span under the request's container
-        span; sampled spans also collect on the request for RPC
-        shipping. Call sites gate on ``self._tracer.enabled``."""
-        if req.trace_ctx is None:
-            return None
-        span = self._tracer.record(
-            name, t0, t1, ctx=req.trace_ctx, attrs=attrs
-        )
+    @staticmethod
+    def _ship_span(req, span):
+        """Sampled request-phase spans also collect on the request, for
+        RPC shipping to the router's trace file."""
         if span is not None and span["sampled"]:
             req.trace_spans.append(span)
-        return span
 
     def _reject_event(self, reason):
         """Admission-verdict breadcrumb for the flight recorder."""
@@ -776,7 +771,7 @@ class ContinuousBatchingScheduler:
         taking the slot. On a paged engine the slot join first reserves
         the request's worst-case KV pages; a shortfall DEFERS the request
         (no slot, no pages) until a finishing request frees pages."""
-        reserve = getattr(self._engine, "reserve_request", None)
+        admitted = 0
         for slot, occupant in enumerate(self._slots):
             if occupant is not None:
                 continue
@@ -799,92 +794,20 @@ class ContinuousBatchingScheduler:
             if req is None:
                 break
             t0 = time.monotonic()
-            # the request OWNS the slot before prefill runs: a prefill
-            # that raises (device OOM, injected chaos) then leaves it in
-            # the slot table, where the crash-recovery / fail-finish
-            # sweeps reach it — popped-but-unplaced requests would hang
-            # their result() waiters forever
-            self._slots[slot] = req
-            self._slot_admit_seq[slot] = self._admit_seq
-            self._admit_seq += 1
-            # a PREEMPTED request re-enters here with committed tokens in
-            # req.tokens: it resumes suffix-only — the effective prompt is
-            # everything already served (original prompt + committed
-            # tokens, whose full KV blocks were registered at park time,
-            # so the re-prefill mostly hits the prefix cache / host tier)
-            # and only the remaining generation budget is re-reserved
-            eff_prompt = list(req.prompt_tokens) + list(req.tokens)
-            eff_budget = max(1, int(req.max_new_tokens) - len(req.tokens))
-            assign = getattr(self._engine, "assign_slot_adapter", None)
-            if assign is not None:
-                try:
-                    joined = assign(slot, getattr(req, "adapter", None))
-                except AdapterPoolFull:
-                    # the adapter is parked in the host tier but every
-                    # pool row is pinned by live requests: defer exactly
-                    # like a KV page shortfall — a finishing request
-                    # unpins a row and the auto-load lands next step
-                    self._free_slot(slot)
-                    self._deferred.appendleft(req)
-                    if self._tracer.enabled:
-                        self._tracer.event(
-                            "sched.defer", ctx=req.trace_ctx,
-                            attrs={
-                                "request_id": req.request_id,
-                                "reason": "adapter_pool",
-                            },
-                        )
-                    break
-                if not joined:
-                    # the adapter was evicted between submit and slot
-                    # join (and is not recoverable from the host tier):
-                    # fail the request loudly rather than decode it
-                    # against the identity (or another tenant's) weights;
-                    # the slot refills at the next step boundary
-                    self._free_slot(slot)
-                    req._finish(_FINISH_ERROR)
-                    continue
-            if reserve is not None:
-                try:
-                    reserve(slot, eff_prompt, eff_budget)
-                except PoolExhausted:
-                    # no pages right now: park the request at the head of
-                    # the deferred line and stop admitting this step —
-                    # an active request's release is what unblocks it.
-                    # _free_slot (not a bare table clear): the slot
-                    # already pinned its adapter above, and leaking that
-                    # reference would make the adapter un-evictable (and
-                    # leave a stale prefix-cache salt on the slot)
-                    self._free_slot(slot)
-                    self._deferred.appendleft(req)
-                    if self._tracer.enabled:
-                        self._tracer.event(
-                            "sched.defer", ctx=req.trace_ctx,
-                            attrs={"request_id": req.request_id},
-                        )
-                    break
-            if self._tracer.enabled:
-                self._trace_phase(req, "sched.queue", req.submitted_at, t0)
-            self._queue_wait_ms.observe(
-                (t0 - req.submitted_at) * 1e3, trace_id=self._trace_id(req)
-            )
-            first = self._engine.prefill_request(
-                slot, eff_prompt, req.temperature
-            )
+            with phase(
+                "sched.prefill", req.trace_ctx, self._tracer,
+                queue_wait_ms=round((t0 - req.submitted_at) * 1e3, 3),
+            ) as prefill:
+                first = self._join_and_prefill(slot, req, t0, prefill)
+            self._ship_span(req, prefill.span)
+            if first is _DEFERRED:
+                break
+            if first is None:
+                continue  # failed loudly; the slot refills next step
+            admitted += 1
             now = time.monotonic()
-            if self._tracer.enabled:
-                # prefix-hit/cold, suffix bucket, adapter name — the
-                # engine owns those facts; the hook keeps this module
-                # jax-free (and stub-engine friendly)
-                attrs_fn = getattr(
-                    self._engine, "prefill_trace_attrs", None
-                )
-                self._trace_phase(
-                    req, "sched.prefill", t0, now,
-                    attrs=attrs_fn(slot) if attrs_fn is not None else None,
-                )
             self._prefill_ms.observe(
-                (now - t0) * 1e3, trace_id=self._trace_id(req)
+                prefill.seconds * 1e3, trace_id=self._trace_id(req)
             )
             req.first_token_at = now
             self._ttft_ms.observe(
@@ -894,6 +817,100 @@ class ContinuousBatchingScheduler:
             # a 1-token request (or instant EOS) frees the slot right here
             self._count_token(req, first)
         self._occupancy.set(len(self.active_slots))
+        return admitted
+
+    def _join_and_prefill(self, slot, req, t0, prefill):
+        """Give ``req`` the slot, its adapter row and its KV pages, and
+        prefill it: the first token, ``_DEFERRED`` when rows or pages came
+        up short (the request waits at the head of the deferred line and
+        admission stops for this step), None when it failed for good.
+        Runs inside the request's ``sched.prefill`` phase."""
+        # the request OWNS the slot before prefill runs: a prefill
+        # that raises (device OOM, injected chaos) then leaves it in
+        # the slot table, where the crash-recovery / fail-finish
+        # sweeps reach it — popped-but-unplaced requests would hang
+        # their result() waiters forever
+        self._slots[slot] = req
+        self._slot_admit_seq[slot] = self._admit_seq
+        self._admit_seq += 1
+        # a PREEMPTED request re-enters here with committed tokens in
+        # req.tokens: it resumes suffix-only — the effective prompt is
+        # everything already served (original prompt + committed
+        # tokens, whose full KV blocks were registered at park time,
+        # so the re-prefill mostly hits the prefix cache / host tier)
+        # and only the remaining generation budget is re-reserved
+        eff_prompt = list(req.prompt_tokens) + list(req.tokens)
+        eff_budget = max(1, int(req.max_new_tokens) - len(req.tokens))
+        assign = getattr(self._engine, "assign_slot_adapter", None)
+        if assign is not None:
+            try:
+                joined = assign(slot, getattr(req, "adapter", None))
+            except AdapterPoolFull:
+                # the adapter is parked in the host tier but every
+                # pool row is pinned by live requests: defer exactly
+                # like a KV page shortfall — a finishing request
+                # unpins a row and the auto-load lands next step
+                self._free_slot(slot)
+                self._deferred.appendleft(req)
+                if self._tracer.enabled:
+                    self._tracer.event(
+                        "sched.defer", ctx=req.trace_ctx,
+                        attrs={
+                            "request_id": req.request_id,
+                            "reason": "adapter_pool",
+                        },
+                    )
+                prefill.set_attr("outcome", "deferred")
+                return _DEFERRED
+            if not joined:
+                # the adapter was evicted between submit and slot
+                # join (and is not recoverable from the host tier):
+                # fail the request loudly rather than decode it
+                # against the identity (or another tenant's) weights;
+                # the slot refills at the next step boundary
+                self._free_slot(slot)
+                req._finish(_FINISH_ERROR)
+                prefill.set_attr("outcome", "adapter_lost")
+                return None
+        reserve = getattr(self._engine, "reserve_request", None)
+        if reserve is not None:
+            try:
+                reserve(slot, eff_prompt, eff_budget)
+            except PoolExhausted:
+                # no pages right now: park the request at the head of
+                # the deferred line and stop admitting this step —
+                # an active request's release is what unblocks it.
+                # _free_slot (not a bare table clear): the slot
+                # already pinned its adapter above, and leaking that
+                # reference would make the adapter un-evictable (and
+                # leave a stale prefix-cache salt on the slot)
+                self._free_slot(slot)
+                self._deferred.appendleft(req)
+                if self._tracer.enabled:
+                    self._tracer.event(
+                        "sched.defer", ctx=req.trace_ctx,
+                        attrs={"request_id": req.request_id},
+                    )
+                prefill.set_attr("outcome", "deferred")
+                return _DEFERRED
+        if self._tracer.enabled and req.trace_ctx is not None:
+            # known only now, and begun on the submitter's thread
+            self._ship_span(req, self._tracer.record(
+                "sched.queue", req.submitted_at, t0, ctx=req.trace_ctx
+            ))
+        self._queue_wait_ms.observe(
+            (t0 - req.submitted_at) * 1e3, trace_id=self._trace_id(req)
+        )
+        first = self._engine.prefill_request(
+            slot, eff_prompt, req.temperature
+        )
+        # prefix-hit/cold, suffix bucket, adapter name — the engine
+        # owns those facts; the hook keeps this module jax-free (and
+        # stub-engine friendly)
+        attrs_fn = getattr(self._engine, "prefill_trace_attrs", None)
+        for key, value in (attrs_fn(slot) if attrs_fn else {}).items():
+            prefill.set_attr(key, value)
+        return first
 
     def _count_token(self, req, token):
         """Record one generated token for ``req`` (slot state lives in the
@@ -926,50 +943,24 @@ class ContinuousBatchingScheduler:
         # reclaim past-deadline slots FIRST: the freed slots are
         # admittable in this same step
         self._expire_deadlines()
-        self._admit()
+        admitted = self._admit()
         self._ensure_decode_capacity()
         active = self.active_slots
         if not active:
             self._flush_rate()  # settle the window's tail tokens
             self._rate_anchor = None  # idle: don't dilute the next window
             return 0
-        t0 = time.monotonic()
-        next_tokens = self._engine.decode_tokens(active)
-        t1 = time.monotonic()
-        if self._tracer.enabled:
+        if self._tracer.enabled and self._driver_ctx is None:
             # batch-level span: one decode step serves EVERY active slot,
             # so it parents to the driver's trace, not any one request
-            if self._driver_ctx is None:
-                self._driver_ctx = self._tracer.child_of(None)
-            self._tracer.record(
-                "sched.decode_step", t0, t1, ctx=self._driver_ctx,
-                attrs={"active_slots": len(active), "step": self._steps},
-            )
-            # speculative engines report the step's draft/verify/commit
-            # phase split (docs/observability.md): three sibling spans
-            # under the driver trace, so flight-recorder dumps and the
-            # bench's per-phase breakdown attribute the decode-step time
-            stats = getattr(self._engine, "spec_step_stats", None)
-            if stats is not None:
-                self._tracer.record(
-                    "sched.spec_draft", stats["draft_t0"],
-                    stats["draft_t1"], ctx=self._driver_ctx,
-                    attrs={"proposed": stats["proposed"]},
-                )
-                self._tracer.record(
-                    "sched.spec_verify", stats["verify_t0"],
-                    stats["verify_t1"], ctx=self._driver_ctx,
-                    attrs={
-                        "proposed": stats["proposed"],
-                        "accepted": stats["accepted"],
-                    },
-                )
-                self._tracer.record(
-                    "sched.spec_commit", stats["commit_t0"],
-                    stats["commit_t1"], ctx=self._driver_ctx,
-                    attrs={"committed": stats["committed"]},
-                )
-        self._token_latency_ms.observe((t1 - t0) * 1e3)
+            self._driver_ctx = self._tracer.child_of(None)
+        # a speculative engine opens sched.spec_draft/verify/commit inside
+        with phase(
+            "sched.decode_step", self._driver_ctx, self._tracer,
+            active_slots=len(active), admitted=admitted, step=self._steps,
+        ) as decode:
+            next_tokens = self._engine.decode_tokens(active)
+        self._token_latency_ms.observe(decode.seconds * 1e3)
         for slot, token in zip(active, next_tokens):
             req = self._slots[slot]
             if req is None:

@@ -47,6 +47,7 @@ from ..config import constants as C
 from ..config.config import DeepSpeedConfig, DeepSpeedConfigError
 from ..ops.optimizers import Optimizer, build_optimizer
 from ..resilience.supervisor import SupervisorEscalation
+from ..telemetry.tracing import phase
 from ..parallel import mesh as mesh_lib
 from ..parallel.mpu import TPUMpu
 from ..utils.logging import log_dist, logger, warn_once
@@ -302,9 +303,11 @@ class DeepSpeedEngine:
         # Deep-copy the caller's parameters: the jitted update step donates
         # its param buffers, and aliasing the user's pytree would delete
         # their arrays out from under them.
-        params_f32 = jax.tree_util.tree_map(
-            lambda p: jnp.array(p, dtype=jnp.float32, copy=True), model_parameters
-        )
+        with phase("init.place_params"):  # the float32 host-side copy
+            params_f32 = jax.tree_util.tree_map(
+                lambda p: jnp.array(p, dtype=jnp.float32, copy=True),
+                model_parameters,
+            )
         # parameter count feeds telemetry's model-TFLOPS gauge (bench.py's
         # 6*N-per-token accounting); a LoRA fine-tune still pushes every
         # token through the frozen base, so those params count too
@@ -406,78 +409,82 @@ class DeepSpeedEngine:
                 and getattr(self.config.zero_config, "master_weights", True)
             )
         )
-        if self.master_in_opt or self.compensated_master:
-            self.params = jax.device_put(
-                jax.tree_util.tree_map(
-                    lambda p: p.astype(self.compute_dtype), params_f32
-                ),
-                self._param_shardings,
-            )
-        else:
-            self.params = jax.device_put(params_f32, self._param_shardings)
+        with phase("init.place_params"):
+            if self.master_in_opt or self.compensated_master:
+                self.params = jax.device_put(
+                    jax.tree_util.tree_map(
+                        lambda p: p.astype(self.compute_dtype), params_f32
+                    ),
+                    self._param_shardings,
+                )
+            else:
+                self.params = jax.device_put(
+                    params_f32, self._param_shardings
+                )
         if stage >= C.ZERO_OPTIMIZATION_WEIGHTS and dp_size > 1:
             self._zero3_account_bytes()
 
         # ---- optimizer ------------------------------------------------
-        self.optimizer_obj = self._configure_optimizer()
-        if stage >= 1 and type(self.optimizer_obj).__name__ == "FusedLamb":
-            # the opaque pallas_call is not partitionable by GSPMD: sharded
-            # optimizer-state leaves would be gathered at the kernel
-            # boundary, silently undoing the ZeRO memory saving
-            log_dist(
-                "WARNING: FusedLamb's Pallas kernel is not GSPMD-"
-                "partitionable; with zero_optimization.stage >= 1 the "
-                "sharded optimizer state is gathered at the kernel "
-                "boundary. Use optimizer type 'Lamb' (XLA-fused, shards "
-                "cleanly) with ZeRO.",
-                ranks=[0],
-            )
-        inner_state = self.optimizer_obj.init(params_f32)
-        inner_shardings = zero_lib.specs_to_shardings(
-            zero_lib.optstate_specs_like(
-                inner_state, optstate_param_specs, params_f32,
-                dp_size=dp_size,
-            ),
-            self._mesh,
-        )
-        if self.host_offload:
-            from ..utils.device import host_cpu_device
-
-            cpu = host_cpu_device()
-            self._cpu_device = cpu
-            from jax.sharding import SingleDeviceSharding
-
-            cpu_sh = SingleDeviceSharding(cpu)
-            self._opt_shardings = {
-                "master": jax.tree_util.tree_map(lambda _: cpu_sh, params_f32),
-                "inner": jax.tree_util.tree_map(
-                    lambda _: cpu_sh, inner_state
+        with phase("init.optimizer_state"):
+            self.optimizer_obj = self._configure_optimizer()
+            if stage >= 1 and type(self.optimizer_obj).__name__ == "FusedLamb":
+                # the opaque pallas_call is not partitionable by GSPMD: sharded
+                # optimizer-state leaves would be gathered at the kernel
+                # boundary, silently undoing the ZeRO memory saving
+                log_dist(
+                    "WARNING: FusedLamb's Pallas kernel is not GSPMD-"
+                    "partitionable; with zero_optimization.stage >= 1 the "
+                    "sharded optimizer state is gathered at the kernel "
+                    "boundary. Use optimizer type 'Lamb' (XLA-fused, shards "
+                    "cleanly) with ZeRO.",
+                    ranks=[0],
+                )
+            inner_state = self.optimizer_obj.init(params_f32)
+            inner_shardings = zero_lib.specs_to_shardings(
+                zero_lib.optstate_specs_like(
+                    inner_state, optstate_param_specs, params_f32,
+                    dp_size=dp_size,
                 ),
-            }
-            self.optimizer_state = {
-                "master": jax.device_put(params_f32, cpu),
-                "inner": jax.device_put(inner_state, cpu),
-            }
-            log_dist(
-                "ZeRO-Offload: fp32 master + optimizer moments on host "
-                "cpu; accelerator holds compute-dtype params/grads "
-                "(per-step d2h grads + h2d params)",
-                ranks=[0],
+                self._mesh,
             )
-        elif self.master_in_opt:
-            master_shardings = zero_lib.specs_to_shardings(
-                optstate_param_specs, self._mesh
-            )
-            self._opt_shardings = {
-                "master": master_shardings, "inner": inner_shardings,
-            }
-            self.optimizer_state = {
-                "master": jax.device_put(params_f32, master_shardings),
-                "inner": jax.device_put(inner_state, inner_shardings),
-            }
-        else:
-            self._opt_shardings = inner_shardings
-            self.optimizer_state = jax.device_put(inner_state, inner_shardings)
+            if self.host_offload:
+                from ..utils.device import host_cpu_device
+
+                cpu = host_cpu_device()
+                self._cpu_device = cpu
+                from jax.sharding import SingleDeviceSharding
+
+                cpu_sh = SingleDeviceSharding(cpu)
+                self._opt_shardings = {
+                    "master": jax.tree_util.tree_map(lambda _: cpu_sh, params_f32),
+                    "inner": jax.tree_util.tree_map(
+                        lambda _: cpu_sh, inner_state
+                    ),
+                }
+                self.optimizer_state = {
+                    "master": jax.device_put(params_f32, cpu),
+                    "inner": jax.device_put(inner_state, cpu),
+                }
+                log_dist(
+                    "ZeRO-Offload: fp32 master + optimizer moments on host "
+                    "cpu; accelerator holds compute-dtype params/grads "
+                    "(per-step d2h grads + h2d params)",
+                    ranks=[0],
+                )
+            elif self.master_in_opt:
+                master_shardings = zero_lib.specs_to_shardings(
+                    optstate_param_specs, self._mesh
+                )
+                self._opt_shardings = {
+                    "master": master_shardings, "inner": inner_shardings,
+                }
+                self.optimizer_state = {
+                    "master": jax.device_put(params_f32, master_shardings),
+                    "inner": jax.device_put(inner_state, inner_shardings),
+                }
+            else:
+                self._opt_shardings = inner_shardings
+                self.optimizer_state = jax.device_put(inner_state, inner_shardings)
         del params_f32  # don't pin the unsharded fp32 copy beyond init
 
         # ---- grad accumulation buffer ---------------------------------
@@ -655,7 +662,8 @@ class DeepSpeedEngine:
             self.training_dataloader = self.deepspeed_io(training_data)
 
         # ---- jitted functions -----------------------------------------
-        self._build_jitted_steps()
+        with phase("init.build_steps"):
+            self._build_jitted_steps()
 
         log_dist(
             f"DeepSpeedEngine initialized: mesh={dict(self._mesh.shape)} "
@@ -1380,16 +1388,23 @@ class DeepSpeedEngine:
         def update_body(params, opt_state, grad_buffer, scaler_state, lr,
                         mom):
             inv_scale = 1.0 / scaler_state.loss_scale
-            raw_norm, overflow = detect_overflow(grad_buffer)
-            new_params, new_opt, grad_norm, coeffs = cond_update(
-                params, opt_state, grad_buffer, raw_norm, overflow,
-                inv_scale, lr, mom, "master" if master_in_opt else "plain",
-            )
-            new_params = jax.tree_util.tree_map(
-                lambda p, s: jax.lax.with_sharding_constraint(p, s),
-                new_params,
-                param_shardings,
-            )
+            # a collective carries the scope of the operation that
+            # PRODUCED the resharded value, so these two scopes say which
+            # of the update's collectives are the norm's and which the
+            # parameters' (benchmark reader collective_scope_time)
+            with jax.named_scope("update_grad_norm"):
+                raw_norm, overflow = detect_overflow(grad_buffer)
+            with jax.named_scope("update_apply"):
+                new_params, new_opt, grad_norm, coeffs = cond_update(
+                    params, opt_state, grad_buffer, raw_norm, overflow,
+                    inv_scale, lr, mom,
+                    "master" if master_in_opt else "plain",
+                )
+                new_params = jax.tree_util.tree_map(
+                    lambda p, s: jax.lax.with_sharding_constraint(p, s),
+                    new_params,
+                    param_shardings,
+                )
             new_scaler = update_scale(scaler_state, overflow)
             return new_params, new_opt, new_scaler, overflow, grad_norm, coeffs
 
@@ -1417,14 +1432,17 @@ class DeepSpeedEngine:
                 fresh compute-dtype params derive from it); returns those
                 params for the h2d push."""
                 inv_scale = 1.0 / scaler_state.loss_scale
-                raw_norm, overflow = detect_overflow(grads)
+                with jax.named_scope("update_grad_norm"):
+                    raw_norm, overflow = detect_overflow(grads)
                 params_like = jax.tree_util.tree_map(
                     lambda m: m.astype(compute_dtype), master
                 )
-                new_params, new_opt, grad_norm, coeffs = cond_update(
-                    params_like, {"master": master, "inner": inner}, grads,
-                    raw_norm, overflow, inv_scale, lr, mom, "master",
-                )
+                with jax.named_scope("update_apply"):
+                    new_params, new_opt, grad_norm, coeffs = cond_update(
+                        params_like, {"master": master, "inner": inner},
+                        grads, raw_norm, overflow, inv_scale, lr, mom,
+                        "master",
+                    )
                 new_scaler = update_scale(scaler_state, overflow)
                 return (
                     new_params, new_opt["master"], new_opt["inner"],
@@ -1704,7 +1722,8 @@ class DeepSpeedEngine:
                 jax.tree_util.tree_leaves(self.optimizer_state)[0]
             )
             self.timers(STEP_TIMER).stop()
-        self._finish_step(overflow, grad_norm, coeffs, window_loss)
+        with phase("train.finish_step"):
+            self._finish_step(overflow, grad_norm, coeffs, window_loss)
 
     def _finish_step(self, overflow, grad_norm, coeffs, window_loss):
         """Post-update host bookkeeping shared by step() and train_batch():
@@ -1949,6 +1968,12 @@ class DeepSpeedEngine:
         saves force ``keep_last=False`` first, so persisted counters are
         always truthful and pending scalars are flushed."""
         keep = 1 if keep_last else 0
+        if len(self._deferred_overflows) <= keep:
+            return
+        with phase("train.settle"):  # bool(flag) WAITS for that window
+            self._settle_deferred(keep)
+
+    def _settle_deferred(self, keep):
         while len(self._deferred_overflows) > keep:
             flag, entry = self._deferred_overflows.pop(0)
             if not bool(flag):
@@ -2041,6 +2066,18 @@ class DeepSpeedEngine:
         Numerics (params, loss, RNG stream) are identical either way.
         """
         accum = self.gradient_accumulation_steps()
+        tel = self.telemetry
+        with phase(
+            "train.window", tel.train_trace_ctx(), tel.tracer,
+            window=self.micro_steps // accum + 1,
+            global_steps=self.global_steps,
+        ) as window:
+            loss = self._train_window(batch_iter_or_batches, accum)
+        if not self.host_offload:  # offload loops forward(): timed there
+            tel.observe_window_time(window.seconds * 1e3, window.span)
+        return loss
+
+    def _train_window(self, batch_iter_or_batches, accum):
         if self._staging_enabled and not self.host_offload:
             stager = self._ensure_stager(batch_iter_or_batches)
             if stager is not None:
@@ -2077,15 +2114,16 @@ class DeepSpeedEngine:
             return jnp.mean(jnp.stack(losses))
 
         if self.telemetry.enabled:
-            self.telemetry.on_window_start()
+            self.telemetry.on_window_start(timed_by_caller=True)
             for batch in batches:
                 self.telemetry.count_batch(*self._batch_tokens(batch))
         if self.wall_clock_breakdown:
             # whole-window wall clock (start() fences outstanding device
             # work); the async fast path is untouched when breakdown is off
             self.timers(TRAIN_BATCH_TIMER).start()
-        stacked = self._stack_window(batches)
-        stacked = self._shard_window_batch(stacked)
+        with phase("train.stack_and_place"):
+            stacked = self._stack_window(batches)
+            stacked = self._shard_window_batch(stacked)
         self._rng, keys = _split_window_keys(self._rng, accum)
         return self._run_window(stacked, keys, accum)
 
@@ -2247,7 +2285,7 @@ class DeepSpeedEngine:
             self._close_stager()
             raise
         if self.telemetry.enabled:
-            self.telemetry.on_window_start()
+            self.telemetry.on_window_start(timed_by_caller=True)
             self.telemetry.count_batch(window.tokens, window.samples)
         if self.wall_clock_breakdown:
             self.timers(TRAIN_BATCH_TIMER).start()
@@ -2261,26 +2299,28 @@ class DeepSpeedEngine:
         unstaged train_batch paths."""
         if self.faults.enabled and self.faults.fire("grads.nan") is not None:
             stacked = _poison_first_float_leaf(stacked)
-        lr = jnp.float32(self._current_lr())
-        mom = jnp.float32(self._current_mom())
-        (
-            self.params,
-            self.optimizer_state,
-            self.loss_scale_state,
-            overflow,
-            grad_norm,
-            coeffs,
-            mean_loss,
-            aux,
-        ) = self._jit_train_window(
-            self.params,
-            self.optimizer_state,
-            self.loss_scale_state,
-            stacked,
-            keys,
-            lr,
-            mom,
-        )
+        with phase("train.place_scalars"):  # two converts, two device_puts
+            lr = jnp.float32(self._current_lr())
+            mom = jnp.float32(self._current_mom())
+        with phase("train.dispatch"):
+            (
+                self.params,
+                self.optimizer_state,
+                self.loss_scale_state,
+                overflow,
+                grad_norm,
+                coeffs,
+                mean_loss,
+                aux,
+            ) = self._jit_train_window(
+                self.params,
+                self.optimizer_state,
+                self.loss_scale_state,
+                stacked,
+                keys,
+                lr,
+                mom,
+            )
         self.micro_steps += accum
         if self.wall_clock_breakdown:
             jax.block_until_ready(mean_loss)
@@ -2292,7 +2332,8 @@ class DeepSpeedEngine:
             self._tb_windows = getattr(self, "_tb_windows", 0) + 1
         # aux outputs from a multi-output model, [accum, ...]-stacked
         self.last_aux = aux
-        self._finish_step(overflow, grad_norm, coeffs, mean_loss)
+        with phase("train.finish_step"):
+            self._finish_step(overflow, grad_norm, coeffs, mean_loss)
         # Returned as a device scalar: float(loss) would block the host on
         # this window and stop it dispatching the next one ahead of the
         # device. Callers that want a python float call float() on it.
@@ -2492,10 +2533,9 @@ class DeepSpeedEngine:
             # checkpoint-commit span (telemetry/tracing.py): atomic
             # commits are the training timeline's landmarks — a trace
             # shows what the run was doing around each one
-            with self.telemetry.tracer.span(
-                "train.checkpoint_commit",
-                ctx=self.telemetry.train_trace_ctx(),
-                attrs={"save_dir": str(save_dir), "tag": tag},
+            with phase(
+                "train.checkpoint_commit", self.telemetry.train_trace_ctx(),
+                self.telemetry.tracer, save_dir=str(save_dir), tag=tag,
             ):
                 result = _save(self, save_dir, tag=tag, client_state=client_state or {})
         # remember the save target: the preemption drain's default sink

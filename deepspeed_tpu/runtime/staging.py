@@ -37,10 +37,10 @@ identity stack, turning it into a device-placing prefetcher).
 
 import queue
 import threading
-import time
 
 import numpy as np
 
+from ..telemetry.tracing import NOOP_TRACER, phase
 from ..utils.logging import logger
 
 
@@ -90,17 +90,16 @@ class StagedWindow:
     """One staged accumulation window, ready (or nearly ready) to dispatch."""
 
     __slots__ = (
-        "arrays", "keys", "rng_after", "index", "stage_ms", "nbytes",
+        "arrays", "keys", "rng_after", "index", "nbytes",
         "placed", "tokens", "samples",
     )
 
-    def __init__(self, arrays, keys, rng_after, index, stage_ms, nbytes,
+    def __init__(self, arrays, keys, rng_after, index, nbytes,
                  placed, tokens, samples):
         self.arrays = arrays
         self.keys = keys
         self.rng_after = rng_after
         self.index = index
-        self.stage_ms = stage_ms
         self.nbytes = nbytes
         self.placed = placed
         self.tokens = tokens
@@ -184,77 +183,92 @@ class WindowStager:
 
     # -- worker ---------------------------------------------------------
     def _run(self):
+        tel = self._telemetry
+        tracer = getattr(tel, "tracer", NOOP_TRACER)
         index = 0
         while not self._stop.is_set():
             if not self._slots.acquire(timeout=0.1):
                 continue
             if self._stop.is_set():
                 return
-            t0 = time.monotonic()
-            batches = []
-            try:
-                if self._fault_fn is not None:
-                    self._fault_fn()
+            with phase(
+                "train.stage_window",
+                tel.train_trace_ctx() if tracer.enabled else None,
+                tracer, index=index,
+            ) as staged:
                 try:
-                    for _ in range(self._accum):
-                        # re-check between pulls: close() mid-window must
-                        # not keep draining the LIVE iterator (a blocked
-                        # next() itself cannot be interrupted, but the
-                        # damage is bounded to one pull)
-                        if self._stop.is_set():
-                            return
-                        batch = next(self._source)
-                        self.pulled_micro_batches += 1
-                        if not isinstance(batch, (tuple, list)):
-                            batch = (batch,)
-                        batches.append(tuple(batch))
-                except StopIteration:
-                    if batches:
-                        self._queue.put(_Failure(
-                            ragged_window_error(len(batches), self._accum)
-                        ))
-                    else:
-                        self._queue.put(_End)
-                    return
-                tokens = samples = 0
-                if self._meta_fn is not None:
-                    for b in batches:
-                        t, s = self._meta_fn(b)
-                        tokens += t
-                        samples += s
-                if self._stop.is_set():  # closed while pulling: drop
-                    return
-                keys = None
-                if self._rng is not None and self._split_fn is not None:
-                    self._rng, keys = self._split_fn(self._rng, self._accum)
-                stacked = self._stack_fn(batches)
-                # bookkeeping tree walk only when someone is listening
-                nbytes = (
-                    _tree_nbytes(stacked) if self._telemetry is not None
-                    else 0
-                )
-                if self._stage_to_device:
-                    stacked = self._place_fn(stacked)
-                    self._tel("count_h2d_bytes", nbytes)
-                stage_ms = (time.monotonic() - t0) * 1000.0
-                window = StagedWindow(
-                    arrays=stacked, keys=keys, rng_after=self._rng,
-                    index=index, stage_ms=stage_ms, nbytes=nbytes,
-                    placed=self._stage_to_device, tokens=tokens,
-                    samples=samples,
-                )
-            except Exception as exc:  # surfaced at get_window, not lost
-                self._queue.put(_Failure(exc))
+                    item = self._assemble(index, staged)
+                except Exception as exc:  # surfaced at get_window, not lost
+                    item = _Failure(exc)
+            if item is None:
+                return
+            if not isinstance(item, StagedWindow):
+                self._queue.put(item)  # end of stream, clean or not
                 return
             if self._stop.is_set():
                 # closed while staging: dropping the window here (instead
                 # of putting it into the drained queue) frees its device
                 # buffers now and keeps close()'s occupancy=0 final
                 return
-            self._queue.put(window)
-            self._tel("observe_staging_time", window.stage_ms)
+            self._queue.put(item)
+            self._tel("observe_staging_time", staged.seconds * 1e3)
             self._tel("set_staging_occupancy", self._queue.qsize())
             index += 1
+
+    def _assemble(self, index, staged):
+        """Pull, stack and place one window: a StagedWindow, ``_End`` or a
+        ``_Failure`` where the source ran dry, None when closed meanwhile."""
+        if self._fault_fn is not None:
+            self._fault_fn()
+        batches = []
+        with phase("stage.pull"):
+            try:
+                for _ in range(self._accum):
+                    # re-check between pulls: close() mid-window must
+                    # not keep draining the LIVE iterator (a blocked
+                    # next() itself cannot be interrupted, but the
+                    # damage is bounded to one pull)
+                    if self._stop.is_set():
+                        return None
+                    batch = next(self._source)
+                    self.pulled_micro_batches += 1
+                    if not isinstance(batch, (tuple, list)):
+                        batch = (batch,)
+                    batches.append(tuple(batch))
+            except StopIteration:
+                if batches:
+                    return _Failure(
+                        ragged_window_error(len(batches), self._accum)
+                    )
+                return _End
+        tokens = samples = 0
+        if self._meta_fn is not None:
+            for b in batches:
+                t, s = self._meta_fn(b)
+                tokens += t
+                samples += s
+        if self._stop.is_set():  # closed while pulling: drop
+            return None
+        keys = None
+        if self._rng is not None and self._split_fn is not None:
+            self._rng, keys = self._split_fn(self._rng, self._accum)
+        with phase("stage.stack"):
+            stacked = self._stack_fn(batches)
+        # bookkeeping tree walk only when someone is listening
+        nbytes = 0
+        if self._telemetry is not None:
+            nbytes = _tree_nbytes(stacked)
+            staged.set_attr("nbytes", nbytes)
+        if self._stage_to_device:
+            with phase("stage.h2d"):
+                stacked = self._place_fn(stacked)
+            self._tel("count_h2d_bytes", nbytes)
+        return StagedWindow(
+            arrays=stacked, keys=keys, rng_after=self._rng,
+            index=index, nbytes=nbytes,
+            placed=self._stage_to_device, tokens=tokens,
+            samples=samples,
+        )
 
     # -- consumer -------------------------------------------------------
     def get_window(self, timeout=60.0):
@@ -265,20 +279,20 @@ class WindowStager:
         ragged-final-window RuntimeError), and detects a dead worker
         instead of hanging forever.
         """
-        t0 = time.monotonic()
-        while True:
-            try:
-                item = self._queue.get(timeout=timeout)
-                break
-            except queue.Empty:
-                if not self._thread.is_alive() and self._queue.qsize() == 0:
-                    raise RuntimeError(
-                        "window-staging worker died without signalling "
-                        "end-of-stream"
-                    ) from None
-                # a slow source is not an error — keep waiting while the
-                # worker is demonstrably alive
-        wait_ms = (time.monotonic() - t0) * 1000.0
+        with phase("train.stage_wait") as waited:
+            while True:
+                try:
+                    item = self._queue.get(timeout=timeout)
+                    break
+                except queue.Empty:
+                    if not self._thread.is_alive() \
+                            and self._queue.qsize() == 0:
+                        raise RuntimeError(
+                            "window-staging worker died without signalling "
+                            "end-of-stream"
+                        ) from None
+                    # a slow source is not an error — keep waiting while
+                    # the worker is demonstrably alive
         if item is _End:
             self.close()
             raise StopIteration
@@ -287,7 +301,7 @@ class WindowStager:
             raise item.exc
         self._slots.release()
         self.windows_served += 1
-        self._tel("observe_staging_wait", wait_ms)
+        self._tel("observe_staging_wait", waited.seconds * 1e3)
         self._tel("set_staging_occupancy", self._queue.qsize())
         if not item.placed:
             item.arrays = self._place_fn(item.arrays)

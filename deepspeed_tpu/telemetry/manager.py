@@ -331,7 +331,6 @@ class Telemetry:
         # lazy per-run trace the training spans parent under (one
         # trace_id for the run's window/staging/checkpoint spans)
         self._train_ctx = None
-        self._window_start_mono = None
         self._windows_ended = 0
         self._windows_since_export = 0
         self._pending_values = None
@@ -380,14 +379,28 @@ class Telemetry:
         atexit.register(_close_at_exit)
 
     # -- engine hooks ---------------------------------------------------
-    def on_window_start(self):
+    def on_window_start(self, timed_by_caller=False):
+        """``timed_by_caller``: train_batch() times its window with the
+        ``train.window`` phase and hands the duration to
+        :meth:`observe_window_time`; the forward/backward/step path has
+        no block to time, so the clock starts here."""
         if not self.enabled:
             return
         if self.profiler is not None:
             self.profiler.on_window_start()
-        self._window_start = time.time()
-        if self.tracer.enabled:
-            self._window_start_mono = time.monotonic()
+        self._window_start = None if timed_by_caller else time.time()
+
+    def observe_window_time(self, ms, span=None):
+        if not self.enabled:
+            return
+        self.registry.histogram(
+            "train/window_time_ms", buckets=DEFAULT_TIME_BUCKETS_MS
+        ).observe(
+            ms,
+            # only SAMPLED traces reach the export file: an exemplar
+            # pointing at an unsampled trace is a dead link
+            trace_id=span["trace_id"] if span and span["sampled"] else None,
+        )
 
     def count_batch(self, tokens, samples):
         if not self.enabled:
@@ -417,29 +430,7 @@ class Telemetry:
         # the end-to-end gap: the gap also counts dataloader wait and eval
         # phases between windows, which would poison the histogram
         if self._window_start is not None:
-            hist = self.registry.histogram(
-                "train/window_time_ms", buckets=DEFAULT_TIME_BUCKETS_MS
-            )
-            span = None
-            if self.tracer.enabled and self._window_start_mono is not None:
-                span = self._record_train_span(
-                    "train.window", self._window_start_mono,
-                    time.monotonic(),
-                    attrs={
-                        "window": self._windows_ended + 1,
-                        "global_steps": int(global_steps),
-                        "micro_steps": int(micro_steps),
-                    },
-                )
-                self._window_start_mono = None
-            hist.observe(
-                (now - self._window_start) * 1000.0,
-                # only SAMPLED traces reach the export file: an exemplar
-                # pointing at an unsampled trace is a dead link
-                trace_id=(
-                    span["trace_id"] if span and span["sampled"] else None
-                ),
-            )
+            self.observe_window_time((now - self._window_start) * 1000.0)
             self._window_start = None
         self._windows_ended += 1
         self._sample_hbm_peak()
@@ -530,14 +521,6 @@ class Telemetry:
     def observe_staging_time(self, ms):
         if not self.enabled:
             return
-        if self.tracer.enabled:
-            # the staging worker just finished assembling one window:
-            # reconstruct its span from the measured duration (called
-            # from the worker thread; the tracer is thread-safe)
-            now = time.monotonic()
-            self._record_train_span(
-                "train.stage_window", now - ms / 1e3, now
-            )
         self.registry.histogram(
             "dataloader/staging_time_ms", buckets=DEFAULT_TIME_BUCKETS_MS
         ).observe(ms)
@@ -549,11 +532,6 @@ class Telemetry:
         if self._train_ctx is None:
             self._train_ctx = self.tracer.child_of(None)
         return self._train_ctx
-
-    def _record_train_span(self, name, t0, t1, attrs=None):
-        return self.tracer.record(
-            name, t0, t1, ctx=self.train_trace_ctx(), attrs=attrs
-        )
 
     def count_h2d_bytes(self, nbytes):
         if not self.enabled:

@@ -356,6 +356,16 @@ def count_suppressed(site, exc=None):
 # drops counters whose telemetry was garbage-collected).
 # ---------------------------------------------------------------------------
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# The duration events (jax 0.9.0) whose seconds go into the phase table
+# (tracing.phase_totals) as ``<kind>@<innermost open phase>``. A backend
+# compile that the persistent cache answers fires both compile.backend
+# and, inside it, compile.cache_load.
+COMPILE_PHASE_KINDS = {
+    "/jax/core/compile/jaxpr_trace_duration": "compile.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile.lower",
+    BACKEND_COMPILE_EVENT: "compile.backend",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "compile.cache_load",
+}
 
 # Persistent-compile-cache accounting (runtime/compile_cache.py): jax
 # records a plain event on every cache read hit, and on every compiled
@@ -371,8 +381,10 @@ _cache_miss_counters = weakref.WeakSet()
 _cache_listener_installed = False
 
 
-def install_recompile_hook(counter):
-    """Count XLA backend compiles into ``counter``.
+def install_recompile_hook(counter=None):
+    """Count XLA backend compiles into ``counter`` (if given), and charge
+    every compile event's seconds to the phase it fired in. The entry
+    points install it without a counter, telemetry on or off.
 
     Every ``jax.jit`` cache miss ends in a backend compile, so after the
     warmup windows this counter moving is the recompile-storm signal
@@ -380,14 +392,21 @@ def install_recompile_hook(counter):
     initial compiles land in it too — read it as a rate, not a level.
     """
     global _listener_installed
-    _recompile_counters.add(counter)
+    if counter is not None:
+        _recompile_counters.add(counter)
     if _listener_installed:
         return True
     try:
         from jax import monitoring as jax_monitoring
 
+        from .tracing import add_compile_time
+
         def _on_event_duration(event, duration, **kwargs):
-            del duration, kwargs
+            del kwargs
+            kind = COMPILE_PHASE_KINDS.get(event)
+            if kind is None:
+                return
+            add_compile_time(kind, duration)
             if event == BACKEND_COMPILE_EVENT:
                 for c in list(_recompile_counters):
                     c.inc()

@@ -22,11 +22,21 @@ Two consumers with different retention:
   watchdog stall reports, supervisor escalations, decode-driver crashes,
   and replica evictions, i.e. exactly when someone starts debugging.
 
-Tracing disabled is a ZERO-overhead passthrough: every integration point
-holds :data:`NOOP_TRACER`, whose ``span()`` returns one shared no-op
-context manager and whose ``record()`` is a bare ``return None`` — the
-hot paths pay a single attribute check (``tracer.enabled``), pinned by
-tests/unit/test_tracing.py.
+Block-shaped host phases (a training window, its dispatch, a prefill, a
+decode step) are marked with :class:`phase`, the ONE way to mark them:
+it always enters a ``jax.profiler.TraceAnnotation``, so any live
+profiler session shows the phase on the ``/host:CPU`` plane on the clock
+of the device planes, always adds to a process-wide table of totals by
+name (:func:`phase_totals`), and records into the tracer when one is
+enabled. ``record()`` stays for spans that cross threads or are only
+known afterwards (``sched.request``, ``sched.queue``, ``fleet.request``,
+``router.*``).
+
+Tracing disabled is a near-zero-overhead passthrough: every integration
+point holds :data:`NOOP_TRACER`, whose ``record()`` is a bare ``return
+None`` — the hot paths pay a single attribute check
+(``tracer.enabled``), and a ``phase`` costs one inactive TraceMe, two
+clock reads and a dict update, pinned by tests/unit/test_tracing.py.
 
 Timestamps: callers pass ``time.monotonic()`` instants (what the
 schedulers already collect); each tracer converts to wall-clock at
@@ -42,6 +52,8 @@ import random
 import threading
 import time
 import uuid
+
+from jax.profiler import TraceAnnotation
 
 from ..utils.logging import logger
 from .registry import count_suppressed, suppressed_errors_snapshot
@@ -92,60 +104,6 @@ class TraceContext:
         )
 
 
-class _SpanHandle:
-    """Context manager returned by :meth:`SpanTracer.span`: times the
-    block, records on exit, exposes ``ctx`` for children and
-    ``set_attr`` for results discovered mid-block."""
-
-    __slots__ = ("_tracer", "_name", "_parent", "_attrs", "_t0", "ctx")
-
-    def __init__(self, tracer, name, parent, attrs):
-        self._tracer = tracer
-        self._name = name
-        self._parent = parent
-        self._attrs = dict(attrs) if attrs else {}
-        self._t0 = None
-        self.ctx = tracer.child_of(parent)
-
-    def set_attr(self, key, value):
-        self._attrs[key] = value
-
-    def __enter__(self):
-        self._t0 = time.monotonic()
-        return self
-
-    def __exit__(self, exc_type, exc, tb):
-        if exc_type is not None:
-            self._attrs.setdefault("error", repr(exc))
-        self._tracer.record(
-            self._name, self._t0, time.monotonic(),
-            ctx=self._parent
-            or TraceContext(self.ctx.trace_id, None, self.ctx.sampled),
-            span_id=self.ctx.span_id, attrs=self._attrs,
-        )
-        return False
-
-
-class _NoopSpan:
-    """The one shared disabled-tracing context manager (identity pinned
-    by the zero-overhead test): stateless, reentrant, allocation-free."""
-
-    __slots__ = ()
-    ctx = None
-
-    def set_attr(self, key, value):
-        pass
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, exc_type, exc, tb):
-        return False
-
-
-_NOOP_SPAN = _NoopSpan()
-
-
 class NoopTracer:
     """Disabled tracing: every method is a constant-time no-op and the
     integration points see ``enabled == False`` before doing any work.
@@ -155,9 +113,6 @@ class NoopTracer:
 
     def record(self, name, t0, t1, ctx=None, attrs=None, span_id=None):
         return None
-
-    def span(self, name, ctx=None, attrs=None):
-        return _NOOP_SPAN
 
     def child_of(self, ctx):
         return None
@@ -185,6 +140,111 @@ class NoopTracer:
 
 
 NOOP_TRACER = NoopTracer()
+
+
+# Phase totals: name -> [count, total seconds, longest]. Process-wide,
+# because set-up is over before any trace starts and its reader (the
+# benchmark's ``phase_total``) asks after the fact; the compile listener
+# (registry.py) adds its events here under ``compile.<kind>@<phase>``.
+_totals = {}
+_totals_lock = threading.Lock()
+_open = threading.local()  # .stack: phases open on this thread
+
+
+def add_phase_time(name, seconds):
+    with _totals_lock:
+        row = _totals.setdefault(name, [0, 0.0, 0.0])
+        row[0] += 1
+        row[1] += seconds
+        row[2] = max(row[2], seconds)
+
+
+def phase_totals():
+    """{name: (count, total seconds, longest)} since the last reset."""
+    with _totals_lock:
+        return {name: tuple(row) for name, row in _totals.items()}
+
+
+def reset_phase_totals():
+    with _totals_lock:
+        _totals.clear()
+
+
+def add_compile_time(kind, seconds):
+    """Charge one ``jax.monitoring`` compile event to the innermost phase
+    open on the thread where it fired: ``<kind>@<phase>``, ``@-`` outside
+    any. A jitted function traced inside another fires its own event
+    inside the outer's and before it, so an outer event gives up what its
+    inner ones already counted: every second is counted once."""
+    stack = getattr(_open, "stack", None)
+    if kind == "compile.trace":
+        now = time.monotonic()
+        inner = _open.__dict__.setdefault("traced", [])
+        nested = 0.0
+        while inner and inner[-1][0] >= now - seconds:
+            nested += inner.pop()[1]
+        inner.append((now, seconds))
+        seconds = max(seconds - nested, 0.0)
+    add_phase_time(f"{kind}@{stack[-1].name if stack else '-'}", seconds)
+
+
+class phase:
+    """Mark one block-shaped host phase: ``with phase("train.dispatch"):``.
+
+    ``ctx``/``tracer`` name the parent context and the tracer to record
+    into; a phase opened inside another on the same thread inherits both,
+    so only the outermost call site of a thread passes them. ``seconds``
+    holds the duration after exit (what the histograms are fed from) and
+    ``span`` the recorded span, if the tracer was enabled."""
+
+    __slots__ = ("name", "attrs", "seconds", "span", "ctx", "_tracer",
+                 "_parent", "_annotation", "_t0")
+
+    def __init__(self, name, ctx=None, tracer=None, **attrs):
+        self.name = name
+        self.attrs = attrs
+        self.seconds = self.span = self.ctx = None
+        self._tracer = tracer
+        self._parent = ctx
+        self._annotation = TraceAnnotation(name, **attrs)
+
+    def set_attr(self, key, value):
+        """An attr only known mid-block (a prefill's prefix hit)."""
+        self.attrs[key] = value
+        self._annotation.set_metadata(**{key: value})
+
+    def __enter__(self):
+        stack = _open.__dict__.setdefault("stack", [])
+        outer = stack[-1] if stack else None
+        if self._tracer is None:
+            self._tracer = outer._tracer if outer else NOOP_TRACER
+        if self._tracer.enabled:
+            parent = TraceContext.from_wire(self._parent)
+            if parent is None and outer is not None:
+                parent = outer.ctx
+            self._parent = parent
+            self.ctx = self._tracer.child_of(parent)
+        stack.append(self)
+        self._annotation.__enter__()
+        self._t0 = time.monotonic()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        t1 = time.monotonic()
+        self._annotation.__exit__(exc_type, exc, tb)
+        _open.stack.pop()
+        self.seconds = t1 - self._t0
+        add_phase_time(self.name, self.seconds)
+        if self.ctx is not None:
+            if exc_type is not None:
+                self.attrs.setdefault("error", repr(exc))
+            self.span = self._tracer.record(
+                self.name, self._t0, t1,
+                ctx=self._parent
+                or TraceContext(self.ctx.trace_id, None, self.ctx.sampled),
+                span_id=self.ctx.span_id, attrs=self.attrs,
+            )
+        return False
 
 
 class SpanTracer:
@@ -278,11 +338,6 @@ class SpanTracer:
             if want_flush:
                 self.flush()
         return span
-
-    def span(self, name, ctx=None, attrs=None):
-        """Context-manager form for block-shaped phases (checkpoint
-        commits, rollbacks): times the block and records at exit."""
-        return _SpanHandle(self, name, TraceContext.from_wire(ctx), attrs)
 
     def event(self, name, attrs=None, ctx=None):
         """Instant event (admission verdicts, rejections, crashes):

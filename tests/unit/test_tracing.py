@@ -43,6 +43,9 @@ from deepspeed_tpu.telemetry.tracing import (  # noqa: E402
     TraceContext,
     build_tracer,
     load_chrome_trace,
+    phase,
+    phase_totals,
+    reset_phase_totals,
 )
 
 
@@ -70,11 +73,42 @@ def test_record_parents_under_context():
 
 def test_span_context_manager_records_block():
     t = SpanTracer(ring_events=16)
-    with t.span("blk", attrs={"a": 1}) as h:
+    with phase("blk", tracer=t, a=1) as h:
         h.set_attr("b", 2)
     (span,) = t.flight_snapshot()
+    assert span is h.span
     assert span["name"] == "blk"
     assert span["attrs"] == {"a": 1, "b": 2}
+    assert span["dur_ms"] == pytest.approx(h.seconds * 1e3)
+
+
+def test_phase_nests_by_thread_and_inherits_the_tracer():
+    t = SpanTracer(ring_events=16)
+    root = t.child_of(None)
+    with phase("outer", root, t) as outer:
+        with phase("inner") as inner:  # no ctx, no tracer: the outer's
+            pass
+        seen = []
+        other = threading.Thread(
+            target=lambda: seen.append(_run_phase("elsewhere"))
+        )
+        other.start()
+        other.join(10)
+    assert outer.span["parent_id"] == root.span_id
+    assert inner.span["parent_id"] == outer.ctx.span_id
+    assert inner.span["trace_id"] == root.trace_id
+    # another thread has its own stack: nothing to inherit, not recorded
+    assert seen == [None]
+    with pytest.raises(ValueError):
+        with phase("fails", tracer=t) as failed:
+            raise ValueError("boom")
+    assert "boom" in failed.span["attrs"]["error"]
+
+
+def _run_phase(name):
+    with phase(name) as p:
+        pass
+    return p.span
 
 
 def test_wire_roundtrip():
@@ -152,11 +186,11 @@ def test_ingest_adopts_foreign_pids_only():
 # ---------------------------------------------------------------------------
 def test_noop_tracer_is_zero_overhead_passthrough():
     assert NOOP_TRACER.enabled is False
-    # one shared allocation-free context manager, pinned by identity
-    cm = NOOP_TRACER.span("anything")
-    assert cm is NOOP_TRACER.span("something else")
-    with cm as h:
+    # a phase under the no-op tracer records nothing and allocates no
+    # trace context; it still times the block
+    with phase("anything", tracer=NOOP_TRACER) as h:
         h.set_attr("ignored", 1)
+    assert h.span is None and h.ctx is None and h.seconds >= 0.0
     assert NOOP_TRACER.record("x", 0.0, 1.0) is None
     assert NOOP_TRACER.child_of(None) is None
     assert NOOP_TRACER.dump_flight("nope") is None
@@ -595,3 +629,284 @@ def test_fleet_tracing_disabled_writes_no_trace_files(tmp_path):
         if "trace" in f or f.startswith("flight-")
     ]
     assert leftovers == []
+
+
+# ---------------------------------------------------------------------------
+# phases: one clock with the profiler, totals, off cost, collective scopes
+# ---------------------------------------------------------------------------
+def _toy_loss(params, batch, rng):
+    x, y = batch
+    return jnp.mean((x @ params["w"] + params["b"] - y[:, None]) ** 2)
+
+
+def _toy_train_engine(staged, telemetry=None, accum=2):
+    cfg = {
+        "train_micro_batch_size_per_gpu": 2,
+        "gradient_accumulation_steps": accum,
+        "optimizer": {"type": "Adam", "params": {"lr": 1e-2}},
+        "steps_per_print": 1000,
+        "data_pipeline": {"enabled": staged},
+    }
+    if telemetry:
+        cfg["telemetry"] = telemetry
+    engine, _, _, _ = deepspeed_tpu.initialize(
+        model=_toy_loss,
+        model_parameters={"w": np.ones((8, 1), np.float32),
+                          "b": np.zeros((1,), np.float32)},
+        config_params=cfg,
+    )
+    return engine
+
+
+def _toy_batches(engine, n):
+    rows = engine.train_micro_batch_size_per_gpu() * engine.dp_world_size
+    r = np.random.default_rng(0)
+    return [(r.standard_normal((rows, 8)).astype(np.float32),
+             r.standard_normal((rows,)).astype(np.float32))
+            for _ in range(n)]
+
+
+def _three_windows(engine, staged):
+    batches = _toy_batches(engine, 6)
+    feed = iter(batches)
+    for i in range(3):
+        # the unstaged path is the one a caller takes who hands a list
+        float(engine.train_batch(
+            feed if staged else batches[2 * i:2 * i + 2]))
+    engine.close_data_pipeline()
+
+
+def _toy_decode():
+    sched = _scheduler(_StubEngine())
+    sched.submit([1, 2, 3], max_new_tokens=3)
+    sched.run_until_idle()
+
+
+@pytest.fixture
+def profiled(tmp_path):
+    """Run a callable under a jax.profiler session (host events only) and
+    get back {line: [event]} of the host plane, by thread."""
+
+    def run(fn):
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 2
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            fn()
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = (tmp_path / "plugins" / "profile").glob("*/*.xplane.pb")
+        data = jax.profiler.ProfileData.from_file(str(path))
+        (host,) = [p for p in data.planes if p.name == "/host:CPU"]
+        out = []
+        for line in host.lines:
+            events = [e for e in line.events
+                      if e.name.startswith(("train.", "stage.", "sched."))]
+            if events:
+                out.append(events)
+        return out
+
+    return run
+
+
+def _inside(outer, events, name):
+    return [e for e in events if e.name == name
+            and e.start_ns >= outer.start_ns
+            and e.start_ns + e.duration_ns
+            <= outer.start_ns + outer.duration_ns]
+
+
+@pytest.mark.parametrize("staged", [True, False])
+def test_training_phases_reach_the_profiler_with_telemetry_off(
+        profiled, staged):
+    engine = _toy_train_engine(staged)
+    assert engine.telemetry.enabled is False
+    float(engine.train_batch(iter(_toy_batches(engine, 2))))  # compile
+    engine.close_data_pipeline()
+    lines = profiled(lambda: _three_windows(engine, staged))
+    (caller,) = [ev for ev in lines
+                 if any(e.name == "train.window" for e in ev)]
+    windows = [e for e in caller if e.name == "train.window"]
+    assert len(windows) == 3
+    for w in windows:
+        assert len(_inside(w, caller, "train.dispatch")) == 1
+        (finish,) = _inside(w, caller, "train.finish_step")
+        assert dict(w.stats).keys() >= {"window", "global_steps"}
+        if staged:
+            assert len(_inside(w, caller, "train.stage_wait")) == 1
+        else:
+            assert len(_inside(w, caller, "train.stack_and_place")) == 1
+    # windows after the first settle the one before: a child of finish_step
+    assert any(_inside(w, caller, "train.settle") for w in windows[1:])
+    workers = [ev for ev in lines if ev is not caller]
+    staged_names = {e.name for ev in workers for e in ev}
+    if staged:
+        assert {"train.stage_window", "stage.pull", "stage.stack",
+                "stage.h2d"} <= staged_names
+    else:
+        assert "train.stage_window" not in staged_names
+
+
+def test_scheduler_phases_reach_the_profiler_with_tracing_off(profiled):
+    (driver,) = profiled(_toy_decode)
+    (prefill,) = [e for e in driver if e.name == "sched.prefill"]
+    stats = dict(prefill.stats)
+    assert stats["prompt_tokens"] == 3 and "queue_wait_ms" in stats
+    steps = [dict(e.stats) for e in driver if e.name == "sched.decode_step"]
+    assert len(steps) == 2  # the first token comes from the prefill
+    assert [s["active_slots"] for s in steps] == [1, 1]
+    assert [s["admitted"] for s in steps] == [1, 0]
+
+
+@pytest.mark.parametrize("staged", [True, False])
+def test_training_phases_reach_the_tracer_with_their_parents(
+        tmp_path, staged):
+    engine = _toy_train_engine(staged, telemetry={
+        "enabled": True, "output_path": str(tmp_path), "job_name": "ph",
+        "exporters": [], "watchdog": {"enabled": False},
+        "tracing": {"enabled": True, "ring_events": 512, "export": "none"},
+    })
+    try:
+        _three_windows(engine, staged)
+        ring = engine.telemetry.tracer.flight_snapshot()
+    finally:
+        engine.telemetry.close()
+    by_id = {s["span_id"]: s for s in ring}
+    run = engine.telemetry.train_trace_ctx()
+
+    def parents(name):
+        return [by_id.get(s["parent_id"], {}).get("name", s["parent_id"])
+                for s in ring if s["name"] == name]
+
+    windows = [s for s in ring if s["name"] == "train.window"]
+    assert [s["attrs"]["window"] for s in windows] == [1, 2, 3]
+    assert {s["parent_id"] for s in windows} == {run.span_id}
+    assert parents("train.dispatch") == ["train.window"] * 3
+    assert parents("train.finish_step") == ["train.window"] * 3
+    assert set(parents("train.settle")) == {"train.finish_step"}
+    if staged:
+        assert parents("train.stage_wait") == ["train.window"] * 3
+        assert set(parents("train.stage_window")) == {run.span_id}
+        assert set(parents("stage.h2d")) == {"train.stage_window"}
+        tids = {s["tid"] for s in ring if s["name"] == "train.stage_window"}
+        assert tids and windows[0]["tid"] not in tids
+    else:
+        assert parents("train.stack_and_place") == ["train.window"] * 3
+    # the histogram is fed from the phase: one sample a window
+    hist = engine.telemetry.registry.histogram("train/window_time_ms")
+    assert hist.count == 3
+    assert hist.sum == pytest.approx(
+        sum(s["dur_ms"] for s in windows), rel=1e-6)
+
+
+def test_decode_step_span_carries_admitted_under_the_driver_trace():
+    tracer = SpanTracer(ring_events=64)
+    sched = _scheduler(_StubEngine(), tracer=tracer)
+    req = sched.submit([1, 2, 3], max_new_tokens=3)
+    sched.run_until_idle()
+    ring = tracer.flight_snapshot()
+    steps = [s for s in ring if s["name"] == "sched.decode_step"]
+    assert [s["attrs"]["admitted"] for s in steps] == [1, 0]
+    assert {s["parent_id"] for s in steps} == {sched._driver_ctx.span_id}
+    (prefill,) = [s for s in ring if s["name"] == "sched.prefill"]
+    assert prefill["parent_id"] == req.trace_ctx.span_id
+    assert prefill["attrs"]["queue_wait_ms"] >= 0.0
+    # the retroactive spans are as they were: same names, same parents
+    by_name = {s["name"]: s for s in req.trace_spans}
+    assert by_name["sched.queue"]["parent_id"] == req.trace_ctx.span_id
+    assert by_name["sched.queue"]["ts"] + by_name["sched.queue"][
+        "dur_ms"] / 1e3 <= prefill["ts"] + 1e-3
+
+
+def test_phase_totals_hold_init_and_compile_time_by_phase():
+    reset_phase_totals()
+    assert phase_totals() == {}
+    with phase("unit.block"):
+        pass
+    with phase("unit.block"):
+        time.sleep(0.002)
+    count, total, longest = phase_totals()["unit.block"]
+    assert count == 2 and total >= longest >= 0.002
+    reset_phase_totals()
+
+    engine = _toy_train_engine(staged=False, accum=3)  # a shape of its own
+    float(engine.train_batch(_toy_batches(engine, 3)))
+    totals = phase_totals()
+    for name in ("init.place_params", "init.optimizer_state",
+                 "init.build_steps", "train.window", "train.dispatch"):
+        assert totals[name][0] >= 1, sorted(totals)
+    # the first window compiled the fused program inside train.dispatch
+    assert totals["compile.backend@train.dispatch"][0] >= 1
+    assert totals["compile.trace@train.dispatch"][1] > 0.0
+    assert all("@" in k for k in totals if k.startswith("compile."))
+    reset_phase_totals()
+    assert phase_totals() == {}
+
+
+def test_phase_off_cost_is_microseconds():
+    n = 10_000
+    best = float("inf")
+    for _ in range(3):  # the least of three: a shared CPU hiccups
+        t0 = time.perf_counter()
+        for _ in range(n):
+            with phase("unit.cost", tracer=NOOP_TRACER, step=1):
+                pass
+        best = min(best, (time.perf_counter() - t0) / n)
+    assert best < 20e-6, f"{best * 1e6:.2f} us a phase"
+
+
+def test_zero2_collectives_carry_their_producers_scope():
+    """What the benchmark's scoped collective reader stands on: under
+    ZeRO-2 on four devices every collective of the fused window is the
+    gradients' (under window_fwd_bwd) or the update's (update_grad_norm,
+    update_apply under window_optimizer_update)."""
+    import re
+
+    from deepspeed_tpu.parallel.mesh import build_mesh
+    from deepspeed_tpu.runtime.engine import _split_window_keys
+
+    cfg = GPT2Config(vocab_size=VOCAB, n_positions=32, n_embd=32,
+                     n_layer=2, n_head=2, dropout=0.0, use_flash=False)
+    model = GPT2LMHeadModel(cfg)
+    ids = np.zeros((8, 16), np.int32)
+    params = model.init(
+        {"params": jax.random.PRNGKey(0), "dropout": jax.random.PRNGKey(1)},
+        ids, ids,
+    )["params"]
+    engine, _, _, _ = deepspeed_tpu.initialize(
+        model=model, model_parameters=params,
+        config_params={
+            "train_micro_batch_size_per_gpu": 2,
+            "gradient_accumulation_steps": 2,
+            "optimizer": {"type": "Adam", "params": {"lr": 1e-3}},
+            "bf16": {"enabled": True},
+            "zero_optimization": {"stage": 2},
+            "steps_per_print": 1000,
+        },
+        mesh=build_mesh(devices=jax.devices()[:4]),
+    )
+    stacked = engine._shard_window_batch(
+        engine._stack_window([(ids, ids)] * 2))
+    _, keys = _split_window_keys(engine._rng, 2)
+    text = engine._jit_train_window.lower(
+        engine.params, engine.optimizer_state, engine.loss_scale_state,
+        stacked, keys, jnp.float32(1e-3), jnp.float32(0.9),
+    ).compile().as_text()
+    found = {"window_fwd_bwd": 0, "update_grad_norm": 0, "update_apply": 0}
+    for line in text.splitlines():
+        if not re.search(r" (all-reduce|all-gather|reduce-scatter|"
+                         r"collective-permute|all-to-all)(-start)?\(", line):
+            continue
+        op_name = re.search(r'op_name="([^"]*)"', line)
+        assert op_name, f"a collective without metadata: {line[:200]}"
+        path = op_name.group(1)
+        if "/window_fwd_bwd/" in path:
+            found["window_fwd_bwd"] += 1
+        else:
+            scope = re.search(
+                r"/window_optimizer_update/(update_grad_norm|update_apply)/",
+                path)
+            assert scope, f"a collective outside the two scopes: {path}"
+            found[scope.group(1)] += 1
+    assert all(found.values()), found
