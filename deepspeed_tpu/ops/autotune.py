@@ -6,9 +6,14 @@ The TPU counterpart of the reference's GEMM autotuner
 ``cublasGemmAlgo_t`` over fwd/bw1/bw2 and picks the fastest; invoked via the
 layer config's ``test_gemm`` flag). On TPU, XLA autotunes its own GEMMs, so
 the only hand-scheduled choice left is the flash kernel's (block_q,
-block_k) tiling — which is worth real throughput: measured on v5e at
-seq 1024, 128x128 -> 37 model TFLOPS vs 512x512 -> 60 on the GPT-2-large
-training step (the static defaults in ops/attention.py record that sweep).
+block_k) outer tiling — which is worth real time: on one "TPU v5 lite"
+chip at [8, 20, 1024, 64] bf16 causal the three kernels alone take
+0.76 + 0.89 + 1.33 ms (forward + dq + dkv, device time) at 512x512 blocks
+and 0.43 + 0.48 + 0.60 ms at 1024x1024, where the sequence is one block
+each way and the kernels' loops unroll (PR 25, docs/TESTING.md; the
+comment above DEFAULT_BLOCK_Q in ops/attention.py has the kernels before
+PR 25 beside them). The sub-tiles inside a block are the kernels' own
+choice (ops/attention.py:pick_subtiles).
 
 Use offline (results are cached per (shape, causal, device-kind)):
 

@@ -91,7 +91,8 @@ def _compiled_text(fn, *shapes):
 @functools.lru_cache(maxsize=None)
 def _flash_train_text(model):
     """One fwd+bwd compile per shape: [8, heads, 1024, 64] bf16 causal at
-    the default 512x512 blocks — the window chip_smoke.py trains with."""
+    the default blocks (one 1024-block each way) — the window
+    chip_smoke.py trains with."""
     from deepspeed_tpu.ops.attention import flash_attention
 
     qkv = _shape((8, HEADS[model], 1024, 64), jnp.bfloat16)
@@ -114,6 +115,52 @@ def _flash_train_text(model):
 )
 def test_flash_kernel_compiles_for_v5e(kernel, model):
     assert kernel in _flash_train_text(model)
+
+
+@functools.lru_cache(maxsize=None)
+def _flash_cell_text(cell):
+    """fwd+bwd through the ``attention()`` dispatcher, the route the
+    benchmark's cells take (blocks and sub-tiles as the code picks them):
+    ``gpt2``: [8, 20, 1024, 64] bf16 causal, no key mask (both GPT-2
+    cells, a chip's share of zero2-dp4 included); ``bert512``: BERT-large
+    pre-training phase 2, [8, 16, 512, 64] bf16, bidirectional with a
+    padding mask (a VMEM or Mosaic refusal of the masked path shows here)."""
+    from deepspeed_tpu.ops.attention import attention
+
+    b, h, s, causal, masked = {
+        "gpt2": (8, 20, 1024, True, False),
+        "bert512": (8, 16, 512, False, True),
+    }[cell]
+    qkv = _shape((b, h, s, 64), jnp.bfloat16)
+    pad = _shape((b, 1, 1, s), jnp.float32)
+
+    def loss(q, k, v, mask):
+        out = attention(
+            q, k, v, mask=mask if masked else None, causal=causal
+        )
+        return out.astype(jnp.float32).sum()
+
+    real = device.on_tpu, jax.device_count
+    device.on_tpu, jax.device_count = (lambda: True), (lambda: 1)
+    try:
+        return _compiled_text(
+            jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv, pad
+        )
+    finally:
+        device.on_tpu, jax.device_count = real
+
+
+@pytest.mark.parametrize("cell", ["gpt2", "bert512"])
+@pytest.mark.parametrize(
+    "kernel", ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]
+)
+def test_flash_kernel_compiles_at_the_cells_shapes(kernel, cell):
+    text = _flash_cell_text(cell)
+    assert kernel in text
+    # exactly three Pallas kernels: flash_ms.train sums these three names,
+    # and a fourth kernel's time would stay in the window unseen
+    calls = [l for l in text.splitlines() if "tpu_custom_call" in l]
+    assert len(calls) == 3, calls
 
 
 # ---------------------------------------------------------------------------

@@ -17,7 +17,9 @@ from deepspeed_tpu.ops.transformer import (
     DeepSpeedTransformerLayer,
 )
 
-pytestmark = pytest.mark.slow  # compile-heavy; excluded from `make test-fast`
+# the layer tests are compile-heavy and excluded from `make test-fast`; the
+# flash-kernel tests (interpret mode, seconds each) run in it
+slow = pytest.mark.slow
 
 
 def naive_layer_forward(params, x, cfg, causal=False, mask=None):
@@ -60,6 +62,7 @@ def naive_layer_forward(params, x, cfg, causal=False, mask=None):
     return x2
 
 
+@slow
 @pytest.mark.parametrize("pre_ln", [True, False])
 @pytest.mark.parametrize("batch,seq", [(2, 64), (1, 128)])
 def test_layer_parity_forward(pre_ln, batch, seq):
@@ -76,6 +79,7 @@ def test_layer_parity_forward(pre_ln, batch, seq):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-4, atol=2e-4)
 
 
+@slow
 @pytest.mark.parametrize("pre_ln", [True, False])
 def test_layer_parity_backward(pre_ln):
     cfg = DeepSpeedTransformerConfig(
@@ -102,6 +106,7 @@ def test_layer_parity_backward(pre_ln):
         )
 
 
+@slow
 def test_stochastic_mode_changes_bf16_path_and_warns():
     """stochastic_mode must be a real behavior change (reference builds a
     distinct relaxed kernel, setup.py:44-118), announced at rank 0 — never
@@ -143,6 +148,7 @@ def test_stochastic_mode_changes_bf16_path_and_warns():
     np.testing.assert_allclose(a, b, rtol=0.1, atol=0.1)
 
 
+@slow
 def test_stochastic_mode_fp16_keeps_fp32_statistics():
     """fp16's narrow range (max 65504; eps underflow) must NOT take the
     relaxed path: outputs stay bit-identical to the default, and large
@@ -168,6 +174,7 @@ def test_stochastic_mode_fp16_keeps_fp32_statistics():
     assert np.isfinite(np.asarray(out_s, np.float32)).all()
 
 
+@slow
 def test_remat_modes_same_output():
     """The reference's memory modes change memory, not numerics
     (ds_transformer_cuda.cpp:189-191) — remat must be invisible."""
@@ -193,6 +200,7 @@ def test_remat_modes_same_output():
         )
 
 
+@slow
 def test_dropout_determinism_same_rng():
     cfg = DeepSpeedTransformerConfig(
         hidden_size=64, heads=4, attn_dropout_ratio=0.1, hidden_dropout_ratio=0.1
@@ -251,3 +259,181 @@ def test_flash_long_sequence_no_cap():
     o1 = flash_attention(q, k, v, causal=True)
     o2 = mha_reference(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), rtol=1e-5, atol=1e-5)
+
+
+# Two-level tiling (PR 25): every case runs forward AND gradients against
+# mha_reference. (sq, sk, causal, masked keys per batch row as [lo, hi),
+# largest outer block)
+FLASH_CASES = {
+    # diag_offset 256 > 0: the diagonal starts in the third key sub-tile
+    "causal_sq256_sk512": (256, 512, True, None, 1024),
+    # one 768-block: 256 sub-tiles in the forward and dq, 128 in dkv
+    "causal_768": (768, 768, True, None, 1024),
+    # 768 under the old default: 256-blocks on a 3 x 3 grid, each its own
+    # sub-tile, loop bounds from the grid position
+    "causal_768_blocks256": (768, 768, True, None, 512),
+    # the block IS the sub-tile
+    "causal_128": (128, 128, True, None, 1024),
+    # the cells' sequence: one block, every bound static, 2 x 2 sub-tiles
+    # in the forward and dq, 8 x 8 in dkv
+    "causal_1024": (1024, 1024, True, None, 1024),
+    # 2 x 2 blocks of 2 x 2 sub-tiles: the fori_loop walk
+    "causal_2048": (2048, 2048, True, None, 1024),
+    # row 0: the whole first key sub-tile and two keys of the second are
+    # padding, so under the causal mask queries 0..129 see no key at all;
+    # row 1: trailing padding
+    "causal_384_leading_keys_masked": (384, 384, True, [(0, 130), (300, 384)], 1024),
+    "noncausal_384_masked": (384, 384, False, [(0, 130), (300, 384)], 1024),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", sorted(FLASH_CASES))
+def test_flash_tiled_matches_reference(case, dtype):
+    sq, sk, causal, masked, block = FLASH_CASES[case]
+    B, H, D = (1, 1, 64) if sq > 1024 else (2, 2, 64)
+    rng = np.random.default_rng(5)
+    q, k, v = (
+        jnp.asarray(rng.normal(size=(B, H, s, D)), dtype) for s in (sq, sk, sk)
+    )
+    w = rng.normal(size=(B, H, sq, D)).astype(np.float32)
+    kv_mask = add = None
+    live = np.ones((B, sq), bool)  # query rows that see at least one key
+    if masked is not None:
+        valid = np.ones((B, sk), np.int32)
+        for b, (lo, hi) in enumerate(masked):
+            valid[b, lo:hi] = 0
+        kv_mask = jnp.asarray(valid)
+        add = jnp.where(kv_mask[:, None, None, :] > 0, 0.0, -1e30)
+        if causal:
+            live = np.cumsum(valid, axis=1)[:, sk - sq:] > 0
+    # rows without a live key: flash gives zeros, the reference an average
+    # over masked keys; they take no part in the comparison
+    w = jnp.asarray(w * live[:, None, :, None])
+
+    def f32(x):
+        return x.astype(jnp.float32)
+
+    def flash(q, k, v):
+        return f32(flash_attention(
+            q, k, v, kv_mask=kv_mask, causal=causal, block_q=block, block_k=block
+        ))
+
+    def reference(q, k, v):
+        return mha_reference(f32(q), f32(k), f32(v), mask=add, causal=causal)
+
+    out, ref = np.asarray(flash(q, k, v)), np.asarray(reference(q, k, v))
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    keep = np.broadcast_to(live[:, None, :, None], out.shape)
+    np.testing.assert_allclose(out[keep], ref[keep], rtol=tol, atol=tol)
+    assert not out[~keep].any(), "a row with no live key must come out exactly zero"
+    if masked is not None and causal:
+        assert (~live).sum() >= 130
+
+    gf = jax.grad(lambda *a: jnp.sum(flash(*a) * w), (0, 1, 2))(q, k, v)
+    gr = jax.grad(lambda *a: jnp.sum(reference(*a) * w), (0, 1, 2))(q, k, v)
+    for a, b, name in zip(gf, gr, "qkv"):
+        a, b = np.asarray(f32(a)), np.asarray(b)
+        assert np.isfinite(a).all(), f"d{name} not finite"
+        scale = np.abs(b).max()
+        gtol = 1e-4 if dtype == jnp.float32 else 3e-2
+        assert np.abs(a - b).max() <= gtol * scale, (
+            f"d{name}: {np.abs(a - b).max() / scale:.2e} of the largest entry"
+        )
+
+
+def test_flash_loop_bounds_stop_at_the_diagonal():
+    from deepspeed_tpu.ops.attention import (
+        _key_range, _query_range, flash_tiling,
+    )
+
+    t = flash_tiling(1024, 1024, 512, 512, True, sub_q=128, sub_k=128)
+    assert t["visited_share"] == 0.5625
+    assert flash_tiling(1024, 1024, 512, 512, False, sub_q=128, sub_k=128)[
+        "visited_share"] == 1.0
+    # the cells' shape as the kernels walk it: the forward and dq in 512
+    # sub-tiles (3 of 4, as the one-level 512-tiles before), dkv in 128s
+    t = flash_tiling(1024, 1024, 1024, 1024, True)
+    assert (t["sub_q"], t["sub_k"], t["visited_share"]) == (512, 512, 0.75)
+    t = flash_tiling(1024, 1024, 1024, 1024, True, key_major=True)
+    assert (t["sub_q"], t["sub_k"], t["visited_share"]) == (128, 128, 0.5625)
+    # on a grid of several blocks dkv too walks in the large sub-tile
+    t = flash_tiling(2048, 2048, 1024, 1024, True, key_major=True)
+    assert (t["sub_q"], t["sub_k"]) == (512, 512)
+    # a block the sub-tile does not divide: the largest halving that does,
+    # else the block itself
+    t = flash_tiling(768, 768, 768, 768, True)
+    assert (t["sub_q"], t["sub_k"]) == (256, 256)
+    t = flash_tiling(1032, 1032, 8, 8, True)
+    assert (t["sub_q"], t["sub_k"]) == (8, 8)
+
+    for sq, sk, bq, bk, sub_q, sub_k in [
+        (1024, 1024, 512, 512, 128, 128), (768, 768, 256, 256, 128, 128),
+        (256, 512, 256, 512, 128, 128), (512, 256, 512, 256, 128, 128),
+        (1024, 1024, 512, 1024, 256, 128), (384, 384, 384, 384, 128, 128),
+        (1024, 1024, 1024, 512, 128, 256), (264, 264, 264, 264, 264, 264),
+    ]:
+        off = sk - sq
+        by_rows = set()
+        for q_first in range(0, sq, sub_q):
+            for k_block in range(0, sk, bk):
+                n_full, hi = _key_range(
+                    q_first, sub_q, k_block, sub_k, bk // sub_k, off
+                )
+                assert 0 <= n_full <= hi <= bk // sub_k
+                for c in range(bk // sub_k):
+                    k_first = k_block + c * sub_k
+                    # some score of the sub-tile is live <=> its first key
+                    # is visible to the last row
+                    live = k_first <= q_first + sub_q - 1 + off
+                    # every score is live <=> its last key is visible to
+                    # the first row
+                    whole = k_first + sub_k - 1 <= q_first + off
+                    assert (c < hi) == live, "visited iff not wholly above"
+                    assert (c < n_full) == whole
+                    if c < hi:
+                        by_rows.add((q_first, k_first, c < n_full))
+        by_keys = set()
+        for k_first in range(0, sk, sub_k):
+            for q_block in range(0, sq, bq):
+                lo, full = _query_range(
+                    k_first, sub_k, q_block, sub_q, bq // sub_q, off
+                )
+                assert 0 <= lo <= full <= bq // sub_q
+                for r in range(lo, bq // sub_q):
+                    by_keys.add((q_block + r * sub_q, k_first, r >= full))
+        # dkv walks the same sub-tiles as fwd and dq, masked the same way
+        assert by_keys == by_rows
+
+
+def test_flash_tiling_is_logged_once_per_shape():
+    import importlib
+    import logging
+
+    # ``deepspeed_tpu.ops.attention`` the attribute is the dispatcher
+    att = importlib.import_module("deepspeed_tpu.ops.attention")
+    seen = []
+
+    class Grab(logging.Handler):
+        def emit(self, record):
+            seen.append(record.getMessage())
+
+    handler, level = Grab(), att.logger.level
+    att.logger.addHandler(handler)
+    att.logger.setLevel(logging.DEBUG)
+    att._log_tiling.cache_clear()
+    try:
+        q = jnp.zeros((1, 1, 1024, 64), jnp.float32)
+        for _i in range(2):
+            jax.eval_shape(lambda q: flash_attention(q, q, q, causal=True), q)
+    finally:
+        att.logger.removeHandler(handler)
+        att.logger.setLevel(level)
+    lines = [m for m in seen if m.startswith("flash_tiling")]
+    assert len(lines) == 1
+    t = att.flash_tiling(1024, 1024, 1024, 1024, True)
+    assert f" sub={t['sub_q']}x{t['sub_k']} " in lines[0]
+    assert f" visited_share={t['visited_share']:.4f} " in lines[0]
+    t = att.flash_tiling(1024, 1024, 1024, 1024, True, key_major=True)
+    assert f"dkv_sub={t['sub_q']}x{t['sub_k']} " in lines[0]
+    assert lines[0].endswith(f"dkv_visited_share={t['visited_share']:.4f}")

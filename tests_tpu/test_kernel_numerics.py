@@ -7,7 +7,8 @@ evidence before this tier (the analog of the reference's on-device kernel
 suites, tests/unit/test_cuda_forward.py / test_cuda_backward.py:1-40).
 
 The dropout backward regenerates its keep-mask by reseeding the TPU PRNG
-per (batch*head, q-block, k-block) tile (ops/attention.py:181,243,295); a
+per (batch*head, q granule, k granule) of 128 x 128 scores, whatever slab a
+kernel computes in (ops/attention.py:_keep_mask); a
 fwd/bwd mask mismatch silently corrupts gradients. The directional-
 derivative test here is the direct check: with a FIXED seed the dropout
 net is deterministic, so a central finite difference along a random
@@ -106,6 +107,41 @@ def test_flash_grads_match_reference_compiled(causal):
     for a, b, name in zip(gf, gr, "qkv"):
         denom = float(jnp.max(jnp.abs(b))) or 1.0
         rel = float(jnp.max(jnp.abs(a - b))) / denom
+        assert rel < 5e-2, f"d{name} rel err {rel:.2e}"
+
+
+def test_flash_matches_reference_at_the_cells_shape():
+    """Forward and gradients at the shape both GPT-2 cells of the
+    benchmark run ([8, 20, 1024, 64] bf16, causal, no key mask, dropout
+    off: one block each way, so every loop bound is static and the walk
+    over sub-tiles unrolls) against the float32 reference."""
+    b, h, s, d = 8, 20, 1024, 64
+    ks = jax.random.split(jax.random.PRNGKey(25), 4)
+    q, k, v, w = (
+        jax.random.normal(kk, (b, h, s, d), jnp.float32).astype(jnp.bfloat16)
+        for kk in ks
+    )
+
+    def f32(x):
+        return x.astype(jnp.float32)
+
+    def flash(q, k, v):
+        return f32(flash_attention(q, k, v, causal=True))
+
+    def reference(q, k, v):
+        return mha_reference(f32(q), f32(k), f32(v), causal=True)
+
+    def out_and_grads(attn):
+        return jax.jit(jax.value_and_grad(
+            lambda q, k, v: jnp.sum(attn(q, k, v) * f32(w)), argnums=(0, 1, 2)
+        ))(q, k, v)[1], jax.jit(attn)(q, k, v)
+
+    gf, out = out_and_grads(flash)
+    gr, ref = out_and_grads(reference)
+    err = float(jnp.max(jnp.abs(out - ref)))
+    assert err < 4e-2, f"max err {err:.2e}"
+    for a, r, name in zip(gf, gr, "qkv"):
+        rel = float(jnp.max(jnp.abs(f32(a) - f32(r))) / jnp.max(jnp.abs(f32(r))))
         assert rel < 5e-2, f"d{name} rel err {rel:.2e}"
 
 
