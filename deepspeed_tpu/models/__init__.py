@@ -7,6 +7,7 @@ from .bert import (
     cross_entropy_ignore_index,
 )
 from .gpt2 import GPT2Config, GPT2LMHeadModel, GPT2Model, partition_specs
+from .hybrid import HybridCausalLM, HybridLMConfig, HybridModel
 
 __all__ = [
     "BertConfig",
@@ -17,6 +18,9 @@ __all__ = [
     "GPT2Config",
     "GPT2LMHeadModel",
     "GPT2Model",
+    "HybridCausalLM",
+    "HybridLMConfig",
+    "HybridModel",
     "partition_specs",
     "cross_entropy_ignore_index",
 ]

@@ -100,8 +100,12 @@ DEFAULT_BLOCK_K = 1024
 # of the ZeRO-3 stack (models/stack.py) — naming it in a policy SAVES the
 # gathered weights across backward (skipping the re-gather at n_layers x
 # full-layer HBM cost; the default stage-3 policies deliberately exclude
-# it so backward re-gathers instead).
-CHECKPOINT_NAMES = ("attn_probs", "flash_out", "flash_lse", "zero3_gathered")
+# it so backward re-gathers instead). "moe_plan" tags the routing choice and
+# the sorted row plan of the no-drop expert layer (ops/moe.py): a few MB of
+# integers that a policy naming it keeps, so that backward does not run the
+# top-k and the sort again.
+CHECKPOINT_NAMES = ("attn_probs", "flash_out", "flash_lse", "zero3_gathered",
+                    "moe_plan")
 
 
 def pick_block(seq, maximum):
@@ -963,7 +967,14 @@ def attention(
     which need exact mask gradients). With ``mesh`` supplied and a
     data/model-parallel layout, flash runs per-shard via ``shard_map``;
     where several devices leave no way to run it (``_flash_route``), the
-    O(S^2) path runs and, on a TPU, says why once."""
+    O(S^2) path runs and, on a TPU, says why once. ``k``/``v`` may have
+    fewer heads than ``q`` (grouped-query attention)."""
+    if k.shape[1] != q.shape[1]:
+        # grouped-query heads: each kv head serves q_heads / kv_heads query
+        # heads. Repeated here, before the kernel; a grouped kernel layout
+        # that reads each kv head once is not built.
+        k, v = (jnp.repeat(t, q.shape[1] // k.shape[1], axis=1)
+                for t in (k, v))
     sq, sk = q.shape[2], k.shape[2]
     bq = pick_block(sq, DEFAULT_BLOCK_Q)
     bk = pick_block(sk, DEFAULT_BLOCK_K)

@@ -14,14 +14,20 @@ Router: top-2 gating with the Switch/GShard load-balancing auxiliary loss
 (mean gate fraction x mean dispatch fraction x E), capacity
 ``capacity_factor * S * K / E`` tokens per expert per group; overflow
 tokens fall through to the residual path (standard MoE semantics).
+
+A second path lives at the end of this file: ``latent_moe_mixer``, the
+expert-SHARE layer of a latent mixture of experts that drops no token
+(sigmoid scores, sorted assignments, a loop over the tiles in use).
 """
 
 import dataclasses
+import functools
 from typing import Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..config.constants import DATA_AXIS
@@ -253,3 +259,215 @@ class DeepSpeedMoETransformerLayer(nn.Module):
             causal=self.causal, use_flash=self.use_flash, mesh=self.mesh,
             train=train, dropout_rng=rng, ffn_fn=moe,
         )
+
+
+# ----------------------------------------------------------------------
+# Latent mixture of experts that drops no token (the expert-SHARE layer).
+#
+# The layer is told which experts it HOLDS (``offset`` .. ``offset + held``)
+# out of those it ROUTES OVER: it scores every token against all of them,
+# takes the top-k, and computes its own experts' part of the result. What
+# the experts held elsewhere would add is left out, and nothing here stands
+# in for the exchange that would fetch it. There is no capacity: the
+# assignments to held experts are sorted by expert (ONE single-operand sort
+# of keys ``expert * tokens + token``), each expert's run is cut into tiles
+# of rows, and a loop over the tiles IN USE gathers each tile's rows,
+# multiplies them by its expert's weights and scatters the result back —
+# work goes with the real group sizes, and only the key array has the
+# worst-case size. Everything that carries a gradient outside that loop is
+# elementwise over [tokens, experts]: no static-size scatter in a backward.
+# ----------------------------------------------------------------------
+
+
+def level_selection_scores(positions, routed):
+    """[len(positions), routed] float32: a fixed pseudo-random number for
+    every (position in the sequence, expert), far apart (integers below
+    2^24, typical gap 2^15). Added to the selection scores they decide the
+    top-k alone: every position picks a fixed random-looking set, every
+    expert takes tokens * k / routed of them give or take a few percent,
+    whatever the weights are and however training moves them."""
+    cell = positions.astype(jnp.uint32)[:, None] * jnp.uint32(routed) \
+        + jnp.arange(routed, dtype=jnp.uint32)[None, :]
+    h = cell * jnp.uint32(2654435761)
+    h = (h ^ (h >> 16)) * jnp.uint32(0x85EBCA6B)
+    h = (h ^ (h >> 13)) * jnp.uint32(0xC2B2AE35)
+    return ((h ^ (h >> 16)) >> 8).astype(jnp.float32)
+
+
+def route_sigmoid_topk(x, router_w, router_bias, top_k, scale, level=None):
+    """Sigmoid scores in float32 over all routed experts; the top-k of
+    ``score + bias`` (the bias chooses, takes no gradient and weighs
+    nothing); weights ``scale * s_e / sum_chosen s``. ``level``: each row's
+    position in its sequence, to force a level selection
+    (``level_selection_scores`` joins the bias); the weights are the
+    router's own either way. x [T, E] -> (chosen [T, k] int32, weights
+    [T, routed] float32, 0 where not chosen)."""
+    scores = jax.nn.sigmoid(
+        jnp.dot(x, router_w, preferred_element_type=jnp.float32))
+    selection = scores + jax.lax.stop_gradient(router_bias.astype(jnp.float32))
+    if level is not None:
+        selection = selection + level_selection_scores(level, scores.shape[-1])
+    _, chosen = jax.lax.top_k(selection, top_k)
+    experts = jnp.arange(scores.shape[-1], dtype=chosen.dtype)
+    mask = jnp.any(chosen[:, :, None] == experts[None, None, :], axis=1)
+    picked = jnp.where(mask, scores, 0.0)
+    return chosen, scale * picked / jnp.sum(picked, axis=-1, keepdims=True)
+
+
+def plan_held_rows(chosen, held, offset, tile):
+    """Sort the assignments to held experts by expert, tokens in order
+    inside each. Returns a dict: ``keys`` — ``expert * tokens + token`` for
+    every held assignment in sorted order, then the sentinel ``held *
+    tokens`` (static length: tokens x k, plus one tile so that a tile's
+    slice never runs off the end);
+    ``tile_expert``, ``tile_first`` (where in ``keys`` the tile's rows
+    start) and ``tile_rows`` (how many are real), one entry for each tile
+    that could be in use; ``n_tiles``, the tiles in use. Beside it,
+    ``sizes`` [held]: each held expert's load."""
+    tokens = chosen.shape[0]
+    if (held + 1) * tokens >= 2 ** 31:
+        raise ValueError(
+            f"{held} held experts x {tokens} tokens overflow the int32 keys")
+    local = chosen - offset
+    sentinel = held * tokens
+    token = jnp.arange(tokens, dtype=jnp.int32)[:, None]
+    keys = jnp.where(
+        (local >= 0) & (local < held), local * tokens + token, sentinel)
+    keys = jnp.sort(keys.reshape(-1).astype(jnp.int32))
+    bounds = jnp.arange(held + 1, dtype=jnp.int32) * tokens
+    starts = jnp.searchsorted(keys, bounds).astype(jnp.int32)
+    sizes = starts[1:] - starts[:-1]
+    tile_starts = jnp.concatenate([
+        jnp.zeros((1,), jnp.int32),
+        jnp.cumsum(-(-sizes // tile)).astype(jnp.int32)])
+    max_tiles = -(-keys.shape[0] // tile) + held
+    ids = jnp.arange(max_tiles, dtype=jnp.int32)
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(tile_starts[1:], ids, side="right"),
+        held - 1).astype(jnp.int32)
+    tile_first = starts[tile_expert] + (ids - tile_starts[tile_expert]) * tile
+    tile_rows = jnp.clip(starts[tile_expert + 1] - tile_first, 0, tile)
+    return {
+        "keys": jnp.concatenate(
+            [keys, jnp.full((tile,), sentinel, jnp.int32)]),
+        "tile_expert": tile_expert, "tile_first": tile_first,
+        "tile_rows": tile_rows, "n_tiles": tile_starts[held],
+    }, sizes
+
+
+def _tile_inputs(t, tile, u, w1, w2, weights_t, plan):
+    """One tile: its expert, its rows' tokens (``tokens`` = no row), their
+    routing weights, the gathered rows and the expert's two matrices."""
+    tokens = u.shape[0]
+    e = plan["tile_expert"][t]
+    keys = jax.lax.dynamic_slice(plan["keys"], (plan["tile_first"][t],), (tile,))
+    real = jnp.arange(tile, dtype=jnp.int32) < plan["tile_rows"][t]
+    tok = jnp.where(real, keys - e * tokens, tokens)
+    wt = jnp.take(
+        jax.lax.dynamic_index_in_dim(weights_t, e, keepdims=False), tok,
+        mode="fill", fill_value=0)
+    x = jnp.take(u, tok, axis=0, mode="fill", fill_value=0)
+    return (e, tok, wt, x,
+            jax.lax.dynamic_index_in_dim(w1, e, keepdims=False),
+            jax.lax.dynamic_index_in_dim(w2, e, keepdims=False))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def grouped_expert_ffn(u, w1, w2, weights_t, plan, tile):
+    """sum over held assignments of ``weight * relu(u[token] W1_e)^2 W2_e``
+    at the token's row. u [T, L]; w1 [held, L, F]; w2 [held, F, L];
+    weights_t [held, T] float32 (0 where the token did not choose the
+    expert); plan: ``plan_held_rows``. -> [T, L] float32."""
+    return _grouped_fwd(u, w1, w2, weights_t, plan, tile)[0]
+
+
+def _grouped_fwd(u, w1, w2, weights_t, plan, tile):
+    def body(t, out):
+        _e, tok, wt, x, w1e, w2e = _tile_inputs(
+            t, tile, u, w1, w2, weights_t, plan)
+        a = jnp.maximum(jnp.dot(x, w1e, preferred_element_type=jnp.float32), 0)
+        y = jnp.dot((a * a).astype(u.dtype), w2e,
+                    preferred_element_type=jnp.float32)
+        return out.at[tok].add(y * wt[:, None], mode="drop")
+
+    out = jax.lax.fori_loop(
+        0, plan["n_tiles"], body, jnp.zeros(u.shape, jnp.float32))
+    return out, (u, w1, w2, weights_t, plan)
+
+
+def _grouped_bwd(tile, res, g):
+    u, w1, w2, weights_t, plan = res
+    dtype = u.dtype
+
+    def body(t, carry):
+        du, dw1, dw2, dwt = carry
+        e, tok, wt, x, w1e, w2e = _tile_inputs(
+            t, tile, u, w1, w2, weights_t, plan)
+        a = jnp.maximum(jnp.dot(x, w1e, preferred_element_type=jnp.float32), 0)
+        h = (a * a).astype(dtype)
+        gy = jnp.take(g, tok, axis=0, mode="fill", fill_value=0)
+        y = jnp.dot(h, w2e, preferred_element_type=jnp.float32)
+        dwt = dwt.at[e, tok].add(jnp.sum(gy * y, axis=-1), mode="drop")
+        dy = (gy * wt[:, None]).astype(dtype)
+        dh = jnp.dot(dy, w2e.T, preferred_element_type=jnp.float32)
+        dpre = (dh * 2.0 * a).astype(dtype)
+        dw2 = dw2.at[e].add(jnp.dot(h.T, dy, preferred_element_type=jnp.float32))
+        dw1 = dw1.at[e].add(jnp.dot(x.T, dpre, preferred_element_type=jnp.float32))
+        dx = jnp.dot(dpre, w1e.T, preferred_element_type=jnp.float32)
+        return du.at[tok].add(dx, mode="drop"), dw1, dw2, dwt
+
+    du, dw1, dw2, dwt = jax.lax.fori_loop(0, plan["n_tiles"], body, (
+        jnp.zeros(u.shape, jnp.float32), jnp.zeros(w1.shape, jnp.float32),
+        jnp.zeros(w2.shape, jnp.float32), jnp.zeros_like(weights_t)))
+    return (du.astype(dtype), dw1.astype(w1.dtype), dw2.astype(w2.dtype), dwt,
+            jax.tree_util.tree_map(lambda _: None, plan))
+
+
+grouped_expert_ffn.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+def latent_moe_mixer(p, x, *, top_k, scale, held, offset, tile,
+                     force_level=False):
+    """One latent mixture-of-experts mixer over normalized ``x`` [B, S, E]
+    -> (out [B, S, E], counters). ``p``: router [E, routed], router_bias
+    [routed], down [E, L], up [L, E], w1 [held, L, F], w2 [held, F, L],
+    shared_w1 [E, Fs], shared_w2 [Fs, E]. The router and the shared expert
+    read ``x``; the routed experts live in the latent ``x W_down``. A token
+    none of whose chosen experts is held gets the shared expert only.
+    ``force_level``: the selection is forced level (for measurements with
+    weights that no balance rule has trained; ``level_selection_scores``)."""
+    b, s, e = x.shape
+    xt = x.reshape(b * s, e)
+    with jax.named_scope("moe_route"):
+        chosen, weights = route_sigmoid_topk(
+            xt, p["router"], p["router_bias"], top_k, scale,
+            level=jnp.arange(b * s) % s if force_level else None)
+        plan, sizes = plan_held_rows(chosen, held, offset, tile)
+        chosen, plan = jax.tree_util.tree_map(
+            lambda a: checkpoint_name(a, "moe_plan"), (chosen, plan))
+        weights_t = weights[:, offset:offset + held].T
+        in_use = jnp.arange(plan["tile_rows"].shape[0]) < plan["n_tiles"]
+        local = chosen - offset
+        is_held = (local >= 0) & (local < held)
+        counters = {
+            "moe/local_assignments": jnp.sum(sizes),
+            "moe/tokens_without_held_expert": jnp.sum(
+                ~jnp.any(is_held, axis=-1)).astype(jnp.int32),
+            "moe/max_expert_load": jnp.max(sizes),
+            # held assignments that no tile in use has a row for
+            "moe/overflow": jnp.sum(is_held).astype(jnp.int32)
+            - jnp.sum(jnp.where(in_use, plan["tile_rows"], 0)),
+        }
+    with jax.named_scope("moe_shared"):
+        u = xt @ p["down"]
+        shared = _relu2(xt @ p["shared_w1"]) @ p["shared_w2"]
+    with jax.named_scope("moe_experts"):
+        routed = grouped_expert_ffn(u, p["w1"], p["w2"], weights_t, plan, tile)
+    with jax.named_scope("moe_shared"):
+        out = routed.astype(x.dtype) @ p["up"] + shared
+    return out.reshape(b, s, e), counters
+
+
+def _relu2(x):
+    r = jnp.maximum(x, 0)
+    return r * r

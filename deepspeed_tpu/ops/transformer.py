@@ -329,6 +329,33 @@ def layer_norm_apply(cfg: DeepSpeedTransformerConfig, x, scale, bias):
     )
 
 
+def rms_norm(x, gain, eps):
+    """RMS norm (no mean, no bias), statistics in float32."""
+    xs = x.astype(jnp.float32)
+    inv = jax.lax.rsqrt(jnp.mean(jnp.square(xs), axis=-1, keepdims=True) + eps)
+    return (xs * inv * gain.astype(jnp.float32)).astype(x.dtype)
+
+
+def gqa_attention_mixer(p, x, *, heads, kv_heads, head_dim, mesh=None):
+    """Causal grouped-query attention over normalized ``x`` [B, S, E] with
+    bias-free projections wq [E, heads * D], wk/wv [E, kv_heads * D], wo
+    [heads * D, E]; no position embedding."""
+    from .attention import attention
+
+    b, s, _ = x.shape
+
+    def split(t, n):
+        return t.reshape(b, s, n, head_dim).transpose(0, 2, 1, 3)
+
+    with jax.named_scope("attn_mixer"):
+        q = split(x @ p["wq"], heads)
+        k = split(x @ p["wk"], kv_heads)
+        v = split(x @ p["wv"], kv_heads)
+        ctx = attention(q, k, v, causal=True, mesh=mesh)
+        ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, heads * head_dim)
+        return ctx @ p["wo"]
+
+
 def transformer_block_apply(
     cfg: DeepSpeedTransformerConfig,
     p: dict,

@@ -93,6 +93,17 @@ def _split_model_output(out):
     return out, ()
 
 
+def _aux_counters(aux):
+    """The named counters among a model's extra outputs: a dict keyed by
+    registry names (``"moe/overflow"``) anywhere in the aux tuple. Device
+    values, [accum]-stacked; nothing here reads them."""
+    out = {}
+    for item in aux if isinstance(aux, (tuple, list)) else ():
+        if isinstance(item, dict):
+            out.update({k: v for k, v in item.items() if isinstance(k, str)})
+    return out
+
+
 def _poison_first_float_leaf(tree):
     """Fault site ``grads.nan`` (resilience/faults.py): NaN-multiply the
     window's first floating batch leaf so its loss AND gradients go
@@ -1840,6 +1851,7 @@ class DeepSpeedEngine:
                 global_steps=self.global_steps,
                 skipped_steps=self.skipped_steps,
                 micro_steps=self.micro_steps,
+                counters=_aux_counters(self.last_aux),
             )
         # settle overflow flags from windows BEFORE this one: their compute
         # has finished (or is about to — the current window is already

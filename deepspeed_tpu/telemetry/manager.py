@@ -23,6 +23,8 @@ import os
 import time
 import weakref
 
+import numpy as np
+
 from ..utils.logging import logger, warn_once
 from .exporters import build_exporter
 from .profiling import ProfilerWindow
@@ -334,6 +336,7 @@ class Telemetry:
         self._windows_ended = 0
         self._windows_since_export = 0
         self._pending_values = None
+        self._pending_counters = []  # per-window aux counters, device values
         self._window_start = None
         self._last_export_time = None
         self._tokens_since_export = 0
@@ -417,12 +420,17 @@ class Telemetry:
         global_steps=0,
         skipped_steps=0,
         micro_steps=0,
+        counters=None,
     ):
         """Window bookkeeping; ``loss``/``grad_norm``/``loss_scale`` may be
         raw device arrays — they are only materialized at export
-        boundaries (see module docstring)."""
+        boundaries (see module docstring). ``counters``: a model's named
+        auxiliary scalars of this window ({registry name: device array over
+        the micro-steps}), kept as device values until then too."""
         if not self.enabled:
             return
+        if counters:
+            self._pending_counters.append(counters)
         if self.profiler is not None:
             self.profiler.on_window_end()
         now = time.time()
@@ -543,6 +551,15 @@ class Telemetry:
         """Resolve device values and derived rates into gauges. The
         float() calls below are the subsystem's only host syncs."""
         reg = self.registry
+        for counters in self._pending_counters:
+            for name, value in counters.items():
+                value = np.asarray(value)
+                if name.rsplit("/", 1)[-1].startswith("max_"):
+                    gauge = reg.gauge(name)
+                    gauge.set(max(gauge.value, float(value.max())))
+                else:
+                    reg.counter(name).inc(float(value.sum()))
+        self._pending_counters = []
         if loss is not None:
             reg.gauge("train/loss").set(float(loss))
         if grad_norm is not None:

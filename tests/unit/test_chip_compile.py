@@ -164,6 +164,57 @@ def test_flash_kernel_compiles_at_the_cells_shapes(kernel, cell):
 
 
 # ---------------------------------------------------------------------------
+# the hybrid stack's mixers at the widths of its benchmark cell
+# (models/hybrid.py; micro 2 x seq 8192, hidden 4096, bf16)
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("kind", ["mamba", "moe", "attn"])
+def test_hybrid_mixer_compiles_at_the_cells_shapes(kind):
+    """Forward and backward of one layer of each kind under the cell's remat
+    policy: the chunked SSD scan (16 heads x 64, state 128, chunk 128), the
+    no-drop expert layer (16 of 512 experts held, top-22, latent 1024,
+    tiles of 384: a loop of dynamic length with gathers and scatter-adds
+    inside), grouped-query attention (4 query heads on 1 kv head, width 128,
+    8,192 positions: the flash kernels' multi-block path)."""
+    from deepspeed_tpu.models.hybrid import HybridLMConfig, HybridModel
+
+    cfg = HybridLMConfig(
+        vocab_size=1024, hidden_size=4096,
+        pattern={"mamba": "M", "moe": "E", "attn": "*"}[kind],
+        mamba_heads=16, mamba_head_dim=64, mamba_groups=1, ssm_state=128,
+        chunk_size=128, n_experts_held=16, n_experts_routed=512, top_k=22,
+        moe_latent=1024, moe_intermediate=2688, moe_shared_intermediate=5376,
+        moe_tile=384, attn_heads=4, kv_heads=1, head_dim=128, remat=True,
+        remat_policy="dots_with_no_batch_dims_saveable+flash_out+flash_lse"
+                     "+moe_plan")
+    model = HybridModel(cfg)
+    ids = _shape((2, 8192), jnp.int32)
+    params = jax.tree_util.tree_map(
+        lambda x: _shape(x.shape, jnp.bfloat16),
+        jax.eval_shape(lambda: model.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 128), jnp.int32)))["params"])
+
+    def loss(p, ids):
+        return model.apply({"params": p}, ids)[0].astype(jnp.float32).sum()
+
+    real = device.on_tpu, jax.device_count
+    device.on_tpu, jax.device_count = (lambda: True), (lambda: 1)
+    try:
+        compiled = jax.jit(jax.grad(loss)).lower(params, ids).compile()
+    finally:
+        device.on_tpu, jax.device_count = real
+    text = compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 6 * 2 ** 30
+    kernels = [l for l in text.splitlines() if "tpu_custom_call" in l]
+    # only attention brings Pallas kernels, and exactly the three that
+    # flash_ms.train sums
+    assert len(kernels) == (3 if kind == "attn" else 0), kernels
+    for scope in {"mamba": ("mamba_mixer", "mamba_ssd"),
+                  "moe": ("moe_route", "moe_experts", "moe_shared"),
+                  "attn": ("attn_mixer",)}[kind]:
+        assert f"/{scope}/" in text, scope
+
+
+# ---------------------------------------------------------------------------
 # decode kernels (ops/decode_attention.py)
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("model", sorted(HEADS))
