@@ -38,6 +38,7 @@ assumed.
 
 import dataclasses
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -45,7 +46,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..utils import device
-from .optimizers import Lamb, _f32
+from . import quant
+from .optimizers import Lamb, _f32, adam_core
 
 
 def _smem():
@@ -338,3 +340,432 @@ class FusedLamb(Lamb):
         new_nu = jax.tree_util.tree_unflatten(treedef, out_v)
         aux = {"lamb_coeffs": coeffs}
         return new_params, {"step": step, "mu": new_mu, "nu": new_nu}, aux
+
+
+# --------------------------------------------------------------------------
+# The reduced-state Adam update in one pass over HBM (PR 27)
+#
+# ops/optimizers.py:Adam hands a leaf here when its first moment is stored
+# as int8 per run of the minor axis (ops/quant.py) and its shape allows.
+# One grid step holds a tile of whole rows of every stored array of the
+# leaf in VMEM (parameter, compensation code, gradient, int8 moment, its
+# scales, bf16 second moment), walks it in slabs of 32 rows x 128 lanes
+# that stay in registers, and writes the tile back in place: every stored
+# byte is read once and written once, and no float32 array of a leaf's
+# size ever exists in HBM. The run's absmax is a reduction inside the
+# tile, which is why the update takes two sweeps over a slab: the first
+# computes everything and parks the new first moment in a VMEM scratch,
+# the second encodes it once each run's scale is known. Rows need not
+# come in whole groups of 128 (GPT-2 1.5B's 1,600; a shard's 12,576): the
+# last tile is then ragged and its missing slabs are not walked. A leaf
+# the chip stores rows-minor (``_rows_minor``) is taken transposed and
+# walked by ``column``, a run down the sublanes. The walks over a
+# tile's slabs, a row's runs and a run's 128-lane chunks are all LOOPS
+# (``fori_loop``), so the body is traced and lowered once, about a millisecond an equation on the chip's host. A
+# Pallas kernel pays that at EVERY start, warm compile cache or not: with
+# the lane walk unrolled (in Python, or by the lowering's ``unroll=True``;
+# Mosaic takes a loop whole or not at all) the kernel is a fifth faster,
+# but GPT-2 large's six matrices then add 7.6 s or 4.8 s to a start, and
+# with only the runs unrolled 2.5 s (the hybrid stack's fifteen: 6 s),
+# where these loops add nothing that shows (my chip runs, PR 27).
+
+_SLAB = 32  # rows per inner step: one packed int8 register is (32, 128)
+_LANES = 128
+_GROUP = 128  # rows whose scales share one lane window of the scale block
+_TILE_ELEMENTS = 1 << 19  # per grid step: 14 bytes each, twice (pipelined)
+_MAX_WIDTH = 8192  # a tile holds whole rows; wider leaves stay plain
+_MAX_RUNS = 16  # a width that is no multiple of 128 unrolls its runs
+_VMEM_LIMIT = 40 << 20
+
+
+def _local_shape(shape, spec, mesh):
+    """A shard's shape under ``spec``, or None if some axis does not
+    divide."""
+    entries = tuple(spec) + (None,) * (len(shape) - len(spec))
+    shards = [quant.spec_shards(entry, mesh.shape) for entry in entries]
+    if any(dim % n for dim, n in zip(shape, shards)):
+        return None
+    return tuple(dim // n for dim, n in zip(shape, shards))
+
+
+def adam_kernel_run(p, m_st, v_st, mesh=None, spec=None):
+    """The run length the one-pass kernel would work ``p``'s leaf with, or
+    None if the leaf has to take the plain update: decided from the stored
+    format and the (shard's) shape alone."""
+    if not quant.is_quantized(m_st) or quant.is_quantized(v_st):
+        return None
+    run = p.shape[-1] // m_st["scale"].shape[-2]
+    shape, scale_shape = p.shape, m_st["scale"].shape
+    if mesh is not None:
+        s_spec = quant.scale_spec(spec, p.shape, mesh.shape)
+        shape = _local_shape(p.shape, spec, mesh)
+        scale_shape = _local_shape(scale_shape, s_spec, mesh)
+        if shape is None or scale_shape is None:
+            return None
+    rows, width = shape[-2:]
+    if (
+        rows < _GROUP
+        or rows % _SLAB
+        or width > _MAX_WIDTH
+        or width > _MAX_RUNS * run
+        or width % run
+        or scale_shape[-2] != width // run
+        or p.dtype not in (jnp.bfloat16, jnp.float32)
+    ):
+        return None
+    return run
+
+
+def _tile_rows(rows, width):
+    """Rows of a grid step's tile: the most whole groups within
+    _TILE_ELEMENTS, and a count that divides ``rows`` where whole groups
+    do. Where they do not (1,600 rows; a shard of 12,576) the last tile is
+    ragged: the pipeline moves only the rows that exist, and the kernel
+    walks only their slabs."""
+    groups = -(-rows // _GROUP)
+    fit = max(1, _TILE_ELEMENTS // (_GROUP * width))
+    if rows % _GROUP == 0:
+        fit = max(k for k in range(1, fit + 1) if groups % k == 0)
+    return min(fit, groups) * _GROUP
+
+
+def _lane_chunks(width, run):
+    """The 128-lane chunks of a row by how the runs cut them: per run, the
+    (first, count) of the chunks that lie wholly inside it, walked by a
+    loop; and the (lo, hi) lanes of the rest (a chunk two runs share, a
+    last one short of 128), which only a width that is no multiple of 128
+    has and which are unrolled with a lane mask."""
+    inside, whole = [], set()
+    for j in range(width // run):
+        first, end = -(-j * run // _LANES), (j + 1) * run // _LANES
+        inside.append((first, max(end - first, 0)))
+        whole.update(range(first, end))
+    edges = [
+        (lo, min(lo + _LANES, width)) for lo in range(0, width, _LANES)
+        if lo // _LANES not in whole
+    ]
+    return inside, edges
+
+
+def _rows_minor(rows, width, run):
+    """Whether the chip stores such a leaf with its ROWS on the lanes: it
+    does where the width is no multiple of 128 and the rows are one (GPT-2
+    1.5B's [48, 6400, 1600] and [50304, 1600] arrive ``{1,2,0}`` and
+    ``{0,1}``), so that nothing is padded. The kernel then takes the leaf
+    transposed, which is that same memory, and a run lies DOWN the
+    sublanes; taken as stored in the program, XLA would lay every stored
+    array out anew before the kernel and after it (3.7 GiB of temporaries
+    for 1.5B's update: described-chip compile, PR 27)."""
+    return width % _LANES != 0 and rows % _GROUP == 0 and run % _SLAB == 0
+
+
+def _adam_leaf_kernel(
+    scal_ref, *refs, comped, run, rows, width, p_dtype, static, rows_minor
+):
+    if comped:
+        (g_ref, p_ref, q_ref, s_ref, v_ref, c_ref,
+         p_out, q_out, s_out, v_out, c_out, m_scr) = refs
+    else:
+        (g_ref, p_ref, q_ref, s_ref, v_ref,
+         p_out, q_out, s_out, v_out, m_scr) = refs
+        c_ref = c_out = None
+    nruns = width // run
+    gate = scal_ref[5]
+
+    @pl.when(gate == 0.0)
+    def _skipped_step():  # the old bytes, bit for bit
+        p_out[...] = p_ref[...]
+        q_out[...] = q_ref[...]
+        s_out[...] = s_ref[...]
+        v_out[...] = v_ref[...]
+        if comped:
+            c_out[...] = c_ref[...]
+
+    def update(here, parked, scale):
+        """Everything but the first moment's encoding on the [32, <= 128]
+        elements at ``here``, their old first moment under ``scale``; parks
+        the new one at ``parked`` of the scratch and returns its size."""
+        lr, b1, c1, c2, grad_scale = (scal_ref[i] for i in range(5))
+        g32 = g_ref[here].astype(jnp.float32) * grad_scale
+        p_old = p_ref[here]
+        if comped:
+            p32 = quant.decode_master(p_old, c_ref[here])
+        else:
+            p32 = p_old.astype(jnp.float32)
+        master, m_new, v_new = adam_core(
+            p32, g32, q_ref[here].astype(jnp.float32) * scale,
+            v_ref[here].astype(jnp.float32),
+            lr=lr, b1=b1, c1=c1, c2=c2, **static,
+        )
+        if comped:
+            p_new, c_new = quant.encode_master(
+                master, p_dtype,
+                to_grid=lambda x: x.astype(p_dtype).astype(jnp.float32),
+            )
+            c_out[here] = c_new
+        else:
+            p_new = master.astype(p_dtype)
+        p_out[here] = p_new
+        v_out[here] = v_new.astype(v_out.dtype)
+        m_scr[parked] = m_new
+        return jnp.abs(m_new)
+
+    def encode(here, parked, inv):
+        q_out[here] = quant.round_to_code(m_scr[parked] * inv)
+
+    def inverse(amax):
+        """(scale, 1 / scale or 0) of a run from its absmax."""
+        scale = amax / 127.0
+        live = scale > 0.0
+        one, zero = jnp.ones_like(scale), jnp.zeros_like(scale)
+        safe = jax.lax.select(live, scale, one)
+        return scale, jax.lax.select(live, one / safe, zero)
+
+    def whole_chunk(first, c):
+        return pl.ds(pl.multiple_of((first + c) * _LANES, _LANES), _LANES)
+
+    def column(i, _):
+        """The tile is [width, rows of the leaf]: 128 rows of the leaf on
+        the lanes, each run down the sublanes in slabs of 32, and the
+        scales of a run one lane-dense row of the scale block."""
+        lanes = whole_chunk(0, i)
+
+        def one_run(j, _):
+            def at(k):
+                down = pl.ds(pl.multiple_of(j * run + k * _SLAB, _SLAB), _SLAB)
+                parked = pl.ds(pl.multiple_of(k * _SLAB, _SLAB), _SLAB)
+                return (0, down, lanes), (parked, slice(None))
+
+            old = s_ref[0, pl.ds(j, 1), lanes]
+            acc = jax.lax.fori_loop(
+                0, run // _SLAB,
+                lambda k, acc: jnp.maximum(acc, update(*at(k), old)),
+                jnp.zeros((_SLAB, _LANES), jnp.float32),
+            )
+            scale, inv = inverse(jnp.max(acc, axis=0, keepdims=True))
+            jax.lax.fori_loop(
+                0, run // _SLAB, lambda k, _: encode(*at(k), inv), None
+            )
+            s_out[0, pl.ds(j, 1), lanes] = scale
+
+        jax.lax.fori_loop(0, nruns, one_run, None)
+
+    def runs_of(lo, hi):
+        return range(lo // run, (hi - 1) // run + 1)
+
+    def lanes_of(j, lo, hi):
+        """Which lanes of the chunk [lo, hi) belong to run ``j``."""
+        lane = jax.lax.broadcasted_iota(jnp.int32, (_SLAB, hi - lo), 1)
+        return (lane >= j * run - lo) & (lane < (j + 1) * run - lo)
+
+    def per_lane(cols, lo, hi):
+        """[_SLAB, hi - lo]: each lane's own run's entry of ``cols``."""
+        first, *later = runs_of(lo, hi)
+        out = cols[first]
+        for j in later:
+            out = jnp.where(lanes_of(j, lo, hi), cols[j], out)
+        return out
+
+    def slab(i, _):
+        """Rows [32 i, + 32) of the tile [rows, width]: slab ``k`` of its
+        group of 128, each run along the lanes."""
+        per_group = jnp.int32(_GROUP // _SLAB)
+        group, k = jax.lax.div(i, per_group), jax.lax.rem(i, per_group)
+        rows_at = pl.ds(pl.multiple_of(i * _SLAB, _SLAB), _SLAB)
+        group_lanes = pl.ds(pl.multiple_of(group * _GROUP, _GROUP), _GROUP)
+        # The scales lie rows-on-lanes: this slab's rows are 32 of its
+        # group's 128 lanes. A row's scale moves from its lane to its
+        # sublane and back by a masked sum, which needs no transpose.
+        lane = jax.lax.broadcasted_iota(jnp.int32, (_SLAB, _GROUP), 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, (_SLAB, _GROUP), 0)
+        is_mine = lane == row + k * _SLAB
+        mine = is_mine.astype(jnp.float32)
+        my_lanes = jnp.sum(mine, axis=0, keepdims=True) > 0.0
+
+        def at(lanes):
+            return (0, rows_at, lanes), (slice(None), lanes)
+
+        def old_scale(j):  # [_SLAB, 1], from the block's [1, _GROUP] row
+            # a select, not a product: in a ragged last tile the group's
+            # other lanes may hold anything, a NaN too
+            lanes = jax.lax.broadcast_in_dim(
+                s_ref[0, pl.ds(j, 1), group_lanes], mine.shape, (0, 1)
+            )
+            return jnp.sum(
+                jax.lax.select(is_mine, lanes, jnp.zeros_like(lanes)),
+                axis=1, keepdims=True,
+            )
+
+        def put_scale(j, col):  # beside what the group's other slabs put
+            lanes = jnp.sum(mine * col, axis=0, keepdims=True)
+            s_out[0, pl.ds(j, 1), group_lanes] = jax.lax.select(
+                my_lanes, lanes, s_out[0, pl.ds(j, 1), group_lanes]
+            )
+
+        inside, edges = _lane_chunks(width, run)
+        if not edges:
+            # every run is whole chunks: ONE body walks all runs
+            per_run = run // _LANES
+
+            def one_run(j, _):
+                col, first = old_scale(j), j * per_run
+                acc = jax.lax.fori_loop(
+                    0, per_run,
+                    lambda c, acc: jnp.maximum(
+                        acc, update(*at(whole_chunk(first, c)), col)
+                    ),
+                    jnp.zeros((_SLAB, _LANES), jnp.float32),
+                )
+                scale, inv = inverse(jnp.max(acc, axis=1, keepdims=True))
+                jax.lax.fori_loop(
+                    0, per_run,
+                    lambda c, _: encode(*at(whole_chunk(first, c)), inv),
+                    None,
+                )
+                put_scale(j, scale)
+
+            jax.lax.fori_loop(0, nruns, one_run, None)
+            return None
+        # a width that is no multiple of 128: runs share chunks, so the
+        # walk over the runs is unrolled and the shared chunks are masked
+        cols = [old_scale(j) for j in range(nruns)]
+        amax = []
+        for (first, count), col in zip(inside, cols):
+            acc = jnp.zeros((_SLAB, _LANES), jnp.float32)
+            if count:
+                acc = jax.lax.fori_loop(
+                    0, count,
+                    lambda c, acc, first=first, col=col: jnp.maximum(
+                        acc, update(*at(whole_chunk(first, c)), col)
+                    ),
+                    acc,
+                )
+            amax.append(jnp.max(acc, axis=1, keepdims=True))
+        for lo, hi in edges:
+            size = update(*at(slice(lo, hi)), per_lane(cols, lo, hi))
+            for j in runs_of(lo, hi):
+                amax[j] = jnp.maximum(amax[j], jnp.max(
+                    jnp.where(lanes_of(j, lo, hi), size, 0.0),
+                    axis=1, keepdims=True,
+                ))
+        scale, inv = zip(*map(inverse, amax))
+        for (first, count), inv_j in zip(inside, inv):
+            if count:
+                jax.lax.fori_loop(
+                    0, count,
+                    lambda c, _, first=first, inv_j=inv_j: encode(
+                        *at(whole_chunk(first, c)), inv_j
+                    ),
+                    None,
+                )
+        for lo, hi in edges:
+            encode(*at(slice(lo, hi)), per_lane(inv, lo, hi))
+        for j, col in enumerate(scale):
+            put_scale(j, col)
+        return None
+
+    tile_rows = s_ref.shape[2]
+    if rows_minor:
+        walk, steps = column, tile_rows // _LANES
+    else:
+        walk, steps = slab, tile_rows // _SLAB
+        if rows % tile_rows:  # the last tile holds fewer rows than a tile
+            steps = jnp.minimum(
+                steps, rows // _SLAB - pl.program_id(1) * steps
+            )
+
+    @pl.when(gate != 0.0)
+    def _step():
+        jax.lax.fori_loop(0, steps, walk, None)
+
+
+def _adam_leaf_call(scal, p, g, q, s, v, comp, *, run, static, interpret):
+    """The kernel over ONE device's arrays of a leaf."""
+    shape = p.shape
+    rows, width = shape[-2:]
+    lead = math.prod(shape[:-2])
+    nruns = width // run
+    tile_rows = _tile_rows(rows, width)
+    rows_minor = _rows_minor(rows, width, run)
+    if rows_minor:  # the same memory, seen [width, rows]
+        as_tiles = lambda a: jnp.swapaxes(a.reshape(lead, rows, width), 1, 2)
+        tile = pl.BlockSpec((1, width, tile_rows), lambda l, r: (l, 0, r))
+        parked = (run, _LANES)
+    else:
+        as_tiles = lambda a: a.reshape(lead, rows, width)
+        tile = pl.BlockSpec((1, tile_rows, width), lambda l, r: (l, r, 0))
+        parked = (_SLAB, width)
+    s_tile = pl.BlockSpec((1, nruns, tile_rows), lambda l, r: (l, 0, r))
+    comped = comp is not None
+    # what the update rewrites, each array aliased to its own output
+    stored = [
+        as_tiles(p), as_tiles(q), s.reshape(lead, nruns, rows), as_tiles(v)
+    ]
+    stored_specs = [tile, tile, s_tile, tile]
+    if comped:
+        stored.append(as_tiles(comp))
+        stored_specs.append(tile)
+    out = pl.pallas_call(
+        functools.partial(
+            _adam_leaf_kernel, comped=comped, run=run, rows=rows,
+            width=width, p_dtype=p.dtype, static=static,
+            rows_minor=rows_minor,
+        ),
+        grid=(lead, pl.cdiv(rows, tile_rows)),
+        in_specs=[pl.BlockSpec(memory_space=_smem()), tile] + stored_specs,
+        out_specs=stored_specs,
+        out_shape=[jax.ShapeDtypeStruct(a.shape, a.dtype) for a in stored],
+        input_output_aliases={2 + i: i for i in range(len(stored))},
+        scratch_shapes=[pltpu.VMEM(parked, jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=_VMEM_LIMIT,
+        ),
+        interpret=interpret,
+        name="adam_leaf_update",
+    )(scal, as_tiles(g), *stored)
+    if rows_minor:
+        back = lambda a: jnp.swapaxes(a, 1, 2).reshape(shape)
+    else:
+        back = lambda a: a.reshape(shape)
+    p_new, q_new, s_new, v_new = out[:4]
+    return (
+        back(p_new), back(q_new), s_new.reshape(s.shape), back(v_new),
+        back(out[4]) if comped else None,
+    )
+
+
+def adam_leaf_update(
+    p, g, m_st, v_st, comp, *, run, lr, b1, c1, c2, grad_scale=None,
+    gate=None, mesh=None, spec=None, interpret=None, **static,
+):
+    """Adam on one leaf whose first moment is stored int8 per run: returns
+    ``(p, mu, nu, comp)`` as ``Adam.apply``'s plain leaf update does, the
+    stored arrays updated in place. ``run`` is ``adam_kernel_run``'s word
+    that the leaf may come here. With a ``mesh`` the kernel runs on each shard of
+    ``spec`` (the state's layout; a replicated parameter is sliced, not
+    moved)."""
+    if interpret is None:
+        interpret = not device.on_tpu()
+    scal = jnp.stack([
+        _f32(lr), _f32(b1), _f32(c1), _f32(c2),
+        _f32(1.0 if grad_scale is None else grad_scale),
+        _f32(1.0 if gate is None else gate),
+    ])
+    call = functools.partial(
+        _adam_leaf_call, run=run, static=static, interpret=interpret
+    )
+    args = (scal, p, g, m_st["q"], m_st["scale"], v_st, comp)
+    if mesh is not None:
+        from jax.sharding import PartitionSpec
+
+        s_spec = quant.scale_spec(spec, p.shape, mesh.shape)
+        c_spec = None if comp is None else spec
+        call = jax.shard_map(
+            call, mesh=mesh,
+            in_specs=(PartitionSpec(), spec, spec, spec, s_spec, spec, c_spec),
+            out_specs=(spec, spec, s_spec, spec, c_spec),
+            check_vma=False,
+        )
+    p_new, q_new, s_new, v_new, comp_new = call(*args)
+    return p_new, {"q": q_new, "scale": s_new}, v_new, comp_new

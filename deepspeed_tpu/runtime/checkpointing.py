@@ -108,39 +108,62 @@ def _read_blob(res, path):
         return f.read()
 
 
-def _normalize_quant_padding(saved_tree, template_tree):
-    """Resize blockwise-quantized ``{'q','scale'}`` leaves to the engine
-    template's (padded) lengths.
+_FLAT_BLOCK = 2048  # elements per scale in the flat format of before PR 27
 
-    The ZeRO pad multiple for quantized state is a policy constant
-    (max(256, dp), runtime/engine.py) — but checkpoints from other
-    policies must still load: pre-padding saves (nb = ceil(n/BLOCK)),
-    future policy changes, or >256-dp pods. The padded tail decodes to
-    zero and never receives updates, so extending with zeros or dropping
-    tail blocks is lossless."""
+
+def _flat_moment_twin(template_tree):
+    """``template_tree`` as a checkpoint written before PR 27 holds it:
+    every first-moment leaf of an int8 engine a flat ``{'q','scale'}``
+    pair (placeholders: only the structure is read)."""
     from ..ops.quant import is_quantized
+
+    def twin(node):
+        if isinstance(node, dict) and "inner" in node:
+            return {**node, "inner": twin(node["inner"])}
+        return {
+            **node,
+            "mu": jax.tree_util.tree_map(
+                lambda m: {"q": 0, "scale": 0}, node["mu"],
+                is_leaf=is_quantized,
+            ),
+        }
+
+    return twin(template_tree)
+
+
+def _moments_to_template(saved_tree, template_tree):
+    """Bring saved int8 moments into the engine template's format.
+
+    Until PR 27 a quantized moment was stored FLAT over the flattened
+    parameter: ``q`` int8[nb * 2048] and ``scale`` f32[nb], the block count
+    padded to a policy's multiple, a zero tail behind the data. Today's
+    leaf has the parameter's shape (ops/quant.py) or, for a leaf with no
+    run to quantize over, is a bf16 array. A flat pair is decoded to
+    float32, cut to the parameter's size and encoded again as the
+    template stores it; leaves already in that format pass through."""
+    import jax.numpy as jnp
+
+    from ..ops.quant import encode_moment, is_quantized
 
     if saved_tree is None:
         return None
 
     def fit(saved, tmpl):
-        if not (is_quantized(tmpl) and isinstance(saved, dict)):
+        if not (isinstance(saved, dict) and set(saved) == {"q", "scale"}):
             return saved
-        out = {}
-        for k in ("q", "scale"):
-            s = np.asarray(saved[k])
-            want = tmpl[k].shape[0]
-            if s.shape[0] < want:
-                s = np.concatenate(
-                    [s, np.zeros((want - s.shape[0],), s.dtype)]
-                )
-            elif s.shape[0] > want:
-                s = s[:want]
-            out[k] = s
-        return out
+        q, scale = np.asarray(saved["q"]), np.asarray(saved["scale"])
+        shape = tuple(tmpl["q"].shape if is_quantized(tmpl) else tmpl.shape)
+        if is_quantized(tmpl) and q.shape == shape:
+            return saved
+        value = q.reshape(-1, _FLAT_BLOCK).astype(np.float32) * scale[:, None]
+        value = value.reshape(-1)[: int(np.prod(shape))].reshape(shape)
+        return jax.tree_util.tree_map(
+            np.asarray, encode_moment(jnp.asarray(value), tmpl)
+        )
 
     return jax.tree_util.tree_map(
-        fit, saved_tree, template_tree, is_leaf=is_quantized
+        fit, saved_tree, template_tree,
+        is_leaf=lambda x: isinstance(x, dict) and set(x) == {"q", "scale"},
     )
 
 
@@ -539,8 +562,6 @@ def _apply_checkpoint(
             "master": jax.tree_util.tree_map(np.asarray, engine.params),
             "inner": inner_template,
         }
-        can_leaves, can_treedef = _flatten(canonical_template)
-        n_inner = len(jax.tree_util.tree_leaves(inner_template))
         canonical = None
         shards = staged.shards
         if shards:
@@ -556,31 +577,46 @@ def _apply_checkpoint(
                     return np.concatenate(pieces, axis=ax)
                 return np.asarray(shards[0]["leaves"][str(i)])
 
-            if n_saved == len(can_leaves):
-                canonical = jax.tree_util.tree_unflatten(
-                    can_treedef, [merge(i) for i in range(n_saved)]
+            # the moments' format of before PR 27 has another leaf count
+            # wherever a leaf now keeps a bf16 moment: tried second
+            templates = [canonical_template]
+            if "mu" in inner_template:
+                templates.append(_flat_moment_twin(canonical_template))
+            structures = [
+                (
+                    jax.tree_util.tree_structure(template),
+                    jax.tree_util.tree_structure(template["inner"]),
                 )
-                master_restored = True
-            elif n_saved == n_inner:
-                # legacy layout: bare inner tree, no master partition —
-                # restore moments, master re-derives from module weights
-                inner_flat, inner_def = _flatten(inner_template)
-                del inner_flat
-                canonical = {
-                    "master": None,
-                    "inner": jax.tree_util.tree_unflatten(
-                        inner_def, [merge(i) for i in range(n_saved)]
-                    ),
-                }
-            else:
+                for template in templates
+            ]
+            match = next(
+                (pair for pair in structures
+                 if n_saved in (pair[0].num_leaves, pair[1].num_leaves)),
+                None,
+            )
+            if match is not None:
+                whole, inner = match
+                merged = [merge(i) for i in range(n_saved)]
+                if n_saved == whole.num_leaves:
+                    canonical = jax.tree_util.tree_unflatten(whole, merged)
+                    master_restored = True
+                else:
+                    # legacy layout: bare inner tree, no master partition —
+                    # restore moments, master re-derives from module weights
+                    canonical = {
+                        "master": None,
+                        "inner": jax.tree_util.tree_unflatten(inner, merged),
+                    }
+            if canonical is None:
                 log_dist(
                     f"optimizer checkpoint has {n_saved} leaves; engine "
-                    f"expects {len(can_leaves)} (or legacy {n_inner}) — "
+                    f"expects {structures[0][0].num_leaves} (or legacy "
+                    f"{structures[0][1].num_leaves}) — "
                     "skipping optimizer restore",
                     ranks=[0],
                 )
         if canonical is not None:
-            canonical["inner"] = _normalize_quant_padding(
+            canonical["inner"] = _moments_to_template(
                 canonical["inner"], inner_template
             )
             if engine.master_in_opt:

@@ -326,10 +326,11 @@ class DeepSpeedEngine:
             int(np.prod(p.shape))
             for p in jax.tree_util.tree_leaves(params_f32)
         )
-        # int8 moments store FLAT dp-sharded {'q','scale'} leaves: leading-
-        # dim specs keep the flat<->shaped reshapes in the update layout-
-        # trivial (zero.py module docstring); fp32/bf16 state keeps the
-        # largest-dim layout of the measured AOT memory proofs
+        # int8 moments are stored per run of the MINOR axis and updated by
+        # a kernel that works on whole rows of a shard: leading-dim specs
+        # keep runs and rows whole (zero.py module docstring); fp32/bf16
+        # state keeps the largest-dim layout of the measured AOT memory
+        # proofs
         prefer_leading = self.config.optimizer_state_dtype == "int8"
         self._param_specs = zero_lib.zero_param_specs(
             params_f32, dp_size, stage, model_specs=self._model_specs,
@@ -343,6 +344,7 @@ class DeepSpeedEngine:
             params_f32, dp_size, stage, model_specs=self._model_specs,
             prefer_leading=prefer_leading,
         )
+        self._optstate_param_specs = optstate_param_specs
         self._param_shardings = zero_lib.specs_to_shardings(
             self._param_specs, self._mesh
         )
@@ -454,7 +456,7 @@ class DeepSpeedEngine:
             inner_shardings = zero_lib.specs_to_shardings(
                 zero_lib.optstate_specs_like(
                     inner_state, optstate_param_specs, params_f32,
-                    dp_size=dp_size,
+                    axis_sizes=dict(self._mesh.shape),
                 ),
                 self._mesh,
             )
@@ -1048,7 +1050,6 @@ class DeepSpeedEngine:
                 type(self.client_optimizer).__name__.lower()
             )
             log_dist("Using client optimizer", ranks=[0])
-            self._apply_zero_state_policies(self.client_optimizer)
             return self.client_optimizer
         name = self.config.optimizer_name
         if name is None:
@@ -1088,40 +1089,7 @@ class DeepSpeedEngine:
                 "(ops/quant.py)",
                 ranks=[0],
             )
-        self._apply_zero_state_policies(opt)
         return opt
-
-    def _apply_zero_state_policies(self, opt):
-        """Per-optimizer adjustments a ZeRO-sharded mesh requires; applied
-        to BUILT and CLIENT optimizers alike (a client-supplied
-        Adam(state_dtype='int8') must not keep single-chip chunking).
-
-        - int8 moments: pad the quantized block count to the dp-INDEPENDENT
-          multiple max(256, dp) so the flat {'q','scale'} leaves split
-          evenly over the data axis (optstate_specs_like shards them) while
-          elastic dp-resize resume keeps working — padding to dp itself
-          would bake the saving mesh's size into the stored shapes (a dp4
-          checkpoint could not deserialize into a dp8 engine's template).
-          256 covers every power-of-two dp <= 256 at < 0.5 MB per leaf.
-        - chunked leaf updates OFF: chunking is a single-chip memory
-          measure; per-device working sets are already divided by dp, and
-          splitting a dp-sharded flat quantized leaf for the chunk scan
-          forces GSPMD to gather it (+12.5 GB of temps at 1.5B dp8 in the
-          AOT proof; ops/optimizers.py:_chunked_leaf_update)."""
-        if self.zero_stage < 1 or self.dp_world_size <= 1:
-            return
-        if getattr(opt, "state_dtype", "fp32") == "int8" and hasattr(
-            opt, "state_pad_blocks"
-        ):
-            pad = max(256, self.dp_world_size)
-            opt.state_pad_blocks = pad
-            log_dist(
-                "int8 optimizer moments shard over the data axis "
-                f"(flat layout, blocks padded to a multiple of {pad})",
-                ranks=[0],
-            )
-        if hasattr(opt, "chunk_elements"):
-            opt.chunk_elements = 1 << 62
 
     def _place_scaler(self, state):
         """The loss-scale state, replicated over the mesh. Every step
@@ -1318,8 +1286,18 @@ class DeepSpeedEngine:
                 ranks=[0],
             )
 
+        # where the update runs, for optimizers that hand leaves to a Pallas
+        # kernel: per shard of the state's own layout on a mesh of several
+        # devices (GSPMD cannot partition a pallas_call), and not at all in
+        # the host-side offload step
+        placed = getattr(optimizer, "supports_placement", False)
+        update_shard = (
+            (self._mesh, self._optstate_param_specs)
+            if self._mesh.size > 1 else None
+        )
+
         def cond_update(params, opt_state, grads, raw_norm, overflow,
-                        inv_scale, lr, mom, layout):
+                        inv_scale, lr, mom, layout, on_host=False):
             """Shared overflow-gated update core: unscale+clip as one
             scalar grad_scale into the optimizer; layout 'master' steps
             opt_state['master'] and publishes compute-dtype params,
@@ -1344,6 +1322,9 @@ class DeepSpeedEngine:
                 opt_kw = {} if gate is None else {"gate": gate}
                 if use_mom:
                     opt_kw["mom"] = mom
+                if placed:
+                    opt_kw["kernel"] = not on_host
+                    opt_kw["shard"] = None if on_host else update_shard
                 if layout == "master":
                     # step the fp32 master, then publish the compute-dtype
                     # params — the reference's fp32-partition step + fp16
@@ -1452,7 +1433,7 @@ class DeepSpeedEngine:
                     new_params, new_opt, grad_norm, coeffs = cond_update(
                         params_like, {"master": master, "inner": inner},
                         grads, raw_norm, overflow, inv_scale, lr, mom,
-                        "master",
+                        "master", on_host=True,
                     )
                 new_scaler = update_scale(scaler_state, overflow)
                 return (

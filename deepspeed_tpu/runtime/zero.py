@@ -23,16 +23,18 @@ collapses into sharding declarations and XLA-inserted collectives:
 Per-leaf partitioning rule: shard the largest unsharded dimension divisible
 by the data-axis size; leaves with no divisible dimension stay replicated
 (the reference's analogous edge case is `zero_empty_partition` — more ranks
-than elements — tested in tests/unit/test_fp16.py). Engines with FLAT
-blockwise-quantized moment storage ({'q','scale'} int8 leaves, ops/quant.py)
-instead prefer the EARLIEST divisible dimension (``prefer_leading=True``):
-each shard is then a CONTIGUOUS row-major block, so the reshape between the
-flat dp-sharded storage and its shaped fp32 working value is layout-trivial
-— with the largest-dim rule the dryrun's dp2xsp2xmp2 update step hit XLA
-"Involuntary full rematerialization" warnings (spmd_partitioner.cc) on
-exactly those reshapes, replicating the tensor mid-update. Either way no
-individual tensor is flattened-and-split, which would fight XLA's tiled
-memory format.
+than elements — tested in tests/unit/test_fp16.py). Engines with int8
+moment storage ({'q','scale'} leaves, ops/quant.py) instead prefer the
+EARLIEST divisible dimension (``prefer_leading=True``). Since PR 27 that
+storage is no longer flat: ``q`` has the parameter's shape and takes the
+spec the second moment has, ``scale`` holds one value per run of the minor
+axis and per row and follows it (``ops.quant.scale_spec``). A shard cut
+along a leading dimension keeps every run and every row whole, so the
+update kernel (ops/pallas.py:adam_leaf_update, per shard under
+``shard_map``) sees its own leaf in small; a cut through the minor axis is
+taken only where whole runs fall to each shard, and the leaf falls back to
+the plain XLA update otherwise. No individual tensor is flattened-and-
+split, which would fight XLA's tiled memory format.
 """
 
 import jax
@@ -83,11 +85,11 @@ def leaf_partition_spec(shape, dp_size, axis_name=C.DATA_AXIS, existing_spec=Non
     placing the data axis on a currently-unsharded dimension.
 
     ``prefer_leading=True`` picks the EARLIEST divisible dimension instead
-    of the largest: shards become contiguous row-major blocks, which makes
-    the flat<->shaped reshapes of blockwise-quantized moment storage
-    layout-trivial (see module docstring). Engines enable it exactly when
-    such flat state exists; the fp32-state layout (largest dim) keeps the
-    measured single/multi-chip memory profile of the AOT proofs.
+    of the largest: shards become contiguous row-major blocks that keep
+    the rows and the quantization runs of int8 moment storage whole (see
+    module docstring). Engines enable it exactly when such state exists;
+    the fp32-state layout (largest dim) keeps the measured single/multi-
+    chip memory profile of the AOT proofs.
     """
     existing = tuple(existing_spec) if existing_spec is not None else ()
     existing = existing + (None,) * (len(shape) - len(existing))
@@ -199,9 +201,7 @@ def constrain(tree, specs):
     )
 
 
-def optstate_specs_like(
-    opt_state, param_specs, params, dp_size=1, data_axis=C.DATA_AXIS
-):
+def optstate_specs_like(opt_state, param_specs, params, axis_sizes=None):
     """Map param specs onto an optax-style optimizer state pytree.
 
     Optimizer moments (``mu``/``nu``/master copies) are pytrees with the
@@ -213,11 +213,11 @@ def optstate_specs_like(
     layouts — the reference keeps optimizer state strictly per-param too
     (deepspeed/pt/deepspeed_zero_optimizer.py:256-263).
 
-    Blockwise-quantized moments (``{'q','scale'}`` flat leaves, ops/quant)
-    shard over the data axis on their single flat dimension when
-    ``dp_size`` divides them (the engine pads the block count so it does);
-    block boundaries align with shard boundaries, keeping the decode
-    shard-local in memory.
+    Quantized moments (``{'q','scale'}`` leaves, ops/quant): ``q`` has its
+    parameter's shape and takes its spec; ``scale`` takes the spec
+    ``ops.quant.scale_spec`` derives from it, given the mesh's
+    ``axis_sizes`` (``dict(mesh.shape)``; without them a cut through the
+    minor axis leaves the scales whole).
 
     A shape-based fallback is used only when it is unambiguous (every param
     of that shape shares one spec); anything else is replicated.
@@ -234,49 +234,23 @@ def optstate_specs_like(
     for shape, s in param_paths.values():
         shape_to_specs.setdefault(shape, set()).add(s)
 
-    # do any params shard over the data axis at all? (stage >= 1 signal —
-    # quantized leaves should only dp-shard when the param specs do)
-    any_dp_sharded = any(
-        any(
-            data_axis == e or (isinstance(e, tuple) and data_axis in e)
-            for e in s
-        )
-        for _, s in param_paths.values()
-    )
-
     def spec_for(path, leaf):
         shape = tuple(getattr(leaf, "shape", ()))
         toks = tuple(_key_token(k) for k in path)
-        if (
-            dp_size > 1
-            and any_dp_sharded
-            and len(toks) >= 2
-            and toks[-1] in ("q", "scale")
-            and len(shape) == 1
-        ):
-            # quantized flat leaf: the PARENT path (without 'q'/'scale')
+        if len(toks) >= 2 and toks[-1] in ("q", "scale"):
+            # quantized leaf: the PARENT path (without 'q'/'scale')
             # suffix-matches a param the usual way. (A real param that
             # happens to be NAMED 'q' never lands here: its parent prefix
             # is a subtree, not a param path, so this falls through to
             # the normal shape-checked matching below.)
             for i in range(len(toks) - 1):
                 hit = param_paths.get(toks[i:-1])
-                if hit is not None:
-                    # shard only when the BLOCK COUNT divides dp (true for
-                    # engine-padded state): q then splits on quant-block
-                    # boundaries and scale splits alongside. An unpadded
-                    # client leaf (nb % dp != 0) replicates BOTH leaves —
-                    # never q-sharded with a replicated scale, which would
-                    # put shard boundaries mid-block and force cross-shard
-                    # gathers on every decode.
-                    nb = shape[0] if toks[-1] == "scale" else None
-                    if toks[-1] == "q":
-                        from ..ops.quant import BLOCK
+                if hit is not None and toks[-1] == "q" and hit[0] == shape:
+                    return hit[1]
+                if hit is not None and toks[-1] == "scale":
+                    from ..ops.quant import scale_spec
 
-                        nb = shape[0] // BLOCK
-                    if nb is not None and nb % dp_size == 0:
-                        return PartitionSpec(data_axis)
-                    return PartitionSpec()
+                    return scale_spec(hit[1], hit[0], axis_sizes or {})
         for i in range(len(toks)):  # longest suffix first
             hit = param_paths.get(toks[i:])
             if hit is not None and hit[0] == shape:

@@ -191,19 +191,17 @@ def test_gpt2_1_5b_int8_state_shards_over_dp():
     bf16_params_shape = jax.tree_util.tree_map(
         lambda l: jax.ShapeDtypeStruct(l.shape, jnp.bfloat16), params_shape
     )
-    # mirror the engine's ZeRO settings (runtime/engine.py): dp-independent
-    # pad multiple, chunking disabled (it is a single-chip measure; under
-    # sharding the chunk scan would force GSPMD to gather the flat leaves)
-    opt = Adam(
-        state_dtype="int8", state_pad_blocks=max(256, dp),
-        master_compensation=True, chunk_elements=1 << 62,
-    )
+    # mirror the engine's ZeRO settings (runtime/engine.py): leading-dim
+    # specs for int8 state, the update told the mesh and the state's specs
+    # so that the kernel runs per shard
+    opt = Adam(state_dtype="int8", master_compensation=True)
     inner_shape = jax.eval_shape(opt.init, bf16_params_shape)
     optstate_param_specs = zero_lib.zero_optstate_specs(
-        params_shape, dp, stage
+        params_shape, dp, stage, prefer_leading=True
     )
     inner_specs = zero_lib.optstate_specs_like(
-        inner_shape, optstate_param_specs, params_shape, dp_size=dp
+        inner_shape, optstate_param_specs, params_shape,
+        axis_sizes=dict(mesh.shape),
     )
     # every quantized leaf's q AND scale shard over the data axis
     flat = jax.tree_util.tree_leaves_with_path(
@@ -219,17 +217,21 @@ def test_gpt2_1_5b_int8_state_shards_over_dp():
             continue
         pq = spec_by_path[tuple(str(k) for k in path) + ("['q']",)]
         ps = spec_by_path[tuple(str(k) for k in path) + ("['scale']",)]
-        assert pq == P("data"), (path, pq)
-        assert ps == P("data"), (path, ps)
+        assert zero_lib.has_axis(pq, "data"), (path, pq)
+        assert zero_lib.has_axis(ps, "data"), (path, ps)
         nq += 1
     assert nq > 0
 
     inner_sh = zero_lib.specs_to_shardings(inner_specs, mesh)
     param_sh = zero_lib.specs_to_shardings(
-        zero_lib.zero_param_specs(params_shape, dp, stage), mesh
+        zero_lib.zero_param_specs(
+            params_shape, dp, stage, prefer_leading=True
+        ), mesh
     )
     grad_sh = zero_lib.specs_to_shardings(
-        zero_lib.zero_grad_specs(params_shape, dp, stage), mesh
+        zero_lib.zero_grad_specs(
+            params_shape, dp, stage, prefer_leading=True
+        ), mesh
     )
     data_sh = NamedSharding(mesh, P("data", None))
 
@@ -242,7 +244,9 @@ def test_gpt2_1_5b_int8_state_shards_over_dp():
             lambda g, s: jax.lax.with_sharding_constraint(g, s),
             grads, grad_sh,
         )
-        new_params, new_inner, _ = opt.apply(params, grads, inner, 1e-4)
+        new_params, new_inner, _ = opt.apply(
+            params, grads, inner, 1e-4, shard=(mesh, optstate_param_specs)
+        )
         new_params = jax.tree_util.tree_map(
             lambda m, s: jax.lax.with_sharding_constraint(m, s),
             new_params, param_sh,

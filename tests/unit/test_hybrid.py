@@ -369,8 +369,20 @@ def test_fused_window_through_initialize_with_int8_moments(weights, tmp_path):
     second = float(engine.train_batch(feed))
     assert np.isfinite(first) and np.isfinite(second)
     assert abs(first - np.log(512)) < 0.2
-    mu = engine.optimizer_state["mu"]["model"]["moe_w1"]
-    assert mu["q"].dtype == jnp.int8
+    # int8 per run of the minor axis where a leaf has a run (ops/quant.py:
+    # a width of 128 or more), bf16 where it has none
+    from deepspeed_tpu.ops import quant
+
+    stored = {
+        k: (m["q"].dtype if quant.is_quantized(m) else m.dtype,
+            quant.quantized_run(engine.params["model"][k].shape))
+        for k, m in engine.optimizer_state["mu"]["model"].items()
+    }
+    assert all(
+        dtype == (jnp.int8 if run else jnp.bfloat16)
+        for dtype, run in stored.values()
+    ), stored
+    assert any(run for _, run in stored.values()), stored
     counters = engine.last_aux[0]
     assert counters["moe/local_assignments"].shape == (2,)     # [accum]
     reg = engine.telemetry.registry

@@ -97,43 +97,39 @@ def test_stage3_composes_with_model_parallel_free_dim_only(dp):
 
 
 @pytest.mark.parametrize("dp", [2, 4, 8])
-def test_quantized_optstate_never_shards_mid_block(dp):
-    from deepspeed_tpu.ops.quant import BLOCK
+def test_quantized_optstate_never_shards_mid_run(dp):
+    """``q`` shards like its parameter; ``scale`` ([runs, rows]) follows on
+    its rows, and on its runs only where each shard holds whole runs."""
+    from deepspeed_tpu.ops.quant import quantized_zeros_like
 
-    params = {"w": jnp.zeros((16, 64), jnp.float32)}
-    pspecs = zero_lib.zero_optstate_specs(params, dp, stage=1)
-    # engine-padded layout: block count divides dp -> flat dp shard on
-    # BLOCK boundaries
-    nb_ok = 8
-    state = {
-        "mu": {
-            "w": {
-                "q": jnp.zeros((nb_ok * BLOCK,), jnp.int8),
-                "scale": jnp.zeros((nb_ok,), jnp.float32),
-            }
-        }
+    params = {
+        "rows": jnp.zeros((16, 4096), jnp.float32),   # 2 runs of 2,048 a row
+        "width": jnp.zeros((3, 16384), jnp.float32),  # only the width divides
     }
+    pspecs = zero_lib.zero_optstate_specs(
+        params, dp, stage=1, prefer_leading=True
+    )
+    assert pspecs["rows"] == P(C.DATA_AXIS, None)
+    assert pspecs["width"] == P(None, C.DATA_AXIS)
+    state = {"mu": {k: quantized_zeros_like(p) for k, p in params.items()}}
     ospecs = zero_lib.optstate_specs_like(
-        state, pspecs, params, dp_size=dp
+        state, pspecs, params, axis_sizes={C.DATA_AXIS: dp}
     )
-    assert ospecs["mu"]["w"]["q"] == P(C.DATA_AXIS)
-    assert ospecs["mu"]["w"]["scale"] == P(C.DATA_AXIS)
-    # unpadded client leaf: nb % dp != 0 -> BOTH leaves replicate (a
-    # q-shard boundary mid-block would force cross-shard gathers)
-    nb_bad = dp + 1
-    state_bad = {
-        "mu": {
-            "w": {
-                "q": jnp.zeros((nb_bad * BLOCK,), jnp.int8),
-                "scale": jnp.zeros((nb_bad,), jnp.float32),
-            }
-        }
-    }
-    ospecs_bad = zero_lib.optstate_specs_like(
-        state_bad, pspecs, params, dp_size=dp
-    )
-    assert ospecs_bad["mu"]["w"]["q"] == P()
-    assert ospecs_bad["mu"]["w"]["scale"] == P()
+    assert ospecs["mu"]["rows"]["q"] == pspecs["rows"]
+    assert ospecs["mu"]["rows"]["scale"] == P(None, C.DATA_AXIS)
+    # 8 runs of 2,048: dp 2, 4, 8 all leave whole runs to a shard
+    assert ospecs["mu"]["width"]["q"] == pspecs["width"]
+    assert ospecs["mu"]["width"]["scale"] == P(C.DATA_AXIS, None)
+    # a width whose runs do not split dp ways: the scales stay whole (and
+    # the leaf takes the plain update) rather than a shard cutting a run
+    odd = {"w": jnp.zeros((3, 2048 * 3), jnp.float32)}
+    odd_specs = {"w": P(None, C.DATA_AXIS)}
+    odd_state = {"mu": {"w": quantized_zeros_like(odd["w"])}}
+    got = zero_lib.optstate_specs_like(
+        odd_state, odd_specs, odd, axis_sizes={C.DATA_AXIS: dp}
+    )["mu"]["w"]
+    assert got["q"] == odd_specs["w"]
+    assert got["scale"] == P(None, None)
 
 
 def test_gathered_spec_strips_only_data_axis():

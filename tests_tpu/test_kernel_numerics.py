@@ -310,6 +310,92 @@ def _decode_exact(q, kp, vp, tables, positions):
 
 
 @pytest.mark.parametrize(
+    "shape",
+    [(36, 1280, 5120), (6, 1600, 6400), (6, 6400, 1600), (4, 1600, 1600),
+     (12576, 1280), (50304, 1600)],
+    ids=lambda s: "x".join(map(str, s)),
+)
+def test_adam_leaf_update_matches_plain_at_the_cells_shape(shape):
+    """The one-pass kernel of the reduced-state Adam update (ops/pallas.py:
+    adam_leaf_update, PR 27) compiled by Mosaic, against the plain XLA
+    update over the same ``adam_core``, on GPT-2 large's largest leaf
+    [36, 1280, 5120] under the cells' recipe (bf16 parameters + int8
+    compensation, int8 first moment in runs of 1,280, bf16 second moment),
+    from a state with one step behind it: masters to one compensation code,
+    scales to float32 rounding, codes to one rounding tie; then a closed
+    gate rewrites every stored byte unchanged. Then the shapes whose rows
+    are no multiple of 128 (a ragged last tile): GPT-2 1.5B's 1,600-row
+    stacks, the 12,576 rows a chip of GPT-2 large's token table under
+    dp 4; and 1.5B's 1,600-WIDE stacks and token table, which the chip
+    stores rows-minor and the kernel takes transposed."""
+    from deepspeed_tpu.ops import pallas as kernels
+    from deepspeed_tpu.ops import quant
+    from deepspeed_tpu.ops.optimizers import Adam
+
+    draw = lambda seed, scale: {"w": (
+        jax.random.normal(jax.random.PRNGKey(seed), shape, jnp.float32) * scale
+    ).astype(jnp.bfloat16)}
+    opt = Adam(state_dtype="int8", master_compensation=True)
+    kw = dict(grad_scale=jnp.float32(0.5), mom=jnp.float32(0.9))
+
+    def step(kernel):
+        return jax.jit(lambda p, g, s, gate: opt.apply(
+            p, g, s, jnp.float32(1e-3), gate=gate, kernel=kernel, **kw
+        )[:2])
+
+    params = draw(0, 0.02)
+    state = opt.init(params)
+    assert kernels.adam_kernel_run(
+        params["w"], state["mu"]["w"], state["nu"]["w"]
+    ) == quant.run_length(shape[-1])
+    params, state = step(False)(params, draw(1, 1e-2), state, jnp.bool_(True))
+    grads = draw(2, 1e-2)
+    p1, s1 = step(True)(params, grads, state, jnp.bool_(True))
+    p2, s2 = step(False)(params, grads, state, jnp.bool_(True))
+
+    @jax.jit
+    def gaps(p0, s0, p1, s1, p2, s2):
+        m0 = quant.decode_master(p0["w"], s0["comp"]["w"])
+        m1 = quant.decode_master(p1["w"], s1["comp"]["w"])
+        m2 = quant.decode_master(p2["w"], s2["comp"]["w"])
+        codes = jnp.abs(
+            s1["mu"]["w"]["q"].astype(jnp.int32)
+            - s2["mu"]["w"]["q"].astype(jnp.int32)
+        )
+        scale = s2["mu"]["w"]["scale"]
+        nu1 = s1["nu"]["w"].astype(jnp.float32)
+        nu2 = s2["nu"]["w"].astype(jnp.float32)
+        return {
+            # beyond one compensation code (2^-8 / 127 of the master)
+            "master": jnp.max(jnp.abs(m1 - m2) - 4e-5 * jnp.abs(m2)),
+            "moved": jnp.mean(jnp.abs(m2 - m0) > 0),
+            "codes_max": jnp.max(codes),
+            "codes_share": jnp.mean(codes > 0),
+            "scale": jnp.max(
+                jnp.abs(s1["mu"]["w"]["scale"] - scale) / (scale + 1e-30)
+            ),
+            "nu": jnp.max(jnp.abs(nu1 - nu2) / (nu2 + 1e-30)),
+        }
+
+    got = {
+        k: float(v)
+        for k, v in gaps(params, state, p1, s1, p2, s2).items()
+    }
+    assert got["moved"] > 0.99, got  # the step is no no-op
+    assert got["master"] <= 1e-8, got  # float32 noise of a 1e-3 step
+    assert got["codes_max"] <= 1 and got["codes_share"] < 1e-3, got
+    assert got["scale"] <= 1e-5, got
+    assert got["nu"] <= 2 ** -7, got  # one bf16 step at a rounding tie
+
+    p3, s3 = step(True)(p1, grads, s1, jnp.bool_(False))
+    same = jax.jit(lambda a, b: jax.tree_util.tree_reduce(
+        jnp.logical_and,
+        jax.tree_util.tree_map(lambda x, y: jnp.all(x == y), a, b),
+    ))
+    assert bool(same((p1, s1), (p3, s3)))
+
+
+@pytest.mark.parametrize(
     "dtype,exact_tol,xla_tol",
     [(jnp.float32, 1e-4, 4e-2), (jnp.bfloat16, 2e-2, 4e-2)],
 )
