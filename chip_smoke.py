@@ -25,8 +25,8 @@ Phases (docs/TESTING.md "Running on the chip"):
 
   device  what jax sees, versions, compile-cache directory in force,
           host_ops.HAVE_NATIVE (the smoke must pass without the extension)
-  train   deepspeed_tpu.initialize() on GPT2Config.large, the r05 recipe
-          (bench.py:gpt2_attempt), micro 8 x seq 1024: forward/backward/
+  train   deepspeed_tpu.initialize() on GPT2Config.large, the recipe of
+          benchmark/configs/gpt2-large.json, micro 8 x seq 1024: forward/backward/
           step windows, then fused train_batch() windows, flash-vs-XLA
           twin loss, checkpoint -> fresh engine -> resume
   serve   deepspeed_tpu.init_inference() on the same shape, bf16, paged KV,
@@ -48,8 +48,9 @@ import sys
 import tempfile
 import time
 
-# bench.py's GPT2_POLICY (r05 recipe): keep the no-batch-dim matmul
-# outputs and the flash kernel's residuals, recompute the rest
+# the remat policy of benchmark/configs/gpt2-large.json: keep the
+# no-batch-dim matmul outputs and the flash kernel's residuals,
+# recompute the rest
 GPT2_POLICY = "dots_with_no_batch_dims_saveable+flash_out+flash_lse"
 
 # bf16 tolerances, stated once. The model's logits have std ~0.7 at this
@@ -127,8 +128,8 @@ def host_init(jax, size, seed):
 
 
 def train_config(micro, accum, stage, telemetry_dir, reduced_state=True):
-    """bench.py:gpt2_attempt's recipe (the one r05's 774M number came
-    through): bf16, Adam, int8 moments + compensated masters, bf16 grad
+    """The recipe of benchmark/configs/gpt2-large.json (kept in step by
+    hand, ROADMAP D16): bf16, Adam, int8 moments + compensated masters, bf16 grad
     accumulation, data_pipeline on. ``train_batch_size`` is left to the
     engine (micro x accum x dp)."""
     return {

@@ -88,9 +88,8 @@ def mha_reference(
 # PR 25 at 512 x 512 blocks 1.22 + 0.94 + 1.10 (16.6 TFLOP/s in the
 # forward), at 1024 x 1024 0.74 + 0.73 + 1.02; these kernels at 512 x 512
 # blocks (a 2 x 2 grid, fori_loop walk) 0.76 + 0.89 + 1.33, at 1024 x 1024
-# 0.43 + 0.48 + 0.60 (50 TFLOP/s in the forward). ops/autotune.py times
-# the choice on new hardware (the role of the reference's GEMM autotuner,
-# gemm_test.h).
+# 0.43 + 0.48 + 0.60 (50 TFLOP/s in the forward). These are the ceiling
+# pick_block starts from; block and sub-tiles follow from the shape.
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 1024
 

@@ -5,8 +5,8 @@ Every restart of a process — including the preemption restarts the
 resilience subsystem makes survivable (docs/resilience.md) — pays full
 XLA recompiles unless ``jax_compilation_cache_dir`` is armed
 (``chip_smoke.py`` prints compile seconds per program, cold and warm).
-This module is the one shared path, so library users, bench.py, the
-chip smoke and the CI smoke run exercise identical code:
+This module is the one shared path, so library users, the benchmark,
+the chip smoke and the tests exercise identical code:
 
     {"compile_cache": {"enabled": true,
                        "cache_dir": "/var/cache/jax",

@@ -319,7 +319,7 @@ class DeepSpeedEngine:
                 lambda p: jnp.array(p, dtype=jnp.float32, copy=True),
                 model_parameters,
             )
-        # parameter count feeds telemetry's model-TFLOPS gauge (bench.py's
+        # parameter count feeds telemetry's model-TFLOPS gauge (the
         # 6*N-per-token accounting); a LoRA fine-tune still pushes every
         # token through the frozen base, so those params count too
         self._n_params = self._frozen_n_params + sum(

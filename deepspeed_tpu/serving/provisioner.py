@@ -22,7 +22,7 @@ is terminated whole.
 :class:`LocalSubprocessProvisioner` is the real implementation shipped
 here: it drives ``python -m deepspeed_tpu.serving.node`` subprocesses
 on this host — the single-machine form of a cloud instance pool, and
-exactly what the failover drills (``bench.py --smoke-node-failover``)
+exactly what the failover drills (``tests/drills/test_node_failover.py``)
 SIGKILL. The health-confirmed join is two gates: the node's one-line
 stdout ``listening`` announcement (printed only after every engine is
 built), then a live ``node_info`` round-trip over the control session —

@@ -214,7 +214,7 @@ PLACEMENT_POLICIES = {
 }
 
 
-# moved to telemetry/registry.py (bench.py --infer shares it); the old
+# moved to telemetry/registry.py; the old
 # name stays importable for existing callers
 _histogram_quantile = histogram_quantile
 
@@ -1873,7 +1873,7 @@ class FleetRouter:
     def refresh_telemetry(self):
         """Mirror per-replica snapshots and fleet aggregates onto the
         fleet/* streams (and export, when a telemetry sink is attached).
-        The monitor calls this on a cadence; tests and bench call it
+        The monitor calls this on a cadence; tests call it
         directly before asserting."""
         with self._refresh_lock:
             self._refresh_telemetry_locked()

@@ -65,8 +65,7 @@ ENGINE_METRICS = (
     ("gauge", "device/peak_bytes_in_use", "peak device HBM bytes in use"),
     # per-WINDOW HBM high-water (vs device/* above, which samples at the
     # export cadence): micro_batch headroom becomes visible in the
-    # trajectory instead of inferred from crash logs (bench.py records it
-    # into extras). 0 where the platform reports no memory stats (CPU).
+    # trajectory instead of inferred from crash logs. 0 where the platform reports no memory stats (CPU).
     ("gauge", "train/hbm_peak_bytes", "per-chip HBM high-water (device memory_stats peak) sampled at every window boundary; 0 when the platform reports none"),
     # ZeRO-3 layout gauges (docs/performance.md "ZeRO-3 & collective
     # overlap"): set once at engine init, 0 below stage 3
@@ -290,8 +289,8 @@ def hbm_peak_bytes():
     state on one chip shows it. None where the platform keeps no memory
     stats (the CPU backend answers None); a TPU that answers None is
     reported as an error, not as "no data". The single probe behind the
-    ``train/hbm_peak_bytes`` gauge and bench.py's per-attempt
-    ``hbm_peak_bytes`` extra."""
+    ``train/hbm_peak_bytes`` gauge and chip_smoke.py's ``hbm_peak_bytes``
+    field."""
     import jax
 
     peaks = []
@@ -580,7 +579,7 @@ class Telemetry:
                 reg.gauge("train/samples_per_sec").set(
                     self._samples_since_export / elapsed
                 )
-                # bench.py's model-flops accounting: 6*N per token
+                # model-flops accounting: 6*N per token
                 # (fwd 2N + bwd 4N), the measured-throughput MFU numerator
                 reg.gauge("train/model_tflops").set(
                     6.0 * self.n_params * tps / 1e12

@@ -142,8 +142,7 @@ def histogram_quantile(hist, q):
     """Linear-interpolated quantile from a fixed-bucket :class:`Histogram`
     (the Prometheus ``histogram_quantile`` estimate). 0.0 with no
     observations; observations in the +Inf bucket clamp to the last
-    finite edge. Shared by the fleet router's TTFT p50/p99 gauges and
-    ``bench.py --infer``'s p99 token latency."""
+    finite edge. Behind the fleet router's TTFT p50/p99 gauges."""
     counts = hist.bucket_counts
     total = sum(counts)
     if total == 0:

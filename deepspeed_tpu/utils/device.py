@@ -18,7 +18,7 @@ def on_tpu():
 
 def describe():
     """The accelerator as jax reports it — the three keys every result
-    line of bench.py and chip_smoke.py carries."""
+    line of benchmark/run.py and chip_smoke.py carries."""
     devices = jax.devices()
     return {
         "platform": devices[0].platform,

@@ -112,23 +112,6 @@ def test_dispatcher_falls_back_when_heads_do_not_divide(dp_mp_mesh):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
-def test_autotune_flash_blocks_smoke():
-    """gemm_test.h analog: sweeps candidates, returns a valid best pair."""
-    from deepspeed_tpu.ops.autotune import autotune_flash_blocks
-
-    (bq, bk), table = autotune_flash_blocks(
-        2, 2, 128, 64, causal=True, dtype=jnp.float32,
-        candidates=((64, 64), (128, 128)), steps=1,
-    )
-    assert (bq, bk) in table and len(table) == 2
-    # cached second call returns identical result without re-timing
-    again, _ = autotune_flash_blocks(
-        2, 2, 128, 64, causal=True, dtype=jnp.float32,
-        candidates=((64, 64), (128, 128)), steps=1,
-    )
-    assert again == (bq, bk)
-
-
 def test_pick_block_falls_back_to_dividing_block():
     from deepspeed_tpu.ops.attention import pick_block
 

@@ -337,6 +337,7 @@ def test_inference_telemetry_streams_populate_and_export(tmp_path):
     snap = engine.metrics.snapshot()
     engine.close()
     assert snap["infer/ttft_ms/count"] == 2
+    assert snap["infer/tokens_per_sec"] > 0
     assert snap["infer/token_latency_ms/count"] >= 7
     assert snap["infer/tokens_generated"] == 16
     assert snap["infer/requests_completed"] == 2

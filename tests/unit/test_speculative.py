@@ -62,7 +62,7 @@ def _agreeing_pair(seed=0, keep_layers=1):
     stream, so target logits equal draft logits while the target still
     pays full-depth compute. The high-acceptance regime with no
     training. The same construction (and the same residual-path key
-    set) lives in bench.py:_agreeing_draft_target — keep them in
+    set) lives in tests/drills/_common.py:agreeing_draft_target — keep them in
     sync."""
     cfg, model, params = _small_model(seed=seed)
     tparams = jax.tree_util.tree_map(np.asarray, params)
