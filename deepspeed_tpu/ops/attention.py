@@ -10,17 +10,23 @@ with an online softmax (flash attention), so there is **no sequence-length
 cap** (the reference hard-limits seq <= 1024,
 ds_transformer_cuda.cpp:133) and HBM traffic is O(S) instead of O(S^2).
 
-Three entry points:
+Entry points:
   - ``mha_reference``: plain XLA attention (always correct, differentiable
     through arbitrary additive masks; the numerics oracle and fallback).
-  - ``flash_attention``: custom-vjp Pallas forward/backward. Masking is a
-    compact per-key validity vector [B, Sk] (non-differentiable padding
-    semantics) — NOT a full [B,H,Sq,Sk] additive bias, which would
-    reintroduce the O(S^2) footprint the kernel exists to avoid.
-  - ``attention``: dispatcher. Padding-style additive masks (broadcast over
-    the query dim) are converted to validity vectors and sent to flash;
-    learned/general additive biases (q-dependent) go to the XLA path so
-    their gradients are exact.
+  - ``flash_attention``: custom-vjp Pallas forward/backward over q, k, v
+    [B, H, S, D]. Masking is a compact per-key validity vector [B, Sk]
+    (non-differentiable padding semantics) — NOT a full [B,H,Sq,Sk]
+    additive bias, which would reintroduce the O(S^2) footprint the kernel
+    exists to avoid.
+  - ``flash_attention_packed``: the same kernels over the fused qkv
+    projection's own result [B, S, 3*H*D]; the context comes back
+    [B, S, H*D] (``_Operands`` below).
+  - ``attention`` / ``attention_packed``: dispatchers for the two operand
+    forms. Padding-style additive masks (broadcast over the query dim) are
+    converted to validity vectors and sent to flash; learned/general
+    additive biases (q-dependent) go to the XLA path so their gradients
+    are exact. ``attention_layout`` chooses, and logs, which form the
+    kernels get.
 
 Dropout inside the kernel uses the TPU PRNG seeded per (batch*head,
 q granule, k granule) of 128 x 128 scores, so the backward pass regenerates
@@ -29,6 +35,7 @@ byte mask, dropout_kernels.cu; regeneration is the bandwidth-friendly TPU
 design).
 """
 
+import dataclasses
 import functools
 import math
 
@@ -352,13 +359,16 @@ def _dot_t(a_t, b, dtype):
 class _Tiles:
     """What the three kernels share: the grid position (a Python 0 on an
     axis of one block, so that every bound derived from it is static),
-    sub-tile counts, and the masking flags."""
+    sub-tile counts, the heads of one program's block, and the masking
+    flags."""
 
     def __init__(
         self, q_axis, *, sm_scale, causal, block_q, block_k, sub_q, sub_k,
-        nq, nk, diag_offset, dropout_rate, use_mask,
+        nq, nk, diag_offset, dropout_rate, use_mask, head_dim, heads_a_block,
+        use_bias,
     ):
-        self.bh = pl.program_id(0)
+        self.group = pl.program_id(0)
+        self.use_bias = use_bias
         self.iq = pl.program_id(q_axis) if nq > 1 else 0
         self.ik = pl.program_id(3 - q_axis) if nk > 1 else 0
         self.causal, self.diag_offset = causal, diag_offset
@@ -366,6 +376,7 @@ class _Tiles:
         self.sub_q, self.sub_k = sub_q, sub_k
         self.nsq, self.nsk = block_q // sub_q, block_k // sub_k
         self.nq, self.nk = nq, nk
+        self.head_dim, self.heads_a_block = head_dim, heads_a_block
         self.sm_scale = sm_scale
         self.fold_scale = _scale_is_exact(sm_scale)
         self.use_mask = use_mask
@@ -388,6 +399,26 @@ class _Tiles:
                 <= self.iq * block_q + (block_q - 1) + diag_offset
             )
 
+    def heads(self):
+        """``(hh, lanes)`` of each head a program serves: its place in the
+        block and the static lanes of the block that hold it (all of them
+        where a block is one head). The same slice picks the head's rows
+        out of a block TRANSPOSED into scratch."""
+        if self.heads_a_block == 1:
+            return [(0, slice(None))]
+        d = self.head_dim
+        return [
+            (hh, slice(hh * d, (hh + 1) * d))
+            for hh in range(self.heads_a_block)
+        ]
+
+    def load(self, ref, bias_ref, rows, lanes=slice(None)):
+        """``rows`` x ``lanes`` of a q, k or v block, with the projection's
+        bias added where the operand arrives without it: the same bf16 sum
+        XLA would have written out."""
+        x = ref[0, rows, lanes]
+        return x + bias_ref[:, lanes] if self.use_bias else x
+
     def q_first(self, r):
         return self.iq * self.block_q + r * self.sub_q
 
@@ -397,10 +428,13 @@ class _Tiles:
     def valid(self, kvm_ref, c):
         return kvm_ref[0, _rows(c, self.sub_k), :] if self.use_mask else None
 
-    def keep(self, seed_ref, q_first, k_first, shape):
+    def keep(self, seed_ref, hh, q_first, k_first, shape):
+        """Dropout bits of head ``hh`` of this program's block, seeded by
+        its GLOBAL batch*head index: the same bits whichever layout the
+        operands arrive in."""
         return _keep_mask(
-            seed_ref, self.bh, q_first, k_first, shape, self.gran,
-            self.dropout_rate,
+            seed_ref, self.group * self.heads_a_block + hh, q_first, k_first,
+            shape, self.gran, self.dropout_rate,
         )
 
     def over_keys(self, r, step, carry):
@@ -431,7 +465,7 @@ class _Tiles:
 
 
 def _fwd_kernel(
-    seed_ref, q_ref, k_ref, v_ref, kvm_ref, o_ref, lse_ref,
+    seed_ref, q_ref, k_ref, v_ref, bq_ref, bk_ref, bv_ref, kvm_ref, o_ref, lse_ref,
     m_scr, l_scr, acc_scr, vt_scr, **static,
 ):
     t = _Tiles(1, **static)
@@ -444,51 +478,63 @@ def _fwd_kernel(
 
     @_when(t.run)
     def _body():
-        # p v runs transposed (acc_t = v^T p_t): transpose the V block once
+        # p v runs transposed (acc_t = v^T p_t): transpose the V block
+        # once, every head of it together (a head's v^T is the rows
+        # ``lanes`` of the result)
         for c in range(t.nsk):
-            vt_scr[c] = v_ref[0, _rows(c, t.sub_k), :].T.astype(vt_scr.dtype)
+            vt_scr[c] = t.load(
+                v_ref, bv_ref, _rows(c, t.sub_k)
+            ).T.astype(vt_scr.dtype)
 
-        for r in range(t.nsq):
-            # matmul operands stay in their storage dtype (MXU-native bf16
-            # pairs, f32 accumulation); softmax bookkeeping is f32
-            q = q_ref[0, _rows(r, t.sub_q), :]
-            if t.fold_scale:
-                q = q * t.sm_scale
-            q_first = t.q_first(r)
+        for hh, lanes in t.heads():
+            for r in range(t.nsq):
+                # matmul operands stay in their storage dtype (MXU-native
+                # bf16 pairs, f32 accumulation); softmax bookkeeping is f32
+                q = t.load(q_ref, bq_ref, _rows(r, t.sub_q), lanes)
+                if t.fold_scale:
+                    q = q * t.sm_scale
+                q_first = t.q_first(r)
 
-            def k_step(c, carry, diagonal):
-                m_prev, l_prev, acc = carry
-                s_t = t.scores(
-                    k_ref[0, _rows(c, t.sub_k), :], q, t.valid(kvm_ref, c),
-                    q_first, t.k_first(c), diagonal=diagonal,
+                def k_step(c, carry, diagonal):
+                    m_prev, l_prev, acc = carry
+                    s_t = t.scores(
+                        t.load(k_ref, bk_ref, _rows(c, t.sub_k), lanes), q,
+                        t.valid(kvm_ref, c), q_first, t.k_first(c),
+                        diagonal=diagonal,
+                    )
+                    m_new = jnp.maximum(
+                        m_prev, jnp.max(s_t, axis=0, keepdims=True)
+                    )
+                    p_t = t.exp(s_t, m_new)
+                    alpha = jnp.exp(m_prev - m_new)
+                    l_new = alpha * l_prev + jnp.sum(p_t, axis=0, keepdims=True)
+                    if t.dropout_rate > 0.0:
+                        keep = t.keep(
+                            seed_ref, hh, q_first, t.k_first(c), p_t.shape
+                        )
+                        p_t = jnp.where(keep, p_t / (1.0 - t.dropout_rate), 0.0)
+                    pv = _dot_t(vt_scr[c, lanes, :], p_t, v_ref.dtype)
+                    return m_new, l_new, acc * alpha + pv
+
+                m_scr[hh, r], l_scr[hh, r], acc_scr[r, lanes, :] = t.over_keys(
+                    r, k_step, (m_scr[hh, r], l_scr[hh, r], acc_scr[r, lanes, :])
                 )
-                m_new = jnp.maximum(m_prev, jnp.max(s_t, axis=0, keepdims=True))
-                p_t = t.exp(s_t, m_new)
-                alpha = jnp.exp(m_prev - m_new)
-                l_new = alpha * l_prev + jnp.sum(p_t, axis=0, keepdims=True)
-                if t.dropout_rate > 0.0:
-                    keep = t.keep(seed_ref, q_first, t.k_first(c), p_t.shape)
-                    p_t = jnp.where(keep, p_t / (1.0 - t.dropout_rate), 0.0)
-                pv = _dot_t(vt_scr[c], p_t, v_ref.dtype)
-                return m_new, l_new, acc * alpha + pv
-
-            m_scr[r], l_scr[r], acc_scr[r] = t.over_keys(
-                r, k_step, (m_scr[r], l_scr[r], acc_scr[r])
-            )
 
     @_when(t.ik == t.nk - 1)
     def _finalize():
         for r in range(t.nsq):
-            l = l_scr[r]
-            l = jnp.where(l == 0.0, 1.0, l)  # fully-masked rows -> zeros
-            o_ref[0, _rows(r, t.sub_q), :] = (
-                (acc_scr[r] / l).T.astype(o_ref.dtype)
-            )
-            lse_ref[0, r] = m_scr[r] + jnp.log(l)
+            for hh, lanes in t.heads():
+                l = l_scr[hh, r]
+                l = jnp.where(l == 0.0, 1.0, l)  # fully-masked rows -> zeros
+                acc_scr[r, lanes, :] = acc_scr[r, lanes, :] / l
+                lse_ref[hh, r] = m_scr[hh, r] + jnp.log(l)
+            # every head of the block in one transpose: whole-lane stores
+            o_ref[0, _rows(r, t.sub_q), :] = acc_scr[r].T.astype(o_ref.dtype)
 
 
 def _bwd_dq_kernel(
-    seed_ref, q_ref, k_ref, v_ref, kvm_ref, do_ref, lse_ref, delta_ref,
+    seed_ref, q_ref, k_ref, v_ref, bq_ref, bk_ref, bv_ref, kvm_ref, do_ref,
+    lse_ref, delta_ref,
     dq_ref, dq_scr, kt_scr, **static,
 ):
     t = _Tiles(1, **static)
@@ -499,35 +545,42 @@ def _bwd_dq_kernel(
 
     @_when(t.run)
     def _body():
-        # dq_t += k^T ds_t: transpose the K block once
+        # dq_t += k^T ds_t: transpose the K block once, all its heads
         for c in range(t.nsk):
-            kt_scr[c] = k_ref[0, _rows(c, t.sub_k), :].T.astype(kt_scr.dtype)
+            kt_scr[c] = t.load(
+                k_ref, bk_ref, _rows(c, t.sub_k)
+            ).T.astype(kt_scr.dtype)
 
-        for r in range(t.nsq):
-            q = q_ref[0, _rows(r, t.sub_q), :]
-            if t.fold_scale:
-                q = q * t.sm_scale
-            do = do_ref[0, _rows(r, t.sub_q), :]
-            lse, delta = lse_ref[0, r], delta_ref[0, r]  # [1, sub_q] rows
-            q_first = t.q_first(r)
+        for hh, lanes in t.heads():
+            for r in range(t.nsq):
+                q = t.load(q_ref, bq_ref, _rows(r, t.sub_q), lanes)
+                if t.fold_scale:
+                    q = q * t.sm_scale
+                do = do_ref[0, _rows(r, t.sub_q), lanes]
+                lse, delta = lse_ref[hh, r], delta_ref[hh, r]  # [1, sub_q] rows
+                q_first = t.q_first(r)
 
-            def k_step(c, dq_t, diagonal):
-                s_t = t.scores(
-                    k_ref[0, _rows(c, t.sub_k), :], q, t.valid(kvm_ref, c),
-                    q_first, t.k_first(c), diagonal=diagonal,
-                )
-                p_t = t.exp(s_t, lse)
-                dp_t = jax.lax.dot_general(
-                    v_ref[0, _rows(c, t.sub_k), :], do,
-                    (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
-                )
-                if t.dropout_rate > 0.0:
-                    keep = t.keep(seed_ref, q_first, t.k_first(c), p_t.shape)
-                    dp_t = jnp.where(keep, dp_t / (1.0 - t.dropout_rate), 0.0)
-                ds_t = p_t * (dp_t - delta)
-                return dq_t + _dot_t(kt_scr[c], ds_t, k_ref.dtype)
+                def k_step(c, dq_t, diagonal):
+                    s_t = t.scores(
+                        t.load(k_ref, bk_ref, _rows(c, t.sub_k), lanes), q,
+                        t.valid(kvm_ref, c), q_first, t.k_first(c),
+                        diagonal=diagonal,
+                    )
+                    p_t = t.exp(s_t, lse)
+                    dp_t = jax.lax.dot_general(
+                        t.load(v_ref, bv_ref, _rows(c, t.sub_k), lanes), do,
+                        (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32,
+                    )
+                    if t.dropout_rate > 0.0:
+                        keep = t.keep(
+                            seed_ref, hh, q_first, t.k_first(c), p_t.shape
+                        )
+                        dp_t = jnp.where(keep, dp_t / (1.0 - t.dropout_rate), 0.0)
+                    ds_t = p_t * (dp_t - delta)
+                    return dq_t + _dot_t(kt_scr[c, lanes, :], ds_t, k_ref.dtype)
 
-            dq_scr[r] = t.over_keys(r, k_step, dq_scr[r])
+                dq_scr[r, lanes, :] = t.over_keys(r, k_step, dq_scr[r, lanes, :])
 
     @_when(t.ik == t.nk - 1)
     def _finalize():
@@ -538,7 +591,8 @@ def _bwd_dq_kernel(
 
 
 def _bwd_dkv_kernel(
-    seed_ref, q_ref, k_ref, v_ref, kvm_ref, do_ref, lse_ref, delta_ref,
+    seed_ref, q_ref, k_ref, v_ref, bq_ref, bk_ref, bv_ref, kvm_ref, do_ref,
+    lse_ref, delta_ref,
     dk_ref, dv_ref, dk_scr, dv_scr, **static,
 ):
     t = _Tiles(2, **static)
@@ -550,50 +604,55 @@ def _bwd_dkv_kernel(
 
     @_when(t.run)
     def _body():
-        for c in range(t.nsk):
-            keys = _rows(c, t.sub_k)
-            k, v = k_ref[0, keys, :], v_ref[0, keys, :]
-            k_scaled = k * t.sm_scale if t.fold_scale else k
-            valid = t.valid(kvm_ref, c)
-            k_first = t.k_first(c)
+        for hh, lanes in t.heads():
+            for c in range(t.nsk):
+                keys = _rows(c, t.sub_k)
+                k = t.load(k_ref, bk_ref, keys, lanes)
+                v = t.load(v_ref, bv_ref, keys, lanes)
+                k_scaled = k * t.sm_scale if t.fold_scale else k
+                valid = t.valid(kvm_ref, c)
+                k_first = t.k_first(c)
 
-            # the sub-tile is computed as k q^T, so that dv += p_t dO and
-            # dk += ds_t q are plain matmuls; lse and delta enter as rows
-            def q_step(r, carry, diagonal):
-                dk, dv = carry
-                q = q_ref[0, _rows(r, t.sub_q), :]
-                do = do_ref[0, _rows(r, t.sub_q), :]
-                q_first = t.q_first(r)
-                s_t = t.scores(
-                    k_scaled, q, valid, q_first, k_first, diagonal=diagonal
-                )
-                p_t = t.exp(s_t, lse_ref[0, r])
-                dp_t = jax.lax.dot_general(
-                    v, do, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-                p_drop = p_t
-                if t.dropout_rate > 0.0:
-                    keep = t.keep(seed_ref, q_first, k_first, p_t.shape)
-                    p_drop = jnp.where(keep, p_t / (1.0 - t.dropout_rate), 0.0)
-                    dp_t = jnp.where(keep, dp_t / (1.0 - t.dropout_rate), 0.0)
-                dv = dv + jnp.dot(
-                    p_drop.astype(do.dtype), do, preferred_element_type=jnp.float32
-                )
-                ds_t = p_t * (dp_t - delta_ref[0, r])
-                dk = dk + jnp.dot(
-                    ds_t.astype(q.dtype), q, preferred_element_type=jnp.float32
-                )
-                return dk, dv
+                # the sub-tile is computed as k q^T, so that dv += p_t dO
+                # and dk += ds_t q are plain matmuls; lse and delta enter
+                # as rows
+                def q_step(r, carry, diagonal):
+                    dk, dv = carry
+                    q = t.load(q_ref, bq_ref, _rows(r, t.sub_q), lanes)
+                    do = do_ref[0, _rows(r, t.sub_q), lanes]
+                    q_first = t.q_first(r)
+                    s_t = t.scores(
+                        k_scaled, q, valid, q_first, k_first, diagonal=diagonal
+                    )
+                    p_t = t.exp(s_t, lse_ref[hh, r])
+                    dp_t = jax.lax.dot_general(
+                        v, do, (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32,
+                    )
+                    p_drop = p_t
+                    if t.dropout_rate > 0.0:
+                        keep = t.keep(seed_ref, hh, q_first, k_first, p_t.shape)
+                        p_drop = jnp.where(keep, p_t / (1.0 - t.dropout_rate), 0.0)
+                        dp_t = jnp.where(keep, dp_t / (1.0 - t.dropout_rate), 0.0)
+                    dv = dv + jnp.dot(
+                        p_drop.astype(do.dtype), do,
+                        preferred_element_type=jnp.float32,
+                    )
+                    ds_t = p_t * (dp_t - delta_ref[hh, r])
+                    dk = dk + jnp.dot(
+                        ds_t.astype(q.dtype), q, preferred_element_type=jnp.float32
+                    )
+                    return dk, dv
 
-            dk_scr[keys, :], dv_scr[keys, :] = t.over_queries(
-                c, q_step, (dk_scr[keys, :], dv_scr[keys, :])
-            )
+                dk_scr[hh, keys, :], dv_scr[hh, keys, :] = t.over_queries(
+                    c, q_step, (dk_scr[hh, keys, :], dv_scr[hh, keys, :])
+                )
 
     @_when(t.iq == t.nq - 1)
     def _finalize():
-        dk_ref[0] = (dk_scr[:] * t.sm_scale).astype(dk_ref.dtype)
-        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+        for hh, lanes in t.heads():
+            dk_ref[0, :, lanes] = (dk_scr[hh] * t.sm_scale).astype(dk_ref.dtype)
+            dv_ref[0, :, lanes] = dv_scr[hh].astype(dv_ref.dtype)
 
 
 def _reshape_bh(x):
@@ -601,17 +660,107 @@ def _reshape_bh(x):
     return x.reshape(b * h, s, d)
 
 
-def _kvm_specs(use_mask, heads, block_k, order="q_inner_k"):
-    """BlockSpec for the [B, Sk, 1] key-validity column (keys lie on the
-    sublanes of a transposed score sub-tile); bh -> batch via // heads."""
-    if not use_mask:
-        if order == "q_inner_k":
-            return pl.BlockSpec((1, 1, 1), lambda bh, iq, ik: (0, 0, 0))
-        return pl.BlockSpec((1, 1, 1), lambda bh, ik, iq: (0, 0, 0))
-    shape = (1, block_k, 1)
-    if order == "q_inner_k":
-        return pl.BlockSpec(shape, lambda bh, iq, ik: (bh // heads, ik, 0))
-    return pl.BlockSpec(shape, lambda bh, ik, iq: (bh // heads, ik, 0))
+# Two layouts of the kernels' operands, one set of kernel bodies.
+#
+# SPLIT: q, k, v, dO and every result are ``[B*H, S, D]`` arrays, one head a
+# program (the public ``flash_attention``: sequence parallelism, the
+# grouped-query mixer, separate projections).
+#
+# PACKED: the kernels read q, k and v straight out of the fused qkv
+# projection's ``[B, S, 3*H*D]`` result (q | k | v along the last axis, a
+# head's D lanes side by side in each) and write the context, dq, dk and dv
+# as ``[B, S, H*D]``: what ``ctx @ attn_ow`` and the projection's backward
+# read, so no split, no ``[B,S,H,D] -> [B,H,S,D]`` transpose and none back.
+# A BlockSpec block is 128 lanes wide: one head of 128, or TWO heads of 64,
+# each a static 64-lane half of the block, walked one after the other by the
+# same kernel body. The lane-block index picks q, k or v and the pair.
+# lse and delta are ``[B*H, Sq/sub_q, 1, sub_q]`` rows in both.
+LANES = 128
+
+
+@dataclasses.dataclass(frozen=True)
+class _Operands:
+    batch: int
+    heads: int
+    head_dim: int
+    packed: bool
+
+    @property
+    def heads_a_block(self):
+        return LANES // self.head_dim if self.packed else 1
+
+    @property
+    def groups_a_batch(self):
+        """Programs (head groups) a batch row."""
+        return self.heads // self.heads_a_block
+
+    @property
+    def groups(self):
+        return self.batch * self.groups_a_batch
+
+    def _row_axis(self, rows, key_major):
+        """Where the grid (group, a, b) holds the block index of the
+        ``rows`` ("q" or "k") axis: query-major grids are (group, iq, ik),
+        dkv's key-major grid is (group, ik, iq)."""
+        return 1 if (rows == "q") != key_major else 2
+
+    def spec(self, rows, block, key_major=False, part=0):
+        """BlockSpec of a group's ``block`` rows of q (``part`` 0), k (1)
+        or v (2) in the projection's result, or of a ``[.., H*D]`` array
+        (``part`` 0)."""
+        axis = self._row_axis(rows, key_major)
+        if not self.packed:
+            return pl.BlockSpec(
+                (1, block, self.head_dim), lambda *g: (g[0], g[axis], 0)
+            )
+        per = self.groups_a_batch
+        return pl.BlockSpec(
+            (1, block, LANES),
+            lambda *g: (g[0] // per, g[axis], part * per + g[0] % per),
+        )
+
+    def bias_spec(self, use_bias, part):
+        """BlockSpec of the group's lanes of the projection's bias
+        [1, 3*H*D] (packed only), or of a dummy."""
+        if not use_bias:
+            return pl.BlockSpec((1, LANES), lambda *g: (0, 0))
+        per = self.groups_a_batch
+        return pl.BlockSpec(
+            (1, LANES), lambda *g: (0, part * per + g[0] % per)
+        )
+
+    def row_spec(self, block_q, sub_q, key_major=False):
+        """BlockSpec of the per-query rows lse/delta of a group's heads:
+        one lane-dense row a head a query sub-tile."""
+        axis = self._row_axis("q", key_major)
+        return pl.BlockSpec(
+            (self.heads_a_block, block_q // sub_q, 1, sub_q),
+            lambda *g: (g[0], g[axis], 0, 0),
+        )
+
+    def kvm_spec(self, use_mask, block_k, key_major=False):
+        """BlockSpec for the [B, Sk, 1] key-validity column (keys lie on
+        the sublanes of a transposed score sub-tile)."""
+        if not use_mask:
+            return pl.BlockSpec((1, 1, 1), lambda *g: (0, 0, 0))
+        axis, per = self._row_axis("k", key_major), self.groups_a_batch
+        return pl.BlockSpec(
+            (1, block_k, 1), lambda *g: (g[0] // per, g[axis], 0)
+        )
+
+    def result(self, seq, dtype):
+        """Shape of the context or of one gradient."""
+        if self.packed:
+            return jax.ShapeDtypeStruct(
+                (self.batch, seq, self.heads * self.head_dim), dtype
+            )
+        return jax.ShapeDtypeStruct(
+            (self.batch * self.heads, seq, self.head_dim), dtype
+        )
+
+    @property
+    def block_lanes(self):
+        return self.heads_a_block * self.head_dim
 
 
 def _kvm_column(kv_mask):
@@ -621,89 +770,195 @@ def _kvm_column(kv_mask):
     return kv_mask.astype(jnp.int32)[:, :, None]
 
 
-def _row_spec(block_q, sub_q, order="q_inner_k"):
-    """BlockSpec for the per-query rows lse/delta, [B*H, Sq/sub_q, 1, sub_q]:
-    one lane-dense row a query sub-tile."""
-    shape = (1, block_q // sub_q, 1, sub_q)
-    if order == "q_inner_k":
-        return pl.BlockSpec(shape, lambda bh, iq, ik: (bh, iq, 0, 0))
-    return pl.BlockSpec(shape, lambda bh, ik, iq: (bh, iq, 0, 0))
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
-def _flash(q, k, v, kv_mask, seed, causal, sm_scale, dropout_rate, block_q, block_k):
-    out, _ = _flash_fwd_impl(
-        q, k, v, kv_mask, seed, causal, sm_scale, dropout_rate, block_q, block_k
+def _static(
+    ops, sq, sk, block_q, block_k, causal, sm_scale, dropout_rate, use_mask,
+    use_bias,
+):
+    """The keyword arguments of ``_Tiles`` that the three kernels share."""
+    return dict(
+        sm_scale=sm_scale, causal=causal, block_q=block_q, block_k=block_k,
+        nq=sq // block_q, nk=sk // block_k, diag_offset=sk - sq,
+        dropout_rate=dropout_rate, use_mask=use_mask, use_bias=use_bias,
+        head_dim=ops.head_dim, heads_a_block=ops.heads_a_block,
     )
-    return out
 
 
-def _flash_fwd_impl(q, k, v, kv_mask, seed, causal, sm_scale, dropout_rate, block_q, block_k):
-    """Returns ``(out [B, H, Sq, D], lse [B*H, Sq] float32)``."""
-    b, h, sq, d = q.shape
-    sk = k.shape[2]
-    nq, nk = sq // block_q, sk // block_k
+def _bias_row(bias, dtype):
+    """The projection's bias [3*H*D] as a [1, 3*H*D] row, or a dummy."""
+    if bias is None:
+        return jnp.zeros((1, LANES), dtype)
+    return bias.astype(dtype)[None, :]
+
+
+def _forward_call(
+    ops, q, k, v, bias, kv_mask, seed, sq, sk, causal, sm_scale, dropout_rate,
+    block_q, block_k,
+):
+    """``flash_fwd`` over all B x H heads. ``q``/``k``/``v``: three
+    ``[B*H, S, D]`` arrays, or the packed projection three times, then
+    with the ``bias`` [3*H*D] it still lacks (or None). Returns the context
+    in the operands' layout and lse ``[B*H, Sq]`` float32."""
+    d, dtype = ops.head_dim, q.dtype
+    use_bias = bias is not None
+    common = _static(
+        ops, sq, sk, block_q, block_k, causal, sm_scale, dropout_rate,
+        kv_mask is not None, use_bias,
+    )
+    nq, nk = common["nq"], common["nk"]
     sub_q, sub_k = pick_subtiles(block_q, block_k, nq, nk, key_major=False)
-    use_mask = kv_mask is not None
     interpret = not device.on_tpu()
     _log_tiling(
-        sq, sk, d, str(q.dtype), block_q, block_k, causal, use_mask,
+        sq, sk, d, str(dtype), block_q, block_k, causal, kv_mask is not None,
         dropout_rate > 0.0,
     )
-
-    q3, k3, v3 = _reshape_bh(q), _reshape_bh(k), _reshape_bh(v)
-    seed_arr = jnp.reshape(jnp.asarray(seed, jnp.int32), (1,))
-
-    kernel = functools.partial(
-        _fwd_kernel,
-        sm_scale=sm_scale, causal=causal, block_q=block_q, block_k=block_k,
-        sub_q=sub_q, sub_k=sub_k, nq=nq, nk=nk, diag_offset=sk - sq,
-        dropout_rate=dropout_rate, use_mask=use_mask,
-    )
+    hb, nsq = ops.heads_a_block, block_q // sub_q
     out, lse = pl.pallas_call(
-        kernel,
-        grid=(b * h, nq, nk),
+        functools.partial(_fwd_kernel, sub_q=sub_q, sub_k=sub_k, **common),
+        grid=(ops.groups, nq, nk),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, block_q, d), lambda bh, iq, ik: (bh, iq, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, iq, ik: (bh, ik, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, iq, ik: (bh, ik, 0)),
-            _kvm_specs(use_mask, h, block_k),
+            ops.spec("q", block_q, part=0),
+            ops.spec("k", block_k, part=1),
+            ops.spec("k", block_k, part=2),
+            *(ops.bias_spec(use_bias, part) for part in range(3)),
+            ops.kvm_spec(kv_mask is not None, block_k),
         ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, iq, ik: (bh, iq, 0)),
-            _row_spec(block_q, sub_q),
-        ],
+        out_specs=[ops.spec("q", block_q), ops.row_spec(block_q, sub_q)],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((b * h, sq // sub_q, 1, sub_q), jnp.float32),
+            ops.result(sq, dtype),
+            jax.ShapeDtypeStruct(
+                (ops.batch * ops.heads, sq // sub_q, 1, sub_q), jnp.float32
+            ),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q // sub_q, 1, sub_q), jnp.float32),
-            pltpu.VMEM((block_q // sub_q, 1, sub_q), jnp.float32),
-            pltpu.VMEM((block_q // sub_q, d, sub_q), jnp.float32),
-            _transposed_scratch(block_k // sub_k, d, sub_k, v.dtype, interpret),
+            pltpu.VMEM((hb, nsq, 1, sub_q), jnp.float32),
+            pltpu.VMEM((hb, nsq, 1, sub_q), jnp.float32),
+            pltpu.VMEM((nsq, ops.block_lanes, sub_q), jnp.float32),
+            _transposed_scratch(
+                block_k // sub_k, ops.block_lanes, sub_k, dtype, interpret
+            ),
         ],
         interpret=interpret,
         name="flash_fwd",
-    )(seed_arr, q3, k3, v3, _kvm_column(kv_mask))
-    return out.reshape(b, h, sq, d), lse.reshape(b * h, sq)
+    )(_seed_array(seed), q, k, v, *[_bias_row(bias, dtype)] * 3,
+      _kvm_column(kv_mask))
+    return out, lse.reshape(ops.batch * ops.heads, sq)
+
+
+def _seed_array(seed):
+    return jnp.reshape(jnp.asarray(seed, jnp.int32), (1,))
+
+
+def _backward_calls(
+    ops, q, k, v, bias, kv_mask, seed, do, lse, delta, sq, sk, causal,
+    sm_scale, dropout_rate, block_q, block_k,
+):
+    """``flash_bwd_dq`` and ``flash_bwd_dkv``: (dq, dk, dv) in the
+    operands' layout. ``lse``/``delta``: ``[B*H, Sq]`` float32."""
+    dtype = q.dtype
+    use_mask, use_bias = kv_mask is not None, bias is not None
+    common = _static(
+        ops, sq, sk, block_q, block_k, causal, sm_scale, dropout_rate,
+        use_mask, use_bias,
+    )
+    nq, nk = common["nq"], common["nk"]
+    interpret = not device.on_tpu()
+    kvm, seed_arr = _kvm_column(kv_mask), _seed_array(seed)
+    biases = [_bias_row(bias, dtype)] * 3
+    bias_specs = [ops.bias_spec(use_bias, part) for part in range(3)]
+    hb, lanes = ops.heads_a_block, ops.block_lanes
+
+    sub_q, sub_k = pick_subtiles(block_q, block_k, nq, nk, key_major=False)
+    # lse and delta enter as one lane-dense row a query sub-tile
+    rows = (ops.batch * ops.heads, sq // sub_q, 1, sub_q)
+    dq = pl.pallas_call(
+        functools.partial(_bwd_dq_kernel, sub_q=sub_q, sub_k=sub_k, **common),
+        grid=(ops.groups, nq, nk),
+        in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            ops.spec("q", block_q, part=0),
+            ops.spec("k", block_k, part=1),
+            ops.spec("k", block_k, part=2),
+            *bias_specs,
+            ops.kvm_spec(use_mask, block_k),
+            ops.spec("q", block_q),
+            ops.row_spec(block_q, sub_q),
+            ops.row_spec(block_q, sub_q),
+        ],
+        out_specs=ops.spec("q", block_q),
+        out_shape=ops.result(sq, dtype),
+        scratch_shapes=[
+            pltpu.VMEM((block_q // sub_q, lanes, sub_q), jnp.float32),
+            _transposed_scratch(block_k // sub_k, lanes, sub_k, dtype, interpret),
+        ],
+        interpret=interpret,
+        name="flash_bwd_dq",
+    )(seed_arr, q, k, v, *biases, kvm, do, lse.reshape(rows), delta.reshape(rows))
+
+    sub_q, sub_k = pick_subtiles(block_q, block_k, nq, nk, key_major=True)
+    rows = (ops.batch * ops.heads, sq // sub_q, 1, sub_q)
+    dk, dv = pl.pallas_call(
+        functools.partial(_bwd_dkv_kernel, sub_q=sub_q, sub_k=sub_k, **common),
+        grid=(ops.groups, nk, nq),
+        in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            ops.spec("q", block_q, key_major=True, part=0),
+            ops.spec("k", block_k, key_major=True, part=1),
+            ops.spec("k", block_k, key_major=True, part=2),
+            *bias_specs,
+            ops.kvm_spec(use_mask, block_k, key_major=True),
+            ops.spec("q", block_q, key_major=True),
+            ops.row_spec(block_q, sub_q, key_major=True),
+            ops.row_spec(block_q, sub_q, key_major=True),
+        ],
+        out_specs=[
+            ops.spec("k", block_k, key_major=True),
+            ops.spec("k", block_k, key_major=True),
+        ],
+        out_shape=[ops.result(sk, dtype), ops.result(sk, dtype)],
+        scratch_shapes=[
+            pltpu.VMEM((hb, block_k, ops.head_dim), jnp.float32),
+            pltpu.VMEM((hb, block_k, ops.head_dim), jnp.float32),
+        ],
+        interpret=interpret,
+        name="flash_bwd_dkv",
+    )(seed_arr, q, k, v, *biases, kvm, do, lse.reshape(rows), delta.reshape(rows))
+    return dq, dk, dv
+
+
+def _no_gradient(kv_mask, seed):
+    """kv_mask is padding metadata (int), seed is RNG state."""
+    return None if kv_mask is None else jnp.zeros_like(kv_mask), jnp.zeros_like(seed)
+
+
+def _name_residuals(out, lse):
+    """checkpoint_name tags let remat policies KEEP these residuals: under a
+    plain dots-saveable policy the pallas outputs are not dot_generals, so
+    per-layer remat would re-run the whole forward kernel in backward just
+    to regenerate them (policy "...+flash_out+flash_lse" in
+    ops/transformer.py saves them for a few MB per layer: lse is one
+    float32 a query row, [B*H, Sq])."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    return checkpoint_name(out, "flash_out"), checkpoint_name(lse, "flash_lse")
+
+
+# ---- split operands: q, k, v [B, H, S, D] ---------------------------------
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _flash(q, k, v, kv_mask, seed, causal, sm_scale, dropout_rate, block_q, block_k):
+    return _flash_fwd(
+        q, k, v, kv_mask, seed, causal, sm_scale, dropout_rate, block_q, block_k
+    )[0]
 
 
 def _flash_fwd(q, k, v, kv_mask, seed, causal, sm_scale, dropout_rate, block_q, block_k):
-    from jax.ad_checkpoint import checkpoint_name
-
-    out, lse = _flash_fwd_impl(
-        q, k, v, kv_mask, seed, causal, sm_scale, dropout_rate, block_q, block_k
+    b, h, sq, d = q.shape
+    out, lse = _forward_call(
+        _Operands(b, h, d, packed=False),
+        _reshape_bh(q), _reshape_bh(k), _reshape_bh(v), None, kv_mask, seed,
+        sq, k.shape[2], causal, sm_scale, dropout_rate, block_q, block_k,
     )
-    # checkpoint_name tags let remat policies KEEP these residuals: under a
-    # plain dots-saveable policy the pallas outputs are not dot_generals, so
-    # per-layer remat would re-run the whole forward kernel in backward just
-    # to regenerate them (policy "...+flash_out+flash_lse" in
-    # ops/transformer.py saves them for a few MB per layer: lse is one
-    # float32 a query row, [B*H, Sq]).
-    out = checkpoint_name(out, "flash_out")
-    lse = checkpoint_name(lse, "flash_lse")
+    out, lse = _name_residuals(out.reshape(b, h, sq, d), lse)
     return out, (q, k, v, kv_mask, seed, out, lse)
 
 
@@ -711,92 +966,86 @@ def _flash_bwd(causal, sm_scale, dropout_rate, block_q, block_k, residuals, g):
     q, k, v, kv_mask, seed, out, lse = residuals
     b, h, sq, d = q.shape
     sk = k.shape[2]
-    nq, nk = sq // block_q, sk // block_k
-    interpret = not device.on_tpu()
-    use_mask = kv_mask is not None
-
     # delta_i = rowsum(dO * O): cheap elementwise reduction, leave to XLA
     delta = jnp.sum(
         g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
     ).reshape(b * h, sq)
-
-    q3, k3, v3 = _reshape_bh(q), _reshape_bh(k), _reshape_bh(v)
-    do3 = _reshape_bh(g)
-    kvm = _kvm_column(kv_mask)
-    seed_arr = jnp.reshape(jnp.asarray(seed, jnp.int32), (1,))
-    common = dict(
-        sm_scale=sm_scale, causal=causal, block_q=block_q, block_k=block_k,
-        nq=nq, nk=nk, diag_offset=sk - sq, dropout_rate=dropout_rate,
-        use_mask=use_mask,
+    dq, dk, dv = _backward_calls(
+        _Operands(b, h, d, packed=False),
+        _reshape_bh(q), _reshape_bh(k), _reshape_bh(v), None, kv_mask, seed,
+        _reshape_bh(g), lse, delta, sq, sk, causal, sm_scale, dropout_rate,
+        block_q, block_k,
     )
-
-    sub_q, sub_k = pick_subtiles(block_q, block_k, nq, nk, key_major=False)
-    # lse and delta enter as one lane-dense row a query sub-tile
-    rows = (b * h, sq // sub_q, 1, sub_q)
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, sub_q=sub_q, sub_k=sub_k, **common),
-        grid=(b * h, nq, nk),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, block_q, d), lambda bh, iq, ik: (bh, iq, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, iq, ik: (bh, ik, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, iq, ik: (bh, ik, 0)),
-            _kvm_specs(use_mask, h, block_k),
-            pl.BlockSpec((1, block_q, d), lambda bh, iq, ik: (bh, iq, 0)),
-            _row_spec(block_q, sub_q),
-            _row_spec(block_q, sub_q),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda bh, iq, ik: (bh, iq, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((block_q // sub_q, d, sub_q), jnp.float32),
-            _transposed_scratch(block_k // sub_k, d, sub_k, k.dtype, interpret),
-        ],
-        interpret=interpret,
-        name="flash_bwd_dq",
-    )(seed_arr, q3, k3, v3, kvm, do3, lse.reshape(rows), delta.reshape(rows))
-
-    sub_q, sub_k = pick_subtiles(block_q, block_k, nq, nk, key_major=True)
-    rows = (b * h, sq // sub_q, 1, sub_q)
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, sub_q=sub_q, sub_k=sub_k, **common),
-        grid=(b * h, nk, nq),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, block_q, d), lambda bh, ik, iq: (bh, iq, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, ik, iq: (bh, ik, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, ik, iq: (bh, ik, 0)),
-            _kvm_specs(use_mask, h, block_k, order="k_inner_q"),
-            pl.BlockSpec((1, block_q, d), lambda bh, ik, iq: (bh, iq, 0)),
-            _row_spec(block_q, sub_q, order="k_inner_q"),
-            _row_spec(block_q, sub_q, order="k_inner_q"),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda bh, ik, iq: (bh, ik, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, ik, iq: (bh, ik, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b * h, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, sk, d), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
-        ],
-        interpret=interpret,
-        name="flash_bwd_dkv",
-    )(seed_arr, q3, k3, v3, kvm, do3, lse.reshape(rows), delta.reshape(rows))
-
-    dq = dq.reshape(b, h, sq, d)
-    dk = dk.reshape(b, h, sk, d)
-    dv = dv.reshape(b, h, sk, d)
-    # kv_mask is padding metadata (int), seed is RNG state: no gradients.
-    dkvm = None if kv_mask is None else jnp.zeros_like(kv_mask)
-    dseed = jnp.zeros_like(seed)
-    return dq, dk, dv, dkvm, dseed
+    return (
+        dq.reshape(b, h, sq, d), dk.reshape(b, h, sk, d),
+        dv.reshape(b, h, sk, d), *_no_gradient(kv_mask, seed),
+    )
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+# ---- packed operands: the qkv projection's result [B, S, 3*H*D] -----------
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _flash_packed(
+    qkv, bias, kv_mask, seed, heads, causal, sm_scale, dropout_rate, block_q,
+    block_k,
+):
+    return _flash_packed_fwd(
+        qkv, bias, kv_mask, seed, heads, causal, sm_scale, dropout_rate,
+        block_q, block_k,
+    )[0]
+
+
+def _packed_operands(qkv, heads):
+    b, s, width = qkv.shape
+    return _Operands(b, heads, width // (3 * heads), packed=True)
+
+
+def _flash_packed_fwd(
+    qkv, bias, kv_mask, seed, heads, causal, sm_scale, dropout_rate, block_q,
+    block_k,
+):
+    s = qkv.shape[1]
+    out, lse = _forward_call(
+        _packed_operands(qkv, heads), qkv, qkv, qkv, bias, kv_mask, seed, s, s,
+        causal, sm_scale, dropout_rate, block_q, block_k,
+    )
+    out, lse = _name_residuals(out, lse)
+    return out, (qkv, bias, kv_mask, seed, out, lse)
+
+
+def _flash_packed_bwd(
+    heads, causal, sm_scale, dropout_rate, block_q, block_k, residuals, g
+):
+    qkv, bias, kv_mask, seed, out, lse = residuals
+    ops = _packed_operands(qkv, heads)
+    b, s, d = ops.batch, qkv.shape[1], ops.head_dim
+    # delta_i = rowsum(dO * O) over each head's D lanes, as a product with
+    # a 0/1 matrix: one pass over dO and O as they lie, [B, H, S] out. (A
+    # reshape to [.., H, D] would lay both out anew for a 64-wide minor
+    # dimension.) A product of two bf16 numbers is exact in float32 and
+    # splits into two bf16 terms, so ``HIGHEST`` sums exactly what the
+    # split path's float32 reduction sums.
+    own = jnp.arange(heads)[:, None] == jnp.arange(heads * d)[None, :] // d
+    delta = jnp.einsum(
+        "hk,bsk->bhs", own.astype(jnp.float32),
+        g.astype(jnp.float32) * out.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    ).reshape(b * heads, s)
+    dq, dk, dv = _backward_calls(
+        ops, qkv, qkv, qkv, bias, kv_mask, seed, g, lse, delta, s, s, causal,
+        sm_scale, dropout_rate, block_q, block_k,
+    )
+    dqkv = jnp.concatenate([dq, dk, dv], axis=-1)
+    dbias = None
+    if bias is not None:
+        dbias = jnp.sum(dqkv.astype(jnp.float32), axis=(0, 1)).astype(bias.dtype)
+    return dqkv, dbias, *_no_gradient(kv_mask, seed)
+
+
+_flash_packed.defvjp(_flash_packed_fwd, _flash_packed_bwd)
 
 
 def additive_mask_to_kv_valid(mask):
@@ -850,6 +1099,51 @@ def flash_attention(
     )
 
 
+def flash_attention_packed(
+    qkv, heads, bias=None, kv_mask=None, causal=False, sm_scale=None,
+    dropout_rate=0.0, dropout_seed=0,
+    block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
+):
+    """``flash_attention`` over the fused qkv projection's own result.
+
+    ``qkv``: [B, S, 3*H*D], q | k | v along the last axis, head by head in
+    each; ``bias``: the projection's bias [3*H*D] where ``qkv`` is the bare
+    product and still lacks it (the kernels add it as they load). Returns
+    the context [B, S, H*D]. The same kernels, tiling, arithmetic and
+    dropout bits as ``flash_attention`` on the split ``[B, H, S, D]``
+    operands; ``packed_refusal`` says which shapes it takes.
+    """
+    b, s, width = qkv.shape
+    d = width // (3 * heads)
+    why = packed_refusal(heads, d, width)
+    if why:
+        raise ValueError(f"flash_attention_packed: {why}")
+    if sm_scale is None:
+        sm_scale = 1.0 / (d ** 0.5)
+    block_q, block_k = pick_block(s, block_q), pick_block(s, block_k)
+    if block_q == 0 or block_k == 0:
+        raise ValueError(f"flash_attention_packed found no block dividing s={s}")
+    return _flash_packed(
+        qkv, bias, kv_mask, jnp.asarray(dropout_seed, jnp.int32), int(heads),
+        causal, float(sm_scale), float(dropout_rate), int(block_q), int(block_k),
+    )
+
+
+def packed_refusal(heads, head_dim, width=None):
+    """Why the kernels cannot read heads out of a ``[B, S, 3*H*D]``
+    projection result, or None where they can: a 128-lane block must hold
+    whole heads."""
+    if width is not None and width != 3 * heads * head_dim:
+        return f"width {width} is not 3 x {heads} heads x {head_dim}"
+    if head_dim not in (64, LANES):
+        return f"head_dim {head_dim} is neither 64 nor {LANES}"
+    if heads % (LANES // head_dim):
+        return (
+            f"{heads} heads of {head_dim} do not pair into {LANES}-lane blocks"
+        )
+    return None
+
+
 # Flash dispatch mode:
 #   "auto"   — flash where _flash_route finds a way (one device, or
 #              per-shard via shard_map over a data/model mesh); otherwise
@@ -901,11 +1195,7 @@ def flash_attention_sharded(
 
     def local(q, k, v, kvm, seed):
         if dropout_rate > 0.0:
-            # decorrelate in-kernel dropout streams across shards (the
-            # kernel seeds per LOCAL (bh, iq, ik) program id)
-            di = jax.lax.axis_index(DATA_AXIS).astype(jnp.int32)
-            mi = jax.lax.axis_index(MODEL_AXIS).astype(jnp.int32)
-            seed = seed + di * jnp.int32(7_368_787) + mi * jnp.int32(15_485_863)
+            seed = _shard_seed(seed)
         return _flash(
             q, k, v, kvm if use_mask else None, seed, causal,
             float(sm_scale), float(dropout_rate), int(block_q), int(block_k),
@@ -920,14 +1210,15 @@ def flash_attention_sharded(
     )(q, k, v, kv_mask if use_mask else jnp.zeros((), jnp.int32), seed)
 
 
-def _flash_route(mesh, q, k):
-    """How flash can run for these operands: ``"sharded"`` (per-shard
-    over the mesh's data/model axes via ``shard_map``), ``"local"`` (the
-    operands live on one device), or a reason string when it cannot (the
-    caller has already validated mask and block tiling via its can_flash
-    gate). A bare ``pallas_call`` inside a GSPMD-jitted program over
-    several devices is not partitioned — XLA would all-gather its
-    operands — so anything else goes to the XLA path."""
+def _flash_route(mesh, batch, heads):
+    """How flash can run for ``batch`` rows of ``heads`` heads:
+    ``"sharded"`` (per-shard over the mesh's data/model axes via
+    ``shard_map``), ``"local"`` (the operands live on one device), or a
+    reason string when it cannot (the caller has already validated mask and
+    block tiling via ``_flash_gate``). A bare ``pallas_call`` inside a
+    GSPMD-jitted program over several devices is not partitioned — XLA
+    would all-gather its operands — so anything else goes to the XLA
+    path."""
     from ..config.constants import DATA_AXIS, MODEL_AXIS, SEQUENCE_AXIS
 
     if mesh is None:
@@ -948,13 +1239,142 @@ def _flash_route(mesh, q, k):
         return "sequence-parallel mesh (handled in parallel/sequence.py)"
     if dp * mp <= 1:
         return f"mesh {shape} shards over neither data nor model"
-    b, h = q.shape[0], q.shape[1]
-    if b % dp or h % mp:
+    if batch % dp or heads % mp:
         return (
-            f"batch {b} / heads {h} do not divide the mesh's "
+            f"batch {batch} / heads {heads} do not divide the mesh's "
             f"data={dp} / model={mp} axes"
         )
     return "sharded"
+
+
+def _flash_gate(sq, sk, mask, dropout_rate, dropout_rng, use_flash):
+    """Whether the kernels can serve this call at all, before any mesh is
+    looked at: ``(why_not or None, kv_mask, block_q, block_k,
+    dropout_rate)``."""
+    bq = pick_block(sq, DEFAULT_BLOCK_Q)
+    bk = pick_block(sk, DEFAULT_BLOCK_K)
+    if dropout_rng is None:
+        dropout_rate = 0.0  # matches the XLA path's no-rng => no-dropout
+    kv_mask = additive_mask_to_kv_valid(mask)
+    why_not = None
+    if not use_flash:
+        why_not = "use_flash is off"
+    elif FLASH_MODE == "never":
+        why_not = 'FLASH_MODE is "never"'
+    elif bq == 0 or bk == 0:
+        why_not = f"no block divides sq={sq}/sk={sk}"
+    elif mask is not None and kv_mask is None:
+        why_not = "the mask depends on the query position"
+    elif dropout_rate > 0.0 and not device.on_tpu():
+        # interpret-mode PRNG is not available off-TPU
+        why_not = "dropout needs the chip's generator"
+    elif FLASH_MODE == "auto" and max(sq, sk) < FLASH_MIN_SEQ:
+        why_not = f"seq {max(sq, sk)} is under FLASH_MIN_SEQ {FLASH_MIN_SEQ}"
+    return why_not, kv_mask, bq, bk, dropout_rate
+
+
+def attention_layout(batch, seq, heads, head_dim, flash, mesh=None):
+    """Which operand layout the attention sublayer's kernels get, chosen
+    from what the caller sees and nothing else: ``("packed", heads a
+    block, None)`` — the kernels read q, k, v out of the qkv projection's
+    ``[B, S, 3*H*D]`` result and write ``[B, S, H*D]`` — or ``("split", 1,
+    reason)``: today's ``[B, H, S, D]`` operands (or no kernel at all).
+    ``flash``: None where the flash gate passed, else why it did not."""
+    from ..config.constants import MODEL_AXIS
+
+    why = flash and f"no flash kernel ({flash})"
+    if not why:
+        route = _flash_route(mesh, batch, heads)
+        if route not in ("local", "sharded") and FLASH_MODE != "always":
+            why = f"no flash kernel ({route})"
+        elif route == "sharded" and dict(mesh.shape)[MODEL_AXIS] > 1:
+            why = (
+                "the model axis shards the heads, and a shard of the "
+                "projection is not q | k | v"
+            )
+    why = why or packed_refusal(heads, head_dim)
+    layout = (
+        ("split", 1, why) if why else ("packed", LANES // head_dim, None)
+    )
+    _log_layout(batch, seq, heads, head_dim, *layout)
+    return layout
+
+
+@functools.lru_cache(maxsize=None)
+def _log_layout(batch, seq, heads, head_dim, layout, heads_a_block, reason):
+    logger.debug(
+        "attention_layout b=%d s=%d heads=%d d=%d layout=%s heads_a_block=%d%s",
+        batch, seq, heads, head_dim, layout, heads_a_block,
+        f" reason={reason!r}" if reason else "",
+    )
+
+
+def _shard_seed(seed):
+    """Decorrelate in-kernel dropout streams across shards (the kernel
+    seeds per LOCAL batch*head index)."""
+    from ..config.constants import DATA_AXIS, MODEL_AXIS
+
+    di = jax.lax.axis_index(DATA_AXIS).astype(jnp.int32)
+    mi = jax.lax.axis_index(MODEL_AXIS).astype(jnp.int32)
+    return seed + di * jnp.int32(7_368_787) + mi * jnp.int32(15_485_863)
+
+
+def attention_packed(
+    qkv, heads, bias=None, mask=None, causal=False, dropout_rate=0.0,
+    dropout_rng=None, use_flash=True, mesh=None,
+):
+    """The attention sublayer between the fused qkv projection and the
+    output projection: ``qkv`` [B, S, 3*H*D] (q | k | v, head by head) to
+    the context [B, S, H*D]. ``bias``: the projection's bias where ``qkv``
+    is the bare product (None where it is already in). Where
+    ``attention_layout`` says ``packed`` the flash kernels take ``qkv`` as
+    it is and add the bias as they load, on one device or per shard over
+    the data axis; anything else gets the bias here, is split into
+    ``[B, H, S, D]`` heads and goes through ``attention`` as before."""
+    b, s, width = qkv.shape
+    d = width // (3 * heads)
+    why_not, kv_mask, bq, bk, rate = _flash_gate(
+        s, s, mask, dropout_rate, dropout_rng, use_flash
+    )
+    layout, _, why_split = attention_layout(b, s, heads, d, why_not, mesh)
+    if layout == "split":
+        if bias is not None:
+            qkv = qkv + bias
+        q, k, v = (
+            t.reshape(b, s, heads, d).transpose(0, 2, 1, 3)
+            for t in jnp.split(qkv, 3, axis=-1)
+        )
+        ctx = _attention_split(
+            q, k, v, mask, causal, None, dropout_rate, dropout_rng,
+            use_flash, mesh, why_split,
+        )
+        return ctx.transpose(0, 2, 1, 3).reshape(b, s, heads * d)
+
+    seed = jnp.asarray(0, jnp.int32)
+    if rate > 0.0:
+        seed = jax.random.randint(dropout_rng, (), 0, 2**31 - 1)
+    use_mask, use_bias = kv_mask is not None, bias is not None
+
+    def local(qkv, bias, kvm, seed, sharded=False):
+        if sharded and rate > 0.0:
+            seed = _shard_seed(seed)
+        return _flash_packed(
+            qkv, bias if use_bias else None, kvm if use_mask else None, seed,
+            heads, causal, 1.0 / (d ** 0.5), float(rate), bq, bk,
+        )
+
+    if _flash_route(mesh, b, heads) != "sharded":
+        return local(qkv, bias, kv_mask, seed)
+    from jax.sharding import PartitionSpec as P
+
+    from ..config.constants import DATA_AXIS
+
+    rows, absent = P(DATA_AXIS, None, None), jnp.zeros((), jnp.int32)
+    return jax.shard_map(
+        functools.partial(local, sharded=True), mesh=mesh,
+        in_specs=(rows, P(), P(DATA_AXIS, None) if use_mask else P(), P()),
+        out_specs=rows, check_vma=False,
+    )(qkv, bias if use_bias else absent, kv_mask if use_mask else absent, seed)
 
 
 def attention(
@@ -967,35 +1387,35 @@ def attention(
     data/model-parallel layout, flash runs per-shard via ``shard_map``;
     where several devices leave no way to run it (``_flash_route``), the
     O(S^2) path runs and, on a TPU, says why once. ``k``/``v`` may have
-    fewer heads than ``q`` (grouped-query attention)."""
+    fewer heads than ``q`` (grouped-query attention). A caller that holds
+    the fused qkv projection's result takes ``attention_packed``."""
+    return _attention_split(
+        q, k, v, mask, causal, sm_scale, dropout_rate, dropout_rng,
+        use_flash, mesh, "q, k and v arrive as separate [B, H, S, D] arrays",
+    )
+
+
+def _attention_split(
+    q, k, v, mask, causal, sm_scale, dropout_rate, dropout_rng, use_flash,
+    mesh, why_split,
+):
     if k.shape[1] != q.shape[1]:
         # grouped-query heads: each kv head serves q_heads / kv_heads query
         # heads. Repeated here, before the kernel; a grouped kernel layout
         # that reads each kv head once is not built.
         k, v = (jnp.repeat(t, q.shape[1] // k.shape[1], axis=1)
                 for t in (k, v))
-    sq, sk = q.shape[2], k.shape[2]
-    bq = pick_block(sq, DEFAULT_BLOCK_Q)
-    bk = pick_block(sk, DEFAULT_BLOCK_K)
-    if dropout_rng is None:
-        dropout_rate = 0.0  # matches the XLA path's no-rng => no-dropout
-    kv_mask = additive_mask_to_kv_valid(mask)
-    can_flash = (
-        use_flash
-        and bq > 0
-        and bk > 0
-        and (mask is None or kv_mask is not None)
+    b, heads, sq, d = q.shape
+    sk = k.shape[2]
+    why_not, kv_mask, bq, bk, dropout_rate = _flash_gate(
+        sq, sk, mask, dropout_rate, dropout_rng, use_flash
     )
-    # interpret-mode PRNG is not available off-TPU; route dropout to XLA there
-    if dropout_rate > 0.0 and not device.on_tpu():
-        can_flash = False
-    if FLASH_MODE == "never":
-        can_flash = False
-    elif FLASH_MODE == "auto" and max(sq, sk) < FLASH_MIN_SEQ:
-        can_flash = False
-
-    if can_flash:
-        route = _flash_route(mesh, q, k)
+    _log_layout(
+        b, sq, heads, d, "split", 1,
+        f"no flash kernel ({why_not})" if why_not else why_split,
+    )
+    if not why_not:
+        route = _flash_route(mesh, b, heads)
         if FLASH_MODE == "always" and route != "sharded":
             route = "local"  # caller guarantees per-device operands
         seed = jnp.asarray(0, jnp.int32)

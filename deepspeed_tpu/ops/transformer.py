@@ -32,7 +32,9 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from .attention import NEG_INF, additive_mask_to_kv_valid, attention
+from .attention import (
+    NEG_INF, additive_mask_to_kv_valid, attention, attention_packed,
+)
 
 
 @dataclasses.dataclass
@@ -438,15 +440,18 @@ def transformer_block_apply(
             layer_norm(x, p["attn_nw"], p["attn_nb"])
             if cfg.pre_layer_norm else x
         )
-        qkv = apply_lora(
-            cfg, p, lora, "attn_qkvw", attn_in,
-            attn_in @ p["attn_qkvw"] + p["attn_qkvb"],
-        )
-        q, k_, v = jnp.split(qkv, 3, axis=-1)
-        # [B,S,H] -> [B,heads,S,hd]  (the reference's
-        # bias_add_transform_0213, transform_kernels.cu:149)
-        def split_heads(t):
-            return t.reshape(b, s, heads, head_dim).transpose(0, 2, 1, 3)
+        product = attn_in @ p["attn_qkvw"]
+        biased = product + p["attn_qkvb"]
+        qkv = apply_lora(cfg, p, lora, "attn_qkvw", attn_in, biased)
+        # [B,S,3H] -> 3 x [B,heads,S,hd]  (the reference's
+        # bias_add_transform_0213, transform_kernels.cu:149): only for the
+        # callers that need heads apart (sequence parallelism, the KV
+        # cache's prefill); the dispatcher below takes ``qkv`` whole
+        def split_heads():
+            return tuple(
+                t.reshape(b, s, heads, head_dim).transpose(0, 2, 1, 3)
+                for t in jnp.split(qkv, 3, axis=-1)
+            )
 
         from ..config import constants as C
 
@@ -454,7 +459,6 @@ def transformer_block_apply(
             mesh is not None
             and dict(mesh.shape).get(C.SEQUENCE_AXIS, 1) > 1
         )
-        qh, kh, vh = split_heads(q), split_heads(k_), split_heads(v)
         if seq_parallel:
             from ..parallel.sequence import sequence_parallel_attention
 
@@ -471,23 +475,31 @@ def transformer_block_apply(
                     "masks only (broadcast over the query dim)"
                 )
             ctx = sequence_parallel_attention(
-                qh, kh, vh,
+                *split_heads(),
                 mesh, kv_valid, impl=seq_parallel_impl,
                 use_flash=use_flash, causal=causal,
                 dropout_rate=cfg.attn_dropout_ratio if train else 0.0,
                 dropout_rng=attn_rng,
             )
+            ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, H)  # transform4d_0213
         else:
-            # with a dp/mp mesh the dispatcher runs flash per-shard via
-            # shard_map instead of falling back to O(S^2) attention
-            ctx = attention(
-                qh, kh, vh,
+            # the projection's result goes in whole and the context comes
+            # back [B,S,H]: where the flash kernels run (one device, or per
+            # shard of a dp mesh via shard_map) they read the heads out of
+            # ``qkv`` themselves; else the dispatcher splits as above
+            # Without an adapter on this projection the BARE product goes
+            # in with the bias beside it: kernels that read it as it lies
+            # add the bias as they load, so what the projection writes is
+            # what a remat policy saves and what backward hands them.
+            bare = qkv is biased
+            ctx = attention_packed(
+                product if bare else qkv, heads,
+                bias=p["attn_qkvb"] if bare else None,
                 mask=attention_mask, causal=causal,
                 dropout_rate=cfg.attn_dropout_ratio if train else 0.0,
                 dropout_rng=attn_rng, use_flash=use_flash,
                 mesh=mesh,
             )
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, H)  # transform4d_0213
         attn_out = apply_lora(
             cfg, p, lora, "attn_ow", ctx, ctx @ p["attn_ow"] + p["attn_ob"]
         )
@@ -526,7 +538,7 @@ def transformer_block_apply(
                     "return_kv does not compose with an aux-returning "
                     "ffn_fn (MoE decode is not supported)"
                 )
-            return x, (kh, vh)
+            return x, split_heads()[1:]
         return x if ffn_aux is None else (x, ffn_aux)
 
     if cfg.use_remat and not return_kv:

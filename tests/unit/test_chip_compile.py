@@ -164,6 +164,143 @@ def test_flash_kernel_compiles_at_the_cells_shapes(kernel, cell):
 
 
 # ---------------------------------------------------------------------------
+# the layer body around the kernels (ops/transformer.py:block, PR 29)
+# ---------------------------------------------------------------------------
+@functools.lru_cache(maxsize=None)
+def _layer_body_text(model):
+    """Forward and backward of two scanned transformer blocks at the GPT-2
+    cells' [8, 1024, width] bf16, causal, dropout off, under the cells' remat
+    policy: the scan saves its residuals in stacks and slices them out
+    again, as the cells' 36 layers do, so the layer body compiles as it
+    does inside their window."""
+    from deepspeed_tpu.ops.transformer import (
+        TRANSFORMER_PARAM_LAYOUT, DeepSpeedTransformerConfig,
+        transformer_block_apply,
+    )
+
+    width, layers = WIDTH[model], 2
+    cfg = DeepSpeedTransformerConfig(
+        hidden_size=width, heads=HEADS[model], attn_dropout_ratio=0.0,
+        hidden_dropout_ratio=0.0, normalize_invertible=True,
+        remat_policy="dots_with_no_batch_dims_saveable+flash_out+flash_lse",
+    )
+    dims = {"H": width, "3H": 3 * width, "I": 4 * width}
+    params = {
+        name: _shape(
+            (layers,) + tuple(dims[d] for d in shape),
+            jnp.float32 if kind.endswith("32") else jnp.bfloat16,
+        )
+        for name, shape, kind in TRANSFORMER_PARAM_LAYOUT
+    }
+
+    def loss(params, x):
+        def layer(h, p):
+            return transformer_block_apply(cfg, p, h, causal=True), None
+
+        return jax.lax.scan(layer, x, params)[0].astype(jnp.float32).sum()
+
+    real = device.on_tpu, jax.device_count
+    device.on_tpu, jax.device_count = (lambda: True), (lambda: 1)
+    try:
+        return _compiled_text(
+            jax.grad(loss, argnums=(0, 1)), params,
+            _shape((8, 1024, width), jnp.bfloat16),
+        )
+    finally:
+        device.on_tpu, jax.device_count = real
+
+
+_HLO_BYTES = {"bf16": 2, "f32": 4, "s32": 4, "u32": 4, "s8": 1, "pred": 1}
+_HLO_NO_WORK = ("get-tuple-element", "bitcast", "parameter", "tuple", "while")
+
+
+def _large_instructions(text, at_least=15e6):
+    """(opcode, result, op_name) of every instruction of a compiled program
+    that is run by itself (not those inside a fusion's computation) and
+    whose result holds ``at_least`` bytes; names for values that exist
+    already (a tuple's element, a bitcast) are left out."""
+    import re
+
+    fused = set(re.findall(r"fusion\([^\n]*calls=%([\w.\-]+)", text))
+    computation, out = None, []
+    for line in text.splitlines():
+        opened = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if opened:
+            computation = opened.group(1)
+            continue
+        m = re.match(
+            r"\s+(?:ROOT )?%[\w.\-]+ = (\(?[a-z0-9]+\[[^=]*?) ([a-z\-]+)\(", line
+        )
+        if not m or computation in fused or m.group(2) in _HLO_NO_WORK:
+            continue
+        size = 0
+        for dtype, shape in re.findall(r"([a-z0-9]+)\[([\d,]*)\]", m.group(1)):
+            n = _HLO_BYTES.get(dtype, 0)
+            for extent in filter(None, shape.split(",")):
+                n *= int(extent)
+            size += n
+        if size >= at_least:
+            name = re.search(r'op_name="([^"]*)"', line)
+            out.append((m.group(2), m.group(1), name.group(1) if name else ""))
+    return out
+
+
+def _layout_operations(text):
+    """The operations of 15 MB and more that move bytes and compute
+    nothing: ``copy`` instructions, and fusions that XLA names after a
+    split, a transpose or a reshape."""
+    large = _large_instructions(text)
+    copies = [i for i in large if i[0] == "copy"]
+    splits = [
+        i for i in large if i[0] == "fusion"
+        and i[2].rsplit("/", 1)[-1] in ("split", "transpose", "reshape")
+    ]
+    return copies, splits
+
+
+def test_layer_body_hands_the_kernels_the_projections_own_buffer():
+    """Before PR 29 this compile counted 19 ``copy`` instructions and 2
+    split fusions of 15 MB and more in the two layer bodies (6 forward, 11
+    backward around the kernels, 2 for the stacks: q, k, v and dO laid out
+    as ``[8, 20, 1024, 64]`` and the gradients back; ISSUE 29 lists them),
+    157 mentions of a ``[.., 1024, 64]`` array, a second and a third write
+    of the qkv projection's result and the bias added again in backward.
+    Now the kernels read heads out of ``[8, 1024, 3840]`` and write
+    ``[8, 1024, 1280]``: no such array is left, nothing is copied, the
+    projection writes into the saved stack as its epilogue, and backward
+    takes the one slice of the saved product out of its stack and hands it
+    to the kernels as it is."""
+    text = _layer_body_text("large")
+    assert "1024,64]" not in text
+    copies, splits = _layout_operations(text)
+    assert copies == [] and splits == []
+    large = _large_instructions(text)
+    qkv = [i for i in large if i[1].startswith("bf16[8,1024,3840]")]
+    # the projection's result on its own: that one slice, nothing else (the
+    # projection itself writes a tuple: the stack and the value)
+    assert [i[2].rsplit("/", 1)[-1] for i in qkv] == ["squeeze"], qkv
+    stacked = [
+        i for i in large if i[1].startswith("(bf16[2,8,1024,3840]")
+        and i[2].endswith("dot_general")
+    ]
+    assert len(stacked) == 1, "the projection no longer writes into the stack"
+    calls = [l for l in text.splitlines() if "tpu_custom_call" in l]
+    assert len(calls) == 3, calls
+
+
+def test_layer_body_falls_back_to_split_heads_at_25_heads():
+    """GPT-2 XL's 25 heads of 64 do not pair into 128-lane blocks: the
+    dispatcher splits as before, the ``[B, H, S, D]`` kernels compile, and
+    the copies are back."""
+    text = _layer_body_text("xl")
+    assert "[8,25,1024,64]" in text
+    copies, splits = _layout_operations(text)
+    assert len(copies) >= 6 and len(splits) == 2
+    calls = [l for l in text.splitlines() if "tpu_custom_call" in l]
+    assert len(calls) == 3, calls
+
+
+# ---------------------------------------------------------------------------
 # the hybrid stack's mixers at the widths of its benchmark cell
 # (models/hybrid.py; micro 2 x seq 8192, hidden 4096, bf16)
 # ---------------------------------------------------------------------------

@@ -5,6 +5,8 @@ test_cuda_backward.py: numerical parity of the fused layer against a naive
 baseline across batch/seq/pre-post-LN grids, in fwd and bwd.
 """
 
+import contextlib
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
@@ -287,11 +289,55 @@ FLASH_CASES = {
 }
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
-@pytest.mark.parametrize("case", sorted(FLASH_CASES))
-def test_flash_tiled_matches_reference(case, dtype):
+# The packed entry (PR 29: the kernels read heads out of the fused qkv
+# projection's [B, S, 3*H*D] result, two heads of 64 or one of 128 to a
+# 128-lane block, and write [B, S, H*D]) runs the same comparison: (case,
+# dtype, head_dim). Only self-attention shapes have a packed form.
+_DTYPES = {"f32": jnp.float32, "bf16": jnp.bfloat16}
+FLASH_RUNS = [
+    pytest.param(case, dtype, None, id=f"{case}-{dtype}")
+    for case in sorted(FLASH_CASES) for dtype in _DTYPES
+] + [
+    pytest.param(case, "f32", 64, id=f"{case}-f32-packed_d64")
+    for case in sorted(FLASH_CASES) if case != "causal_sq256_sk512"
+] + [
+    pytest.param(case, dtype, d, id=f"{case}-{dtype}-packed_d{d}")
+    for case, dtype, d in [
+        ("causal_1024", "bf16", 64),
+        ("causal_2048", "bf16", 64),
+        ("noncausal_384_masked", "bf16", 64),
+        ("causal_1024", "f32", 128),
+        ("causal_1024", "bf16", 128),
+        ("causal_2048", "f32", 128),
+        ("causal_768_blocks256", "f32", 128),
+        ("noncausal_384_masked", "f32", 128),
+    ]
+]
+
+
+def _packed_as_split(q, k, v, **kw):
+    """``flash_attention_packed`` behind the split signature: q, k, v
+    [B, H, S, D] are laid side by side as the projection would, and the
+    context comes back [B, H, S, D]."""
+    from deepspeed_tpu.ops.attention import flash_attention_packed
+
+    def merge(t):
+        return t.transpose(0, 2, 1, 3).reshape(b, s, h * d)
+
+    b, h, s, d = q.shape
+    qkv = jnp.concatenate([merge(q), merge(k), merge(v)], axis=-1)
+    out = flash_attention_packed(qkv, h, **kw)
+    return out.reshape(b, s, h, d).transpose(0, 2, 1, 3)
+
+
+@pytest.mark.parametrize("case,dtype,packed_d", FLASH_RUNS)
+def test_flash_tiled_matches_reference(case, dtype, packed_d):
     sq, sk, causal, masked, block = FLASH_CASES[case]
+    dtype = _DTYPES[dtype]
     B, H, D = (1, 1, 64) if sq > 1024 else (2, 2, 64)
+    if packed_d:
+        # a 128-lane block holds whole heads: two of 64, one of 128
+        H, D = max(H, 128 // packed_d), packed_d
     rng = np.random.default_rng(5)
     q, k, v = (
         jnp.asarray(rng.normal(size=(B, H, s, D)), dtype) for s in (sq, sk, sk)
@@ -314,8 +360,10 @@ def test_flash_tiled_matches_reference(case, dtype):
     def f32(x):
         return x.astype(jnp.float32)
 
+    kernel = _packed_as_split if packed_d else flash_attention
+
     def flash(q, k, v):
-        return f32(flash_attention(
+        return f32(kernel(
             q, k, v, kv_mask=kv_mask, causal=causal, block_q=block, block_k=block
         ))
 
@@ -406,29 +454,35 @@ def test_flash_loop_bounds_stop_at_the_diagonal():
         assert by_keys == by_rows
 
 
-def test_flash_tiling_is_logged_once_per_shape():
+@contextlib.contextmanager
+def _attention_debug_log():
+    """The debug lines ``ops/attention.py`` logs while the block is open,
+    with its once-a-shape caches emptied first."""
     import importlib
     import logging
 
     # ``deepspeed_tpu.ops.attention`` the attribute is the dispatcher
     att = importlib.import_module("deepspeed_tpu.ops.attention")
     seen = []
-
-    class Grab(logging.Handler):
-        def emit(self, record):
-            seen.append(record.getMessage())
-
-    handler, level = Grab(), att.logger.level
+    handler = logging.Handler()
+    handler.emit = lambda record: seen.append(record.getMessage())
+    level = att.logger.level
     att.logger.addHandler(handler)
     att.logger.setLevel(logging.DEBUG)
     att._log_tiling.cache_clear()
+    att._log_layout.cache_clear()
     try:
-        q = jnp.zeros((1, 1, 1024, 64), jnp.float32)
-        for _i in range(2):
-            jax.eval_shape(lambda q: flash_attention(q, q, q, causal=True), q)
+        yield att, seen
     finally:
         att.logger.removeHandler(handler)
         att.logger.setLevel(level)
+
+
+def test_flash_tiling_is_logged_once_per_shape():
+    with _attention_debug_log() as (att, seen):
+        q = jnp.zeros((1, 1, 1024, 64), jnp.float32)
+        for _i in range(2):
+            jax.eval_shape(lambda q: flash_attention(q, q, q, causal=True), q)
     lines = [m for m in seen if m.startswith("flash_tiling")]
     assert len(lines) == 1
     t = att.flash_tiling(1024, 1024, 1024, 1024, True)
@@ -437,3 +491,144 @@ def test_flash_tiling_is_logged_once_per_shape():
     t = att.flash_tiling(1024, 1024, 1024, 1024, True, key_major=True)
     assert f"dkv_sub={t['sub_q']}x{t['sub_k']} " in lines[0]
     assert lines[0].endswith(f"dkv_visited_share={t['visited_share']:.4f}")
+
+
+# what the dispatcher sees in each cell of the benchmark (and in two shapes
+# no cell runs), and the layout it must choose from that alone:
+# (entry, batch, seq, heads, head_dim, kv heads, mesh (data, model),
+#  layout, heads a block, a word of the reason)
+LAYOUT_CASES = {
+    "gpt2-large.train-seq1024": ("packed", 8, 1024, 20, 64, 20, (1, 1), "packed", 2, None),
+    "gpt2-large.train-accum1": ("packed", 8, 1024, 20, 64, 20, (1, 1), "packed", 2, None),
+    # dp 4: 32 rows over the data axis, the kernels run per shard
+    "gpt2-large.zero2-dp4": ("packed", 32, 1024, 20, 64, 20, (4, 1), "packed", 2, None),
+    # seq 128 never reaches the kernels
+    "bert-large.pretrain-seq128": ("packed", 32, 128, 16, 64, 16, (1, 1), "split", 1, "FLASH_MIN_SEQ"),
+    # the hybrid mixer: separate projections, one kv head for four
+    "nemotron3-super-120b-a12b.train-seq8192": ("split", 2, 8192, 4, 128, 1, (1, 1), "split", 1, "separate"),
+    "gpt2-xl_25_heads": ("packed", 4, 1024, 25, 64, 25, (1, 1), "split", 1, "25 heads"),
+    "head_dim_128": ("packed", 2, 2048, 8, 128, 8, (1, 1), "packed", 1, None),
+    "head_dim_80": ("packed", 2, 1024, 16, 80, 16, (1, 1), "split", 1, "head_dim 80"),
+    "model_axis_2": ("packed", 8, 1024, 20, 64, 20, (2, 2), "split", 1, "model axis"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(LAYOUT_CASES))
+def test_attention_layout_is_logged_with_its_reason(case):
+    from deepspeed_tpu.parallel.mesh import build_mesh
+
+    entry, b, s, h, d, kv, (dp, mp), layout, heads_a_block, reason = (
+        LAYOUT_CASES[case]
+    )
+    mesh = build_mesh(
+        devices=jax.devices()[:dp * mp], data_parallel_size=dp,
+        model_parallel_size=mp,
+    )
+    with _attention_debug_log() as (att, seen):
+        for _i in range(2):
+            if entry == "packed":
+                out = jax.eval_shape(
+                    lambda x: att.attention_packed(
+                        x, h, causal=True, mesh=mesh),
+                    jax.ShapeDtypeStruct((b, s, 3 * h * d), jnp.bfloat16),
+                )
+                assert out.shape == (b, s, h * d)
+            else:
+                jax.eval_shape(
+                    lambda q, k: att.attention(q, k, k, causal=True, mesh=mesh),
+                    jax.ShapeDtypeStruct((b, h, s, d), jnp.bfloat16),
+                    jax.ShapeDtypeStruct((b, kv, s, d), jnp.bfloat16),
+                )
+    lines = [m for m in seen if m.startswith("attention_layout")]
+    assert len(lines) == 1, lines
+    assert f" b={b} s={s} heads={h} d={d} " in lines[0]
+    assert f" layout={layout} heads_a_block={heads_a_block}" in lines[0]
+    if reason is None:
+        assert "reason" not in lines[0]
+    else:
+        assert reason in lines[0].split("reason=")[1]
+    # the chooser itself says the same
+    chosen = att.attention_layout(
+        b, s, h, d, att._flash_gate(s, s, None, 0.0, None, True)[0], mesh)
+    if entry == "packed":
+        assert chosen[:2] == (layout, heads_a_block)
+
+
+@pytest.mark.parametrize("variant", ["one_device", "dp2_per_shard", "lora", "return_kv"])
+def test_block_on_the_packed_route_matches_the_plain_block(variant):
+    """``block()`` hands the kernels the projection's bare product and its
+    bias (one device, or per shard of a data-parallel mesh) and gets
+    [B, S, H] back: output and every parameter's gradient against the
+    hand-written block; an adapter on ``attn_qkvw`` and the KV cache's
+    prefill (``return_kv``) keep working."""
+    from deepspeed_tpu.ops.transformer import (
+        TRANSFORMER_PARAM_LAYOUT, transformer_block_apply,
+    )
+    from deepspeed_tpu.parallel.mesh import build_mesh
+
+    dp = 2 if variant == "dp2_per_shard" else 1
+    mesh = build_mesh(devices=jax.devices()[:dp], data_parallel_size=dp)
+    cfg = DeepSpeedTransformerConfig(
+        hidden_size=128, heads=2, attn_dropout_ratio=0.0,
+        hidden_dropout_ratio=0.0, lora_rank=4 if variant == "lora" else 0,
+        lora_targets=("attn_qkvw",) if variant == "lora" else (),
+    )
+    dims = {"H": 128, "3H": 384, "I": 512}
+    keys = iter(jax.random.split(jax.random.PRNGKey(7), 32))
+    p = {
+        name: (1.0 if kind.startswith("ones") else 0.0)
+        + 0.05 * jax.random.normal(next(keys), tuple(dims[d] for d in shape))
+        for name, shape, kind in TRANSFORMER_PARAM_LAYOUT
+    }
+    if variant == "lora":
+        p["attn_qkvw_lora_a"] = 0.1 * jax.random.normal(next(keys), (128, 4))
+        p["attn_qkvw_lora_b"] = 0.1 * jax.random.normal(next(keys), (4, 384))
+    x = jax.random.normal(next(keys), (2, 256, 128))
+
+    def plain(p, x):
+        if variant == "lora":
+            p = dict(p, attn_qkvw=p["attn_qkvw"]
+                     + p["attn_qkvw_lora_a"] @ p["attn_qkvw_lora_b"])
+        return naive_layer_forward(p, x, cfg, causal=True)
+
+    with _attention_debug_log() as (_att, seen):
+        if variant == "return_kv":
+            out, (k, v) = transformer_block_apply(
+                cfg, p, x, causal=True, train=False, mesh=mesh, return_kv=True)
+            qkv = naive_qkv(p, x, cfg)
+            np.testing.assert_allclose(np.asarray(k), np.asarray(qkv[1]), atol=1e-5)
+            np.testing.assert_allclose(np.asarray(v), np.asarray(qkv[2]), atol=1e-5)
+            np.testing.assert_allclose(
+                np.asarray(out), np.asarray(plain(p, x)), rtol=2e-4, atol=2e-4)
+        else:
+            def ours(p, x):
+                return transformer_block_apply(
+                    cfg, p, x, causal=True, train=False, mesh=mesh)
+
+            w = jax.random.normal(next(keys), x.shape)
+            (lo, go), (lr, gr) = (
+                jax.value_and_grad(lambda p, x: jnp.sum(f(p, x) * w), (0, 1))(p, x)
+                for f in (ours, plain)
+            )
+            np.testing.assert_allclose(float(lo), float(lr), rtol=2e-4)
+            for a, b in zip(jax.tree_util.tree_leaves(go),
+                            jax.tree_util.tree_leaves(gr)):
+                scale = float(jnp.abs(b).max()) or 1.0
+                assert float(jnp.abs(a - b).max()) <= 2e-3 * scale
+    lines = [m for m in seen if m.startswith("attention_layout")]
+    assert lines and all("layout=packed heads_a_block=2" in m for m in lines)
+
+
+def naive_qkv(params, x, cfg):
+    """q, k, v [B, heads, S, hd] of the hand-written block."""
+    def ln(t, w, b):
+        mu, var = t.mean(-1, keepdims=True), t.var(-1, keepdims=True)
+        return ((t - mu) / jnp.sqrt(var + cfg.layer_norm_eps)) * w + b
+
+    h = ln(x, params["attn_nw"], params["attn_nb"]) if cfg.pre_layer_norm else x
+    qkv = h @ params["attn_qkvw"] + params["attn_qkvb"]
+    b, s, _ = x.shape
+    return [
+        t.reshape(b, s, cfg.heads, -1).transpose(0, 2, 1, 3)
+        for t in jnp.split(qkv, 3, axis=-1)
+    ]

@@ -36,7 +36,9 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.ops.attention import flash_attention, mha_reference
+from deepspeed_tpu.ops.attention import (
+    flash_attention, flash_attention_packed, mha_reference,
+)
 
 pytestmark = [
     pytest.mark.tpu,
@@ -195,6 +197,119 @@ def test_flash_dropout_fwd_bwd_mask_consistency(wrt):
     assert abs(fd - ad) / scale < 0.15, (
         f"directional derivative mismatch wrt {'qkv'[wrt]}: fd={fd:.4f} "
         f"ad={ad:.4f} — fwd/bwd dropout masks disagree"
+    )
+
+
+# ---------------------------------------------------------------------------
+# the packed entry (PR 29): the kernels read heads out of the fused qkv
+# projection's [B, S, 3*H*D] result and write [B, S, H*D]
+# ---------------------------------------------------------------------------
+def _merge(t):
+    b, h, s, d = t.shape
+    return t.transpose(0, 2, 1, 3).reshape(b, s, h * d)
+
+
+def _f32(x):
+    return x.astype(jnp.float32)
+
+
+def _projection(shape, seed, dtype=jnp.bfloat16):
+    """q, k, v [B, H, S, D], the same numbers side by side as the
+    projection lays them [B, S, 3*H*D], and a weight for the loss."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    q, k, v, w = (
+        jax.random.normal(kk, shape, jnp.float32).astype(dtype) for kk in ks
+    )
+    qkv = jnp.concatenate([_merge(q), _merge(k), _merge(v)], -1)
+    return (q, k, v), qkv, _f32(_merge(w))
+
+
+def _one_step_apart(a, b, what):
+    """bf16 roundings of float32 sums that differ in their last bits: a few
+    elements land one bf16 step apart, none further."""
+    a, b = np.asarray(_f32(a)), np.asarray(_f32(b))
+    off = np.abs(a - b)
+    assert off.max() <= 2.0 ** -7 * np.abs(a).max(), f"{what}: {off.max():.3e}"
+    assert (off > 0).mean() < 0.02, f"{what}: {(off > 0).mean():.4f} differ"
+
+
+@pytest.mark.parametrize("dropout", [0.0, 0.3], ids=["plain", "dropout"])
+@pytest.mark.parametrize(
+    "shape", [(8, 20, 1024, 64), (2, 8, 2048, 128)],
+    ids=["cells_two_heads_a_block", "d128_grid_of_blocks"],
+)
+def test_packed_matches_split_bit_for_bit(shape, dropout):
+    """Same kernels, same products, same dropout bits by global position
+    and head: the context is the SAME BITS whichever layout the operands
+    arrive in. The gradients differ only through delta (a 0/1 product in
+    place of a float32 reduction over a head's 64 or 128 terms)."""
+    h = shape[1]
+    (q, k, v), qkv, w = _projection(shape, 29)
+    kw = dict(causal=True, dropout_rate=dropout, dropout_seed=5)
+
+    def split(q, k, v):
+        return _merge(flash_attention(q, k, v, **kw))
+
+    def packed(qkv):
+        return flash_attention_packed(qkv, h, **kw)
+
+    np.testing.assert_array_equal(
+        np.asarray(_f32(jax.jit(split)(q, k, v))),
+        np.asarray(_f32(jax.jit(packed)(qkv))),
+    )
+    gs = jax.jit(jax.grad(
+        lambda *a: jnp.sum(_f32(split(*a)) * w), argnums=(0, 1, 2)
+    ))(q, k, v)
+    gp = jax.jit(jax.grad(lambda a: jnp.sum(_f32(packed(a)) * w)))(qkv)
+    _one_step_apart(jnp.concatenate([_merge(g) for g in gs], -1), gp, "dqkv")
+
+
+def test_packed_bias_is_the_projections_own_sum():
+    """The kernels add the projection's bias as they load: the same bf16
+    sum XLA writes out, so context and gradients are the same bits as for
+    the biased operand; the bias's gradient is a float32 sum over rows."""
+    shape = (8, 20, 1024, 64)
+    _, qkv, w = _projection(shape, 30)
+    bias = jax.random.normal(
+        jax.random.PRNGKey(31), (qkv.shape[-1],), jnp.float32
+    ).astype(jnp.bfloat16)
+
+    def inside(qkv, bias):
+        return flash_attention_packed(qkv, shape[1], bias=bias, causal=True)
+
+    def outside(qkv, bias):
+        return flash_attention_packed(qkv + bias, shape[1], causal=True)
+
+    def both(f):
+        return jax.jit(jax.value_and_grad(
+            lambda a, b: jnp.sum(_f32(f(a, b)) * w), argnums=(0, 1)
+        ))(qkv, bias)
+
+    (li, (gi, bi)), (lo, (go, bo)) = both(inside), both(outside)
+    assert float(li) == float(lo)
+    np.testing.assert_array_equal(np.asarray(_f32(gi)), np.asarray(_f32(go)))
+    _one_step_apart(bo, bi, "dbias")
+
+
+def test_packed_dropout_fwd_bwd_mask_consistency():
+    """The directional derivative of the packed entry with dropout on, as
+    ``test_flash_dropout_fwd_bwd_mask_consistency`` for the split one: a
+    head's bits are drawn by its global index from a program that holds
+    two heads, in the forward and in both backward kernels."""
+    shape = (B, H, S, D)
+    _, qkv, w = _projection(shape, 32, jnp.float32)
+
+    def loss(x):
+        return jnp.sum(flash_attention_packed(
+            x, H, causal=True, dropout_rate=0.3, dropout_seed=11) * w)
+
+    g = jax.jit(jax.grad(loss))(qkv)
+    d = jax.random.normal(jax.random.PRNGKey(33), qkv.shape, jnp.float32)
+    h, jl = 2e-2, jax.jit(loss)
+    fd = (float(jl(qkv + h * d)) - float(jl(qkv - h * d))) / (2 * h)
+    ad = float(jnp.sum(g * d))
+    assert abs(fd - ad) / max(abs(fd), abs(ad), 1.0) < 0.15, (
+        f"directional derivative mismatch: fd={fd:.4f} ad={ad:.4f}"
     )
 
 
