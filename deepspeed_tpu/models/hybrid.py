@@ -2,9 +2,17 @@
 a pre-RMS-norm and a residual, and a pattern string says which — ``M`` a
 Mamba-2 state-space mixer (ops/ssm.py), ``E`` a latent mixture of experts
 that drops no token (ops/moe.py:latent_moe_mixer), ``*`` causal
-grouped-query attention (ops/transformer.py:gqa_attention_mixer). No
-position embedding (the state-space layers carry position), a final RMS
-norm, an untied head, bias-free projections, squared-ReLU experts.
+grouped-query attention (ops/transformer.py:gqa_attention_mixer); ``D`` a
+Gated DeltaNet linear-attention mixer (ops/linear_attention.py), ``G`` gated
+softmax attention with per-head q/k norms and partial rotary
+(ops/transformer.py:gated_attention_mixer), ``X`` a mixture of SiLU-gated
+experts at the model's own width behind softmax routing, with a gated shared
+expert (ops/moe.py:gated_moe_mixer). A published layer that is a token mixer
+THEN experts is two letters (``DXDXDXGX`` is one period of three DeltaNet
+layers and one attention layer, each with its experts). No learned position
+embedding (the recurrent layers carry position; ``G`` rotates), a final RMS
+norm, an untied head, bias-free projections; ``norm_zero_centered`` stores
+every norm's gain around 0 and applies ``1 + gain``.
 
 ``models/stack.py`` and ``nn.scan`` assume identical layers, so this stack
 is a Python loop over the pattern. The parameters of each KIND are stacked
@@ -33,15 +41,18 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.cross_entropy import blocked_lm_head_loss
-from ..ops.moe import latent_moe_mixer
+from ..ops.linear_attention import gated_deltanet_mixer
+from ..ops.moe import gated_moe_mixer, latent_moe_mixer
 from ..ops.ssm import mamba2_mixer
 from ..ops.transformer import (
+    gated_attention_mixer,
     gqa_attention_mixer,
     resolve_remat_policy,
     rms_norm,
 )
 
-KINDS = {"M": "mamba", "E": "moe", "*": "attn"}
+KINDS = {"M": "mamba", "E": "moe", "*": "attn",
+         "D": "gdn", "G": "gattn", "X": "gmoe"}
 
 
 @dataclasses.dataclass(unsafe_hash=True)
@@ -50,6 +61,8 @@ class HybridLMConfig:
     hidden_size: int = 64
     pattern: str = "MEM*E"
     norm_eps: float = 1e-5
+    # gains stored around 0 and applied as 1 + gain (the final norm too)
+    norm_zero_centered: bool = False
     initializer_range: float = 0.02
     # M: Mamba-2. heads and groups HELD here (a whole group at a time)
     mamba_heads: int = 4
@@ -74,10 +87,23 @@ class HybridLMConfig:
     moe_shared_intermediate: int = 96
     # rows per tile of the grouped expert products
     moe_tile: int = 512
-    # *: grouped-query attention. heads HELD here
+    # X: gated experts at the model's width. Shares the held/routed counts,
+    # top_k, router_force_level, moe_intermediate, moe_shared_intermediate
+    # and moe_tile with E; has no latent, bias or scaling
+    # * and G: grouped-query attention. heads HELD here
     attn_heads: int = 2
     kv_heads: int = 1
     head_dim: int = 16
+    # G: lanes of each head that rotate (0: none), and the base
+    rotary_lanes: int = 0
+    rope_theta: float = 10000.0
+    # D: Gated DeltaNet. key heads each serve value_heads / key_heads value
+    # heads; the chunk of the recurrence is a power of two; conv_kernel taps
+    gdn_key_heads: int = 2
+    gdn_value_heads: int = 4
+    gdn_key_dim: int = 16
+    gdn_value_dim: int = 16
+    gdn_chunk: int = 64
     # per-layer remat (jax.checkpoint around each layer, whatever its kind)
     remat: bool = False
     remat_policy: str = "nothing_saveable"
@@ -90,11 +116,16 @@ class HybridLMConfig:
         if unknown:
             raise ValueError(
                 f"pattern {self.pattern!r}: unknown layer kinds "
-                f"{sorted(unknown)}; M, E and * are known")
+                f"{sorted(unknown)}; {', '.join(KINDS)} are known")
         if self.mamba_heads % self.mamba_groups:
             raise ValueError("mamba_heads must be a multiple of mamba_groups")
         if self.attn_heads % self.kv_heads:
             raise ValueError("attn_heads must be a multiple of kv_heads")
+        if self.gdn_value_heads % self.gdn_key_heads:
+            raise ValueError(
+                "gdn_value_heads must be a multiple of gdn_key_heads")
+        if self.rotary_lanes % 2 or self.rotary_lanes > self.head_dim:
+            raise ValueError("rotary_lanes must be even and within head_dim")
         if not (0 <= self.expert_offset
                 and self.expert_offset + self.n_experts_held
                 <= self.n_experts_routed):
@@ -107,6 +138,9 @@ class HybridLMConfig:
         conv = di + 2 * self.mamba_groups * self.ssm_state
         lat, f = self.moe_latent, self.moe_intermediate
         qd, kvd = self.attn_heads * self.head_dim, self.kv_heads * self.head_dim
+        gqk = self.gdn_key_heads * self.gdn_key_dim
+        gvz = self.gdn_value_heads * self.gdn_value_dim
+        fs = self.moe_shared_intermediate
         return {
             "mamba": {
                 "norm": (e,), "in_proj": (e, di + conv + self.mamba_heads),
@@ -128,19 +162,42 @@ class HybridLMConfig:
                 "norm": (e,), "wq": (e, qd), "wk": (e, kvd), "wv": (e, kvd),
                 "wo": (qd, e),
             },
+            "gdn": {
+                "norm": (e,), "in_qkvz": (e, 2 * gqk + 2 * gvz),
+                "in_ba": (e, 2 * self.gdn_value_heads),
+                "conv_w": (self.conv_kernel, 2 * gqk + gvz),
+                "dt_bias": (self.gdn_value_heads,),
+                "A_log": (self.gdn_value_heads,),
+                "out_norm": (self.gdn_value_dim,), "out_proj": (gvz, e),
+            },
+            "gattn": {
+                "norm": (e,), "wq": (e, 2 * qd), "wk": (e, kvd),
+                "wv": (e, kvd), "q_norm": (self.head_dim,),
+                "k_norm": (self.head_dim,), "wo": (qd, e),
+            },
+            "gmoe": {
+                "norm": (e,), "router": (e, self.n_experts_routed),
+                "wg": (self.n_experts_held, e, f),
+                "wu": (self.n_experts_held, e, f),
+                "wd": (self.n_experts_held, f, e),
+                "shared_wg": (e, fs), "shared_wu": (e, fs),
+                "shared_wd": (fs, e), "shared_gate": (e, 1),
+            },
         }
 
 
-# leaves that start at 1 (gains, D) and at 0 (biases); A_log and dt_bias
-# start at the family's usual spread, every other leaf at N(0, range)
-_ONES = ("norm", "gate_norm", "D")
+# leaves that start at 1 (gains, D) and at 0 (biases; the gains that
+# ``norm_zero_centered`` stores around 0); A_log and dt_bias start at the
+# family's usual spread, every other leaf at N(0, range)
+_ONES = ("gate_norm", "out_norm", "D")
+_GAINS = ("norm", "q_norm", "k_norm")
 _ZEROS = ("conv_b", "router_bias")
 
 
 def _leaf_init(cfg, leaf):
-    if leaf in _ONES:
+    if leaf in _ONES or (leaf in _GAINS and not cfg.norm_zero_centered):
         return nn.initializers.ones
-    if leaf in _ZEROS:
+    if leaf in _ZEROS or leaf in _GAINS:
         return nn.initializers.zeros
     if leaf == "A_log":
         return lambda key, shape, dtype=jnp.float32: jnp.broadcast_to(
@@ -168,7 +225,7 @@ class HybridModel(nn.Module):
         embed = self.param("embed", init, (cfg.vocab_size, cfg.hidden_size))
         head = self.param("head", init, (cfg.vocab_size, cfg.hidden_size))
         norm_f = self.param(
-            "norm_f", nn.initializers.ones, (cfg.hidden_size,))
+            "norm_f", _leaf_init(cfg, "norm"), (cfg.hidden_size,))
         params = {}
         for kind, leaves in cfg.leaf_shapes().items():
             n = sum(KINDS[c] == kind for c in cfg.pattern)
@@ -191,12 +248,26 @@ class HybridModel(nn.Module):
             "attn": lambda p, x: (gqa_attention_mixer(
                 p, x, heads=cfg.attn_heads, kv_heads=cfg.kv_heads,
                 head_dim=cfg.head_dim, mesh=cfg.mesh), {}),
+            "gdn": lambda p, x: (gated_deltanet_mixer(
+                p, x, key_heads=cfg.gdn_key_heads,
+                value_heads=cfg.gdn_value_heads, key_dim=cfg.gdn_key_dim,
+                value_dim=cfg.gdn_value_dim, chunk=cfg.gdn_chunk,
+                eps=cfg.norm_eps), {}),
+            "gattn": lambda p, x: (gated_attention_mixer(
+                p, x, heads=cfg.attn_heads, kv_heads=cfg.kv_heads,
+                head_dim=cfg.head_dim, rotary_lanes=cfg.rotary_lanes,
+                rope_theta=cfg.rope_theta, eps=cfg.norm_eps,
+                mesh=cfg.mesh), {}),
+            "gmoe": lambda p, x: gated_moe_mixer(
+                p, x, top_k=cfg.top_k, held=cfg.n_experts_held,
+                offset=cfg.expert_offset, tile=cfg.moe_tile,
+                force_level=cfg.router_force_level),
         }
 
         def layer(kind):
             def apply(p, x):
-                out, counters = mixers[kind](
-                    p, rms_norm(x, p["norm"], cfg.norm_eps))
+                out, counters = mixers[kind](p, rms_norm(
+                    x, p["norm"], cfg.norm_eps, cfg.norm_zero_centered))
                 return x + out.astype(x.dtype), counters
 
             if cfg.remat:
@@ -220,7 +291,8 @@ class HybridModel(nn.Module):
                    else jnp.sum)(
                 jnp.stack([c[name] for c in per_layer]))
             for name in (per_layer[0] if per_layer else {})}
-        return rms_norm(x, norm_f, cfg.norm_eps), head, counters
+        return rms_norm(
+            x, norm_f, cfg.norm_eps, cfg.norm_zero_centered), head, counters
 
 
 class HybridCausalLM(nn.Module):

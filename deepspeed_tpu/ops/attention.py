@@ -109,9 +109,13 @@ DEFAULT_BLOCK_K = 1024
 # it so backward re-gathers instead). "moe_plan" tags the routing choice and
 # the sorted row plan of the no-drop expert layer (ops/moe.py): a few MB of
 # integers that a policy naming it keeps, so that backward does not run the
-# top-k and the sort again.
+# top-k and the sort again. "gdn_segments" tags what the backward pass of the
+# segmented delta rule needs of its forward scan (ops/linear_attention.py:
+# the mixer's output before the out-projection and one state a segment): a
+# policy naming it keeps them, and per-layer remat then does not run the scan
+# over chunks a second time only to have them.
 CHECKPOINT_NAMES = ("attn_probs", "flash_out", "flash_lse", "zero3_gathered",
-                    "moe_plan")
+                    "moe_plan", "gdn_segments")
 
 
 def pick_block(seq, maximum):
@@ -1389,9 +1393,11 @@ def attention(
     O(S^2) path runs and, on a TPU, says why once. ``k``/``v`` may have
     fewer heads than ``q`` (grouped-query attention). A caller that holds
     the fused qkv projection's result takes ``attention_packed``."""
+    why = "q, k and v arrive as separate [B, H, S, D] arrays"
+    refusal = packed_refusal(q.shape[1], q.shape[-1])
     return _attention_split(
         q, k, v, mask, causal, sm_scale, dropout_rate, dropout_rng,
-        use_flash, mesh, "q, k and v arrive as separate [B, H, S, D] arrays",
+        use_flash, mesh, f"{why}; {refusal}" if refusal else why,
     )
 
 

@@ -307,11 +307,17 @@ def route_sigmoid_topk(x, router_w, router_bias, top_k, scale, level=None):
     selection = scores + jax.lax.stop_gradient(router_bias.astype(jnp.float32))
     if level is not None:
         selection = selection + level_selection_scores(level, scores.shape[-1])
+    chosen, picked = _top_k_of(selection, scores, top_k)
+    return chosen, scale * picked / jnp.sum(picked, axis=-1, keepdims=True)
+
+
+def _top_k_of(selection, scores, top_k):
+    """The top-k of ``selection`` a row, and ``scores`` where chosen, 0
+    elsewhere."""
     _, chosen = jax.lax.top_k(selection, top_k)
     experts = jnp.arange(scores.shape[-1], dtype=chosen.dtype)
     mask = jnp.any(chosen[:, :, None] == experts[None, None, :], axis=1)
-    picked = jnp.where(mask, scores, 0.0)
-    return chosen, scale * picked / jnp.sum(picked, axis=-1, keepdims=True)
+    return chosen, jnp.where(mask, scores, 0.0)
 
 
 def plan_held_rows(chosen, held, offset, tile):
@@ -355,9 +361,72 @@ def plan_held_rows(chosen, held, offset, tile):
     }, sizes
 
 
-def _tile_inputs(t, tile, u, w1, w2, weights_t, plan):
+def route_softmax_topk(x, router_w, top_k, level=None):
+    """Softmax in float32 over all routed experts; the top-k of it; weights
+    ``p_e / sum_chosen p`` (the sum runs over all k chosen, held here or
+    not). ``level`` as in ``route_sigmoid_topk``. x [T, E] -> (chosen [T, k]
+    int32, weights [T, routed] float32, 0 where not chosen)."""
+    probs = jax.nn.softmax(
+        jnp.dot(x, router_w, preferred_element_type=jnp.float32), axis=-1)
+    selection = probs
+    if level is not None:
+        selection = probs + level_selection_scores(level, probs.shape[-1])
+    chosen, picked = _top_k_of(selection, probs, top_k)
+    return chosen, picked / jnp.sum(picked, axis=-1, keepdims=True)
+
+
+# The two expert forms of the grouped loop, each as (forward of one tile's
+# rows, its backward): ``relu2`` is ``relu(x W1)^2 W2`` over ``mats = (W1,
+# W2)``; ``swiglu`` is ``(silu(x Wg) * (x Wu)) Wd`` over ``(Wg, Wu, Wd)``.
+# Products take the rows' dtype with float32 accumulation; the backward
+# recomputes the tile's forward rather than keep any tile's activations.
+def _relu2_fwd(x, mats):
+    w1, w2 = mats
+    a = jnp.maximum(jnp.dot(x, w1, preferred_element_type=jnp.float32), 0)
+    h = (a * a).astype(x.dtype)
+    return jnp.dot(h, w2, preferred_element_type=jnp.float32), (a, h)
+
+
+def _relu2_bwd(x, mats, saved, dy):
+    w1, w2 = mats
+    a, h = saved
+    dh = jnp.dot(dy, w2.T, preferred_element_type=jnp.float32)
+    dpre = (dh * 2.0 * a).astype(x.dtype)
+    dw2 = jnp.dot(h.T, dy, preferred_element_type=jnp.float32)
+    dw1 = jnp.dot(x.T, dpre, preferred_element_type=jnp.float32)
+    return jnp.dot(dpre, w1.T, preferred_element_type=jnp.float32), (dw1, dw2)
+
+
+def _swiglu_fwd(x, mats):
+    wg, wu, wd = mats
+    a = jnp.dot(x, wg, preferred_element_type=jnp.float32)
+    b = jnp.dot(x, wu, preferred_element_type=jnp.float32)
+    h = (jax.nn.silu(a) * b).astype(x.dtype)
+    return jnp.dot(h, wd, preferred_element_type=jnp.float32), (a, b, h)
+
+
+def _swiglu_bwd(x, mats, saved, dy):
+    wg, wu, wd = mats
+    a, b, h = saved
+    dh = jnp.dot(dy, wd.T, preferred_element_type=jnp.float32)
+    sig = jax.nn.sigmoid(a)
+    da = (dh * b * sig * (1.0 + a * (1.0 - sig))).astype(x.dtype)
+    db = (dh * a * sig).astype(x.dtype)
+    dwd = jnp.dot(h.T, dy, preferred_element_type=jnp.float32)
+    dwg = jnp.dot(x.T, da, preferred_element_type=jnp.float32)
+    dwu = jnp.dot(x.T, db, preferred_element_type=jnp.float32)
+    dx = jnp.dot(da, wg.T, preferred_element_type=jnp.float32) \
+        + jnp.dot(db, wu.T, preferred_element_type=jnp.float32)
+    return dx, (dwg, dwu, dwd)
+
+
+EXPERT_FORMS = {"relu2": (_relu2_fwd, _relu2_bwd),
+                "swiglu": (_swiglu_fwd, _swiglu_bwd)}
+
+
+def _tile_inputs(t, tile, u, mats, weights_t, plan):
     """One tile: its expert, its rows' tokens (``tokens`` = no row), their
-    routing weights, the gathered rows and the expert's two matrices."""
+    routing weights, the gathered rows and the expert's matrices."""
     tokens = u.shape[0]
     e = plan["tile_expert"][t]
     keys = jax.lax.dynamic_slice(plan["keys"], (plan["tile_first"][t],), (tile,))
@@ -367,63 +436,84 @@ def _tile_inputs(t, tile, u, w1, w2, weights_t, plan):
         jax.lax.dynamic_index_in_dim(weights_t, e, keepdims=False), tok,
         mode="fill", fill_value=0)
     x = jnp.take(u, tok, axis=0, mode="fill", fill_value=0)
-    return (e, tok, wt, x,
-            jax.lax.dynamic_index_in_dim(w1, e, keepdims=False),
-            jax.lax.dynamic_index_in_dim(w2, e, keepdims=False))
+    return (e, tok, wt, x, tuple(
+        jax.lax.dynamic_index_in_dim(m, e, keepdims=False) for m in mats))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def grouped_expert_ffn(u, w1, w2, weights_t, plan, tile):
-    """sum over held assignments of ``weight * relu(u[token] W1_e)^2 W2_e``
-    at the token's row. u [T, L]; w1 [held, L, F]; w2 [held, F, L];
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def grouped_expert_ffn(u, mats, weights_t, plan, tile, form="relu2"):
+    """sum over held assignments of ``weight * expert_e(u[token])`` at the
+    token's row, the expert in one of ``EXPERT_FORMS``. u [T, L]; ``mats``:
+    the held experts' matrices, each [held, ...] (``relu2``: w1 [held, L, F],
+    w2 [held, F, L]; ``swiglu``: wg and wu [held, L, F], wd [held, F, L]);
     weights_t [held, T] float32 (0 where the token did not choose the
     expert); plan: ``plan_held_rows``. -> [T, L] float32."""
-    return _grouped_fwd(u, w1, w2, weights_t, plan, tile)[0]
+    return _grouped_fwd(u, mats, weights_t, plan, tile, form)[0]
 
 
-def _grouped_fwd(u, w1, w2, weights_t, plan, tile):
+def _grouped_fwd(u, mats, weights_t, plan, tile, form):
+    expert = EXPERT_FORMS[form][0]
+
     def body(t, out):
-        _e, tok, wt, x, w1e, w2e = _tile_inputs(
-            t, tile, u, w1, w2, weights_t, plan)
-        a = jnp.maximum(jnp.dot(x, w1e, preferred_element_type=jnp.float32), 0)
-        y = jnp.dot((a * a).astype(u.dtype), w2e,
-                    preferred_element_type=jnp.float32)
+        _e, tok, wt, x, me = _tile_inputs(t, tile, u, mats, weights_t, plan)
+        y, _ = expert(x, me)
         return out.at[tok].add(y * wt[:, None], mode="drop")
 
     out = jax.lax.fori_loop(
         0, plan["n_tiles"], body, jnp.zeros(u.shape, jnp.float32))
-    return out, (u, w1, w2, weights_t, plan)
+    return out, (u, mats, weights_t, plan)
 
 
-def _grouped_bwd(tile, res, g):
-    u, w1, w2, weights_t, plan = res
+def _grouped_bwd(tile, form, res, g):
+    u, mats, weights_t, plan = res
     dtype = u.dtype
+    expert, expert_bwd = EXPERT_FORMS[form]
 
     def body(t, carry):
-        du, dw1, dw2, dwt = carry
-        e, tok, wt, x, w1e, w2e = _tile_inputs(
-            t, tile, u, w1, w2, weights_t, plan)
-        a = jnp.maximum(jnp.dot(x, w1e, preferred_element_type=jnp.float32), 0)
-        h = (a * a).astype(dtype)
+        du, dmats, dwt = carry
+        e, tok, wt, x, me = _tile_inputs(t, tile, u, mats, weights_t, plan)
+        y, saved = expert(x, me)
         gy = jnp.take(g, tok, axis=0, mode="fill", fill_value=0)
-        y = jnp.dot(h, w2e, preferred_element_type=jnp.float32)
         dwt = dwt.at[e, tok].add(jnp.sum(gy * y, axis=-1), mode="drop")
         dy = (gy * wt[:, None]).astype(dtype)
-        dh = jnp.dot(dy, w2e.T, preferred_element_type=jnp.float32)
-        dpre = (dh * 2.0 * a).astype(dtype)
-        dw2 = dw2.at[e].add(jnp.dot(h.T, dy, preferred_element_type=jnp.float32))
-        dw1 = dw1.at[e].add(jnp.dot(x.T, dpre, preferred_element_type=jnp.float32))
-        dx = jnp.dot(dpre, w1e.T, preferred_element_type=jnp.float32)
-        return du.at[tok].add(dx, mode="drop"), dw1, dw2, dwt
+        dx, dme = expert_bwd(x, me, saved, dy)
+        dmats = tuple(d.at[e].add(de) for d, de in zip(dmats, dme))
+        return du.at[tok].add(dx, mode="drop"), dmats, dwt
 
-    du, dw1, dw2, dwt = jax.lax.fori_loop(0, plan["n_tiles"], body, (
-        jnp.zeros(u.shape, jnp.float32), jnp.zeros(w1.shape, jnp.float32),
-        jnp.zeros(w2.shape, jnp.float32), jnp.zeros_like(weights_t)))
-    return (du.astype(dtype), dw1.astype(w1.dtype), dw2.astype(w2.dtype), dwt,
+    du, dmats, dwt = jax.lax.fori_loop(0, plan["n_tiles"], body, (
+        jnp.zeros(u.shape, jnp.float32),
+        tuple(jnp.zeros(m.shape, jnp.float32) for m in mats),
+        jnp.zeros_like(weights_t)))
+    return (du.astype(dtype),
+            tuple(d.astype(m.dtype) for d, m in zip(dmats, mats)), dwt,
             jax.tree_util.tree_map(lambda _: None, plan))
 
 
 grouped_expert_ffn.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+def _route_and_plan(xt, seq, route, held, offset, tile, force_level):
+    """What both expert layers do under ``moe_route``: choose, plan the held
+    rows, count. ``route(xt, level) -> (chosen, weights)``. Returns the
+    held experts' weights [held, T], the plan and the counters."""
+    chosen, weights = route(
+        xt, jnp.arange(xt.shape[0]) % seq if force_level else None)
+    plan, sizes = plan_held_rows(chosen, held, offset, tile)
+    chosen, plan = jax.tree_util.tree_map(
+        lambda a: checkpoint_name(a, "moe_plan"), (chosen, plan))
+    in_use = jnp.arange(plan["tile_rows"].shape[0]) < plan["n_tiles"]
+    local = chosen - offset
+    is_held = (local >= 0) & (local < held)
+    counters = {
+        "moe/local_assignments": jnp.sum(sizes),
+        "moe/tokens_without_held_expert": jnp.sum(
+            ~jnp.any(is_held, axis=-1)).astype(jnp.int32),
+        "moe/max_expert_load": jnp.max(sizes),
+        # held assignments that no tile in use has a row for
+        "moe/overflow": jnp.sum(is_held).astype(jnp.int32)
+        - jnp.sum(jnp.where(in_use, plan["tile_rows"], 0)),
+    }
+    return weights[:, offset:offset + held].T, plan, counters
 
 
 def latent_moe_mixer(p, x, *, top_k, scale, held, offset, tile,
@@ -439,30 +529,16 @@ def latent_moe_mixer(p, x, *, top_k, scale, held, offset, tile,
     b, s, e = x.shape
     xt = x.reshape(b * s, e)
     with jax.named_scope("moe_route"):
-        chosen, weights = route_sigmoid_topk(
-            xt, p["router"], p["router_bias"], top_k, scale,
-            level=jnp.arange(b * s) % s if force_level else None)
-        plan, sizes = plan_held_rows(chosen, held, offset, tile)
-        chosen, plan = jax.tree_util.tree_map(
-            lambda a: checkpoint_name(a, "moe_plan"), (chosen, plan))
-        weights_t = weights[:, offset:offset + held].T
-        in_use = jnp.arange(plan["tile_rows"].shape[0]) < plan["n_tiles"]
-        local = chosen - offset
-        is_held = (local >= 0) & (local < held)
-        counters = {
-            "moe/local_assignments": jnp.sum(sizes),
-            "moe/tokens_without_held_expert": jnp.sum(
-                ~jnp.any(is_held, axis=-1)).astype(jnp.int32),
-            "moe/max_expert_load": jnp.max(sizes),
-            # held assignments that no tile in use has a row for
-            "moe/overflow": jnp.sum(is_held).astype(jnp.int32)
-            - jnp.sum(jnp.where(in_use, plan["tile_rows"], 0)),
-        }
+        weights_t, plan, counters = _route_and_plan(
+            xt, s, lambda xt, level: route_sigmoid_topk(
+                xt, p["router"], p["router_bias"], top_k, scale, level=level),
+            held, offset, tile, force_level)
     with jax.named_scope("moe_shared"):
         u = xt @ p["down"]
         shared = _relu2(xt @ p["shared_w1"]) @ p["shared_w2"]
     with jax.named_scope("moe_experts"):
-        routed = grouped_expert_ffn(u, p["w1"], p["w2"], weights_t, plan, tile)
+        routed = grouped_expert_ffn(
+            u, (p["w1"], p["w2"]), weights_t, plan, tile)
     with jax.named_scope("moe_shared"):
         out = routed.astype(x.dtype) @ p["up"] + shared
     return out.reshape(b, s, e), counters
@@ -471,3 +547,30 @@ def latent_moe_mixer(p, x, *, top_k, scale, held, offset, tile,
 def _relu2(x):
     r = jnp.maximum(x, 0)
     return r * r
+
+
+def gated_moe_mixer(p, x, *, top_k, held, offset, tile, force_level=False):
+    """One mixture of SiLU-gated experts at the model's own width, dropping
+    no token, over normalized ``x`` [B, S, E] -> (out [B, S, E], counters).
+    ``p``: router [E, routed], wg and wu [held, E, F], wd [held, F, E],
+    shared_wg and shared_wu [E, Fs], shared_wd [Fs, E], shared_gate [E, 1].
+    Softmax routing (``route_softmax_topk``), no selection bias and no
+    scaling; the shared expert is weighed by ``sigmoid(x shared_gate)``. The
+    same plan, loop and counters as ``latent_moe_mixer``."""
+    b, s, e = x.shape
+    xt = x.reshape(b * s, e)
+    with jax.named_scope("moe_route"):
+        weights_t, plan, counters = _route_and_plan(
+            xt, s, lambda xt, level: route_softmax_topk(
+                xt, p["router"], top_k, level=level),
+            held, offset, tile, force_level)
+    with jax.named_scope("moe_experts"):
+        routed = grouped_expert_ffn(
+            xt, (p["wg"], p["wu"], p["wd"]), weights_t, plan, tile, "swiglu")
+    with jax.named_scope("moe_shared"):
+        gate = jax.nn.sigmoid(jnp.dot(
+            xt, p["shared_gate"], preferred_element_type=jnp.float32))
+        shared = (jax.nn.silu(xt @ p["shared_wg"]) * (xt @ p["shared_wu"])) \
+            @ p["shared_wd"]
+        out = routed + gate * shared.astype(jnp.float32)
+    return out.astype(x.dtype).reshape(b, s, e), counters

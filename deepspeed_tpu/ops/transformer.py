@@ -31,6 +31,7 @@ from typing import Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .attention import (
     NEG_INF, additive_mask_to_kv_valid, attention, attention_packed,
@@ -331,11 +332,13 @@ def layer_norm_apply(cfg: DeepSpeedTransformerConfig, x, scale, bias):
     )
 
 
-def rms_norm(x, gain, eps):
-    """RMS norm (no mean, no bias), statistics in float32."""
+def rms_norm(x, gain, eps, zero_centered=False):
+    """RMS norm (no mean, no bias), statistics in float32. ``zero_centered``:
+    the gain is stored around 0 and applied as ``1 + gain``."""
     xs = x.astype(jnp.float32)
     inv = jax.lax.rsqrt(jnp.mean(jnp.square(xs), axis=-1, keepdims=True) + eps)
-    return (xs * inv * gain.astype(jnp.float32)).astype(x.dtype)
+    gain = gain.astype(jnp.float32)
+    return (xs * inv * (1.0 + gain if zero_centered else gain)).astype(x.dtype)
 
 
 def gqa_attention_mixer(p, x, *, heads, kv_heads, head_dim, mesh=None):
@@ -356,6 +359,54 @@ def gqa_attention_mixer(p, x, *, heads, kv_heads, head_dim, mesh=None):
         ctx = attention(q, k, v, causal=True, mesh=mesh)
         ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, heads * head_dim)
         return ctx @ p["wo"]
+
+
+def rotary_frequencies(lanes, theta):
+    """[lanes / 2] float32 inverse frequencies ``theta^(-2i / lanes)``, made
+    on the host in float64."""
+    return np.asarray(
+        float(theta) ** (-np.arange(0, lanes, 2) / lanes), np.float32)
+
+
+def apply_rotary(x, lanes, theta):
+    """Rotary position embedding in the half-split convention on the first
+    ``lanes`` lanes of each head of ``x`` [B, H, S, D] (lane i pairs with
+    lane i + lanes / 2; the lanes after stay as they are), positions 0..S-1,
+    no scaling, angles and rotation in float32."""
+    half = lanes // 2
+    angle = jnp.arange(x.shape[2], dtype=jnp.float32)[:, None] \
+        * rotary_frequencies(lanes, theta)[None, :]
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    xs = x.astype(jnp.float32)
+    x1, x2 = xs[..., :half], xs[..., half:lanes]
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin, xs[..., lanes:]],
+        axis=-1).astype(x.dtype)
+
+
+def gated_attention_mixer(p, x, *, heads, kv_heads, head_dim, rotary_lanes,
+                          rope_theta, eps, mesh=None):
+    """Causal grouped-query attention with an output gate over normalized
+    ``x`` [B, S, E]: wq [E, heads * 2 D] gives each head D query lanes then D
+    gate lanes; wk/wv [E, kv_heads * D]; a zero-centred RMS norm over D on q
+    (q_norm [D]) and on k (k_norm [D]); rotary on the first ``rotary_lanes``
+    lanes of q and k; ``out = (context * sigmoid(gate)) wo``, wo [heads * D,
+    E]. No bias anywhere."""
+    b, s, _ = x.shape
+    with jax.named_scope("attn_mixer"):
+        qg = (x @ p["wq"]).reshape(b, s, heads, 2 * head_dim)
+        q, gate = qg[..., :head_dim], qg[..., head_dim:]
+        k = (x @ p["wk"]).reshape(b, s, kv_heads, head_dim)
+        v = (x @ p["wv"]).reshape(b, s, kv_heads, head_dim)
+        q = rms_norm(q, p["q_norm"], eps, zero_centered=True)
+        k = rms_norm(k, p["k_norm"], eps, zero_centered=True)
+        q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
+        q = apply_rotary(q, rotary_lanes, rope_theta)
+        k = apply_rotary(k, rotary_lanes, rope_theta)
+        ctx = attention(q, k, v, causal=True, mesh=mesh)
+        ctx = ctx.transpose(0, 2, 1, 3) * jax.nn.sigmoid(
+            gate.astype(jnp.float32)).astype(ctx.dtype)
+        return ctx.reshape(b, s, heads * head_dim) @ p["wo"]
 
 
 def transformer_block_apply(

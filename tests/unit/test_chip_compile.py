@@ -7,7 +7,8 @@ refuses too — a dot batched over a middle axis, a block that breaks the
 that (both decode kernels passed every interpret-mode test for ten PRs
 and neither compiled). So the Pallas kernels of the train and serve paths
 are compiled here at GPT-2 large (20 heads x 64) and XL (25 x 64) shapes
-for a ``v5e:2x2`` device, ~2 s each, with the persistent compile cache
+for a ``v5e:2x2`` device, ~2 s each (the hybrid stacks' sublayers at their
+cells' shapes 10-20 s each), with the persistent compile cache
 off (a described-device entry can be written but never read back). A
 compile that passes is not a chip run; tests_tpu/ holds the numerics.
 
@@ -348,6 +349,61 @@ def test_hybrid_mixer_compiles_at_the_cells_shapes(kind):
     for scope in {"mamba": ("mamba_mixer", "mamba_ssd"),
                   "moe": ("moe_route", "moe_experts", "moe_shared"),
                   "attn": ("attn_mixer",)}[kind]:
+        assert f"/{scope}/" in text, scope
+
+
+# ---------------------------------------------------------------------------
+# the linear/full hybrid's sublayers at the widths of its benchmark cell
+# (models/hybrid.py kinds D, G, X; micro 2 x seq 16,384, hidden 2048, bf16)
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("kind", ["gdn", "gattn", "gmoe"])
+def test_gated_hybrid_sublayer_compiles_at_the_cells_shapes(kind):
+    """Forward and backward of one sublayer of each kind under the cell's
+    remat policy: the chunked gated delta rule (16 key heads serving 32
+    value heads of 128, chunks of 64 in 64 segments of 4: scans inside a
+    scan, the float32 triangular inverse), gated attention (16 query heads
+    on 2 kv heads at head width 256 over 16,384 positions: the flash
+    kernels' split layout on a 16 x 16 grid of blocks, three 1024-row blocks
+    of 256 lanes and their float32 scratches in VMEM), the gated experts (32
+    of 512 held, top-10, three matrices of 2048 x 512 an expert, tiles of
+    352)."""
+    from deepspeed_tpu.models.hybrid import HybridLMConfig, HybridModel
+
+    cfg = HybridLMConfig(
+        vocab_size=1024, hidden_size=2048, norm_eps=1e-6,
+        norm_zero_centered=True,
+        pattern={"gdn": "D", "gattn": "G", "gmoe": "X"}[kind],
+        gdn_key_heads=16, gdn_value_heads=32, gdn_key_dim=128,
+        gdn_value_dim=128, gdn_chunk=64, n_experts_held=32,
+        n_experts_routed=512, top_k=10, moe_intermediate=512,
+        moe_shared_intermediate=512, moe_tile=352, router_force_level=True,
+        attn_heads=16, kv_heads=2, head_dim=256, rotary_lanes=64,
+        rope_theta=1e7, remat=True,
+        remat_policy="nothing_saveable+flash_out+flash_lse+moe_plan"
+                     "+gdn_segments")
+    model = HybridModel(cfg)
+    ids = _shape((2, 16384), jnp.int32)
+    params = jax.tree_util.tree_map(
+        lambda x: _shape(x.shape, jnp.bfloat16),
+        jax.eval_shape(lambda: model.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 128), jnp.int32)))["params"])
+
+    def loss(p, ids):
+        return model.apply({"params": p}, ids)[0].astype(jnp.float32).sum()
+
+    real = device.on_tpu, jax.device_count
+    device.on_tpu, jax.device_count = (lambda: True), (lambda: 1)
+    try:
+        compiled = jax.jit(jax.grad(loss)).lower(params, ids).compile()
+    finally:
+        device.on_tpu, jax.device_count = real
+    text = compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 6 * 2 ** 30
+    kernels = [l for l in text.splitlines() if "tpu_custom_call" in l]
+    assert len(kernels) == (3 if kind == "gattn" else 0), kernels
+    for scope in {"gdn": ("gdn_mixer", "gdn_delta_rule"),
+                  "gattn": ("attn_mixer",),
+                  "gmoe": ("moe_route", "moe_experts", "moe_shared")}[kind]:
         assert f"/{scope}/" in text, scope
 
 

@@ -300,7 +300,7 @@ def test_attention_repeats_kv_heads_for_grouped_queries():
 
 
 @pytest.mark.parametrize("bad", [
-    dict(pattern="MEX"), dict(n_experts_held=4, expert_offset=6),
+    dict(pattern="MEZ"), dict(n_experts_held=4, expert_offset=6),
     dict(attn_heads=3, kv_heads=2), dict(mamba_heads=3, mamba_groups=2)])
 def test_config_refuses_what_it_cannot_run(bad):
     with pytest.raises(ValueError):
