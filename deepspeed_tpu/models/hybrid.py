@@ -252,7 +252,7 @@ class HybridModel(nn.Module):
                 p, x, key_heads=cfg.gdn_key_heads,
                 value_heads=cfg.gdn_value_heads, key_dim=cfg.gdn_key_dim,
                 value_dim=cfg.gdn_value_dim, chunk=cfg.gdn_chunk,
-                eps=cfg.norm_eps), {}),
+                eps=cfg.norm_eps, mesh=cfg.mesh), {}),
             "gattn": lambda p, x: (gated_attention_mixer(
                 p, x, heads=cfg.attn_heads, kv_heads=cfg.kv_heads,
                 head_dim=cfg.head_dim, rotary_lanes=cfg.rotary_lanes,

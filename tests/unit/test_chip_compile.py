@@ -360,8 +360,9 @@ def test_hybrid_mixer_compiles_at_the_cells_shapes(kind):
 def test_gated_hybrid_sublayer_compiles_at_the_cells_shapes(kind):
     """Forward and backward of one sublayer of each kind under the cell's
     remat policy: the chunked gated delta rule (16 key heads serving 32
-    value heads of 128, chunks of 64 in 64 segments of 4: scans inside a
-    scan, the float32 triangular inverse), gated attention (16 query heads
+    value heads of 128, chunks of 64 in 32 segments of 8: the two kernels
+    ``gdn_fwd`` and ``gdn_bwd``, a segment's states, T, W and U and the
+    float32 triangular inverse in VMEM), gated attention (16 query heads
     on 2 kv heads at head width 256 over 16,384 positions: the flash
     kernels' split layout on a 16 x 16 grid of blocks, three 1024-row blocks
     of 256 lanes and their float32 scratches in VMEM), the gated experts (32
@@ -400,11 +401,18 @@ def test_gated_hybrid_sublayer_compiles_at_the_cells_shapes(kind):
     text = compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 6 * 2 ** 30
     kernels = [l for l in text.splitlines() if "tpu_custom_call" in l]
-    assert len(kernels) == (3 if kind == "gattn" else 0), kernels
+    assert len(kernels) == {"gdn": 2, "gattn": 3, "gmoe": 0}[kind], kernels
     for scope in {"gdn": ("gdn_mixer", "gdn_delta_rule"),
                   "gattn": ("attn_mixer",),
                   "gmoe": ("moe_route", "moe_experts", "moe_shared")}[kind]:
         assert f"/{scope}/" in text, scope
+    if kind == "gdn":
+        # the forward kernel once (the remat policy keeps what the backward
+        # kernel needs of it) and the backward kernel once, both under the
+        # scopes that the per-layer metrics read
+        for kernel, line in zip(("gdn_fwd", "gdn_bwd"), sorted(
+                kernels, key=lambda l: "gdn_bwd" in l)):
+            assert f"/gdn_mixer/gdn_delta_rule/{kernel}" in line, line
 
 
 # ---------------------------------------------------------------------------

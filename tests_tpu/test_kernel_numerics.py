@@ -602,3 +602,67 @@ def test_device_sync_fence_orders_behind_compute():
     out = busy(x)
     _device_sync()
     assert out.is_ready(), "the fence returned before the work it follows"
+
+
+def test_gated_delta_rule_kernels_match_the_recurrence_at_the_cells_shape():
+    """``gdn_fwd`` and ``gdn_bwd`` compiled, at the shape of the benchmark's
+    Qwen3-Next cell (one row of 16,384 positions, 16 key heads serving 32
+    value heads of 128, chunks of 64, bf16 operands and float32 gates),
+    against the float32 per-position recurrence: the output and the gradient
+    of q, k, v, the log decay, beta and the state before the first
+    position."""
+    from deepspeed_tpu.ops import linear_attention as la
+
+    s, hk, hv, d, chunk = 16384, 16, 32, 128, 64
+    assert la.gdn_path(1, s, hk, hv, d, d, chunk)["path"] == "kernel"
+    ks = jax.random.split(jax.random.PRNGKey(31), 7)
+    q = (la.l2_normalise(jax.random.normal(ks[0], (1, s, hk, d))) * d ** -0.5
+         ).astype(jnp.bfloat16)
+    k = la.l2_normalise(jax.random.normal(ks[1], (1, s, hk, d))).astype(
+        jnp.bfloat16)
+    v = jax.random.normal(ks[2], (1, s, hv, d)).astype(jnp.bfloat16)
+    # the cell's decays: a time step of 1e-3..1e-1 times a rate of 1..16
+    g = -jnp.exp(jax.random.uniform(ks[3], (1, s, hv), minval=-7.0, maxval=0.5))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (1, s, hv)))
+    s0 = 0.1 * jax.random.normal(ks[5], (1, hk, hv // hk, d, d))
+    w = jax.random.normal(ks[6], (1, s, hv, d))
+
+    def f32(x):
+        return x.astype(jnp.float32)
+
+    def kernels(q, k, v, g, beta, s0):
+        return f32(la.gated_delta_rule_chunked(
+            q, k, v, g, beta, chunk, initial_state=s0))
+
+    def recurrence(q, k, v, g, beta, s0):
+        r = hv // hk
+
+        def step(state, inp):
+            q_t, k_t, v_t, g_t, b_t = inp
+            state = state * jnp.exp(g_t)[..., None, None]
+            u = b_t[..., None] * (
+                v_t - jnp.sum(state * k_t[..., :, None], axis=-2))
+            state = state + k_t[..., :, None] * u[..., None, :]
+            return state, jnp.sum(state * q_t[..., :, None], axis=-2)
+
+        seq = tuple(jnp.moveaxis(t, 1, 0) for t in (
+            jnp.repeat(f32(q), r, 2), jnp.repeat(f32(k), r, 2), f32(v), g,
+            beta))
+        seq = tuple(t.reshape((s // 128, 128) + t.shape[1:]) for t in seq)
+        _, o = jax.lax.scan(
+            jax.checkpoint(lambda st, inp: jax.lax.scan(step, st, inp)),
+            s0.reshape(1, hv, d, d), seq)
+        return jnp.moveaxis(o.reshape((s,) + o.shape[2:]), 0, 1)
+
+    def out_and_grads(rule):
+        return jax.jit(jax.grad(
+            lambda *a: jnp.sum(rule(*a) * w), argnums=range(6)
+        ))(q, k, v, g, beta, s0), jax.jit(rule)(q, k, v, g, beta, s0)
+
+    gk, out = out_and_grads(kernels)
+    gr, ref = out_and_grads(recurrence)
+    err = float(jnp.max(jnp.abs(out - ref)) / jnp.max(jnp.abs(ref)))
+    assert err < 2e-2, f"max err {err:.2e} of the largest output"
+    for a, r, name in zip(gk, gr, "q k v g beta state".split()):
+        rel = float(jnp.max(jnp.abs(f32(a) - f32(r))) / jnp.max(jnp.abs(f32(r))))
+        assert rel < 5e-2, f"d{name} rel err {rel:.2e}"
