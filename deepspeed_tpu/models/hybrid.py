@@ -7,19 +7,38 @@ Gated DeltaNet linear-attention mixer (ops/linear_attention.py), ``G`` gated
 softmax attention with per-head q/k norms and partial rotary
 (ops/transformer.py:gated_attention_mixer), ``X`` a mixture of SiLU-gated
 experts at the model's own width behind softmax routing, with a gated shared
-expert (ops/moe.py:gated_moe_mixer). A published layer that is a token mixer
-THEN experts is two letters (``DXDXDXGX`` is one period of three DeltaNet
-layers and one attention layer, each with its experts). No learned position
-embedding (the recurrent layers carry position; ``G`` rotates), a final RMS
-norm, an untied head, bias-free projections; ``norm_zero_centered`` stores
-every norm's gain around 0 and applies ``1 + gain``.
+expert (ops/moe.py:gated_moe_mixer); ``R`` causal multi-head attention with
+rotary on every lane (ops/transformer.py:rotary_attention_mixer), ``F`` a
+dense SiLU-gated FFN (ops/transformer.py:swiglu_ffn_mixer). A published
+layer that is a token mixer THEN experts or an FFN is two letters
+(``DXDXDXGX`` is one period of three DeltaNet layers and one attention layer,
+each with its experts; ``RF`` is one decoder layer). No learned position
+embedding (the recurrent layers carry position; ``G`` and ``R`` rotate), a
+final RMS norm, an untied head, bias-free projections; ``norm_zero_centered``
+stores every norm's gain around 0 and applies ``1 + gain``; ``post_norm``
+gives ``R`` and ``F`` a second gain AFTER the mixer (``x + norm_b(mixer(
+norm_a(x)))``, a sandwich norm).
 
 ``models/stack.py`` and ``nn.scan`` assume identical layers, so this stack
-is a Python loop over the pattern. The parameters of each KIND are stacked
-on a leading axis (``mamba_in_proj`` is [n M-layers, E, ...], ``moe_w1`` is
-[n E-layers, held, L, F]): a checkpoint, a ZeRO partition spec
-(runtime/zero.py shards any leaf over the data axis) and the optimizer see
-a dozen-odd leaves, not a dozen-odd per layer.
+is a Python loop over the pattern's PERIOD (the shortest string whose
+repetition the pattern is: all of ``DXDXDXGX``, the ``RF`` of ``RFRFRF``),
+and ``lax.scan`` walks the repetitions where there are several: the compiled
+program holds one period's bodies however deep the stack. The parameters of
+each KIND are stacked on a leading axis (``mamba_in_proj`` is [n M-layers, E,
+...], ``moe_w1`` is [n E-layers, held, L, F]): a checkpoint, a ZeRO partition
+spec (runtime/zero.py shards any leaf over the data axis) and the optimizer
+see a dozen-odd leaves, not a dozen-odd per layer.
+
+``passes`` > 1 is a LOOPED stack: the whole pattern and the final norm run
+``passes`` times over the same parameters (a second ``lax.scan``, over the
+passes; the parameters are constants of its body, so the backward pass sums
+the passes' gradients of each leaf in the leaf's own type), each pass's
+normed state feeding the next. Every pass's state is read by the head, and
+an exit gate ``lambda_t = sigmoid(h_t . gate_w + gate_b)`` turns the passes
+into a distribution over where a position leaves (ops/cross_entropy.py:
+exit_log_probs); the loss is the expected next-token loss under it less
+``exit_entropy_weight`` times its entropy. ``labels=None`` gives the last
+pass's logits (inference here never leaves early).
 
 Counts HELD and counts ROUTED OVER are separate fields. A chip of an
 expert-parallel group holds ``n_experts_held`` experts starting at
@@ -31,7 +50,7 @@ exchange is built). Head counts are the heads held here.
 engine trains on the loss, and the routing counters (``moe/...``, summed or
 maximised over the E layers) leave the compiled window beside it through
 the multi-output contract and reach the telemetry registry in
-``train.finish_step``.
+``train.finish_step``; a looped stack adds ``loop/...`` (docs/hybrid.md).
 """
 
 import dataclasses
@@ -40,7 +59,11 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..ops.cross_entropy import blocked_lm_head_loss
+from ..ops.cross_entropy import (
+    blocked_lm_head_loss,
+    exit_log_probs,
+    weighted_lm_head_loss,
+)
 from ..ops.linear_attention import gated_deltanet_mixer
 from ..ops.moe import gated_moe_mixer, latent_moe_mixer
 from ..ops.ssm import mamba2_mixer
@@ -49,10 +72,36 @@ from ..ops.transformer import (
     gqa_attention_mixer,
     resolve_remat_policy,
     rms_norm,
+    rotary_attention_mixer,
+    swiglu_ffn_mixer,
 )
 
 KINDS = {"M": "mamba", "E": "moe", "*": "attn",
-         "D": "gdn", "G": "gattn", "X": "gmoe"}
+         "D": "gdn", "G": "gattn", "X": "gmoe",
+         "R": "rattn", "F": "ffn"}
+
+
+def period(pattern):
+    """``(unit, repetitions)``: the shortest string whose repetition
+    ``pattern`` is."""
+    n = len(pattern)
+    size = next(k for k in range(1, n + 1)
+                if n % k == 0 and pattern[:k] * (n // k) == pattern)
+    return pattern[:size], n // size
+
+
+def merge_counters(found, axis=None):
+    """One value a name out of several layers' (or passes') counters by the
+    registry's rule (telemetry/manager.py): names whose last part starts
+    with ``max_`` keep the maximum, the others add up. ``found`` is a list
+    of dicts, or with ``axis`` one dict of stacked values."""
+    if axis is None:
+        found = {name: jnp.stack([c[name] for c in found])
+                 for name in (found[0] if found else {})}
+    return {
+        name: (jnp.max if name.rsplit("/", 1)[-1].startswith("max_")
+               else jnp.sum)(value, axis=axis)
+        for name, value in found.items()}
 
 
 @dataclasses.dataclass(unsafe_hash=True)
@@ -90,13 +139,22 @@ class HybridLMConfig:
     # X: gated experts at the model's width. Shares the held/routed counts,
     # top_k, router_force_level, moe_intermediate, moe_shared_intermediate
     # and moe_tile with E; has no latent, bias or scaling
-    # * and G: grouped-query attention. heads HELD here
+    # * and G: grouped-query attention. heads HELD here. R has attn_heads
+    # kv heads too and rotates all head_dim lanes
     attn_heads: int = 2
     kv_heads: int = 1
     head_dim: int = 16
-    # G: lanes of each head that rotate (0: none), and the base
+    # G: lanes of each head that rotate (0: none); G and R: the base
     rotary_lanes: int = 0
     rope_theta: float = 10000.0
+    # F: the dense gated FFN's width
+    ffn_intermediate: int = 96
+    # R and F: a second gain, after the mixer
+    post_norm: bool = False
+    # the whole stack and the final norm run this many times over the same
+    # parameters; above 1 an exit gate weights the passes' losses
+    passes: int = 1
+    exit_entropy_weight: float = 0.1
     # D: Gated DeltaNet. key heads each serve value_heads / key_heads value
     # heads; the chunk of the recurrence is a power of two; conv_kernel taps
     gdn_key_heads: int = 2
@@ -126,6 +184,10 @@ class HybridLMConfig:
                 "gdn_value_heads must be a multiple of gdn_key_heads")
         if self.rotary_lanes % 2 or self.rotary_lanes > self.head_dim:
             raise ValueError("rotary_lanes must be even and within head_dim")
+        if "R" in self.pattern and self.head_dim % 2:
+            raise ValueError("R rotates lane pairs: head_dim must be even")
+        if self.passes < 1:
+            raise ValueError("passes must be at least 1")
         if not (0 <= self.expert_offset
                 and self.expert_offset + self.n_experts_held
                 <= self.n_experts_routed):
@@ -141,6 +203,7 @@ class HybridLMConfig:
         gqk = self.gdn_key_heads * self.gdn_key_dim
         gvz = self.gdn_value_heads * self.gdn_value_dim
         fs = self.moe_shared_intermediate
+        post = {"post_norm": (e,)} if self.post_norm else {}
         return {
             "mamba": {
                 "norm": (e,), "in_proj": (e, di + conv + self.mamba_heads),
@@ -183,6 +246,15 @@ class HybridLMConfig:
                 "shared_wg": (e, fs), "shared_wu": (e, fs),
                 "shared_wd": (fs, e), "shared_gate": (e, 1),
             },
+            "rattn": {
+                "norm": (e,), "wq": (e, qd), "wk": (e, qd), "wv": (e, qd),
+                "wo": (qd, e), **post,
+            },
+            "ffn": {
+                "norm": (e,), "wg": (e, self.ffn_intermediate),
+                "wu": (e, self.ffn_intermediate),
+                "wd": (self.ffn_intermediate, e), **post,
+            },
         }
 
 
@@ -190,7 +262,7 @@ class HybridLMConfig:
 # ``norm_zero_centered`` stores around 0); A_log and dt_bias start at the
 # family's usual spread, every other leaf at N(0, range)
 _ONES = ("gate_norm", "out_norm", "D")
-_GAINS = ("norm", "q_norm", "k_norm")
+_GAINS = ("norm", "q_norm", "k_norm", "post_norm")
 _ZEROS = ("conv_b", "router_bias")
 
 
@@ -214,7 +286,8 @@ def _leaf_init(cfg, leaf):
 
 class HybridModel(nn.Module):
     """input_ids [B, S] -> (hidden [B, S, E] after the final norm, the
-    head's table, counters)."""
+    head's table, counters, None); a looped stack gives every pass's hidden
+    [passes, B, S, E] and, last, its exit gate ``(gate_w, gate_b)``."""
 
     config: HybridLMConfig
 
@@ -262,52 +335,134 @@ class HybridModel(nn.Module):
                 p, x, top_k=cfg.top_k, held=cfg.n_experts_held,
                 offset=cfg.expert_offset, tile=cfg.moe_tile,
                 force_level=cfg.router_force_level),
+            "rattn": lambda p, x: (rotary_attention_mixer(
+                p, x, heads=cfg.attn_heads, head_dim=cfg.head_dim,
+                rope_theta=cfg.rope_theta, mesh=cfg.mesh), {}),
+            "ffn": lambda p, x: (swiglu_ffn_mixer(p, x), {}),
         }
+
+        unit, repetitions = period(cfg.pattern)
+
+        def remat(body):
+            """A checkpoint is the body of the loop that walks the stack: a
+            layer of the Python loop, a period of the scan (a layer's input
+            saved for each period, not for each of its sublayers)."""
+            if cfg.remat:
+                return jax.checkpoint(
+                    body, policy=resolve_remat_policy(cfg.remat_policy))
+            return body
 
         def layer(kind):
             def apply(p, x):
                 out, counters = mixers[kind](p, rms_norm(
                     x, p["norm"], cfg.norm_eps, cfg.norm_zero_centered))
+                if "post_norm" in p:
+                    out = rms_norm(out, p["post_norm"], cfg.norm_eps,
+                                   cfg.norm_zero_centered)
                 return x + out.astype(x.dtype), counters
 
-            if cfg.remat:
-                return jax.checkpoint(
-                    apply, policy=resolve_remat_policy(cfg.remat_policy))
-            return apply
+            return remat(apply) if repetitions == 1 else apply
+
+        def one_period(x, params):
+            """The period's layers, each on the next slice of its kind."""
+            seen = dict.fromkeys(params, 0)
+            per_layer = []
+            for c in unit:
+                kind = KINDS[c]
+                p = {k: v[seen[kind]] for k, v in params[kind].items()}
+                seen[kind] += 1
+                x, counters = layer(kind)(p, x)
+                if counters:
+                    per_layer.append(counters)
+            return x, merge_counters(per_layer)
+
+        def one_pass(x):
+            """The whole pattern and the final norm."""
+            if repetitions == 1:
+                x, counters = one_period(x, params)
+            else:
+                x, counters = jax.lax.scan(
+                    remat(one_period), x, jax.tree_util.tree_map(
+                        lambda v: v.reshape(
+                            (repetitions, v.shape[0] // repetitions)
+                            + v.shape[1:]), params))
+                counters = merge_counters(counters, axis=0)
+            return rms_norm(
+                x, norm_f, cfg.norm_eps, cfg.norm_zero_centered), counters
 
         x = embed[input_ids]
-        seen = dict.fromkeys(params, 0)
-        per_layer = []
-        for c in cfg.pattern:
-            kind = KINDS[c]
-            p = {k: v[seen[kind]] for k, v in params[kind].items()}
-            seen[kind] += 1
-            x, counters = layer(kind)(p, x)
-            if counters:
-                per_layer.append(counters)
-        counters = {
-            # the registry's rule (telemetry/manager.py): max_* keep the maximum
-            name: (jnp.max if name.rsplit("/", 1)[-1].startswith("max_")
-                   else jnp.sum)(
-                jnp.stack([c[name] for c in per_layer]))
-            for name in (per_layer[0] if per_layer else {})}
-        return rms_norm(
-            x, norm_f, cfg.norm_eps, cfg.norm_zero_centered), head, counters
+        if cfg.passes == 1:
+            x, counters = one_pass(x)
+            return x, head, counters, None
+        gate = (self.param("gate_w", init, (cfg.hidden_size,)),
+                self.param("gate_b", nn.initializers.zeros, (1,)))
+
+        def loop_pass(x, _):
+            with jax.named_scope("loop_pass"):
+                x, counters = one_pass(x)
+            return x, (x, counters)
+
+        _, (states, counters) = jax.lax.scan(
+            loop_pass, x, None, length=cfg.passes)
+        return states, head, merge_counters(counters, axis=0), gate
 
 
 class HybridCausalLM(nn.Module):
     """``__call__(input_ids, labels) -> (loss, counters)``: next-token loss
     (the shift happens inside) through the blocked head loss, and the
-    routing counters of this micro-step. ``labels=None`` gives logits."""
+    routing counters of this micro-step. ``labels=None`` gives logits. A
+    looped stack's loss is ``looped_loss``."""
 
     config: HybridLMConfig
 
     @nn.compact
     def __call__(self, input_ids, labels=None):
-        x, head, counters = HybridModel(self.config, name="model")(input_ids)
+        cfg = self.config
+        x, head, counters, gate = HybridModel(cfg, name="model")(input_ids)
         if labels is None:
-            return x @ head.T
+            return (x if gate is None else x[-1]) @ head.T
+        if gate is not None:
+            loss, loop = looped_loss(
+                x[:, :, :-1], head, labels[:, 1:], *gate,
+                entropy_weight=cfg.exit_entropy_weight,
+                block_rows=cfg.ce_block_rows)
+            return loss, {**counters, **loop}
         loss = blocked_lm_head_loss(
-            x[:, :-1], head, labels[:, 1:],
-            block_rows=self.config.ce_block_rows)
+            x[:, :-1], head, labels[:, 1:], block_rows=cfg.ce_block_rows)
         return (loss, counters) if counters else loss
+
+
+def looped_loss(states, head, labels, gate_w, gate_b, *, entropy_weight,
+                block_rows, ignore_values=(-1, -100)):
+    """The objective of a looped stack over ``states`` [R, B, T, E] (each
+    pass's normed state at the positions that have a next token) and their
+    ``labels`` [B, T]: the mean over the counted positions of ``sum_t p_t
+    nll_t - entropy_weight * H(p)``, ``p`` the exit distribution of the gate
+    ``sigmoid(states . gate_w + gate_b)`` and ``nll_t`` pass t's next-token
+    loss through the head; and the ``loop/...`` counters of this micro-step:
+    the passes run, each pass's mean exit probability and the mean entropy
+    (added up over micro-steps by the registry: a pass's share of the exits
+    is its counter over the four's sum)."""
+    passes = states.shape[0]
+    with jax.named_scope("loop_head_loss"):
+        with jax.named_scope("exit_gate"):
+            log_p = exit_log_probs(jnp.einsum(
+                "rbte,e->rbt", states, gate_w,
+                preferred_element_type=jnp.float32)
+                + gate_b.astype(jnp.float32))
+            p = jnp.exp(log_p)
+            counted = jnp.ones(labels.shape, bool)
+            for value in ignore_values:
+                counted &= labels != value
+            share = counted / jnp.maximum(jnp.sum(counted), 1)
+            exit_share = jnp.sum(p * share, axis=(1, 2))
+            entropy = -jnp.sum(p * log_p * share)
+        expected = weighted_lm_head_loss(
+            states, head, labels, p, block_rows=block_rows,
+            ignore_values=ignore_values)
+        loss = expected - entropy_weight * entropy
+    counters = {"loop/passes": jnp.int32(passes),
+                "loop/exit_entropy": entropy}
+    counters.update({f"loop/exit_share_{t + 1}": exit_share[t]
+                     for t in range(passes)})
+    return loss, jax.lax.stop_gradient(counters)

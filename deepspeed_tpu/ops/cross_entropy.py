@@ -29,6 +29,59 @@ import jax
 import jax.numpy as jnp
 
 
+def _sequence_blocks(hidden, labels, block_rows):
+    """``hidden`` [..., B, T, H] and ``labels`` [B, T] as blocks of
+    ``block_rows`` sequence positions for ``lax.scan`` to walk: ``(xs
+    [nb, ..., B, block, H], ls [nb, B, block], pos [nb, B, block], T)``."""
+    B, T, H = hidden.shape[-3:]
+    lead = hidden.shape[:-3]
+    block = min(block_rows, T)
+    nb = -(-T // block)
+    pad = nb * block - T
+    if pad:
+        # pad positions are masked BY INDEX in the chunk body (pos >= T),
+        # not by a sentinel label value — so an explicit ignore_values=()
+        # (count every real label) stays correct and label-0 padding is
+        # never mistaken for a real target
+        hidden = jnp.concatenate(
+            [hidden, jnp.zeros(lead + (B, pad, H), hidden.dtype)], axis=-2
+        )
+        labels = jnp.concatenate(
+            [labels, jnp.zeros((B, pad), labels.dtype)], axis=1
+        )
+    # [nb, ..., B, block, ...] so lax.scan walks sequence chunks
+    xs = jnp.moveaxis(hidden.reshape(lead + (B, nb, block, H)), -3, 0)
+    ls = labels.reshape(B, nb, block).transpose(1, 0, 2)
+    pos = jnp.broadcast_to(
+        jnp.arange(nb * block, dtype=jnp.int32).reshape(nb, 1, block),
+        (nb, B, block),
+    )
+    return xs, ls, pos, T
+
+
+def _chunk_nll(x, word_table, labels, p_idx, T, ignore_values):
+    """One chunk's ``(nll [..., B, block] float32, valid [B, block])``: the
+    logits of ``x`` [..., B, block, H] in the compute dtype (MXU), the
+    log-sum-exp in float32."""
+    valid = p_idx < T
+    for iv in ignore_values:
+        valid &= labels != iv
+    safe = jnp.where(valid, labels, 0)
+    logits = x @ word_table.T  # [..., B, block, V]
+    picked = jnp.take_along_axis(
+        logits, jnp.broadcast_to(safe, logits.shape[:-1])[..., None], axis=-1
+    )[..., 0].astype(jnp.float32)
+    m = jax.lax.stop_gradient(jnp.max(logits, axis=-1))
+    z = jnp.sum(
+        jnp.exp(
+            logits.astype(jnp.float32) - m.astype(jnp.float32)[..., None]
+        ),
+        axis=-1,
+    )
+    log_z = jnp.log(z) + m.astype(jnp.float32)
+    return log_z - picked, valid
+
+
 @functools.partial(
     jax.jit, static_argnames=("block_rows", "ignore_values")
 )
@@ -46,49 +99,12 @@ def blocked_lm_head_loss(
         buffer alive.
       ignore_values: labels to exclude from the mean.
     """
-    B, T, H = hidden.shape
-    block = min(block_rows, T)
-    nb = -(-T // block)
-    pad = nb * block - T
-    if pad:
-        # pad positions are masked BY INDEX in the chunk body (pos >= T),
-        # not by a sentinel label value — so an explicit ignore_values=()
-        # (count every real label) stays correct and label-0 padding is
-        # never mistaken for a real target
-        hidden = jnp.concatenate(
-            [hidden, jnp.zeros((B, pad, H), hidden.dtype)], axis=1
-        )
-        labels = jnp.concatenate(
-            [labels, jnp.zeros((B, pad), labels.dtype)], axis=1
-        )
-    # [nb, B, block, ...] so lax.scan walks sequence chunks
-    xs = hidden.reshape(B, nb, block, H).transpose(1, 0, 2, 3)
-    ls = labels.reshape(B, nb, block).transpose(1, 0, 2)
-    pos = jnp.broadcast_to(
-        jnp.arange(nb * block, dtype=jnp.int32).reshape(nb, 1, block),
-        (nb, B, block),
-    )
+    xs, ls, pos, T = _sequence_blocks(hidden, labels, block_rows)
 
     def chunk(carry, inputs):
         num, den = carry
         x, l, p_idx = inputs
-        valid = p_idx < T
-        for iv in ignore_values:
-            valid &= l != iv
-        safe = jnp.where(valid, l, 0)
-        logits = x @ word_table.T  # [B, block, V] in compute dtype (MXU)
-        picked = jnp.take_along_axis(logits, safe[..., None], axis=-1)[
-            ..., 0
-        ].astype(jnp.float32)
-        m = jax.lax.stop_gradient(jnp.max(logits, axis=-1))
-        z = jnp.sum(
-            jnp.exp(
-                logits.astype(jnp.float32) - m.astype(jnp.float32)[..., None]
-            ),
-            axis=-1,
-        )
-        log_z = jnp.log(z) + m.astype(jnp.float32)
-        nll = log_z - picked
+        nll, valid = _chunk_nll(x, word_table, l, p_idx, T, ignore_values)
         num = num + jnp.sum(jnp.where(valid, nll, 0.0))
         den = den + jnp.sum(valid.astype(jnp.int32))
         return (num, den), None
@@ -100,3 +116,57 @@ def blocked_lm_head_loss(
         chunk, (jnp.float32(0.0), jnp.int32(0)), (xs, ls, pos)
     )
     return num / jnp.maximum(den, 1).astype(jnp.float32)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("block_rows", "ignore_values")
+)
+def weighted_lm_head_loss(
+    hiddens, word_table, labels, weights, block_rows=512,
+    ignore_values=(-1, -100),
+):
+    """``blocked_lm_head_loss`` for R states of the same positions, each
+    position's R losses weighted: the mean over the counted positions of
+    ``sum_r weights[r] * nll_r``, ``nll_r`` the CE of ``hiddens[r] @
+    word_table.T`` against ``labels``.
+
+    Args:
+      hiddens: [R, B, T, H]; a chunk's R x B x block rows go through the
+        head in ONE product, so the table is read once a chunk whatever R.
+      weights: [R, B, T] float32, differentiable (a looped model's exit
+        distribution, models/hybrid.py).
+
+    With R = 1 and weights of 1 this is ``blocked_lm_head_loss`` bit for
+    bit: the same chunks, the same sums in the same order.
+    """
+    xs, ls, pos, T = _sequence_blocks(hiddens, labels, block_rows)
+    ws, _, _, _ = _sequence_blocks(weights[..., None], labels, block_rows)
+
+    def chunk(carry, inputs):
+        num, den = carry
+        x, w, l, p_idx = inputs
+        nll, valid = _chunk_nll(x, word_table, l, p_idx, T, ignore_values)
+        weighted = jnp.sum(nll * w[..., 0], axis=0)
+        num = num + jnp.sum(jnp.where(valid, weighted, 0.0))
+        den = den + jnp.sum(valid.astype(jnp.int32))
+        return (num, den), None
+
+    (num, den), _ = jax.lax.scan(
+        jax.checkpoint(chunk), (jnp.float32(0.0), jnp.int32(0)),
+        (xs, ws, ls, pos),
+    )
+    return num / jnp.maximum(den, 1).astype(jnp.float32)
+
+
+def exit_log_probs(gate_logits):
+    """Log of a looped model's exit distribution from its gate's logits
+    [R, ...]: ``lambda_t = sigmoid(z_t)``; pass t < R is left with
+    probability ``p_t = lambda_t prod_{j<t} (1 - lambda_j)`` and the last
+    takes what is left, ``p_R = prod_{j<R} (1 - lambda_j)`` (its own gate is
+    not read). In log space, so that a saturated gate gives a large finite
+    log and ``p log p`` stays 0 there; ``exp`` of the result sums to 1 over
+    the first axis."""
+    z = gate_logits.astype(jnp.float32)
+    stay = jnp.cumsum(jax.nn.log_sigmoid(-z[:-1]), axis=0)
+    before = jnp.concatenate([jnp.zeros_like(z[:1]), stay], axis=0)
+    return before.at[:-1].add(jax.nn.log_sigmoid(z[:-1]))

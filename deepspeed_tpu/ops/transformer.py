@@ -368,14 +368,17 @@ def rotary_frequencies(lanes, theta):
         float(theta) ** (-np.arange(0, lanes, 2) / lanes), np.float32)
 
 
-def apply_rotary(x, lanes, theta):
+def apply_rotary(x, lanes, theta, seq_axis=2):
     """Rotary position embedding in the half-split convention on the first
     ``lanes`` lanes of each head of ``x`` [B, H, S, D] (lane i pairs with
     lane i + lanes / 2; the lanes after stay as they are), positions 0..S-1,
-    no scaling, angles and rotation in float32."""
+    no scaling, angles and rotation in float32. ``seq_axis=1``: ``x`` is
+    [B, S, H, D], as a projection leaves it."""
     half = lanes // 2
-    angle = jnp.arange(x.shape[2], dtype=jnp.float32)[:, None] \
+    angle = jnp.arange(x.shape[seq_axis], dtype=jnp.float32)[:, None] \
         * rotary_frequencies(lanes, theta)[None, :]
+    if seq_axis == 1:
+        angle = angle[:, None, :]
     cos, sin = jnp.cos(angle), jnp.sin(angle)
     xs = x.astype(jnp.float32)
     x1, x2 = xs[..., :half], xs[..., half:lanes]
@@ -407,6 +410,35 @@ def gated_attention_mixer(p, x, *, heads, kv_heads, head_dim, rotary_lanes,
         ctx = ctx.transpose(0, 2, 1, 3) * jax.nn.sigmoid(
             gate.astype(jnp.float32)).astype(ctx.dtype)
         return ctx.reshape(b, s, heads * head_dim) @ p["wo"]
+
+
+def rotary_attention_mixer(p, x, *, heads, head_dim, rope_theta, mesh=None):
+    """Causal multi-head attention (as many kv heads as query heads) with
+    rotary on every lane of q and k, over normalized ``x`` [B, S, E]: wq,
+    wk, wv [E, heads * D] multiplied as ONE projection q | k | v, which
+    ``attention_packed`` takes as it is once q and k are rotated in place
+    ([B, S, 3 * heads * D]: no head transpose on either side of the
+    kernels); wo [heads * D, E]. No bias, no q/k norm, no gate."""
+    from .attention import attention_packed
+
+    b, s, _ = x.shape
+    with jax.named_scope("attn_mixer"):
+        qkv = (x @ jnp.concatenate([p["wq"], p["wk"], p["wv"]], axis=1)
+               ).reshape(b, s, 3 * heads, head_dim)
+        qk = apply_rotary(
+            qkv[:, :, :2 * heads], head_dim, rope_theta, seq_axis=1)
+        qkv = jnp.concatenate([qk, qkv[:, :, 2 * heads:]], axis=2)
+        ctx = attention_packed(
+            qkv.reshape(b, s, 3 * heads * head_dim), heads, causal=True,
+            mesh=mesh)
+        return ctx @ p["wo"]
+
+
+def swiglu_ffn_mixer(p, x):
+    """A dense SiLU-gated FFN over normalized ``x`` [B, S, E]: ``(silu(x wg)
+    * (x wu)) wd`` with wg, wu [E, F] and wd [F, E]; no bias."""
+    with jax.named_scope("swiglu_ffn"):
+        return (jax.nn.silu(x @ p["wg"]) * (x @ p["wu"])) @ p["wd"]
 
 
 def transformer_block_apply(

@@ -416,6 +416,53 @@ def test_gated_hybrid_sublayer_compiles_at_the_cells_shapes(kind):
 
 
 # ---------------------------------------------------------------------------
+# the looped stack at the widths of its benchmark cell (models/hybrid.py
+# kinds R and F under ``passes``; micro 1 x seq 8,192, hidden 2048, bf16)
+# ---------------------------------------------------------------------------
+def test_looped_stack_compiles_at_the_cells_shapes():
+    """Forward and backward of two layers run twice under the cell's remat
+    policy: 16 heads of 128 out of one q | k | v projection on the flash
+    kernels' PACKED layout (one head a 128-lane block, an 8 x 8 grid of
+    1,024-blocks: no cell of GPT-2's runs more than one block a side), the
+    dense gated FFN at 5,632, the scan over the layers inside the scan over
+    the passes, and the objective over both passes' states. The kernels are
+    in the program once each however many layers and passes there are."""
+    from deepspeed_tpu.models.hybrid import HybridCausalLM, HybridLMConfig
+
+    cfg = HybridLMConfig(
+        vocab_size=4096, hidden_size=2048, norm_eps=1e-6, pattern="RFRF",
+        attn_heads=16, head_dim=128, rope_theta=1e6, ffn_intermediate=5632,
+        post_norm=True, passes=2, remat=True,
+        remat_policy="nothing_saveable+flash_out+flash_lse")
+    model = HybridCausalLM(cfg)
+    ids = _shape((1, 8192), jnp.int32)
+    small = jnp.zeros((1, 128), jnp.int32)
+    params = jax.tree_util.tree_map(
+        lambda x: _shape(x.shape, jnp.bfloat16),
+        jax.eval_shape(lambda: model.init(
+            jax.random.PRNGKey(0), small, small))["params"])
+
+    def loss(p, ids):
+        return model.apply({"params": p}, ids, ids)[0]
+
+    real = device.on_tpu, jax.device_count
+    device.on_tpu, jax.device_count = (lambda: True), (lambda: 1)
+    try:
+        compiled = jax.jit(jax.grad(loss)).lower(params, ids).compile()
+    finally:
+        device.on_tpu, jax.device_count = real
+    text = compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 6 * 2 ** 30
+    kernels = [l for l in text.splitlines() if "tpu_custom_call" in l]
+    assert len(kernels) == 3, kernels
+    assert all("/loop_pass/" in l and "/attn_mixer/" in l for l in kernels)
+    # packed: the kernels read [B, S, 3 * H * D] and no [B, H, S, D] exists
+    assert "[1,8192,6144]" in text and "[1,16,8192,128]" not in text
+    for scope in ("swiglu_ffn", "loop_head_loss", "exit_gate"):
+        assert f"/{scope}/" in text, scope
+
+
+# ---------------------------------------------------------------------------
 # decode kernels (ops/decode_attention.py)
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("model", sorted(HEADS))
