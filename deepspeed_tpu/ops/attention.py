@@ -89,8 +89,8 @@ def mha_reference(
 # 128 KB each of q, k, v at head_dim 64 in bf16. The arithmetic runs in
 # sub-tiles inside a block (below), so a large block costs little VMEM, and
 # a sequence that fits ONE block each way takes the kernels' static path.
-# The three kernels alone at [8, 20, 1024, 64] bf16 causal on one
-# "TPU v5 lite" chip, forward + dq + dkv in ms (device time from a
+# The three kernels of before PR 33 alone at [8, 20, 1024, 64] bf16 causal
+# on one "TPU v5 lite" chip, forward + dq + dkv in ms (device time from a
 # profiler trace, PR 25, docs/TESTING.md): the one-level kernels before
 # PR 25 at 512 x 512 blocks 1.22 + 0.94 + 1.10 (16.6 TFLOP/s in the
 # forward), at 1024 x 1024 0.74 + 0.73 + 1.02; these kernels at 512 x 512
@@ -148,15 +148,35 @@ def pick_block(seq, maximum):
 # With more blocks the bounds depend on the grid position and the walk is
 # a ``fori_loop``, whose every step costs ~0.3 us that nothing overlaps.
 #
-# Sub-tile sizes, measured alone at [8, 20, 1024, 64] bf16 causal on
-# "TPU v5 lite" (docs/TESTING.md, PR 25; 128 / 256 / 512 square, ms):
-# forward 0.83 / 0.67 / 0.43, dq 1.35 / 0.57 / 0.48, dkv 0.60 / 0.63 /
-# 0.78. The query-major kernels (forward, dq) carry a chain from one key
-# sub-tile to the next (the running max; the accumulator) and want few
-# large steps even at 3/4 of the causal square; dkv's sub-tiles are
-# independent, so it takes the 128-steps that visit 9/16 of it.
+# Two kernels run in training: ``flash_fwd`` and ONE backward, key-major
+# like the old dkv kernel, that computes each score sub-tile once and puts
+# dq, dk and dv out of it (PR 33; it runs as ``flash_bwd_dkv``, the name
+# the benchmark's ``flash_ms.train`` sums). Before, ``flash_bwd_dq`` and
+# ``flash_bwd_dkv`` each computed the same ``k q^T``, ``exp``, ``v dO^T``
+# and ``ds``: seven products and two ``exp`` passes a sub-tile where five
+# and one do. The pair is kept for the shapes whose dq does not fit VMEM
+# (``backward_plan``).
+#
+# Sub-tile sizes, measured alone on "TPU v5 lite" (docs/TESTING.md; ms a
+# call, bf16). At [8, 20, 1024, 64] causal, one block each way, 128 / 256
+# / 512 square (PR 25): forward 0.83 / 0.67 / 0.43, dq 1.35 / 0.57 / 0.48,
+# dkv 0.60 / 0.63 / 0.78; the fused backward (PR 33, packed operands with
+# the bias) 0.92 / 0.74 / 0.87, 256 q x 128 k 0.89, 128 q x 256 k 0.98,
+# against the pair's 0.49 + 0.59. The query-major kernels (forward, dq)
+# carry a chain from one key sub-tile to the next (the running max; the
+# accumulator) and want few large steps even at 3/4 of the causal square;
+# dkv's sub-tiles are independent, so it takes the 128-steps that visit
+# 9/16 of it; the fused kernel adds every sub-tile's product into dq's
+# accumulator in VMEM, which 128-steps do four times as often as 256-steps
+# (5/8 of the square). BERT's [8, 16, 512, 64] with a key mask reads the
+# same: 0.36 / 0.26 / 0.28 against the pair's 0.16 + 0.24. On a grid of
+# several blocks (``fori_loop`` walk) [1, 16, 8192, 128] read 5.60 at 512
+# square, 5.59 at 1024 q x 512 k, 5.55 at 512 q x 256 k, 5.87 and 6.06 at
+# 512 q x 1024 k and 256 q x 512 k, against the pair's 3.88 + 4.82: nothing
+# to choose, so 512 stays.
 SUB_QUERY_MAJOR = 512
 SUB_KEY_MAJOR = 128
+SUB_FUSED = 256
 # in-kernel dropout draws its bits in granules of this size (or the block,
 # where 128 does not divide it), whatever sub-tile a kernel computes in
 DROPOUT_TILE = 128
@@ -175,11 +195,13 @@ def pick_subtile(block, target):
     return block
 
 
-def pick_subtiles(block_q, block_k, nq, nk, key_major):
-    """(sub_q, sub_k) of the forward and dq kernels, or of dkv
-    (``key_major``), for blocks on an ``nq x nk`` grid."""
-    static = nq == nk == 1
-    target = SUB_KEY_MAJOR if key_major and static else SUB_QUERY_MAJOR
+def pick_subtiles(block_q, block_k, nq, nk, key_major, fused=False):
+    """(sub_q, sub_k) of the forward and dq kernels, or of the key-major
+    ones (``key_major``: dkv of the pair, or the ``fused`` backward), for
+    blocks on an ``nq x nk`` grid."""
+    target = SUB_QUERY_MAJOR
+    if key_major and nq == nk == 1:
+        target = SUB_FUSED if fused else SUB_KEY_MAJOR
     return pick_subtile(block_q, target), pick_subtile(block_k, target)
 
 
@@ -212,44 +234,96 @@ def _query_range(k_first, sub_k, q_first, sub_q, nsq, diag_offset):
     return _clip(lo, 0, nsq), _clip(full, 0, nsq)
 
 
+def _visited_share(sq, sk, block_k, sub_q, sub_k, causal):
+    """Share of the ``sq x sk`` score square that lies in sub-tiles a
+    kernel visits, from the bounds that set its loops."""
+    if not causal:
+        return 1.0
+    visited = 0
+    for q_first in range(0, sq, sub_q):
+        for k_first in range(0, sk, block_k):
+            visited += _key_range(
+                q_first, sub_q, k_first, sub_k, block_k // sub_k, sk - sq
+            )[1]
+    return visited * sub_q * sub_k / (sq * sk)
+
+
+# What the fused backward may hold in VMEM for dq: the float32 accumulator
+# over a group's whole query length and the two buffers of its output block.
+# Everything else the kernel holds is what ``flash_bwd_dkv`` of the pair
+# holds, which fits Mosaic's default limit; the call asks for the sum. A v5e
+# core has 128 MiB (``gdn_bwd`` runs with a limit of 64).
+FUSED_DQ_VMEM_BUDGET = 48 * 2**20
+PAIR_VMEM_BYTES = 16 * 2**20
+
+
+def backward_plan(
+    sq, sk, block_q, block_k, causal, lanes=128, itemsize=2, budget=None
+):
+    """Which backward a call gets, from its shape, its dtype and the VMEM
+    budget alone: ``fused`` (one key-major kernel writes dq, dk and dv from
+    each score sub-tile) where dq's accumulator and output for ``sq`` rows of
+    ``lanes`` lanes fit ``budget``, else the ``pair`` (``flash_bwd_dq`` and
+    ``flash_bwd_dkv``), with the reason. Also the sub-tiles the key-major
+    walk computes in and the share of the score square it visits."""
+    if budget is None:
+        budget = FUSED_DQ_VMEM_BUDGET
+    nq, nk = sq // block_q, sk // block_k
+    # a block narrower than the 128 lanes of a vector register takes them all
+    dq_bytes = sq * -(-lanes // 128) * 128 * (4 + 2 * itemsize)
+    fused = dq_bytes <= budget
+    sub_q, sub_k = pick_subtiles(block_q, block_k, nq, nk, True, fused)
+    return {
+        "backward": "fused" if fused else "pair",
+        "sub_q": sub_q, "sub_k": sub_k,
+        "visited_share": _visited_share(sq, sk, block_k, sub_q, sub_k, causal),
+        "dq_vmem_bytes": dq_bytes if fused else 0,
+        "reason": None if fused else (
+            f"dq over {sq} rows of {lanes} lanes takes {dq_bytes} bytes of "
+            f"VMEM, budget {budget}"
+        ),
+    }
+
+
 def flash_tiling(
-    sq, sk, block_q, block_k, causal, key_major=False, sub_q=None, sub_k=None
+    sq, sk, block_q, block_k, causal, key_major=False, sub_q=None, sub_k=None,
+    **plan,
 ):
     """Outer blocks, sub-tiles and the share of the ``sq x sk`` score
     square whose sub-tiles a kernel visits (the forward and dq, or dkv
-    with ``key_major``), from the same bounds that set its loops."""
+    with ``key_major``), from the same bounds that set its loops; and
+    under ``backward`` what ``backward_plan`` chooses for the call
+    (``plan``: its ``lanes``, ``itemsize`` and ``budget``)."""
     picked = pick_subtiles(
         block_q, block_k, sq // block_q, sk // block_k, key_major
     )
     sub_q = picked[0] if sub_q is None else sub_q
     sub_k = picked[1] if sub_k is None else sub_k
-    visited = sk // sub_k * (sq // sub_q)
-    if causal:
-        visited = 0
-        for q_first in range(0, sq, sub_q):
-            for k_first in range(0, sk, block_k):
-                _, hi = _key_range(
-                    q_first, sub_q, k_first, sub_k, block_k // sub_k, sk - sq
-                )
-                visited += hi
     return {
         "block_q": block_q, "block_k": block_k, "sub_q": sub_q,
         "sub_k": sub_k,
-        "visited_share": visited * sub_q * sub_k / (sq * sk),
+        "visited_share": _visited_share(sq, sk, block_k, sub_q, sub_k, causal),
+        "backward": backward_plan(sq, sk, block_q, block_k, causal, **plan),
     }
 
 
 @functools.lru_cache(maxsize=None)
-def _log_tiling(sq, sk, d, dtype, block_q, block_k, causal, use_mask, dropout):
-    t = flash_tiling(sq, sk, block_q, block_k, causal)
-    kv = flash_tiling(sq, sk, block_q, block_k, causal, key_major=True)
+def _log_tiling(
+    sq, sk, d, lanes, dtype, block_q, block_k, causal, use_mask, dropout
+):
+    t = flash_tiling(
+        sq, sk, block_q, block_k, causal, lanes=lanes,
+        itemsize=jnp.dtype(dtype).itemsize,
+    )
+    b = t["backward"]
     logger.debug(
         "flash_tiling sq=%d sk=%d d=%d %s causal=%s mask=%s dropout=%s "
         "block=%dx%d sub=%dx%d visited_share=%.4f "
-        "dkv_sub=%dx%d dkv_visited_share=%.4f",
+        "backward=%s bwd_sub=%dx%d bwd_visited_share=%.4f dq_vmem_bytes=%d%s",
         sq, sk, d, dtype, causal, use_mask, dropout, block_q, block_k,
         t["sub_q"], t["sub_k"], t["visited_share"],
-        kv["sub_q"], kv["sub_k"], kv["visited_share"],
+        b["backward"], b["sub_q"], b["sub_k"], b["visited_share"],
+        b["dq_vmem_bytes"], f" reason={b['reason']!r}" if b["reason"] else "",
     )
 
 
@@ -263,8 +337,8 @@ def _keep_mask(seed_ref, bh, q_first, k_first, shape, gran, rate):
     """Regenerable keep-mask of a ``[keys, queries]`` sub-tile of scores whose
     corner is (``k_first``, ``q_first``), drawn granule by granule: each
     ``gran = (gran_k, gran_q)`` granule is seeded by its global (batch*head,
-    q granule, k granule) position, so the forward and both backward
-    kernels draw the same bit for the same score element whatever sub-tile
+    q granule, k granule) position, so the forward and every backward
+    kernel draw the same bit for the same score element whatever sub-tile
     they compute in."""
     gran_k, gran_q = gran
     threshold = jnp.uint32(int(rate * (2**32)))
@@ -361,7 +435,7 @@ def _dot_t(a_t, b, dtype):
 
 
 class _Tiles:
-    """What the three kernels share: the grid position (a Python 0 on an
+    """What the kernels share: the grid position (a Python 0 on an
     axis of one block, so that every bound derived from it is static),
     sub-tile counts, the heads of one program's block, and the masking
     flags."""
@@ -596,15 +670,41 @@ def _bwd_dq_kernel(
 
 def _bwd_dkv_kernel(
     seed_ref, q_ref, k_ref, v_ref, bq_ref, bk_ref, bv_ref, kvm_ref, do_ref,
-    lse_ref, delta_ref,
-    dk_ref, dv_ref, dk_scr, dv_scr, **static,
+    lse_ref, delta_ref, *results, fused, **static,
 ):
+    """The key-major backward. ``fused``: the one backward kernel, which
+    also puts out dq from the ``ds_t`` it holds, one more product a
+    sub-tile. dq sums over KEYS, the outer axis of this grid, so ``dq_scr``
+    and the ``dq_ref`` block cover the group's whole query length: a Q
+    block's rows are zeroed in the first key block's steps, summed
+    transposed (``dq_t += k^T ds_t``, as ``_bwd_dq_kernel`` does) through
+    every key block, and cast out in the last one's. Without ``fused`` it
+    is the pair's dkv kernel and dq is ``_bwd_dq_kernel``'s."""
     t = _Tiles(2, **static)
+    if fused:
+        dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr, kt_scr = results
+    else:
+        dk_ref, dv_ref, dk_scr, dv_scr = results
+
+    def dq_rows(r):
+        """Sub-tile ``r`` of this Q block among the whole sequence's."""
+        return t.iq * t.nsq + r
+
+    @_when(fused and t.ik == 0)
+    def _init_dq():
+        for r in range(t.nsq):
+            dq_scr[dq_rows(r)] = jnp.zeros(dq_scr.shape[1:], dq_scr.dtype)
 
     @_when(t.iq == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
+        # the K block stays for every Q block of this row of the grid:
+        # transposed once for all of them, all its heads together
+        for c in range(t.nsk if fused else 0):
+            kt_scr[c] = t.load(
+                k_ref, bk_ref, _rows(c, t.sub_k)
+            ).T.astype(kt_scr.dtype)
 
     @_when(t.run)
     def _body():
@@ -642,10 +742,13 @@ def _bwd_dkv_kernel(
                         p_drop.astype(do.dtype), do,
                         preferred_element_type=jnp.float32,
                     )
-                    ds_t = p_t * (dp_t - delta_ref[hh, r])
-                    dk = dk + jnp.dot(
-                        ds_t.astype(q.dtype), q, preferred_element_type=jnp.float32
-                    )
+                    ds_t = (p_t * (dp_t - delta_ref[hh, r])).astype(q.dtype)
+                    dk = dk + jnp.dot(ds_t, q, preferred_element_type=jnp.float32)
+                    if fused:
+                        at = dq_rows(r)
+                        dq_scr[at, lanes, :] = dq_scr[at, lanes, :] + _dot_t(
+                            kt_scr[c, lanes, :], ds_t, k_ref.dtype
+                        )
                     return dk, dv
 
                 dk_scr[hh, keys, :], dv_scr[hh, keys, :] = t.over_queries(
@@ -657,6 +760,14 @@ def _bwd_dkv_kernel(
         for hh, lanes in t.heads():
             dk_ref[0, :, lanes] = (dk_scr[hh] * t.sm_scale).astype(dk_ref.dtype)
             dv_ref[0, :, lanes] = dv_scr[hh].astype(dv_ref.dtype)
+
+    @_when(fused and t.ik == t.nk - 1)
+    def _finalize_dq():
+        for r in range(t.nsq):
+            at = dq_rows(r)
+            dq_ref[0, _rows(at, t.sub_q), :] = (
+                (dq_scr[at] * t.sm_scale).T.astype(dq_ref.dtype)
+            )
 
 
 def _reshape_bh(x):
@@ -679,6 +790,12 @@ def _reshape_bh(x):
 # each a static 64-lane half of the block, walked one after the other by the
 # same kernel body. The lane-block index picks q, k or v and the pair.
 # lse and delta are ``[B*H, Sq/sub_q, 1, sub_q]`` rows in both.
+#
+# In both, the fused backward's dq block is a group's WHOLE query length
+# (``spec(None, ..)``): its grid runs key blocks outermost and dq sums over
+# keys, so the block and its float32 accumulator stay in VMEM for all the
+# steps of a group and go back to HBM once (no partial dq a key block
+# through HBM). dk and dv are a key block each, as in the pair.
 LANES = 128
 
 
@@ -711,16 +828,22 @@ class _Operands:
     def spec(self, rows, block, key_major=False, part=0):
         """BlockSpec of a group's ``block`` rows of q (``part`` 0), k (1)
         or v (2) in the projection's result, or of a ``[.., H*D]`` array
-        (``part`` 0)."""
-        axis = self._row_axis(rows, key_major)
+        (``part`` 0). ``rows`` None: ``block`` is ALL the group's rows,
+        wherever the grid stands in them, so it stays in VMEM for every
+        step of the group and is written back once."""
+        axis = rows and self._row_axis(rows, key_major)
+
+        def at(g):
+            return g[axis] if rows else 0
+
         if not self.packed:
             return pl.BlockSpec(
-                (1, block, self.head_dim), lambda *g: (g[0], g[axis], 0)
+                (1, block, self.head_dim), lambda *g: (g[0], at(g), 0)
             )
         per = self.groups_a_batch
         return pl.BlockSpec(
             (1, block, LANES),
-            lambda *g: (g[0] // per, g[axis], part * per + g[0] % per),
+            lambda *g: (g[0] // per, at(g), part * per + g[0] % per),
         )
 
     def bias_spec(self, use_bias, part):
@@ -778,7 +901,7 @@ def _static(
     ops, sq, sk, block_q, block_k, causal, sm_scale, dropout_rate, use_mask,
     use_bias,
 ):
-    """The keyword arguments of ``_Tiles`` that the three kernels share."""
+    """The keyword arguments of ``_Tiles`` that the kernels share."""
     return dict(
         sm_scale=sm_scale, causal=causal, block_q=block_q, block_k=block_k,
         nq=sq // block_q, nk=sk // block_k, diag_offset=sk - sq,
@@ -812,8 +935,8 @@ def _forward_call(
     sub_q, sub_k = pick_subtiles(block_q, block_k, nq, nk, key_major=False)
     interpret = not device.on_tpu()
     _log_tiling(
-        sq, sk, d, str(dtype), block_q, block_k, causal, kv_mask is not None,
-        dropout_rate > 0.0,
+        sq, sk, d, ops.block_lanes, str(dtype), block_q, block_k, causal,
+        kv_mask is not None, dropout_rate > 0.0,
     )
     hb, nsq = ops.heads_a_block, block_q // sub_q
     out, lse = pl.pallas_call(
@@ -857,8 +980,11 @@ def _backward_calls(
     ops, q, k, v, bias, kv_mask, seed, do, lse, delta, sq, sk, causal,
     sm_scale, dropout_rate, block_q, block_k,
 ):
-    """``flash_bwd_dq`` and ``flash_bwd_dkv``: (dq, dk, dv) in the
-    operands' layout. ``lse``/``delta``: ``[B*H, Sq]`` float32."""
+    """(dq, dk, dv) in the operands' layout, from the backward that
+    ``backward_plan`` chooses: the fused kernel, which runs as
+    ``flash_bwd_dkv`` (its walk, now also putting out dq), or the pair
+    ``flash_bwd_dq`` and ``flash_bwd_dkv``. ``lse``/``delta``:
+    ``[B*H, Sq]`` float32."""
     dtype = q.dtype
     use_mask, use_bias = kv_mask is not None, bias is not None
     common = _static(
@@ -867,66 +993,77 @@ def _backward_calls(
     )
     nq, nk = common["nq"], common["nk"]
     interpret = not device.on_tpu()
-    kvm, seed_arr = _kvm_column(kv_mask), _seed_array(seed)
-    biases = [_bias_row(bias, dtype)] * 3
-    bias_specs = [ops.bias_spec(use_bias, part) for part in range(3)]
     hb, lanes = ops.heads_a_block, ops.block_lanes
+    plan = backward_plan(
+        sq, sk, block_q, block_k, causal, lanes, jnp.dtype(dtype).itemsize
+    )
 
-    sub_q, sub_k = pick_subtiles(block_q, block_k, nq, nk, key_major=False)
-    # lse and delta enter as one lane-dense row a query sub-tile
-    rows = (ops.batch * ops.heads, sq // sub_q, 1, sub_q)
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, sub_q=sub_q, sub_k=sub_k, **common),
-        grid=(ops.groups, nq, nk),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            ops.spec("q", block_q, part=0),
-            ops.spec("k", block_k, part=1),
-            ops.spec("k", block_k, part=2),
-            *bias_specs,
-            ops.kvm_spec(use_mask, block_k),
-            ops.spec("q", block_q),
-            ops.row_spec(block_q, sub_q),
-            ops.row_spec(block_q, sub_q),
-        ],
-        out_specs=ops.spec("q", block_q),
-        out_shape=ops.result(sq, dtype),
-        scratch_shapes=[
+    def call(kernel, name, key_major, sub, out_specs, out_shape, scratch,
+             **params):
+        """One backward kernel over the operands they all read; lse and
+        delta enter as one lane-dense row a query sub-tile."""
+        sub_q, sub_k = sub
+        rows = (ops.batch * ops.heads, sq // sub_q, 1, sub_q)
+        return pl.pallas_call(
+            functools.partial(kernel, sub_q=sub_q, sub_k=sub_k, **common),
+            grid=(ops.groups, nk, nq) if key_major else (ops.groups, nq, nk),
+            in_specs=[
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+                ops.spec("q", block_q, key_major, part=0),
+                ops.spec("k", block_k, key_major, part=1),
+                ops.spec("k", block_k, key_major, part=2),
+                *(ops.bias_spec(use_bias, part) for part in range(3)),
+                ops.kvm_spec(use_mask, block_k, key_major),
+                ops.spec("q", block_q, key_major),
+                ops.row_spec(block_q, sub_q, key_major),
+                ops.row_spec(block_q, sub_q, key_major),
+            ],
+            out_specs=out_specs, out_shape=out_shape, scratch_shapes=scratch,
+            interpret=interpret, name=name, **params,
+        )(_seed_array(seed), q, k, v, *[_bias_row(bias, dtype)] * 3,
+          _kvm_column(kv_mask), do, lse.reshape(rows), delta.reshape(rows))
+
+    dkv_specs = [
+        ops.spec("k", block_k, key_major=True),
+        ops.spec("k", block_k, key_major=True),
+    ]
+    dkv_shapes = [ops.result(sk, dtype), ops.result(sk, dtype)]
+    dkv_scratch = [
+        pltpu.VMEM((hb, block_k, ops.head_dim), jnp.float32),
+        pltpu.VMEM((hb, block_k, ops.head_dim), jnp.float32),
+    ]
+    sub_q, sub_k = sub = plan["sub_q"], plan["sub_k"]
+    if plan["backward"] == "fused":
+        return call(
+            functools.partial(_bwd_dkv_kernel, fused=True), "flash_bwd_dkv",
+            True, sub,
+            [ops.spec(None, sq), *dkv_specs],
+            [ops.result(sq, dtype), *dkv_shapes],
+            [
+                pltpu.VMEM((sq // sub_q, lanes, sub_q), jnp.float32),
+                *dkv_scratch,
+                _transposed_scratch(
+                    block_k // sub_k, lanes, sub_k, dtype, interpret
+                ),
+            ],
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=PAIR_VMEM_BYTES + plan["dq_vmem_bytes"]
+            ),
+        )
+
+    dk, dv = call(
+        functools.partial(_bwd_dkv_kernel, fused=False), "flash_bwd_dkv", True,
+        sub, dkv_specs, dkv_shapes, dkv_scratch,
+    )
+    sub_q, sub_k = sub = pick_subtiles(block_q, block_k, nq, nk, key_major=False)
+    dq = call(
+        _bwd_dq_kernel, "flash_bwd_dq", False, sub, ops.spec("q", block_q),
+        ops.result(sq, dtype),
+        [
             pltpu.VMEM((block_q // sub_q, lanes, sub_q), jnp.float32),
             _transposed_scratch(block_k // sub_k, lanes, sub_k, dtype, interpret),
         ],
-        interpret=interpret,
-        name="flash_bwd_dq",
-    )(seed_arr, q, k, v, *biases, kvm, do, lse.reshape(rows), delta.reshape(rows))
-
-    sub_q, sub_k = pick_subtiles(block_q, block_k, nq, nk, key_major=True)
-    rows = (ops.batch * ops.heads, sq // sub_q, 1, sub_q)
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, sub_q=sub_q, sub_k=sub_k, **common),
-        grid=(ops.groups, nk, nq),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            ops.spec("q", block_q, key_major=True, part=0),
-            ops.spec("k", block_k, key_major=True, part=1),
-            ops.spec("k", block_k, key_major=True, part=2),
-            *bias_specs,
-            ops.kvm_spec(use_mask, block_k, key_major=True),
-            ops.spec("q", block_q, key_major=True),
-            ops.row_spec(block_q, sub_q, key_major=True),
-            ops.row_spec(block_q, sub_q, key_major=True),
-        ],
-        out_specs=[
-            ops.spec("k", block_k, key_major=True),
-            ops.spec("k", block_k, key_major=True),
-        ],
-        out_shape=[ops.result(sk, dtype), ops.result(sk, dtype)],
-        scratch_shapes=[
-            pltpu.VMEM((hb, block_k, ops.head_dim), jnp.float32),
-            pltpu.VMEM((hb, block_k, ops.head_dim), jnp.float32),
-        ],
-        interpret=interpret,
-        name="flash_bwd_dkv",
-    )(seed_arr, q, k, v, *biases, kvm, do, lse.reshape(rows), delta.reshape(rows))
+    )
     return dq, dk, dv
 
 
@@ -1042,7 +1179,14 @@ def _flash_packed_bwd(
         ops, qkv, qkv, qkv, bias, kv_mask, seed, g, lse, delta, s, s, causal,
         sm_scale, dropout_rate, block_q, block_k,
     )
-    dqkv = jnp.concatenate([dq, dk, dv], axis=-1)
+    # With dq, dk and dv the three results of ONE kernel XLA writes their
+    # concatenation out in three passes of its own (0.12 ms a layer at the
+    # GPT-2 cells' shape, PERF.md PR 33); behind the barrier dq is another
+    # operation's result, as it was when it had its own kernel, and the
+    # concatenation folds into the projection's backward products again.
+    dqkv = jnp.concatenate(
+        [jax.lax.optimization_barrier(dq), dk, dv], axis=-1
+    )
     dbias = None
     if bias is not None:
         dbias = jnp.sum(dqkv.astype(jnp.float32), axis=(0, 1)).astype(bias.dtype)

@@ -87,81 +87,97 @@ def _compiled_text(fn, *shapes):
 
 
 # ---------------------------------------------------------------------------
-# flash attention: forward, dq, dkv (ops/attention.py)
+# flash attention: the forward and the backward that the shape gets, the
+# fused kernel or the pair (ops/attention.py)
 # ---------------------------------------------------------------------------
-@functools.lru_cache(maxsize=None)
-def _flash_train_text(model):
-    """One fwd+bwd compile per shape: [8, heads, 1024, 64] bf16 causal at
-    the default blocks (one 1024-block each way) — the window
-    chip_smoke.py trains with."""
-    from deepspeed_tpu.ops.attention import flash_attention
+FLASH_KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+FUSED, PAIR = ["flash_bwd_dkv", "flash_fwd"], sorted(FLASH_KERNELS)
 
-    qkv = _shape((8, HEADS[model], 1024, 64), jnp.bfloat16)
 
-    def loss(q, k, v):
-        return flash_attention(q, k, v, causal=True).astype(jnp.float32).sum()
+def _pallas_kernels(text):
+    """The kernel of every Mosaic call of a compiled program, sorted.
+    ``flash_ms.train`` sums exactly ``FLASH_KERNELS``: a kernel under any
+    other name would stay in the window unseen."""
+    import re
+
+    names = []
+    for line in text.splitlines():
+        if "tpu_custom_call" in line:
+            instruction = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = ", line).group(1)
+            kernel = re.search(r"flash_(?:fwd|bwd_dq|bwd_dkv)(?![a-z])", instruction)
+            assert kernel, f"a Pallas kernel outside {FLASH_KERNELS}: {instruction}"
+            names.append(kernel.group(0))
+    return sorted(names)
+
+
+# Forward + backward, bf16, blocks and sub-tiles as the code picks them:
+# (entry, batch, heads, seq, head width, causal, key mask, the kernels).
+# The fused backward's risk is VMEM: dq's float32 accumulator and output
+# block cover the whole query length (0.5 + 0.5 MiB at 1,024 positions, 4 + 4
+# in the Ouro and Nemotron cells, 16 + 16 in Qwen3-Next's), and the call
+# raises its own limit to hold them. What Mosaic refuses here the chip
+# refuses too.
+FLASH_COMPILES = {
+    # [8, heads, 1024, 64] causal at the default blocks (one 1024-block each
+    # way): the window chip_smoke.py trains with
+    "large": ("split", 8, 20, 1024, 64, True, False, FUSED),
+    "xl": ("split", 8, 25, 1024, 64, True, False, FUSED),
+    # through the ``attention()`` dispatcher, the route models take: both
+    # GPT-2 cells' shape (a chip's share of zero2-dp4 included) ...
+    "gpt2": ("dispatch", 8, 20, 1024, 64, True, False, FUSED),
+    # ... and BERT-large pre-training phase 2, bidirectional with a padding
+    # mask (a refusal of the masked path shows here)
+    "bert512": ("dispatch", 8, 16, 512, 64, False, True, FUSED),
+    # the cells' packed entry: two heads of 64 a block with the bias added on
+    # load (GPT-2, BERT), one head of 128 on an 8 x 8 grid (Ouro)
+    "gpt2_packed": ("packed", 8, 20, 1024, 64, True, False, FUSED),
+    "ouro": ("packed", 1, 16, 8192, 128, True, False, FUSED),
+    # split operands after the grouped-query repetition: Nemotron's 8 x 8
+    # grid at width 128, Qwen3-Next's 16 x 16 at width 256
+    "nemotron": ("split", 2, 4, 8192, 128, True, False, FUSED),
+    "qwen3next": ("split", 2, 16, 16384, 256, True, False, FUSED),
+    # past the budget (64 MiB of dq): the pair, three kernels
+    "seq32768_width256": ("split", 1, 2, 32768, 256, True, False, PAIR),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(FLASH_COMPILES))
+def test_flash_kernels_compile_for_v5e(shape):
+    from deepspeed_tpu.ops.attention import (
+        attention, flash_attention, flash_attention_packed,
+    )
+
+    entry, b, h, s, d, causal, masked, kernels = FLASH_COMPILES[shape]
+    pad = _shape((b, 1, 1, s), jnp.float32)
+    if entry == "packed":
+        operands = (
+            _shape((b, s, 3 * h * d), jnp.bfloat16),
+            _shape((3 * h * d,), jnp.bfloat16),
+        )
+
+        def run(qkv, bias, mask):
+            return flash_attention_packed(qkv, h, bias=bias, causal=causal)
+    else:
+        operands = (_shape((b, h, s, d), jnp.bfloat16),) * 3
+        call = attention if entry == "dispatch" else flash_attention
+
+        def run(q, k, v, mask):
+            return call(q, k, v, mask=mask if masked else None, causal=causal)
+
+    def loss(*args):
+        return run(*args).astype(jnp.float32).sum()
 
     # the flash entry points take no ``interpret`` argument; they ask the
     # one platform probe, which a rehearsal answers for the described chip
-    real, device.on_tpu = device.on_tpu, lambda: True
-    try:
-        return _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv)
-    finally:
-        device.on_tpu = real
-
-
-@pytest.mark.parametrize("model", sorted(HEADS))
-@pytest.mark.parametrize(
-    "kernel", ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]
-)
-def test_flash_kernel_compiles_for_v5e(kernel, model):
-    assert kernel in _flash_train_text(model)
-
-
-@functools.lru_cache(maxsize=None)
-def _flash_cell_text(cell):
-    """fwd+bwd through the ``attention()`` dispatcher, the route the
-    benchmark's cells take (blocks and sub-tiles as the code picks them):
-    ``gpt2``: [8, 20, 1024, 64] bf16 causal, no key mask (both GPT-2
-    cells, a chip's share of zero2-dp4 included); ``bert512``: BERT-large
-    pre-training phase 2, [8, 16, 512, 64] bf16, bidirectional with a
-    padding mask (a VMEM or Mosaic refusal of the masked path shows here)."""
-    from deepspeed_tpu.ops.attention import attention
-
-    b, h, s, causal, masked = {
-        "gpt2": (8, 20, 1024, True, False),
-        "bert512": (8, 16, 512, False, True),
-    }[cell]
-    qkv = _shape((b, h, s, 64), jnp.bfloat16)
-    pad = _shape((b, 1, 1, s), jnp.float32)
-
-    def loss(q, k, v, mask):
-        out = attention(
-            q, k, v, mask=mask if masked else None, causal=causal
-        )
-        return out.astype(jnp.float32).sum()
-
     real = device.on_tpu, jax.device_count
     device.on_tpu, jax.device_count = (lambda: True), (lambda: 1)
     try:
-        return _compiled_text(
-            jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv, pad
+        text = _compiled_text(
+            jax.grad(loss, argnums=tuple(range(len(operands)))), *operands, pad
         )
     finally:
         device.on_tpu, jax.device_count = real
-
-
-@pytest.mark.parametrize("cell", ["gpt2", "bert512"])
-@pytest.mark.parametrize(
-    "kernel", ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]
-)
-def test_flash_kernel_compiles_at_the_cells_shapes(kernel, cell):
-    text = _flash_cell_text(cell)
-    assert kernel in text
-    # exactly three Pallas kernels: flash_ms.train sums these three names,
-    # and a fourth kernel's time would stay in the window unseen
-    calls = [l for l in text.splitlines() if "tpu_custom_call" in l]
-    assert len(calls) == 3, calls
+    assert _pallas_kernels(text) == kernels
 
 
 # ---------------------------------------------------------------------------
@@ -285,8 +301,7 @@ def test_layer_body_hands_the_kernels_the_projections_own_buffer():
         and i[2].endswith("dot_general")
     ]
     assert len(stacked) == 1, "the projection no longer writes into the stack"
-    calls = [l for l in text.splitlines() if "tpu_custom_call" in l]
-    assert len(calls) == 3, calls
+    assert _pallas_kernels(text) == FUSED
 
 
 def test_layer_body_falls_back_to_split_heads_at_25_heads():
@@ -297,8 +312,7 @@ def test_layer_body_falls_back_to_split_heads_at_25_heads():
     assert "[8,25,1024,64]" in text
     copies, splits = _layout_operations(text)
     assert len(copies) >= 6 and len(splits) == 2
-    calls = [l for l in text.splitlines() if "tpu_custom_call" in l]
-    assert len(calls) == 3, calls
+    assert _pallas_kernels(text) == FUSED
 
 
 # ---------------------------------------------------------------------------
@@ -342,10 +356,9 @@ def test_hybrid_mixer_compiles_at_the_cells_shapes(kind):
         device.on_tpu, jax.device_count = real
     text = compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 6 * 2 ** 30
-    kernels = [l for l in text.splitlines() if "tpu_custom_call" in l]
-    # only attention brings Pallas kernels, and exactly the three that
-    # flash_ms.train sums
-    assert len(kernels) == (3 if kind == "attn" else 0), kernels
+    # only attention brings Pallas kernels: the forward and the fused
+    # backward, under names that flash_ms.train sums
+    assert _pallas_kernels(text) == (FUSED if kind == "attn" else [])
     for scope in {"mamba": ("mamba_mixer", "mamba_ssd"),
                   "moe": ("moe_route", "moe_experts", "moe_shared"),
                   "attn": ("attn_mixer",)}[kind]:
@@ -401,7 +414,9 @@ def test_gated_hybrid_sublayer_compiles_at_the_cells_shapes(kind):
     text = compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 6 * 2 ** 30
     kernels = [l for l in text.splitlines() if "tpu_custom_call" in l]
-    assert len(kernels) == {"gdn": 2, "gattn": 3, "gmoe": 0}[kind], kernels
+    assert len(kernels) == {"gdn": 2, "gattn": 2, "gmoe": 0}[kind], kernels
+    if kind == "gattn":
+        assert _pallas_kernels(text) == FUSED
     for scope in {"gdn": ("gdn_mixer", "gdn_delta_rule"),
                   "gattn": ("attn_mixer",),
                   "gmoe": ("moe_route", "moe_experts", "moe_shared")}[kind]:
@@ -454,7 +469,7 @@ def test_looped_stack_compiles_at_the_cells_shapes():
     text = compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 6 * 2 ** 30
     kernels = [l for l in text.splitlines() if "tpu_custom_call" in l]
-    assert len(kernels) == 3, kernels
+    assert _pallas_kernels(text) == FUSED
     assert all("/loop_pass/" in l and "/attn_mixer/" in l for l in kernels)
     # packed: the kernels read [B, S, 3 * H * D] and no [B, H, S, D] exists
     assert "[1,8192,6144]" in text and "[1,16,8192,128]" not in text

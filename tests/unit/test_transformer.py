@@ -269,7 +269,7 @@ def test_flash_long_sequence_no_cap():
 FLASH_CASES = {
     # diag_offset 256 > 0: the diagonal starts in the third key sub-tile
     "causal_sq256_sk512": (256, 512, True, None, 1024),
-    # one 768-block: 256 sub-tiles in the forward and dq, 128 in dkv
+    # one 768-block: 256 sub-tiles in the forward and the backward
     "causal_768": (768, 768, True, None, 1024),
     # 768 under the old default: 256-blocks on a 3 x 3 grid, each its own
     # sub-tile, loop bounds from the grid position
@@ -277,7 +277,8 @@ FLASH_CASES = {
     # the block IS the sub-tile
     "causal_128": (128, 128, True, None, 1024),
     # the cells' sequence: one block, every bound static, 2 x 2 sub-tiles
-    # in the forward and dq, 8 x 8 in dkv
+    # in the forward, 4 x 4 in the fused backward (dq 2 x 2 and dkv 8 x 8
+    # where the pair runs)
     "causal_1024": (1024, 1024, True, None, 1024),
     # 2 x 2 blocks of 2 x 2 sub-tiles: the fori_loop walk
     "causal_2048": (2048, 2048, True, None, 1024),
@@ -295,13 +296,13 @@ FLASH_CASES = {
 # dtype, head_dim). Only self-attention shapes have a packed form.
 _DTYPES = {"f32": jnp.float32, "bf16": jnp.bfloat16}
 FLASH_RUNS = [
-    pytest.param(case, dtype, None, id=f"{case}-{dtype}")
+    pytest.param(case, dtype, None, False, id=f"{case}-{dtype}")
     for case in sorted(FLASH_CASES) for dtype in _DTYPES
 ] + [
-    pytest.param(case, "f32", 64, id=f"{case}-f32-packed_d64")
+    pytest.param(case, "f32", 64, False, id=f"{case}-f32-packed_d64")
     for case in sorted(FLASH_CASES) if case != "causal_sq256_sk512"
 ] + [
-    pytest.param(case, dtype, d, id=f"{case}-{dtype}-packed_d{d}")
+    pytest.param(case, dtype, d, False, id=f"{case}-{dtype}-packed_d{d}")
     for case, dtype, d in [
         ("causal_1024", "bf16", 64),
         ("causal_2048", "bf16", 64),
@@ -311,6 +312,24 @@ FLASH_RUNS = [
         ("causal_2048", "f32", 128),
         ("causal_768_blocks256", "f32", 128),
         ("noncausal_384_masked", "f32", 128),
+    ]
+] + [
+    # Two backwards (PR 33). Every case above takes the one
+    # ``backward_plan`` picks for its shape, which at these sizes is the
+    # fused kernel; these run the PAIR as well (the budget that the plan
+    # reads set to nothing) and hold it to the reference and to the fused
+    # kernel's gradients: sq != sk, a leading masked sub-tile, a 3 x 3 and
+    # a 2 x 2 grid, two heads of 64 and one of 128 to a block.
+    pytest.param(case, dtype, d, True, id=f"{case}-{dtype}-{d and f'packed_d{d}-'}pair")
+    for case, dtype, d in [
+        ("causal_sq256_sk512", "f32", ""),
+        ("causal_384_leading_keys_masked", "f32", ""),
+        ("causal_768_blocks256", "f32", ""),
+        ("causal_2048", "f32", ""),
+        ("causal_1024", "f32", 64),
+        ("causal_2048", "bf16", 64),
+        ("noncausal_384_masked", "f32", 64),
+        ("causal_768_blocks256", "f32", 128),
     ]
 ]
 
@@ -330,8 +349,16 @@ def _packed_as_split(q, k, v, **kw):
     return out.reshape(b, s, h, d).transpose(0, 2, 1, 3)
 
 
-@pytest.mark.parametrize("case,dtype,packed_d", FLASH_RUNS)
-def test_flash_tiled_matches_reference(case, dtype, packed_d):
+def _attention_module():
+    """``deepspeed_tpu.ops.attention`` the attribute is the dispatcher."""
+    import importlib
+
+    return importlib.import_module("deepspeed_tpu.ops.attention")
+
+
+@pytest.mark.parametrize("case,dtype,packed_d,pair", FLASH_RUNS)
+def test_flash_tiled_matches_reference(case, dtype, packed_d, pair, monkeypatch):
+    att = _attention_module()
     sq, sk, causal, masked, block = FLASH_CASES[case]
     dtype = _DTYPES[dtype]
     B, H, D = (1, 1, 64) if sq > 1024 else (2, 2, 64)
@@ -378,7 +405,28 @@ def test_flash_tiled_matches_reference(case, dtype, packed_d):
     if masked is not None and causal:
         assert (~live).sum() >= 130
 
-    gf = jax.grad(lambda *a: jnp.sum(flash(*a) * w), (0, 1, 2))(q, k, v)
+    def flash_grads():
+        return jax.grad(lambda *a: jnp.sum(flash(*a) * w), (0, 1, 2))(q, k, v)
+
+    blocks = att.pick_block(sq, block), att.pick_block(sk, block)
+    assert att.backward_plan(sq, sk, *blocks, causal)["backward"] == "fused"
+    gf = flash_grads()
+    if pair:
+        monkeypatch.setattr(att, "FUSED_DQ_VMEM_BUDGET", 0)
+        plan = att.backward_plan(sq, sk, *blocks, causal)
+        assert plan["backward"] == "pair" and "budget 0" in plan["reason"]
+        fused, gf = gf, flash_grads()
+        # the same products of the same rounded operands, summed in float32
+        # in another order (dq: key block by key block in both, but the
+        # fused kernel adds each sub-tile's product into its accumulator
+        # where the pair chains a block's sub-tiles first)
+        for a, b, name in zip(fused, gf, "qkv"):
+            a, b = np.asarray(f32(a)), np.asarray(f32(b))
+            ulp = 2e-6 if dtype == jnp.float32 else 2.0 ** -7
+            assert np.abs(a - b).max() <= ulp * np.abs(b).max(), (
+                f"d{name}: fused and pair part by "
+                f"{np.abs(a - b).max() / np.abs(b).max():.2e}"
+            )
     gr = jax.grad(lambda *a: jnp.sum(reference(*a) * w), (0, 1, 2))(q, k, v)
     for a, b, name in zip(gf, gr, "qkv"):
         a, b = np.asarray(f32(a)), np.asarray(b)
@@ -408,6 +456,38 @@ def test_flash_loop_bounds_stop_at_the_diagonal():
     # on a grid of several blocks dkv too walks in the large sub-tile
     t = flash_tiling(2048, 2048, 1024, 1024, True, key_major=True)
     assert (t["sub_q"], t["sub_k"]) == (512, 512)
+    # the backward (PR 33): one key-major kernel for dq, dk and dv, in
+    # 256-steps at one block each way (5/8 of the causal square, where the
+    # pair's dq kernel walked 3/4 and its dkv 9/16); its dq accumulator
+    # (float32) and output block (two buffers of bf16) cover the whole
+    # query length in VMEM
+    b = flash_tiling(1024, 1024, 1024, 1024, True)["backward"]
+    assert b == {
+        "backward": "fused", "sub_q": 256, "sub_k": 256,
+        "visited_share": 0.625, "dq_vmem_bytes": 1024 * 128 * 8,
+        "reason": None,
+    }
+    # BERT at 512: one block, 2 x 2 sub-tiles; 768: 3 x 3
+    b = flash_tiling(512, 512, 512, 512, False)["backward"]
+    assert (b["sub_q"], b["sub_k"], b["visited_share"]) == (256, 256, 1.0)
+    b = flash_tiling(768, 768, 768, 768, True)["backward"]
+    assert (b["sub_q"], b["sub_k"]) == (256, 256)
+    b = flash_tiling(8192, 8192, 1024, 1024, True)["backward"]
+    assert (b["backward"], b["sub_q"], b["sub_k"]) == ("fused", 512, 512)
+    assert b["visited_share"] == 0.53125 and b["dq_vmem_bytes"] == 8 * 2**20
+    # a block of 64 lanes takes a register's 128 all the same
+    assert flash_tiling(1024, 1024, 1024, 1024, True, lanes=64)["backward"][
+        "dq_vmem_bytes"] == 2**20
+    # past the budget the pair runs, and the plan says why: 32k positions at
+    # width 256 (64 MiB of dq) under the default, anything under none
+    b = flash_tiling(32768, 32768, 1024, 1024, True, lanes=256)["backward"]
+    assert b["backward"] == "pair" and b["dq_vmem_bytes"] == 0
+    assert "32768 rows of 256 lanes" in b["reason"]
+    assert flash_tiling(16384, 16384, 1024, 1024, True, lanes=256)[
+        "backward"]["backward"] == "fused"
+    b = flash_tiling(1024, 1024, 1024, 1024, True, budget=2**20 - 1)["backward"]
+    assert b["backward"] == "pair" and "budget 1048575" in b["reason"]
+    assert (b["sub_q"], b["sub_k"], b["visited_share"]) == (128, 128, 0.5625)
     # a block the sub-tile does not divide: the largest halving that does,
     # else the block itself
     t = flash_tiling(768, 768, 768, 768, True)
@@ -458,11 +538,9 @@ def test_flash_loop_bounds_stop_at_the_diagonal():
 def _attention_debug_log():
     """The debug lines ``ops/attention.py`` logs while the block is open,
     with its once-a-shape caches emptied first."""
-    import importlib
     import logging
 
-    # ``deepspeed_tpu.ops.attention`` the attribute is the dispatcher
-    att = importlib.import_module("deepspeed_tpu.ops.attention")
+    att = _attention_module()
     seen = []
     handler = logging.Handler()
     handler.emit = lambda record: seen.append(record.getMessage())
@@ -488,9 +566,66 @@ def test_flash_tiling_is_logged_once_per_shape():
     t = att.flash_tiling(1024, 1024, 1024, 1024, True)
     assert f" sub={t['sub_q']}x{t['sub_k']} " in lines[0]
     assert f" visited_share={t['visited_share']:.4f} " in lines[0]
-    t = att.flash_tiling(1024, 1024, 1024, 1024, True, key_major=True)
-    assert f"dkv_sub={t['sub_q']}x{t['sub_k']} " in lines[0]
-    assert lines[0].endswith(f"dkv_visited_share={t['visited_share']:.4f}")
+    b = att.flash_tiling(1024, 1024, 1024, 1024, True, lanes=64, itemsize=4)[
+        "backward"]
+    assert lines[0].endswith(
+        f" backward=fused bwd_sub={b['sub_q']}x{b['sub_k']} "
+        f"bwd_visited_share={b['visited_share']:.4f} "
+        f"dq_vmem_bytes={b['dq_vmem_bytes']}"
+    )
+
+
+# The flash calls of the seven cells that run the kernels, as their models make
+# them: (entry, batch, seq, heads, head width, causal, key mask).
+# ``zero2-dp4``'s is one chip's shard of 32 rows under ``shard_map``.
+BACKWARD_CASES = {
+    "gpt2-large.train-seq1024": ("packed", 8, 1024, 20, 64, True, False),
+    "gpt2-large.train-accum1": ("packed", 8, 1024, 20, 64, True, False),
+    "gpt2-large.zero2-dp4": ("packed", 8, 1024, 20, 64, True, False),
+    "bert-large.pretrain-seq512": ("packed", 8, 512, 16, 64, False, True),
+    "ouro-2.6b.train-seq8192": ("packed", 1, 8192, 16, 128, True, False),
+    "nemotron3-super-120b-a12b.train-seq8192": ("split", 2, 8192, 4, 128, True, False),
+    "qwen3-next-80b-a3b.train-seq16384": ("split", 2, 16384, 16, 256, True, False),
+    # no cell: a sequence whose dq does not fit the budget keeps the pair
+    "seq32768_width256": ("split", 1, 32768, 2, 256, True, False),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(BACKWARD_CASES))
+def test_backward_is_chosen_by_shape_and_logged(cell):
+    """The ``flash_tiling`` line of each cell's call names the backward it
+    gets, from the function that makes the choice (``backward_plan``)."""
+    from deepspeed_tpu.ops.attention import flash_attention_packed
+
+    entry, b, s, h, d, causal, masked = BACKWARD_CASES[cell]
+    kv_mask = jnp.ones((b, s), jnp.int32) if masked else None
+    with _attention_debug_log() as (att, seen):
+        if entry == "packed":
+            jax.eval_shape(
+                lambda x: flash_attention_packed(
+                    x, h, kv_mask=kv_mask, causal=causal),
+                jax.ShapeDtypeStruct((b, s, 3 * h * d), jnp.bfloat16),
+            )
+        else:
+            q = jax.ShapeDtypeStruct((b, h, s, d), jnp.bfloat16)
+            jax.eval_shape(
+                lambda q: flash_attention(q, q, q, kv_mask=kv_mask, causal=causal),
+                q,
+            )
+    (line,) = [m for m in seen if m.startswith("flash_tiling")]
+    lanes = 128 if entry == "packed" else d
+    block = min(s, 1024)
+    plan = att.backward_plan(s, s, block, block, causal, lanes)
+    if cell == "seq32768_width256":
+        assert plan["backward"] == "pair"
+        assert line.endswith(f" backward=pair bwd_sub=512x512 "
+                             f"bwd_visited_share={plan['visited_share']:.4f} "
+                             f"dq_vmem_bytes=0 reason={plan['reason']!r}")
+        return
+    assert plan["backward"] == "fused" and plan["reason"] is None
+    assert plan["dq_vmem_bytes"] == s * lanes * 8 <= att.FUSED_DQ_VMEM_BUDGET
+    assert f" backward=fused bwd_sub={plan['sub_q']}x{plan['sub_k']} " in line
+    assert line.endswith(f" dq_vmem_bytes={plan['dq_vmem_bytes']}")
 
 
 # what the dispatcher sees in each cell of the benchmark (and in two shapes

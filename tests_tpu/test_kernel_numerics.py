@@ -264,6 +264,40 @@ def test_packed_matches_split_bit_for_bit(shape, dropout):
     _one_step_apart(jnp.concatenate([_merge(g) for g in gs], -1), gp, "dqkv")
 
 
+@pytest.mark.parametrize("dropout", [0.0, 0.3], ids=["plain", "dropout"])
+@pytest.mark.parametrize(
+    "shape", [(8, 20, 1024, 64), (1, 16, 8192, 128)],
+    ids=["gpt2_cells_one_block", "ouro_cell_8x8_blocks"],
+)
+def test_fused_backward_matches_the_pair(shape, dropout, monkeypatch):
+    """The one key-major backward kernel (PR 33) against ``flash_bwd_dq`` +
+    ``flash_bwd_dkv`` at two cells' packed shapes: the same products of the
+    same rounded operands and, with dropout on, the same bits (they are a
+    function of seed, head and position, not of the walk), so dq, dk and dv
+    differ only by the order of their float32 sums."""
+    import importlib
+
+    # ``deepspeed_tpu.ops.attention`` the attribute is the dispatcher
+    att = importlib.import_module("deepspeed_tpu.ops.attention")
+    h = shape[1]
+    _, qkv, w = _projection(shape, 33)
+    kw = dict(causal=True, dropout_rate=dropout, dropout_seed=5)
+
+    def grad():
+        # a new function each time: nothing of the other backward's trace
+        return jax.jit(jax.grad(
+            lambda a: jnp.sum(_f32(flash_attention_packed(a, h, **kw)) * w)
+        ))(qkv)
+
+    s = shape[2]
+    block = min(s, 1024)
+    assert att.backward_plan(s, s, block, block, True)["backward"] == "fused"
+    fused = grad()
+    monkeypatch.setattr(att, "FUSED_DQ_VMEM_BUDGET", 0)
+    assert att.backward_plan(s, s, block, block, True)["backward"] == "pair"
+    _one_step_apart(fused, grad(), "dqkv")
+
+
 def test_packed_bias_is_the_projections_own_sum():
     """The kernels add the projection's bias as they load: the same bf16
     sum XLA writes out, so context and gradients are the same bits as for
