@@ -126,16 +126,17 @@ class BertEmbeddings(nn.Module):
         tok = self.param("token_type_embeddings", init, (cfg.type_vocab_size, cfg.hidden_size))
 
         s = input_ids.shape[1]
-        if cfg.sparse_gradients:
-            from ..runtime.sparse import sparse_embedding_lookup
+        with jax.named_scope("embed"):
+            if cfg.sparse_gradients:
+                from ..runtime.sparse import sparse_embedding_lookup
 
-            x = sparse_embedding_lookup(word, input_ids, cfg.mesh)
-        else:
-            x = word[input_ids]
-        x = x + pos[None, :s, :]
-        if token_type_ids is not None:
-            x = x + tok[token_type_ids]
-        x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, name="LayerNorm")(x)
+                x = sparse_embedding_lookup(word, input_ids, cfg.mesh)
+            else:
+                x = word[input_ids]
+            x = x + pos[None, :s, :]
+            if token_type_ids is not None:
+                x = x + tok[token_type_ids]
+            x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, name="LayerNorm")(x)
         if train and cfg.hidden_dropout_prob > 0:
             x = nn.Dropout(cfg.hidden_dropout_prob, deterministic=False)(
                 x, rng=self.make_rng("dropout")
@@ -167,25 +168,27 @@ class BertEncoder(nn.Module):
                 or cfg.attention_probs_dropout_prob > 0
             )
             dropout_key = self.make_rng("dropout") if need_rng else None
-            return zero3_scan_stack(
-                layer_cfg, p, hidden_states, cfg.zero3_gather, cfg.mesh,
-                causal=False, use_flash=cfg.use_flash, train=train,
-                dropout_key=dropout_key, attention_mask=attention_mask,
+            with jax.named_scope("stack_scan"):
+                return zero3_scan_stack(
+                    layer_cfg, p, hidden_states, cfg.zero3_gather, cfg.mesh,
+                    causal=False, use_flash=cfg.use_flash, train=train,
+                    dropout_key=dropout_key, attention_mask=attention_mask,
+                )
+        with jax.named_scope("stack_scan"):
+            hidden_states, _ = nn.scan(
+                lambda mdl, c, _: (mdl(c, attention_mask, train=train), None),
+                variable_axes={"params": 0},
+                split_rngs={"params": True, "dropout": True},
+                length=cfg.num_hidden_layers,
+                metadata_params={nn.PARTITION_NAME: "layers"},
+            )(
+                DeepSpeedTransformerLayer(
+                    config=cfg.layer_config(), causal=False,
+                    use_flash=cfg.use_flash, mesh=cfg.mesh, name="layer",
+                ),
+                hidden_states,
+                None,
             )
-        hidden_states, _ = nn.scan(
-            lambda mdl, c, _: (mdl(c, attention_mask, train=train), None),
-            variable_axes={"params": 0},
-            split_rngs={"params": True, "dropout": True},
-            length=cfg.num_hidden_layers,
-            metadata_params={nn.PARTITION_NAME: "layers"},
-        )(
-            DeepSpeedTransformerLayer(
-                config=cfg.layer_config(), causal=False,
-                use_flash=cfg.use_flash, mesh=cfg.mesh, name="layer",
-            ),
-            hidden_states,
-            None,
-        )
         return hidden_states
 
 
@@ -205,9 +208,10 @@ class BertModel(nn.Module):
             ).astype(jnp.float32)
         x = BertEncoder(cfg, name="encoder")(x, additive_mask, train=train)
         # pooler: tanh(dense(first token)), used by the NSP head
-        pooled = nn.tanh(
-            nn.Dense(cfg.hidden_size, name="pooler")(x[:, 0])
-        )
+        with jax.named_scope("head_loss"):
+            pooled = nn.tanh(
+                nn.Dense(cfg.hidden_size, name="pooler")(x[:, 0])
+            )
         return x, pooled, word_table
 
 
@@ -288,19 +292,23 @@ class BertForPreTraining(nn.Module):
             input_ids, attention_mask, token_type_ids, train=train
         )
         # MLM head: transform + decoder tied to word embeddings
-        h = nn.Dense(cfg.hidden_size, name="transform")(seq_out)
-        h = nn.gelu(h, approximate=True)
-        h = nn.LayerNorm(epsilon=cfg.layer_norm_eps, name="transform_ln")(h)
-        vocab_padded = word_emb.shape[0]
-        mlm_bias = self.param("mlm_bias", nn.initializers.zeros, (vocab_padded,))
-        logits = h @ word_emb.T + mlm_bias
+        with jax.named_scope("head_loss"):
+            h = nn.Dense(cfg.hidden_size, name="transform")(seq_out)
+            h = nn.gelu(h, approximate=True)
+            h = nn.LayerNorm(
+                epsilon=cfg.layer_norm_eps, name="transform_ln")(h)
+            vocab_padded = word_emb.shape[0]
+            mlm_bias = self.param(
+                "mlm_bias", nn.initializers.zeros, (vocab_padded,))
+            logits = h @ word_emb.T + mlm_bias
 
-        loss = jnp.float32(0.0)
-        if masked_lm_labels is not None:
-            loss = loss + cross_entropy_ignore_index(logits, masked_lm_labels)
-        if next_sentence_label is not None:
-            nsp_logits = nn.Dense(2, name="nsp")(pooled)
-            loss = loss + cross_entropy_ignore_index(
-                nsp_logits, next_sentence_label
-            )
+            loss = jnp.float32(0.0)
+            if masked_lm_labels is not None:
+                loss = loss + cross_entropy_ignore_index(
+                    logits, masked_lm_labels)
+            if next_sentence_label is not None:
+                nsp_logits = nn.Dense(2, name="nsp")(pooled)
+                loss = loss + cross_entropy_ignore_index(
+                    nsp_logits, next_sentence_label
+                )
         return loss

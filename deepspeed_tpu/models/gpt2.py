@@ -149,67 +149,70 @@ class GPT2Model(nn.Module):
         wpe = self.param("wpe", init, (cfg.n_positions, cfg.n_embd))
 
         s = input_ids.shape[1]
-        if cfg.sparse_gradients:
-            from ..runtime.sparse import sparse_embedding_lookup
+        with jax.named_scope("embed"):
+            if cfg.sparse_gradients:
+                from ..runtime.sparse import sparse_embedding_lookup
 
-            x = sparse_embedding_lookup(wte, input_ids, cfg.mesh) + wpe[None, :s, :]
-        else:
-            x = wte[input_ids] + wpe[None, :s, :]
+                x = sparse_embedding_lookup(
+                    wte, input_ids, cfg.mesh) + wpe[None, :s, :]
+            else:
+                x = wte[input_ids] + wpe[None, :s, :]
         if train and cfg.dropout > 0:
             x = nn.Dropout(cfg.dropout, deterministic=False)(
                 x, rng=self.make_rng("dropout")
             )
 
         moe_aux = None
-        if cfg.pipeline_stages > 1:
-            if cfg.moe_experts > 0:
-                raise ValueError(
-                    "pipeline_stages > 1 with moe_experts > 0 is not "
-                    "supported yet; pick one of pp or ep for the stack"
-                )
-            x = self._pipelined_stack(x, train)
-        elif cfg.moe_experts > 0:
-            from ..ops.moe import DeepSpeedMoETransformerLayer, MoEConfig
+        with jax.named_scope("stack_scan"):
+            if cfg.pipeline_stages > 1:
+                if cfg.moe_experts > 0:
+                    raise ValueError(
+                        "pipeline_stages > 1 with moe_experts > 0 is not "
+                        "supported yet; pick one of pp or ep for the stack"
+                    )
+                x = self._pipelined_stack(x, train)
+            elif cfg.moe_experts > 0:
+                from ..ops.moe import DeepSpeedMoETransformerLayer, MoEConfig
 
-            x, aux_per_layer = nn.scan(
-                lambda mdl, c, _: mdl(c, None, train=train),
-                variable_axes={"params": 0},
-                split_rngs={"params": True, "dropout": True},
-                length=cfg.n_layer,
-                metadata_params={nn.PARTITION_NAME: "layers"},
-            )(
-                DeepSpeedMoETransformerLayer(
-                    config=cfg.layer_config(),
-                    moe=MoEConfig(
-                        n_experts=cfg.moe_experts,
-                        top_k=cfg.moe_top_k,
-                        capacity_factor=cfg.moe_capacity_factor,
-                        aux_loss_weight=cfg.moe_aux_loss_weight,
+                x, aux_per_layer = nn.scan(
+                    lambda mdl, c, _: mdl(c, None, train=train),
+                    variable_axes={"params": 0},
+                    split_rngs={"params": True, "dropout": True},
+                    length=cfg.n_layer,
+                    metadata_params={nn.PARTITION_NAME: "layers"},
+                )(
+                    DeepSpeedMoETransformerLayer(
+                        config=cfg.layer_config(),
+                        moe=MoEConfig(
+                            n_experts=cfg.moe_experts,
+                            top_k=cfg.moe_top_k,
+                            capacity_factor=cfg.moe_capacity_factor,
+                            aux_loss_weight=cfg.moe_aux_loss_weight,
+                        ),
+                        causal=True, use_flash=cfg.use_flash, mesh=cfg.mesh,
+                        name="h",
                     ),
-                    causal=True, use_flash=cfg.use_flash, mesh=cfg.mesh,
-                    name="h",
-                ),
-                x,
-                None,
-            )
-            moe_aux = jnp.sum(aux_per_layer)
-        elif cfg.zero3_gather is not None:
-            x = self._zero3_stack(x, train)
-        else:
-            x, _ = nn.scan(
-                lambda mdl, c, _: (mdl(c, None, train=train), None),
-                variable_axes={"params": 0},
-                split_rngs={"params": True, "dropout": True},
-                length=cfg.n_layer,
-                metadata_params={nn.PARTITION_NAME: "layers"},
-            )(
-                DeepSpeedTransformerLayer(
-                    config=cfg.layer_config(), causal=True,
-                    use_flash=cfg.use_flash, mesh=cfg.mesh, name="h",
-                ),
-                x,
-                None,
-            )
+                    x,
+                    None,
+                )
+                moe_aux = jnp.sum(aux_per_layer)
+            elif cfg.zero3_gather is not None:
+                x = self._zero3_stack(x, train)
+            else:
+                x, _ = nn.scan(
+                    lambda mdl, c, _: (mdl(c, None, train=train), None),
+                    variable_axes={"params": 0},
+                    split_rngs={"params": True, "dropout": True},
+                    length=cfg.n_layer,
+                    metadata_params={nn.PARTITION_NAME: "layers"},
+                )(
+                    DeepSpeedTransformerLayer(
+                        config=cfg.layer_config(), causal=True,
+                        use_flash=cfg.use_flash, mesh=cfg.mesh, name="h",
+                    ),
+                    x,
+                    None,
+                )
         x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, name="ln_f")(x)
         return (x, wte) if moe_aux is None else (x, wte, moe_aux)
 
@@ -346,17 +349,18 @@ class GPT2LMHeadModel(nn.Module):
         if labels is None:
             return x @ wte.T  # tied lm head
         # next-token prediction: logits[:, :-1] vs labels[:, 1:]
-        if self.config.ce_block_rows > 0:
-            from ..ops.cross_entropy import blocked_lm_head_loss
+        with jax.named_scope("head_loss"):
+            if self.config.ce_block_rows > 0:
+                from ..ops.cross_entropy import blocked_lm_head_loss
 
-            lm_loss = blocked_lm_head_loss(
-                x[:, :-1], wte, labels[:, 1:],
-                block_rows=self.config.ce_block_rows,
-            )
-        else:
-            lm_loss = cross_entropy_ignore_index(
-                x[:, :-1] @ wte.T, labels[:, 1:]
-            )
+                lm_loss = blocked_lm_head_loss(
+                    x[:, :-1], wte, labels[:, 1:],
+                    block_rows=self.config.ce_block_rows,
+                )
+            else:
+                lm_loss = cross_entropy_ignore_index(
+                    x[:, :-1] @ wte.T, labels[:, 1:]
+                )
         if moe_aux is None:
             return lm_loss
         return lm_loss + moe_aux, lm_loss, moe_aux

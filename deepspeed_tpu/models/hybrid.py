@@ -354,12 +354,15 @@ class HybridModel(nn.Module):
 
         def layer(kind):
             def apply(p, x):
-                out, counters = mixers[kind](p, rms_norm(
-                    x, p["norm"], cfg.norm_eps, cfg.norm_zero_centered))
-                if "post_norm" in p:
-                    out = rms_norm(out, p["post_norm"], cfg.norm_eps,
-                                   cfg.norm_zero_centered)
-                return x + out.astype(x.dtype), counters
+                with jax.named_scope("stack_norms"):
+                    normed = rms_norm(
+                        x, p["norm"], cfg.norm_eps, cfg.norm_zero_centered)
+                out, counters = mixers[kind](p, normed)
+                with jax.named_scope("stack_norms"):
+                    if "post_norm" in p:
+                        out = rms_norm(out, p["post_norm"], cfg.norm_eps,
+                                       cfg.norm_zero_centered)
+                    return x + out.astype(x.dtype), counters
 
             return remat(apply) if repetitions == 1 else apply
 
@@ -381,16 +384,19 @@ class HybridModel(nn.Module):
             if repetitions == 1:
                 x, counters = one_period(x, params)
             else:
-                x, counters = jax.lax.scan(
-                    remat(one_period), x, jax.tree_util.tree_map(
-                        lambda v: v.reshape(
-                            (repetitions, v.shape[0] // repetitions)
-                            + v.shape[1:]), params))
+                with jax.named_scope("stack_scan"):
+                    x, counters = jax.lax.scan(
+                        remat(one_period), x, jax.tree_util.tree_map(
+                            lambda v: v.reshape(
+                                (repetitions, v.shape[0] // repetitions)
+                                + v.shape[1:]), params))
                 counters = merge_counters(counters, axis=0)
-            return rms_norm(
-                x, norm_f, cfg.norm_eps, cfg.norm_zero_centered), counters
+            with jax.named_scope("stack_norms"):
+                return rms_norm(
+                    x, norm_f, cfg.norm_eps, cfg.norm_zero_centered), counters
 
-        x = embed[input_ids]
+        with jax.named_scope("embed"):
+            x = embed[input_ids]
         if cfg.passes == 1:
             x, counters = one_pass(x)
             return x, head, counters, None
@@ -402,8 +408,9 @@ class HybridModel(nn.Module):
                 x, counters = one_pass(x)
             return x, (x, counters)
 
-        _, (states, counters) = jax.lax.scan(
-            loop_pass, x, None, length=cfg.passes)
+        with jax.named_scope("stack_scan"):
+            _, (states, counters) = jax.lax.scan(
+                loop_pass, x, None, length=cfg.passes)
         return states, head, merge_counters(counters, axis=0), gate
 
 
@@ -427,8 +434,9 @@ class HybridCausalLM(nn.Module):
                 entropy_weight=cfg.exit_entropy_weight,
                 block_rows=cfg.ce_block_rows)
             return loss, {**counters, **loop}
-        loss = blocked_lm_head_loss(
-            x[:, :-1], head, labels[:, 1:], block_rows=cfg.ce_block_rows)
+        with jax.named_scope("head_loss"):
+            loss = blocked_lm_head_loss(
+                x[:, :-1], head, labels[:, 1:], block_rows=cfg.ce_block_rows)
         return (loss, counters) if counters else loss
 
 

@@ -518,103 +518,108 @@ def transformer_block_apply(
     def block(x):
         b, s, _ = x.shape
         # ---- attention sublayer -----------------------------------
-        residual = x
-        attn_in = (
-            layer_norm(x, p["attn_nw"], p["attn_nb"])
-            if cfg.pre_layer_norm else x
-        )
-        product = attn_in @ p["attn_qkvw"]
-        biased = product + p["attn_qkvb"]
-        qkv = apply_lora(cfg, p, lora, "attn_qkvw", attn_in, biased)
-        # [B,S,3H] -> 3 x [B,heads,S,hd]  (the reference's
-        # bias_add_transform_0213, transform_kernels.cu:149): only for the
-        # callers that need heads apart (sequence parallelism, the KV
-        # cache's prefill); the dispatcher below takes ``qkv`` whole
-        def split_heads():
-            return tuple(
-                t.reshape(b, s, heads, head_dim).transpose(0, 2, 1, 3)
-                for t in jnp.split(qkv, 3, axis=-1)
+        with jax.named_scope("dense_attn"):
+            residual = x
+            attn_in = (
+                layer_norm(x, p["attn_nw"], p["attn_nb"])
+                if cfg.pre_layer_norm else x
             )
-
-        from ..config import constants as C
-
-        seq_parallel = (
-            mesh is not None
-            and dict(mesh.shape).get(C.SEQUENCE_AXIS, 1) > 1
-        )
-        if seq_parallel:
-            from ..parallel.sequence import sequence_parallel_attention
-
-            if return_kv:
-                raise ValueError(
-                    "return_kv (KV-cache prefill) does not compose with "
-                    "sequence-parallel attention; decode with a mesh whose "
-                    "sequence axis is 1"
+            product = attn_in @ p["attn_qkvw"]
+            biased = product + p["attn_qkvb"]
+            qkv = apply_lora(cfg, p, lora, "attn_qkvw", attn_in, biased)
+            # [B,S,3H] -> 3 x [B,heads,S,hd]  (the reference's
+            # bias_add_transform_0213, transform_kernels.cu:149): only for the
+            # callers that need heads apart (sequence parallelism, the KV
+            # cache's prefill); the dispatcher below takes ``qkv`` whole
+            def split_heads():
+                return tuple(
+                    t.reshape(b, s, heads, head_dim).transpose(0, 2, 1, 3)
+                    for t in jnp.split(qkv, 3, axis=-1)
                 )
-            kv_valid = additive_mask_to_kv_valid(attention_mask)
-            if attention_mask is not None and kv_valid is None:
-                raise ValueError(
-                    "sequence-parallel attention supports padding-style "
-                    "masks only (broadcast over the query dim)"
+
+            from ..config import constants as C
+
+            seq_parallel = (
+                mesh is not None
+                and dict(mesh.shape).get(C.SEQUENCE_AXIS, 1) > 1
+            )
+            if seq_parallel:
+                from ..parallel.sequence import sequence_parallel_attention
+
+                if return_kv:
+                    raise ValueError(
+                        "return_kv (KV-cache prefill) does not compose "
+                        "with sequence-parallel attention; decode with a "
+                        "mesh whose sequence axis is 1"
+                    )
+                kv_valid = additive_mask_to_kv_valid(attention_mask)
+                if attention_mask is not None and kv_valid is None:
+                    raise ValueError(
+                        "sequence-parallel attention supports padding-style "
+                        "masks only (broadcast over the query dim)"
+                    )
+                ctx = sequence_parallel_attention(
+                    *split_heads(),
+                    mesh, kv_valid, impl=seq_parallel_impl,
+                    use_flash=use_flash, causal=causal,
+                    dropout_rate=cfg.attn_dropout_ratio if train else 0.0,
+                    dropout_rng=attn_rng,
                 )
-            ctx = sequence_parallel_attention(
-                *split_heads(),
-                mesh, kv_valid, impl=seq_parallel_impl,
-                use_flash=use_flash, causal=causal,
-                dropout_rate=cfg.attn_dropout_ratio if train else 0.0,
-                dropout_rng=attn_rng,
+                # transform4d_0213
+                ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, H)
+            else:
+                # the projection's result goes in whole and the context comes
+                # back [B,S,H]: where the flash kernels run (one device, or per
+                # shard of a dp mesh via shard_map) they read the heads out of
+                # ``qkv`` themselves; else the dispatcher splits as above
+                # Without an adapter on this projection the BARE product goes
+                # in with the bias beside it: kernels that read it as it lies
+                # add the bias as they load, so what the projection writes is
+                # what a remat policy saves and what backward hands them.
+                bare = qkv is biased
+                ctx = attention_packed(
+                    product if bare else qkv, heads,
+                    bias=p["attn_qkvb"] if bare else None,
+                    mask=attention_mask, causal=causal,
+                    dropout_rate=cfg.attn_dropout_ratio if train else 0.0,
+                    dropout_rng=attn_rng, use_flash=use_flash,
+                    mesh=mesh,
+                )
+            attn_out = apply_lora(
+                cfg, p, lora, "attn_ow", ctx, ctx @ p["attn_ow"] + p["attn_ob"]
             )
-            ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, H)  # transform4d_0213
-        else:
-            # the projection's result goes in whole and the context comes
-            # back [B,S,H]: where the flash kernels run (one device, or per
-            # shard of a dp mesh via shard_map) they read the heads out of
-            # ``qkv`` themselves; else the dispatcher splits as above
-            # Without an adapter on this projection the BARE product goes
-            # in with the bias beside it: kernels that read it as it lies
-            # add the bias as they load, so what the projection writes is
-            # what a remat policy saves and what backward hands them.
-            bare = qkv is biased
-            ctx = attention_packed(
-                product if bare else qkv, heads,
-                bias=p["attn_qkvb"] if bare else None,
-                mask=attention_mask, causal=causal,
-                dropout_rate=cfg.attn_dropout_ratio if train else 0.0,
-                dropout_rng=attn_rng, use_flash=use_flash,
-                mesh=mesh,
-            )
-        attn_out = apply_lora(
-            cfg, p, lora, "attn_ow", ctx, ctx @ p["attn_ow"] + p["attn_ob"]
-        )
-        attn_out = hid_dropout(attn_out, h1_rng)
-        x = residual + attn_out
-        if not cfg.pre_layer_norm:
-            x = layer_norm(x, p["attn_nw"], p["attn_nb"])
+            attn_out = hid_dropout(attn_out, h1_rng)
+            x = residual + attn_out
+            if not cfg.pre_layer_norm:
+                x = layer_norm(x, p["attn_nw"], p["attn_nb"])
 
         # ---- feed-forward sublayer --------------------------------
-        residual = x
-        ff_in = (
-            layer_norm(x, p["norm_w"], p["norm_b"])
-            if cfg.pre_layer_norm else x
-        )
-        ffn_aux = None
-        if ffn_fn is not None:
-            h = ffn_fn(ff_in)
-            if isinstance(h, tuple):
-                h, ffn_aux = h
-        else:
-            h = apply_lora(
-                cfg, p, lora, "inter_w", ff_in,
-                ff_in @ p["inter_w"] + p["inter_b"],
+        with jax.named_scope("dense_ffn"):
+            residual = x
+            ff_in = (
+                layer_norm(x, p["norm_w"], p["norm_b"])
+                if cfg.pre_layer_norm else x
             )
-            h = nn.gelu(h, approximate=True)  # tanh-approx gelu, gelu_kernels.cu:38
-            h = apply_lora(
-                cfg, p, lora, "output_w", h, h @ p["output_w"] + p["output_b"]
-            )
-        h = hid_dropout(h, h2_rng)
-        x = residual + h
-        if not cfg.pre_layer_norm:
-            x = layer_norm(x, p["norm_w"], p["norm_b"])
+            ffn_aux = None
+            if ffn_fn is not None:
+                h = ffn_fn(ff_in)
+                if isinstance(h, tuple):
+                    h, ffn_aux = h
+            else:
+                h = apply_lora(
+                    cfg, p, lora, "inter_w", ff_in,
+                    ff_in @ p["inter_w"] + p["inter_b"],
+                )
+                # tanh-approx gelu, gelu_kernels.cu:38
+                h = nn.gelu(h, approximate=True)
+                h = apply_lora(
+                    cfg, p, lora, "output_w", h,
+                    h @ p["output_w"] + p["output_b"],
+                )
+            h = hid_dropout(h, h2_rng)
+            x = residual + h
+            if not cfg.pre_layer_norm:
+                x = layer_norm(x, p["norm_w"], p["norm_b"])
         if return_kv:
             if ffn_aux is not None:
                 raise ValueError(

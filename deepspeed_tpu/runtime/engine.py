@@ -1470,25 +1470,26 @@ class DeepSpeedEngine:
                     # match the accum>1 scan's [accum]-stacked aux layout
                     aux = jax.tree_util.tree_map(lambda a: a[None], aux)
                 else:
-                    zeros = jax.tree_util.tree_map(
-                        lambda p, s: jax.lax.with_sharding_constraint(
-                            jnp.zeros(p.shape, accum_dtype), s
-                        ),
-                        params,
-                        grad_shardings,
-                    )
+                    with jax.named_scope("grad_accum"):
+                        zeros = jax.tree_util.tree_map(
+                            lambda p, s: jax.lax.with_sharding_constraint(
+                                jnp.zeros(p.shape, accum_dtype), s
+                            ),
+                            params,
+                            grad_shardings,
+                        )
 
                     def body(gbuf, xs):
                         b, k = xs
                         loss, aux, g = fwd_bwd(params, b, k, loss_scale)
-                        gbuf = jax.tree_util.tree_map(
-                            lambda a, gg, s: jax.lax.with_sharding_constraint(
-                                a + gg, s
-                            ),
-                            gbuf,
-                            g,
-                            grad_shardings,
-                        )
+                        with jax.named_scope("grad_accum"):
+                            gbuf = jax.tree_util.tree_map(
+                                lambda a, gg, s:
+                                jax.lax.with_sharding_constraint(a + gg, s),
+                                gbuf,
+                                g,
+                                grad_shardings,
+                            )
                         return gbuf, (loss.astype(jnp.float32), aux)
 
                     grads, (losses, aux) = jax.lax.scan(

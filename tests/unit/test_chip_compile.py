@@ -315,6 +315,33 @@ def test_layer_body_falls_back_to_split_heads_at_25_heads():
     assert _pallas_kernels(text) == FUSED
 
 
+def _kernel_paths(text):
+    """{kernel: the ``op_name`` of its Mosaic call} of a compiled program."""
+    import re
+
+    out = {}
+    for line in text.splitlines():
+        if "tpu_custom_call" in line:
+            kernel = re.match(r"\s*(?:ROOT )?%([a-z_]+?)[.\d]* = ", line).group(1)
+            out[kernel] = re.search(r'op_name="([^"]*)"', line).group(1)
+    return out
+
+
+@pytest.mark.parametrize("model", sorted(HEADS))
+def test_dense_block_kernels_lower_under_dense_attn(model):
+    """``dense_attn_ms.train`` holds the flash kernels of the dense block,
+    packed layout (large) or split (xl) alike: the forward under the
+    forward's path, the fused backward under autodiff's, both inside the
+    scope and under their own names, which ``flash_ms.train`` sums."""
+    paths = _kernel_paths(_layer_body_text(model))
+    assert sorted(paths) == FUSED
+    assert "/dense_attn/" in paths["flash_fwd"]
+    assert "transpose(" not in paths["flash_fwd"]
+    assert "/dense_attn/" in paths["flash_bwd_dkv"]
+    assert "transpose(" in paths["flash_bwd_dkv"]
+    assert "/dense_ffn/" not in "".join(paths.values())
+
+
 # ---------------------------------------------------------------------------
 # the hybrid stack's mixers at the widths of its benchmark cell
 # (models/hybrid.py; micro 2 x seq 8192, hidden 4096, bf16)
@@ -359,6 +386,8 @@ def test_hybrid_mixer_compiles_at_the_cells_shapes(kind):
     # only attention brings Pallas kernels: the forward and the fused
     # backward, under names that flash_ms.train sums
     assert _pallas_kernels(text) == (FUSED if kind == "attn" else [])
+    assert all("/attn_mixer/" in path and "/stack_norms/" not in path
+               for path in _kernel_paths(text).values())
     for scope in {"mamba": ("mamba_mixer", "mamba_ssd"),
                   "moe": ("moe_route", "moe_experts", "moe_shared"),
                   "attn": ("attn_mixer",)}[kind]:
@@ -417,6 +446,8 @@ def test_gated_hybrid_sublayer_compiles_at_the_cells_shapes(kind):
     assert len(kernels) == {"gdn": 2, "gattn": 2, "gmoe": 0}[kind], kernels
     if kind == "gattn":
         assert _pallas_kernels(text) == FUSED
+        assert all("/attn_mixer/" in path
+                   for path in _kernel_paths(text).values())
     for scope in {"gdn": ("gdn_mixer", "gdn_delta_rule"),
                   "gattn": ("attn_mixer",),
                   "gmoe": ("moe_route", "moe_experts", "moe_shared")}[kind]:
@@ -473,7 +504,8 @@ def test_looped_stack_compiles_at_the_cells_shapes():
     assert all("/loop_pass/" in l and "/attn_mixer/" in l for l in kernels)
     # packed: the kernels read [B, S, 3 * H * D] and no [B, H, S, D] exists
     assert "[1,8192,6144]" in text and "[1,16,8192,128]" not in text
-    for scope in ("swiglu_ffn", "loop_head_loss", "exit_gate"):
+    for scope in ("swiglu_ffn", "loop_head_loss", "exit_gate", "stack_norms",
+                  "embed"):
         assert f"/{scope}/" in text, scope
 
 

@@ -7,9 +7,13 @@ by its own time a run of the jitted program.
 Reads the ``.xplane.pb`` that the traced run left under ``.bench_trace/<cell>``
 with the benchmark's own reduction (``benchmark/trace.py``: own time, so a
 ``while`` does not count its body twice) and writes one row an operation
-name: calls and milliseconds a run, the operation's HLO text (its result
-shape and layout) and its scope path. ``fwd_bwd_ms.train`` is the sum of
-these rows; ``breakdown`` prints only the first ten.
+name: calls and milliseconds a run, the pass (forward, recompute, backward),
+the opcode and the profiler's category, the operation's HLO text (its result
+shape and layout) and its scope path. The rows are
+``benchmark/readers/unscoped_time.py:op_rows``'s, which the ``unscoped_ops``
+note of a traced run lists the first ten of for the operations under no
+layer's scope: the tool and the metric cannot disagree. ``fwd_bwd_ms.train``
+is the sum of these rows; ``breakdown`` prints only the first ten.
 """
 
 import glob
@@ -21,29 +25,19 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 from benchmark import trace  # noqa: E402
+from benchmark.readers import pass_time, unscoped_time  # noqa: E402
 
 
 def rows(path, scope, module):
-    t = trace.Trace(None, path=path)
-    runs = t.runs(module)
-    if not runs:
+    ctx = {"trace": trace.Trace(None, path=path)}
+    made = pass_time.table(ctx, module)
+    if made is None:
         raise SystemExit(f"no whole run of jit_{module} in the traced window")
-    tag = f"/{scope}/"
-    by_name = {}
-    for e, own in trace.self_times(t._inside(runs, t.devices[0].ops)):
-        scope_path = str(e.meta.get("tf_op", ""))
-        if tag not in scope_path:
-            continue
-        row = by_name.setdefault(e.name, {
-            "name": e.name, "calls": 0, "ms": 0.0,
-            "hlo": e.long_name[:400], "scope": scope_path[-200:]})
-        row["calls"] += 1
-        row["ms"] += 1e3 * trace.PS * own
-    out = sorted(by_name.values(), key=lambda r: -r["ms"])
+    out = unscoped_time.op_rows(
+        made["own"], made["runs"], lambda p: f"/{scope}/" in p)
     for row in out:
-        row["calls"] /= len(runs)
-        row["ms"] /= len(runs)
-    return {"runs": len(runs), "scope": scope, "module": module,
+        row["hlo"], row["scope"] = row["hlo"][:400], row.pop("path")[-200:]
+    return {"runs": made["runs"], "scope": scope, "module": module,
             "total_ms": sum(r["ms"] for r in out), "ops": out}
 
 
