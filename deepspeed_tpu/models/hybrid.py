@@ -317,7 +317,8 @@ class HybridModel(nn.Module):
             "moe": lambda p, x: latent_moe_mixer(
                 p, x, top_k=cfg.top_k, scale=cfg.routed_scaling,
                 held=cfg.n_experts_held, offset=cfg.expert_offset,
-                tile=cfg.moe_tile, force_level=cfg.router_force_level),
+                tile=cfg.moe_tile, force_level=cfg.router_force_level,
+                mesh=cfg.mesh),
             "attn": lambda p, x: (gqa_attention_mixer(
                 p, x, heads=cfg.attn_heads, kv_heads=cfg.kv_heads,
                 head_dim=cfg.head_dim, mesh=cfg.mesh), {}),
@@ -334,7 +335,7 @@ class HybridModel(nn.Module):
             "gmoe": lambda p, x: gated_moe_mixer(
                 p, x, top_k=cfg.top_k, held=cfg.n_experts_held,
                 offset=cfg.expert_offset, tile=cfg.moe_tile,
-                force_level=cfg.router_force_level),
+                force_level=cfg.router_force_level, mesh=cfg.mesh),
             "rattn": lambda p, x: (rotary_attention_mixer(
                 p, x, heads=cfg.attn_heads, head_dim=cfg.head_dim,
                 rope_theta=cfg.rope_theta, mesh=cfg.mesh), {}),

@@ -327,6 +327,28 @@ def _kernel_paths(text):
     return out
 
 
+def _moe_kernels(text):
+    """(kernel, pass) of every Mosaic call of a compiled expert layer,
+    sorted; each under ``/moe_experts/``, whose time the layer's metrics
+    read, and under no name that ``flash_ms.train`` or ``gdn_scan_ms.train``
+    sum."""
+    import re
+
+    found = []
+    for line in text.splitlines():
+        if "tpu_custom_call" in line:
+            kernel = re.match(
+                r"\s*(?:ROOT )?%([a-z_]+?)[.\d]* = ", line).group(1)
+            path = re.search(r'op_name="([^"]*)"', line).group(1)
+            assert kernel in ("moe_ffn_fwd", "moe_ffn_bwd"), kernel
+            assert "/moe_experts/" in path and path.endswith(
+                f"/{kernel}/pallas_call"), path
+            found.append((kernel, "recompute" if "rematted_computation" in path
+                          else "backward" if "transpose(" in path
+                          else "forward"))
+    return sorted(found)
+
+
 @pytest.mark.parametrize("model", sorted(HEADS))
 def test_dense_block_kernels_lower_under_dense_attn(model):
     """``dense_attn_ms.train`` holds the flash kernels of the dense block,
@@ -351,8 +373,9 @@ def test_hybrid_mixer_compiles_at_the_cells_shapes(kind):
     """Forward and backward of one layer of each kind under the cell's remat
     policy: the chunked SSD scan (16 heads x 64, state 128, chunk 128), the
     no-drop expert layer (16 of 512 experts held, top-22, latent 1024,
-    tiles of 384: a loop of dynamic length with gathers and scatter-adds
-    inside), grouped-query attention (4 query heads on 1 kv head, width 128,
+    tiles of 384: the kernels ``moe_ffn_fwd`` and ``moe_ffn_bwd``, an
+    expert's two [1024, 2688] matrices whole in VMEM and, in the backward,
+    their float32 gradients too), grouped-query attention (4 query heads on 1 kv head, width 128,
     8,192 positions: the flash kernels' multi-block path)."""
     from deepspeed_tpu.models.hybrid import HybridLMConfig, HybridModel
 
@@ -383,11 +406,19 @@ def test_hybrid_mixer_compiles_at_the_cells_shapes(kind):
         device.on_tpu, jax.device_count = real
     text = compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 6 * 2 ** 30
-    # only attention brings Pallas kernels: the forward and the fused
-    # backward, under names that flash_ms.train sums
-    assert _pallas_kernels(text) == (FUSED if kind == "attn" else [])
-    assert all("/attn_mixer/" in path and "/stack_norms/" not in path
-               for path in _kernel_paths(text).values())
+    if kind == "moe":
+        # the forward and the backward, under the scope that
+        # moe_experts_ms.train and its roofline share read; remat runs no
+        # kernel again: the routed sum that ``up``'s product reads is kept
+        # with the plan (``moe_plan``), the backward's residuals are inputs
+        assert _moe_kernels(text) == [
+            ("moe_ffn_bwd", "backward"), ("moe_ffn_fwd", "forward")]
+    else:
+        # attention's forward and fused backward, under names that
+        # flash_ms.train sums; the scan brings no kernel
+        assert _pallas_kernels(text) == (FUSED if kind == "attn" else [])
+        assert all("/attn_mixer/" in path and "/stack_norms/" not in path
+                   for path in _kernel_paths(text).values())
     for scope in {"mamba": ("mamba_mixer", "mamba_ssd"),
                   "moe": ("moe_route", "moe_experts", "moe_shared"),
                   "attn": ("attn_mixer",)}[kind]:
@@ -409,7 +440,7 @@ def test_gated_hybrid_sublayer_compiles_at_the_cells_shapes(kind):
     kernels' split layout on a 16 x 16 grid of blocks, three 1024-row blocks
     of 256 lanes and their float32 scratches in VMEM), the gated experts (32
     of 512 held, top-10, three matrices of 2048 x 512 an expert, tiles of
-    352)."""
+    352: ``moe_ffn_fwd`` and ``moe_ffn_bwd``)."""
     from deepspeed_tpu.models.hybrid import HybridLMConfig, HybridModel
 
     cfg = HybridLMConfig(
@@ -443,7 +474,12 @@ def test_gated_hybrid_sublayer_compiles_at_the_cells_shapes(kind):
     text = compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 6 * 2 ** 30
     kernels = [l for l in text.splitlines() if "tpu_custom_call" in l]
-    assert len(kernels) == {"gdn": 2, "gattn": 2, "gmoe": 0}[kind], kernels
+    assert len(kernels) == {"gdn": 2, "gattn": 2, "gmoe": 2}[kind], kernels
+    if kind == "gmoe":
+        # remat runs no forward kernel again: the rerun's sum feeds nothing
+        # that the backward reads (its residuals are the layer's inputs)
+        assert _moe_kernels(text) == [
+            ("moe_ffn_bwd", "backward"), ("moe_ffn_fwd", "forward")]
     if kind == "gattn":
         assert _pallas_kernels(text) == FUSED
         assert all("/attn_mixer/" in path

@@ -41,10 +41,12 @@ FAMILIES = {
     "bert": ("bert-large.pretrain-seq128", {
         "dense_attn": ALL, "dense_ffn": ALL, "embed": ONCE,
         "head_loss": ONCE, "stack_scan": ALL, "grad_accum": SUM}),
+    # the latent experts' routed sum is kept with the plan (PR 37): remat
+    # runs their kernels no second time
     "nemotron": ("nemotron3-super-120b-a12b.train-seq8192", {
         "stack_norms": ALL, "embed": ONCE, "head_loss": ALL,
         "mamba_mixer": ALL, "attn_mixer": ALL, "moe_route": ALL,
-        "moe_experts": ALL, "moe_shared": ALL, "grad_accum": SUM}),
+        "moe_experts": ONCE, "moe_shared": ALL, "grad_accum": SUM}),
     # the gated experts keep their plan and outputs by name: no second run
     "qwen3-next": ("qwen3-next-80b-a3b.train-seq16384", {
         "stack_norms": ALL, "embed": ONCE, "head_loss": ALL,

@@ -1,6 +1,6 @@
 """The mixture of SiLU-gated experts (ops/moe.py:gated_moe_mixer: softmax
 top-k routing renormalised over the chosen, three-matrix experts at the
-model's own width through the grouped loop, a shared expert behind a scalar
+model's own width through the grouped kernels, a shared expert behind a scalar
 sigmoid gate) against a dense loop over all experts
 (benchmark/reference/qwen3_next.py:experts), at toy size on the CPU: values
 and every leaf's gradient, a skewed router, a token that finds no held
@@ -151,18 +151,141 @@ def test_forced_level_selection_ignores_the_weights_and_matches_reference():
 
 
 def test_expert_forms_backward_matches_autodiff():
-    """The hand-written backward of each expert form of the grouped loop."""
+    """The hand-written backward of each form's hidden activation, which
+    the kernels apply to a block of a tile's rows."""
     rng = np.random.default_rng(1)
-    x, dy = normal(rng, 8, E), normal(rng, 8, E)
-    for form, mats in {
-            "relu2": (normal(rng, E, F), normal(rng, F, E)),
-            "swiglu": (normal(rng, E, F), normal(rng, E, F),
-                       normal(rng, F, E))}.items():
-        fwd, bwd = moe_ops.EXPERT_FORMS[form]
-        y, saved = fwd(x, mats)
-        dx, dmats = bwd(x, mats, saved, dy)
-        want = jax.grad(
-            lambda x, mats: jnp.sum(fwd(x, mats)[0] * dy), (0, 1))(x, mats)
-        for a, b in zip(jax.tree_util.tree_leaves((dx, dmats)),
-                        jax.tree_util.tree_leaves(want)):
-            np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4, err_msg=form)
+    a, b, dh = normal(rng, 8, F), normal(rng, 8, F), normal(rng, 8, F)
+    for form, pre in {"relu2": (a,), "swiglu": (a, b)}.items():
+        act, act_bwd = moe_ops.EXPERT_FORMS[form]
+        want = jax.grad(lambda *pre: jnp.sum(act(*pre) * dh),
+                        tuple(range(len(pre))))(*pre)
+        for got, ref_ in zip(act_bwd(*pre, dh), want):
+            np.testing.assert_allclose(got, ref_, rtol=1e-5, atol=1e-6,
+                                       err_msg=form)
+
+
+# ----------------------------------------------------------------------
+# the grouped products themselves (``grouped_expert_ffn``: the kernels
+# ``moe_ffn_fwd`` and ``moe_ffn_bwd`` in interpret mode, the chunks' gathers
+# and combines around them) against autodiff of a dense loop over the held
+# experts, under routers that fill one chunk, many, or none
+# ----------------------------------------------------------------------
+TOKENS, K, ROUTED, HELD, OFFSET = 48, 3, 12, 4, 2
+
+
+def dense_experts(form, u, mats, weights_t):
+    """sum_e weights_t[e] * expert_e(u), every expert over every token."""
+    act = moe_ops.EXPERT_FORMS[form][0]
+    out = 0.0
+    for e in range(weights_t.shape[0]):
+        *w_in, w_out = (m[e] for m in mats)
+        out = out + weights_t[e][:, None] * (act(*(u @ w for w in w_in)) @ w_out)
+    return out
+
+
+def routed_to(router, rng):
+    """[TOKENS, K] distinct experts a token under the named router."""
+    def pick(allowed, n=K):
+        return rng.permutation(np.asarray(allowed))[:n]
+
+    everyone = np.arange(ROUTED)
+    held = np.arange(OFFSET, OFFSET + HELD)
+    elsewhere = np.setdiff1d(everyone, held)
+    if router == "level":
+        rows = [pick(everyone) for _ in range(TOKENS)]
+    elif router == "one_expert_takes_every_token":
+        rows = [np.append(pick(np.setdiff1d(everyone, [OFFSET + 1]), K - 1),
+                          OFFSET + 1) for _ in range(TOKENS)]
+    elif router == "tokens_without_a_held_expert":
+        rows = [pick(elsewhere if t % 3 == 0 else everyone)
+                for t in range(TOKENS)]
+    elif router == "an_expert_without_a_row":
+        rows = [pick(np.setdiff1d(everyone, [OFFSET + 2]))
+                for _ in range(TOKENS)]
+    else:
+        assert router == "no_held_assignment"
+        rows = [pick(elsewhere) for _ in range(TOKENS)]
+    return np.stack(rows).astype(np.int32)
+
+
+@pytest.mark.parametrize("form", ["relu2", "swiglu"])
+@pytest.mark.parametrize("router,tile", [
+    ("level", 4), ("level", 8), ("level", 64),
+    ("one_expert_takes_every_token", 4),
+    ("tokens_without_a_held_expert", 8),
+    ("an_expert_without_a_row", 8),
+    ("no_held_assignment", 8)])
+def test_grouped_products_match_the_dense_loop(form, router, tile):
+    """Output, du, every matrix's gradient and the routing weights'
+    gradient; no assignment without a row; the tiles in use and the chunks
+    they take as the shapes say."""
+    rng = np.random.default_rng(len(router) + tile)
+    chosen = routed_to(router, rng)
+    weights = np.zeros((TOKENS, ROUTED), np.float32)
+    np.put_along_axis(weights, chosen, rng.uniform(
+        0.2, 1.0, chosen.shape).astype(np.float32), axis=1)
+    u, probe = normal(rng, TOKENS, E), normal(rng, TOKENS, E)
+    n_in = {"relu2": 1, "swiglu": 2}[form]
+    mats = tuple([0.3 * normal(rng, HELD, E, F) for _ in range(n_in)]
+                 + [0.3 * normal(rng, HELD, F, E)])
+
+    def ours(u, mats, weights):
+        weights_t, plan, chunk_tiles, counters = moe_ops._route_and_plan(
+            u, TOKENS, lambda _x, _level: (jnp.asarray(chosen), weights),
+            HELD, OFFSET, tile, False)
+        return moe_ops.grouped_expert_ffn(
+            u, mats, weights_t, plan, tile, form, chunk_tiles), counters
+
+    def theirs(u, mats, weights):
+        return dense_experts(form, u, mats, weights[:, OFFSET:OFFSET + HELD].T)
+
+    out, counters = ours(u, mats, jnp.asarray(weights))
+    np.testing.assert_allclose(
+        out, theirs(u, mats, jnp.asarray(weights)), rtol=2e-4, atol=2e-5)
+    local = chosen - OFFSET
+    sizes = np.bincount(local[(local >= 0) & (local < HELD)], minlength=HELD)
+    tiles = int(np.sum(-(-sizes // tile)))
+    chunk_tiles = HELD - (-TOKENS * K * HELD // (ROUTED * tile))
+    assert {k: int(v) for k, v in counters.items()} == {
+        "moe/local_assignments": int(sizes.sum()),
+        "moe/tokens_without_held_expert": int(np.sum(
+            ~np.any((local >= 0) & (local < HELD), axis=1))),
+        "moe/max_expert_load": int(sizes.max()), "moe/overflow": 0,
+        "moe/tiles": tiles, "moe/chunks": -(-tiles // chunk_tiles)}
+    assert {"one_expert_takes_every_token": int(counters["moe/chunks"]) > 1,
+            "no_held_assignment": tiles == 0,
+            "an_expert_without_a_row": sizes[2] == 0,
+            "tokens_without_a_held_expert":
+                int(counters["moe/tokens_without_held_expert"]) >= TOKENS // 3,
+            }.get(router, int(counters["moe/chunks"]) == 1)
+    got = jax.grad(lambda *a: jnp.sum(ours(*a)[0] * probe), (0, 1, 2))(
+        u, mats, jnp.asarray(weights))
+    want = jax.grad(lambda *a: jnp.sum(theirs(*a) * probe), (0, 1, 2))(
+        u, mats, jnp.asarray(weights))
+    # a weight that chose nothing is not an input of ours: no gradient there
+    want = (want[0], want[1], jnp.where(weights != 0, want[2], 0.0))
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(
+            a, b, rtol=1e-3, atol=2e-5 * (1 + float(jnp.max(jnp.abs(b)))),
+            err_msg=str(path))
+
+
+def test_the_chip_refuses_widths_that_fill_no_lane_block(monkeypatch):
+    """Off the chip any width goes (interpret mode); on it an expert's
+    matrices come in whole 128-lane blocks or not at all, and the refusal
+    names the shape: no second, silent path."""
+    from deepspeed_tpu.utils import device
+
+    rng = np.random.default_rng(3)
+    chosen = jnp.asarray(routed_to("level", rng))
+    weights = jnp.ones((TOKENS, ROUTED), jnp.float32)
+    mats = (normal(rng, HELD, E, F), normal(rng, HELD, F, E))
+    weights_t, plan, chunk_tiles, _ = moe_ops._route_and_plan(
+        normal(rng, TOKENS, E), TOKENS, lambda _x, _level: (chosen, weights),
+        HELD, OFFSET, 8, False)
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
+    with pytest.raises(ValueError, match=r"128-lane blocks, not \(32, 24\)"):
+        moe_ops.grouped_expert_ffn(
+            normal(rng, TOKENS, E), mats, weights_t, plan, 8, "relu2",
+            chunk_tiles)

@@ -219,6 +219,34 @@ def test_skewed_router_drops_no_token():
         out, ref.moe_mixer(theirs_p, xn, cfg, DOT), rtol=2e-4, atol=2e-6)
 
 
+def test_expert_layer_on_a_mesh_of_several_devices_computes_the_same():
+    """A kernel is not partitioned: on a mesh of more than one device the
+    grouped products run whole on each (``shard_map`` over replicated
+    operands, as ``ops/pallas.py:adam_leaf_update`` wraps its kernel),
+    values and gradients those of one device, the tokens sharded or not."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    flat = ref.init_params(ref_ops.seed_key(11), TOY)
+    ours_p, theirs_p = layer_of(flat, "moe")
+    xn = ref.rms_norm(normal(7, (2, 32, TOY["hidden_size"])),
+                      theirs_p["norm.g"], 1e-5)
+    kw = dict(top_k=TOY["num_experts_per_tok"],
+              scale=TOY["routed_scaling_factor"],
+              held=TOY["n_routed_experts"], offset=0, tile=8)
+    mesh = build_mesh(devices=jax.devices()[:2])
+
+    def summed(mesh):
+        return jax.jit(jax.value_and_grad(lambda p, x: jnp.sum(
+            moe_ops.latent_moe_mixer(p, x, mesh=mesh, **kw)[0] ** 2), (0, 1)))
+
+    want = summed(None)(ours_p, xn)
+    got = summed(mesh)(ours_p, jax.device_put(
+        xn, NamedSharding(mesh, PartitionSpec("data"))))
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7)
+
+
 def test_forced_level_selection_ignores_the_weights_and_matches_reference():
     """``router_force_level``: the same chosen sets whatever the router's
     weights are (so the held experts' load cannot drift or collapse), near

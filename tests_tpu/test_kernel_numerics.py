@@ -700,3 +700,70 @@ def test_gated_delta_rule_kernels_match_the_recurrence_at_the_cells_shape():
     for a, r, name in zip(gk, gr, "q k v g beta state".split()):
         rel = float(jnp.max(jnp.abs(f32(a) - f32(r))) / jnp.max(jnp.abs(f32(r))))
         assert rel < 5e-2, f"d{name} rel err {rel:.2e}"
+
+
+@pytest.mark.parametrize("form,held,top_k,latent,inter,tile", [
+    ("relu2", 16, 22, 1024, 2688, 384),     # the Nemotron cell's experts
+    ("swiglu", 32, 10, 2048, 512, 352)])    # the Qwen3-Next cell's
+def test_grouped_expert_kernels_match_the_float32_loop_at_the_cells_widths(
+        form, held, top_k, latent, inter, tile):
+    """``moe_ffn_fwd`` and ``moe_ffn_bwd`` compiled, an expert's matrices
+    whole in VMEM at the cells' widths and tile sizes, bf16 rows and
+    weights under the forced-level selection over 512 experts (8,192
+    tokens: one chunk) and under a router that sends every token to ONE
+    held expert too (more tiles than a chunk holds): output, du, every
+    matrix's gradient and the routing weights' gradient against a float32
+    loop over the held experts at the highest precision."""
+    from deepspeed_tpu.ops import moe
+
+    tokens, routed = 8192, 512
+    ks = jax.random.split(jax.random.PRNGKey(37), 8)
+    n_in = {"relu2": 1, "swiglu": 2}[form]
+    mats = tuple(
+        [0.03 * jax.random.normal(ks[i], (held, latent, inter))
+         for i in range(n_in)]
+        + [0.03 * jax.random.normal(ks[2], (held, inter, latent))])
+    u = jax.random.normal(ks[3], (tokens, latent))
+    probe = jax.random.normal(ks[4], (tokens, latent))
+    scores = jax.nn.sigmoid(jax.random.normal(ks[5], (tokens, routed)))
+    act = moe.EXPERT_FORMS[form][0]
+
+    def kernels(chosen, u, mats, weights):
+        weights_t, plan, chunk_tiles, counters = moe._route_and_plan(
+            u, tokens, lambda _x, _level: (chosen, weights), held, 0, tile,
+            False)
+        return moe.grouped_expert_ffn(
+            u.astype(jnp.bfloat16),
+            tuple(m.astype(jnp.bfloat16) for m in mats), weights_t, plan,
+            tile, form, chunk_tiles), counters
+
+    def loop(u, mats, weights):
+        dot = functools.partial(jnp.dot, precision="highest")
+        out = 0.0
+        for e in range(held):
+            *w_in, w_out = (m[e] for m in mats)
+            out = out + weights[:, e, None] * dot(
+                act(*(dot(u, w) for w in w_in)), w_out)
+        return out
+
+    for skew in (False, True):
+        selection = moe.level_selection_scores(jnp.arange(tokens), routed)
+        if skew:
+            selection = selection.at[:, 3].set(2.0 ** 25)
+        chosen, picked = moe._top_k_of(selection, scores, top_k)
+        weights = picked / jnp.sum(picked, axis=-1, keepdims=True)
+        out, counters = jax.jit(kernels)(chosen, u, mats, weights)
+        assert int(counters["moe/overflow"]) == 0
+        assert (int(counters["moe/chunks"]) > 1) == skew, counters
+        ref = jax.jit(loop)(u, mats, weights)
+        err = float(jnp.max(jnp.abs(out - ref)) / jnp.max(jnp.abs(ref)))
+        assert err < 2e-2, f"skew={skew}: max err {err:.2e} of the largest"
+        got = jax.jit(jax.grad(lambda *a: jnp.sum(
+            kernels(chosen, *a)[0] * probe), (0, 1, 2)))(u, mats, weights)
+        want = jax.jit(jax.grad(lambda *a: jnp.sum(
+            loop(*a) * probe), (0, 1, 2)))(u, mats, weights)
+        want = (want[0], want[1], jnp.where(weights != 0, want[2], 0.0))
+        for (path, a), r in zip(jax.tree_util.tree_leaves_with_path(got),
+                                jax.tree_util.tree_leaves(want)):
+            rel = float(jnp.max(jnp.abs(a - r)) / jnp.max(jnp.abs(r)))
+            assert rel < 5e-2, f"skew={skew} {path}: rel err {rel:.2e}"
