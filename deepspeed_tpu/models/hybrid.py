@@ -9,11 +9,15 @@ softmax attention with per-head q/k norms and partial rotary
 experts at the model's own width behind softmax routing, with a gated shared
 expert (ops/moe.py:gated_moe_mixer); ``R`` causal multi-head attention with
 rotary on every lane (ops/transformer.py:rotary_attention_mixer), ``F`` a
-dense SiLU-gated FFN (ops/transformer.py:swiglu_ffn_mixer). A published
+dense SiLU-gated FFN (ops/transformer.py:swiglu_ffn_mixer); ``A``
+grouped-query attention with per-head q/k norms and rotary on every lane
+(ops/transformer.py:rotary_gqa_attention_mixer), ``S`` the experts of ``X``
+with no shared expert beside them. A published
 layer that is a token mixer THEN experts or an FFN is two letters
 (``DXDXDXGX`` is one period of three DeltaNet layers and one attention layer,
-each with its experts; ``RF`` is one decoder layer). No learned position
-embedding (the recurrent layers carry position; ``G`` and ``R`` rotate), a
+each with its experts; ``RF`` and ``AS`` are one decoder layer each). No
+learned position
+embedding (the recurrent layers carry position; ``G``, ``R`` and ``A`` rotate), a
 final RMS norm, an untied head, bias-free projections; ``norm_zero_centered``
 stores every norm's gain around 0 and applies ``1 + gain``; ``post_norm``
 gives ``R`` and ``F`` a second gain AFTER the mixer (``x + norm_b(mixer(
@@ -46,6 +50,18 @@ expert-parallel group holds ``n_experts_held`` experts starting at
 experts' part of the result and adds nothing for the absent chips (no
 exchange is built). Head counts are the heads held here.
 
+``objective="block_diffusion"`` trains a stack of ``A`` mixers (and any
+position-wise kinds) by block diffusion with an absorbing mask:
+``HybridCausalLM(noisy_ids, clean_ids, loss_weights)``, each ``[B, L]``. The
+stack runs on the row ``[noisy ; clean]`` of ``2 L`` positions, both halves
+with position ids ``0..L-1``, under the block-diffusion mask of
+``diffusion_block`` positions a block inside the flash kernels
+(ops/attention.py); the head reads the noisy half only and position ``i``'s
+logits are scored against ``clean_ids[i]`` (no shift): the loss is ``sum_i
+loss_weights[i] nll_i / (B L)``. The caller draws the noise: ``loss_weights``
+is ``1 / t`` of a position's block where the position was replaced by the
+mask id, else 0. It adds the ``diffusion/...`` counters.
+
 ``HybridCausalLM(input_ids, labels)`` returns ``(loss, counters)``: the
 engine trains on the loss, and the routing counters (``moe/...``, summed or
 maximised over the E layers) leave the compiled window beside it through
@@ -73,12 +89,16 @@ from ..ops.transformer import (
     resolve_remat_policy,
     rms_norm,
     rotary_attention_mixer,
+    rotary_gqa_attention_mixer,
     swiglu_ffn_mixer,
 )
 
 KINDS = {"M": "mamba", "E": "moe", "*": "attn",
          "D": "gdn", "G": "gattn", "X": "gmoe",
-         "R": "rattn", "F": "ffn"}
+         "R": "rattn", "F": "ffn", "A": "qattn", "S": "smoe"}
+# the token mixers that are causal by construction: not for block diffusion
+CAUSAL_ONLY = "M*DGR"
+OBJECTIVES = ("next_token", "block_diffusion")
 
 
 def period(pattern):
@@ -139,8 +159,9 @@ class HybridLMConfig:
     # X: gated experts at the model's width. Shares the held/routed counts,
     # top_k, router_force_level, moe_intermediate, moe_shared_intermediate
     # and moe_tile with E; has no latent, bias or scaling
-    # * and G: grouped-query attention. heads HELD here. R has attn_heads
-    # kv heads too and rotates all head_dim lanes
+    # *, G and A: grouped-query attention. heads HELD here. R has attn_heads
+    # kv heads too; R and A rotate all head_dim lanes. S shares X's fields
+    # and has no shared expert
     attn_heads: int = 2
     kv_heads: int = 1
     head_dim: int = 16
@@ -167,6 +188,10 @@ class HybridLMConfig:
     remat_policy: str = "nothing_saveable"
     # sequence positions per block of the head loss (ops/cross_entropy.py)
     ce_block_rows: int = 512
+    # "block_diffusion": rows are [noisy ; clean] under the block-diffusion
+    # mask of diffusion_block positions a block (a power of two)
+    objective: str = "next_token"
+    diffusion_block: int = 4
     mesh: object = dataclasses.field(default=None, hash=False, compare=False)
 
     def __post_init__(self):
@@ -188,6 +213,17 @@ class HybridLMConfig:
             raise ValueError("R rotates lane pairs: head_dim must be even")
         if self.passes < 1:
             raise ValueError("passes must be at least 1")
+        if self.objective not in OBJECTIVES:
+            raise ValueError(
+                f"objective {self.objective!r}: {', '.join(OBJECTIVES)} "
+                "are known")
+        if self.objective == "block_diffusion":
+            causal = sorted(set(self.pattern) & set(CAUSAL_ONLY))
+            if causal or self.passes > 1:
+                raise ValueError(
+                    "block diffusion needs token mixers that take its mask "
+                    f"(A) and one pass; the pattern has {causal}, passes "
+                    f"{self.passes}")
         if not (0 <= self.expert_offset
                 and self.expert_offset + self.n_experts_held
                 <= self.n_experts_routed):
@@ -255,6 +291,17 @@ class HybridLMConfig:
                 "wu": (e, self.ffn_intermediate),
                 "wd": (self.ffn_intermediate, e), **post,
             },
+            "qattn": {
+                "norm": (e,), "wq": (e, qd), "wk": (e, kvd), "wv": (e, kvd),
+                "q_norm": (self.head_dim,), "k_norm": (self.head_dim,),
+                "wo": (qd, e),
+            },
+            "smoe": {
+                "norm": (e,), "router": (e, self.n_experts_routed),
+                "wg": (self.n_experts_held, e, f),
+                "wu": (self.n_experts_held, e, f),
+                "wd": (self.n_experts_held, f, e),
+            },
         }
 
 
@@ -287,13 +334,19 @@ def _leaf_init(cfg, leaf):
 class HybridModel(nn.Module):
     """input_ids [B, S] -> (hidden [B, S, E] after the final norm, the
     head's table, counters, None); a looped stack gives every pass's hidden
-    [passes, B, S, E] and, last, its exit gate ``(gate_w, gate_b)``."""
+    [passes, B, S, E] and, last, its exit gate ``(gate_w, gate_b)``. Under
+    ``objective="block_diffusion"`` a row is ``[noisy ; clean]``: S = 2 L."""
 
     config: HybridLMConfig
 
     @nn.compact
     def __call__(self, input_ids):
         cfg = self.config
+        positions, diffusion_block = None, 0
+        if cfg.objective == "block_diffusion":
+            half = input_ids.shape[1] // 2
+            positions = jnp.arange(2 * half) % half
+            diffusion_block = cfg.diffusion_block
         init = nn.initializers.normal(stddev=cfg.initializer_range)
         embed = self.param("embed", init, (cfg.vocab_size, cfg.hidden_size))
         head = self.param("head", init, (cfg.vocab_size, cfg.hidden_size))
@@ -340,7 +393,14 @@ class HybridModel(nn.Module):
                 p, x, heads=cfg.attn_heads, head_dim=cfg.head_dim,
                 rope_theta=cfg.rope_theta, mesh=cfg.mesh), {}),
             "ffn": lambda p, x: (swiglu_ffn_mixer(p, x), {}),
+            "qattn": lambda p, x: (rotary_gqa_attention_mixer(
+                p, x, heads=cfg.attn_heads, kv_heads=cfg.kv_heads,
+                head_dim=cfg.head_dim, rope_theta=cfg.rope_theta,
+                eps=cfg.norm_eps, zero_centered=cfg.norm_zero_centered,
+                positions=positions, block_diffusion=diffusion_block,
+                mesh=cfg.mesh), {}),
         }
+        mixers["smoe"] = mixers["gmoe"]   # the same layer, fewer leaves
 
         unit, repetitions = period(cfg.pattern)
 
@@ -419,13 +479,26 @@ class HybridCausalLM(nn.Module):
     """``__call__(input_ids, labels) -> (loss, counters)``: next-token loss
     (the shift happens inside) through the blocked head loss, and the
     routing counters of this micro-step. ``labels=None`` gives logits. A
-    looped stack's loss is ``looped_loss``."""
+    looped stack's loss is ``looped_loss``. Under
+    ``objective="block_diffusion"`` the call is ``(noisy_ids, clean_ids,
+    loss_weights)`` and the loss ``block_diffusion_loss``."""
 
     config: HybridLMConfig
 
     @nn.compact
-    def __call__(self, input_ids, labels=None):
+    def __call__(self, input_ids, labels=None, loss_weights=None):
         cfg = self.config
+        if cfg.objective == "block_diffusion":
+            if labels is None or loss_weights is None:
+                raise ValueError(
+                    "block diffusion trains on (noisy_ids, clean_ids, "
+                    "loss_weights); generation by blocks is not built")
+            x, head, counters, _ = HybridModel(cfg, name="model")(
+                jnp.concatenate([input_ids, labels], axis=1))
+            loss, diffusion = block_diffusion_loss(
+                x[:, :labels.shape[1]], head, labels, loss_weights,
+                block_rows=cfg.ce_block_rows)
+            return loss, {**counters, **diffusion}
         x, head, counters, gate = HybridModel(cfg, name="model")(input_ids)
         if labels is None:
             return (x if gate is None else x[-1]) @ head.T
@@ -439,6 +512,26 @@ class HybridCausalLM(nn.Module):
             loss = blocked_lm_head_loss(
                 x[:, :-1], head, labels[:, 1:], block_rows=cfg.ce_block_rows)
         return (loss, counters) if counters else loss
+
+
+def block_diffusion_loss(noisy_states, head, clean_ids, loss_weights, *,
+                         block_rows):
+    """``sum_i loss_weights[i] nll_i / (B L)`` over the noisy half's normed
+    states [B, L, E], position i scored against ``clean_ids[i]`` (no shift),
+    every position in the denominator: ``weighted_lm_head_loss`` with one
+    pass of per-position weights. And the ``diffusion/...`` counters of this
+    micro-step."""
+    weights = loss_weights.astype(jnp.float32)
+    with jax.named_scope("head_loss"):
+        loss = weighted_lm_head_loss(
+            noisy_states[None], head, clean_ids, weights[None],
+            block_rows=block_rows, ignore_values=())
+    counters = {
+        "diffusion/positions": jnp.int32(clean_ids.size),
+        "diffusion/masked_positions": jnp.sum(weights > 0).astype(jnp.int32),
+        "diffusion/loss_weight_sum": jnp.sum(weights),
+    }
+    return loss, jax.lax.stop_gradient(counters)
 
 
 def looped_loss(states, head, labels, gate_w, gate_b, *, entropy_weight,

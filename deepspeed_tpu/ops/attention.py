@@ -234,17 +234,135 @@ def _query_range(k_first, sub_k, q_first, sub_q, nsq, diag_offset):
     return _clip(lo, 0, nsq), _clip(full, 0, nsq)
 
 
-def _visited_share(sq, sk, block_k, sub_q, sub_k, causal):
+# The block-diffusion mask (``block_diffusion=B``): a row of ``2 L``
+# positions is ``[noisy ; clean]``, both halves numbered ``0..L-1`` in blocks
+# of ``B``. A noisy query sees the noisy keys of its OWN block (both ways
+# inside it) and the clean keys of strictly EARLIER blocks; a clean query sees
+# the clean keys up to the end of its own block and no noisy key. Neither a
+# diagonal nor a property of the key alone: a third, structural form beside
+# ``causal`` and the key-validity column. Blocks and sub-tiles divide ``L``,
+# so each lies in one half and the case is a scalar of the grid position;
+# the bounds below skip what the mask empties and only the sub-tiles that
+# the staircase or the block diagonal crosses build it from ``iota``. ``L^2 +
+# L B`` of the ``4 L^2`` pairs are allowed: a quarter of the square, half of
+# what a causal walk over ``2 L`` visits.
+
+
+def _sel(cond, a, b):
+    """``a if cond else b`` for a condition known at trace time or not."""
+    if isinstance(cond, bool):
+        return a if cond else b
+    return jnp.where(cond, a, b)
+
+
+def _bd_key_range(q_first, sub_q, k_first, sub_k, nsk, half, block):
+    """``(lo, n_full, hi)`` under the block-diffusion mask: of the ``nsk``
+    key sub-tiles from key ``k_first`` (one half's), ``[lo, n_full)`` are
+    wholly allowed for the ``sub_q`` query rows from ``q_first`` (one
+    half's), ``[n_full, hi)`` are crossed, the rest are empty. Python ints
+    or traced int32 alike."""
+    q_clean, k_clean = q_first >= half, k_first >= half
+    q = q_first - _sel(q_clean, half, 0)
+    k = k_first - _sel(k_clean, half, 0)
+    first = q // block * block                 # the first row's block starts
+    last = (q + sub_q - 1) // block * block    # the last row's
+    # clean keys: a row sees those before its block (noisy row) or before
+    # its block's end (clean row)
+    ext = _sel(q_clean, block, 0)
+    full_c = (first + ext - k) // sub_k
+    hi_c = -((k - last - ext) // sub_k)
+    # noisy keys: a noisy row sees its own block, a clean row none
+    lo_n = _sel(q_clean, 0, (first - k) // sub_k)
+    hi_n = _sel(q_clean, 0, -((k - last - block) // sub_k))
+    lo = _clip(_sel(k_clean, 0, lo_n), 0, nsk)
+    n_full = _clip(_sel(k_clean, full_c, lo_n), 0, nsk)
+    return lo, n_full, _clip(_sel(k_clean, hi_c, hi_n), 0, nsk)
+
+
+def _bd_query_range(k_first, sub_k, q_first, sub_q, nsq, half, block):
+    """The same seen from ``sub_k`` keys from ``k_first``, over the ``nsq``
+    query sub-tiles of a Q block that starts at row ``q_first``: ``(lo,
+    full, hi)``, ``[lo, full)`` crossed, ``[full, hi)`` wholly allowed."""
+    q_clean, k_clean = q_first >= half, k_first >= half
+    q = q_first - _sel(q_clean, half, 0)
+    k = k_first - _sel(k_clean, half, 0)
+    # clean keys: row i sees key j iff j < i's block start + ext
+    ext = _sel(q_clean, block, 0)
+    some = ((k - ext) // block + 1) * block           # first row seeing key k
+    every = ((k + sub_k - 1 - ext) // block + 1) * block   # ... the last key
+    lo_c, full_c = (some - q) // sub_q, -((q - every) // sub_q)
+    # noisy keys: the noisy rows of the keys' own blocks, all crossed
+    lo_n = _sel(q_clean, 0, (k // block * block - q) // sub_q)
+    hi_n = _sel(
+        q_clean, 0,
+        -((q - (k + sub_k - 1) // block * block - block) // sub_q),
+    )
+    lo = _clip(_sel(k_clean, lo_c, lo_n), 0, nsq)
+    full = _clip(_sel(k_clean, full_c, hi_n), 0, nsq)
+    return lo, full, _clip(_sel(k_clean, nsq, hi_n), 0, nsq)
+
+
+def _bd_key_block(iq, ik, block_q, block_k, half, block):
+    """The key block that step ``(iq, ik)`` of a query-major grid holds
+    under the block-diffusion mask: ``ik`` where the step runs, else the
+    nearest block, in the walk's order, that a step of this Q block needs.
+    A skipped step then asks for the block its neighbour holds and the
+    pipeline copies nothing for it."""
+    nqh, nkh = half // block_q, half // block_k
+    q_clean = iq >= nqh
+    q = (iq - _sel(q_clean, nqh, 0)) * block_q
+    last = (q + block_q - 1) // block * block + _sel(q_clean, block, 0) - 1
+    own = _clip(ik, q // block_k, (q + block_q - 1) // block_k)
+    clean = nkh + _clip(ik - nkh, 0, _clip(last, 0, half) // block_k)
+    return _sel(ik < nkh, _sel(q_clean, nkh, own), clean)
+
+
+def _bd_query_block(ik, iq, block_q, block_k, half, block):
+    """The same for step ``(ik, iq)`` of a key-major grid: the Q block
+    (of q, dO, lse and delta) it holds."""
+    nqh, nkh = half // block_q, half // block_k
+    k = (ik - _sel(ik >= nkh, nkh, 0)) * block_k
+    own = _clip(iq, k // block_q, (k + block_k - 1) // block_q)
+    # a clean key: noisy rows from the block after its own, clean rows from
+    # its own block's
+    start = k // block * block
+    after = (start + block) // block_q
+    noisy = _sel(after < nqh, _clip(iq, after, nqh - 1), nqh + start // block_q)
+    clean = nqh + _clip(iq - nqh, start // block_q, nqh - 1)
+    return _sel(ik >= nkh, _sel(iq < nqh, noisy, clean), own)
+
+
+def block_diffusion_mask(seq, block):
+    """The mask as a dense additive ``[seq, seq]`` float32 array (0 allowed,
+    ``NEG_INF`` not): what the kernels never build. For the XLA path at
+    lengths under the kernels' and for tests."""
+    half = seq // 2
+    at = jnp.arange(seq)
+    clean, blk = at >= half, (at % half) // block
+    qc, kc, qb, kb = clean[:, None], clean[None, :], blk[:, None], blk[None, :]
+    allowed = jnp.where(
+        kc, jnp.where(qc, kb <= qb, kb < qb), ~qc & (kb == qb))
+    return jnp.where(allowed, 0.0, NEG_INF).astype(jnp.float32)
+
+
+def _visited_share(sq, sk, block_k, sub_q, sub_k, causal, block_diffusion=0):
     """Share of the ``sq x sk`` score square that lies in sub-tiles a
     kernel visits, from the bounds that set its loops."""
-    if not causal:
+    if not causal and not block_diffusion:
         return 1.0
-    visited = 0
+    visited, nsk = 0, block_k // sub_k
     for q_first in range(0, sq, sub_q):
         for k_first in range(0, sk, block_k):
-            visited += _key_range(
-                q_first, sub_q, k_first, sub_k, block_k // sub_k, sk - sq
-            )[1]
+            if block_diffusion:
+                lo, _, hi = _bd_key_range(
+                    q_first, sub_q, k_first, sub_k, nsk, sq // 2,
+                    block_diffusion,
+                )
+            else:
+                lo, hi = 0, _key_range(
+                    q_first, sub_q, k_first, sub_k, nsk, sk - sq
+                )[1]
+            visited += hi - lo
     return visited * sub_q * sub_k / (sq * sk)
 
 
@@ -258,7 +376,8 @@ PAIR_VMEM_BYTES = 16 * 2**20
 
 
 def backward_plan(
-    sq, sk, block_q, block_k, causal, lanes=128, itemsize=2, budget=None
+    sq, sk, block_q, block_k, causal, lanes=128, itemsize=2, budget=None,
+    block_diffusion=0,
 ):
     """Which backward a call gets, from its shape, its dtype and the VMEM
     budget alone: ``fused`` (one key-major kernel writes dq, dk and dv from
@@ -276,7 +395,9 @@ def backward_plan(
     return {
         "backward": "fused" if fused else "pair",
         "sub_q": sub_q, "sub_k": sub_k,
-        "visited_share": _visited_share(sq, sk, block_k, sub_q, sub_k, causal),
+        "visited_share": _visited_share(
+            sq, sk, block_k, sub_q, sub_k, causal, block_diffusion
+        ),
         "dq_vmem_bytes": dq_bytes if fused else 0,
         "reason": None if fused else (
             f"dq over {sq} rows of {lanes} lanes takes {dq_bytes} bytes of "
@@ -287,7 +408,7 @@ def backward_plan(
 
 def flash_tiling(
     sq, sk, block_q, block_k, causal, key_major=False, sub_q=None, sub_k=None,
-    **plan,
+    block_diffusion=0, **plan,
 ):
     """Outer blocks, sub-tiles and the share of the ``sq x sk`` score
     square whose sub-tiles a kernel visits (the forward and dq, or dkv
@@ -302,28 +423,35 @@ def flash_tiling(
     return {
         "block_q": block_q, "block_k": block_k, "sub_q": sub_q,
         "sub_k": sub_k,
-        "visited_share": _visited_share(sq, sk, block_k, sub_q, sub_k, causal),
-        "backward": backward_plan(sq, sk, block_q, block_k, causal, **plan),
+        "visited_share": _visited_share(
+            sq, sk, block_k, sub_q, sub_k, causal, block_diffusion
+        ),
+        "backward": backward_plan(
+            sq, sk, block_q, block_k, causal, block_diffusion=block_diffusion,
+            **plan,
+        ),
     }
 
 
 @functools.lru_cache(maxsize=None)
 def _log_tiling(
-    sq, sk, d, lanes, dtype, block_q, block_k, causal, use_mask, dropout
+    sq, sk, d, lanes, dtype, block_q, block_k, causal, use_mask, dropout,
+    block_diffusion=0,
 ):
     t = flash_tiling(
         sq, sk, block_q, block_k, causal, lanes=lanes,
-        itemsize=jnp.dtype(dtype).itemsize,
+        itemsize=jnp.dtype(dtype).itemsize, block_diffusion=block_diffusion,
     )
     b = t["backward"]
     logger.debug(
         "flash_tiling sq=%d sk=%d d=%d %s causal=%s mask=%s dropout=%s "
         "block=%dx%d sub=%dx%d visited_share=%.4f "
-        "backward=%s bwd_sub=%dx%d bwd_visited_share=%.4f dq_vmem_bytes=%d%s",
+        "backward=%s bwd_sub=%dx%d bwd_visited_share=%.4f dq_vmem_bytes=%d%s%s",
         sq, sk, d, dtype, causal, use_mask, dropout, block_q, block_k,
         t["sub_q"], t["sub_k"], t["visited_share"],
         b["backward"], b["sub_q"], b["sub_k"], b["visited_share"],
         b["dq_vmem_bytes"], f" reason={b['reason']!r}" if b["reason"] else "",
+        f" block_diffusion={block_diffusion}" if block_diffusion else "",
     )
 
 
@@ -355,12 +483,32 @@ def _keep_mask(seed_ref, bh, q_first, k_first, shape, gran, rate):
     return rows[0] if len(rows) == 1 else jnp.concatenate(rows, axis=0)
 
 
+def _block_diffusion_allowed(shape, q_first, k_first, half, block):
+    """Which scores of a ``[keys, queries]`` sub-tile the block-diffusion
+    mask allows, from ``iota``; the sub-tile's rows lie in one half and so
+    do its keys, so which case applies is two scalars. ``start`` is each
+    query's block start: a key is seen from ``start - below`` (its own
+    block's first noisy key; every clean key) up to ``start + above`` (its
+    block's end; a noisy query stops before its block among the clean
+    keys). A clean query never meets a noisy key here: no loop visits it."""
+    q_clean, k_clean = q_first >= half, k_first >= half
+    keys = jax.lax.broadcasted_iota(jnp.int32, shape, 0) + (
+        k_first - _sel(k_clean, half, 0))
+    rows = jax.lax.broadcasted_iota(jnp.int32, shape, 1) + (
+        q_first - _sel(q_clean, half, 0))
+    start = rows & -block   # a power of two
+    above = _sel(k_clean & ~q_clean, 0, block)
+    below = _sel(k_clean, half, 0)
+    return (keys < start + above) & (keys >= start - below)
+
+
 def _scores_t(
     k, q, valid, q_first, k_first, *, sm_scale, fold_scale, diagonal,
-    diag_offset,
+    diag_offset, block_diffusion=0, half=0,
 ):
     """One transposed score sub-tile ``s_t = k q^T`` ([keys, queries]) with
-    causal and key-validity masking. ``diagonal``: the causal diagonal
+    causal, block-diffusion and key-validity masking. ``diagonal``: the
+    causal diagonal (the block-diffusion mask's staircase or block diagonal)
     crosses this sub-tile; the ones wholly under it skip the
     iota/compare/select. ``valid``: the keys' validity column or None."""
     s_t = jax.lax.dot_general(
@@ -368,7 +516,13 @@ def _scores_t(
     )
     if not fold_scale:
         s_t = s_t * sm_scale
-    if diagonal:
+    if diagonal and block_diffusion:
+        s_t = jnp.where(
+            _block_diffusion_allowed(
+                s_t.shape, q_first, k_first, half, block_diffusion),
+            s_t, NEG_INF,
+        )
+    elif diagonal:
         keys = jax.lax.broadcasted_iota(jnp.int32, s_t.shape, 0) + k_first
         rows = jax.lax.broadcasted_iota(jnp.int32, s_t.shape, 1) + q_first
         s_t = jnp.where(keys <= rows + diag_offset, s_t, NEG_INF)
@@ -443,9 +597,10 @@ class _Tiles:
     def __init__(
         self, q_axis, *, sm_scale, causal, block_q, block_k, sub_q, sub_k,
         nq, nk, diag_offset, dropout_rate, use_mask, head_dim, heads_a_block,
-        use_bias,
+        use_bias, block_diffusion=0,
     ):
         self.group = pl.program_id(0)
+        self.block_diffusion, self.half = block_diffusion, nq * block_q // 2
         self.use_bias = use_bias
         self.iq = pl.program_id(q_axis) if nq > 1 else 0
         self.ik = pl.program_id(3 - q_axis) if nk > 1 else 0
@@ -464,18 +619,34 @@ class _Tiles:
         )
         self.scores = functools.partial(
             _scores_t, sm_scale=sm_scale, fold_scale=self.fold_scale,
-            diag_offset=diag_offset,
+            diag_offset=diag_offset, block_diffusion=block_diffusion,
+            half=self.half,
         )
-        self.exp = functools.partial(
-            _exp_t, guard=use_mask or diag_offset < 0
-        )
-        # whole blocks above the diagonal are skipped
+        self.guard = use_mask or diag_offset < 0
+        # whole blocks above the diagonal (that the mask empties) are skipped
         self.run = True
-        if causal:
+        if block_diffusion:
+            lo, _, hi = _bd_key_range(
+                self.iq * block_q, block_q, self.ik * block_k, block_k, 1,
+                self.half, block_diffusion,
+            )
+            self.run = hi > lo
+        elif causal:
             self.run = (
                 self.ik * block_k
                 <= self.iq * block_q + (block_q - 1) + diag_offset
             )
+
+    def exp(self, s_t, stat, diagonal=False):
+        """``_exp_t`` with this call's guard. Under the block-diffusion mask
+        a crossed sub-tile may hold no key of a row that has met none yet (a
+        noisy row's own block lies in ONE of the noisy key sub-tiles that its
+        stripe crosses): the forward, whose ``stat`` is the running maximum,
+        says which sub-tiles are crossed."""
+        return _exp_t(
+            s_t, stat,
+            self.guard or (bool(self.block_diffusion) and diagonal),
+        )
 
     def heads(self):
         """``(hh, lanes)`` of each head a program serves: its place in the
@@ -519,27 +690,37 @@ class _Tiles:
         """``step(c, carry, diagonal)`` over the key sub-tiles of this
         K block that query stripe ``r`` sees: the ones wholly under the
         diagonal, then the ones it crosses."""
-        n_full = hi = self.nsk
-        if self.causal:
+        lo, n_full, hi = 0, self.nsk, self.nsk
+        if self.block_diffusion:
+            lo, n_full, hi = _bd_key_range(
+                self.q_first(r), self.sub_q, self.k_first(0), self.sub_k,
+                self.nsk, self.half, self.block_diffusion,
+            )
+        elif self.causal:
             n_full, hi = _key_range(
                 self.q_first(r), self.sub_q, self.k_first(0), self.sub_k,
                 self.nsk, self.diag_offset,
             )
-        carry = _span(0, n_full, functools.partial(step, diagonal=False), carry)
+        carry = _span(lo, n_full, functools.partial(step, diagonal=False), carry)
         return _span(n_full, hi, functools.partial(step, diagonal=True), carry)
 
     def over_queries(self, c, step, carry):
         """``step(r, carry, diagonal)`` over the query sub-tiles of this
         Q block that see key sub-tile ``c``: the ones the diagonal crosses,
         then the ones wholly under it."""
-        lo = full = 0
-        if self.causal:
+        lo, full, hi = 0, 0, self.nsq
+        if self.block_diffusion:
+            lo, full, hi = _bd_query_range(
+                self.k_first(c), self.sub_k, self.q_first(0), self.sub_q,
+                self.nsq, self.half, self.block_diffusion,
+            )
+        elif self.causal:
             lo, full = _query_range(
                 self.k_first(c), self.sub_k, self.q_first(0), self.sub_q,
                 self.nsq, self.diag_offset,
             )
         carry = _span(lo, full, functools.partial(step, diagonal=True), carry)
-        return _span(full, self.nsq, functools.partial(step, diagonal=False), carry)
+        return _span(full, hi, functools.partial(step, diagonal=False), carry)
 
 
 def _fwd_kernel(
@@ -583,7 +764,7 @@ def _fwd_kernel(
                     m_new = jnp.maximum(
                         m_prev, jnp.max(s_t, axis=0, keepdims=True)
                     )
-                    p_t = t.exp(s_t, m_new)
+                    p_t = t.exp(s_t, m_new, diagonal)
                     alpha = jnp.exp(m_prev - m_new)
                     l_new = alpha * l_prev + jnp.sum(p_t, axis=0, keepdims=True)
                     if t.dropout_rate > 0.0:
@@ -825,16 +1006,20 @@ class _Operands:
         dkv's key-major grid is (group, ik, iq)."""
         return 1 if (rows == "q") != key_major else 2
 
-    def spec(self, rows, block, key_major=False, part=0):
+    def _at(self, rows, key_major, needed=None):
+        """``g -> `` the block index of the ``rows`` axis at grid position
+        ``g``; ``needed(g)`` replaces it where a mask form says which block
+        a step needs (``_bd_key_block``)."""
+        axis = self._row_axis(rows, key_major)
+        return needed or (lambda g: g[axis])
+
+    def spec(self, rows, block, key_major=False, part=0, needed=None):
         """BlockSpec of a group's ``block`` rows of q (``part`` 0), k (1)
         or v (2) in the projection's result, or of a ``[.., H*D]`` array
         (``part`` 0). ``rows`` None: ``block`` is ALL the group's rows,
         wherever the grid stands in them, so it stays in VMEM for every
         step of the group and is written back once."""
-        axis = rows and self._row_axis(rows, key_major)
-
-        def at(g):
-            return g[axis] if rows else 0
+        at = self._at(rows, key_major, needed) if rows else (lambda g: 0)
 
         if not self.packed:
             return pl.BlockSpec(
@@ -856,23 +1041,23 @@ class _Operands:
             (1, LANES), lambda *g: (0, part * per + g[0] % per)
         )
 
-    def row_spec(self, block_q, sub_q, key_major=False):
+    def row_spec(self, block_q, sub_q, key_major=False, needed=None):
         """BlockSpec of the per-query rows lse/delta of a group's heads:
         one lane-dense row a head a query sub-tile."""
-        axis = self._row_axis("q", key_major)
+        at = self._at("q", key_major, needed)
         return pl.BlockSpec(
             (self.heads_a_block, block_q // sub_q, 1, sub_q),
-            lambda *g: (g[0], g[axis], 0, 0),
+            lambda *g: (g[0], at(g), 0, 0),
         )
 
-    def kvm_spec(self, use_mask, block_k, key_major=False):
+    def kvm_spec(self, use_mask, block_k, key_major=False, needed=None):
         """BlockSpec for the [B, Sk, 1] key-validity column (keys lie on
         the sublanes of a transposed score sub-tile)."""
         if not use_mask:
             return pl.BlockSpec((1, 1, 1), lambda *g: (0, 0, 0))
-        axis, per = self._row_axis("k", key_major), self.groups_a_batch
+        at, per = self._at("k", key_major, needed), self.groups_a_batch
         return pl.BlockSpec(
-            (1, block_k, 1), lambda *g: (g[0] // per, g[axis], 0)
+            (1, block_k, 1), lambda *g: (g[0] // per, at(g), 0)
         )
 
     def result(self, seq, dtype):
@@ -899,7 +1084,7 @@ def _kvm_column(kv_mask):
 
 def _static(
     ops, sq, sk, block_q, block_k, causal, sm_scale, dropout_rate, use_mask,
-    use_bias,
+    use_bias, block_diffusion=0,
 ):
     """The keyword arguments of ``_Tiles`` that the kernels share."""
     return dict(
@@ -907,6 +1092,19 @@ def _static(
         nq=sq // block_q, nk=sk // block_k, diag_offset=sk - sq,
         dropout_rate=dropout_rate, use_mask=use_mask, use_bias=use_bias,
         head_dim=ops.head_dim, heads_a_block=ops.heads_a_block,
+        block_diffusion=block_diffusion,
+    )
+
+
+def _needed_blocks(sq, block_q, block_k, block_diffusion, key_major):
+    """``g -> block`` for the operands a grid's INNER axis walks (the keys'
+    of a query-major grid ``(group, iq, ik)``, the queries' of a key-major
+    one ``(group, ik, iq)``), or None where every step holds its own."""
+    if not block_diffusion:
+        return None
+    pick = _bd_query_block if key_major else _bd_key_block
+    return lambda g: pick(
+        g[1], g[2], block_q, block_k, sq // 2, block_diffusion
     )
 
 
@@ -919,7 +1117,7 @@ def _bias_row(bias, dtype):
 
 def _forward_call(
     ops, q, k, v, bias, kv_mask, seed, sq, sk, causal, sm_scale, dropout_rate,
-    block_q, block_k,
+    block_q, block_k, block_diffusion=0,
 ):
     """``flash_fwd`` over all B x H heads. ``q``/``k``/``v``: three
     ``[B*H, S, D]`` arrays, or the packed projection three times, then
@@ -929,26 +1127,27 @@ def _forward_call(
     use_bias = bias is not None
     common = _static(
         ops, sq, sk, block_q, block_k, causal, sm_scale, dropout_rate,
-        kv_mask is not None, use_bias,
+        kv_mask is not None, use_bias, block_diffusion,
     )
     nq, nk = common["nq"], common["nk"]
     sub_q, sub_k = pick_subtiles(block_q, block_k, nq, nk, key_major=False)
     interpret = not device.on_tpu()
     _log_tiling(
         sq, sk, d, ops.block_lanes, str(dtype), block_q, block_k, causal,
-        kv_mask is not None, dropout_rate > 0.0,
+        kv_mask is not None, dropout_rate > 0.0, block_diffusion,
     )
     hb, nsq = ops.heads_a_block, block_q // sub_q
+    keys = _needed_blocks(sq, block_q, block_k, block_diffusion, False)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, sub_q=sub_q, sub_k=sub_k, **common),
         grid=(ops.groups, nq, nk),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             ops.spec("q", block_q, part=0),
-            ops.spec("k", block_k, part=1),
-            ops.spec("k", block_k, part=2),
+            ops.spec("k", block_k, part=1, needed=keys),
+            ops.spec("k", block_k, part=2, needed=keys),
             *(ops.bias_spec(use_bias, part) for part in range(3)),
-            ops.kvm_spec(kv_mask is not None, block_k),
+            ops.kvm_spec(kv_mask is not None, block_k, needed=keys),
         ],
         out_specs=[ops.spec("q", block_q), ops.row_spec(block_q, sub_q)],
         out_shape=[
@@ -978,7 +1177,7 @@ def _seed_array(seed):
 
 def _backward_calls(
     ops, q, k, v, bias, kv_mask, seed, do, lse, delta, sq, sk, causal,
-    sm_scale, dropout_rate, block_q, block_k,
+    sm_scale, dropout_rate, block_q, block_k, block_diffusion=0,
 ):
     """(dq, dk, dv) in the operands' layout, from the backward that
     ``backward_plan`` chooses: the fused kernel, which runs as
@@ -989,13 +1188,14 @@ def _backward_calls(
     use_mask, use_bias = kv_mask is not None, bias is not None
     common = _static(
         ops, sq, sk, block_q, block_k, causal, sm_scale, dropout_rate,
-        use_mask, use_bias,
+        use_mask, use_bias, block_diffusion,
     )
     nq, nk = common["nq"], common["nk"]
     interpret = not device.on_tpu()
     hb, lanes = ops.heads_a_block, ops.block_lanes
     plan = backward_plan(
-        sq, sk, block_q, block_k, causal, lanes, jnp.dtype(dtype).itemsize
+        sq, sk, block_q, block_k, causal, lanes, jnp.dtype(dtype).itemsize,
+        block_diffusion=block_diffusion,
     )
 
     def call(kernel, name, key_major, sub, out_specs, out_shape, scratch,
@@ -1004,19 +1204,23 @@ def _backward_calls(
         delta enter as one lane-dense row a query sub-tile."""
         sub_q, sub_k = sub
         rows = (ops.batch * ops.heads, sq // sub_q, 1, sub_q)
+        # the inner axis's operands: queries of the key-major walk, keys
+        # of the query-major one
+        inner = _needed_blocks(sq, block_q, block_k, block_diffusion, key_major)
+        qs, ks = (inner, None) if key_major else (None, inner)
         return pl.pallas_call(
             functools.partial(kernel, sub_q=sub_q, sub_k=sub_k, **common),
             grid=(ops.groups, nk, nq) if key_major else (ops.groups, nq, nk),
             in_specs=[
                 pl.BlockSpec(memory_space=pltpu.SMEM),
-                ops.spec("q", block_q, key_major, part=0),
-                ops.spec("k", block_k, key_major, part=1),
-                ops.spec("k", block_k, key_major, part=2),
+                ops.spec("q", block_q, key_major, part=0, needed=qs),
+                ops.spec("k", block_k, key_major, part=1, needed=ks),
+                ops.spec("k", block_k, key_major, part=2, needed=ks),
                 *(ops.bias_spec(use_bias, part) for part in range(3)),
-                ops.kvm_spec(use_mask, block_k, key_major),
-                ops.spec("q", block_q, key_major),
-                ops.row_spec(block_q, sub_q, key_major),
-                ops.row_spec(block_q, sub_q, key_major),
+                ops.kvm_spec(use_mask, block_k, key_major, needed=ks),
+                ops.spec("q", block_q, key_major, needed=qs),
+                ops.row_spec(block_q, sub_q, key_major, needed=qs),
+                ops.row_spec(block_q, sub_q, key_major, needed=qs),
             ],
             out_specs=out_specs, out_shape=out_shape, scratch_shapes=scratch,
             interpret=interpret, name=name, **params,
@@ -1085,25 +1289,36 @@ def _name_residuals(out, lse):
 
 
 # ---- split operands: q, k, v [B, H, S, D] ---------------------------------
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
-def _flash(q, k, v, kv_mask, seed, causal, sm_scale, dropout_rate, block_q, block_k):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+def _flash(
+    q, k, v, kv_mask, seed, causal, sm_scale, dropout_rate, block_q, block_k,
+    block_diffusion=0,
+):
     return _flash_fwd(
-        q, k, v, kv_mask, seed, causal, sm_scale, dropout_rate, block_q, block_k
+        q, k, v, kv_mask, seed, causal, sm_scale, dropout_rate, block_q,
+        block_k, block_diffusion,
     )[0]
 
 
-def _flash_fwd(q, k, v, kv_mask, seed, causal, sm_scale, dropout_rate, block_q, block_k):
+def _flash_fwd(
+    q, k, v, kv_mask, seed, causal, sm_scale, dropout_rate, block_q, block_k,
+    block_diffusion=0,
+):
     b, h, sq, d = q.shape
     out, lse = _forward_call(
         _Operands(b, h, d, packed=False),
         _reshape_bh(q), _reshape_bh(k), _reshape_bh(v), None, kv_mask, seed,
         sq, k.shape[2], causal, sm_scale, dropout_rate, block_q, block_k,
+        block_diffusion,
     )
     out, lse = _name_residuals(out.reshape(b, h, sq, d), lse)
     return out, (q, k, v, kv_mask, seed, out, lse)
 
 
-def _flash_bwd(causal, sm_scale, dropout_rate, block_q, block_k, residuals, g):
+def _flash_bwd(
+    causal, sm_scale, dropout_rate, block_q, block_k, block_diffusion,
+    residuals, g,
+):
     q, k, v, kv_mask, seed, out, lse = residuals
     b, h, sq, d = q.shape
     sk = k.shape[2]
@@ -1115,7 +1330,7 @@ def _flash_bwd(causal, sm_scale, dropout_rate, block_q, block_k, residuals, g):
         _Operands(b, h, d, packed=False),
         _reshape_bh(q), _reshape_bh(k), _reshape_bh(v), None, kv_mask, seed,
         _reshape_bh(g), lse, delta, sq, sk, causal, sm_scale, dropout_rate,
-        block_q, block_k,
+        block_q, block_k, block_diffusion,
     )
     return (
         dq.reshape(b, h, sq, d), dk.reshape(b, h, sk, d),
@@ -1209,24 +1424,55 @@ def additive_mask_to_kv_valid(mask):
     return None
 
 
+def block_diffusion_refusal(sq, sk, causal, block_diffusion):
+    """Why the kernels cannot apply the block-diffusion mask to a call, or
+    None where they can (or none is asked for)."""
+    if not block_diffusion:
+        return None
+    if causal:
+        return "block_diffusion is a mask form of its own, not causal's"
+    if sq != sk or sq % 2:
+        return f"a [noisy ; clean] row has 2 L positions each way, not {sq} x {sk}"
+    if block_diffusion & (block_diffusion - 1) or (sq // 2) % block_diffusion:
+        return (
+            f"block length {block_diffusion} must be a power of two that "
+            f"divides L = {sq // 2}"
+        )
+    return None
+
+
+def _pick_blocks(sq, sk, block_q, block_k, block_diffusion=0):
+    """The largest dividing blocks; under the block-diffusion mask those
+    that divide a HALF of the row, so that a block lies in one half."""
+    if block_diffusion:
+        sq, sk = sq // 2, sk // 2
+    return pick_block(sq, block_q), pick_block(sk, block_k)
+
+
 def flash_attention(
     q, k, v, mask=None, kv_mask=None, causal=False, sm_scale=None,
     dropout_rate=0.0, dropout_seed=0,
-    block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
+    block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K, block_diffusion=0,
 ):
     """Blockwise flash attention. q,k,v: [B, H, S, D].
 
     Masking: pass ``kv_mask`` [B, Sk] (nonzero = attend) or a padding-style
     additive ``mask`` (converted). Query-dependent additive biases are not
     supported here — use ``attention()`` / ``mha_reference`` for those.
+    ``block_diffusion=B``: the rows are ``[noisy ; clean]`` halves of one
+    sequence in blocks of ``B`` and the kernels apply that mask
+    (``block_diffusion_mask``) from the grid position, skipping what it
+    empties.
     """
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)
     sq, sk = q.shape[2], k.shape[2]
+    why = block_diffusion_refusal(sq, sk, causal, block_diffusion)
+    if why:
+        raise ValueError(f"flash_attention: {why}")
     # shrink to the largest dividing block so e.g. seq 768 runs with
     # 256-blocks instead of failing the divisibility check on the default
-    block_q = pick_block(sq, block_q)
-    block_k = pick_block(sk, block_k)
+    block_q, block_k = _pick_blocks(sq, sk, block_q, block_k, block_diffusion)
     if block_q == 0 or block_k == 0:
         raise ValueError(
             f"flash_attention found no block size dividing sq={sq}/sk={sk}; "
@@ -1243,7 +1489,7 @@ def flash_attention(
     seed = jnp.asarray(dropout_seed, jnp.int32)
     return _flash(
         q, k, v, kv_mask, seed, causal, float(sm_scale), float(dropout_rate),
-        int(block_q), int(block_k),
+        int(block_q), int(block_k), int(block_diffusion),
     )
 
 
@@ -1313,7 +1559,7 @@ FLASH_MIN_SEQ = 256
 def flash_attention_sharded(
     q, k, v, mesh, kv_mask=None, causal=False, sm_scale=None,
     dropout_rate=0.0, dropout_seed=0,
-    block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
+    block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K, block_diffusion=0,
 ):
     """Flash attention under a data/model-parallel mesh via ``shard_map``.
 
@@ -1331,8 +1577,9 @@ def flash_attention_sharded(
 
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)
-    block_q = pick_block(q.shape[2], block_q)
-    block_k = pick_block(k.shape[2], block_k)
+    block_q, block_k = _pick_blocks(
+        q.shape[2], k.shape[2], block_q, block_k, block_diffusion
+    )
     if block_q == 0 or block_k == 0:
         raise ValueError(
             f"no block size divides sq={q.shape[2]}/sk={k.shape[2]}"
@@ -1347,6 +1594,7 @@ def flash_attention_sharded(
         return _flash(
             q, k, v, kvm if use_mask else None, seed, causal,
             float(sm_scale), float(dropout_rate), int(block_q), int(block_k),
+            int(block_diffusion),
         )
 
     return jax.shard_map(
@@ -1395,12 +1643,15 @@ def _flash_route(mesh, batch, heads):
     return "sharded"
 
 
-def _flash_gate(sq, sk, mask, dropout_rate, dropout_rng, use_flash):
+def _flash_gate(
+    sq, sk, mask, dropout_rate, dropout_rng, use_flash, block_diffusion=0
+):
     """Whether the kernels can serve this call at all, before any mesh is
     looked at: ``(why_not or None, kv_mask, block_q, block_k,
     dropout_rate)``."""
-    bq = pick_block(sq, DEFAULT_BLOCK_Q)
-    bk = pick_block(sk, DEFAULT_BLOCK_K)
+    bq, bk = _pick_blocks(
+        sq, sk, DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K, block_diffusion
+    )
     if dropout_rng is None:
         dropout_rate = 0.0  # matches the XLA path's no-rng => no-dropout
     kv_mask = additive_mask_to_kv_valid(mask)
@@ -1527,7 +1778,7 @@ def attention_packed(
 
 def attention(
     q, k, v, mask=None, causal=False, sm_scale=None, dropout_rate=0.0,
-    dropout_rng=None, use_flash=True, mesh=None,
+    dropout_rng=None, use_flash=True, mesh=None, block_diffusion=0,
 ):
     """Dispatcher: flash kernel when shapes tile cleanly and the mask is a
     padding mask; XLA reference otherwise (incl. learned additive biases,
@@ -1536,18 +1787,22 @@ def attention(
     where several devices leave no way to run it (``_flash_route``), the
     O(S^2) path runs and, on a TPU, says why once. ``k``/``v`` may have
     fewer heads than ``q`` (grouped-query attention). A caller that holds
-    the fused qkv projection's result takes ``attention_packed``."""
+    the fused qkv projection's result takes ``attention_packed``.
+    ``block_diffusion=B``: the block-diffusion mask over ``[noisy ; clean]``
+    rows, inside the kernels; where no kernel runs, ``block_diffusion_mask``
+    as a dense additive mask on the XLA path."""
     why = "q, k and v arrive as separate [B, H, S, D] arrays"
     refusal = packed_refusal(q.shape[1], q.shape[-1])
     return _attention_split(
         q, k, v, mask, causal, sm_scale, dropout_rate, dropout_rng,
         use_flash, mesh, f"{why}; {refusal}" if refusal else why,
+        block_diffusion,
     )
 
 
 def _attention_split(
     q, k, v, mask, causal, sm_scale, dropout_rate, dropout_rng, use_flash,
-    mesh, why_split,
+    mesh, why_split, block_diffusion=0,
 ):
     if k.shape[1] != q.shape[1]:
         # grouped-query heads: each kv head serves q_heads / kv_heads query
@@ -1557,8 +1812,11 @@ def _attention_split(
                 for t in (k, v))
     b, heads, sq, d = q.shape
     sk = k.shape[2]
+    refusal = block_diffusion_refusal(sq, sk, causal, block_diffusion)
+    if refusal:
+        raise ValueError(f"attention: {refusal}")
     why_not, kv_mask, bq, bk, dropout_rate = _flash_gate(
-        sq, sk, mask, dropout_rate, dropout_rng, use_flash
+        sq, sk, mask, dropout_rate, dropout_rng, use_flash, block_diffusion
     )
     _log_layout(
         b, sq, heads, d, "split", 1,
@@ -1576,12 +1834,13 @@ def _attention_split(
                 q, k, v, mesh, kv_mask=kv_mask, causal=causal,
                 sm_scale=sm_scale, dropout_rate=dropout_rate,
                 dropout_seed=seed, block_q=bq, block_k=bk,
+                block_diffusion=block_diffusion,
             )
         if route == "local":
             return flash_attention(
                 q, k, v, kv_mask=kv_mask, causal=causal, sm_scale=sm_scale,
                 dropout_rate=dropout_rate, dropout_seed=seed,
-                block_q=bq, block_k=bk,
+                block_q=bq, block_k=bk, block_diffusion=block_diffusion,
             )
         if device.on_tpu():
             # a shape the kernel could have served is about to pay
@@ -1592,6 +1851,9 @@ def _attention_split(
                 "running the O(S^2) XLA path",
                 tuple(q.shape), tuple(k.shape), route,
             )
+    if block_diffusion:
+        dense = block_diffusion_mask(sq, block_diffusion)
+        mask = dense if mask is None else mask + dense
     return mha_reference(
         q, k, v, mask=mask, causal=causal, sm_scale=sm_scale,
         dropout_rate=dropout_rate, dropout_rng=dropout_rng,

@@ -807,8 +807,10 @@ def gated_moe_mixer(p, x, *, top_k, held, offset, tile, force_level=False,
     ``p``: router [E, routed], wg and wu [held, E, F], wd [held, F, E],
     shared_wg and shared_wu [E, Fs], shared_wd [Fs, E], shared_gate [E, 1].
     Softmax routing (``route_softmax_topk``), no selection bias and no
-    scaling; the shared expert is weighed by ``sigmoid(x shared_gate)``. The
-    same plan, loop and counters as ``latent_moe_mixer``."""
+    scaling; the shared expert is weighed by ``sigmoid(x shared_gate)``. A
+    family without a shared expert has no ``shared_*`` leaves and the layer
+    is the routed sum alone (no ``moe_shared`` scope opens). The same plan,
+    loop and counters as ``latent_moe_mixer``."""
     b, s, e = x.shape
     xt = x.reshape(b * s, e)
     with jax.named_scope("moe_route"):
@@ -820,6 +822,8 @@ def gated_moe_mixer(p, x, *, top_k, held, offset, tile, force_level=False,
         routed = grouped_expert_ffn(
             xt, (p["wg"], p["wu"], p["wd"]), weights_t, plan, tile, "swiglu",
             chunk_tiles, mesh)
+    if "shared_wg" not in p:
+        return routed.astype(x.dtype).reshape(b, s, e), counters
     with jax.named_scope("moe_shared"):
         gate = jax.nn.sigmoid(jnp.dot(
             xt, p["shared_gate"], preferred_element_type=jnp.float32))

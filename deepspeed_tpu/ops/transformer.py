@@ -368,14 +368,18 @@ def rotary_frequencies(lanes, theta):
         float(theta) ** (-np.arange(0, lanes, 2) / lanes), np.float32)
 
 
-def apply_rotary(x, lanes, theta, seq_axis=2):
+def apply_rotary(x, lanes, theta, seq_axis=2, positions=None):
     """Rotary position embedding in the half-split convention on the first
     ``lanes`` lanes of each head of ``x`` [B, H, S, D] (lane i pairs with
-    lane i + lanes / 2; the lanes after stay as they are), positions 0..S-1,
-    no scaling, angles and rotation in float32. ``seq_axis=1``: ``x`` is
-    [B, S, H, D], as a projection leaves it."""
+    lane i + lanes / 2; the lanes after stay as they are), no scaling,
+    angles and rotation in float32. ``positions`` [S]: each row's position
+    id (the two halves of a block-diffusion row carry the same ids); None
+    is 0..S-1. ``seq_axis=1``: ``x`` is [B, S, H, D], as a projection
+    leaves it."""
     half = lanes // 2
-    angle = jnp.arange(x.shape[seq_axis], dtype=jnp.float32)[:, None] \
+    if positions is None:
+        positions = jnp.arange(x.shape[seq_axis], dtype=jnp.float32)
+    angle = positions.astype(jnp.float32)[:, None] \
         * rotary_frequencies(lanes, theta)[None, :]
     if seq_axis == 1:
         angle = angle[:, None, :]
@@ -410,6 +414,34 @@ def gated_attention_mixer(p, x, *, heads, kv_heads, head_dim, rotary_lanes,
         ctx = ctx.transpose(0, 2, 1, 3) * jax.nn.sigmoid(
             gate.astype(jnp.float32)).astype(ctx.dtype)
         return ctx.reshape(b, s, heads * head_dim) @ p["wo"]
+
+
+def rotary_gqa_attention_mixer(p, x, *, heads, kv_heads, head_dim, rope_theta,
+                               eps, zero_centered=False, positions=None,
+                               block_diffusion=0, mesh=None):
+    """Grouped-query attention with per-head q/k norms and rotary on every
+    lane, over normalized ``x`` [B, S, E]: wq [E, heads * D], wk/wv [E,
+    kv_heads * D]; an RMS norm over D on q (q_norm [D], one gain for all
+    heads) and on k (k_norm [D]), THEN rotary on all D lanes at
+    ``positions`` [S] (None: 0..S-1); kv head j serves query heads ``j *
+    heads / kv_heads`` onward (repeated before the kernels); wo [heads * D,
+    E]. No bias, no gate. Causal, or with ``block_diffusion=B`` under the
+    block-diffusion mask over a ``[noisy ; clean]`` row (ops/attention.py),
+    whose halves then carry the same ``positions``."""
+    b, s, _ = x.shape
+    with jax.named_scope("attn_mixer"):
+        q = (x @ p["wq"]).reshape(b, s, heads, head_dim)
+        k = (x @ p["wk"]).reshape(b, s, kv_heads, head_dim)
+        v = (x @ p["wv"]).reshape(b, s, kv_heads, head_dim)
+        q, k = (
+            apply_rotary(rms_norm(t, gain, eps, zero_centered), head_dim,
+                         rope_theta, seq_axis=1, positions=positions)
+            for t, gain in ((q, p["q_norm"]), (k, p["k_norm"])))
+        q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
+        ctx = attention(q, k, v, causal=not block_diffusion, mesh=mesh,
+                        block_diffusion=block_diffusion)
+        return ctx.transpose(0, 2, 1, 3).reshape(b, s, heads * head_dim) \
+            @ p["wo"]
 
 
 def rotary_attention_mixer(p, x, *, heads, head_dim, rope_theta, mesh=None):
