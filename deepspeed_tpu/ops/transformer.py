@@ -36,6 +36,7 @@ import numpy as np
 from .attention import (
     NEG_INF, additive_mask_to_kv_valid, attention, attention_packed,
 )
+from .qk_prep import qk_prep, qk_prep_in_place, qk_prep_path
 
 
 @dataclasses.dataclass
@@ -368,19 +369,26 @@ def rotary_frequencies(lanes, theta):
         float(theta) ** (-np.arange(0, lanes, 2) / lanes), np.float32)
 
 
+def rotary_angles(seq, lanes, theta, positions=None):
+    """[S, lanes / 2] float32 angles ``position * frequency``. ``positions``
+    [S]: each row's position id (the two halves of a block-diffusion row
+    carry the same ids); None is 0..S-1."""
+    if positions is None:
+        positions = jnp.arange(seq, dtype=jnp.float32)
+    return positions.astype(jnp.float32)[:, None] \
+        * rotary_frequencies(lanes, theta)[None, :]
+
+
 def apply_rotary(x, lanes, theta, seq_axis=2, positions=None):
     """Rotary position embedding in the half-split convention on the first
     ``lanes`` lanes of each head of ``x`` [B, H, S, D] (lane i pairs with
     lane i + lanes / 2; the lanes after stay as they are), no scaling,
-    angles and rotation in float32. ``positions`` [S]: each row's position
-    id (the two halves of a block-diffusion row carry the same ids); None
-    is 0..S-1. ``seq_axis=1``: ``x`` is [B, S, H, D], as a projection
-    leaves it."""
+    angles and rotation in float32, at ``rotary_angles``' positions.
+    ``seq_axis=1``: ``x`` is [B, S, H, D], as a projection leaves it. The
+    reference of the kernels of ops/qk_prep.py, and the path of every shape
+    ``qk_prep_path`` refuses."""
     half = lanes // 2
-    if positions is None:
-        positions = jnp.arange(x.shape[seq_axis], dtype=jnp.float32)
-    angle = positions.astype(jnp.float32)[:, None] \
-        * rotary_frequencies(lanes, theta)[None, :]
+    angle = rotary_angles(x.shape[seq_axis], lanes, theta, positions)
     if seq_axis == 1:
         angle = angle[:, None, :]
     cos, sin = jnp.cos(angle), jnp.sin(angle)
@@ -389,6 +397,16 @@ def apply_rotary(x, lanes, theta, seq_axis=2, positions=None):
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin, xs[..., lanes:]],
         axis=-1).astype(x.dtype)
+
+
+def _qk_prep(projections, gains, head_dim, angle, eps, zero_centered):
+    """q and k [B, heads, S, D] out of their projections' results [B, S,
+    heads * D] through the kernels of ops/qk_prep.py: each head's RMS norm
+    with its gain, then rotary by ``angle`` (``rotary_angles``)."""
+    return tuple(
+        qk_prep(t, gain, angle, head_dim=head_dim, eps=eps,
+                zero_centered=zero_centered)
+        for t, gain in zip(projections, gains))
 
 
 def gated_attention_mixer(p, x, *, heads, kv_heads, head_dim, rotary_lanes,
@@ -400,16 +418,30 @@ def gated_attention_mixer(p, x, *, heads, kv_heads, head_dim, rotary_lanes,
     lanes of q and k; ``out = (context * sigmoid(gate)) wo``, wo [heads * D,
     E]. No bias anywhere."""
     b, s, _ = x.shape
+    fused = qk_prep_path(b, s, heads, head_dim, rotary_lanes, mesh)[0] == "fused"
     with jax.named_scope("attn_mixer"):
-        qg = (x @ p["wq"]).reshape(b, s, heads, 2 * head_dim)
-        q, gate = qg[..., :head_dim], qg[..., head_dim:]
-        k = (x @ p["wk"]).reshape(b, s, kv_heads, head_dim)
-        v = (x @ p["wv"]).reshape(b, s, kv_heads, head_dim)
-        q = rms_norm(q, p["q_norm"], eps, zero_centered=True)
-        k = rms_norm(k, p["k_norm"], eps, zero_centered=True)
-        q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
-        q = apply_rotary(q, rotary_lanes, rope_theta)
-        k = apply_rotary(k, rotary_lanes, rope_theta)
+        if fused:
+            # q and the gate as two products of wq's two halves of each head:
+            # both lane-dense [B, S, heads * D], no interleave to take apart
+            wq = p["wq"].reshape(-1, heads, 2, head_dim)
+            q, gate = (x @ wq[:, :, i].reshape(-1, heads * head_dim)
+                       for i in (0, 1))
+            gate = gate.reshape(b, s, heads, head_dim)
+            q, k = _qk_prep(
+                (q, x @ p["wk"]), (p["q_norm"], p["k_norm"]), head_dim,
+                rotary_angles(s, rotary_lanes, rope_theta), eps, True)
+            v = (x @ p["wv"]).reshape(b, s, kv_heads, head_dim).transpose(
+                0, 2, 1, 3)
+        else:
+            qg = (x @ p["wq"]).reshape(b, s, heads, 2 * head_dim)
+            q, gate = qg[..., :head_dim], qg[..., head_dim:]
+            k = (x @ p["wk"]).reshape(b, s, kv_heads, head_dim)
+            v = (x @ p["wv"]).reshape(b, s, kv_heads, head_dim)
+            q = rms_norm(q, p["q_norm"], eps, zero_centered=True)
+            k = rms_norm(k, p["k_norm"], eps, zero_centered=True)
+            q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
+            q = apply_rotary(q, rotary_lanes, rope_theta)
+            k = apply_rotary(k, rotary_lanes, rope_theta)
         ctx = attention(q, k, v, causal=True, mesh=mesh)
         ctx = ctx.transpose(0, 2, 1, 3) * jax.nn.sigmoid(
             gate.astype(jnp.float32)).astype(ctx.dtype)
@@ -429,15 +461,24 @@ def rotary_gqa_attention_mixer(p, x, *, heads, kv_heads, head_dim, rope_theta,
     block-diffusion mask over a ``[noisy ; clean]`` row (ops/attention.py),
     whose halves then carry the same ``positions``."""
     b, s, _ = x.shape
+    fused = qk_prep_path(b, s, heads, head_dim, head_dim, mesh)[0] == "fused"
     with jax.named_scope("attn_mixer"):
-        q = (x @ p["wq"]).reshape(b, s, heads, head_dim)
-        k = (x @ p["wk"]).reshape(b, s, kv_heads, head_dim)
-        v = (x @ p["wv"]).reshape(b, s, kv_heads, head_dim)
-        q, k = (
-            apply_rotary(rms_norm(t, gain, eps, zero_centered), head_dim,
-                         rope_theta, seq_axis=1, positions=positions)
-            for t, gain in ((q, p["q_norm"]), (k, p["k_norm"])))
-        q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
+        if fused:
+            q, k = _qk_prep(
+                (x @ p["wq"], x @ p["wk"]), (p["q_norm"], p["k_norm"]),
+                head_dim, rotary_angles(s, head_dim, rope_theta, positions),
+                eps, zero_centered)
+            v = (x @ p["wv"]).reshape(b, s, kv_heads, head_dim).transpose(
+                0, 2, 1, 3)
+        else:
+            q = (x @ p["wq"]).reshape(b, s, heads, head_dim)
+            k = (x @ p["wk"]).reshape(b, s, kv_heads, head_dim)
+            v = (x @ p["wv"]).reshape(b, s, kv_heads, head_dim)
+            q, k = (
+                apply_rotary(rms_norm(t, gain, eps, zero_centered), head_dim,
+                             rope_theta, seq_axis=1, positions=positions)
+                for t, gain in ((q, p["q_norm"]), (k, p["k_norm"])))
+            q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
         ctx = attention(q, k, v, causal=not block_diffusion, mesh=mesh,
                         block_diffusion=block_diffusion)
         return ctx.transpose(0, 2, 1, 3).reshape(b, s, heads * head_dim) \
@@ -454,12 +495,18 @@ def rotary_attention_mixer(p, x, *, heads, head_dim, rope_theta, mesh=None):
     from .attention import attention_packed
 
     b, s, _ = x.shape
+    fused = qk_prep_path(b, s, heads, head_dim, head_dim, mesh)[0] == "fused"
     with jax.named_scope("attn_mixer"):
-        qkv = (x @ jnp.concatenate([p["wq"], p["wk"], p["wv"]], axis=1)
-               ).reshape(b, s, 3 * heads, head_dim)
-        qk = apply_rotary(
-            qkv[:, :, :2 * heads], head_dim, rope_theta, seq_axis=1)
-        qkv = jnp.concatenate([qk, qkv[:, :, 2 * heads:]], axis=2)
+        qkv = x @ jnp.concatenate([p["wq"], p["wk"], p["wv"]], axis=1)
+        if fused:
+            qkv = qk_prep_in_place(
+                qkv, rotary_angles(s, head_dim, rope_theta),
+                heads=2 * heads, head_dim=head_dim)
+        else:
+            qkv = qkv.reshape(b, s, 3 * heads, head_dim)
+            qk = apply_rotary(
+                qkv[:, :, :2 * heads], head_dim, rope_theta, seq_axis=1)
+            qkv = jnp.concatenate([qk, qkv[:, :, 2 * heads:]], axis=2)
         ctx = attention_packed(
             qkv.reshape(b, s, 3 * heads * head_dim), heads, causal=True,
             mesh=mesh)

@@ -94,16 +94,23 @@ FLASH_KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
 FUSED, PAIR = ["flash_bwd_dkv", "flash_fwd"], sorted(FLASH_KERNELS)
 
 
+QK_PREP_KERNELS = ("qk_prep_fwd", "qk_prep_bwd")
+
+
 def _pallas_kernels(text):
-    """The kernel of every Mosaic call of a compiled program, sorted.
+    """The flash kernel of every Mosaic call of a compiled program, sorted.
     ``flash_ms.train`` sums exactly ``FLASH_KERNELS``: a kernel under any
-    other name would stay in the window unseen."""
+    other name would stay in the window unseen. The mixers' q/k norm-and-
+    rotary kernels (``QK_PREP_KERNELS``, which ``qk_prep_ms.train`` sums)
+    are let through and not listed."""
     import re
 
     names = []
     for line in text.splitlines():
         if "tpu_custom_call" in line:
             instruction = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = ", line).group(1)
+            if re.search(r"qk_prep_(?:fwd|bwd)(?![a-z])", instruction):
+                continue
             kernel = re.search(r"flash_(?:fwd|bwd_dq|bwd_dkv)(?![a-z])", instruction)
             assert kernel, f"a Pallas kernel outside {FLASH_KERNELS}: {instruction}"
             names.append(kernel.group(0))
@@ -304,6 +311,135 @@ def test_layer_body_hands_the_kernels_the_projections_own_buffer():
     assert _pallas_kernels(text) == FUSED
 
 
+# the two claimed cells' attention mixers alone (ops/transformer.py), forward
+# and backward under the cell's remat policy: (mixer, its arguments, x's
+# shape, the remat policy)
+ROTARY_MIXERS = {
+    # SDAR: 32 query heads on 4 kv heads of 128, q/k norms, a [noisy ; clean]
+    # row of 2 x 8,192 positions whose halves repeat the position ids
+    "A": ("rotary_gqa_attention_mixer",
+          dict(heads=32, kv_heads=4, head_dim=128, rope_theta=1e6, eps=1e-6,
+               block_diffusion=4),
+          (2, 16384, 2048), "nothing_saveable+flash_out+flash_lse+moe_plan"),
+    # Ouro: 16 heads of 128 out of one q | k | v product
+    "R": ("rotary_attention_mixer",
+          dict(heads=16, head_dim=128, rope_theta=1e6),
+          (1, 8192, 2048), "nothing_saveable+flash_out+flash_lse"),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _rotary_mixer_text(kind):
+    from deepspeed_tpu.ops import transformer
+
+    name, args, (b, s, e), policy = ROTARY_MIXERS[kind]
+    args = dict(args)
+    heads, d = args["heads"], args["head_dim"]
+    kv = args.get("kv_heads", heads)
+    p = {"wq": (e, heads * d), "wk": (e, kv * d), "wv": (e, kv * d),
+         "wo": (heads * d, e)}
+    if kind == "A":
+        p.update(q_norm=(d,), k_norm=(d,))
+        args["positions"] = jnp.concatenate([jnp.arange(s // 2)] * 2)
+    p = {k: _shape(v, jnp.bfloat16) for k, v in p.items()}
+    mixer = functools.partial(getattr(transformer, name), **args)
+
+    def loss(p, x):
+        return jax.checkpoint(
+            mixer, policy=transformer.resolve_remat_policy(policy)
+        )(p, x).astype(jnp.float32).sum()
+
+    real = device.on_tpu, jax.device_count
+    device.on_tpu, jax.device_count = (lambda: True), (lambda: 1)
+    try:
+        return _compiled_text(
+            jax.grad(loss, argnums=(0, 1)), p, _shape((b, s, e), jnp.bfloat16)
+        )
+    finally:
+        device.on_tpu, jax.device_count = real
+
+
+def _large_arrays(text):
+    """(dtype, dims) of every array among the results of a compiled
+    program's instructions of 15 MB and more (a fusion's inner values are
+    not written anywhere and are not listed)."""
+    import re
+
+    return {
+        (dtype, tuple(int(n) for n in dims.split(",")))
+        for _, result, _ in _large_instructions(text)
+        for dtype, dims in re.findall(r"\b([a-z]+[0-9]+)\[([\d,]+)\]", result)
+    }
+
+
+def _around_the_kernels(text):
+    """The instructions of 15 MB and more that are neither a product nor a
+    kernel: what XLA runs around them."""
+    return [
+        i for i in _large_instructions(text)
+        if i[0] not in ("custom-call", "convolution")
+        and not i[2].endswith("dot_general")
+    ]
+
+
+def _rotary_kernel_paths(text):
+    paths = _kernel_paths(text)
+    assert sorted(paths) == sorted(FUSED + list(QK_PREP_KERNELS))
+    assert all("attn_mixer" in path for path in paths.values())
+    assert "transpose(" in paths["qk_prep_bwd"]
+
+
+def test_rotary_gqa_mixer_norms_and_rotates_q_and_k_in_one_pass():
+    """Before PR 39 this compile counted 53 instructions of 15 MB and more
+    around the products and the kernels, 22 of them with float32 results
+    (the q product written as ``f32[2,16384,4096]``, copied, normed into a
+    third float32 array, rotated into two half-lane ``bf16[2,16384,32,64]``
+    arrays and concatenated; all of it again under remat; the mirror in
+    backward), writing 7.8 GB. Now ``qk_prep_fwd`` reads the product's own
+    bf16 result and writes ``[2,32,16384,128]`` once and ``qk_prep_bwd``
+    writes the product's cotangent once: no float32 array of B S H D
+    elements, no 64-lane half, 31 such instructions (what stays: v's
+    transpose, the repetition's broadcasts, the sums of dk and dv over a
+    group, dO's copy, the context's reduce-precision, asynchronous copies of
+    weights and of the rotary tables into fast memory)."""
+    text = _rotary_mixer_text("A")
+    whole = 2 * 16384 * 32 * 128
+    for dtype, dims in _large_arrays(text):
+        size = 1
+        for n in dims:
+            size *= n
+        assert not (dtype == "f32" and size >= whole), (dtype, dims)
+        assert dims[-1] != 64, (dtype, dims)
+    around = _around_the_kernels(text)
+    assert len(around) <= 31, len(around)
+    assert not [i for i in around if i[1].startswith("f32") and "copy" not in i[0]]
+    _rotary_kernel_paths(text)
+
+
+def test_rotary_mixer_rotates_q_and_k_in_the_projections_own_buffer():
+    """Before PR 39: a ``convert`` to ``f32[1,8192,32,128]``, two half-lane
+    ``bf16[1,8192,32,64]`` arrays, a ``concatenate`` to ``[1,8192,48,128]``
+    in a layout XLA chose for the rotation, a ``copy`` of the whole
+    ``[1,8192,6144]`` back to row-major for the kernels and a ``slice`` of
+    v, and the mirror in backward. Now the product's result goes to
+    ``qk_prep_fwd``, which rotates q | k in place, and from there to
+    ``flash_fwd``; backward concatenates dq | dk | dv once and
+    ``qk_prep_bwd`` rotates it back in place."""
+    text = _rotary_mixer_text("R")
+    arrays = _large_arrays(text)
+    assert not [a for a in arrays if a[0] == "f32" and a[1][1] == 8192]
+    assert not [a for a in arrays if a[1][-2:] == (48, 128)]
+    assert not [a for a in arrays if a[1][-1] == 64]
+    assert "f32[1,8192,32,128]" not in text and "[1,8192,48,128]" not in text
+    large = _large_instructions(text)
+    assert not [i for i in large if i[0] == "copy" and "[1,8192,6144]" in i[1]]
+    in_place = [i for i in large if i[0] == "custom-call"
+                and i[1].startswith("bf16[1,8192,6144]")]
+    assert len(in_place) == 3, in_place      # forward, under remat, backward
+    assert len(_around_the_kernels(text)) <= 15
+    _rotary_kernel_paths(text)
+
+
 def test_layer_body_falls_back_to_split_heads_at_25_heads():
     """GPT-2 XL's 25 heads of 64 do not pair into 128-lane blocks: the
     dispatcher splits as before, the ``[B, H, S, D]`` kernels compile, and
@@ -362,6 +498,42 @@ def test_dense_block_kernels_lower_under_dense_attn(model):
     assert "/dense_attn/" in paths["flash_bwd_dkv"]
     assert "transpose(" in paths["flash_bwd_dkv"]
     assert "/dense_ffn/" not in "".join(paths.values())
+
+
+def test_window_program_hashes_a_kernel_without_its_debug_locations():
+    """``tools/window_program.py`` is how a change shows that it bypasses a
+    cell: the same hash on both commits. A Mosaic kernel's bytecode holds
+    file paths and line numbers, so the same kernel lowered from two call
+    sites differs in its raw bytes and must not in the tool's hash."""
+    import importlib.util
+
+    from deepspeed_tpu.ops.attention import flash_attention
+
+    spec = importlib.util.spec_from_file_location(
+        "window_program", os.path.join(REPO, "tools", "window_program.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    q = _shape((1, 2, 512, 128), jnp.bfloat16)
+
+    def here(q, k, v):
+        return flash_attention(q, k, v, causal=True)
+
+    def there(q, k, v):
+        out = flash_attention(q, k, v, causal=True)
+        return out
+
+    real = device.on_tpu, jax.device_count
+    device.on_tpu, jax.device_count = (lambda: True), (lambda: 1)
+    try:
+        texts = [jax.jit(f).lower(q, q, q).as_text() for f in (here, there)]
+    finally:
+        device.on_tpu, jax.device_count = real
+    bodies = [tool.BODY.search(t).group(2) for t in texts]
+    assert bodies[0] != bodies[1]
+    (a, kernels), (b, _) = (tool.without_locations(t) for t in texts)
+    assert list(kernels) == ["flash_fwd"] and len(kernels["flash_fwd"]) == 1
+    assert a.replace("jit_here", "jit_there").replace("@here", "@there") \
+        == b.replace("jit_here", "jit_there").replace("@here", "@there")
 
 
 # ---------------------------------------------------------------------------
@@ -474,7 +646,10 @@ def test_gated_hybrid_sublayer_compiles_at_the_cells_shapes(kind):
     text = compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 6 * 2 ** 30
     kernels = [l for l in text.splitlines() if "tpu_custom_call" in l]
-    assert len(kernels) == {"gdn": 2, "gattn": 2, "gmoe": 2}[kind], kernels
+    # gated attention: the two flash kernels, and the q/k norm-and-rotary
+    # pass once for q and once for k forward, again under remat, and
+    # backward
+    assert len(kernels) == {"gdn": 2, "gattn": 8, "gmoe": 2}[kind], kernels
     if kind == "gmoe":
         # remat runs no forward kernel again: the rerun's sum feeds nothing
         # that the backward reads (its residuals are the layer's inputs)
@@ -482,6 +657,8 @@ def test_gated_hybrid_sublayer_compiles_at_the_cells_shapes(kind):
             ("moe_ffn_bwd", "backward"), ("moe_ffn_fwd", "forward")]
     if kind == "gattn":
         assert _pallas_kernels(text) == FUSED
+        assert sorted(_kernel_paths(text)) == sorted(
+            FUSED + list(QK_PREP_KERNELS))
         assert all("/attn_mixer/" in path
                    for path in _kernel_paths(text).values())
     for scope in {"gdn": ("gdn_mixer", "gdn_delta_rule"),

@@ -767,3 +767,50 @@ def test_grouped_expert_kernels_match_the_float32_loop_at_the_cells_widths(
                                 jax.tree_util.tree_leaves(want)):
             rel = float(jnp.max(jnp.abs(a - r)) / jnp.max(jnp.abs(r)))
             assert rel < 5e-2, f"skew={skew} {path}: rel err {rel:.2e}"
+
+
+@pytest.mark.parametrize("cell", ["sdar", "ouro", "qwen"])
+def test_qk_prep_kernels_match_the_xla_functions_at_the_cells_shapes(cell):
+    """``qk_prep_fwd`` and ``qk_prep_bwd`` compiled, at the three cells'
+    shapes (SDAR: 32 heads of 128 over 2 x 16,384 rows whose two halves
+    repeat the position ids, plain gains; Ouro: q | k of a packed 16-head
+    product rotated in place, v's lanes bit-equal; Qwen3-Next: 16 heads of
+    256, zero-centred gains, 64 lanes rotated), bf16, against ``apply_rotary(rms_norm(..))`` compiled by XLA:
+    the result, the projection's cotangent and the gain's gradient, the
+    largest gap in steps of bfloat16 at the largest magnitude of the head's
+    row. The kernels round once where the XLA functions round after the norm
+    and after the rotation, so a step or two either way is what two correct
+    programs differ by."""
+    import importlib.util
+    import os
+
+    spec = importlib.util.spec_from_file_location(
+        "qk_prep_alone", os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "tools", "qk_prep_alone.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    c = tool.cells[cell]
+    width = (3 if c["mixer"] == "R" else 1) * c["H"] * c["D"]
+    ks = jax.random.split(jax.random.PRNGKey(39), 3)
+    x = jax.random.normal(ks[0], (c["B"], c["S"], width)).astype(jnp.bfloat16)
+    gain = (1.0 - c["zc"] + 0.1 * jax.random.normal(ks[1], (c["D"],))).astype(
+        jnp.bfloat16)
+    got = {}
+    for path in ("fused", "xla"):
+        f = tool.pass_of(c, path)
+        out = jax.jit(f)(x, gain)
+        probe = jax.random.normal(ks[2], out.shape).astype(jnp.bfloat16)
+        got[path] = (out, *jax.jit(jax.grad(
+            lambda x, g: jnp.sum((f(x, g) * probe).astype(jnp.float32)),
+            (0, 1)))(x, gain))
+    gaps = {name: tool.steps(a, b) for name, a, b in zip(
+        ("out", "dx", "dgain"), got["fused"], got["xla"])}
+    print(cell, gaps)
+    assert gaps["out"] <= 2 and gaps["dx"] <= 4, gaps
+    if c["mixer"] == "R":
+        v = 2 * c["H"] * c["D"]
+        assert jnp.array_equal(got["fused"][0][..., v:], x[..., v:])
+        assert jnp.array_equal(got["fused"][1][..., v:], got["xla"][1][..., v:])
+    else:
+        assert gaps["dgain"] <= 4, gaps
