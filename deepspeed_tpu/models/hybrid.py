@@ -12,12 +12,21 @@ rotary on every lane (ops/transformer.py:rotary_attention_mixer), ``F`` a
 dense SiLU-gated FFN (ops/transformer.py:swiglu_ffn_mixer); ``A``
 grouped-query attention with per-head q/k norms and rotary on every lane
 (ops/transformer.py:rotary_gqa_attention_mixer), ``S`` the experts of ``X``
-with no shared expert beside them. A published
-layer that is a token mixer THEN experts or an FFN is two letters
+with no shared expert beside them; ``H`` and ``W`` grouped-query attention
+gated per head with no q/k norm (ops/transformer.py:
+head_gated_attention_mixer), ``H`` seeing the whole causal row with YaRN
+rotary on ``rotary_lanes`` lanes, ``W`` a sliding window of ``window`` keys
+(the band inside the flash kernels) with plain rotary on every lane, each
+with its own head count on the same kv heads; ``U`` the experts of ``X`` with
+an UNGATED shared expert and the routed sum times ``routed_scaling``. A
+published layer that is a token mixer THEN experts or an FFN is two letters
 (``DXDXDXGX`` is one period of three DeltaNet layers and one attention layer,
-each with its experts; ``RF`` and ``AS`` are one decoder layer each). No
+each with its experts; ``RF`` and ``AS`` are one decoder layer each; ``HF``
+then ``WUWUWUHU`` repeated is a leading dense layer and periods of three
+windowed layers and a full one). No
 learned position
-embedding (the recurrent layers carry position; ``G``, ``R`` and ``A`` rotate), a
+embedding (the recurrent layers carry position; ``G``, ``R``, ``A``, ``H``
+and ``W`` rotate), a
 final RMS norm, an untied head, bias-free projections; ``norm_zero_centered``
 stores every norm's gain around 0 and applies ``1 + gain``; ``post_norm``
 gives ``R`` and ``F`` a second gain AFTER the mixer (``x + norm_b(mixer(
@@ -27,7 +36,11 @@ norm_a(x)))``, a sandwich norm).
 is a Python loop over the pattern's PERIOD (the shortest string whose
 repetition the pattern is: all of ``DXDXDXGX``, the ``RF`` of ``RFRFRF``),
 and ``lax.scan`` walks the repetitions where there are several: the compiled
-program holds one period's bodies however deep the stack. The parameters of
+program holds one period's bodies however deep the stack. A pattern that is
+no pure repetition is a PREFIX, the longest repeated run and a TAIL
+(``stack_plan``: ``HF`` + 11 x ``WUWUWUHU`` + ``WUWUWU``): the scan walks the
+run and the layers before and after it unroll, one period's bodies plus
+theirs. The parameters of
 each KIND are stacked on a leading axis (``mamba_in_proj`` is [n M-layers, E,
 ...], ``moe_w1`` is [n E-layers, held, L, F]): a checkpoint, a ZeRO partition
 spec (runtime/zero.py shards any leaf over the data axis) and the optimizer
@@ -80,24 +93,29 @@ from ..ops.cross_entropy import (
     exit_log_probs,
     weighted_lm_head_loss,
 )
+from ..ops.attention import window_visited_share
 from ..ops.linear_attention import gated_deltanet_mixer
 from ..ops.moe import gated_moe_mixer, latent_moe_mixer
 from ..ops.ssm import mamba2_mixer
 from ..ops.transformer import (
     gated_attention_mixer,
     gqa_attention_mixer,
+    head_gated_attention_mixer,
     resolve_remat_policy,
     rms_norm,
     rotary_attention_mixer,
+    rotary_frequencies,
     rotary_gqa_attention_mixer,
     swiglu_ffn_mixer,
+    yarn_frequencies,
 )
 
 KINDS = {"M": "mamba", "E": "moe", "*": "attn",
          "D": "gdn", "G": "gattn", "X": "gmoe",
-         "R": "rattn", "F": "ffn", "A": "qattn", "S": "smoe"}
+         "R": "rattn", "F": "ffn", "A": "qattn", "S": "smoe",
+         "H": "hattn", "W": "wattn", "U": "umoe"}
 # the token mixers that are causal by construction: not for block diffusion
-CAUSAL_ONLY = "M*DGR"
+CAUSAL_ONLY = "M*DGRHW"
 OBJECTIVES = ("next_token", "block_diffusion")
 
 
@@ -110,14 +128,50 @@ def period(pattern):
     return pattern[:size], n // size
 
 
+# A scan stacks its bodies' residuals and slices them back (4% of the SDAR
+# window, 5% of the Ouro one: ``stack_scan_ms.train``, PERF.md). Inside a
+# longer pattern a run is worth one only where it takes at least this share
+# of the layers' bodies out of the program (the published window/full stack
+# loses 80 of 96; ``MEMEMEMEM*E`` would lose 6 of 11 and ``HFWUWUWUHU`` 4 of
+# 10: both unroll). A pattern that is nothing but a repetition scans from
+# two repetitions, as it always has.
+SCAN_MIN_SAVING = 2 / 3
+
+
+def stack_plan(pattern):
+    """``(prefix, unit, repetitions, tail)`` with ``pattern == prefix + unit
+    * repetitions + tail``: how the stack walks the pattern. A pure
+    repetition is ``period``'s, with no prefix and no tail. Else the repeated
+    run whose scan takes the most bodies out of the program (``len(unit) *
+    (repetitions - 1)``, at least ``SCAN_MIN_SAVING`` of the pattern), of the
+    shortest unit and then the earliest start where several take as many,
+    between the layers before and after it; a pattern with no such run is
+    its own unit, once."""
+    unit, repetitions = period(pattern)
+    best, n = ("", unit, repetitions, ""), len(pattern)
+    saved = n * SCAN_MIN_SAVING - 1e-9
+    for size in range(1, n // 2 + 1) if repetitions == 1 else ():
+        for start in range(n - 2 * size + 1):
+            unit = pattern[start:start + size]
+            reps = 1
+            while pattern.startswith(unit, start + reps * size):
+                reps += 1
+            if size * (reps - 1) > saved:
+                saved = size * (reps - 1)
+                best = (pattern[:start], unit, reps,
+                        pattern[start + size * reps:])
+    return best
+
+
 def merge_counters(found, axis=None):
     """One value a name out of several layers' (or passes') counters by the
     registry's rule (telemetry/manager.py): names whose last part starts
     with ``max_`` keep the maximum, the others add up. ``found`` is a list
-    of dicts, or with ``axis`` one dict of stacked values."""
+    of dicts (a name need not be in each: layers of different kinds count
+    different things), or with ``axis`` one dict of stacked values."""
     if axis is None:
-        found = {name: jnp.stack([c[name] for c in found])
-                 for name in (found[0] if found else {})}
+        found = {name: jnp.stack([c[name] for c in found if name in c])
+                 for name in dict.fromkeys(n for c in found for n in c)}
     return {
         name: (jnp.max if name.rsplit("/", 1)[-1].startswith("max_")
                else jnp.sum)(value, axis=axis)
@@ -159,15 +213,28 @@ class HybridLMConfig:
     # X: gated experts at the model's width. Shares the held/routed counts,
     # top_k, router_force_level, moe_intermediate, moe_shared_intermediate
     # and moe_tile with E; has no latent, bias or scaling
-    # *, G and A: grouped-query attention. heads HELD here. R has attn_heads
-    # kv heads too; R and A rotate all head_dim lanes. S shares X's fields
-    # and has no shared expert
+    # *, G, A and H: grouped-query attention. heads HELD here. R has
+    # attn_heads kv heads too; R and A rotate all head_dim lanes. S shares
+    # X's fields and has no shared expert; U shares them too, has no gate
+    # on its shared expert, and scales the routed sum by routed_scaling
     attn_heads: int = 2
     kv_heads: int = 1
     head_dim: int = 16
-    # G: lanes of each head that rotate (0: none); G and R: the base
+    # G and H: lanes of each head that rotate (0: none); G, R, A, H: the base
     rotary_lanes: int = 0
     rope_theta: float = 10000.0
+    # H: YaRN over rotary_lanes (yarn_factor 1: plain frequencies); cosine
+    # and sine times rotary_attention_factor
+    yarn_factor: float = 1.0
+    yarn_original_positions: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    rotary_attention_factor: float = 1.0
+    # W: keys a query sees, itself among them; its own head count on the
+    # same kv heads (0: attn_heads) and its own base, on every lane
+    window: int = 0
+    window_attn_heads: int = 0
+    window_rope_theta: float = 10000.0
     # F: the dense gated FFN's width
     ffn_intermediate: int = 96
     # R and F: a second gain, after the mixer
@@ -202,8 +269,16 @@ class HybridLMConfig:
                 f"{sorted(unknown)}; {', '.join(KINDS)} are known")
         if self.mamba_heads % self.mamba_groups:
             raise ValueError("mamba_heads must be a multiple of mamba_groups")
-        if self.attn_heads % self.kv_heads:
-            raise ValueError("attn_heads must be a multiple of kv_heads")
+        if self.attn_heads % self.kv_heads \
+                or self.window_attn_heads % self.kv_heads:
+            raise ValueError(
+                "attn_heads and window_attn_heads must be multiples of "
+                "kv_heads")
+        if "W" in self.pattern and self.window < 1:
+            raise ValueError("W layers need a window of at least one key")
+        if "H" in self.pattern and self.yarn_factor != 1.0 \
+                and self.yarn_original_positions < 1:
+            raise ValueError("YaRN needs yarn_original_positions")
         if self.gdn_value_heads % self.gdn_key_heads:
             raise ValueError(
                 "gdn_value_heads must be a multiple of gdn_key_heads")
@@ -229,6 +304,11 @@ class HybridLMConfig:
                 <= self.n_experts_routed):
             raise ValueError("held experts must lie inside those routed over")
 
+    def heads(self, kind):
+        """Query heads held here of an attention kind: W's own count where
+        it is set, else ``attn_heads``."""
+        return kind == "wattn" and self.window_attn_heads or self.attn_heads
+
     def leaf_shapes(self):
         """{kind: {leaf: shape of ONE layer's slice}}."""
         e = self.hidden_size
@@ -240,6 +320,21 @@ class HybridLMConfig:
         gvz = self.gdn_value_heads * self.gdn_value_dim
         fs = self.moe_shared_intermediate
         post = {"post_norm": (e,)} if self.post_norm else {}
+        gated_experts = {
+            "norm": (e,), "router": (e, self.n_experts_routed),
+            "wg": (self.n_experts_held, e, f),
+            "wu": (self.n_experts_held, e, f),
+            "wd": (self.n_experts_held, f, e),
+        }
+        shared_expert = {
+            "shared_wg": (e, fs), "shared_wu": (e, fs), "shared_wd": (fs, e)}
+
+        def head_gated(heads):
+            return {
+                "norm": (e,), "wq": (e, heads * self.head_dim),
+                "wk": (e, kvd), "wv": (e, kvd), "wg": (e, heads),
+                "wo": (heads * self.head_dim, e)}
+
         return {
             "mamba": {
                 "norm": (e,), "in_proj": (e, di + conv + self.mamba_heads),
@@ -275,13 +370,7 @@ class HybridLMConfig:
                 "k_norm": (self.head_dim,), "wo": (qd, e),
             },
             "gmoe": {
-                "norm": (e,), "router": (e, self.n_experts_routed),
-                "wg": (self.n_experts_held, e, f),
-                "wu": (self.n_experts_held, e, f),
-                "wd": (self.n_experts_held, f, e),
-                "shared_wg": (e, fs), "shared_wu": (e, fs),
-                "shared_wd": (fs, e), "shared_gate": (e, 1),
-            },
+                **gated_experts, **shared_expert, "shared_gate": (e, 1)},
             "rattn": {
                 "norm": (e,), "wq": (e, qd), "wk": (e, qd), "wv": (e, qd),
                 "wo": (qd, e), **post,
@@ -296,12 +385,10 @@ class HybridLMConfig:
                 "q_norm": (self.head_dim,), "k_norm": (self.head_dim,),
                 "wo": (qd, e),
             },
-            "smoe": {
-                "norm": (e,), "router": (e, self.n_experts_routed),
-                "wg": (self.n_experts_held, e, f),
-                "wu": (self.n_experts_held, e, f),
-                "wd": (self.n_experts_held, f, e),
-            },
+            "smoe": gated_experts,
+            "hattn": head_gated(self.heads("hattn")),
+            "wattn": head_gated(self.heads("wattn")),
+            "umoe": {**gated_experts, **shared_expert},
         }
 
 
@@ -385,10 +472,6 @@ class HybridModel(nn.Module):
                 head_dim=cfg.head_dim, rotary_lanes=cfg.rotary_lanes,
                 rope_theta=cfg.rope_theta, eps=cfg.norm_eps,
                 mesh=cfg.mesh), {}),
-            "gmoe": lambda p, x: gated_moe_mixer(
-                p, x, top_k=cfg.top_k, held=cfg.n_experts_held,
-                offset=cfg.expert_offset, tile=cfg.moe_tile,
-                force_level=cfg.router_force_level, mesh=cfg.mesh),
             "rattn": lambda p, x: (rotary_attention_mixer(
                 p, x, heads=cfg.attn_heads, head_dim=cfg.head_dim,
                 rope_theta=cfg.rope_theta, mesh=cfg.mesh), {}),
@@ -400,9 +483,58 @@ class HybridModel(nn.Module):
                 positions=positions, block_diffusion=diffusion_block,
                 mesh=cfg.mesh), {}),
         }
-        mixers["smoe"] = mixers["gmoe"]   # the same layer, fewer leaves
 
-        unit, repetitions = period(cfg.pattern)
+        def gated_experts(scale=1.0):
+            return lambda p, x: gated_moe_mixer(
+                p, x, top_k=cfg.top_k, held=cfg.n_experts_held,
+                offset=cfg.expert_offset, tile=cfg.moe_tile,
+                force_level=cfg.router_force_level, scale=scale,
+                mesh=cfg.mesh)
+
+        # one layer, by its leaves: with a gated shared expert, with none,
+        # with an ungated one beside a scaled routed sum
+        mixers["gmoe"] = mixers["smoe"] = gated_experts()
+        mixers["umoe"] = gated_experts(cfg.routed_scaling)
+
+        def head_gated(kind, window=0, **rotary):
+            """A layer's counters: the query heads it runs (added up over
+            layers and micro-steps) and, windowed, the band and the share of
+            the score square that the banded kernels' walks visit."""
+            heads = cfg.heads(kind)
+
+            def mixer(p, x):
+                out = head_gated_attention_mixer(
+                    p, x, heads=heads, kv_heads=cfg.kv_heads,
+                    head_dim=cfg.head_dim, window=window, mesh=cfg.mesh,
+                    **rotary)
+                if not window:
+                    return out, {"attn/full_heads": jnp.int32(heads)}
+                return out, {
+                    "attn/window_heads": jnp.int32(heads),
+                    "attn/max_window": jnp.int32(window),
+                    "attn/max_window_visited_share": jnp.float32(
+                        window_visited_share(x.shape[1], window))}
+
+            return mixer
+
+        if "H" in cfg.pattern:
+            lanes = cfg.rotary_lanes or cfg.head_dim
+            mixers["hattn"] = head_gated(
+                "hattn", rotary_lanes=lanes,
+                rotary_factor=cfg.rotary_attention_factor,
+                frequencies=rotary_frequencies(lanes, cfg.rope_theta)
+                if cfg.yarn_factor == 1.0 else yarn_frequencies(
+                    lanes, cfg.rope_theta, cfg.yarn_factor,
+                    cfg.yarn_original_positions, cfg.yarn_beta_fast,
+                    cfg.yarn_beta_slow))
+        if "W" in cfg.pattern:
+            mixers["wattn"] = head_gated(
+                "wattn", window=cfg.window,
+                rotary_lanes=cfg.head_dim, frequencies=rotary_frequencies(
+                    cfg.head_dim, cfg.window_rope_theta))
+
+        prefix, unit, repetitions, tail = stack_plan(cfg.pattern)
+        scanned = repetitions > 1
 
         def remat(body):
             """A checkpoint is the body of the loop that walks the stack: a
@@ -425,33 +557,60 @@ class HybridModel(nn.Module):
                                        cfg.norm_zero_centered)
                     return x + out.astype(x.dtype), counters
 
-            return remat(apply) if repetitions == 1 else apply
+            return apply
 
-        def one_period(x, params):
-            """The period's layers, each on the next slice of its kind."""
-            seen = dict.fromkeys(params, 0)
-            per_layer = []
-            for c in unit:
-                kind = KINDS[c]
-                p = {k: v[seen[kind]] for k, v in params[kind].items()}
-                seen[kind] += 1
-                x, counters = layer(kind)(p, x)
-                if counters:
-                    per_layer.append(counters)
-            return x, merge_counters(per_layer)
+        def walk(letters, each=lambda body: body):
+            """``(x, params) -> (x, counters)`` over ``letters``' layers, each
+            on the next slice of its kind."""
+            def body(x, params):
+                seen = dict.fromkeys(params, 0)
+                per_layer = []
+                for c in letters:
+                    kind = KINDS[c]
+                    p = {k: v[seen[kind]] for k, v in params[kind].items()}
+                    seen[kind] += 1
+                    x, counters = each(layer(kind))(p, x)
+                    if counters:
+                        per_layer.append(counters)
+                return x, merge_counters(per_layer)
+
+            return body
+
+        def slices(letters, before, times=1):
+            """The stacked leaves' slices for ``times`` runs of ``letters``
+            that stand after ``before`` in the pattern."""
+            out = {}
+            for kind in {KINDS[c] for c in letters}:
+                lo = sum(KINDS[c] == kind for c in before)
+                n = sum(KINDS[c] == kind for c in letters) * times
+                out[kind] = {k: v if (lo, n) == (0, v.shape[0])
+                             else v[lo:lo + n]
+                             for k, v in params[kind].items()}
+            return out
 
         def one_pass(x):
-            """The whole pattern and the final norm."""
-            if repetitions == 1:
-                x, counters = one_period(x, params)
+            """The whole pattern and the final norm: the layers before the
+            scanned run unrolled, the run, the layers after it; a pattern
+            that scans nothing is ``unit`` alone, unrolled."""
+            if not scanned:
+                x, counters = walk(unit, remat)(x, params)
             else:
+                found = []
+                x, counters = walk(prefix, remat)(x, slices(prefix, ""))
+                found += [counters] if counters else []
                 with jax.named_scope("stack_scan"):
                     x, counters = jax.lax.scan(
-                        remat(one_period), x, jax.tree_util.tree_map(
+                        remat(walk(unit)), x, jax.tree_util.tree_map(
                             lambda v: v.reshape(
                                 (repetitions, v.shape[0] // repetitions)
-                                + v.shape[1:]), params))
-                counters = merge_counters(counters, axis=0)
+                                + v.shape[1:]),
+                            slices(unit, prefix, repetitions)))
+                found += [merge_counters(counters, axis=0)] if counters else []
+                x, counters = walk(tail, remat)(
+                    x, slices(tail, prefix + unit * repetitions))
+                found += [counters] if counters else []
+                counters = found[0] if len(found) == 1 \
+                    else merge_counters(found)
             with jax.named_scope("stack_norms"):
                 return rms_norm(
                     x, norm_f, cfg.norm_eps, cfg.norm_zero_centered), counters

@@ -17,7 +17,9 @@ Entry points:
     [B, H, S, D]. Masking is a compact per-key validity vector [B, Sk]
     (non-differentiable padding semantics) — NOT a full [B,H,Sq,Sk]
     additive bias, which would reintroduce the O(S^2) footprint the kernel
-    exists to avoid.
+    exists to avoid. Three STRUCTURAL forms are the kernels' own, made from
+    ``iota`` on the sub-tiles their edges cross and skipped elsewhere:
+    ``causal``, ``block_diffusion=B`` and, beside ``causal``, ``window=W``.
   - ``flash_attention_packed``: the same kernels over the fused qkv
     projection's own result [B, S, 3*H*D]; the context comes back
     [B, S, H*D] (``_Operands`` below).
@@ -54,9 +56,11 @@ NEG_INF = -1e30
 # XLA reference implementation
 # ---------------------------------------------------------------------------
 def mha_reference(
-    q, k, v, mask=None, causal=False, sm_scale=None, dropout_rate=0.0, dropout_rng=None
+    q, k, v, mask=None, causal=False, sm_scale=None, dropout_rate=0.0,
+    dropout_rng=None, window=0,
 ):
-    """q,k,v: [B, H, S, D]; mask: additive, broadcastable to [B, H, Sq, Sk]."""
+    """q,k,v: [B, H, S, D]; mask: additive, broadcastable to [B, H, Sq, Sk].
+    ``window=W`` beside ``causal``: a query sees its last W keys only."""
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)
     s = jnp.einsum(
@@ -67,6 +71,8 @@ def mha_reference(
         idx_q = jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 0)
         idx_k = jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 1)
         s = jnp.where(idx_k <= idx_q + (sk - sq), s, NEG_INF)
+        if window:
+            s = jnp.where(idx_k > idx_q + (sk - sq) - window, s, NEG_INF)
     if mask is not None:
         s = s + mask.astype(s.dtype)
     p = jax.nn.softmax(s, axis=-1)
@@ -206,7 +212,7 @@ def pick_subtiles(block_q, block_k, nq, nk, key_major, fused=False):
 
 
 def _clip(x, lo, hi):
-    if isinstance(x, int):
+    if all(isinstance(a, int) for a in (x, lo, hi)):
         return max(lo, min(hi, x))
     return jnp.clip(x, lo, hi)
 
@@ -232,6 +238,79 @@ def _query_range(k_first, sub_k, q_first, sub_q, nsq, diag_offset):
     lo = d // sub_q
     full = -((-(d + sub_k - 1)) // sub_q)
     return _clip(lo, 0, nsq), _clip(full, 0, nsq)
+
+
+# The band (``window=W`` beside ``causal``): query ``i`` sees key ``j`` iff
+# ``i - W < j <= i``: a sliding window of ``W`` keys that ends at the query's
+# own position, the fourth structural form. Two diagonals ``W`` apart bound it,
+# so a walk has a lower bound as well as an upper one, and the sub-tiles that
+# EITHER diagonal crosses build the mask from ``iota``; ``W`` need not divide
+# or be divided by a block or a sub-tile. ``S W - W (W - 1) / 2`` of the ``S^2``
+# pairs are allowed. A grid's INNER axis holds only the blocks the band touches
+# (``_band_blocks``): at W 512 under 1,024-blocks a query block needs its own
+# key block and the one before, 2 steps a query block at any length. ``W >= S``
+# is ``causal`` and lowers to its program.
+
+
+def _band_key_range(q_first, sub_q, k_first, sub_k, nsk, diag_offset, window):
+    """``(lo, a, b, hi)`` under the band: of the ``nsk`` key sub-tiles from
+    key ``k_first``, ``[lo, a)`` are crossed by the band's lower edge for the
+    ``sub_q`` query rows from ``q_first``, ``[a, b)`` are wholly allowed,
+    ``[b, hi)`` are crossed by the causal diagonal (or, where the band is
+    narrower than a sub-tile, by both), the rest are empty."""
+    n_full, hi = _key_range(q_first, sub_q, k_first, sub_k, nsk, diag_offset)
+    # the first key the first row sees, the first that the last row sees
+    first = q_first + diag_offset - k_first - window + 1
+    lo = _clip(first // sub_k, 0, hi)
+    a = _clip(-((-(first + sub_q - 1)) // sub_k), lo, hi)
+    return lo, a, _clip(n_full, a, hi), hi
+
+
+def _band_query_range(k_first, sub_k, q_first, sub_q, nsq, diag_offset, window):
+    """The same seen from ``sub_k`` keys starting at ``k_first`` over a Q block
+    that starts at row ``q_first``: ``(lo, a, b, hi)``, query sub-tiles ``[lo,
+    a)`` crossed by the causal diagonal, ``[a, b)`` wholly allowed, ``[b, hi)``
+    crossed by the band's lower edge."""
+    lo, full = _query_range(k_first, sub_k, q_first, sub_q, nsq, diag_offset)
+    # one past the last row that sees the first key; the last key's
+    past = k_first - diag_offset - q_first + window
+    hi = _clip((past + sub_k - 2) // sub_q + 1, lo, nsq)
+    a = _clip(full, lo, hi)
+    return lo, a, _clip(past // sub_q, a, hi), hi
+
+
+def _band_blocks(outer, block_q, block_k, nq, nk, diag_offset, window,
+                 key_major):
+    """``(first, last)`` of the blocks of the INNER axis that the band
+    touches for block ``outer`` of the outer one: the key blocks of a query
+    block (a query-major grid), or with ``key_major`` the query blocks of a
+    key block. Python ints or traced int32 alike."""
+    if key_major:
+        first = (outer * block_k - diag_offset) // block_q
+        last = (outer * block_k + block_k - 2 - diag_offset + window) // block_q
+        return _clip(first, 0, nq - 1), _clip(last, 0, nq - 1)
+    first = (outer * block_q + diag_offset - window + 1) // block_k
+    last = (outer * block_q + block_q - 1 + diag_offset) // block_k
+    return _clip(first, 0, nk - 1), _clip(last, 0, nk - 1)
+
+
+def _band_inner_steps(block_q, block_k, nq, nk, diag_offset, window, key_major):
+    """Steps of a grid's inner axis under the band: the most blocks that any
+    block of the outer axis needs."""
+    spans = (
+        _band_blocks(o, block_q, block_k, nq, nk, diag_offset, window, key_major)
+        for o in range(nk if key_major else nq)
+    )
+    return max(last - first + 1 for first, last in spans)
+
+
+def band_mask(sq, sk, window):
+    """The band as a dense additive ``[sq, sk]`` float32 array (0 allowed,
+    ``NEG_INF`` not): what the kernels never build. For tests."""
+    rows = jnp.arange(sq)[:, None] + (sk - sq)
+    keys = jnp.arange(sk)[None, :]
+    allowed = (keys <= rows) & (keys > rows - window)
+    return jnp.where(allowed, 0.0, NEG_INF).astype(jnp.float32)
 
 
 # The block-diffusion mask (``block_diffusion=B``): a row of ``2 L``
@@ -345,7 +424,9 @@ def block_diffusion_mask(seq, block):
     return jnp.where(allowed, 0.0, NEG_INF).astype(jnp.float32)
 
 
-def _visited_share(sq, sk, block_k, sub_q, sub_k, causal, block_diffusion=0):
+def _visited_share(
+    sq, sk, block_k, sub_q, sub_k, causal, block_diffusion=0, window=0
+):
     """Share of the ``sq x sk`` score square that lies in sub-tiles a
     kernel visits, from the bounds that set its loops."""
     if not causal and not block_diffusion:
@@ -357,6 +438,10 @@ def _visited_share(sq, sk, block_k, sub_q, sub_k, causal, block_diffusion=0):
                 lo, _, hi = _bd_key_range(
                     q_first, sub_q, k_first, sub_k, nsk, sq // 2,
                     block_diffusion,
+                )
+            elif window:
+                lo, _, _, hi = _band_key_range(
+                    q_first, sub_q, k_first, sub_k, nsk, sk - sq, window
                 )
             else:
                 lo, hi = 0, _key_range(
@@ -377,7 +462,7 @@ PAIR_VMEM_BYTES = 16 * 2**20
 
 def backward_plan(
     sq, sk, block_q, block_k, causal, lanes=128, itemsize=2, budget=None,
-    block_diffusion=0,
+    block_diffusion=0, window=0,
 ):
     """Which backward a call gets, from its shape, its dtype and the VMEM
     budget alone: ``fused`` (one key-major kernel writes dq, dk and dv from
@@ -396,7 +481,7 @@ def backward_plan(
         "backward": "fused" if fused else "pair",
         "sub_q": sub_q, "sub_k": sub_k,
         "visited_share": _visited_share(
-            sq, sk, block_k, sub_q, sub_k, causal, block_diffusion
+            sq, sk, block_k, sub_q, sub_k, causal, block_diffusion, window
         ),
         "dq_vmem_bytes": dq_bytes if fused else 0,
         "reason": None if fused else (
@@ -408,7 +493,7 @@ def backward_plan(
 
 def flash_tiling(
     sq, sk, block_q, block_k, causal, key_major=False, sub_q=None, sub_k=None,
-    block_diffusion=0, **plan,
+    block_diffusion=0, window=0, **plan,
 ):
     """Outer blocks, sub-tiles and the share of the ``sq x sk`` score
     square whose sub-tiles a kernel visits (the forward and dq, or dkv
@@ -424,11 +509,11 @@ def flash_tiling(
         "block_q": block_q, "block_k": block_k, "sub_q": sub_q,
         "sub_k": sub_k,
         "visited_share": _visited_share(
-            sq, sk, block_k, sub_q, sub_k, causal, block_diffusion
+            sq, sk, block_k, sub_q, sub_k, causal, block_diffusion, window
         ),
         "backward": backward_plan(
             sq, sk, block_q, block_k, causal, block_diffusion=block_diffusion,
-            **plan,
+            window=window, **plan,
         ),
     }
 
@@ -436,22 +521,24 @@ def flash_tiling(
 @functools.lru_cache(maxsize=None)
 def _log_tiling(
     sq, sk, d, lanes, dtype, block_q, block_k, causal, use_mask, dropout,
-    block_diffusion=0,
+    block_diffusion=0, window=0,
 ):
     t = flash_tiling(
         sq, sk, block_q, block_k, causal, lanes=lanes,
         itemsize=jnp.dtype(dtype).itemsize, block_diffusion=block_diffusion,
+        window=window,
     )
     b = t["backward"]
     logger.debug(
         "flash_tiling sq=%d sk=%d d=%d %s causal=%s mask=%s dropout=%s "
         "block=%dx%d sub=%dx%d visited_share=%.4f "
-        "backward=%s bwd_sub=%dx%d bwd_visited_share=%.4f dq_vmem_bytes=%d%s%s",
+        "backward=%s bwd_sub=%dx%d bwd_visited_share=%.4f dq_vmem_bytes=%d%s%s%s",
         sq, sk, d, dtype, causal, use_mask, dropout, block_q, block_k,
         t["sub_q"], t["sub_k"], t["visited_share"],
         b["backward"], b["sub_q"], b["sub_k"], b["visited_share"],
         b["dq_vmem_bytes"], f" reason={b['reason']!r}" if b["reason"] else "",
         f" block_diffusion={block_diffusion}" if block_diffusion else "",
+        f" window={window}" if window else "",
     )
 
 
@@ -504,7 +591,7 @@ def _block_diffusion_allowed(shape, q_first, k_first, half, block):
 
 def _scores_t(
     k, q, valid, q_first, k_first, *, sm_scale, fold_scale, diagonal,
-    diag_offset, block_diffusion=0, half=0,
+    diag_offset, block_diffusion=0, half=0, window=0,
 ):
     """One transposed score sub-tile ``s_t = k q^T`` ([keys, queries]) with
     causal, block-diffusion and key-validity masking. ``diagonal``: the
@@ -525,7 +612,10 @@ def _scores_t(
     elif diagonal:
         keys = jax.lax.broadcasted_iota(jnp.int32, s_t.shape, 0) + k_first
         rows = jax.lax.broadcasted_iota(jnp.int32, s_t.shape, 1) + q_first
-        s_t = jnp.where(keys <= rows + diag_offset, s_t, NEG_INF)
+        allowed = keys <= rows + diag_offset
+        if window:
+            allowed &= keys > rows + (diag_offset - window)
+        s_t = jnp.where(allowed, s_t, NEG_INF)
     if valid is not None:
         s_t = jnp.where(valid > 0, s_t, NEG_INF)
     return s_t
@@ -597,13 +687,26 @@ class _Tiles:
     def __init__(
         self, q_axis, *, sm_scale, causal, block_q, block_k, sub_q, sub_k,
         nq, nk, diag_offset, dropout_rate, use_mask, head_dim, heads_a_block,
-        use_bias, block_diffusion=0,
+        use_bias, block_diffusion=0, window=0,
     ):
         self.group = pl.program_id(0)
         self.block_diffusion, self.half = block_diffusion, nq * block_q // 2
+        self.window, self.key_major = window, q_axis == 2
         self.use_bias = use_bias
-        self.iq = pl.program_id(q_axis) if nq > 1 else 0
-        self.ik = pl.program_id(3 - q_axis) if nk > 1 else 0
+        if window:
+            # the inner axis walks the blocks the band touches, from the
+            # first that this block of the outer axis needs
+            self.band = (block_q, block_k, nq, nk, diag_offset, window)
+            self.steps = _band_inner_steps(*self.band, self.key_major)
+            self.step = pl.program_id(2) if self.steps > 1 else 0
+            outer = (
+                pl.program_id(1) if (nk if self.key_major else nq) > 1 else 0
+            )
+            at = _band_blocks(outer, *self.band, self.key_major)[0] + self.step
+            self.iq, self.ik = (at, outer) if self.key_major else (outer, at)
+        else:
+            self.iq = pl.program_id(q_axis) if nq > 1 else 0
+            self.ik = pl.program_id(3 - q_axis) if nk > 1 else 0
         self.causal, self.diag_offset = causal, diag_offset
         self.block_q, self.block_k = block_q, block_k
         self.sub_q, self.sub_k = sub_q, sub_k
@@ -620,7 +723,7 @@ class _Tiles:
         self.scores = functools.partial(
             _scores_t, sm_scale=sm_scale, fold_scale=self.fold_scale,
             diag_offset=diag_offset, block_diffusion=block_diffusion,
-            half=self.half,
+            half=self.half, window=window,
         )
         self.guard = use_mask or diag_offset < 0
         # whole blocks above the diagonal (that the mask empties) are skipped
@@ -631,11 +734,45 @@ class _Tiles:
                 self.half, block_diffusion,
             )
             self.run = hi > lo
+        elif window:
+            # a step past the last block that the band touches does nothing
+            self.run = (
+                (self.ik * block_k
+                 <= self.iq * block_q + (block_q - 1) + diag_offset)
+                & (self.ik * block_k + (block_k - 1)
+                   > self.iq * block_q + diag_offset - window)
+                & (self.iq < nq)
+            )
         elif causal:
             self.run = (
                 self.ik * block_k
                 <= self.iq * block_q + (block_q - 1) + diag_offset
             )
+
+    # where a walk of the inner axis starts and ends: the first and last
+    # block of it, or under the band the first and last step
+    @property
+    def first_step(self):
+        if self.window:
+            return self.step == 0
+        return (self.iq if self.key_major else self.ik) == 0
+
+    @property
+    def last_step(self):
+        if self.window:
+            return self.step == self.steps - 1
+        if self.key_major:
+            return self.iq == self.nq - 1
+        return self.ik == self.nk - 1
+
+    def dq_edge(self, last):
+        """The fused backward's dq rows of a Q block are zeroed in the first
+        key block's step that holds them and cast out in the last one's:
+        whether this step is that one."""
+        if self.window:
+            return self.run & (
+                self.ik == _band_blocks(self.iq, *self.band, False)[last])
+        return self.ik == (self.nk - 1 if last else 0)
 
     def exp(self, s_t, stat, diagonal=False):
         """``_exp_t`` with this call's guard. Under the block-diffusion mask
@@ -645,7 +782,8 @@ class _Tiles:
         says which sub-tiles are crossed."""
         return _exp_t(
             s_t, stat,
-            self.guard or (bool(self.block_diffusion) and diagonal),
+            self.guard
+            or (bool(self.block_diffusion or self.window) and diagonal),
         )
 
     def heads(self):
@@ -696,6 +834,13 @@ class _Tiles:
                 self.q_first(r), self.sub_q, self.k_first(0), self.sub_k,
                 self.nsk, self.half, self.block_diffusion,
             )
+        elif self.window:
+            lo, a, n_full, hi = _band_key_range(
+                self.q_first(r), self.sub_q, self.k_first(0), self.sub_k,
+                self.nsk, self.diag_offset, self.window,
+            )
+            carry = _span(lo, a, functools.partial(step, diagonal=True), carry)
+            lo = a
         elif self.causal:
             n_full, hi = _key_range(
                 self.q_first(r), self.sub_q, self.k_first(0), self.sub_k,
@@ -714,6 +859,14 @@ class _Tiles:
                 self.k_first(c), self.sub_k, self.q_first(0), self.sub_q,
                 self.nsq, self.half, self.block_diffusion,
             )
+        elif self.window:
+            lo, full, b, hi = _band_query_range(
+                self.k_first(c), self.sub_k, self.q_first(0), self.sub_q,
+                self.nsq, self.diag_offset, self.window,
+            )
+            carry = _span(lo, full, functools.partial(step, diagonal=True), carry)
+            carry = _span(full, b, functools.partial(step, diagonal=False), carry)
+            return _span(b, hi, functools.partial(step, diagonal=True), carry)
         elif self.causal:
             lo, full = _query_range(
                 self.k_first(c), self.sub_k, self.q_first(0), self.sub_q,
@@ -729,7 +882,7 @@ def _fwd_kernel(
 ):
     t = _Tiles(1, **static)
 
-    @_when(t.ik == 0)
+    @_when(t.first_step)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
@@ -779,7 +932,7 @@ def _fwd_kernel(
                     r, k_step, (m_scr[hh, r], l_scr[hh, r], acc_scr[r, lanes, :])
                 )
 
-    @_when(t.ik == t.nk - 1)
+    @_when(t.last_step)
     def _finalize():
         for r in range(t.nsq):
             for hh, lanes in t.heads():
@@ -798,7 +951,7 @@ def _bwd_dq_kernel(
 ):
     t = _Tiles(1, **static)
 
-    @_when(t.ik == 0)
+    @_when(t.first_step)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
@@ -841,7 +994,7 @@ def _bwd_dq_kernel(
 
                 dq_scr[r, lanes, :] = t.over_keys(r, k_step, dq_scr[r, lanes, :])
 
-    @_when(t.ik == t.nk - 1)
+    @_when(t.last_step)
     def _finalize():
         for r in range(t.nsq):
             dq_ref[0, _rows(r, t.sub_q), :] = (
@@ -871,12 +1024,12 @@ def _bwd_dkv_kernel(
         """Sub-tile ``r`` of this Q block among the whole sequence's."""
         return t.iq * t.nsq + r
 
-    @_when(fused and t.ik == 0)
+    @_when(fused and t.dq_edge(0))
     def _init_dq():
         for r in range(t.nsq):
             dq_scr[dq_rows(r)] = jnp.zeros(dq_scr.shape[1:], dq_scr.dtype)
 
-    @_when(t.iq == 0)
+    @_when(t.first_step)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
@@ -936,13 +1089,13 @@ def _bwd_dkv_kernel(
                     c, q_step, (dk_scr[hh, keys, :], dv_scr[hh, keys, :])
                 )
 
-    @_when(t.iq == t.nq - 1)
+    @_when(t.last_step)
     def _finalize():
         for hh, lanes in t.heads():
             dk_ref[0, :, lanes] = (dk_scr[hh] * t.sm_scale).astype(dk_ref.dtype)
             dv_ref[0, :, lanes] = dv_scr[hh].astype(dv_ref.dtype)
 
-    @_when(fused and t.ik == t.nk - 1)
+    @_when(fused and t.dq_edge(1))
     def _finalize_dq():
         for r in range(t.nsq):
             at = dq_rows(r)
@@ -1084,7 +1237,7 @@ def _kvm_column(kv_mask):
 
 def _static(
     ops, sq, sk, block_q, block_k, causal, sm_scale, dropout_rate, use_mask,
-    use_bias, block_diffusion=0,
+    use_bias, block_diffusion=0, window=0,
 ):
     """The keyword arguments of ``_Tiles`` that the kernels share."""
     return dict(
@@ -1092,20 +1245,35 @@ def _static(
         nq=sq // block_q, nk=sk // block_k, diag_offset=sk - sq,
         dropout_rate=dropout_rate, use_mask=use_mask, use_bias=use_bias,
         head_dim=ops.head_dim, heads_a_block=ops.heads_a_block,
-        block_diffusion=block_diffusion,
+        block_diffusion=block_diffusion, window=window,
     )
 
 
-def _needed_blocks(sq, block_q, block_k, block_diffusion, key_major):
-    """``g -> block`` for the operands a grid's INNER axis walks (the keys'
-    of a query-major grid ``(group, iq, ik)``, the queries' of a key-major
-    one ``(group, ik, iq)``), or None where every step holds its own."""
+def _needed_blocks(common, block_diffusion, key_major):
+    """``(g -> block, steps)`` for the operands a grid's INNER axis walks
+    (the keys' of a query-major grid ``(group, iq, ik)``, the queries' of a
+    key-major one ``(group, ik, iq)``) and how many steps that axis has; the
+    map is None where every step holds its own block. Under the band the
+    axis has only as many steps as a block of the outer axis needs blocks
+    (``_band_blocks``), and a step past a walk's last holds the last."""
+    block_q, block_k, nq, nk = (
+        common[k] for k in ("block_q", "block_k", "nq", "nk"))
+    steps = nq if key_major else nk
+    if common["window"]:
+        band = (block_q, block_k, nq, nk, common["diag_offset"],
+                common["window"])   # ``_Tiles.band``
+
+        def needed(g):
+            first, last = _band_blocks(g[1], *band, key_major)
+            return jnp.minimum(first + g[2], last)
+
+        return needed, _band_inner_steps(*band, key_major)
     if not block_diffusion:
-        return None
+        return None, steps
     pick = _bd_query_block if key_major else _bd_key_block
     return lambda g: pick(
-        g[1], g[2], block_q, block_k, sq // 2, block_diffusion
-    )
+        g[1], g[2], block_q, block_k, nq * block_q // 2, block_diffusion
+    ), steps
 
 
 def _bias_row(bias, dtype):
@@ -1117,7 +1285,7 @@ def _bias_row(bias, dtype):
 
 def _forward_call(
     ops, q, k, v, bias, kv_mask, seed, sq, sk, causal, sm_scale, dropout_rate,
-    block_q, block_k, block_diffusion=0,
+    block_q, block_k, block_diffusion=0, window=0,
 ):
     """``flash_fwd`` over all B x H heads. ``q``/``k``/``v``: three
     ``[B*H, S, D]`` arrays, or the packed projection three times, then
@@ -1127,20 +1295,20 @@ def _forward_call(
     use_bias = bias is not None
     common = _static(
         ops, sq, sk, block_q, block_k, causal, sm_scale, dropout_rate,
-        kv_mask is not None, use_bias, block_diffusion,
+        kv_mask is not None, use_bias, block_diffusion, window,
     )
     nq, nk = common["nq"], common["nk"]
     sub_q, sub_k = pick_subtiles(block_q, block_k, nq, nk, key_major=False)
     interpret = not device.on_tpu()
     _log_tiling(
         sq, sk, d, ops.block_lanes, str(dtype), block_q, block_k, causal,
-        kv_mask is not None, dropout_rate > 0.0, block_diffusion,
+        kv_mask is not None, dropout_rate > 0.0, block_diffusion, window,
     )
     hb, nsq = ops.heads_a_block, block_q // sub_q
-    keys = _needed_blocks(sq, block_q, block_k, block_diffusion, False)
+    keys, steps = _needed_blocks(common, block_diffusion, False)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, sub_q=sub_q, sub_k=sub_k, **common),
-        grid=(ops.groups, nq, nk),
+        grid=(ops.groups, nq, steps),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             ops.spec("q", block_q, part=0),
@@ -1177,7 +1345,7 @@ def _seed_array(seed):
 
 def _backward_calls(
     ops, q, k, v, bias, kv_mask, seed, do, lse, delta, sq, sk, causal,
-    sm_scale, dropout_rate, block_q, block_k, block_diffusion=0,
+    sm_scale, dropout_rate, block_q, block_k, block_diffusion=0, window=0,
 ):
     """(dq, dk, dv) in the operands' layout, from the backward that
     ``backward_plan`` chooses: the fused kernel, which runs as
@@ -1188,14 +1356,14 @@ def _backward_calls(
     use_mask, use_bias = kv_mask is not None, bias is not None
     common = _static(
         ops, sq, sk, block_q, block_k, causal, sm_scale, dropout_rate,
-        use_mask, use_bias, block_diffusion,
+        use_mask, use_bias, block_diffusion, window,
     )
     nq, nk = common["nq"], common["nk"]
     interpret = not device.on_tpu()
     hb, lanes = ops.heads_a_block, ops.block_lanes
     plan = backward_plan(
         sq, sk, block_q, block_k, causal, lanes, jnp.dtype(dtype).itemsize,
-        block_diffusion=block_diffusion,
+        block_diffusion=block_diffusion, window=window,
     )
 
     def call(kernel, name, key_major, sub, out_specs, out_shape, scratch,
@@ -1206,11 +1374,11 @@ def _backward_calls(
         rows = (ops.batch * ops.heads, sq // sub_q, 1, sub_q)
         # the inner axis's operands: queries of the key-major walk, keys
         # of the query-major one
-        inner = _needed_blocks(sq, block_q, block_k, block_diffusion, key_major)
+        inner, steps = _needed_blocks(common, block_diffusion, key_major)
         qs, ks = (inner, None) if key_major else (None, inner)
         return pl.pallas_call(
             functools.partial(kernel, sub_q=sub_q, sub_k=sub_k, **common),
-            grid=(ops.groups, nk, nq) if key_major else (ops.groups, nq, nk),
+            grid=(ops.groups, nk if key_major else nq, steps),
             in_specs=[
                 pl.BlockSpec(memory_space=pltpu.SMEM),
                 ops.spec("q", block_q, key_major, part=0, needed=qs),
@@ -1289,34 +1457,34 @@ def _name_residuals(out, lse):
 
 
 # ---- split operands: q, k, v [B, H, S, D] ---------------------------------
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
 def _flash(
     q, k, v, kv_mask, seed, causal, sm_scale, dropout_rate, block_q, block_k,
-    block_diffusion=0,
+    block_diffusion=0, window=0,
 ):
     return _flash_fwd(
         q, k, v, kv_mask, seed, causal, sm_scale, dropout_rate, block_q,
-        block_k, block_diffusion,
+        block_k, block_diffusion, window,
     )[0]
 
 
 def _flash_fwd(
     q, k, v, kv_mask, seed, causal, sm_scale, dropout_rate, block_q, block_k,
-    block_diffusion=0,
+    block_diffusion=0, window=0,
 ):
     b, h, sq, d = q.shape
     out, lse = _forward_call(
         _Operands(b, h, d, packed=False),
         _reshape_bh(q), _reshape_bh(k), _reshape_bh(v), None, kv_mask, seed,
         sq, k.shape[2], causal, sm_scale, dropout_rate, block_q, block_k,
-        block_diffusion,
+        block_diffusion, window,
     )
     out, lse = _name_residuals(out.reshape(b, h, sq, d), lse)
     return out, (q, k, v, kv_mask, seed, out, lse)
 
 
 def _flash_bwd(
-    causal, sm_scale, dropout_rate, block_q, block_k, block_diffusion,
+    causal, sm_scale, dropout_rate, block_q, block_k, block_diffusion, window,
     residuals, g,
 ):
     q, k, v, kv_mask, seed, out, lse = residuals
@@ -1330,7 +1498,7 @@ def _flash_bwd(
         _Operands(b, h, d, packed=False),
         _reshape_bh(q), _reshape_bh(k), _reshape_bh(v), None, kv_mask, seed,
         _reshape_bh(g), lse, delta, sq, sk, causal, sm_scale, dropout_rate,
-        block_q, block_k, block_diffusion,
+        block_q, block_k, block_diffusion, window,
     )
     return (
         dq.reshape(b, h, sq, d), dk.reshape(b, h, sk, d),
@@ -1441,6 +1609,43 @@ def block_diffusion_refusal(sq, sk, causal, block_diffusion):
     return None
 
 
+def window_refusal(sq, sk, causal, window, block_diffusion=0):
+    """Why the kernels cannot apply the band to a call, or None where they
+    can (or none is asked for)."""
+    if not window:
+        return None
+    if window < 0:
+        return f"a window of {window} keys"
+    if not causal or block_diffusion:
+        return "a window is a band under causal's diagonal, no other form's"
+    if sq != sk:
+        return f"a band over self-attention's square, not {sq} x {sk}"
+    return None
+
+
+def _checked_window(who, sq, sk, causal, block_diffusion, window):
+    """``window`` as the kernels take it (0 where it reaches past the row:
+    plain causal), once neither structural form refuses the call."""
+    why = block_diffusion_refusal(sq, sk, causal, block_diffusion) \
+        or window_refusal(sq, sk, causal, window, block_diffusion)
+    if why:
+        raise ValueError(f"{who}: {why}")
+    return 0 if window >= sk else window
+
+
+def window_visited_share(seq, window):
+    """Share of a ``seq x seq`` score square that lies in sub-tiles the banded
+    kernels' forward walk visits at the blocks ``attention()`` picks (the
+    backward's: ``flash_tiling``), for a layer's ``attn/...`` counters."""
+    block_q, block_k = _pick_blocks(seq, seq, DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K)
+    if not (block_q and block_k):
+        return 1.0
+    return _visited_share(
+        seq, seq, block_k,
+        *pick_subtiles(block_q, block_k, seq // block_q, seq // block_k, False),
+        True, window=window if window < seq else 0)
+
+
 def _pick_blocks(sq, sk, block_q, block_k, block_diffusion=0):
     """The largest dividing blocks; under the block-diffusion mask those
     that divide a HALF of the row, so that a block lies in one half."""
@@ -1453,6 +1658,7 @@ def flash_attention(
     q, k, v, mask=None, kv_mask=None, causal=False, sm_scale=None,
     dropout_rate=0.0, dropout_seed=0,
     block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K, block_diffusion=0,
+    window=0,
 ):
     """Blockwise flash attention. q,k,v: [B, H, S, D].
 
@@ -1462,14 +1668,15 @@ def flash_attention(
     ``block_diffusion=B``: the rows are ``[noisy ; clean]`` halves of one
     sequence in blocks of ``B`` and the kernels apply that mask
     (``block_diffusion_mask``) from the grid position, skipping what it
-    empties.
+    empties. ``window=W`` beside ``causal``: a query sees its last W keys,
+    itself among them (``band_mask``); the walks and the grids skip what lies
+    outside the band, and ``W >= S`` is plain ``causal``.
     """
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)
     sq, sk = q.shape[2], k.shape[2]
-    why = block_diffusion_refusal(sq, sk, causal, block_diffusion)
-    if why:
-        raise ValueError(f"flash_attention: {why}")
+    window = _checked_window(
+        "flash_attention", sq, sk, causal, block_diffusion, window)
     # shrink to the largest dividing block so e.g. seq 768 runs with
     # 256-blocks instead of failing the divisibility check on the default
     block_q, block_k = _pick_blocks(sq, sk, block_q, block_k, block_diffusion)
@@ -1489,7 +1696,7 @@ def flash_attention(
     seed = jnp.asarray(dropout_seed, jnp.int32)
     return _flash(
         q, k, v, kv_mask, seed, causal, float(sm_scale), float(dropout_rate),
-        int(block_q), int(block_k), int(block_diffusion),
+        int(block_q), int(block_k), int(block_diffusion), int(window),
     )
 
 
@@ -1560,6 +1767,7 @@ def flash_attention_sharded(
     q, k, v, mesh, kv_mask=None, causal=False, sm_scale=None,
     dropout_rate=0.0, dropout_seed=0,
     block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K, block_diffusion=0,
+    window=0,
 ):
     """Flash attention under a data/model-parallel mesh via ``shard_map``.
 
@@ -1594,7 +1802,7 @@ def flash_attention_sharded(
         return _flash(
             q, k, v, kvm if use_mask else None, seed, causal,
             float(sm_scale), float(dropout_rate), int(block_q), int(block_k),
-            int(block_diffusion),
+            int(block_diffusion), int(window),
         )
 
     return jax.shard_map(
@@ -1778,7 +1986,7 @@ def attention_packed(
 
 def attention(
     q, k, v, mask=None, causal=False, sm_scale=None, dropout_rate=0.0,
-    dropout_rng=None, use_flash=True, mesh=None, block_diffusion=0,
+    dropout_rng=None, use_flash=True, mesh=None, block_diffusion=0, window=0,
 ):
     """Dispatcher: flash kernel when shapes tile cleanly and the mask is a
     padding mask; XLA reference otherwise (incl. learned additive biases,
@@ -1790,19 +1998,21 @@ def attention(
     the fused qkv projection's result takes ``attention_packed``.
     ``block_diffusion=B``: the block-diffusion mask over ``[noisy ; clean]``
     rows, inside the kernels; where no kernel runs, ``block_diffusion_mask``
-    as a dense additive mask on the XLA path."""
+    as a dense additive mask on the XLA path. ``window=W`` beside ``causal``:
+    the band of the last W keys, inside the kernels or as two comparisons of
+    ``mha_reference``."""
     why = "q, k and v arrive as separate [B, H, S, D] arrays"
     refusal = packed_refusal(q.shape[1], q.shape[-1])
     return _attention_split(
         q, k, v, mask, causal, sm_scale, dropout_rate, dropout_rng,
         use_flash, mesh, f"{why}; {refusal}" if refusal else why,
-        block_diffusion,
+        block_diffusion, window,
     )
 
 
 def _attention_split(
     q, k, v, mask, causal, sm_scale, dropout_rate, dropout_rng, use_flash,
-    mesh, why_split, block_diffusion=0,
+    mesh, why_split, block_diffusion=0, window=0,
 ):
     if k.shape[1] != q.shape[1]:
         # grouped-query heads: each kv head serves q_heads / kv_heads query
@@ -1812,9 +2022,8 @@ def _attention_split(
                 for t in (k, v))
     b, heads, sq, d = q.shape
     sk = k.shape[2]
-    refusal = block_diffusion_refusal(sq, sk, causal, block_diffusion)
-    if refusal:
-        raise ValueError(f"attention: {refusal}")
+    window = _checked_window(
+        "attention", sq, sk, causal, block_diffusion, window)
     why_not, kv_mask, bq, bk, dropout_rate = _flash_gate(
         sq, sk, mask, dropout_rate, dropout_rng, use_flash, block_diffusion
     )
@@ -1834,13 +2043,14 @@ def _attention_split(
                 q, k, v, mesh, kv_mask=kv_mask, causal=causal,
                 sm_scale=sm_scale, dropout_rate=dropout_rate,
                 dropout_seed=seed, block_q=bq, block_k=bk,
-                block_diffusion=block_diffusion,
+                block_diffusion=block_diffusion, window=window,
             )
         if route == "local":
             return flash_attention(
                 q, k, v, kv_mask=kv_mask, causal=causal, sm_scale=sm_scale,
                 dropout_rate=dropout_rate, dropout_seed=seed,
                 block_q=bq, block_k=bk, block_diffusion=block_diffusion,
+                window=window,
             )
         if device.on_tpu():
             # a shape the kernel could have served is about to pay
@@ -1856,5 +2066,5 @@ def _attention_split(
         mask = dense if mask is None else mask + dense
     return mha_reference(
         q, k, v, mask=mask, causal=causal, sm_scale=sm_scale,
-        dropout_rate=dropout_rate, dropout_rng=dropout_rng,
+        dropout_rate=dropout_rate, dropout_rng=dropout_rng, window=window,
     )

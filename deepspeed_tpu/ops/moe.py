@@ -546,20 +546,26 @@ def _ffn_bwd_kernel(expert_ref, meta_ref, x_ref, g_ref, wt_ref, *refs, form):
 
 
 def _ffn_call(kernel, name, form, expert, meta, rows, mats, out_rows,
-              grads=(), left_open=()):
+              grads=(), left_open=(), part=(0, 1)):
     """One chunk of tiles through a kernel. ``rows``: the arrays that hold
     a tile's rows ``[tiles * stride, width]``; ``out_rows``: the widths and
     dtypes of such results; ``grads``: arrays like ``mats`` in which the
     kernel writes the gradients of the experts whose runs end in this
     chunk, and ``left_open`` the float32 sums ``[1, ...]`` of the run a
-    chunk's end cuts, both in place."""
+    chunk's end cuts, both in place. ``part = (at, parts)``: the call takes
+    part ``at`` of ``parts`` of every matrix's intermediate width (the last
+    axis of the input matrices, the first of the output one) and of every
+    gradient, and ``left_open`` holds that part's sums (``_width_parts``)."""
     tiles = expert.shape[0]
     stride = rows[0].shape[0] // tiles
-    if device.on_tpu() and any(width % 128 for width in mats[0].shape[1:]):
+    at, parts = part
+    if device.on_tpu() and any(
+            width % 128 for width in (
+                *mats[0].shape[1:], mats[0].shape[2] // parts)):
         raise ValueError(
             "on the chip the grouped expert kernels take an expert's "
             "matrices in whole 128-lane blocks, not "
-            f"{tuple(mats[0].shape[1:])}")
+            f"{tuple(mats[0].shape[1:])} in {parts} part(s)")
 
     def at_tile(t, meta):
         return jnp.maximum(jnp.minimum(t, meta[0] - 1), 0)
@@ -568,10 +574,19 @@ def _ffn_call(kernel, name, form, expert, meta, rows, mats, out_rows,
         return pl.BlockSpec(
             (stride, width), lambda t, e, m: (at_tile(t, m), 0))
 
-    def expert_spec(mat, lead=None):
-        return pl.BlockSpec(
-            (lead,) + mat.shape[1:],
-            lambda t, e, m: (e[at_tile(t, m)], 0, 0))
+    def expert_specs(like, lead=None):
+        """One spec a matrix: the intermediate width is the last axis of the
+        input matrices and the first of the output one, the last of them."""
+        specs = []
+        for i, mat in enumerate(like):
+            down, across = mat.shape[1:]
+            specs.append(pl.BlockSpec(
+                (lead, down // parts, across),
+                lambda t, e, m: (e[at_tile(t, m)], at, 0))
+                if i == len(like) - 1 else pl.BlockSpec(
+                (lead, down, across // parts),
+                lambda t, e, m: (e[at_tile(t, m)], 0, at)))
+        return specs
 
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     outs = [jax.ShapeDtypeStruct((tiles * stride, w), d) for w, d in out_rows]
@@ -581,10 +596,10 @@ def _ffn_call(kernel, name, form, expert, meta, rows, mats, out_rows,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(tiles,),
             in_specs=[tile_spec(r.shape[1]) for r in rows]
-            + [expert_spec(m) for m in mats]
+            + expert_specs(mats)
             + [in_hbm] * (len(left_open) + len(grads)),
             out_specs=[tile_spec(w) for w, _ in out_rows]
-            + [expert_spec(m, 1) for m in grads] + [in_hbm] * len(left_open),
+            + expert_specs(grads, 1) + [in_hbm] * len(left_open),
             scratch_shapes=[
                 pltpu.VMEM(m.shape, jnp.float32) for m in left_open]
             + [pltpu.SemaphoreType.DMA(())] * bool(grads)),
@@ -601,6 +616,30 @@ def _ffn_call(kernel, name, form, expert, meta, rows, mats, out_rows,
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=not device.on_tpu(), name=name,
     )(expert, meta, *rows, *mats, *left_open, *grads)
+
+
+# What the backward kernel holds of ONE expert in VMEM: its matrices and their
+# gradients' output blocks, two buffers each, and the float32 sums. Above this
+# an expert is taken in parts of its intermediate width, one call of the
+# kernel a part: the gated FFN and its backward are sums over that width, so
+# the parts' dx and routing-weight gradients add up and each part writes its
+# own columns of the matrices' gradients. 3 x 2048 x 768 (56.6 MB) stays whole;
+# 3 x 3072 x 1024 (113 MB, over the chip's 128 MiB with the rows) takes two.
+_BWD_EXPERT_BUDGET = 64 * 2 ** 20
+
+
+def _width_parts(mats):
+    """Parts of the intermediate width in which ``moe_ffn_bwd`` takes an
+    expert's matrices: the fewest (a power of two, whole 128-lane blocks each
+    on the chip) under ``_BWD_EXPERT_BUDGET``."""
+    held, width = mats[0].shape[0], mats[-1].shape[1]
+    need = sum(m.size // held for m in mats) * (
+        4 * mats[0].dtype.itemsize + 4)
+    parts, lanes = 1, 128 if device.on_tpu() else 1
+    while need > parts * _BWD_EXPERT_BUDGET \
+            and width % (2 * parts * lanes) == 0:
+        parts *= 2
+    return parts
 
 
 def _row_stride(tile, dtype):
@@ -710,23 +749,35 @@ def _grouped_bwd(tile, form, chunk_tiles, mesh, res, g):
             expert, meta, key, tok, wt = _chunk_of_tiles(
                 c, tiles, tile, stride, plan, weights_t)
             x, gy = _rows_of(u, tok), _rows_of(g, tok)
-            dx, dw_rows, *dws = _ffn_call(
-                _ffn_bwd_kernel, "moe_ffn_bwd", form, expert, meta,
-                (x, gy, wt), mats,
-                [(u.shape[1], jnp.float32), (1, jnp.float32)], dmats,
-                left_open)
+            dx = dw_rows = None
+            for at in range(parts):
+                dx_part, dw_part, *dws = _ffn_call(
+                    _ffn_bwd_kernel, "moe_ffn_bwd", form, expert, meta,
+                    (x, gy, wt), mats,
+                    [(u.shape[1], jnp.float32), (1, jnp.float32)], dmats,
+                    left_open[at], part=(at, parts))
+                dmats = tuple(dws[:len(mats)])
+                left_open = (*left_open[:at], tuple(dws[len(mats):]),
+                             *left_open[at + 1:])
+                dx = dx_part if dx is None else dx + dx_part
+                dw_rows = dw_part if dw_rows is None else dw_rows + dw_part
             # the keys are sorted and no two alike: one write an assignment
             dwt = dwt.at[key].add(
                 dw_rows[:, 0], mode="drop", indices_are_sorted=True,
                 unique_indices=True)
-            return (du.at[tok].add(dx, mode="drop"), tuple(dws[:len(mats)]),
-                    tuple(dws[len(mats):]), dwt)
+            return du.at[tok].add(dx, mode="drop"), dmats, left_open, dwt
 
+        # a part's sums: the input matrices' columns, the output one's rows
+        parts = _width_parts(mats)
+        open_shapes = [
+            (1, m.shape[1], m.shape[2] // parts) for m in mats[:-1]] + [
+            (1, mats[-1].shape[1] // parts, mats[-1].shape[2])]
         # an expert that no tile names keeps these zeros
         du, dmats, _, dwt = jax.lax.fori_loop(0, n_chunks, chunk, (
             jnp.zeros(u.shape, jnp.float32),
             tuple(jnp.zeros_like(m) for m in mats),
-            tuple(jnp.zeros((1,) + m.shape[1:], jnp.float32) for m in mats),
+            tuple(tuple(jnp.zeros(shape, jnp.float32) for shape in open_shapes)
+                  for _ in range(parts)),
             jnp.zeros((weights_t.size,), jnp.float32)))
         return du.astype(u.dtype), dmats, dwt.reshape(weights_t.shape)
 
@@ -801,16 +852,19 @@ def latent_moe_mixer(p, x, *, top_k, scale, held, offset, tile,
 
 
 def gated_moe_mixer(p, x, *, top_k, held, offset, tile, force_level=False,
-                    mesh=None):
+                    scale=1.0, mesh=None):
     """One mixture of SiLU-gated experts at the model's own width, dropping
     no token, over normalized ``x`` [B, S, E] -> (out [B, S, E], counters).
     ``p``: router [E, routed], wg and wu [held, E, F], wd [held, F, E],
     shared_wg and shared_wu [E, Fs], shared_wd [Fs, E], shared_gate [E, 1].
-    Softmax routing (``route_softmax_topk``), no selection bias and no
-    scaling; the shared expert is weighed by ``sigmoid(x shared_gate)``. A
-    family without a shared expert has no ``shared_*`` leaves and the layer
-    is the routed sum alone (no ``moe_shared`` scope opens). The same plan,
-    loop and counters as ``latent_moe_mixer``."""
+    Softmax routing (``route_softmax_topk``), no selection bias; ``scale``
+    multiplies the routed experts' weighted sum (a family's routed scaling
+    factor, on the experts' output). The shared expert is weighed by
+    ``sigmoid(x shared_gate)``, or added as it is where the family has no
+    ``shared_gate`` leaf. A family without a shared expert has no
+    ``shared_*`` leaves and the layer is the routed sum alone (no
+    ``moe_shared`` scope opens). The same plan, loop and counters as
+    ``latent_moe_mixer``."""
     b, s, e = x.shape
     xt = x.reshape(b * s, e)
     with jax.named_scope("moe_route"):
@@ -818,6 +872,8 @@ def gated_moe_mixer(p, x, *, top_k, held, offset, tile, force_level=False,
             xt, s, lambda xt, level: route_softmax_topk(
                 xt, p["router"], top_k, level=level),
             held, offset, tile, force_level)
+        if scale != 1.0:
+            weights_t = weights_t * scale
     with jax.named_scope("moe_experts"):
         routed = grouped_expert_ffn(
             xt, (p["wg"], p["wu"], p["wd"]), weights_t, plan, tile, "swiglu",
@@ -825,9 +881,11 @@ def gated_moe_mixer(p, x, *, top_k, held, offset, tile, force_level=False,
     if "shared_wg" not in p:
         return routed.astype(x.dtype).reshape(b, s, e), counters
     with jax.named_scope("moe_shared"):
-        gate = jax.nn.sigmoid(jnp.dot(
-            xt, p["shared_gate"], preferred_element_type=jnp.float32))
+        if "shared_gate" in p:
+            gate = jax.nn.sigmoid(jnp.dot(
+                xt, p["shared_gate"], preferred_element_type=jnp.float32))
         shared = (jax.nn.silu(xt @ p["shared_wg"]) * (xt @ p["shared_wu"])) \
             @ p["shared_wd"]
-        out = routed + gate * shared.astype(jnp.float32)
+        shared = shared.astype(jnp.float32)
+        out = routed + (gate * shared if "shared_gate" in p else shared)
     return out.astype(x.dtype).reshape(b, s, e), counters

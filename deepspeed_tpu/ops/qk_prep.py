@@ -96,12 +96,16 @@ def _row_blocks(seq, head_dim):
     return rows, rows and pick_block(rows, max(WALK_ELEMENTS // head_dim, 8))
 
 
-def rotary_tables(angle):
+def rotary_tables(angle, factor=1.0):
     """``angle`` [S, rotary lanes / 2] float32, each row's angles -> the two
     [S, 128 k] tables the kernels multiply by, over the whole 128-lane blocks
-    that hold the rotated lanes: ``cos | cos | 1`` and ``-sin | sin | 0``."""
+    that hold the rotated lanes: ``cos | cos | 1`` and ``-sin | sin | 0``,
+    cosine and sine times ``factor`` (the lanes that pass through keep 1 and
+    0; the backward's rotation by the negated angle reads the same tables)."""
     seq, half = angle.shape
     cos, sin, rest = jnp.cos(angle), jnp.sin(angle), -2 * half % LANES
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     return (
         jnp.concatenate(
             [cos, cos, jnp.ones((seq, rest), jnp.float32)], axis=1),
@@ -273,15 +277,18 @@ def _gain_row(gain, zero_centered):
     return (1.0 + gain if zero_centered else gain)[None, :]
 
 
-def qk_prep(x, gain, angle, *, head_dim, eps=0.0, zero_centered=False):
+def qk_prep(x, gain, angle, *, head_dim, eps=0.0, zero_centered=False,
+            factor=1.0):
     """``apply_rotary(rms_norm(x's heads, gain), rotary_lanes)`` as ``[B,
     heads, S, D]``. ``x`` [B, S, heads * D]: a projection's result. ``gain``
     [D] (``zero_centered``: applied as ``1 + gain``) or None: no norm.
     ``angle`` [S, rotary_lanes / 2] float32: each row's angles
-    (ops/transformer.py:rotary_angles)."""
+    (ops/transformer.py:rotary_angles); ``factor`` on their cosine and sine
+    (``apply_rotary``'s)."""
     return _prep(
         x, None if gain is None else _gain_row(gain, zero_centered),
-        *rotary_tables(angle), int(head_dim), 2 * angle.shape[1], float(eps))
+        *rotary_tables(angle, factor), int(head_dim), 2 * angle.shape[1],
+        float(eps))
 
 
 # ---- [B, S, W] in place: rotation of the first heads ------------------------

@@ -369,29 +369,59 @@ def rotary_frequencies(lanes, theta):
         float(theta) ** (-np.arange(0, lanes, 2) / lanes), np.float32)
 
 
-def rotary_angles(seq, lanes, theta, positions=None):
+def yarn_frequencies(lanes, theta, factor, original_positions,
+                     beta_fast=32.0, beta_slow=1.0):
+    """[lanes / 2] float32 inverse frequencies under YaRN (Peng et al. 2023,
+    arXiv:2309.00071), made on the host in float64: frequency i is a blend of
+    ``theta^(-2i / lanes)`` (kept where a lane turns more than ``beta_fast``
+    times over ``original_positions``) and that over ``factor`` (where it
+    turns fewer than ``beta_slow`` times), by a linear ramp over the lane
+    pairs between the two correction dimensions (floor and ceiling, kept
+    inside the lanes)."""
+    plain = float(theta) ** (-np.arange(0, lanes, 2) / lanes)
+
+    def correction(turns):
+        return lanes * np.log(original_positions / (turns * 2 * np.pi)) / (
+            2 * np.log(float(theta)))
+
+    low = max(np.floor(correction(beta_fast)), 0)
+    high = min(np.ceil(correction(beta_slow)), lanes - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(lanes // 2) - low) / (high - low), 0, 1)
+    return np.asarray(plain / factor * ramp + plain * (1 - ramp), np.float32)
+
+
+def rotary_angles(seq, lanes, theta, positions=None, frequencies=None):
     """[S, lanes / 2] float32 angles ``position * frequency``. ``positions``
     [S]: each row's position id (the two halves of a block-diffusion row
-    carry the same ids); None is 0..S-1."""
+    carry the same ids); None is 0..S-1. ``frequencies`` [lanes / 2]: a
+    table that takes ``rotary_frequencies``' place (``yarn_frequencies``)."""
     if positions is None:
         positions = jnp.arange(seq, dtype=jnp.float32)
-    return positions.astype(jnp.float32)[:, None] \
-        * rotary_frequencies(lanes, theta)[None, :]
+    if frequencies is None:
+        frequencies = rotary_frequencies(lanes, theta)
+    return positions.astype(jnp.float32)[:, None] * frequencies[None, :]
 
 
-def apply_rotary(x, lanes, theta, seq_axis=2, positions=None):
+def apply_rotary(x, lanes, theta, seq_axis=2, positions=None,
+                 frequencies=None, factor=1.0):
     """Rotary position embedding in the half-split convention on the first
     ``lanes`` lanes of each head of ``x`` [B, H, S, D] (lane i pairs with
-    lane i + lanes / 2; the lanes after stay as they are), no scaling,
-    angles and rotation in float32, at ``rotary_angles``' positions.
-    ``seq_axis=1``: ``x`` is [B, S, H, D], as a projection leaves it. The
-    reference of the kernels of ops/qk_prep.py, and the path of every shape
-    ``qk_prep_path`` refuses."""
+    lane i + lanes / 2; the lanes after stay as they are), angles and
+    rotation in float32, at ``rotary_angles``' positions and frequencies;
+    ``factor`` multiplies cosine and sine, so the rotated lanes and not the
+    others (YaRN's attention factor). ``seq_axis=1``: ``x`` is [B, S, H, D],
+    as a projection leaves it. The reference of the kernels of
+    ops/qk_prep.py, and the path of every shape ``qk_prep_path`` refuses."""
     half = lanes // 2
-    angle = rotary_angles(x.shape[seq_axis], lanes, theta, positions)
+    angle = rotary_angles(
+        x.shape[seq_axis], lanes, theta, positions, frequencies)
     if seq_axis == 1:
         angle = angle[:, None, :]
     cos, sin = jnp.cos(angle), jnp.sin(angle)
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     xs = x.astype(jnp.float32)
     x1, x2 = xs[..., :half], xs[..., half:lanes]
     return jnp.concatenate(
@@ -399,13 +429,15 @@ def apply_rotary(x, lanes, theta, seq_axis=2, positions=None):
         axis=-1).astype(x.dtype)
 
 
-def _qk_prep(projections, gains, head_dim, angle, eps, zero_centered):
+def _qk_prep(projections, gains, head_dim, angle, eps, zero_centered,
+             factor=1.0):
     """q and k [B, heads, S, D] out of their projections' results [B, S,
     heads * D] through the kernels of ops/qk_prep.py: each head's RMS norm
-    with its gain, then rotary by ``angle`` (``rotary_angles``)."""
+    with its gain (None: no norm), then rotary by ``angle``
+    (``rotary_angles``) with ``factor`` on cosine and sine."""
     return tuple(
         qk_prep(t, gain, angle, head_dim=head_dim, eps=eps,
-                zero_centered=zero_centered)
+                zero_centered=zero_centered, factor=factor)
         for t, gain in zip(projections, gains))
 
 
@@ -483,6 +515,49 @@ def rotary_gqa_attention_mixer(p, x, *, heads, kv_heads, head_dim, rope_theta,
                         block_diffusion=block_diffusion)
         return ctx.transpose(0, 2, 1, 3).reshape(b, s, heads * head_dim) \
             @ p["wo"]
+
+
+def head_gated_attention_mixer(p, x, *, heads, kv_heads, head_dim,
+                               rotary_lanes, frequencies, rotary_factor=1.0,
+                               window=0, mesh=None):
+    """Grouped-query attention gated PER HEAD, over normalized ``x`` [B, S,
+    E]: wq [E, heads * D], wk/wv [E, kv_heads * D], wg [E, heads], wo [heads
+    * D, E]; no q/k norm, no bias. Rotary on the first ``rotary_lanes`` lanes
+    of q and k at the ``frequencies`` [rotary_lanes / 2] given
+    (``rotary_frequencies``, ``yarn_frequencies``), cosine and sine times
+    ``rotary_factor``; kv head j serves query heads ``j * heads / kv_heads``
+    onward; causal, and with ``window=W`` a query sees its last W keys only
+    (the band inside the flash kernels, ops/attention.py); ``out = (context_h
+    * sigmoid((x wg)_h)) wo``: one gate a head and position, read from the
+    sublayer's own input. One mixer for a stack's full and windowed layers:
+    heads, lanes, frequencies, factor and window are the caller's; inside
+    ``attn_mixer`` the scope ``attn_window`` or ``attn_full`` names the
+    kind."""
+    b, s, _ = x.shape
+    fused = qk_prep_path(b, s, heads, head_dim, rotary_lanes, mesh)[0] == "fused"
+    kind = jax.named_scope("attn_window") if window \
+        else jax.named_scope("attn_full")
+    with jax.named_scope("attn_mixer"), kind:
+        q, k = x @ p["wq"], x @ p["wk"]
+        v = (x @ p["wv"]).reshape(b, s, kv_heads, head_dim).transpose(
+            0, 2, 1, 3)
+        if fused:
+            q, k = _qk_prep(
+                (q, k), (None, None), head_dim,
+                rotary_angles(s, rotary_lanes, None, frequencies=frequencies),
+                0.0, False, rotary_factor)
+        else:
+            q, k = (
+                apply_rotary(
+                    t.reshape(b, s, n, head_dim), rotary_lanes, None,
+                    seq_axis=1, frequencies=frequencies, factor=rotary_factor
+                ).transpose(0, 2, 1, 3)
+                for t, n in ((q, heads), (k, kv_heads)))
+        ctx = attention(q, k, v, causal=True, window=window, mesh=mesh)
+        gate = jax.nn.sigmoid(jnp.dot(
+            x, p["wg"], preferred_element_type=jnp.float32))
+        ctx = ctx.transpose(0, 2, 1, 3) * gate[..., None].astype(ctx.dtype)
+        return ctx.reshape(b, s, heads * head_dim) @ p["wo"]
 
 
 def rotary_attention_mixer(p, x, *, heads, head_dim, rope_theta, mesh=None):

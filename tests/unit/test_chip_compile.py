@@ -675,6 +675,88 @@ def test_gated_hybrid_sublayer_compiles_at_the_cells_shapes(kind):
 
 
 # ---------------------------------------------------------------------------
+# the window/full stack's sublayers at the widths of its benchmark cell
+# (models/hybrid.py kinds H, W, U; micro 2 x seq 8,192, hidden 3072, bf16)
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("kind", ["hattn", "wattn", "umoe"])
+def test_window_full_sublayer_compiles_at_the_cells_shapes(kind):
+    """Forward and backward of one sublayer of each kind under the cell's
+    remat policy: full attention (48 query heads on 8 kv heads of 128, YaRN on
+    64 of 128 lanes through ``qk_prep``, the causal flash kernels on an 8 x 8
+    grid of blocks), windowed attention (72 heads, plain rotary on 128 lanes,
+    the flash kernels under a band of 512 keys: a grid of 8 x 2 steps a head,
+    the fused backward's dq accumulator zeroed and cast out a Q block at the
+    band's first and last key block), each under its own scope inside
+    ``attn_mixer``; the experts (8 of 256 held, top-10, three matrices of
+    3072 x 1024 an expert, tiles of 352: ``moe_ffn_fwd`` whole, and
+    ``moe_ffn_bwd`` in TWO parts of the width 1,024, whose matrices, gradient
+    blocks and float32 sums whole would take 144 MB of the chip's 128 MiB of
+    VMEM; the ungated shared expert)."""
+    from deepspeed_tpu.models.hybrid import HybridLMConfig, HybridModel
+    from deepspeed_tpu.ops import moe
+
+    cfg = HybridLMConfig(
+        vocab_size=1024, hidden_size=3072, norm_eps=1e-6,
+        pattern={"hattn": "H", "wattn": "W", "umoe": "U"}[kind],
+        n_experts_held=8, n_experts_routed=256, top_k=10, routed_scaling=2.5,
+        moe_intermediate=1024, moe_shared_intermediate=1024, moe_tile=352,
+        router_force_level=True, attn_heads=48, window_attn_heads=72,
+        kv_heads=8, head_dim=128, rotary_lanes=64, rope_theta=5e5,
+        yarn_factor=128.0, yarn_original_positions=8192,
+        rotary_attention_factor=1.4852030263919618, window=512, remat=True,
+        remat_policy="nothing_saveable+flash_out+flash_lse+moe_plan")
+    model = HybridModel(cfg)
+    ids = _shape((2, 8192), jnp.int32)
+    params = jax.tree_util.tree_map(
+        lambda x: _shape(x.shape, jnp.bfloat16),
+        jax.eval_shape(lambda: model.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 128), jnp.int32)))["params"])
+    if kind == "umoe":
+        mats = [jax.ShapeDtypeStruct(s, jnp.bfloat16) for s in (
+            (8, 3072, 1024), (8, 3072, 1024), (8, 1024, 3072))]
+        real = device.on_tpu
+        device.on_tpu = lambda: True
+        try:
+            assert moe._width_parts(mats) == 2
+            # the accepted cells' experts stay whole
+            assert moe._width_parts([jax.ShapeDtypeStruct(s, jnp.bfloat16) for s in (
+                (16, 2048, 768), (16, 2048, 768), (16, 768, 2048))]) == 1
+        finally:
+            device.on_tpu = real
+
+    def loss(p, ids):
+        return model.apply({"params": p}, ids)[0].astype(jnp.float32).sum()
+
+    real = device.on_tpu, jax.device_count
+    device.on_tpu, jax.device_count = (lambda: True), (lambda: 1)
+    try:
+        compiled = jax.jit(jax.grad(loss)).lower(params, ids).compile()
+    finally:
+        device.on_tpu, jax.device_count = real
+    text = compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 6 * 2 ** 30
+    kernels = [l for l in text.splitlines() if "tpu_custom_call" in l]
+    # attention: the two flash kernels, and the q/k rotary pass once for q
+    # and once for k forward, again under remat, and backward; the experts:
+    # the forward kernel and the backward one a part
+    assert len(kernels) == {"hattn": 8, "wattn": 8, "umoe": 3}[kind], kernels
+    if kind == "umoe":
+        assert _moe_kernels(text) == [
+            ("moe_ffn_bwd", "backward"), ("moe_ffn_bwd", "backward"),
+            ("moe_ffn_fwd", "forward")]
+        for scope in ("moe_route", "moe_experts", "moe_shared"):
+            assert f"/{scope}/" in text, scope
+        return
+    assert _pallas_kernels(text) == FUSED
+    paths = _kernel_paths(text)
+    assert sorted(paths) == sorted(FUSED + list(QK_PREP_KERNELS))
+    scope = {"hattn": "attn_full", "wattn": "attn_window"}[kind]
+    assert all(f"/attn_mixer/{scope}/" in path for path in paths.values())
+    other = {"hattn": "attn_window", "wattn": "attn_full"}[kind]
+    assert f"/{other}/" not in text
+
+
+# ---------------------------------------------------------------------------
 # the looped stack at the widths of its benchmark cell (models/hybrid.py
 # kinds R and F under ``passes``; micro 1 x seq 8,192, hidden 2048, bf16)
 # ---------------------------------------------------------------------------

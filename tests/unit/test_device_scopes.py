@@ -52,6 +52,15 @@ FAMILIES = {
         "stack_norms": ALL, "embed": ONCE, "head_loss": ALL,
         "gdn_mixer": ALL, "attn_mixer": ALL, "moe_route": ALL,
         "moe_experts": ONCE, "moe_shared": ALL, "grad_accum": SUM}),
+    # two attention kinds in one stack, each under its own scope INSIDE
+    # attn_mixer (``other`` in benchmark/scopes/attention_kinds.json): the
+    # layers stay disjoint; ten sublayers unrolled, no scan, and no remat
+    # (five layers' activations fit): only the blocked head loss runs twice
+    "laguna": ("laguna-s-2.1.train-seq8192", {
+        "stack_norms": ONCE, "embed": ONCE, "head_loss": ALL,
+        "attn_mixer": ONCE, "attn_full": ONCE, "attn_window": ONCE,
+        "swiglu_ffn": ONCE, "moe_route": ONCE, "moe_experts": ONCE,
+        "moe_shared": ONCE, "grad_accum": SUM}),
     "ouro": ("ouro-2.6b.train-seq8192", {
         "stack_norms": ALL, "embed": ONCE, "loop_head_loss": ALL,
         "attn_mixer": ALL, "swiglu_ffn": ALL, "loop_pass": ALL,
