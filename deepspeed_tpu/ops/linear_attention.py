@@ -58,11 +58,12 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..utils import device
 from ..utils.logging import logger
+from .gdn_glue import (L2_EPS, gdn_glue_path, gdn_inputs_fused,
+                       gdn_output_fused)
 from .ssm import causal_depthwise_conv
 
 F32 = jnp.float32
 HIGHEST = jax.lax.Precision.HIGHEST
-L2_EPS = 1e-6
 LANES = 128
 
 
@@ -774,30 +775,20 @@ def gated_head_rms_norm(o, z, gain, eps):
         o.dtype)
 
 
-# Positions a piece of the mixer's float32 glue. A piece's temporaries (half
-# a dozen arrays of [positions, 8192] float32 at the benchmark's widths) are
-# small enough for the compiler to keep in VMEM; over the whole sequence every
-# one of them crosses HBM (my chip runs, PR 31: 121 ms more a window).
-GLUE_POSITIONS = 512
-
-
-def over_positions(fn, *arrays):
-    """``fn`` over [B, S, ...] ``arrays``, ``GLUE_POSITIONS`` of one row at
-    a time, one piece after the other: ``fn`` maps [L, ...] pieces to a tuple
-    of [L, ...] pieces and looks at no other position. Rows and pieces fold
-    into one leading axis, so no array changes layout; the backward pass
-    computes a piece again (only the operands are kept)."""
-    bsz, s = arrays[0].shape[:2]
-    if s <= GLUE_POSITIONS:
-        return jax.vmap(fn)(*arrays)
-    pad = -s % GLUE_POSITIONS
-    pieces = tuple(
-        jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2)).reshape(
-            (-1, GLUE_POSITIONS) + t.shape[2:]) for t in arrays)
-    _, out = jax.lax.scan(
-        lambda _, piece: (None, jax.checkpoint(fn)(*piece)), None, pieces)
-    return tuple(
-        t.reshape((bsz, s + pad) + t.shape[2:])[:, :s] for t in out)
+def gdn_inputs(mixed, conv_w, key_heads, key_dim):
+    """``mixed`` [B, S, 2 Hk dk + Hv dv] (q | k | v) and ``conv_w`` [K, the
+    same lanes] -> (q, k [B, S, Hk dk], v [B, S, Hv dv]): the causal depthwise
+    convolution and SiLU over all of it, q and k L2-normalised a head and q
+    times ``dk ** -0.5``. The XLA form of ``gdn_glue.gdn_inputs_fused``."""
+    bsz, s, _ = mixed.shape
+    qk = key_heads * key_dim
+    mixed = jax.nn.silu(causal_depthwise_conv(mixed, conv_w, 0))
+    q, k, v = jnp.split(mixed, [qk, 2 * qk], axis=-1)
+    heads = (bsz, s, key_heads, key_dim)
+    q = l2_normalise(q.reshape(heads)) * key_dim ** -0.5
+    k = l2_normalise(k.reshape(heads))
+    return (q.reshape(bsz, s, qk).astype(mixed.dtype),
+            k.reshape(bsz, s, qk).astype(mixed.dtype), v)
 
 
 def gated_deltanet_mixer(p, x, *, key_heads, value_heads, key_dim, value_dim,
@@ -806,28 +797,17 @@ def gated_deltanet_mixer(p, x, *, key_heads, value_heads, key_dim, value_dim,
     in_qkvz [E, 2 Hk dk + 2 Hv dv] (q | k | v | z, head by head in each),
     in_ba [E, 2 Hv] (b | a), conv_w [K, 2 Hk dk + Hv dv] (no bias), A_log and
     dt_bias [Hv], out_norm [dv], out_proj [Hv dv, E]. The float32 glue on
-    either side of the delta rule (the L2 norms and the decays before it,
-    the gated output norm after it) runs a piece of positions at a time
-    (``over_positions``); the rule between them takes the whole sequence and
-    cuts it itself."""
+    either side of the delta rule (convolution, SiLU and the L2 norms before
+    it, the gated output norm after it) is one Pallas pass a side wherever
+    ``gdn_glue_path`` says ``fused``, else ``gdn_inputs`` and
+    ``gated_head_rms_norm`` over the whole array; the decays and beta are
+    [B, S, Hv] float32 and plain XLA either way."""
     bsz, s, _ = x.shape
     qk, vz = key_heads * key_dim, value_heads * value_dim
     a_neg, dt_bias = -jnp.exp(p["A_log"].astype(F32)), p["dt_bias"].astype(F32)
-
-    def before(mixed, b, a):
-        q, k, v = jnp.split(mixed, [qk, 2 * qk], axis=-1)
-        q = l2_normalise(q.reshape(-1, key_heads, key_dim)) * key_dim ** -0.5
-        k = l2_normalise(k.reshape(-1, key_heads, key_dim))
-        return (q.reshape(-1, qk).astype(x.dtype),
-                k.reshape(-1, qk).astype(x.dtype), v,
-                a_neg * jax.nn.softplus(a.astype(F32) + dt_bias),
-                jax.nn.sigmoid(b.astype(F32)))
-
-    def after(o, z):
-        heads = (-1, value_heads, value_dim)
-        return (gated_head_rms_norm(
-            o.reshape(heads), z.reshape(heads), p["out_norm"], eps
-        ).reshape(-1, vz),)
+    fused = gdn_glue_path(
+        bsz, s, key_heads, value_heads, key_dim, value_dim,
+        p["conv_w"].shape[0], mesh)[0] == "fused"
 
     with jax.named_scope("gdn_mixer"):
         # two products over the two column blocks of the one leaf: the
@@ -836,13 +816,19 @@ def gated_deltanet_mixer(p, x, *, key_heads, value_heads, key_dim, value_dim,
         mixed = x @ p["in_qkvz"][:, :2 * qk + vz]
         z = x @ p["in_qkvz"][:, 2 * qk + vz:]
         b, a = jnp.split(x @ p["in_ba"], 2, axis=-1)
-        mixed = jax.nn.silu(causal_depthwise_conv(mixed, p["conv_w"], 0))
-        q, k, v, g, beta = over_positions(before, mixed, b, a)
+        g = a_neg * jax.nn.softplus(a.astype(F32) + dt_bias)
+        beta = jax.nn.sigmoid(b.astype(F32))
+        q, k, v = (gdn_inputs_fused if fused else gdn_inputs)(
+            mixed, p["conv_w"], key_heads=key_heads, key_dim=key_dim)
         with jax.named_scope("gdn_delta_rule"):
             o = gated_delta_rule_chunked(
                 q.reshape(bsz, s, key_heads, key_dim),
                 k.reshape(bsz, s, key_heads, key_dim),
                 v.reshape(bsz, s, value_heads, value_dim), g, beta, chunk,
                 mesh=mesh)
-        o, = over_positions(after, o.reshape(bsz, s, vz), z)
+        if fused:
+            o = gdn_output_fused(o.reshape(bsz, s, vz), z, p["out_norm"], eps)
+        else:
+            o = gated_head_rms_norm(
+                o, z.reshape(o.shape), p["out_norm"], eps).reshape(bsz, s, vz)
         return o @ p["out_proj"]

@@ -814,3 +814,36 @@ def test_qk_prep_kernels_match_the_xla_functions_at_the_cells_shapes(cell):
         assert jnp.array_equal(got["fused"][1][..., v:], got["xla"][1][..., v:])
     else:
         assert gaps["dgain"] <= 4, gaps
+
+
+def test_gdn_glue_kernels_match_the_xla_functions_at_the_cells_shape():
+    """``gdn_in_fwd`` / ``gdn_in_bwd`` and ``gdn_out_fwd`` / ``gdn_out_bwd``
+    compiled, at the Qwen3-Next cell's shape (one row of 16,384 positions, 16
+    key heads on 32 value heads of 128, 4 taps, bf16), against
+    ``gdn_inputs`` and ``gated_head_rms_norm`` compiled by XLA: q, k, v, the
+    projection's cotangent, ``d conv_w``, the gated output, ``d o``, ``d z``
+    and the gain's gradient, the largest gap in steps of bfloat16 at the
+    largest magnitude of the head's row. The kernels round once: against the
+    XLA functions over the same operands in float32 they are within half a
+    step (a tie rounds either way); against them in bf16, which round after
+    every tap of the convolution, after SiLU and after the norm, within a few
+    steps (my chip run, PR 41: 1 for q, k, v, d o, d z and d conv_w, 5 for d
+    mixed, 0 for the gated output and the gain's gradient)."""
+    import importlib.util
+    import os
+
+    spec = importlib.util.spec_from_file_location(
+        "gdn_glue_alone", os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "tools", "gdn_glue_alone.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    c = tool.CELL
+    from deepspeed_tpu.ops import gdn_glue
+
+    assert gdn_glue.gdn_glue_path(
+        1, c["S"], c["Hk"], c["Hv"], c["dk"], c["dv"], c["K"])[0] == "fused"
+    gaps = tool.gaps(c)
+    print(gaps)
+    assert max(gaps["xla_float32"].values()) <= 0.51, gaps
+    assert max(gaps["xla_bf16"].values()) <= 8, gaps
