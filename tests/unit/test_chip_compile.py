@@ -37,7 +37,8 @@ WIDTH = {"large": 1280, "xl": 1600}
 
 
 @functools.lru_cache(maxsize=None)
-def _v5e():
+def _v5e_host():
+    """The four described chips of one ``v5e:2x2`` host."""
     from jax.experimental import topologies
 
     # describing a chip loads libtpu, which by default takes a machine-wide
@@ -50,7 +51,7 @@ def _v5e():
     try:
         return topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2"
-        ).devices[0]
+        ).devices
     except Exception as e:  # no libtpu here: nothing to rehearse against
         pytest.skip(f"cannot describe a v5e:2x2 topology: {e}")
     finally:
@@ -59,6 +60,10 @@ def _v5e():
                 os.environ.pop(k, None)
             else:
                 os.environ[k] = v
+
+
+def _v5e():
+    return _v5e_host()[0]
 
 
 def _shape(shape, dtype):
@@ -871,6 +876,55 @@ def test_looped_stack_compiles_at_the_cells_shapes():
     for scope in ("swiglu_ffn", "loop_head_loss", "exit_gate", "stack_norms",
                   "embed"):
         assert f"/{scope}/" in text, scope
+    assert _head_products(text, "loop_head_loss") == ["forward"] * 3
+
+
+def _head_products(text, scope):
+    """The pass of every matrix product of a compiled program under the head
+    loss's ``scope``, sorted. The blocked head loss takes its gradient in its
+    forward (ops/cross_entropy.py): three products a chunk, all the
+    forward's, and nothing under the scope is run again."""
+    from benchmark.readers.pass_time import which_pass
+
+    lines = [l for l in text.splitlines() if f"/{scope}/" in l]
+    assert lines and "recompute" not in {which_pass(l) for l in lines}
+    return sorted(which_pass(l) for l in lines
+                  if re.search(r" (?:convolution|dot)\(", l))
+
+
+def test_dp4_window_reduces_what_it_reduced_and_runs_no_head_product_again(
+        monkeypatch):
+    """The whole fused window of ``gpt2-large.zero2-dp4`` (36 layers, micro 8
+    x seq 1024 x accum 2 a chip, ZeRO-2 over four described chips), built by
+    the engine as ``benchmark/compile_described.py`` builds it: the table's
+    gradient contracts over the sharded batch inside the head's FORWARD scan,
+    and the partitioner still places the collectives of PERF.md section 4 and
+    no all-reduce a chunk; the head's products are the forward's; the window
+    stays inside the 10.53 GiB a chip it compiled to before PR 42."""
+    from benchmark import compile_described, harness
+    from deepspeed_tpu.runtime.compile_cache import disarm_compile_cache
+
+    compiled = []
+    monkeypatch.setattr(
+        compile_described, "report",
+        lambda label, program, t0: compiled.append(program) or program)
+    cell = harness.load_json("workloads", "gpt2-large.zero2-dp4.json")
+    config = harness.load_json("configs", cell["config"] + ".json")
+    try:
+        compile_described.program_window(
+            cell, config, harness.sizes(config, False),
+            _v5e_host()[:cell["chips"]])
+    finally:
+        # the cell's recipe arms the persistent cache (off in this file)
+        disarm_compile_cache()
+    text = compiled[0].as_text()
+    assert {op: text.count(f" {op}(") + text.count(f" {op}-start(")
+            for op in ("all-reduce", "all-gather", "reduce-scatter",
+                       "collective-permute")} == {
+        "all-reduce": 8, "all-gather": 34, "reduce-scatter": 0,
+        "collective-permute": 5}
+    assert set(_head_products(text, "head_loss")) == {"forward"}
+    assert compiled[0].memory_analysis().peak_memory_in_bytes < 10.6 * 2 ** 30
 
 
 # ---------------------------------------------------------------------------

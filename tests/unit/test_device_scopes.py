@@ -29,40 +29,40 @@ LAYER_SCOPES = tuple(pass_time.listed()["layers"])
 AROUND = tuple(pass_time.listed()["around"])
 
 ALL = {"forward", "recompute", "backward"}
-ONCE = {"forward", "backward"}          # nothing runs it again
+ONCE = {"forward", "backward"}          # nothing runs it again: so the
+# blocked head loss, whose forward takes its gradient (ops/cross_entropy.py)
 SUM = {"forward"}                       # the engine's: autodiff never sees it
 
 # family -> (its cell, {scope: the passes its operations must show})
 FAMILIES = {
     "gpt2": ("gpt2-large.train-accum1", {
         "dense_attn": ALL, "dense_ffn": ALL, "embed": ONCE,
-        "head_loss": ALL, "stack_scan": ALL}),
-    # BERT's head is not blocked: no checkpoint inside it
+        "head_loss": ONCE, "stack_scan": ALL}),
     "bert": ("bert-large.pretrain-seq128", {
         "dense_attn": ALL, "dense_ffn": ALL, "embed": ONCE,
         "head_loss": ONCE, "stack_scan": ALL, "grad_accum": SUM}),
     # the latent experts' routed sum is kept with the plan (PR 37): remat
     # runs their kernels no second time
     "nemotron": ("nemotron3-super-120b-a12b.train-seq8192", {
-        "stack_norms": ALL, "embed": ONCE, "head_loss": ALL,
+        "stack_norms": ALL, "embed": ONCE, "head_loss": ONCE,
         "mamba_mixer": ALL, "attn_mixer": ALL, "moe_route": ALL,
         "moe_experts": ONCE, "moe_shared": ALL, "grad_accum": SUM}),
     # the gated experts keep their plan and outputs by name: no second run
     "qwen3-next": ("qwen3-next-80b-a3b.train-seq16384", {
-        "stack_norms": ALL, "embed": ONCE, "head_loss": ALL,
+        "stack_norms": ALL, "embed": ONCE, "head_loss": ONCE,
         "gdn_mixer": ALL, "attn_mixer": ALL, "moe_route": ALL,
         "moe_experts": ONCE, "moe_shared": ALL, "grad_accum": SUM}),
     # two attention kinds in one stack, each under its own scope INSIDE
     # attn_mixer (``other`` in benchmark/scopes/attention_kinds.json): the
     # layers stay disjoint; ten sublayers unrolled, no scan, and no remat
-    # (five layers' activations fit): only the blocked head loss runs twice
+    # (five layers' activations fit): nothing in the window runs twice
     "laguna": ("laguna-s-2.1.train-seq8192", {
-        "stack_norms": ONCE, "embed": ONCE, "head_loss": ALL,
+        "stack_norms": ONCE, "embed": ONCE, "head_loss": ONCE,
         "attn_mixer": ONCE, "attn_full": ONCE, "attn_window": ONCE,
         "swiglu_ffn": ONCE, "moe_route": ONCE, "moe_experts": ONCE,
         "moe_shared": ONCE, "grad_accum": SUM}),
     "ouro": ("ouro-2.6b.train-seq8192", {
-        "stack_norms": ALL, "embed": ONCE, "loop_head_loss": ALL,
+        "stack_norms": ALL, "embed": ONCE, "loop_head_loss": ONCE,
         "attn_mixer": ALL, "swiglu_ffn": ALL, "loop_pass": ALL,
         "exit_gate": ONCE, "stack_scan": ALL, "grad_accum": SUM}),
 }

@@ -303,11 +303,16 @@ def test_two_windows_through_initialize_follow_the_reference(weights):
 # widths its glue runs as ``gdn_inputs`` / ``gated_head_rms_norm`` over the
 # whole array where a row was taken under ``jax.vmap`` (it was
 # 782645bc...d505e); the Nemotron one holds.
+# PR 42 moved both on purpose and recorded them again on its own tree: the
+# blocked head loss is a ``custom_vjp`` whose forward takes the chunk's
+# gradient (ops/cross_entropy.py), so the checkpointed chunk and the scan's
+# transposition are gone from every program that calls it (they were
+# 822ad3b3...c42ad and fa45d5d6...a2806).
 ACCEPTED_PROGRAMS = {
     "nemotron3-super-120b-a12b":
-        "822ad3b3249541040432ad540411ad12f2d1c6dfd22a129f156e057ad7ec42ad",
+        "9f986de3c115df17c112ff43a7024f947ff3bc183959de30ea3da75316adea62",
     "qwen3-next-80b-a3b":
-        "fa45d5d6da42393fc88af5a23348058a7359e7eff3d24f9d0eded1300b6a2806",
+        "17d8c4fc47ccdf27152b136efed882a0f253708474c869deb04ee94193e936cd",
 }
 
 
