@@ -308,11 +308,20 @@ def test_two_windows_through_initialize_follow_the_reference(weights):
 # gradient (ops/cross_entropy.py), so the checkpointed chunk and the scan's
 # transposition are gone from every program that calls it (they were
 # 822ad3b3...c42ad and fa45d5d6...a2806).
+# PR 43 added the Ouro, SDAR and Laguna ones as its first commit, on its
+# parent's code (70e5656), before it made the five attention mixers one: all
+# five hold across it, the XLA branch of every attention kind among them.
 ACCEPTED_PROGRAMS = {
+    "laguna-s-2.1":
+        "6a72d3c4a99bab23327f611f272c20fb7f5b0c99b2790681b21c8d2ede11d604",
     "nemotron3-super-120b-a12b":
         "9f986de3c115df17c112ff43a7024f947ff3bc183959de30ea3da75316adea62",
+    "ouro-2.6b":
+        "05be95d6131629ed74b2708d367e3f1f11188e9fdaf9449db4dcc4baf11846fa",
     "qwen3-next-80b-a3b":
         "17d8c4fc47ccdf27152b136efed882a0f253708474c869deb04ee94193e936cd",
+    "sdar-30b-a3b-chat":
+        "e5c7f978791edb41e5a1fe32834561b63b6f61d10af0d04d9e763ad664adae93",
 }
 
 
@@ -323,15 +332,20 @@ def test_accepted_hybrid_configurations_compile_what_they_compiled(name):
     args = {arg: size[key]
             for arg, key in config["program"]["config_args"].items()}
     args.update(config["train"]["model_args"])
-    model = HybridCausalLM(HybridLMConfig(**args))
+    cfg = HybridLMConfig(**args)
+    model = HybridCausalLM(cfg)
     ids = jnp.zeros((2, 64), jnp.int32)
+    # block diffusion trains on (noisy_ids, clean_ids, loss_weights)
+    weights = () if cfg.objective == "next_token" \
+        else (jnp.ones(ids.shape, jnp.float32),)
     params = jax.eval_shape(
-        lambda: model.init(jax.random.PRNGKey(0), ids, ids))
+        lambda: model.init(jax.random.PRNGKey(0), ids, ids, *weights))
 
-    def loss(p, ids):
-        return model.apply(p, ids, ids)[0]
+    def loss(p, ids, *weights):
+        return model.apply(p, ids, ids, *weights)[0]
 
-    text = jax.jit(jax.value_and_grad(loss)).lower(params, ids).as_text()
+    text = jax.jit(jax.value_and_grad(loss)).lower(
+        params, ids, *weights).as_text()
     assert hashlib.sha256(text.encode()).hexdigest() == \
         ACCEPTED_PROGRAMS[name]
 
