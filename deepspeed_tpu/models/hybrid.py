@@ -1,32 +1,29 @@
 """A causal LM whose layers differ in kind: each layer is ONE mixer behind
 a pre-RMS-norm and a residual, and a pattern string says which — ``M`` a
 Mamba-2 state-space mixer (ops/ssm.py), ``E`` a latent mixture of experts
-that drops no token (ops/moe.py:latent_moe_mixer), ``*`` causal
-grouped-query attention (ops/transformer.py:gqa_attention_mixer); ``D`` a
-Gated DeltaNet linear-attention mixer (ops/linear_attention.py), ``G`` gated
-softmax attention with per-head q/k norms and partial rotary
-(ops/transformer.py:gated_attention_mixer), ``X`` a mixture of SiLU-gated
-experts at the model's own width behind softmax routing, with a gated shared
-expert (ops/moe.py:gated_moe_mixer); ``R`` causal multi-head attention with
-rotary on every lane (ops/transformer.py:rotary_attention_mixer), ``F`` a
-dense SiLU-gated FFN (ops/transformer.py:swiglu_ffn_mixer); ``A``
-grouped-query attention with per-head q/k norms and rotary on every lane
-(ops/transformer.py:rotary_gqa_attention_mixer), ``S`` the experts of ``X``
-with no shared expert beside them; ``H`` and ``W`` grouped-query attention
-gated per head with no q/k norm (ops/transformer.py:
-head_gated_attention_mixer), ``H`` seeing the whole causal row with YaRN
-rotary on ``rotary_lanes`` lanes, ``W`` a sliding window of ``window`` keys
-(the band inside the flash kernels) with plain rotary on every lane, each
-with its own head count on the same kv heads; ``U`` the experts of ``X`` with
-an UNGATED shared expert and the routed sum times ``routed_scaling``. A
-published layer that is a token mixer THEN experts or an FFN is two letters
-(``DXDXDXGX`` is one period of three DeltaNet layers and one attention layer,
-each with its experts; ``RF`` and ``AS`` are one decoder layer each; ``HF``
-then ``WUWUWUHU`` repeated is a leading dense layer and periods of three
-windowed layers and a full one). No
-learned position
-embedding (the recurrent layers carry position; ``G``, ``R``, ``A``, ``H``
-and ``W`` rotate), a
+that drops no token (ops/moe.py:latent_moe_mixer), ``D`` a Gated DeltaNet
+linear-attention mixer (ops/linear_attention.py), ``X`` a mixture of
+SiLU-gated experts at the model's own width behind softmax routing, with a
+gated shared expert (ops/moe.py:gated_moe_mixer), ``S`` the experts of ``X``
+with no shared expert beside them, ``U`` the experts of ``X`` with an UNGATED
+shared expert and the routed sum times ``routed_scaling``, ``F`` a dense
+SiLU-gated FFN (ops/transformer.py:swiglu_ffn_mixer); and six letters of
+attention, all ops/transformer.py:attention_mixer, each under the spec that
+``attention_spec`` makes of the configuration: ``*`` causal grouped-query
+attention; ``G`` gated softmax attention with zero-centred per-head q/k norms
+and partial rotary; ``R`` causal multi-head attention with rotary on every
+lane; ``A`` grouped-query attention with per-head q/k norms and rotary on
+every lane; ``H`` and ``W`` grouped-query attention gated per head with no
+q/k norm, ``H`` seeing the whole causal row with YaRN rotary on
+``rotary_lanes`` lanes, ``W`` a sliding window of ``window`` keys (the band
+inside the flash kernels) with plain rotary on every lane, each with its own
+head count on the same kv heads. A published layer that is a token mixer
+THEN experts or an FFN is two letters (``DXDXDXGX`` is one period of three
+DeltaNet layers and one attention layer, each with its experts; ``RF`` and
+``AS`` are one decoder layer each; ``HF`` then ``WUWUWUHU`` repeated is a
+leading dense layer and periods of three windowed layers and a full one). No
+learned position embedding (the recurrent layers carry position; ``G``,
+``R``, ``A``, ``H`` and ``W`` rotate), a
 final RMS norm, an untied head, bias-free projections; ``norm_zero_centered``
 stores every norm's gain around 0 and applies ``1 + gain``; ``post_norm``
 gives ``R`` and ``F`` a second gain AFTER the mixer (``x + norm_b(mixer(
@@ -98,14 +95,11 @@ from ..ops.linear_attention import gated_deltanet_mixer
 from ..ops.moe import gated_moe_mixer, latent_moe_mixer
 from ..ops.ssm import mamba2_mixer
 from ..ops.transformer import (
-    gated_attention_mixer,
-    gqa_attention_mixer,
-    head_gated_attention_mixer,
+    AttentionSpec,
+    attention_mixer,
     resolve_remat_policy,
     rms_norm,
-    rotary_attention_mixer,
     rotary_frequencies,
-    rotary_gqa_attention_mixer,
     swiglu_ffn_mixer,
     yarn_frequencies,
 )
@@ -418,6 +412,43 @@ def _leaf_init(cfg, leaf):
     return nn.initializers.normal(stddev=cfg.initializer_range)
 
 
+def attention_spec(cfg, kind):
+    """What attention letter ``kind`` is under ``cfg``: the one table between
+    the configuration's fields and ``attention_mixer``."""
+    d = cfg.head_dim
+
+    def rotary(lanes, theta):
+        return dict(lanes=lanes, frequencies=rotary_frequencies(lanes, theta))
+
+    spec = dict(
+        heads=cfg.heads(KINDS[kind]), kv_heads=cfg.kv_heads, head_dim=d)
+    if kind == "G":
+        spec.update(rotary(cfg.rotary_lanes, cfg.rope_theta), gate="lanes",
+                    norm="zero_centered", eps=cfg.norm_eps)
+    elif kind == "R":
+        spec.update(rotary(d, cfg.rope_theta), kv_heads=cfg.attn_heads)
+    elif kind == "A":
+        spec.update(
+            rotary(d, cfg.rope_theta), eps=cfg.norm_eps,
+            norm="zero_centered" if cfg.norm_zero_centered else "rms",
+            block_diffusion=cfg.diffusion_block
+            if cfg.objective == "block_diffusion" else 0)
+    elif kind == "H":
+        lanes = cfg.rotary_lanes or d
+        spec.update(rotary(lanes, cfg.rope_theta), gate="head",
+                    scope="attn_full",
+                    rotary_factor=cfg.rotary_attention_factor)
+        if cfg.yarn_factor != 1.0:
+            spec.update(frequencies=yarn_frequencies(
+                lanes, cfg.rope_theta, cfg.yarn_factor,
+                cfg.yarn_original_positions, cfg.yarn_beta_fast,
+                cfg.yarn_beta_slow))
+    elif kind == "W":
+        spec.update(rotary(d, cfg.window_rope_theta), gate="head",
+                    scope="attn_window", window=cfg.window)
+    return AttentionSpec(**spec)
+
+
 class HybridModel(nn.Module):
     """input_ids [B, S] -> (hidden [B, S, E] after the final norm, the
     head's table, counters, None); a looped stack gives every pass's hidden
@@ -429,11 +460,10 @@ class HybridModel(nn.Module):
     @nn.compact
     def __call__(self, input_ids):
         cfg = self.config
-        positions, diffusion_block = None, 0
+        positions = None
         if cfg.objective == "block_diffusion":
             half = input_ids.shape[1] // 2
             positions = jnp.arange(2 * half) % half
-            diffusion_block = cfg.diffusion_block
         init = nn.initializers.normal(stddev=cfg.initializer_range)
         embed = self.param("embed", init, (cfg.vocab_size, cfg.hidden_size))
         head = self.param("head", init, (cfg.vocab_size, cfg.hidden_size))
@@ -459,29 +489,12 @@ class HybridModel(nn.Module):
                 held=cfg.n_experts_held, offset=cfg.expert_offset,
                 tile=cfg.moe_tile, force_level=cfg.router_force_level,
                 mesh=cfg.mesh),
-            "attn": lambda p, x: (gqa_attention_mixer(
-                p, x, heads=cfg.attn_heads, kv_heads=cfg.kv_heads,
-                head_dim=cfg.head_dim, mesh=cfg.mesh), {}),
             "gdn": lambda p, x: (gated_deltanet_mixer(
                 p, x, key_heads=cfg.gdn_key_heads,
                 value_heads=cfg.gdn_value_heads, key_dim=cfg.gdn_key_dim,
                 value_dim=cfg.gdn_value_dim, chunk=cfg.gdn_chunk,
                 eps=cfg.norm_eps, mesh=cfg.mesh), {}),
-            "gattn": lambda p, x: (gated_attention_mixer(
-                p, x, heads=cfg.attn_heads, kv_heads=cfg.kv_heads,
-                head_dim=cfg.head_dim, rotary_lanes=cfg.rotary_lanes,
-                rope_theta=cfg.rope_theta, eps=cfg.norm_eps,
-                mesh=cfg.mesh), {}),
-            "rattn": lambda p, x: (rotary_attention_mixer(
-                p, x, heads=cfg.attn_heads, head_dim=cfg.head_dim,
-                rope_theta=cfg.rope_theta, mesh=cfg.mesh), {}),
             "ffn": lambda p, x: (swiglu_ffn_mixer(p, x), {}),
-            "qattn": lambda p, x: (rotary_gqa_attention_mixer(
-                p, x, heads=cfg.attn_heads, kv_heads=cfg.kv_heads,
-                head_dim=cfg.head_dim, rope_theta=cfg.rope_theta,
-                eps=cfg.norm_eps, zero_centered=cfg.norm_zero_centered,
-                positions=positions, block_diffusion=diffusion_block,
-                mesh=cfg.mesh), {}),
         }
 
         def gated_experts(scale=1.0):
@@ -496,42 +509,28 @@ class HybridModel(nn.Module):
         mixers["gmoe"] = mixers["smoe"] = gated_experts()
         mixers["umoe"] = gated_experts(cfg.routed_scaling)
 
-        def head_gated(kind, window=0, **rotary):
-            """A layer's counters: the query heads it runs (added up over
-            layers and micro-steps) and, windowed, the band and the share of
-            the score square that the banded kernels' walks visit."""
-            heads = cfg.heads(kind)
-
+        def attention(kind):
+            """The one mixer under ``kind``'s spec. A head-gated layer counts
+            the query heads it runs (added up over layers and micro-steps)
+            and, windowed, the band and the share of the score square that
+            the banded kernels' walks visit."""
             def mixer(p, x):
-                out = head_gated_attention_mixer(
-                    p, x, heads=heads, kv_heads=cfg.kv_heads,
-                    head_dim=cfg.head_dim, window=window, mesh=cfg.mesh,
-                    **rotary)
-                if not window:
-                    return out, {"attn/full_heads": jnp.int32(heads)}
+                spec = attention_spec(cfg, kind)
+                out = attention_mixer(
+                    p, x, spec, positions=positions, mesh=cfg.mesh)
+                if kind not in "HW":
+                    return out, {}
+                if not spec.window:
+                    return out, {"attn/full_heads": jnp.int32(spec.heads)}
                 return out, {
-                    "attn/window_heads": jnp.int32(heads),
-                    "attn/max_window": jnp.int32(window),
+                    "attn/window_heads": jnp.int32(spec.heads),
+                    "attn/max_window": jnp.int32(spec.window),
                     "attn/max_window_visited_share": jnp.float32(
-                        window_visited_share(x.shape[1], window))}
+                        window_visited_share(x.shape[1], spec.window))}
 
             return mixer
 
-        if "H" in cfg.pattern:
-            lanes = cfg.rotary_lanes or cfg.head_dim
-            mixers["hattn"] = head_gated(
-                "hattn", rotary_lanes=lanes,
-                rotary_factor=cfg.rotary_attention_factor,
-                frequencies=rotary_frequencies(lanes, cfg.rope_theta)
-                if cfg.yarn_factor == 1.0 else yarn_frequencies(
-                    lanes, cfg.rope_theta, cfg.yarn_factor,
-                    cfg.yarn_original_positions, cfg.yarn_beta_fast,
-                    cfg.yarn_beta_slow))
-        if "W" in cfg.pattern:
-            mixers["wattn"] = head_gated(
-                "wattn", window=cfg.window,
-                rotary_lanes=cfg.head_dim, frequencies=rotary_frequencies(
-                    cfg.head_dim, cfg.window_rope_theta))
+        mixers.update({KINDS[kind]: attention(kind) for kind in "*GRAHW"})
 
         prefix, unit, repetitions, tail = stack_plan(cfg.pattern)
         scanned = repetitions > 1

@@ -25,6 +25,7 @@ Parameter names mirror the reference's 12-tensor layout
 inter_w/b, output_w/b, norm_w/b) so state_dicts translate mechanically.
 """
 
+import contextlib
 import dataclasses
 from typing import Optional
 
@@ -342,26 +343,6 @@ def rms_norm(x, gain, eps, zero_centered=False):
     return (xs * inv * (1.0 + gain if zero_centered else gain)).astype(x.dtype)
 
 
-def gqa_attention_mixer(p, x, *, heads, kv_heads, head_dim, mesh=None):
-    """Causal grouped-query attention over normalized ``x`` [B, S, E] with
-    bias-free projections wq [E, heads * D], wk/wv [E, kv_heads * D], wo
-    [heads * D, E]; no position embedding."""
-    from .attention import attention
-
-    b, s, _ = x.shape
-
-    def split(t, n):
-        return t.reshape(b, s, n, head_dim).transpose(0, 2, 1, 3)
-
-    with jax.named_scope("attn_mixer"):
-        q = split(x @ p["wq"], heads)
-        k = split(x @ p["wk"], kv_heads)
-        v = split(x @ p["wv"], kv_heads)
-        ctx = attention(q, k, v, causal=True, mesh=mesh)
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, heads * head_dim)
-        return ctx @ p["wo"]
-
-
 def rotary_frequencies(lanes, theta):
     """[lanes / 2] float32 inverse frequencies ``theta^(-2i / lanes)``, made
     on the host in float64."""
@@ -429,163 +410,127 @@ def apply_rotary(x, lanes, theta, seq_axis=2, positions=None,
         axis=-1).astype(x.dtype)
 
 
-def _qk_prep(projections, gains, head_dim, angle, eps, zero_centered,
-             factor=1.0):
-    """q and k [B, heads, S, D] out of their projections' results [B, S,
-    heads * D] through the kernels of ops/qk_prep.py: each head's RMS norm
-    with its gain (None: no norm), then rotary by ``angle``
-    (``rotary_angles``) with ``factor`` on cosine and sine."""
-    return tuple(
-        qk_prep(t, gain, angle, head_dim=head_dim, eps=eps,
-                zero_centered=zero_centered, factor=factor)
-        for t, gain in zip(projections, gains))
+@dataclasses.dataclass(frozen=True)
+class AttentionSpec:
+    """What a layer kind's attention IS; how it runs is ``attention_mixer``'s.
+    ``heads`` query heads on ``kv_heads`` kv heads of ``head_dim`` lanes (kv
+    head j serves query heads ``j * heads / kv_heads`` onward). ``norm``: an
+    RMS norm over each head of q (gain q_norm [D]) and of k (k_norm [D])
+    before rotary: None, ``"rms"`` or ``"zero_centered"`` (the gain applied
+    as ``1 + gain``), with ``eps``. Rotary on the first ``lanes`` lanes (0:
+    none) at ``frequencies`` [lanes / 2] (``rotary_frequencies``,
+    ``yarn_frequencies``; kept as a tuple), cosine and sine times
+    ``rotary_factor``. ``gate``: None; ``"lanes"``: wq [E, heads * 2 D] gives
+    each head D query lanes then D gate lanes; ``"head"``: one value a head
+    out of wg [E, heads], read from the sublayer's own input; ``out =
+    (context * sigmoid(gate)) wo``. ``window=W``: a query sees its last W
+    keys only; ``block_diffusion=B``: that mask over a ``[noisy ; clean]``
+    row (both inside the flash kernels, ops/attention.py); neither: causal.
+    ``scope``: the device scope the kind opens inside ``attn_mixer``."""
+
+    heads: int
+    kv_heads: int
+    head_dim: int
+    norm: Optional[str] = None
+    eps: float = 0.0
+    lanes: int = 0
+    frequencies: tuple = ()
+    rotary_factor: float = 1.0
+    gate: Optional[str] = None
+    window: int = 0
+    block_diffusion: int = 0
+    scope: Optional[str] = None
+
+    def __post_init__(self):
+        object.__setattr__(
+            self, "frequencies", tuple(float(f) for f in self.frequencies))
 
 
-def gated_attention_mixer(p, x, *, heads, kv_heads, head_dim, rotary_lanes,
-                          rope_theta, eps, mesh=None):
-    """Causal grouped-query attention with an output gate over normalized
-    ``x`` [B, S, E]: wq [E, heads * 2 D] gives each head D query lanes then D
-    gate lanes; wk/wv [E, kv_heads * D]; a zero-centred RMS norm over D on q
-    (q_norm [D]) and on k (k_norm [D]); rotary on the first ``rotary_lanes``
-    lanes of q and k; ``out = (context * sigmoid(gate)) wo``, wo [heads * D,
-    E]. No bias anywhere."""
+def attention_mixer(p, x, spec, *, positions=None, mesh=None):
+    """``spec``'s attention over normalized ``x`` [B, S, E] with bias-free
+    projections wq [E, heads * D], wk/wv [E, kv_heads * D], wo [heads * D,
+    E]; rotary at ``positions`` [S] (None: 0..S-1; the halves of a
+    block-diffusion row carry the same ids). The q/k norm and rotary are the
+    kernels of ops/qk_prep.py where ``qk_prep_path`` says ``fused``, else
+    ``apply_rotary(rms_norm(..))``. The layout follows from the spec: ONE
+    product q | k | v, rotated in place and taken whole by
+    ``attention_packed`` ([B, S, 3 * heads * D]: no head transpose on either
+    side of the kernels), where there are as many kv heads as query heads and
+    nothing but a rotation of every lane before the kernels; else three
+    products and ``attention`` over [B, heads, S, D]."""
     b, s, _ = x.shape
-    fused = qk_prep_path(b, s, heads, head_dim, rotary_lanes, mesh)[0] == "fused"
-    with jax.named_scope("attn_mixer"):
-        if fused:
-            # q and the gate as two products of wq's two halves of each head:
-            # both lane-dense [B, S, heads * D], no interleave to take apart
-            wq = p["wq"].reshape(-1, heads, 2, head_dim)
-            q, gate = (x @ wq[:, :, i].reshape(-1, heads * head_dim)
-                       for i in (0, 1))
-            gate = gate.reshape(b, s, heads, head_dim)
-            q, k = _qk_prep(
-                (q, x @ p["wk"]), (p["q_norm"], p["k_norm"]), head_dim,
-                rotary_angles(s, rotary_lanes, rope_theta), eps, True)
-            v = (x @ p["wv"]).reshape(b, s, kv_heads, head_dim).transpose(
-                0, 2, 1, 3)
-        else:
-            qg = (x @ p["wq"]).reshape(b, s, heads, 2 * head_dim)
-            q, gate = qg[..., :head_dim], qg[..., head_dim:]
-            k = (x @ p["wk"]).reshape(b, s, kv_heads, head_dim)
-            v = (x @ p["wv"]).reshape(b, s, kv_heads, head_dim)
-            q = rms_norm(q, p["q_norm"], eps, zero_centered=True)
-            k = rms_norm(k, p["k_norm"], eps, zero_centered=True)
-            q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
-            q = apply_rotary(q, rotary_lanes, rope_theta)
-            k = apply_rotary(k, rotary_lanes, rope_theta)
-        ctx = attention(q, k, v, causal=True, mesh=mesh)
-        ctx = ctx.transpose(0, 2, 1, 3) * jax.nn.sigmoid(
-            gate.astype(jnp.float32)).astype(ctx.dtype)
-        return ctx.reshape(b, s, heads * head_dim) @ p["wo"]
+    heads, kv_heads, d = spec.heads, spec.kv_heads, spec.head_dim
+    fused = qk_prep_path(b, s, heads, d, spec.lanes, mesh)[0] == "fused"
+    packed = kv_heads == heads and spec.lanes == d \
+        and not (spec.norm or spec.gate or spec.window or spec.block_diffusion)
+    zero_centered = spec.norm == "zero_centered"
+    rotary = dict(positions=positions,
+                  frequencies=np.asarray(spec.frequencies, np.float32))
 
+    def by_xla(t, gain=None):
+        """The heads ``t`` [B, S, n, D] normed and rotated."""
+        if gain is not None:
+            t = rms_norm(t, gain, spec.eps, zero_centered)
+        if spec.lanes:
+            t = apply_rotary(t, spec.lanes, None, seq_axis=1,
+                             factor=spec.rotary_factor, **rotary)
+        return t
 
-def rotary_gqa_attention_mixer(p, x, *, heads, kv_heads, head_dim, rope_theta,
-                               eps, zero_centered=False, positions=None,
-                               block_diffusion=0, mesh=None):
-    """Grouped-query attention with per-head q/k norms and rotary on every
-    lane, over normalized ``x`` [B, S, E]: wq [E, heads * D], wk/wv [E,
-    kv_heads * D]; an RMS norm over D on q (q_norm [D], one gain for all
-    heads) and on k (k_norm [D]), THEN rotary on all D lanes at
-    ``positions`` [S] (None: 0..S-1); kv head j serves query heads ``j *
-    heads / kv_heads`` onward (repeated before the kernels); wo [heads * D,
-    E]. No bias, no gate. Causal, or with ``block_diffusion=B`` under the
-    block-diffusion mask over a ``[noisy ; clean]`` row (ops/attention.py),
-    whose halves then carry the same ``positions``."""
-    b, s, _ = x.shape
-    fused = qk_prep_path(b, s, heads, head_dim, head_dim, mesh)[0] == "fused"
-    with jax.named_scope("attn_mixer"):
-        if fused:
-            q, k = _qk_prep(
-                (x @ p["wq"], x @ p["wk"]), (p["q_norm"], p["k_norm"]),
-                head_dim, rotary_angles(s, head_dim, rope_theta, positions),
-                eps, zero_centered)
-            v = (x @ p["wv"]).reshape(b, s, kv_heads, head_dim).transpose(
-                0, 2, 1, 3)
-        else:
-            q = (x @ p["wq"]).reshape(b, s, heads, head_dim)
-            k = (x @ p["wk"]).reshape(b, s, kv_heads, head_dim)
-            v = (x @ p["wv"]).reshape(b, s, kv_heads, head_dim)
-            q, k = (
-                apply_rotary(rms_norm(t, gain, eps, zero_centered), head_dim,
-                             rope_theta, seq_axis=1, positions=positions)
-                for t, gain in ((q, p["q_norm"]), (k, p["k_norm"])))
-            q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
-        ctx = attention(q, k, v, causal=not block_diffusion, mesh=mesh,
-                        block_diffusion=block_diffusion)
-        return ctx.transpose(0, 2, 1, 3).reshape(b, s, heads * head_dim) \
-            @ p["wo"]
-
-
-def head_gated_attention_mixer(p, x, *, heads, kv_heads, head_dim,
-                               rotary_lanes, frequencies, rotary_factor=1.0,
-                               window=0, mesh=None):
-    """Grouped-query attention gated PER HEAD, over normalized ``x`` [B, S,
-    E]: wq [E, heads * D], wk/wv [E, kv_heads * D], wg [E, heads], wo [heads
-    * D, E]; no q/k norm, no bias. Rotary on the first ``rotary_lanes`` lanes
-    of q and k at the ``frequencies`` [rotary_lanes / 2] given
-    (``rotary_frequencies``, ``yarn_frequencies``), cosine and sine times
-    ``rotary_factor``; kv head j serves query heads ``j * heads / kv_heads``
-    onward; causal, and with ``window=W`` a query sees its last W keys only
-    (the band inside the flash kernels, ops/attention.py); ``out = (context_h
-    * sigmoid((x wg)_h)) wo``: one gate a head and position, read from the
-    sublayer's own input. One mixer for a stack's full and windowed layers:
-    heads, lanes, frequencies, factor and window are the caller's; inside
-    ``attn_mixer`` the scope ``attn_window`` or ``attn_full`` names the
-    kind."""
-    b, s, _ = x.shape
-    fused = qk_prep_path(b, s, heads, head_dim, rotary_lanes, mesh)[0] == "fused"
-    kind = jax.named_scope("attn_window") if window \
-        else jax.named_scope("attn_full")
+    kind = jax.named_scope(spec.scope) if spec.scope \
+        else contextlib.nullcontext()
     with jax.named_scope("attn_mixer"), kind:
-        q, k = x @ p["wq"], x @ p["wk"]
-        v = (x @ p["wv"]).reshape(b, s, kv_heads, head_dim).transpose(
-            0, 2, 1, 3)
+        if packed:
+            qkv = x @ jnp.concatenate([p["wq"], p["wk"], p["wv"]], axis=1)
+            if fused:
+                qkv = qk_prep_in_place(
+                    qkv, rotary_angles(s, d, None, **rotary),
+                    heads=2 * heads, head_dim=d)
+            else:
+                qkv = qkv.reshape(b, s, 3 * heads, d)
+                qkv = jnp.concatenate(
+                    [by_xla(qkv[:, :, :2 * heads]), qkv[:, :, 2 * heads:]],
+                    axis=2)
+            return attention_packed(
+                qkv.reshape(b, s, 3 * heads * d), heads, causal=True,
+                mesh=mesh) @ p["wo"]
+        gains = (p["q_norm"], p["k_norm"]) if spec.norm else (None, None)
         if fused:
-            q, k = _qk_prep(
-                (q, k), (None, None), head_dim,
-                rotary_angles(s, rotary_lanes, None, frequencies=frequencies),
-                0.0, False, rotary_factor)
-        else:
+            if spec.gate == "lanes":
+                # q and the gate as two products of wq's two halves of each
+                # head: both lane-dense [B, S, heads * D], no interleave to
+                # take apart
+                wq = p["wq"].reshape(-1, heads, 2, d)
+                q, gate = (x @ wq[:, :, i].reshape(-1, heads * d)
+                           for i in (0, 1))
+                gate = gate.reshape(b, s, heads, d)
+            else:
+                q = x @ p["wq"]
+            k = x @ p["wk"]
+            angle = rotary_angles(s, spec.lanes, None, **rotary)
             q, k = (
-                apply_rotary(
-                    t.reshape(b, s, n, head_dim), rotary_lanes, None,
-                    seq_axis=1, frequencies=frequencies, factor=rotary_factor
-                ).transpose(0, 2, 1, 3)
-                for t, n in ((q, heads), (k, kv_heads)))
-        ctx = attention(q, k, v, causal=True, window=window, mesh=mesh)
-        gate = jax.nn.sigmoid(jnp.dot(
-            x, p["wg"], preferred_element_type=jnp.float32))
-        ctx = ctx.transpose(0, 2, 1, 3) * gate[..., None].astype(ctx.dtype)
-        return ctx.reshape(b, s, heads * head_dim) @ p["wo"]
-
-
-def rotary_attention_mixer(p, x, *, heads, head_dim, rope_theta, mesh=None):
-    """Causal multi-head attention (as many kv heads as query heads) with
-    rotary on every lane of q and k, over normalized ``x`` [B, S, E]: wq,
-    wk, wv [E, heads * D] multiplied as ONE projection q | k | v, which
-    ``attention_packed`` takes as it is once q and k are rotated in place
-    ([B, S, 3 * heads * D]: no head transpose on either side of the
-    kernels); wo [heads * D, E]. No bias, no q/k norm, no gate."""
-    from .attention import attention_packed
-
-    b, s, _ = x.shape
-    fused = qk_prep_path(b, s, heads, head_dim, head_dim, mesh)[0] == "fused"
-    with jax.named_scope("attn_mixer"):
-        qkv = x @ jnp.concatenate([p["wq"], p["wk"], p["wv"]], axis=1)
-        if fused:
-            qkv = qk_prep_in_place(
-                qkv, rotary_angles(s, head_dim, rope_theta),
-                heads=2 * heads, head_dim=head_dim)
+                qk_prep(t, gain, angle, head_dim=d, eps=spec.eps,
+                        zero_centered=zero_centered,
+                        factor=spec.rotary_factor)
+                for t, gain in zip((q, k), gains))
         else:
-            qkv = qkv.reshape(b, s, 3 * heads, head_dim)
-            qk = apply_rotary(
-                qkv[:, :, :2 * heads], head_dim, rope_theta, seq_axis=1)
-            qkv = jnp.concatenate([qk, qkv[:, :, 2 * heads:]], axis=2)
-        ctx = attention_packed(
-            qkv.reshape(b, s, 3 * heads * head_dim), heads, causal=True,
-            mesh=mesh)
-        return ctx @ p["wo"]
+            q = (x @ p["wq"]).reshape(b, s, heads, -1)
+            if spec.gate == "lanes":
+                q, gate = q[..., :d], q[..., d:]
+            q = by_xla(q, gains[0]).transpose(0, 2, 1, 3)
+            k = by_xla((x @ p["wk"]).reshape(b, s, kv_heads, d),
+                       gains[1]).transpose(0, 2, 1, 3)
+        v = (x @ p["wv"]).reshape(b, s, kv_heads, d).transpose(0, 2, 1, 3)
+        ctx = attention(q, k, v, causal=not spec.block_diffusion, mesh=mesh,
+                        block_diffusion=spec.block_diffusion,
+                        window=spec.window).transpose(0, 2, 1, 3)
+        if spec.gate == "head":
+            gate = jax.nn.sigmoid(jnp.dot(
+                x, p["wg"], preferred_element_type=jnp.float32))[..., None]
+        elif spec.gate:
+            gate = jax.nn.sigmoid(gate.astype(jnp.float32))
+        if spec.gate:
+            ctx = ctx * gate.astype(ctx.dtype)
+        return ctx.reshape(b, s, heads * d) @ p["wo"]
 
 
 def swiglu_ffn_mixer(p, x):

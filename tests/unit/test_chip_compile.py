@@ -318,37 +318,38 @@ def test_layer_body_hands_the_kernels_the_projections_own_buffer():
 
 
 # the two claimed cells' attention mixers alone (ops/transformer.py), forward
-# and backward under the cell's remat policy: (mixer, its arguments, x's
-# shape, the remat policy)
+# and backward under the cell's remat policy: (HybridLMConfig's fields for
+# the kind's spec, x's shape, the remat policy)
 ROTARY_MIXERS = {
     # SDAR: 32 query heads on 4 kv heads of 128, q/k norms, a [noisy ; clean]
     # row of 2 x 8,192 positions whose halves repeat the position ids
-    "A": ("rotary_gqa_attention_mixer",
-          dict(heads=32, kv_heads=4, head_dim=128, rope_theta=1e6, eps=1e-6,
-               block_diffusion=4),
+    "A": (dict(attn_heads=32, kv_heads=4, head_dim=128, rope_theta=1e6,
+               norm_eps=1e-6, objective="block_diffusion", diffusion_block=4),
           (2, 16384, 2048), "nothing_saveable+flash_out+flash_lse+moe_plan"),
     # Ouro: 16 heads of 128 out of one q | k | v product
-    "R": ("rotary_attention_mixer",
-          dict(heads=16, head_dim=128, rope_theta=1e6),
+    "R": (dict(attn_heads=16, head_dim=128, rope_theta=1e6),
           (1, 8192, 2048), "nothing_saveable+flash_out+flash_lse"),
 }
 
 
 @functools.lru_cache(maxsize=None)
 def _rotary_mixer_text(kind):
+    from deepspeed_tpu.models.hybrid import HybridLMConfig, attention_spec
     from deepspeed_tpu.ops import transformer
 
-    name, args, (b, s, e), policy = ROTARY_MIXERS[kind]
-    args = dict(args)
-    heads, d = args["heads"], args["head_dim"]
-    kv = args.get("kv_heads", heads)
+    fields, (b, s, e), policy = ROTARY_MIXERS[kind]
+    spec = attention_spec(
+        HybridLMConfig(pattern=kind, hidden_size=e, **fields), kind)
+    heads, kv, d = spec.heads, spec.kv_heads, spec.head_dim
     p = {"wq": (e, heads * d), "wk": (e, kv * d), "wv": (e, kv * d),
          "wo": (heads * d, e)}
+    positions = None
     if kind == "A":
         p.update(q_norm=(d,), k_norm=(d,))
-        args["positions"] = jnp.concatenate([jnp.arange(s // 2)] * 2)
+        positions = jnp.concatenate([jnp.arange(s // 2)] * 2)
     p = {k: _shape(v, jnp.bfloat16) for k, v in p.items()}
-    mixer = functools.partial(getattr(transformer, name), **args)
+    mixer = functools.partial(
+        transformer.attention_mixer, spec=spec, positions=positions)
 
     def loss(p, x):
         return jax.checkpoint(

@@ -195,17 +195,20 @@ def test_the_scans_own_time_is_slices_and_writes(window_ops, family):
 
 def test_every_scope_the_program_opens_is_in_the_catalog():
     """The catalog of docs/observability.md names every ``jax.named_scope``
-    of the program's source, and ``benchmark/scopes/`` (what the readers
-    split a window by) lists none that the program does not open. A new
-    scope needs its row in the catalog; it joins the readers' lists by a new
-    file under ``benchmark/scopes/``, and reads as unscoped until then."""
+    of the program's source (a literal, or the ``scope=`` a layer kind's
+    ``AttentionSpec`` hands ``attention_mixer``), and ``benchmark/scopes/``
+    (what the readers split a window by) lists none that the program does
+    not open. A new scope needs its row in the catalog; it joins the readers'
+    lists by a new file under ``benchmark/scopes/``, and reads as unscoped
+    until then."""
     opened = set()
     for folder, _dirs, files in os.walk(os.path.join(REPO, "deepspeed_tpu")):
         for name in files:
             if name.endswith(".py"):
                 with open(os.path.join(folder, name)) as fd:
                     opened |= set(re.findall(
-                        r'jax\.named_scope\(\s*"(\w+)"', fd.read()))
+                        r'(?:jax\.named_scope\(\s*|\bscope=)"(\w+)"',
+                        fd.read()))
     with open(os.path.join(REPO, "docs", "observability.md")) as fd:
         catalog = fd.read().split("## Device scopes", 1)[1]
     assert not [s for s in opened if f"`{s}`" not in catalog]

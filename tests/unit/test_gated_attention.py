@@ -1,9 +1,9 @@
-"""The gated attention mixer (ops/transformer.py:gated_attention_mixer)
-against its plain reference (benchmark/reference/qwen3_next.py): partial
-rotary, zero-centred q/k norms, the output gate, 8 query heads a kv head;
-the three flash kernels at head width 256 against the XLA softmax path,
-values and gradients (interpret mode on the CPU); the ``attention_layout``
-log line for that shape."""
+"""The gated attention mixer (ops/transformer.py:attention_mixer under kind
+``G``'s spec, models/hybrid.py:attention_spec) against its plain reference
+(benchmark/reference/qwen3_next.py): partial rotary, zero-centred q/k norms,
+the output gate, 8 query heads a kv head; the three flash kernels at head
+width 256 against the XLA softmax path, values and gradients (interpret mode
+on the CPU); the ``attention_layout`` log line for that shape."""
 
 import importlib
 import logging
@@ -15,9 +15,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from deepspeed_tpu.models.hybrid import HybridLMConfig, attention_spec
 from deepspeed_tpu.ops.transformer import (
     apply_rotary,
-    gated_attention_mixer,
+    attention_mixer,
     rotary_frequencies,
 )
 from deepspeed_tpu.utils.logging import logger
@@ -51,11 +52,12 @@ def leaves(rng, cfg=CFG):
 
 
 def ours(p, x, cfg=CFG):
-    return gated_attention_mixer(
-        p, x, heads=cfg["num_attention_heads"],
+    return attention_mixer(p, x, attention_spec(HybridLMConfig(
+        pattern="G", hidden_size=cfg["hidden_size"],
+        attn_heads=cfg["num_attention_heads"],
         kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
         rotary_lanes=int(cfg["partial_rotary_factor"] * cfg["head_dim"]),
-        rope_theta=cfg["rope_theta"], eps=cfg["rms_norm_eps"])
+        rope_theta=cfg["rope_theta"], norm_eps=cfg["rms_norm_eps"]), "G"))
 
 
 def theirs(p, x, cfg=CFG):
@@ -65,7 +67,7 @@ def theirs(p, x, cfg=CFG):
 
 
 @pytest.mark.parametrize("kv_heads", [1, 2])
-def test_gated_attention_mixer_matches_reference(kv_heads):
+def test_gated_attention_matches_reference(kv_heads):
     """Output and the gradient of every leaf and of the input: 8 (and 4)
     query heads a kv head, 4 of 16 lanes rotating."""
     cfg = dict(CFG, num_key_value_heads=kv_heads)

@@ -19,7 +19,8 @@ from deepspeed_tpu.models import HybridCausalLM, HybridLMConfig
 from deepspeed_tpu.ops import moe as moe_ops
 from deepspeed_tpu.ops import ssm
 from deepspeed_tpu.ops.attention import attention
-from deepspeed_tpu.ops.transformer import gqa_attention_mixer, rms_norm
+from deepspeed_tpu.models.hybrid import attention_spec
+from deepspeed_tpu.ops.transformer import attention_mixer, rms_norm
 from deepspeed_tpu.parallel.mesh import build_mesh
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -84,9 +85,7 @@ def mixers(cfg=TOY):
         "moe": lambda p, x: moe_ops.latent_moe_mixer(
             p, x, top_k=pc.top_k, scale=pc.routed_scaling,
             held=pc.n_experts_held, offset=pc.expert_offset, tile=pc.moe_tile)[0],
-        "attn": lambda p, x: gqa_attention_mixer(
-            p, x, heads=pc.attn_heads, kv_heads=pc.kv_heads,
-            head_dim=pc.head_dim),
+        "attn": lambda p, x: attention_mixer(p, x, attention_spec(pc, "*")),
     }
 
 

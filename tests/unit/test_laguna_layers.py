@@ -1,14 +1,16 @@
 """The sublayers that Laguna-S-2.1 adds (models/hybrid.py kinds ``H``, ``W``
 and ``U``) against the plain float32 reference (benchmark/reference/laguna.py)
 on seeded weights at toy widths on the CPU: grouped-query attention gated per
-head (ops/transformer.py:head_gated_attention_mixer), full with YaRN on part
-of the lanes and windowed with plain rotary, on the XLA path and through the
-flash kernels in interpret mode, and through the fused q/k pass against the
-XLA functions; the YaRN table against its closed form and the factor on the
-rotated lanes only; the experts with a routed scaling factor and an ungated
-shared expert (ops/moe.py:gated_moe_mixer), the learned selection and a
-skewed router included; and the THIRTY-TWO shares' expert parts, with the
-shared expert counted once, adding up to the uncut layer."""
+head (ops/transformer.py:attention_mixer under the kinds' specs,
+models/hybrid.py:attention_spec), full with YaRN on part of the lanes and
+windowed with plain rotary, on the XLA path and through the flash kernels in
+interpret mode (through the fused q/k pass against the XLA functions:
+test_qk_prep.py, beside the other kinds); the YaRN table against its closed
+form and the factor on the rotated lanes only; the experts with a routed
+scaling factor and an ungated shared expert (ops/moe.py:gated_moe_mixer), the
+learned selection and a skewed router included; and the THIRTY-TWO shares'
+expert parts, with the shared expert counted once, adding up to the uncut
+layer."""
 
 import importlib
 import os
@@ -19,17 +21,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from deepspeed_tpu.models.hybrid import HybridLMConfig, attention_spec
 from deepspeed_tpu.ops.moe import gated_moe_mixer
 from deepspeed_tpu.ops.transformer import (
     apply_rotary,
-    head_gated_attention_mixer,
+    attention_mixer,
     rotary_frequencies,
     yarn_frequencies,
 )
 
 attn_ops = importlib.import_module("deepspeed_tpu.ops.attention")
-T = importlib.import_module("deepspeed_tpu.ops.transformer")
-qp = importlib.import_module("deepspeed_tpu.ops.qk_prep")
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
@@ -115,15 +116,26 @@ def attn_leaves(rng, kind, cfg=CFG):
             "wo": 0.3 * normal(rng, hq * d, e)}
 
 
+def attn_spec(kind, cfg=CFG):
+    """Kinds ``H`` (full) and ``W`` (win) out of the table."""
+    return attention_spec(HybridLMConfig(
+        pattern="HW", hidden_size=cfg["hidden_size"],
+        attn_heads=cfg["num_attention_heads"],
+        window_attn_heads=cfg["sliding_attention_heads"],
+        kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
+        rotary_lanes=cfg["full_rotary_lanes"],
+        rope_theta=cfg["full_rope_theta"], yarn_factor=cfg["yarn_factor"],
+        yarn_original_positions=cfg["yarn_original_positions"],
+        yarn_beta_fast=cfg["yarn_beta_fast"],
+        yarn_beta_slow=cfg["yarn_beta_slow"],
+        rotary_attention_factor=cfg["yarn_attention_factor"],
+        window=cfg["sliding_window"],
+        window_rope_theta=cfg["sliding_rope_theta"]),
+        {"full": "H", "win": "W"}[kind])
+
+
 def our_attn(p, x, kind, cfg=CFG):
-    full = kind == "full"
-    return head_gated_attention_mixer(
-        p, x, heads=ref.heads(cfg, kind), kv_heads=cfg["num_key_value_heads"],
-        head_dim=cfg["head_dim"],
-        rotary_lanes=cfg["full_rotary_lanes"] if full else cfg["head_dim"],
-        frequencies=frequencies(cfg, kind),
-        rotary_factor=cfg["yarn_attention_factor"] if full else 1.0,
-        window=0 if full else cfg["sliding_window"])
+    return attention_mixer(p, x, attn_spec(kind, cfg))
 
 
 @pytest.mark.parametrize("flash", [False, True])
@@ -184,54 +196,6 @@ def test_the_window_cuts_and_the_gate_is_one_a_head():
         our_attn(dict(even, wo=without["wo"]), x, "win"), atol=1e-6)
     assert float(jnp.max(jnp.abs(
         our_attn(closed, x, "win") - our_attn(even, x, "win")))) > 1e-3
-
-
-@pytest.mark.parametrize("kind", ["full", "win"])
-def test_mixer_through_the_kernels_matches_the_mixer_through_xla(
-        kind, monkeypatch):
-    """At a head width the q/k kernels take (128): no norm, rotary on 64 of
-    128 lanes with the factor (full) or on all 128 (windowed), forward and
-    backward, against ``apply_rotary``; float32, so the two paths differ by
-    summation order only. The kernels lower under the kind's scope inside
-    ``attn_mixer``."""
-    import re
-
-    monkeypatch.setattr(jax, "device_count", lambda: 1)
-    cfg = dict(CFG, head_dim=128, full_rotary_lanes=64,
-               yarn_original_positions=64, sliding_window=24)
-    rng = np.random.default_rng(5)
-    p = attn_leaves(rng, kind, cfg)
-    x, probe = normal(rng, 2, 64, 48), normal(rng, 2, 64, 48)
-
-    def run():
-        out = our_attn(p, x, kind, cfg)
-        grads = jax.grad(
-            lambda p, x: jnp.sum(our_attn(p, x, kind, cfg) * probe),
-            (0, 1))(p, x)
-        return out, grads
-
-    lanes = 64 if kind == "full" else 128
-    assert qp.qk_prep_path(2, 64, ref.heads(cfg, kind), 128, lanes)[0] == "fused"
-    scope = {"full": "attn_full", "win": "attn_window"}[kind]
-    compiled = jax.jit(jax.grad(
-        lambda p, x: jnp.sum(our_attn(p, x, kind, cfg)))).lower(
-            p, x).compile().as_text()
-    assert re.search(rf"attn_mixer\)*/{scope}/qk_prep_fwd/", compiled)
-    assert re.search(rf"attn_mixer\)*/{scope}/qk_prep_bwd/", compiled)
-    other = {"full": "attn_window", "win": "attn_full"}[kind]
-    assert f"/{other}/" not in compiled
-    fused = run()
-    monkeypatch.setattr(
-        T, "qk_prep_path", lambda *a, **k: ("xla", "held by the test"))
-    xla = run()
-    assert "qk_prep" not in jax.jit(
-        lambda p, x: our_attn(p, x, kind, cfg)).lower(p, x).as_text()
-    np.testing.assert_allclose(
-        xla[0], ref.attn(p, x, cfg, DOT, kind), atol=2e-5, rtol=2e-5)
-    for a, b in zip(jax.tree_util.tree_leaves(fused),
-                    jax.tree_util.tree_leaves(xla)):
-        np.testing.assert_allclose(
-            a, b, rtol=2e-4, atol=2e-5 * float(jnp.max(jnp.abs(b))))
 
 
 def expert_leaves(rng, cfg=CFG, skew=0.0):

@@ -2,8 +2,8 @@
 ``qk_prep_bwd``; interpret mode on the CPU) against ``apply_rotary(
 rms_norm(..))`` and against ``jax.grad`` of it, for the three mixers'
 parameter sets; the packed in-place form; ``qk_prep_path``'s choices and its
-log line; the three mixers through the kernels against the same mixers
-through the XLA functions."""
+log line; the one mixer under each rotating kind's spec through the kernels
+against the same through the XLA functions."""
 
 import logging
 import re
@@ -13,9 +13,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from deepspeed_tpu.models.hybrid import HybridLMConfig, attention_spec
 from deepspeed_tpu.ops import qk_prep as qp
 from deepspeed_tpu.ops import transformer as T
 from deepspeed_tpu.utils.logging import logger
+from tests.unit import test_laguna_layers as laguna
 
 # (heads, head width, rotated lanes, zero-centred gains, gains at all,
 # position ids repeat): SDAR's q and k (plain gains, every lane, the two
@@ -214,52 +216,63 @@ def test_the_path_is_chosen_from_shapes_and_mesh_and_logged_once(caplog):
     assert results[2][1] == "a kernel is not partitioned over devices"
 
 
+# HybridLMConfig's fields for each attention kind at a head width the
+# kernels take; H and W are tests/unit/test_laguna_layers.py's at width 128
+FIELDS = {
+    "A": dict(attn_heads=4, kv_heads=2, head_dim=128,
+              objective="block_diffusion", diffusion_block=4),
+    "G": dict(attn_heads=2, kv_heads=1, head_dim=256, rotary_lanes=64),
+    "R": dict(attn_heads=2, kv_heads=2, head_dim=128),
+}
+LAGUNA = dict(laguna.CFG, head_dim=128, full_rotary_lanes=64,
+              yarn_original_positions=64, sliding_window=24)
+
+
 def _mixer(kind, dtype):
-    """(mixer over (p, x), leaves, x) at a width the kernels take."""
+    """(mixer over (p, x), leaves, x, the plain reference or None) at a width
+    the kernels take, the spec out of ``attention_spec``'s table."""
     rng = np.random.default_rng(5)
     e, s = 64, 64
 
     def leaf(*shape, scale=0.3):
         return jnp.asarray(scale * rng.normal(size=shape), dtype)
 
+    if kind in "HW":
+        name = {"H": "full", "W": "win"}[kind]
+        return (
+            lambda p, x: laguna.our_attn(p, x, name, LAGUNA),
+            laguna.attn_leaves(rng, name, LAGUNA), laguna.normal(rng, 2, s, 48),
+            lambda p, x: laguna.ref.attn(p, x, LAGUNA, laguna.DOT, name))
+    spec = attention_spec(HybridLMConfig(
+        pattern=kind, hidden_size=e, rope_theta=THETA, norm_eps=EPS,
+        **FIELDS[kind]), kind)
+    heads, kv, d = spec.heads, spec.kv_heads, spec.head_dim
+    positions = None
     if kind == "A":
-        heads, kv, d = 4, 2, 128
         p = {"wq": leaf(e, heads * d), "q_norm": 1 + leaf(d, scale=0.2),
              "k_norm": 1 + leaf(d, scale=0.2)}
         positions = jnp.concatenate([jnp.arange(s // 2)] * 2)
-
-        def mixer(p, x):
-            return T.rotary_gqa_attention_mixer(
-                p, x, heads=heads, kv_heads=kv, head_dim=d, rope_theta=THETA,
-                eps=EPS, positions=positions, block_diffusion=4)
     elif kind == "G":
-        heads, kv, d = 2, 1, 256
         p = {"wq": leaf(e, heads * 2 * d), "q_norm": leaf(d, scale=0.2),
              "k_norm": leaf(d, scale=0.2)}
-
-        def mixer(p, x):
-            return T.gated_attention_mixer(
-                p, x, heads=heads, kv_heads=kv, head_dim=d, rotary_lanes=64,
-                rope_theta=THETA, eps=EPS)
     else:
-        heads, kv, d = 2, 2, 128
         p = {"wq": leaf(e, heads * d)}
-
-        def mixer(p, x):
-            return T.rotary_attention_mixer(
-                p, x, heads=heads, head_dim=d, rope_theta=THETA)
     p.update(wk=leaf(e, kv * d), wv=leaf(e, kv * d), wo=leaf(heads * d, e))
-    return mixer, p, leaf(2, s, e, scale=1.0)
+    return (lambda p, x: T.attention_mixer(p, x, spec, positions=positions),
+            p, leaf(2, s, e, scale=1.0), None)
 
 
-@pytest.mark.parametrize("kind", ["A", "G", "R"])
+@pytest.mark.parametrize("kind", ["A", "G", "R", "H", "W"])
 def test_mixers_through_the_kernels_match_the_mixers_through_xla(
         kind, monkeypatch):
-    """Each of the three mixers at a head width the kernels take (float32:
-    the two paths then differ by summation order only), output and the
-    gradient of every leaf and of the input; the kernels lower under
-    ``attn_mixer`` by name."""
-    mixer, p, x = _mixer(kind, jnp.float32)
+    """The one mixer under each rotating kind's spec at a head width the
+    kernels take (float32: the two paths then differ by summation order
+    only), output and the gradient of every leaf and of the input; H and W
+    (no norm, rotary on 64 of 128 lanes with the factor, or on all 128 under
+    the window) against the plain reference too. The kernels lower under
+    ``attn_mixer`` by name, and under the kind's own scope inside it where
+    it opens one."""
+    mixer, p, x, reference = _mixer(kind, jnp.float32)
     probe = jnp.asarray(
         np.random.default_rng(6).normal(size=x.shape), jnp.float32)
 
@@ -270,17 +283,24 @@ def test_mixers_through_the_kernels_match_the_mixers_through_xla(
         return out, grads
 
     assert qp.qk_prep_path(2, 64, 2, 128, 128)[0] == "fused"
+    assert qp.qk_prep_path(2, 64, 4, 128, 64)[0] == "fused"
     # interpret mode lowers a kernel to operations that carry its name where
     # the chip has one custom call
     compiled = jax.jit(jax.grad(
         lambda p, x: jnp.sum(mixer(p, x)))).lower(p, x).compile().as_text()
-    assert re.search(r"attn_mixer\)*/qk_prep_fwd/", compiled)
-    assert re.search(r"attn_mixer\)*/qk_prep_bwd/", compiled)
+    scope = {"H": "attn_full/", "W": "attn_window/"}.get(kind, "")
+    assert re.search(rf"attn_mixer\)*/{scope}qk_prep_fwd/", compiled)
+    assert re.search(rf"attn_mixer\)*/{scope}qk_prep_bwd/", compiled)
+    for other in {"attn_full/", "attn_window/"} - {scope}:
+        assert "/" + other not in compiled
     fused = run()
     monkeypatch.setattr(
         T, "qk_prep_path", lambda *a, **k: ("xla", "held by the test"))
     xla = run()
     assert "qk_prep" not in jax.jit(mixer).lower(p, x).as_text()
+    if reference:
+        np.testing.assert_allclose(
+            xla[0], reference(p, x), atol=2e-5, rtol=2e-5)
     for a, b in zip(jax.tree_util.tree_leaves(fused),
                     jax.tree_util.tree_leaves(xla)):
         np.testing.assert_allclose(

@@ -2,7 +2,8 @@
 ``A`` and ``S``) against the plain float32 reference
 (benchmark/reference/sdar.py) on seeded weights at toy widths on the CPU:
 rotary grouped-query attention with q/k norms under the block-diffusion mask
-(ops/transformer.py:rotary_gqa_attention_mixer), on the XLA path and through
+(ops/transformer.py:attention_mixer under kind ``A``'s spec,
+models/hybrid.py:attention_spec), on the XLA path and through
 the flash kernels in interpret mode, and causal; the experts with no shared
 one (ops/moe.py:gated_moe_mixer), the learned selection and a skewed router
 included; and the EIGHT shares' expert parts adding up to the uncut layer."""
@@ -16,11 +17,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from deepspeed_tpu.models.hybrid import HybridLMConfig, attention_spec
 from deepspeed_tpu.ops.moe import gated_moe_mixer
-from deepspeed_tpu.ops.transformer import (
-    apply_rotary,
-    rotary_gqa_attention_mixer,
-)
+from deepspeed_tpu.ops.transformer import apply_rotary, attention_mixer
 
 attn_ops = importlib.import_module("deepspeed_tpu.ops.attention")
 
@@ -55,13 +54,21 @@ def as_reference(p):
             for k, v in p.items()}
 
 
+def attn_spec(block, cfg=CFG):
+    """Kind ``A`` out of the table; ``block`` 0: the next-token objective."""
+    return attention_spec(HybridLMConfig(
+        pattern="A", hidden_size=cfg["hidden_size"],
+        attn_heads=cfg["num_attention_heads"],
+        kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
+        rope_theta=cfg["rope_theta"], norm_eps=cfg["rms_norm_eps"],
+        objective="block_diffusion" if block else "next_token",
+        diffusion_block=block or cfg["block_length"]), "A")
+
+
 def our_attn(p, x, block=4, cfg=CFG):
     half = x.shape[1] // 2
-    return rotary_gqa_attention_mixer(
-        p, x, heads=cfg["num_attention_heads"],
-        kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
-        rope_theta=cfg["rope_theta"], eps=cfg["rms_norm_eps"],
-        positions=jnp.arange(2 * half) % half, block_diffusion=block)
+    return attention_mixer(
+        p, x, attn_spec(block, cfg), positions=jnp.arange(2 * half) % half)
 
 
 @pytest.mark.parametrize("flash", [False, True])
@@ -100,9 +107,8 @@ def test_mixer_is_causal_without_the_objective():
     causal mask; an early output does not move with a late input."""
     rng = np.random.default_rng(1)
     p, x = attn_leaves(rng), normal(rng, 1, 32, 48)
-    kw = dict(heads=8, kv_heads=1, head_dim=16, rope_theta=1e6, eps=1e-6)
-    out = rotary_gqa_attention_mixer(p, x, **kw)
-    moved = rotary_gqa_attention_mixer(p, x.at[:, 20:].add(1.0), **kw)
+    out = attention_mixer(p, x, attn_spec(0))
+    moved = attention_mixer(p, x.at[:, 20:].add(1.0), attn_spec(0))
     np.testing.assert_allclose(out[:, :20], moved[:, :20], atol=1e-6)
     assert float(jnp.max(jnp.abs(out[:, 20:] - moved[:, 20:]))) > 1e-3
 

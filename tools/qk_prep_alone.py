@@ -13,6 +13,7 @@ import json, os, sys, time
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 import jax, jax.numpy as jnp
+from deepspeed_tpu.models.hybrid import HybridLMConfig, attention_spec
 from deepspeed_tpu.ops import transformer as T
 
 BF = jnp.bfloat16
@@ -40,21 +41,17 @@ def timed(fn, *args, n=10):
 
 def mixer_of(c, path):
     """The cell's mixer with the chooser held to ``path`` ("fused" | "xla")."""
-    m, H, KV, D = c["mixer"], c["H"], c["KV"], c["D"]
+    spec = attention_spec(HybridLMConfig(
+        pattern=c["mixer"], hidden_size=c["E"], attn_heads=c["H"], kv_heads=c["KV"], head_dim=c["D"],
+        rotary_lanes=c["R"], rope_theta=c["theta"], norm_eps=1e-6, norm_zero_centered=c["zc"],
+        objective="block_diffusion" if c["bd"] else "next_token", diffusion_block=c["bd"] or 4), c["mixer"])
     pos = jnp.concatenate([jnp.arange(c["S"] // 2)] * 2) if c["bd"] else None
 
     def run(p, x):
         real = T.qk_prep_path
         T.qk_prep_path = lambda *a, **k: (path, "held by tools/qk_prep_alone.py")
         try:
-            if m == "A":
-                return T.rotary_gqa_attention_mixer(
-                    p, x, heads=H, kv_heads=KV, head_dim=D, rope_theta=c["theta"], eps=1e-6,
-                    positions=pos, block_diffusion=c["bd"])
-            if m == "R":
-                return T.rotary_attention_mixer(p, x, heads=H, head_dim=D, rope_theta=c["theta"])
-            return T.gated_attention_mixer(
-                p, x, heads=H, kv_heads=KV, head_dim=D, rotary_lanes=c["R"], rope_theta=c["theta"], eps=1e-6)
+            return T.attention_mixer(p, x, spec, positions=pos)
         finally:
             T.qk_prep_path = real
     return run
