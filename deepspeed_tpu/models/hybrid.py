@@ -6,8 +6,10 @@ linear-attention mixer (ops/linear_attention.py), ``X`` a mixture of
 SiLU-gated experts at the model's own width behind softmax routing, with a
 gated shared expert (ops/moe.py:gated_moe_mixer), ``S`` the experts of ``X``
 with no shared expert beside them, ``U`` the experts of ``X`` with an UNGATED
-shared expert and the routed sum times ``routed_scaling``, ``F`` a dense
-SiLU-gated FFN (ops/transformer.py:swiglu_ffn_mixer); and six letters of
+shared expert and the routed sum times ``routed_scaling``, ``B`` the experts
+of ``U`` chosen by SIGMOID scores plus a selection bias (a ``router_bias``
+leaf that chooses, weighs nothing and takes no gradient), ``F`` a dense
+SiLU-gated FFN (ops/transformer.py:swiglu_ffn_mixer); and seven letters of
 attention, all ops/transformer.py:attention_mixer, each under the spec that
 ``attention_spec`` makes of the configuration: ``*`` causal grouped-query
 attention; ``G`` gated softmax attention with zero-centred per-head q/k norms
@@ -17,13 +19,18 @@ every lane; ``H`` and ``W`` grouped-query attention gated per head with no
 q/k norm, ``H`` seeing the whole causal row with YaRN rotary on
 ``rotary_lanes`` lanes, ``W`` a sliding window of ``window`` keys (the band
 inside the flash kernels) with plain rotary on every lane, each with its own
-head count on the same kv heads. A published layer that is a token mixer
-THEN experts or an FFN is two letters (``DXDXDXGX`` is one period of three
-DeltaNet layers and one attention layer, each with its experts; ``RF`` and
-``AS`` are one decoder layer each; ``HF`` then ``WUWUWUHU`` repeated is a
-leading dense layer and periods of three windowed layers and a full one). No
+head count on the same kv heads; ``L`` multi-head LATENT attention: q and k
+out of two low-rank chains with a norm in the middle of each, ``mla_nope_dim
++ mla_rope_dim`` lanes a head of which the last ``mla_rope_dim`` rotate (the
+key's rotated part is ONE vector shared by the heads), v of ``mla_v_dim``
+lanes, the flash kernels at those unequal widths. A published layer that is
+a token mixer THEN experts or an FFN is two letters (``DXDXDXGX`` is one
+period of three DeltaNet layers and one attention layer, each with its
+experts; ``RF`` and ``AS`` are one decoder layer each; ``HF`` then ``WUWUWUHU`` repeated is a
+leading dense layer and periods of three windowed layers and a full one;
+``LF`` then ``LB`` repeated is a leading dense layer and sparse ones). No
 learned position embedding (the recurrent layers carry position; ``G``,
-``R``, ``A``, ``H`` and ``W`` rotate), a
+``R``, ``A``, ``H``, ``W`` and ``L`` rotate), a
 final RMS norm, an untied head, bias-free projections; ``norm_zero_centered``
 stores every norm's gain around 0 and applies ``1 + gain``; ``post_norm``
 gives ``R`` and ``F`` a second gain AFTER the mixer (``x + norm_b(mixer(
@@ -72,6 +79,23 @@ loss_weights[i] nll_i / (B L)``. The caller draws the noise: ``loss_weights``
 is ``1 / t`` of a position's block where the position was replaced by the
 mask id, else 0. It adds the ``diffusion/...`` counters.
 
+``mtp_depth`` > 0 adds MULTI-TOKEN PREDICTION: module k (1 .. depth) reads
+the state ``z`` that the head reads (the main stack's after its final norm,
+then module k - 1's) and the TABLE a second time, at ids shifted by k: ``u_i
+= mtp_proj [rms(embed[t_{i+k}]; mtp_embed_norm) ; rms(z_i; mtp_state_norm)]``
+([2 E] -> E), one layer of the stack's repeated unit on leaves of its own
+(``mtp_<kind>_<leaf>``, stacked over the modules as the stack's are over its
+layers: a checkpoint, a ZeRO spec and the optimizer see more leaves of the
+same form), ``rms(.; mtp_norm)`` and the SHARED head, scored against
+``t_{i+k+1}``. The loss is the main one plus ``mtp_loss_weight`` times the
+mean over the modules of each module's mean nll; the table's and the head's
+gradients are the sums of both uses. The module runs over all S positions of
+a row (the ids past the row's end are the row's first: those positions'
+targets do not exist and are not scored, and under causal mixers nothing
+they hold reaches a scored position), so the kernels see the stack's own
+shapes. It adds ``mtp/depth`` and ``mtp/loss`` (the modules' own mean nll,
+unweighted). ``labels=None`` gives the main logits only.
+
 ``HybridCausalLM(input_ids, labels)`` returns ``(loss, counters)``: the
 engine trains on the loss, and the routing counters (``moe/...``, summed or
 maximised over the E layers) leave the compiled window beside it through
@@ -107,9 +131,10 @@ from ..ops.transformer import (
 KINDS = {"M": "mamba", "E": "moe", "*": "attn",
          "D": "gdn", "G": "gattn", "X": "gmoe",
          "R": "rattn", "F": "ffn", "A": "qattn", "S": "smoe",
-         "H": "hattn", "W": "wattn", "U": "umoe"}
+         "H": "hattn", "W": "wattn", "U": "umoe",
+         "L": "lattn", "B": "bmoe"}
 # the token mixers that are causal by construction: not for block diffusion
-CAUSAL_ONLY = "M*DGRHW"
+CAUSAL_ONLY = "M*DGRHWL"
 OBJECTIVES = ("next_token", "block_diffusion")
 
 
@@ -206,11 +231,14 @@ class HybridLMConfig:
     moe_tile: int = 512
     # X: gated experts at the model's width. Shares the held/routed counts,
     # top_k, router_force_level, moe_intermediate, moe_shared_intermediate
-    # and moe_tile with E; has no latent, bias or scaling
-    # *, G, A and H: grouped-query attention. heads HELD here. R has
-    # attn_heads kv heads too; R and A rotate all head_dim lanes. S shares
-    # X's fields and has no shared expert; U shares them too, has no gate
-    # on its shared expert, and scales the routed sum by routed_scaling
+    # and moe_tile with E; has no latent, bias or scaling. S shares X's
+    # fields and has no shared expert; U shares them too, has no gate on its
+    # shared expert, and scales the routed sum by routed_scaling; B is U
+    # under E's routing: sigmoid scores, a selection bias, routed_scaling
+    # in the weights
+    # *, G, A, H and L: heads HELD here; *, G, A, H and W: grouped-query
+    # attention on kv_heads. R has attn_heads kv heads too; R and A rotate
+    # all head_dim lanes
     attn_heads: int = 2
     kv_heads: int = 1
     head_dim: int = 16
@@ -229,6 +257,14 @@ class HybridLMConfig:
     window: int = 0
     window_attn_heads: int = 0
     window_rope_theta: float = 10000.0
+    # L: latent attention on attn_heads heads. The ranks of q's and of k and
+    # v's low-rank chains; a head's unrotated and rotated q/k lanes (the
+    # kernels' q/k width is their sum) and its v lanes; rope_theta is the base
+    mla_q_rank: int = 24
+    mla_kv_rank: int = 16
+    mla_nope_dim: int = 16
+    mla_rope_dim: int = 8
+    mla_v_dim: int = 16
     # F: the dense gated FFN's width
     ffn_intermediate: int = 96
     # R and F: a second gain, after the mixer
@@ -237,6 +273,11 @@ class HybridLMConfig:
     # parameters; above 1 an exit gate weights the passes' losses
     passes: int = 1
     exit_entropy_weight: float = 0.1
+    # multi-token-prediction modules after the final norm (0: none), each one
+    # layer of the stack's repeated unit (the ``LB`` of ``LF`` + 5 x ``LB``) on
+    # leaves of its own; their mean nll joins the loss times mtp_loss_weight
+    mtp_depth: int = 0
+    mtp_loss_weight: float = 0.3
     # D: Gated DeltaNet. key heads each serve value_heads / key_heads value
     # heads; the chunk of the recurrence is a power of two; conv_kernel taps
     gdn_key_heads: int = 2
@@ -282,6 +323,15 @@ class HybridLMConfig:
             raise ValueError("R rotates lane pairs: head_dim must be even")
         if self.passes < 1:
             raise ValueError("passes must be at least 1")
+        if "L" in self.pattern and self.mla_rope_dim % 2:
+            raise ValueError("L rotates lane pairs: mla_rope_dim must be even")
+        if self.mtp_depth:
+            if self.mtp_depth < 0:
+                raise ValueError(f"mtp_depth {self.mtp_depth}")
+            if self.passes > 1 or self.objective != "next_token":
+                raise ValueError(
+                    "multi-token prediction is built for one pass of the "
+                    "next-token objective")
         if self.objective not in OBJECTIVES:
             raise ValueError(
                 f"objective {self.objective!r}: {', '.join(OBJECTIVES)} "
@@ -297,6 +347,12 @@ class HybridLMConfig:
                 and self.expert_offset + self.n_experts_held
                 <= self.n_experts_routed):
             raise ValueError("held experts must lie inside those routed over")
+
+    @property
+    def mtp_letters(self):
+        """The kinds of one multi-token-prediction module's layer: the
+        stack's repeated unit (the whole pattern where nothing repeats)."""
+        return stack_plan(self.pattern)[1]
 
     def heads(self, kind):
         """Query heads held here of an attention kind: W's own count where
@@ -322,6 +378,7 @@ class HybridLMConfig:
         }
         shared_expert = {
             "shared_wg": (e, fs), "shared_wu": (e, fs), "shared_wd": (fs, e)}
+        qk = self.mla_nope_dim + self.mla_rope_dim
 
         def head_gated(heads):
             return {
@@ -383,6 +440,19 @@ class HybridLMConfig:
             "hattn": head_gated(self.heads("hattn")),
             "wattn": head_gated(self.heads("wattn")),
             "umoe": {**gated_experts, **shared_expert},
+            "lattn": {
+                "norm": (e,), "wqa": (e, self.mla_q_rank),
+                "q_norm": (self.mla_q_rank,),
+                "wqb": (self.mla_q_rank, self.attn_heads * qk),
+                "wkva": (e, self.mla_kv_rank + self.mla_rope_dim),
+                "kv_norm": (self.mla_kv_rank,),
+                "wkvb": (self.mla_kv_rank, self.attn_heads * (
+                    self.mla_nope_dim + self.mla_v_dim)),
+                "wo": (self.attn_heads * self.mla_v_dim, e),
+            },
+            "bmoe": {
+                **gated_experts, "router_bias": (self.n_experts_routed,),
+                **shared_expert},
         }
 
 
@@ -390,7 +460,8 @@ class HybridLMConfig:
 # ``norm_zero_centered`` stores around 0); A_log and dt_bias start at the
 # family's usual spread, every other leaf at N(0, range)
 _ONES = ("gate_norm", "out_norm", "D")
-_GAINS = ("norm", "q_norm", "k_norm", "post_norm")
+_GAINS = ("norm", "q_norm", "k_norm", "kv_norm", "post_norm",
+          "embed_norm", "state_norm")
 _ZEROS = ("conv_b", "router_bias")
 
 
@@ -446,6 +517,13 @@ def attention_spec(cfg, kind):
     elif kind == "W":
         spec.update(rotary(d, cfg.window_rope_theta), gate="head",
                     scope="attn_window", window=cfg.window)
+    elif kind == "L":
+        spec.update(
+            rotary(cfg.mla_rope_dim, cfg.rope_theta), kv_heads=cfg.attn_heads,
+            head_dim=cfg.mla_nope_dim + cfg.mla_rope_dim, eps=cfg.norm_eps,
+            norm="zero_centered" if cfg.norm_zero_centered else None,
+            q_rank=cfg.mla_q_rank, kv_rank=cfg.mla_kv_rank,
+            v_dim=cfg.mla_v_dim, scope="attn_mla")
     return AttentionSpec(**spec)
 
 
@@ -453,12 +531,14 @@ class HybridModel(nn.Module):
     """input_ids [B, S] -> (hidden [B, S, E] after the final norm, the
     head's table, counters, None); a looped stack gives every pass's hidden
     [passes, B, S, E] and, last, its exit gate ``(gate_w, gate_b)``. Under
-    ``objective="block_diffusion"`` a row is ``[noisy ; clean]``: S = 2 L."""
+    ``objective="block_diffusion"`` a row is ``[noisy ; clean]``: S = 2 L.
+    ``mtp`` (``mtp_depth`` > 0): the multi-token-prediction modules run too
+    and their normed states [depth, B, S, E] come last."""
 
     config: HybridLMConfig
 
     @nn.compact
-    def __call__(self, input_ids):
+    def __call__(self, input_ids, mtp=False):
         cfg = self.config
         positions = None
         if cfg.objective == "block_diffusion":
@@ -469,15 +549,21 @@ class HybridModel(nn.Module):
         head = self.param("head", init, (cfg.vocab_size, cfg.hidden_size))
         norm_f = self.param(
             "norm_f", _leaf_init(cfg, "norm"), (cfg.hidden_size,))
-        params = {}
-        for kind, leaves in cfg.leaf_shapes().items():
-            n = sum(KINDS[c] == kind for c in cfg.pattern)
-            if n:
-                params[kind] = {
-                    leaf: self.param(
-                        f"{kind}_{leaf}", _leaf_init(cfg, leaf),
-                        (n,) + shape)
-                    for leaf, shape in leaves.items()}
+        def stacked(letters, times=1, prefix=""):
+            """{kind: {leaf: [its layers among ``letters`` x ``times``, ...]}},
+            the leaves named ``<prefix><kind>_<leaf>``."""
+            found = {}
+            for kind, leaves in cfg.leaf_shapes().items():
+                n = sum(KINDS[c] == kind for c in letters) * times
+                if n:
+                    found[kind] = {
+                        leaf: self.param(
+                            f"{prefix}{kind}_{leaf}", _leaf_init(cfg, leaf),
+                            (n,) + shape)
+                        for leaf, shape in leaves.items()}
+            return found
+
+        params = stacked(cfg.pattern)
 
         mixers = {
             "mamba": lambda p, x: (mamba2_mixer(
@@ -497,27 +583,31 @@ class HybridModel(nn.Module):
             "ffn": lambda p, x: (swiglu_ffn_mixer(p, x), {}),
         }
 
-        def gated_experts(scale=1.0):
+        def gated_experts(scale=1.0, route="softmax"):
             return lambda p, x: gated_moe_mixer(
                 p, x, top_k=cfg.top_k, held=cfg.n_experts_held,
                 offset=cfg.expert_offset, tile=cfg.moe_tile,
-                force_level=cfg.router_force_level, scale=scale,
+                force_level=cfg.router_force_level, scale=scale, route=route,
                 mesh=cfg.mesh)
 
         # one layer, by its leaves: with a gated shared expert, with none,
-        # with an ungated one beside a scaled routed sum
+        # with an ungated one beside a scaled routed sum; that under sigmoid
+        # scores and a selection bias
         mixers["gmoe"] = mixers["smoe"] = gated_experts()
         mixers["umoe"] = gated_experts(cfg.routed_scaling)
+        mixers["bmoe"] = gated_experts(cfg.routed_scaling, "sigmoid")
 
         def attention(kind):
-            """The one mixer under ``kind``'s spec. A head-gated layer counts
-            the query heads it runs (added up over layers and micro-steps)
-            and, windowed, the band and the share of the score square that
-            the banded kernels' walks visit."""
+            """The one mixer under ``kind``'s spec. A head-gated or latent
+            layer counts the query heads it runs (added up over layers and
+            micro-steps) and, windowed, the band and the share of the score
+            square that the banded kernels' walks visit."""
             def mixer(p, x):
                 spec = attention_spec(cfg, kind)
                 out = attention_mixer(
                     p, x, spec, positions=positions, mesh=cfg.mesh)
+                if kind == "L":
+                    return out, {"attn/mla_heads": jnp.int32(spec.heads)}
                 if kind not in "HW":
                     return out, {}
                 if not spec.window:
@@ -530,7 +620,7 @@ class HybridModel(nn.Module):
 
             return mixer
 
-        mixers.update({KINDS[kind]: attention(kind) for kind in "*GRAHW"})
+        mixers.update({KINDS[kind]: attention(kind) for kind in "*GRAHWL"})
 
         prefix, unit, repetitions, tail = stack_plan(cfg.pattern)
         scanned = repetitions > 1
@@ -614,11 +704,56 @@ class HybridModel(nn.Module):
                 return rms_norm(
                     x, norm_f, cfg.norm_eps, cfg.norm_zero_centered), counters
 
+        if mtp and cfg.mtp_depth:
+            e, depth, letters = cfg.hidden_size, cfg.mtp_depth, cfg.mtp_letters
+            modules = stacked(letters, depth, "mtp_")
+            joint = {
+                leaf: self.param(
+                    f"mtp_{leaf}", _leaf_init(cfg, leaf), (depth,) + shape)
+                for leaf, shape in {
+                    "embed_norm": (e,), "state_norm": (e,),
+                    "proj": (2 * e, e), "norm": (e,)}.items()}
+
+        def mtp_module(k, z):
+            """Module ``k + 1`` over the state ``z`` [B, S, E] that the head
+            reads: position i's token ``k + 1`` ahead out of the table beside
+            its state, one layer of ``letters`` on the module's own slices,
+            its own final norm. The ids past a row's end are the row's
+            first: what those positions hold is not scored."""
+            def normed(t, leaf):
+                return rms_norm(t, joint[leaf][k], cfg.norm_eps,
+                                cfg.norm_zero_centered)
+
+            with jax.named_scope("mtp"):
+                with jax.named_scope("mtp_proj"):
+                    ahead = embed[jnp.roll(input_ids, -(k + 1), axis=1)]
+                with jax.named_scope("stack_norms"):
+                    both = jnp.concatenate(
+                        [normed(ahead, "embed_norm"),
+                         normed(z, "state_norm")], axis=-1)
+                with jax.named_scope("mtp_proj"):
+                    u = both @ joint["proj"][k]
+                u, counters = walk(letters, remat)(u, {
+                    kind: {leaf: v if depth == 1 else
+                           v[k * v.shape[0] // depth:
+                             (k + 1) * v.shape[0] // depth]
+                           for leaf, v in leaves.items()}
+                    for kind, leaves in modules.items()})
+                with jax.named_scope("stack_norms"):
+                    return normed(u, "norm"), counters
+
         with jax.named_scope("embed"):
             x = embed[input_ids]
         if cfg.passes == 1:
             x, counters = one_pass(x)
-            return x, head, counters, None
+            if not (mtp and cfg.mtp_depth):
+                return x, head, counters, None
+            states, found, z = [], [counters] if counters else [], x
+            for k in range(cfg.mtp_depth):
+                z, counters = mtp_module(k, z)
+                states.append(z)
+                found += [counters] if counters else []
+            return x, head, merge_counters(found), jnp.stack(states)
         gate = (self.param("gate_w", init, (cfg.hidden_size,)),
                 self.param("gate_b", nn.initializers.zeros, (1,)))
 
@@ -657,7 +792,15 @@ class HybridCausalLM(nn.Module):
                 x[:, :labels.shape[1]], head, labels, loss_weights,
                 block_rows=cfg.ce_block_rows)
             return loss, {**counters, **diffusion}
-        x, head, counters, gate = HybridModel(cfg, name="model")(input_ids)
+        # last: a looped stack's exit gate, or the modules' states
+        x, head, counters, last = HybridModel(cfg, name="model")(
+            input_ids, mtp=labels is not None)
+        if cfg.mtp_depth and labels is not None:
+            loss, modules = mtp_loss(
+                x, last, head, labels, weight=cfg.mtp_loss_weight,
+                block_rows=cfg.ce_block_rows)
+            return loss, {**counters, **modules}
+        gate = last
         if labels is None:
             return (x if gate is None else x[-1]) @ head.T
         if gate is not None:
@@ -670,6 +813,26 @@ class HybridCausalLM(nn.Module):
             loss = blocked_lm_head_loss(
                 x[:, :-1], head, labels[:, 1:], block_rows=cfg.ce_block_rows)
         return (loss, counters) if counters else loss
+
+
+def mtp_loss(state, module_states, head, labels, *, weight, block_rows):
+    """The objective with multi-token prediction: the next-token loss of
+    ``state`` [B, S, E] plus ``weight`` times the mean over the modules of
+    each one's mean nll, ``module_states[k]`` [B, S, E] at position i scored
+    against ``labels[i + k + 2]`` through the SAME ``head`` (its gradient is
+    the sum over the passes); and the ``mtp/...`` counters."""
+    with jax.named_scope("head_loss"):
+        loss = blocked_lm_head_loss(
+            state[:, :-1], head, labels[:, 1:], block_rows=block_rows)
+    depth = module_states.shape[0]
+    with jax.named_scope("mtp_head_loss"):
+        ahead = sum(
+            blocked_lm_head_loss(
+                module_states[k, :, :-(k + 2)], head, labels[:, k + 2:],
+                block_rows=block_rows)
+            for k in range(depth)) / depth
+    counters = {"mtp/depth": jnp.int32(depth), "mtp/loss": ahead}
+    return loss + weight * ahead, jax.lax.stop_gradient(counters)
 
 
 def block_diffusion_loss(noisy_states, head, clean_ids, loss_weights, *,

@@ -1139,6 +1139,13 @@ class _Operands:
     heads: int
     head_dim: int
     packed: bool
+    # lanes of a head of v, of the context and of dO and dv: split operands
+    # say them (they may differ from q's and k's ``head_dim``); 0: the same
+    v_dim: int = 0
+
+    @property
+    def v_width(self):
+        return self.v_dim or self.head_dim
 
     @property
     def heads_a_block(self):
@@ -1166,18 +1173,19 @@ class _Operands:
         axis = self._row_axis(rows, key_major)
         return needed or (lambda g: g[axis])
 
-    def spec(self, rows, block, key_major=False, part=0, needed=None):
+    def spec(self, rows, block, key_major=False, part=0, needed=None,
+             v=False):
         """BlockSpec of a group's ``block`` rows of q (``part`` 0), k (1)
         or v (2) in the projection's result, or of a ``[.., H*D]`` array
         (``part`` 0). ``rows`` None: ``block`` is ALL the group's rows,
         wherever the grid stands in them, so it stays in VMEM for every
-        step of the group and is written back once."""
+        step of the group and is written back once. ``v``: an array of v's
+        width (the context, dO, dv), as ``part`` 2 is."""
         at = self._at(rows, key_major, needed) if rows else (lambda g: 0)
 
         if not self.packed:
-            return pl.BlockSpec(
-                (1, block, self.head_dim), lambda *g: (g[0], at(g), 0)
-            )
+            width = self.v_width if v or part == 2 else self.head_dim
+            return pl.BlockSpec((1, block, width), lambda *g: (g[0], at(g), 0))
         per = self.groups_a_batch
         return pl.BlockSpec(
             (1, block, LANES),
@@ -1213,19 +1221,24 @@ class _Operands:
             (1, block_k, 1), lambda *g: (g[0] // per, at(g), 0)
         )
 
-    def result(self, seq, dtype):
-        """Shape of the context or of one gradient."""
+    def result(self, seq, dtype, v=False):
+        """Shape of the context or of one gradient (``v``: of v's width)."""
         if self.packed:
             return jax.ShapeDtypeStruct(
                 (self.batch, seq, self.heads * self.head_dim), dtype
             )
         return jax.ShapeDtypeStruct(
-            (self.batch * self.heads, seq, self.head_dim), dtype
+            (self.batch * self.heads, seq,
+             self.v_width if v else self.head_dim), dtype
         )
 
     @property
     def block_lanes(self):
         return self.heads_a_block * self.head_dim
+
+    @property
+    def v_lanes(self):
+        return self.heads_a_block * self.v_width
 
 
 def _kvm_column(kv_mask):
@@ -1317,9 +1330,9 @@ def _forward_call(
             *(ops.bias_spec(use_bias, part) for part in range(3)),
             ops.kvm_spec(kv_mask is not None, block_k, needed=keys),
         ],
-        out_specs=[ops.spec("q", block_q), ops.row_spec(block_q, sub_q)],
+        out_specs=[ops.spec("q", block_q, v=True), ops.row_spec(block_q, sub_q)],
         out_shape=[
-            ops.result(sq, dtype),
+            ops.result(sq, dtype, v=True),
             jax.ShapeDtypeStruct(
                 (ops.batch * ops.heads, sq // sub_q, 1, sub_q), jnp.float32
             ),
@@ -1327,9 +1340,9 @@ def _forward_call(
         scratch_shapes=[
             pltpu.VMEM((hb, nsq, 1, sub_q), jnp.float32),
             pltpu.VMEM((hb, nsq, 1, sub_q), jnp.float32),
-            pltpu.VMEM((nsq, ops.block_lanes, sub_q), jnp.float32),
+            pltpu.VMEM((nsq, ops.v_lanes, sub_q), jnp.float32),
             _transposed_scratch(
-                block_k // sub_k, ops.block_lanes, sub_k, dtype, interpret
+                block_k // sub_k, ops.v_lanes, sub_k, dtype, interpret
             ),
         ],
         interpret=interpret,
@@ -1386,7 +1399,7 @@ def _backward_calls(
                 ops.spec("k", block_k, key_major, part=2, needed=ks),
                 *(ops.bias_spec(use_bias, part) for part in range(3)),
                 ops.kvm_spec(use_mask, block_k, key_major, needed=ks),
-                ops.spec("q", block_q, key_major, needed=qs),
+                ops.spec("q", block_q, key_major, needed=qs, v=True),
                 ops.row_spec(block_q, sub_q, key_major, needed=qs),
                 ops.row_spec(block_q, sub_q, key_major, needed=qs),
             ],
@@ -1397,12 +1410,12 @@ def _backward_calls(
 
     dkv_specs = [
         ops.spec("k", block_k, key_major=True),
-        ops.spec("k", block_k, key_major=True),
+        ops.spec("k", block_k, key_major=True, v=True),
     ]
-    dkv_shapes = [ops.result(sk, dtype), ops.result(sk, dtype)]
+    dkv_shapes = [ops.result(sk, dtype), ops.result(sk, dtype, v=True)]
     dkv_scratch = [
         pltpu.VMEM((hb, block_k, ops.head_dim), jnp.float32),
-        pltpu.VMEM((hb, block_k, ops.head_dim), jnp.float32),
+        pltpu.VMEM((hb, block_k, ops.v_width), jnp.float32),
     ]
     sub_q, sub_k = sub = plan["sub_q"], plan["sub_k"]
     if plan["backward"] == "fused":
@@ -1456,7 +1469,16 @@ def _name_residuals(out, lse):
     return checkpoint_name(out, "flash_out"), checkpoint_name(lse, "flash_lse")
 
 
-# ---- split operands: q, k, v [B, H, S, D] ---------------------------------
+# ---- split operands: q, k [B, H, S, D], v [B, H, S, D or Dv] ---------------
+def _split_operands(q, v):
+    """The operands of ``q`` [B, H, S, D] and ``v`` [B, H, S, Dv]: q and k
+    share the D lanes the scores contract over; v, the context, dO and dv
+    have Dv, which is D nearly everywhere (a latent mixer's q and k carry
+    rotary lanes that its v does not: 192 and 128)."""
+    b, h, _, d = q.shape
+    return _Operands(b, h, d, packed=False, v_dim=v.shape[-1])
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
 def _flash(
     q, k, v, kv_mask, seed, causal, sm_scale, dropout_rate, block_q, block_k,
@@ -1474,12 +1496,12 @@ def _flash_fwd(
 ):
     b, h, sq, d = q.shape
     out, lse = _forward_call(
-        _Operands(b, h, d, packed=False),
+        _split_operands(q, v),
         _reshape_bh(q), _reshape_bh(k), _reshape_bh(v), None, kv_mask, seed,
         sq, k.shape[2], causal, sm_scale, dropout_rate, block_q, block_k,
         block_diffusion, window,
     )
-    out, lse = _name_residuals(out.reshape(b, h, sq, d), lse)
+    out, lse = _name_residuals(out.reshape(b, h, sq, v.shape[-1]), lse)
     return out, (q, k, v, kv_mask, seed, out, lse)
 
 
@@ -1495,14 +1517,14 @@ def _flash_bwd(
         g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
     ).reshape(b * h, sq)
     dq, dk, dv = _backward_calls(
-        _Operands(b, h, d, packed=False),
+        _split_operands(q, v),
         _reshape_bh(q), _reshape_bh(k), _reshape_bh(v), None, kv_mask, seed,
         _reshape_bh(g), lse, delta, sq, sk, causal, sm_scale, dropout_rate,
         block_q, block_k, block_diffusion, window,
     )
     return (
-        dq.reshape(b, h, sq, d), dk.reshape(b, h, sk, d),
-        dv.reshape(b, h, sk, d), *_no_gradient(kv_mask, seed),
+        dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape),
+        *_no_gradient(kv_mask, seed),
     )
 
 

@@ -852,13 +852,15 @@ def latent_moe_mixer(p, x, *, top_k, scale, held, offset, tile,
 
 
 def gated_moe_mixer(p, x, *, top_k, held, offset, tile, force_level=False,
-                    scale=1.0, mesh=None):
+                    scale=1.0, route="softmax", mesh=None):
     """One mixture of SiLU-gated experts at the model's own width, dropping
     no token, over normalized ``x`` [B, S, E] -> (out [B, S, E], counters).
     ``p``: router [E, routed], wg and wu [held, E, F], wd [held, F, E],
     shared_wg and shared_wu [E, Fs], shared_wd [Fs, E], shared_gate [E, 1].
-    Softmax routing (``route_softmax_topk``), no selection bias; ``scale``
-    multiplies the routed experts' weighted sum (a family's routed scaling
+    ``route`` ``"softmax"``: ``route_softmax_topk``, no selection bias;
+    ``"sigmoid"``: ``route_sigmoid_topk`` under the leaf router_bias
+    [routed], which chooses and takes no gradient. ``scale`` multiplies the
+    routed experts' weighted sum either way (a family's routed scaling
     factor, on the experts' output). The shared expert is weighed by
     ``sigmoid(x shared_gate)``, or added as it is where the family has no
     ``shared_gate`` leaf. A family without a shared expert has no
@@ -868,10 +870,13 @@ def gated_moe_mixer(p, x, *, top_k, held, offset, tile, force_level=False,
     b, s, e = x.shape
     xt = x.reshape(b * s, e)
     with jax.named_scope("moe_route"):
-        weights_t, plan, chunk_tiles, counters = _route_and_plan(
-            xt, s, lambda xt, level: route_softmax_topk(
+        routes = {
+            "softmax": lambda xt, level: route_softmax_topk(
                 xt, p["router"], top_k, level=level),
-            held, offset, tile, force_level)
+            "sigmoid": lambda xt, level: route_sigmoid_topk(
+                xt, p["router"], p["router_bias"], top_k, 1.0, level=level)}
+        weights_t, plan, chunk_tiles, counters = _route_and_plan(
+            xt, s, routes[route], held, offset, tile, force_level)
         if scale != 1.0:
             weights_t = weights_t * scale
     with jax.named_scope("moe_experts"):

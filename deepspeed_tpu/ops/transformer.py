@@ -426,7 +426,18 @@ class AttentionSpec:
     (context * sigmoid(gate)) wo``. ``window=W``: a query sees its last W
     keys only; ``block_diffusion=B``: that mask over a ``[noisy ; clean]``
     row (both inside the flash kernels, ops/attention.py); neither: causal.
-    ``scope``: the device scope the kind opens inside ``attn_mixer``."""
+    ``scope``: the device scope the kind opens inside ``attn_mixer``.
+
+    ``kv_rank`` > 0 is a LATENT mixer (multi-head latent attention): q and k
+    of ``head_dim`` lanes, the LAST ``lanes`` of them rotated, and v of
+    ``v_dim``. ``c_q = rms(x wqa; q_norm)`` [``q_rank``], ``q = c_q wqb``
+    (each head its ``head_dim - lanes`` unrotated lanes, then its rotated
+    ones); ``[c_kv ; k_r] = x wkva`` [``kv_rank`` + ``lanes``], ``c_kv`` under
+    ``rms(.; kv_norm)``, ``[k_nope ; v] = c_kv wkvb`` a head, and the ONE
+    rotated ``k_r`` is every head's last ``lanes`` key lanes; ``softmax(q k^T
+    / sqrt(head_dim)) v`` with ``wo`` from ``heads * v_dim``. It has as many
+    kv heads as heads and takes no gate or mask form; ``norm`` says only
+    whether the two latent gains are ``"zero_centered"``."""
 
     heads: int
     kv_heads: int
@@ -440,10 +451,19 @@ class AttentionSpec:
     window: int = 0
     block_diffusion: int = 0
     scope: Optional[str] = None
+    q_rank: int = 0
+    kv_rank: int = 0
+    v_dim: int = 0
 
     def __post_init__(self):
         object.__setattr__(
             self, "frequencies", tuple(float(f) for f in self.frequencies))
+        if self.kv_rank and (
+                self.gate or self.window or self.block_diffusion
+                or self.kv_heads != self.heads):
+            raise ValueError(
+                "a latent mixer has one key and one value a head and no "
+                "gate or mask form")
 
 
 def attention_mixer(p, x, spec, *, positions=None, mesh=None):
@@ -457,7 +477,8 @@ def attention_mixer(p, x, spec, *, positions=None, mesh=None):
     ``attention_packed`` ([B, S, 3 * heads * D]: no head transpose on either
     side of the kernels), where there are as many kv heads as query heads and
     nothing but a rotation of every lane before the kernels; else three
-    products and ``attention`` over [B, heads, S, D]."""
+    products and ``attention`` over [B, heads, S, D]. A latent spec
+    (``kv_rank``) is ``_latent_attention``'s."""
     b, s, _ = x.shape
     heads, kv_heads, d = spec.heads, spec.kv_heads, spec.head_dim
     fused = qk_prep_path(b, s, heads, d, spec.lanes, mesh)[0] == "fused"
@@ -479,6 +500,8 @@ def attention_mixer(p, x, spec, *, positions=None, mesh=None):
     kind = jax.named_scope(spec.scope) if spec.scope \
         else contextlib.nullcontext()
     with jax.named_scope("attn_mixer"), kind:
+        if spec.kv_rank:
+            return _latent_attention(p, x, spec, rotary, mesh)
         if packed:
             qkv = x @ jnp.concatenate([p["wq"], p["wk"], p["wv"]], axis=1)
             if fused:
@@ -531,6 +554,46 @@ def attention_mixer(p, x, spec, *, positions=None, mesh=None):
         if spec.gate:
             ctx = ctx * gate.astype(ctx.dtype)
         return ctx.reshape(b, s, heads * d) @ p["wo"]
+
+
+def _latent_attention(p, x, spec, rotary, mesh):
+    """``AttentionSpec``'s latent mixer over normalized ``x`` [B, S, E], at
+    ``rotary``'s positions and frequencies (``apply_rotary``'s): wqa [E,
+    q_rank], q_norm [q_rank], wqb [q_rank, heads * head_dim], wkva [E,
+    kv_rank + lanes], kv_norm [kv_rank], wkvb [kv_rank, heads * (head_dim -
+    lanes + v_dim)], wo [heads * v_dim, E]. The kernels get q and k [B, heads,
+    S, head_dim] (the one rotated key part repeated over the heads) and v [B,
+    heads, S, v_dim] as they are: nothing is padded to another width. The
+    norms and the rotations are ``rms_norm`` and ``apply_rotary``
+    (``qk_prep_path`` refuses a head that does not fill 128-lane blocks: the
+    passes of ops/qk_prep.py rotate whole heads on their FIRST lanes, and
+    here the rotated lanes are the last 64 of 192 and a lone 64-lane key;
+    the latent norms come with no rotation)."""
+    b, s, _ = x.shape
+    heads, rope = spec.heads, spec.lanes
+    nope = spec.head_dim - rope
+
+    def rotated(t):
+        return apply_rotary(t, rope, None, seq_axis=1,
+                            factor=spec.rotary_factor, **rotary)
+
+    centered = spec.norm == "zero_centered"
+    q = rms_norm(x @ p["wqa"], p["q_norm"], spec.eps, centered) @ p["wqb"]
+    q = q.reshape(b, s, heads, spec.head_dim)
+    q = jnp.concatenate([q[..., :nope], rotated(q[..., nope:])], axis=-1)
+    latent = x @ p["wkva"]
+    k_rope = rotated(latent[:, :, None, spec.kv_rank:])
+    kv = rms_norm(latent[..., :spec.kv_rank], p["kv_norm"], spec.eps,
+                  centered) @ p["wkvb"]
+    kv = kv.reshape(b, s, heads, nope + spec.v_dim)
+    k = jnp.concatenate(
+        [kv[..., :nope], jnp.broadcast_to(k_rope, (b, s, heads, rope))],
+        axis=-1)
+    ctx = attention(
+        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+        kv[..., nope:].transpose(0, 2, 1, 3), causal=True, mesh=mesh)
+    return ctx.transpose(0, 2, 1, 3).reshape(b, s, heads * spec.v_dim) \
+        @ p["wo"]
 
 
 def swiglu_ffn_mixer(p, x):

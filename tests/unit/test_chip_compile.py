@@ -928,6 +928,54 @@ def test_dp4_window_reduces_what_it_reduced_and_runs_no_head_product_again(
     assert compiled[0].memory_analysis().peak_memory_in_bytes < 10.6 * 2 ** 30
 
 
+def test_latent_window_compiles_at_the_cells_shapes(monkeypatch):
+    """The whole fused window of ``joyai-llm-flash.train-seq8192`` (the leading
+    dense layer, five scanned sparse layers and the multi-token-prediction
+    module at the published widths, micro 2 x seq 8,192 x accum 2, ZeRO-2 on
+    one described chip), built by the engine as
+    ``benchmark/compile_described.py`` builds it: the flash kernels take q and
+    k 192 lanes wide and v 128 as they are (no operand of the kernels is 256
+    lanes wide, and none of v's side 192), every one of them under
+    ``attn_mla``, the stack's in the scan and the module's under ``mtp``; no
+    O(S^2) array exists (the XLA path's scores at 2 x 32 x 8,192^2 would be
+    8 GiB in bf16); the module's head products are the forward's, beside the
+    main ones; and the window stays inside the 15.07 GiB of the chip's 15.75
+    that it compiled to in PR 44 under the cell's policy, which keeps every
+    projection's output (10.33 under ``nothing_saveable``: PERF.md section
+    6)."""
+    from benchmark import compile_described, harness
+    from deepspeed_tpu.runtime.compile_cache import disarm_compile_cache
+
+    compiled = []
+    monkeypatch.setattr(
+        compile_described, "report",
+        lambda label, program, t0: compiled.append(program) or program)
+    cell = harness.load_json("workloads", "joyai-llm-flash.train-seq8192.json")
+    config = harness.load_json("configs", cell["config"] + ".json")
+    try:
+        compile_described.program_window(
+            cell, config, harness.sizes(config, False),
+            _v5e_host()[:cell["chips"]])
+    finally:
+        # the cell's recipe arms the persistent cache (off in this file)
+        disarm_compile_cache()
+    text = compiled[0].as_text()
+    kernels = [l for l in text.splitlines() if "tpu_custom_call" in l
+               and re.search(r"flash_(fwd|bwd_dkv)", l)]
+    assert len(kernels) == 6, kernels    # fwd + bwd: prefix, scan, module
+    assert all("/attn_mixer/attn_mla/" in l for l in kernels)
+    assert sum("/mtp/" in l for l in kernels) == 2
+    assert sum("/stack_scan/" in l for l in kernels) == 2
+    for line in kernels:
+        operands = re.findall(r"bf16\[64,8192,(\d+)\]", line)
+        assert set(operands) == {"192", "128"}, line
+    # (the k | v up-projection's result is [2, 8192, 32 x 256]: not a score)
+    assert not re.search(r"\[(?:2,32|64),8192,8192\]", text)
+    assert set(_head_products(text, "mtp_head_loss")) == {"forward"}
+    assert set(_head_products(text, "head_loss")) == {"forward"}
+    assert compiled[0].memory_analysis().peak_memory_in_bytes < 15.2 * 2 ** 30
+
+
 # ---------------------------------------------------------------------------
 # decode kernels (ops/decode_attention.py)
 # ---------------------------------------------------------------------------

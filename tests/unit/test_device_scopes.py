@@ -61,6 +61,17 @@ FAMILIES = {
         "attn_mixer": ONCE, "attn_full": ONCE, "attn_window": ONCE,
         "swiglu_ffn": ONCE, "moe_route": ONCE, "moe_experts": ONCE,
         "moe_shared": ONCE, "grad_accum": SUM}),
+    # the latent mixers under attn_mla INSIDE attn_mixer and the module's
+    # layer under mtp (both ``other`` in benchmark/scopes/latent_mtp.json:
+    # the layers inside them stay the layers they are); the module's
+    # projection and its pass through the shared head are layers of their
+    # own (the projection outside every checkpoint: nothing runs it again);
+    # a prefix and a scanned run under the siblings' remat policy
+    "joyai": ("joyai-llm-flash.train-seq8192", {
+        "stack_norms": ALL, "embed": ONCE, "head_loss": ONCE,
+        "mtp_head_loss": ONCE, "mtp_proj": ONCE, "attn_mixer": ALL,
+        "swiglu_ffn": ALL, "moe_route": ALL, "moe_experts": ONCE,
+        "moe_shared": ALL, "stack_scan": ALL, "grad_accum": SUM}),
     "ouro": ("ouro-2.6b.train-seq8192", {
         "stack_norms": ALL, "embed": ONCE, "loop_head_loss": ONCE,
         "attn_mixer": ALL, "swiglu_ffn": ALL, "loop_pass": ALL,
@@ -187,10 +198,43 @@ def test_the_scans_own_time_is_slices_and_writes(window_ops, family):
     assert {"while", "dynamic_slice", "dynamic_update_slice"} <= own
     stack = set(FAMILIES[family][1]) - {
         "embed", "head_loss", "loop_head_loss", "exit_gate", "grad_accum",
-        "stack_scan"}
-    assert not [path for _op, path in ops if "/stack_scan/" not in path
-                and path.endswith("/dot_general")
-                and any(f"/{s}/" in path for s in stack)]
+        "stack_scan", "mtp_head_loss", "mtp_proj"}
+    outside = [path for _op, path in ops if "/stack_scan/" not in path
+               and path.endswith("/dot_general")
+               and any(f"/{s}/" in path for s in stack)]
+    if family != "joyai":
+        assert not outside
+        return
+    # a prefix before the scanned run and a module after the final norm: the
+    # leading dense layer's products (the only ``swiglu_ffn``) and the
+    # module's lie outside the scan, the five sparse layers' inside
+    assert outside and all(
+        "/mtp/" in path or "/swiglu_ffn/" in path or "/attn_mixer/" in path
+        for path in outside)
+    assert not [path for _op, path in ops
+                if "/swiglu_ffn/" in path and "/stack_scan/" in path]
+    assert [path for _op, path in ops if "/stack_scan/" in path
+            and "/moe_experts/" in path and path.endswith("/dot_general")]
+
+
+def test_the_latent_and_module_scopes_enclose_what_they_name(window_ops):
+    """``attn_mla`` lies inside ``attn_mixer`` on every operation that carries
+    it, in the stack and in the module; ``mtp`` holds a latent mixer, the
+    experts, the norms and its own projection, and neither head pass; the
+    module's head pass reads under ``mtp_head_loss`` and not ``head_loss``."""
+    ops = [path for _op, path in window_ops("joyai")]
+    latent = [p for p in ops if "/attn_mla/" in p]
+    assert latent and all("/attn_mixer/attn_mla/" in p for p in latent)
+    assert any("/stack_scan/" in p for p in latent)
+    module = [p for p in ops if "/mtp/" in p]
+    for scope in ("attn_mixer/attn_mla", "moe_route", "moe_experts",
+                  "moe_shared", "stack_norms", "mtp_proj"):
+        assert any(f"/mtp/{scope}/" in p for p in module), scope
+    assert not [p for p in module if "head_loss/" in p or "/stack_scan/" in p]
+    assert all("/mtp/mtp_proj/" in p for p in ops if "/mtp_proj/" in p)
+    heads = [p for p in ops if "head_loss/" in p]
+    assert {("/mtp_head_loss/" in p, "/head_loss/" in p) for p in heads} == {
+        (True, False), (False, True)}
 
 
 def test_every_scope_the_program_opens_is_in_the_catalog():

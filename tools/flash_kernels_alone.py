@@ -28,13 +28,16 @@ sys.path.insert(0, ROOT)
 att = importlib.import_module("deepspeed_tpu.ops.attention")
 from benchmark.trace import PS, Trace  # noqa: E402
 
-# name: (entry, batch, heads, seq, head width, causal, key mask, bias)
+# name: (entry, batch, heads, seq, head width, causal, key mask, bias); a
+# head width (q and k's, v's) where they differ (a latent mixer's)
 SHAPES = {
     "gpt2": ("packed", 8, 20, 1024, 64, True, False, True),
     "bert512": ("packed", 8, 16, 512, 64, False, True, True),
     "ouro": ("packed", 1, 16, 8192, 128, True, False, False),
     "nemotron": ("split", 2, 4, 8192, 128, True, False, False),
     "qwen3next": ("split", 2, 16, 16384, 256, True, False, False),
+    "joyai": ("split", 2, 32, 8192, (192, 128), True, False, False),
+    "joyai_v192": ("split", 2, 32, 8192, 192, True, False, False),
 }
 CALLS = 5
 
@@ -57,7 +60,9 @@ def build(shape):
             return jnp.sum(out.astype(jnp.float32) * w.astype(jnp.float32))
 
         return loss, (qkv, bias), (0, 1) if biased else (0,)
-    q, k, v, w = (jax.random.normal(kk, (b, h, s, d), jnp.bfloat16) for kk in keys)
+    dqk, dv = d if isinstance(d, tuple) else (d, d)
+    q, k, v, w = (jax.random.normal(kk, (b, h, s, width), jnp.bfloat16)
+                  for kk, width in zip(keys, (dqk, dqk, dv, dv)))
 
     def loss(q, k, v):
         out = att.flash_attention(q, k, v, kv_mask=kv_mask, causal=causal)
