@@ -37,6 +37,7 @@ byte mask, dropout_kernels.cu; regeneration is the bandwidth-friendly TPU
 design).
 """
 
+import collections
 import dataclasses
 import functools
 import math
@@ -100,9 +101,9 @@ def mha_reference(
 # profiler trace, PR 25, docs/TESTING.md): the one-level kernels before
 # PR 25 at 512 x 512 blocks 1.22 + 0.94 + 1.10 (16.6 TFLOP/s in the
 # forward), at 1024 x 1024 0.74 + 0.73 + 1.02; these kernels at 512 x 512
-# blocks (a 2 x 2 grid, fori_loop walk) 0.76 + 0.89 + 1.33, at 1024 x 1024
-# 0.43 + 0.48 + 0.60 (50 TFLOP/s in the forward). These are the ceiling
-# pick_block starts from; block and sub-tiles follow from the shape.
+# blocks (a 2 x 2 grid, then a fori_loop walk) 0.76 + 0.89 + 1.33, at
+# 1024 x 1024 0.43 + 0.48 + 0.60 (50 TFLOP/s in the forward). These are the
+# ceiling pick_block starts from; block and sub-tiles follow from the shape.
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 1024
 
@@ -151,8 +152,12 @@ def pick_block(seq, maximum):
 # Where a grid has ONE block each way (every seq up to DEFAULT_BLOCK),
 # every loop bound is known at trace time and the walk unrolls: the
 # scheduler overlaps one sub-tile's matmuls with its neighbour's softmax.
-# With more blocks the bounds depend on the grid position and the walk is
-# a ``fori_loop``, whose every step costs ~0.3 us that nothing overlaps.
+# With more blocks the bounds depend on the grid position, but only through
+# the few CLASSES of step a grid has (under ``causal`` a step lies ON the
+# diagonal or UNDER it): a kernel lowers one body a class, each with its
+# bounds as ints, and the walk unrolls there too (PR 46; ``_walk_plan``
+# below). Only a shape with more classes than ``MAX_WALK_BODIES`` walks by
+# ``fori_loop``, whose every step costs ~0.3 us that nothing overlaps.
 #
 # Two kernels run in training: ``flash_fwd`` and ONE backward, key-major
 # like the old dkv kernel, that computes each score sub-tile once and puts
@@ -176,10 +181,16 @@ def pick_block(seq, maximum):
 # accumulator in VMEM, which 128-steps do four times as often as 256-steps
 # (5/8 of the square). BERT's [8, 16, 512, 64] with a key mask reads the
 # same: 0.36 / 0.26 / 0.28 against the pair's 0.16 + 0.24. On a grid of
-# several blocks (``fori_loop`` walk) [1, 16, 8192, 128] read 5.60 at 512
-# square, 5.59 at 1024 q x 512 k, 5.55 at 512 q x 256 k, 5.87 and 6.06 at
-# 512 q x 1024 k and 256 q x 512 k, against the pair's 3.88 + 4.82: nothing
-# to choose, so 512 stays.
+# several blocks the fused kernel under a ``fori_loop`` walk had nothing to
+# choose ([1, 16, 8192, 128]: 5.60 at 512 square, 5.55 to 6.06 elsewhere;
+# PR 33); with one static body a class of step (PR 46) the same shape reads
+# 4.21 at 512 square, 4.21 at 512 q x 256 k and 256 q x 512 k, 4.10 at 256
+# square, and 256 square wins at every cell's shape: 61.28 -> 60.46 at
+# [2, 16, 16384, 256], 24.33 -> 23.74 at q/k 192 on v 128, 39.37 -> 37.65
+# under the block-diffusion mask, 10.00 -> 7.96 under a band of 512 keys
+# (three quarter-size sub-tiles a stripe where two whole ones were visited).
+# So the fused kernel takes 256 on every grid; the pair's dkv (no cell runs
+# it on several blocks) keeps 512 there, not measured since.
 SUB_QUERY_MAJOR = 512
 SUB_KEY_MAJOR = 128
 SUB_FUSED = 256
@@ -204,10 +215,13 @@ def pick_subtile(block, target):
 def pick_subtiles(block_q, block_k, nq, nk, key_major, fused=False):
     """(sub_q, sub_k) of the forward and dq kernels, or of the key-major
     ones (``key_major``: dkv of the pair, or the ``fused`` backward), for
-    blocks on an ``nq x nk`` grid."""
+    blocks on an ``nq x nk`` grid: the fused kernel's 256 on every grid, the
+    pair's dkv 128 on one block."""
     target = SUB_QUERY_MAJOR
-    if key_major and nq == nk == 1:
-        target = SUB_FUSED if fused else SUB_KEY_MAJOR
+    if key_major and fused:
+        target = SUB_FUSED
+    elif key_major and nq == nk == 1:
+        target = SUB_KEY_MAJOR
     return pick_subtile(block_q, target), pick_subtile(block_k, target)
 
 
@@ -424,31 +438,184 @@ def block_diffusion_mask(seq, block):
     return jnp.where(allowed, 0.0, NEG_INF).astype(jnp.float32)
 
 
+# what ``_key_spans`` / ``_query_spans`` need of a call's mask
+_MaskForm = collections.namedtuple(
+    "_MaskForm", "causal diag_offset block_diffusion half window")
+
+
+def _key_spans(q_first, sub_q, k_first, sub_k, nsk, mask):
+    """The walk of ``sub_q`` query rows from ``q_first`` over the ``nsk`` key
+    sub-tiles of a K block from key ``k_first`` under ``mask`` (``_MaskForm``),
+    as runs ``(lo, hi, diagonal)`` in the walk's order: sub-tiles ``[lo, hi)``,
+    crossed by an edge of the mask (``diagonal``: they build it from ``iota``)
+    or wholly allowed. Python ints or traced int32 alike."""
+    causal, diag_offset, block_diffusion, half, window = mask
+    if block_diffusion:
+        lo, n_full, hi = _bd_key_range(
+            q_first, sub_q, k_first, sub_k, nsk, half, block_diffusion)
+        return (lo, n_full, False), (n_full, hi, True)
+    if window:
+        lo, a, b, hi = _band_key_range(
+            q_first, sub_q, k_first, sub_k, nsk, diag_offset, window)
+        return (lo, a, True), (a, b, False), (b, hi, True)
+    if causal:
+        n_full, hi = _key_range(q_first, sub_q, k_first, sub_k, nsk, diag_offset)
+        return (0, n_full, False), (n_full, hi, True)
+    return ((0, nsk, False),)
+
+
+def _query_spans(k_first, sub_k, q_first, sub_q, nsq, mask):
+    """The same seen from ``sub_k`` keys from ``k_first`` over the ``nsq``
+    query sub-tiles of a Q block that starts at row ``q_first``."""
+    causal, diag_offset, block_diffusion, half, window = mask
+    if block_diffusion:
+        lo, full, hi = _bd_query_range(
+            k_first, sub_k, q_first, sub_q, nsq, half, block_diffusion)
+        return (lo, full, True), (full, hi, False)
+    if window:
+        lo, a, b, hi = _band_query_range(
+            k_first, sub_k, q_first, sub_q, nsq, diag_offset, window)
+        return (lo, a, True), (a, b, False), (b, hi, True)
+    if causal:
+        lo, full = _query_range(k_first, sub_k, q_first, sub_q, nsq, diag_offset)
+        return (lo, full, True), (full, nsq, False)
+    return ((0, nsq, False),)
+
+
 def _visited_share(
     sq, sk, block_k, sub_q, sub_k, causal, block_diffusion=0, window=0
 ):
     """Share of the ``sq x sk`` score square that lies in sub-tiles a
     kernel visits, from the bounds that set its loops."""
-    if not causal and not block_diffusion:
-        return 1.0
-    visited, nsk = 0, block_k // sub_k
-    for q_first in range(0, sq, sub_q):
-        for k_first in range(0, sk, block_k):
-            if block_diffusion:
-                lo, _, hi = _bd_key_range(
-                    q_first, sub_q, k_first, sub_k, nsk, sq // 2,
-                    block_diffusion,
-                )
-            elif window:
-                lo, _, _, hi = _band_key_range(
-                    q_first, sub_q, k_first, sub_k, nsk, sk - sq, window
-                )
-            else:
-                lo, hi = 0, _key_range(
-                    q_first, sub_q, k_first, sub_k, nsk, sk - sq
-                )[1]
-            visited += hi - lo
+    mask = _MaskForm(causal, sk - sq, block_diffusion, sq // 2, window)
+    visited = sum(
+        max(hi - lo, 0)
+        for q_first in range(0, sq, sub_q)
+        for k_first in range(0, sk, block_k)
+        for lo, hi, _ in _key_spans(
+            q_first, sub_q, k_first, sub_k, block_k // sub_k, mask)
+    )
     return visited * sub_q * sub_k / (sq * sk)
+
+
+# A grid of several blocks. A step's place beside the mask's edges follows from
+# its grid position, and on the cells' grids only a few places differ: under
+# plain ``causal`` with square blocks a step lies ABOVE the diagonal (nothing
+# to do), ON it (the bounds of the one-block case) or UNDER it (every sub-tile
+# whole, no mask). ``_walk_plan`` finds these CLASSES of step at trace time, by
+# running the same span arithmetic the kernels would run, with Python ints, over
+# every step of the grid: steps whose spans are equal are one class. A kernel
+# then lowers one body a class, each under its own ``pl.when`` with its spans as
+# ints (so that the walk unrolls as it does on one block, and only the crossed
+# sub-tiles build a mask), and reads the running step's class from a table that
+# rides behind the dropout seed in SMEM. Shapes with more classes than
+# ``MAX_WALK_BODIES`` keep ONE body whose bounds are traced and whose walk is a
+# ``fori_loop``: ~0.3 us a step that nothing overlaps, and a loop body that the
+# scheduler cannot interleave with its neighbour's. The ceiling is what
+# lowering costs: a body more is 0.35 to 1 s of Mosaic's compile a kernel (a
+# forward + backward pair compiled for a described v5e, PR 46: two bodies 1.9
+# to 4.0 s where the one loop body takes 1.2 to 1.9, four bodies 3.7), paid at
+# every cold start, and no cell's grid has more than three classes.
+#
+# The other half of the same fact: the operands of a grid's INNER axis (K and V
+# of a query-major grid; q, dO, lse and delta of a key-major one) hold, through
+# the steps that do nothing, the block their neighbour needs (``_needed_blocks``),
+# and the pipeline moves a block only when its index changes.
+MAX_WALK_BODIES = 4
+
+
+def _needed_blocks(
+    key_major, block_q, block_k, nq, nk, causal, diag_offset, block_diffusion,
+    window,
+):
+    """``(g -> block, steps)`` for the operands a grid's INNER axis walks
+    (the keys' of a query-major grid ``(group, iq, ik)``, the queries' of a
+    key-major one ``(group, ik, iq)``) and how many steps that axis has; the
+    map is None where every step holds its own block. A step that does
+    nothing holds the nearest block, in the walk's order, that a step of its
+    row needs. Under the band the axis has only as many steps as a block of
+    the outer axis needs blocks (``_band_blocks``). Python ints or traced
+    int32 alike."""
+    steps = nq if key_major else nk
+    if window or (causal and steps > 1):
+        # plain causal: the band whose lower edge lies before the first key
+        band = (block_q, block_k, nq, nk, diag_offset,
+                window or nq * block_q + nk * block_k)
+
+        def needed(g):
+            # the band's inner axis counts its steps from ``first``
+            first, last = _band_blocks(g[1], *band, key_major)
+            return _clip(g[2] + (first if window else 0), first, last)
+
+        return needed, _band_inner_steps(*band, key_major) if window else steps
+    if not block_diffusion:
+        return None, steps
+    pick = _bd_query_block if key_major else _bd_key_block
+    return lambda g: pick(
+        g[1], g[2], block_q, block_k, nq * block_q // 2, block_diffusion
+    ), steps
+
+
+def _walk_plan(*walk):
+    """How a kernel walks an ``nq x nk`` grid (``walk``: ``key_major``, the
+    sub-tiles, then ``_grid_form``): ``walk`` (``static``: one body a class
+    of step; ``loop``: more classes than ``MAX_WALK_BODIES``), ``bodies``
+    (each class's spans, a tuple of ``_key_spans`` a query sub-tile or of
+    ``_query_spans`` a key sub-tile, empty runs left out; none under
+    ``loop``), ``table`` (the class of every step, row by row of the outer
+    axis: 0 does nothing, ``i + 1`` runs ``bodies[i]``; None where one body
+    serves every step) and ``steps``: how many of the grid's steps ``run``,
+    are ``skipped``, and ``fetched`` a block of an inner-axis operand (a step
+    whose block differs from the step before in its row)."""
+    bodies, table, steps = _walk_classes(*walk)
+    static = len(bodies) <= MAX_WALK_BODIES
+    one_body = not static or (len(bodies) == 1 and not steps["skipped"])
+    return {
+        "walk": "static" if static else "loop",
+        "bodies": bodies if static else (),
+        "table": None if one_body else table,
+        "steps": steps,
+    }
+
+
+@functools.lru_cache(maxsize=None)
+def _walk_classes(
+    key_major, sub_q, sub_k, block_q, block_k, nq, nk, causal, diag_offset,
+    block_diffusion, window,
+):
+    """``(bodies, table, steps)`` of ``_walk_plan``, before its ceiling: the
+    span arithmetic of the kernels, run with Python ints over every step."""
+    mask = _MaskForm(causal, diag_offset, block_diffusion, nq * block_q // 2, window)
+    needed, steps = _needed_blocks(
+        key_major, block_q, block_k, nq, nk, causal, diag_offset,
+        block_diffusion, window)
+    band = (block_q, block_k, nq, nk, diag_offset, window)
+    nsq, nsk = block_q // sub_q, block_k // sub_k
+    bodies, table, fetched = {}, [], 0
+    for outer in range(nk if key_major else nq):
+        at = _band_blocks(outer, *band, key_major)[0] if window else 0
+        held = None
+        for step in range(steps):
+            iq, ik = (at + step, outer) if key_major else (outer, at + step)
+            spans = ()
+            if iq < nq and ik < nk:
+                q_first, k_first = iq * block_q, ik * block_k
+                walks = (
+                    _query_spans(k_first + c * sub_k, sub_k, q_first, sub_q, nsq, mask)
+                    for c in range(nsk)
+                ) if key_major else (
+                    _key_spans(q_first + r * sub_q, sub_q, k_first, sub_k, nsk, mask)
+                    for r in range(nsq)
+                )
+                spans = tuple(
+                    tuple(run for run in walk if run[1] > run[0]) for walk in walks)
+            table.append(bodies.setdefault(spans, len(bodies) + 1) if any(spans) else 0)
+            block = needed((0, outer, step)) if needed else step
+            fetched += block != held
+            held = block
+    run = sum(c > 0 for c in table)
+    return tuple(bodies), tuple(table), {
+        "run": run, "skipped": len(table) - run, "fetched": fetched}
 
 
 # What the fused backward may hold in VMEM for dq: the float32 accumulator
@@ -469,7 +636,8 @@ def backward_plan(
     each score sub-tile) where dq's accumulator and output for ``sq`` rows of
     ``lanes`` lanes fit ``budget``, else the ``pair`` (``flash_bwd_dq`` and
     ``flash_bwd_dkv``), with the reason. Also the sub-tiles the key-major
-    walk computes in and the share of the score square it visits."""
+    walk computes in, the share of the score square it visits and how it
+    walks the grid (``_walk_report``)."""
     if budget is None:
         budget = FUSED_DQ_VMEM_BUDGET
     nq, nk = sq // block_q, sk // block_k
@@ -488,7 +656,18 @@ def backward_plan(
             f"dq over {sq} rows of {lanes} lanes takes {dq_bytes} bytes of "
             f"VMEM, budget {budget}"
         ),
+        **_walk_report(
+            True, sub_q, sub_k, block_q, block_k, nq, nk, causal, sk - sq,
+            block_diffusion, window),
     }
+
+
+def _walk_report(*walk):
+    """``walk`` (``static`` | ``loop``), ``bodies`` (classes of step lowered)
+    and ``steps`` (``run``, ``skipped``, ``fetched``) of ``_walk_plan``."""
+    plan = _walk_plan(*walk)
+    return {"walk": plan["walk"], "bodies": len(plan["bodies"]) or 1,
+            "steps": dict(plan["steps"])}
 
 
 def flash_tiling(
@@ -497,9 +676,10 @@ def flash_tiling(
 ):
     """Outer blocks, sub-tiles and the share of the ``sq x sk`` score
     square whose sub-tiles a kernel visits (the forward and dq, or dkv
-    with ``key_major``), from the same bounds that set its loops; and
-    under ``backward`` what ``backward_plan`` chooses for the call
-    (``plan``: its ``lanes``, ``itemsize`` and ``budget``)."""
+    with ``key_major``), from the same bounds that set its loops; how the
+    kernel walks the grid (``_walk_report``); and under ``backward`` what
+    ``backward_plan`` chooses for the call (``plan``: its ``lanes``,
+    ``itemsize`` and ``budget``)."""
     picked = pick_subtiles(
         block_q, block_k, sq // block_q, sk // block_k, key_major
     )
@@ -511,6 +691,9 @@ def flash_tiling(
         "visited_share": _visited_share(
             sq, sk, block_k, sub_q, sub_k, causal, block_diffusion, window
         ),
+        **_walk_report(
+            key_major, sub_q, sub_k, block_q, block_k, sq // block_q,
+            sk // block_k, causal, sk - sq, block_diffusion, window),
         "backward": backward_plan(
             sq, sk, block_q, block_k, causal, block_diffusion=block_diffusion,
             window=window, **plan,
@@ -529,13 +712,19 @@ def _log_tiling(
         window=window,
     )
     b = t["backward"]
+
+    def walk(w):
+        return "%s bodies=%d steps=%d/%d/%d" % (
+            w["walk"], w["bodies"], *w["steps"].values())
+
     logger.debug(
         "flash_tiling sq=%d sk=%d d=%d %s causal=%s mask=%s dropout=%s "
-        "block=%dx%d sub=%dx%d visited_share=%.4f "
-        "backward=%s bwd_sub=%dx%d bwd_visited_share=%.4f dq_vmem_bytes=%d%s%s%s",
+        "block=%dx%d sub=%dx%d visited_share=%.4f walk=%s "
+        "backward=%s bwd_sub=%dx%d bwd_visited_share=%.4f bwd_walk=%s "
+        "dq_vmem_bytes=%d%s%s%s",
         sq, sk, d, dtype, causal, use_mask, dropout, block_q, block_k,
-        t["sub_q"], t["sub_k"], t["visited_share"],
-        b["backward"], b["sub_q"], b["sub_k"], b["visited_share"],
+        t["sub_q"], t["sub_k"], t["visited_share"], walk(t),
+        b["backward"], b["sub_q"], b["sub_k"], b["visited_share"], walk(b),
         b["dq_vmem_bytes"], f" reason={b['reason']!r}" if b["reason"] else "",
         f" block_diffusion={block_diffusion}" if block_diffusion else "",
         f" window={window}" if window else "",
@@ -681,13 +870,13 @@ def _dot_t(a_t, b, dtype):
 class _Tiles:
     """What the kernels share: the grid position (a Python 0 on an
     axis of one block, so that every bound derived from it is static),
-    sub-tile counts, the heads of one program's block, and the masking
-    flags."""
+    sub-tile counts, the heads of one program's block, the masking flags,
+    and the bodies a kernel lowers: one a class of step (``_walk_plan``)."""
 
     def __init__(
-        self, q_axis, *, sm_scale, causal, block_q, block_k, sub_q, sub_k,
-        nq, nk, diag_offset, dropout_rate, use_mask, head_dim, heads_a_block,
-        use_bias, block_diffusion=0, window=0,
+        self, q_axis, scalars_ref, *, sm_scale, causal, block_q, block_k,
+        sub_q, sub_k, nq, nk, diag_offset, dropout_rate, use_mask, head_dim,
+        heads_a_block, use_bias, block_diffusion=0, window=0,
     ):
         self.group = pl.program_id(0)
         self.block_diffusion, self.half = block_diffusion, nq * block_q // 2
@@ -720,34 +909,59 @@ class _Tiles:
         self.gran = (
             pick_subtile(block_k, DROPOUT_TILE), pick_subtile(block_q, DROPOUT_TILE)
         )
+        self.mask = _MaskForm(
+            causal, diag_offset, block_diffusion, self.half, window)
         self.scores = functools.partial(
             _scores_t, sm_scale=sm_scale, fold_scale=self.fold_scale,
             diag_offset=diag_offset, block_diffusion=block_diffusion,
             half=self.half, window=window,
         )
         self.guard = use_mask or diag_offset < 0
-        # whole blocks above the diagonal (that the mask empties) are skipped
-        self.run = True
-        if block_diffusion:
+        # ``classes``: (the condition a body runs under, its spans) a body
+        plan = _walk_plan(
+            self.key_major, sub_q, sub_k, block_q, block_k, nq, nk, causal,
+            diag_offset, block_diffusion, window)
+        if plan["walk"] == "loop":
+            # one body, its bounds from the grid position as it runs
+            self.run = self._runs()
+            self.classes = [(self.run, None)]
+        elif plan["table"] is None:
+            self.run = True
+            self.classes = [(True, plan["bodies"][0])]
+        else:
+            inner = len(plan["table"]) // (nk if self.key_major else nq)
+            this = scalars_ref[
+                1 + pl.program_id(1) * inner + pl.program_id(2)]
+            self.run = this > 0
+            self.classes = [
+                (this == i + 1, spans) for i, spans in enumerate(plan["bodies"])]
+
+    def each_body(self, body):
+        """Lower ``body(spans)`` once a class of step, each under the
+        condition that the running step is of that class."""
+        for run, spans in self.classes:
+            _when(run)(functools.partial(body, spans))
+
+    def _runs(self):
+        """Whether the mask leaves this step anything to do (whole blocks
+        above the diagonal, past the band, outside the block-diffusion mask
+        are skipped), from the grid position as the step runs."""
+        if self.block_diffusion:
             lo, _, hi = _bd_key_range(
-                self.iq * block_q, block_q, self.ik * block_k, block_k, 1,
-                self.half, block_diffusion,
+                self.iq * self.block_q, self.block_q, self.ik * self.block_k,
+                self.block_k, 1, self.half, self.block_diffusion,
             )
-            self.run = hi > lo
-        elif window:
+            return hi > lo
+        last_row = self.iq * self.block_q + (self.block_q - 1) + self.diag_offset
+        if self.window:
             # a step past the last block that the band touches does nothing
-            self.run = (
-                (self.ik * block_k
-                 <= self.iq * block_q + (block_q - 1) + diag_offset)
-                & (self.ik * block_k + (block_k - 1)
-                   > self.iq * block_q + diag_offset - window)
-                & (self.iq < nq)
+            return (
+                (self.ik * self.block_k <= last_row)
+                & (self.ik * self.block_k + (self.block_k - 1)
+                   > self.iq * self.block_q + self.diag_offset - self.window)
+                & (self.iq < self.nq)
             )
-        elif causal:
-            self.run = (
-                self.ik * block_k
-                <= self.iq * block_q + (block_q - 1) + diag_offset
-            )
+        return self.ik * self.block_k <= last_row if self.causal else True
 
     # where a walk of the inner axis starts and ends: the first and last
     # block of it, or under the band the first and last step
@@ -824,63 +1038,39 @@ class _Tiles:
             shape, self.gran, self.dropout_rate,
         )
 
-    def over_keys(self, r, step, carry):
+    def over_keys(self, spans, r, step, carry):
         """``step(c, carry, diagonal)`` over the key sub-tiles of this
-        K block that query stripe ``r`` sees: the ones wholly under the
-        diagonal, then the ones it crosses."""
-        lo, n_full, hi = 0, self.nsk, self.nsk
-        if self.block_diffusion:
-            lo, n_full, hi = _bd_key_range(
+        K block that query stripe ``r`` sees, in the order of ``_key_spans``:
+        a class's ``spans`` (Python ints: the walk unrolls), or None for the
+        ones that follow from the grid position as the step runs."""
+        if spans is None:
+            return self._over(_key_spans(
                 self.q_first(r), self.sub_q, self.k_first(0), self.sub_k,
-                self.nsk, self.half, self.block_diffusion,
-            )
-        elif self.window:
-            lo, a, n_full, hi = _band_key_range(
-                self.q_first(r), self.sub_q, self.k_first(0), self.sub_k,
-                self.nsk, self.diag_offset, self.window,
-            )
-            carry = _span(lo, a, functools.partial(step, diagonal=True), carry)
-            lo = a
-        elif self.causal:
-            n_full, hi = _key_range(
-                self.q_first(r), self.sub_q, self.k_first(0), self.sub_k,
-                self.nsk, self.diag_offset,
-            )
-        carry = _span(lo, n_full, functools.partial(step, diagonal=False), carry)
-        return _span(n_full, hi, functools.partial(step, diagonal=True), carry)
+                self.nsk, self.mask), step, carry)
+        return self._over(spans[r], step, carry)
 
-    def over_queries(self, c, step, carry):
+    def over_queries(self, spans, c, step, carry):
         """``step(r, carry, diagonal)`` over the query sub-tiles of this
-        Q block that see key sub-tile ``c``: the ones the diagonal crosses,
-        then the ones wholly under it."""
-        lo, full, hi = 0, 0, self.nsq
-        if self.block_diffusion:
-            lo, full, hi = _bd_query_range(
+        Q block that see key sub-tile ``c``, in the order of
+        ``_query_spans``."""
+        if spans is None:
+            return self._over(_query_spans(
                 self.k_first(c), self.sub_k, self.q_first(0), self.sub_q,
-                self.nsq, self.half, self.block_diffusion,
-            )
-        elif self.window:
-            lo, full, b, hi = _band_query_range(
-                self.k_first(c), self.sub_k, self.q_first(0), self.sub_q,
-                self.nsq, self.diag_offset, self.window,
-            )
-            carry = _span(lo, full, functools.partial(step, diagonal=True), carry)
-            carry = _span(full, b, functools.partial(step, diagonal=False), carry)
-            return _span(b, hi, functools.partial(step, diagonal=True), carry)
-        elif self.causal:
-            lo, full = _query_range(
-                self.k_first(c), self.sub_k, self.q_first(0), self.sub_q,
-                self.nsq, self.diag_offset,
-            )
-        carry = _span(lo, full, functools.partial(step, diagonal=True), carry)
-        return _span(full, hi, functools.partial(step, diagonal=False), carry)
+                self.nsq, self.mask), step, carry)
+        return self._over(spans[c], step, carry)
+
+    @staticmethod
+    def _over(spans, step, carry):
+        for lo, hi, diagonal in spans:
+            carry = _span(lo, hi, functools.partial(step, diagonal=diagonal), carry)
+        return carry
 
 
 def _fwd_kernel(
     seed_ref, q_ref, k_ref, v_ref, bq_ref, bk_ref, bv_ref, kvm_ref, o_ref, lse_ref,
     m_scr, l_scr, acc_scr, vt_scr, **static,
 ):
-    t = _Tiles(1, **static)
+    t = _Tiles(1, seed_ref, **static)
 
     @_when(t.first_step)
     def _init():
@@ -888,8 +1078,8 @@ def _fwd_kernel(
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    @_when(t.run)
-    def _body():
+    @t.each_body
+    def _body(spans):
         # p v runs transposed (acc_t = v^T p_t): transpose the V block
         # once, every head of it together (a head's v^T is the rows
         # ``lanes`` of the result)
@@ -929,7 +1119,7 @@ def _fwd_kernel(
                     return m_new, l_new, acc * alpha + pv
 
                 m_scr[hh, r], l_scr[hh, r], acc_scr[r, lanes, :] = t.over_keys(
-                    r, k_step, (m_scr[hh, r], l_scr[hh, r], acc_scr[r, lanes, :])
+                    spans, r, k_step, (m_scr[hh, r], l_scr[hh, r], acc_scr[r, lanes, :])
                 )
 
     @_when(t.last_step)
@@ -949,14 +1139,14 @@ def _bwd_dq_kernel(
     lse_ref, delta_ref,
     dq_ref, dq_scr, kt_scr, **static,
 ):
-    t = _Tiles(1, **static)
+    t = _Tiles(1, seed_ref, **static)
 
     @_when(t.first_step)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    @_when(t.run)
-    def _body():
+    @t.each_body
+    def _body(spans):
         # dq_t += k^T ds_t: transpose the K block once, all its heads
         for c in range(t.nsk):
             kt_scr[c] = t.load(
@@ -992,7 +1182,8 @@ def _bwd_dq_kernel(
                     ds_t = p_t * (dp_t - delta)
                     return dq_t + _dot_t(kt_scr[c, lanes, :], ds_t, k_ref.dtype)
 
-                dq_scr[r, lanes, :] = t.over_keys(r, k_step, dq_scr[r, lanes, :])
+                dq_scr[r, lanes, :] = t.over_keys(
+                    spans, r, k_step, dq_scr[r, lanes, :])
 
     @_when(t.last_step)
     def _finalize():
@@ -1014,7 +1205,7 @@ def _bwd_dkv_kernel(
     transposed (``dq_t += k^T ds_t``, as ``_bwd_dq_kernel`` does) through
     every key block, and cast out in the last one's. Without ``fused`` it
     is the pair's dkv kernel and dq is ``_bwd_dq_kernel``'s."""
-    t = _Tiles(2, **static)
+    t = _Tiles(2, seed_ref, **static)
     if fused:
         dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr, kt_scr = results
     else:
@@ -1040,8 +1231,8 @@ def _bwd_dkv_kernel(
                 k_ref, bk_ref, _rows(c, t.sub_k)
             ).T.astype(kt_scr.dtype)
 
-    @_when(t.run)
-    def _body():
+    @t.each_body
+    def _body(spans):
         for hh, lanes in t.heads():
             for c in range(t.nsk):
                 keys = _rows(c, t.sub_k)
@@ -1086,7 +1277,7 @@ def _bwd_dkv_kernel(
                     return dk, dv
 
                 dk_scr[hh, keys, :], dv_scr[hh, keys, :] = t.over_queries(
-                    c, q_step, (dk_scr[hh, keys, :], dv_scr[hh, keys, :])
+                    spans, c, q_step, (dk_scr[hh, keys, :], dv_scr[hh, keys, :])
                 )
 
     @_when(t.last_step)
@@ -1262,31 +1453,20 @@ def _static(
     )
 
 
-def _needed_blocks(common, block_diffusion, key_major):
-    """``(g -> block, steps)`` for the operands a grid's INNER axis walks
-    (the keys' of a query-major grid ``(group, iq, ik)``, the queries' of a
-    key-major one ``(group, ik, iq)``) and how many steps that axis has; the
-    map is None where every step holds its own block. Under the band the
-    axis has only as many steps as a block of the outer axis needs blocks
-    (``_band_blocks``), and a step past a walk's last holds the last."""
-    block_q, block_k, nq, nk = (
-        common[k] for k in ("block_q", "block_k", "nq", "nk"))
-    steps = nq if key_major else nk
-    if common["window"]:
-        band = (block_q, block_k, nq, nk, common["diag_offset"],
-                common["window"])   # ``_Tiles.band``
+def _grid_form(common):
+    """What ``_walk_plan`` and ``_needed_blocks`` need of ``_static``'s."""
+    return tuple(common[k] for k in (
+        "block_q", "block_k", "nq", "nk", "causal", "diag_offset",
+        "block_diffusion", "window"))
 
-        def needed(g):
-            first, last = _band_blocks(g[1], *band, key_major)
-            return jnp.minimum(first + g[2], last)
 
-        return needed, _band_inner_steps(*band, key_major)
-    if not block_diffusion:
-        return None, steps
-    pick = _bd_query_block if key_major else _bd_key_block
-    return lambda g: pick(
-        g[1], g[2], block_q, block_k, nq * block_q // 2, block_diffusion
-    ), steps
+def _scalars(seed, plan):
+    """The kernels' SMEM operand: the dropout seed and, behind it, the class
+    of every grid step where the walk has a table (``_walk_plan``)."""
+    seed = jnp.reshape(jnp.asarray(seed, jnp.int32), (1,))
+    if plan["table"] is None:
+        return seed
+    return jnp.concatenate([seed, jnp.asarray(plan["table"], jnp.int32)])
 
 
 def _bias_row(bias, dtype):
@@ -1318,7 +1498,8 @@ def _forward_call(
         kv_mask is not None, dropout_rate > 0.0, block_diffusion, window,
     )
     hb, nsq = ops.heads_a_block, block_q // sub_q
-    keys, steps = _needed_blocks(common, block_diffusion, False)
+    keys, steps = _needed_blocks(False, *_grid_form(common))
+    plan = _walk_plan(False, sub_q, sub_k, *_grid_form(common))
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, sub_q=sub_q, sub_k=sub_k, **common),
         grid=(ops.groups, nq, steps),
@@ -1347,13 +1528,9 @@ def _forward_call(
         ],
         interpret=interpret,
         name="flash_fwd",
-    )(_seed_array(seed), q, k, v, *[_bias_row(bias, dtype)] * 3,
+    )(_scalars(seed, plan), q, k, v, *[_bias_row(bias, dtype)] * 3,
       _kvm_column(kv_mask))
     return out, lse.reshape(ops.batch * ops.heads, sq)
-
-
-def _seed_array(seed):
-    return jnp.reshape(jnp.asarray(seed, jnp.int32), (1,))
 
 
 def _backward_calls(
@@ -1387,7 +1564,8 @@ def _backward_calls(
         rows = (ops.batch * ops.heads, sq // sub_q, 1, sub_q)
         # the inner axis's operands: queries of the key-major walk, keys
         # of the query-major one
-        inner, steps = _needed_blocks(common, block_diffusion, key_major)
+        inner, steps = _needed_blocks(key_major, *_grid_form(common))
+        walk = _walk_plan(key_major, sub_q, sub_k, *_grid_form(common))
         qs, ks = (inner, None) if key_major else (None, inner)
         return pl.pallas_call(
             functools.partial(kernel, sub_q=sub_q, sub_k=sub_k, **common),
@@ -1405,7 +1583,7 @@ def _backward_calls(
             ],
             out_specs=out_specs, out_shape=out_shape, scratch_shapes=scratch,
             interpret=interpret, name=name, **params,
-        )(_seed_array(seed), q, k, v, *[_bias_row(bias, dtype)] * 3,
+        )(_scalars(seed, walk), q, k, v, *[_bias_row(bias, dtype)] * 3,
           _kvm_column(kv_mask), do, lse.reshape(rows), delta.reshape(rows))
 
     dkv_specs = [

@@ -118,14 +118,25 @@ def kernels(q, k, v, block, blocks):
 
 @pytest.mark.parametrize("backward", ["fused", "pair"])
 @pytest.mark.parametrize("block,blocks", [
-    (4, (64, 64)), (32, (64, 64)), (4, (32, 64)), (32, (128, 32))])
+    (4, (64, 64)), (32, (64, 64)), (4, (32, 64)), (32, (128, 32)),
+    (4, (128, 128)), (64, (128, 64)), (32, (64, 64, "loop"))])
 def test_kernels_against_the_dense_mask(block, blocks, backward, monkeypatch):
     """Values and the three gradients, 8 query heads on one kv head (the
     kv head's gradient is the sum over its query heads), L 128 = 32 or 4
-    blocks a side, on grids of 4 x 4 to 2 x 8 blocks; ``pair``: the
-    fallback kernels, by a VMEM budget that dq does not fit."""
+    blocks a side, on grids of 2 x 2 and 4 x 4 to 2 x 8 blocks; ``pair``: the
+    fallback kernels, by a VMEM budget that dq does not fit. Every grid's
+    walk is static (one body a class of step, PR 46); ``loop``: the walk
+    that shapes with more classes keep, by a ceiling of no body."""
     if backward == "pair":
         monkeypatch.setattr(A, "FUSED_DQ_VMEM_BUDGET", 1)
+    if blocks[2:]:
+        monkeypatch.setattr(A, "MAX_WALK_BODIES", 0)
+    walk = "loop" if blocks[2:] else "static"
+    blocks = blocks[:2]
+    tiling = A.flash_tiling(
+        256, 256, *blocks, False, lanes=16, itemsize=4, block_diffusion=block)
+    assert tiling["walk"] == tiling["backward"]["walk"] == walk
+    assert tiling["steps"]["fetched"] == tiling["steps"]["run"]
     rng = np.random.default_rng(block + blocks[0])
     q = normal(rng, 1, 8, 256, 16)
     k, v = normal(rng, 1, 1, 256, 16), normal(rng, 1, 1, 256, 16)
@@ -189,13 +200,16 @@ def test_refusals(kwargs, why):
 
 
 def test_visited_share_at_the_cell_shape_is_logged():
-    """micro 2 x 32 heads x (2 x 8,192) x 128: 16 blocks a side, 512 square
-    sub-tiles, 288 of 1,024 visited both ways: 28% of the (2L)^2 square,
-    where a causal walk over 2L visits 52%."""
+    """micro 2 x 32 heads x (2 x 8,192) x 128: 16 blocks a side; the forward
+    in 512 square sub-tiles, 288 of 1,024 visited: 28% of the (2L)^2 square,
+    where a causal walk over 2L visits 52%; the fused backward in 256 square
+    sub-tiles since PR 46 (its walk is static on the 16 x 16 grid): 1,088 of
+    4,096, 27%."""
     tiling = A.flash_tiling(
         16384, 16384, 1024, 1024, False, lanes=128, block_diffusion=4)
     assert tiling["visited_share"] == 288 / 1024
-    assert tiling["backward"]["visited_share"] == 288 / 1024
+    assert tiling["backward"]["visited_share"] == 1088 / 4096
+    assert (tiling["backward"]["sub_q"], tiling["backward"]["sub_k"]) == (256, 256)
     assert tiling["backward"]["backward"] == "fused"
     assert A.flash_tiling(
         16384, 16384, 1024, 1024, True, lanes=128)["visited_share"] == 528 / 1024
@@ -214,7 +228,8 @@ def test_visited_share_at_the_cell_shape_is_logged():
         logger.removeHandler(handler)
         logger.setLevel(level)
     line, = [m for m in seen if m.startswith("flash_tiling")]
-    assert "visited_share=0.2812" in line and "bwd_visited_share=0.2812" in line
+    assert "visited_share=0.2812" in line and "bwd_visited_share=0.2656" in line
+    assert line.count("walk=static bodies=3 steps=80/176/80 ") == 2
     assert "backward=fused" in line and line.endswith("block_diffusion=4")
 
 

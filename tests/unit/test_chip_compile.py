@@ -86,10 +86,36 @@ def _no_persistent_cache():
     cc.reset_cache()
 
 
-def _compiled_text(fn, *shapes):
-    text = jax.jit(fn).lower(*shapes).compile().as_text()
+def _compiled_text(fn, *shapes, lowered=None):
+    """The compiled program's text; ``lowered``: a list that takes the
+    StableHLO text it was compiled from (``_mosaic_kernels`` reads it)."""
+    low = jax.jit(fn).lower(*shapes)
+    if lowered is not None:
+        lowered.append(low.as_text())
+    text = low.compile().as_text()
     assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
     return text
+
+
+def _window_program_tool():
+    """``tools/window_program.py`` as a module (``tools`` is no package)."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "window_program", os.path.join(REPO, "tools", "window_program.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    return tool
+
+
+def _mosaic_kernels(lowered_text):
+    """``{kernel name: [assembly, ...]}`` of the Mosaic modules in a lowered
+    program's text, decoded as ``tools/window_program.py`` decodes them."""
+    tool, kernels = _window_program_tool(), {}
+    for _, body, _ in tool.BODY.findall(lowered_text):
+        name, asm = tool.mosaic_assembly(body)
+        kernels.setdefault(name, []).append(asm)
+    return kernels
 
 
 # ---------------------------------------------------------------------------
@@ -184,13 +210,26 @@ def test_flash_kernels_compile_for_v5e(shape):
     # one platform probe, which a rehearsal answers for the described chip
     real = device.on_tpu, jax.device_count
     device.on_tpu, jax.device_count = (lambda: True), (lambda: 1)
+    lowered = []
     try:
         text = _compiled_text(
-            jax.grad(loss, argnums=tuple(range(len(operands)))), *operands, pad
+            jax.grad(loss, argnums=tuple(range(len(operands)))), *operands, pad,
+            lowered=lowered,
         )
     finally:
         device.on_tpu, jax.device_count = real
     assert _pallas_kernels(text) == kernels
+    # PR 46: where the fused backward runs, on one block or on a grid of
+    # several, a kernel walks its sub-tiles in bodies whose bounds are ints:
+    # no loop in the Mosaic module, one body a class of step (two under
+    # causal: ON the diagonal and UNDER it)
+    if kernels == FUSED:
+        mosaic = _mosaic_kernels(lowered[0])
+        assert sorted(mosaic) == kernels
+        for name, (asm,) in mosaic.items():
+            assert "scf.for" not in asm and "scf.while" not in asm, name
+            several = causal and s > 1024
+            assert asm.count("scf.if") >= (2 if several else 0), name
 
 
 # ---------------------------------------------------------------------------
@@ -552,14 +591,9 @@ def test_window_program_hashes_a_kernel_without_its_debug_locations():
     cell: the same hash on both commits. A Mosaic kernel's bytecode holds
     file paths and line numbers, so the same kernel lowered from two call
     sites differs in its raw bytes and must not in the tool's hash."""
-    import importlib.util
-
     from deepspeed_tpu.ops.attention import flash_attention
 
-    spec = importlib.util.spec_from_file_location(
-        "window_program", os.path.join(REPO, "tools", "window_program.py"))
-    tool = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(tool)
+    tool = _window_program_tool()
     q = _shape((1, 2, 512, 128), jnp.bfloat16)
 
     def here(q, k, v):

@@ -28,12 +28,16 @@ def operands(seed, b, h, s, dqk, dv, dtype=jnp.float32):
 TIGHT = dict(atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.parametrize("block", [1024, 64])
+@pytest.mark.parametrize("block", [1024, 128, 64])
 @pytest.mark.parametrize("dqk,dv", [(192, 128), (48, 16), (64, 128)])
 def test_forward_and_gradients_against_the_reference(dqk, dv, block):
-    """``block`` 1024: one block each way (the static walk); 64: a 4 x 4 grid
-    (the ``fori_loop`` walk, the causal skip of whole blocks)."""
+    """``block`` 1024: one block each way; 128 and 64: grids of 2 x 2 and
+    4 x 4 (a body ON the diagonal and one UNDER it, the causal skip of whole
+    blocks); the walk static on all three."""
     q, k, v, g = operands(dqk + dv, 2, 2, 256, dqk, dv)
+    tiling = att.flash_tiling(
+        256, 256, min(block, 256), min(block, 256), True, lanes=dqk, itemsize=4)
+    assert tiling["walk"] == tiling["backward"]["walk"] == "static"
 
     def ours(q, k, v):
         return jnp.sum(att.flash_attention(
@@ -54,12 +58,14 @@ def test_forward_and_gradients_against_the_reference(dqk, dv, block):
         np.testing.assert_allclose(a / scale, r / scale, atol=2e-5)
 
 
-def test_lse_and_residuals_follow_the_value_width():
+@pytest.mark.parametrize("block", [128, 64, 32])
+def test_lse_and_residuals_follow_the_value_width(block):
     """``lse`` is the scores' log-sum-exp at 1 / sqrt(Dqk) (one float32 a
-    query row); the named residual ``flash_out`` has v's width."""
+    query row); the named residual ``flash_out`` has v's width. On one block
+    and on grids of 2 x 2 and 4 x 4."""
     q, k, v, _ = operands(3, 1, 2, 128, 192, 128)
     out, residuals = att._flash_fwd(
-        q, k, v, None, jnp.int32(0), True, 192 ** -0.5, 0.0, 128, 128)
+        q, k, v, None, jnp.int32(0), True, 192 ** -0.5, 0.0, block, block)
     lse = residuals[-1]
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) * 192 ** -0.5
     scores = jnp.where(jnp.tril(jnp.ones((128, 128), bool)), scores, -1e30)
@@ -68,9 +74,12 @@ def test_lse_and_residuals_follow_the_value_width():
     assert residuals[-2].shape == out.shape == (1, 2, 128, 128)
 
 
-def test_the_pair_of_backward_kernels_takes_them_too(monkeypatch):
-    """A budget of 0 forces ``flash_bwd_dq`` + ``flash_bwd_dkv``."""
-    q, k, v, g = operands(5, 1, 2, 256, 192, 128)
+@pytest.mark.parametrize("sq,sk", [(256, 256), (512, 256)])
+def test_the_pair_of_backward_kernels_takes_them_too(sq, sk, monkeypatch):
+    """A budget of 0 forces ``flash_bwd_dq`` + ``flash_bwd_dkv``; on a 2 x 2
+    grid, and on 4 x 2 with the diagonal two blocks down."""
+    q, _, _, g = operands(5, 1, 2, sq, 192, 128)
+    _, k, v, _ = operands(6, 1, 2, sk, 192, 128)
 
     def loss(q, k, v):
         return jnp.sum(att.flash_attention(

@@ -287,6 +287,17 @@ FLASH_CASES = {
     # row 1: trailing padding
     "causal_384_leading_keys_masked": (384, 384, True, [(0, 130), (300, 384)], 1024),
     "noncausal_384_masked": (384, 384, False, [(0, 130), (300, 384)], 1024),
+    # grids of several blocks at blocks of 128 (PR 46: one body a class of
+    # step, ON the diagonal or UNDER it, the class from a table in SMEM; the
+    # skipped steps hold their neighbour's block): 2 x 2; 3 x 3; 2 x 4 with the
+    # diagonal two blocks to the right; 4 x 2 with it two blocks down, so the
+    # first 256 rows see no key and two of the four Q blocks' steps all skip
+    "causal_256_blocks128": (256, 256, True, None, 128),
+    "causal_384_blocks128": (384, 384, True, None, 128),
+    "causal_sq256_sk512_blocks128": (256, 512, True, None, 128),
+    "causal_sq512_sk256_blocks128": (512, 256, True, None, 128),
+    # a key mask on a grid of several blocks, row 0's first block all padding
+    "causal_384_blocks128_masked": (384, 384, True, [(0, 130), (300, 384)], 128),
 }
 
 
@@ -298,9 +309,11 @@ _DTYPES = {"f32": jnp.float32, "bf16": jnp.bfloat16}
 FLASH_RUNS = [
     pytest.param(case, dtype, None, False, id=f"{case}-{dtype}")
     for case in sorted(FLASH_CASES) for dtype in _DTYPES
+    if dtype == "f32" or "_blocks128" not in case
 ] + [
     pytest.param(case, "f32", 64, False, id=f"{case}-f32-packed_d64")
-    for case in sorted(FLASH_CASES) if case != "causal_sq256_sk512"
+    for case in sorted(FLASH_CASES)
+    if FLASH_CASES[case][0] == FLASH_CASES[case][1]
 ] + [
     pytest.param(case, dtype, d, False, id=f"{case}-{dtype}-packed_d{d}")
     for case, dtype, d in [
@@ -330,6 +343,17 @@ FLASH_RUNS = [
         ("causal_2048", "bf16", 64),
         ("noncausal_384_masked", "f32", 64),
         ("causal_768_blocks256", "f32", 128),
+        ("causal_sq256_sk512_blocks128", "f32", ""),
+        ("causal_sq512_sk256_blocks128", "f32", ""),
+    ]
+] + [
+    # The walk whose bounds follow from the grid position as a step runs (a
+    # ``fori_loop``), which shapes with more classes of step than
+    # ``MAX_WALK_BODIES`` keep: the same cases with the ceiling at nothing.
+    pytest.param(case, "f32", d, "loop", id=f"{case}-f32-{d and f'packed_d{d}-'}loop")
+    for case, d in [
+        ("causal_sq256_sk512_blocks128", ""), ("causal_sq512_sk256_blocks128", ""),
+        ("causal_384_blocks128_masked", 64), ("causal_2048", ""),
     ]
 ]
 
@@ -361,6 +385,15 @@ def test_flash_tiled_matches_reference(case, dtype, packed_d, pair, monkeypatch)
     att = _attention_module()
     sq, sk, causal, masked, block = FLASH_CASES[case]
     dtype = _DTYPES[dtype]
+    blocks = att.pick_block(sq, block), att.pick_block(sk, block)
+    several = sq // blocks[0] > 1 or sk // blocks[1] > 1
+    if pair == "loop":
+        monkeypatch.setattr(att, "MAX_WALK_BODIES", 0)
+    walk = att.flash_tiling(sq, sk, *blocks, causal)
+    assert walk["walk"] == walk["backward"]["walk"] == (
+        "loop" if pair == "loop" else "static")
+    assert walk["bodies"] == (1 if pair == "loop" or not several else 2)
+    pair = pair is True
     B, H, D = (1, 1, 64) if sq > 1024 else (2, 2, 64)
     if packed_d:
         # a 128-lane block holds whole heads: two of 64, one of 128
@@ -371,15 +404,18 @@ def test_flash_tiled_matches_reference(case, dtype, packed_d, pair, monkeypatch)
     )
     w = rng.normal(size=(B, H, sq, D)).astype(np.float32)
     kv_mask = add = None
-    live = np.ones((B, sq), bool)  # query rows that see at least one key
+    valid = np.ones((B, sk), np.int32)
     if masked is not None:
-        valid = np.ones((B, sk), np.int32)
         for b, (lo, hi) in enumerate(masked):
             valid[b, lo:hi] = 0
         kv_mask = jnp.asarray(valid)
         add = jnp.where(kv_mask[:, None, None, :] > 0, 0.0, -1e30)
-        if causal:
-            live = np.cumsum(valid, axis=1)[:, sk - sq:] > 0
+    # query rows that see at least one key: row i sees keys 0 .. i + sk - sq
+    live = np.ones((B, sq), bool)
+    if causal:
+        before = np.concatenate(
+            [np.zeros((B, 1), np.int32), np.cumsum(valid, axis=1)], axis=1)
+        live = before[:, np.clip(np.arange(sq) + sk - sq + 1, 0, sk)] > 0
     # rows without a live key: flash gives zeros, the reference an average
     # over masked keys; they take no part in the comparison
     w = jnp.asarray(w * live[:, None, :, None])
@@ -397,18 +433,36 @@ def test_flash_tiled_matches_reference(case, dtype, packed_d, pair, monkeypatch)
     def reference(q, k, v):
         return mha_reference(f32(q), f32(k), f32(v), mask=add, causal=causal)
 
-    out, ref = np.asarray(flash(q, k, v)), np.asarray(reference(q, k, v))
+    lse = None
+    if packed_d:
+        out = flash(q, k, v)
+    else:
+        # the forward's other result too, lse a row, which the backward reads
+        out, (*_, lse) = att._flash_fwd(
+            q, k, v, kv_mask, jnp.zeros((), jnp.int32), causal, D ** -0.5, 0.0,
+            *blocks)
+    out, ref = np.asarray(f32(out)), np.asarray(reference(q, k, v))
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
     keep = np.broadcast_to(live[:, None, :, None], out.shape)
     np.testing.assert_allclose(out[keep], ref[keep], rtol=tol, atol=tol)
     assert not out[~keep].any(), "a row with no live key must come out exactly zero"
     if masked is not None and causal:
         assert (~live).sum() >= 130
+    if lse is not None:
+        scores = jnp.einsum("bhqd,bhkd->bhqk", f32(q), f32(k)) * D ** -0.5
+        allowed = np.broadcast_to(valid[:, None, None, :] > 0, scores.shape)
+        if causal:
+            allowed = allowed & np.asarray(
+                np.arange(sk)[None, :] <= np.arange(sq)[:, None] + sk - sq)
+        want = jax.nn.logsumexp(jnp.where(allowed, scores, -jnp.inf), axis=-1)
+        rows = np.broadcast_to(live[:, None, :], want.shape)
+        np.testing.assert_allclose(
+            np.asarray(lse).reshape(want.shape)[rows], np.asarray(want)[rows],
+            rtol=tol, atol=tol)
 
     def flash_grads():
         return jax.grad(lambda *a: jnp.sum(flash(*a) * w), (0, 1, 2))(q, k, v)
 
-    blocks = att.pick_block(sq, block), att.pick_block(sk, block)
     assert att.backward_plan(sq, sk, *blocks, causal)["backward"] == "fused"
     gf = flash_grads()
     if pair:
@@ -453,7 +507,7 @@ def test_flash_loop_bounds_stop_at_the_diagonal():
     assert (t["sub_q"], t["sub_k"], t["visited_share"]) == (512, 512, 0.75)
     t = flash_tiling(1024, 1024, 1024, 1024, True, key_major=True)
     assert (t["sub_q"], t["sub_k"], t["visited_share"]) == (128, 128, 0.5625)
-    # on a grid of several blocks dkv too walks in the large sub-tile
+    # on a grid of several blocks the pair's dkv walks in the large sub-tile
     t = flash_tiling(2048, 2048, 1024, 1024, True, key_major=True)
     assert (t["sub_q"], t["sub_k"]) == (512, 512)
     # the backward (PR 33): one key-major kernel for dq, dk and dv, in
@@ -465,16 +519,20 @@ def test_flash_loop_bounds_stop_at_the_diagonal():
     assert b == {
         "backward": "fused", "sub_q": 256, "sub_k": 256,
         "visited_share": 0.625, "dq_vmem_bytes": 1024 * 128 * 8,
-        "reason": None,
+        "reason": None, "walk": "static", "bodies": 1,
+        "steps": {"run": 1, "skipped": 0, "fetched": 1},
     }
     # BERT at 512: one block, 2 x 2 sub-tiles; 768: 3 x 3
     b = flash_tiling(512, 512, 512, 512, False)["backward"]
     assert (b["sub_q"], b["sub_k"], b["visited_share"]) == (256, 256, 1.0)
     b = flash_tiling(768, 768, 768, 768, True)["backward"]
     assert (b["sub_q"], b["sub_k"]) == (256, 256)
+    # on a grid of several blocks the fused kernel keeps its 256-steps since
+    # PR 46 (the walk is static there too: 5/8 of a diagonal block where 512
+    # visits 3/4; docs/TESTING.md has the sweep), the pair's dkv the 512
     b = flash_tiling(8192, 8192, 1024, 1024, True)["backward"]
-    assert (b["backward"], b["sub_q"], b["sub_k"]) == ("fused", 512, 512)
-    assert b["visited_share"] == 0.53125 and b["dq_vmem_bytes"] == 8 * 2**20
+    assert (b["backward"], b["sub_q"], b["sub_k"]) == ("fused", 256, 256)
+    assert b["visited_share"] == 33 / 64 and b["dq_vmem_bytes"] == 8 * 2**20
     # a block of 64 lanes takes a register's 128 all the same
     assert flash_tiling(1024, 1024, 1024, 1024, True, lanes=64)["backward"][
         "dq_vmem_bytes"] == 2**20
@@ -494,6 +552,48 @@ def test_flash_loop_bounds_stop_at_the_diagonal():
     assert (t["sub_q"], t["sub_k"]) == (256, 256)
     t = flash_tiling(1032, 1032, 8, 8, True)
     assert (t["sub_q"], t["sub_k"]) == (8, 8)
+
+    # PR 46: how a kernel walks its GRID. One block each way: one body, one
+    # step. The cells' causal grids: a step lies ON the diagonal or UNDER it
+    # (two bodies, bounds as ints), the steps above it are skipped and hold
+    # their neighbour's block, so nothing is fetched for them
+    def walk(t):
+        return t["walk"], t["bodies"], t["steps"]
+
+    one = {"run": 1, "skipped": 0, "fetched": 1}
+    t = flash_tiling(1024, 1024, 1024, 1024, True)
+    assert walk(t) == walk(t["backward"]) == ("static", 1, one)
+    assert walk(flash_tiling(512, 512, 512, 512, False)) == ("static", 1, one)
+    for seq, run in ((8192, 36), (16384, 136), (2048, 3)):
+        steps = {"run": run, "skipped": (seq // 1024) ** 2 - run, "fetched": run}
+        t = flash_tiling(seq, seq, 1024, 1024, True, lanes=256)
+        assert walk(t) == walk(t["backward"]) == ("static", 2, steps)
+    # past the VMEM budget the pair: both its walks are static too
+    t = flash_tiling(32768, 32768, 1024, 1024, True, lanes=256)
+    assert t["backward"]["backward"] == "pair"
+    assert walk(t)[:2] == walk(t["backward"])[:2] == ("static", 2)
+    # not causal, several blocks: every step the same, one body, all fetched
+    assert walk(flash_tiling(2048, 2048, 1024, 1024, False)) == (
+        "static", 1, {"run": 4, "skipped": 0, "fetched": 4})
+    # Laguna's band, W 512 under 1,024-blocks: 2 steps a query block, its own
+    # key block and the one before; the first query block's second step skips
+    t = flash_tiling(8192, 8192, 1024, 1024, True, window=512)
+    assert walk(t) == walk(t["backward"]) == (
+        "static", 2, {"run": 15, "skipped": 1, "fetched": 15})
+    # SDAR's block-diffusion mask on 16 x 16: 80 of 256 steps run, 3 bodies
+    t = flash_tiling(16384, 16384, 1024, 1024, False, block_diffusion=4)
+    assert walk(t) == walk(t["backward"]) == (
+        "static", 3, {"run": 80, "skipped": 176, "fetched": 80})
+    # diag_offset != 0 and blocks that differ each way: the same computation
+    t = flash_tiling(2048, 3072, 1024, 512, True)
+    assert walk(t) == ("static", 3, {"run": 10, "skipped": 2, "fetched": 10})
+    # more classes than MAX_WALK_BODIES (a Q block of two halves' worth under
+    # the block-diffusion mask): the walk stays the ``fori_loop`` it was, one
+    # body whose bounds follow from the grid position; the index maps still
+    # hold a neighbour's block through the skipped steps
+    t = flash_tiling(16384, 16384, 2048, 1024, False, block_diffusion=4)
+    assert walk(t)[:2] == walk(t["backward"])[:2] == ("loop", 1)
+    assert t["steps"]["fetched"] == t["steps"]["run"] < 8 * 16
 
     for sq, sk, bq, bk, sub_q, sub_k in [
         (1024, 1024, 512, 512, 128, 128), (768, 768, 256, 256, 128, 128),
@@ -534,6 +634,76 @@ def test_flash_loop_bounds_stop_at_the_diagonal():
         assert by_keys == by_rows
 
 
+# One forward + backward as jaxpr text (kernel bodies, grids and index maps
+# included, function addresses stripped): (entry, seq, heads, head width,
+# causal, key mask, bias, the mask form's keywords, block).
+WALK_PROGRAMS = {
+    # one block each way, the six GPT-2 and BERT cells' calls at two heads: the
+    # parent's text (sha256 of bd1970c's, PR 46: the classes must not reach it)
+    "gpt2_packed_1024": (
+        "packed", 1024, 2, 64, True, False, True, {}, 1024, "3f5fc0c0372a70c5"),
+    "bert_packed_512_masked": (
+        "packed", 512, 2, 64, False, True, True, {}, 1024, "653f50cce8b25de8"),
+    "bert_packed_384_masked": (
+        "packed", 384, 2, 64, False, True, True, {}, 1024, "b68fbe756e2b1649"),
+    "split_causal_1024_d64": (
+        "split", 1024, 1, 64, True, False, False, {}, 1024, "e48e444c7b019763"),
+    "split_noncausal_1024_d128": (
+        "split", 1024, 1, 128, False, False, False, {}, 1024, "922bc84e5265d3de"),
+    # grids of several blocks: no loop in either kernel
+    "causal_2048": ("split", 2048, 1, 128, True, False, False, {}, 1024, None),
+    "causal_512_blocks128_masked": (
+        "split", 512, 1, 64, True, True, False, {}, 128, None),
+    "packed_causal_2048": ("packed", 2048, 1, 128, True, False, False, {}, 1024, None),
+    "band_2048": (
+        "split", 2048, 1, 128, True, False, False, {"window": 512}, 1024, None),
+    "block_diffusion_4096": (
+        "split", 4096, 1, 128, False, False, False, {"block_diffusion": 4}, 1024,
+        None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WALK_PROGRAMS))
+def test_flash_walk_is_static_and_one_block_is_the_program_it_was(case):
+    import hashlib
+    import re
+
+    from deepspeed_tpu.ops.attention import flash_attention_packed
+
+    entry, s, h, d, causal, masked, biased, form, block, pin = WALK_PROGRAMS[case]
+    blocks = dict(block_q=block, block_k=block)
+    if entry == "packed":
+        args = [jax.ShapeDtypeStruct((1, s, 3 * h * d), jnp.bfloat16),
+                jax.ShapeDtypeStruct((3 * h * d,), jnp.bfloat16)]
+        nums = (0, 1) if biased else (0,)
+
+        def loss(qkv, bias, kvm=None):
+            return flash_attention_packed(
+                qkv, h, bias=bias if biased else None, kv_mask=kvm,
+                causal=causal, **blocks,
+            ).astype(jnp.float32).sum()
+    else:
+        args = [jax.ShapeDtypeStruct((1, h, s, d), jnp.bfloat16)] * 3
+        nums = (0, 1, 2)
+
+        def loss(q, k, v, kvm=None):
+            return flash_attention(
+                q, k, v, kv_mask=kvm, causal=causal, **form, **blocks
+            ).astype(jnp.float32).sum()
+
+    if masked:
+        args.append(jax.ShapeDtypeStruct((1, s), jnp.int32))
+    text = re.sub(
+        r"0x[0-9a-f]+", "0x", str(jax.make_jaxpr(jax.grad(loss, nums))(*args)))
+    assert text.count("pallas_call") >= 2
+    assert "while" not in text and "scan" not in text
+    if pin:
+        assert hashlib.sha256(text.encode()).hexdigest()[:16] == pin
+    else:
+        # the class of the running step comes from the table behind the seed
+        assert text.count("cond[") >= 4
+
+
 @contextlib.contextmanager
 def _attention_debug_log():
     """The debug lines ``ops/attention.py`` logs while the block is open,
@@ -568,9 +738,11 @@ def test_flash_tiling_is_logged_once_per_shape():
     assert f" visited_share={t['visited_share']:.4f} " in lines[0]
     b = att.flash_tiling(1024, 1024, 1024, 1024, True, lanes=64, itemsize=4)[
         "backward"]
+    assert " walk=static bodies=1 steps=1/0/1 backward=fused " in lines[0]
     assert lines[0].endswith(
         f" backward=fused bwd_sub={b['sub_q']}x{b['sub_k']} "
         f"bwd_visited_share={b['visited_share']:.4f} "
+        f"bwd_walk=static bodies=1 steps=1/0/1 "
         f"dq_vmem_bytes={b['dq_vmem_bytes']}"
     )
 
@@ -618,14 +790,21 @@ def test_backward_is_chosen_by_shape_and_logged(cell):
     plan = att.backward_plan(s, s, block, block, causal, lanes)
     if cell == "seq32768_width256":
         assert plan["backward"] == "pair"
-        assert line.endswith(f" backward=pair bwd_sub=512x512 "
+        assert line.endswith(f" backward=pair bwd_sub={plan['sub_q']}x{plan['sub_k']} "
                              f"bwd_visited_share={plan['visited_share']:.4f} "
+                             f"bwd_walk=static bodies=2 steps=528/496/528 "
                              f"dq_vmem_bytes=0 reason={plan['reason']!r}")
         return
     assert plan["backward"] == "fused" and plan["reason"] is None
     assert plan["dq_vmem_bytes"] == s * lanes * 8 <= att.FUSED_DQ_VMEM_BUDGET
     assert f" backward=fused bwd_sub={plan['sub_q']}x{plan['sub_k']} " in line
     assert line.endswith(f" dq_vmem_bytes={plan['dq_vmem_bytes']}")
+    # the walk of both kernels: static at every cell's shape, and nothing
+    # fetched for a step that skips
+    run = (s // block) * (s // block + 1) // 2 if causal else (s // block) ** 2
+    steps = f"steps={run}/{(s // block) ** 2 - run}/{run}"
+    bodies = 2 if causal and s > block else 1
+    assert line.count(f"walk=static bodies={bodies} {steps} ") == 2
 
 
 # what the dispatcher sees in each cell of the benchmark (and in two shapes

@@ -127,11 +127,11 @@ def kernels(q, k, v, window, blocks):
 def test_kernels_against_the_dense_band(window, seq, backward, monkeypatch):
     """Values and the three gradients, 4 query heads on one kv head (the kv
     head's gradient is the sum over its query heads), 256-blocks in 128
-    sub-tiles: one block a side (every bound static, the walks unroll) and a
-    2 x 2 grid (``fori_loop`` walks, an inner axis of as many steps as the
-    band needs); W 50 under a sub-tile, 200 between sub-tile and block, 256
-    a block, 300 above it, 600 past S (plain causal); ``pair``: the fallback
-    kernels, by a VMEM budget that dq does not fit."""
+    sub-tiles: one block a side and a 2 x 2 grid (an inner axis of as many
+    steps as the band needs; one body a class of step, PR 46), every bound
+    static and the walks unrolled in both; W 50 under a sub-tile, 200 between
+    sub-tile and block, 256 a block, 300 above it, 600 past S (plain causal);
+    ``pair``: the fallback kernels, by a VMEM budget that dq does not fit."""
     for name in ("SUB_QUERY_MAJOR", "SUB_KEY_MAJOR", "SUB_FUSED"):
         monkeypatch.setattr(A, name, 128)
     if backward == "pair":
@@ -161,12 +161,23 @@ def test_kernels_against_the_dense_band(window, seq, backward, monkeypatch):
 
 
 @pytest.mark.parametrize("window,blocks", [
-    (5, (64, 64)), (24, (64, 128)), (100, (128, 32)), (64, (32, 32))])
-def test_kernels_on_uneven_grids(window, blocks):
+    (5, (64, 64)), (24, (64, 128)), (100, (128, 32)), (64, (32, 32)),
+    (100, (128, 128)), (150, (128, 128, 384)), (70, (64, 64, 256, "loop"))])
+def test_kernels_on_uneven_grids(window, blocks, monkeypatch):
     """Blocks that differ each way and a band that divides nothing: grids of
-    2 x 4 to 8 x 8 blocks, whose inner axes take 2 to 5 steps."""
+    2 x 2, 3 x 3 (S 384) and 2 x 4 to 8 x 8 blocks, whose inner axes take 2 to
+    5 steps; the walk static on each (PR 46), and once the ``loop`` that
+    shapes with more classes of step keep, by a ceiling of no body."""
+    blocks, seq, loop = blocks[:2], (*blocks, 256)[2], blocks[3:]
+    if loop:
+        monkeypatch.setattr(A, "MAX_WALK_BODIES", 0)
+    tiling = A.flash_tiling(
+        seq, seq, *blocks, True, lanes=16, itemsize=4, window=window)
+    assert tiling["walk"] == tiling["backward"]["walk"] == (
+        "loop" if loop else "static")
+    assert tiling["steps"]["fetched"] == tiling["steps"]["run"]
     rng = np.random.default_rng(window)
-    q, k, v, w = (normal(rng, 1, 2, 256, 16) for _ in range(4))
+    q, k, v, w = (normal(rng, 1, 2, seq, 16) for _ in range(4))
     np.testing.assert_allclose(
         kernels(q, k, v, window, blocks), reference(q, k, v, window),
         atol=2e-5, rtol=2e-5)
@@ -262,16 +273,20 @@ def test_older_callers_and_a_window_past_the_row_trace_as_before(
 
 
 def test_visited_share_at_the_cell_shape_is_logged(monkeypatch):
-    """micro 2 x 72 heads x 8,192 x 128 under W 512: 8 blocks a side, 512
-    square sub-tiles, 31 of 256 visited both ways (a stripe's own sub-tile
-    and the one before), 12% of the square where the allowed pairs are 6%
-    and a causal walk visits 53%; the inner axes take 2 steps of 8."""
+    """micro 2 x 72 heads x 8,192 x 128 under W 512: 8 blocks a side; the
+    forward in 512 square sub-tiles, 31 of 256 visited (a stripe's own
+    sub-tile and the one before), 12% of the square where the allowed pairs
+    are 6% and a causal walk visits 53%; the fused backward in 256 square
+    sub-tiles since PR 46 (its walk is static on the 8 x 2 grid): 93 of 1,024
+    (a stripe's own and the two before), 9%; the inner axes take 2 steps of
+    8."""
     tiling = A.flash_tiling(
         8192, 8192, 1024, 1024, True, lanes=128, window=512)
     assert tiling["visited_share"] == 31 / 256
-    assert tiling["backward"]["visited_share"] == 31 / 256
+    assert tiling["backward"]["visited_share"] == 93 / 1024
     assert tiling["backward"]["backward"] == "fused"
     assert (tiling["sub_q"], tiling["sub_k"]) == (512, 512)
+    assert (tiling["backward"]["sub_q"], tiling["backward"]["sub_k"]) == (256, 256)
     assert A.flash_tiling(
         8192, 8192, 1024, 1024, True, lanes=128)["visited_share"] == 136 / 256
     band = (1024, 1024, 8, 8, 0, 512)
@@ -303,6 +318,7 @@ def test_visited_share_at_the_cell_shape_is_logged(monkeypatch):
         "heads_a_block=1 reason='q, k and v arrive as separate [B, H, S, D] "
         "arrays'" for heads in (72, 48)]
     assert "visited_share=0.1211" in banded
-    assert "bwd_visited_share=0.1211" in banded
+    assert "bwd_visited_share=0.0908" in banded
+    assert banded.count("walk=static bodies=2 steps=15/1/15 ") == 2
     assert "backward=fused" in banded and banded.endswith("window=512")
     assert "visited_share=0.5312" in causal and "window" not in causal

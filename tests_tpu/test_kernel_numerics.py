@@ -112,26 +112,62 @@ def test_flash_grads_match_reference_compiled(causal):
         assert rel < 5e-2, f"d{name} rel err {rel:.2e}"
 
 
-def test_flash_matches_reference_at_the_cells_shape():
-    """Forward and gradients at the shape both GPT-2 cells of the
-    benchmark run ([8, 20, 1024, 64] bf16, causal, no key mask, dropout
-    off: one block each way, so every loop bound is static and the walk
-    over sub-tiles unrolls) against the float32 reference."""
-    b, h, s, d = 8, 20, 1024, 64
+# (entry, [b, h, s], q/k width, v width, the mask form's keywords): the GPT-2
+# cells' one block each way, and the 8 x 8 grids of 1,024-blocks of the cells
+# whose kernels lower one body a class of step (PR 46), at two heads so that
+# the float32 reference's [b, h, s, s] scores fit beside them
+CELL_SHAPES = {
+    "gpt2_one_block": ("split", (8, 20, 1024), 64, 64, {"causal": True}),
+    "ouro_packed_8x8": ("packed", (1, 2, 8192), 128, 128, {"causal": True}),
+    "joyai_192_128_8x8": ("split", (1, 2, 8192), 192, 128, {"causal": True}),
+    "sdar_mask_8x8": ("split", (1, 2, 8192), 128, 128, {"block_diffusion": 4}),
+    "laguna_band_8x8": (
+        "split", (1, 2, 8192), 128, 128, {"causal": True, "window": 512}),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_SHAPES))
+def test_flash_matches_reference_at_the_cells_shape(cell):
+    """Forward and gradients against the float32 reference, bf16, no key
+    mask, dropout off: at the shape both GPT-2 cells of the benchmark run
+    (one block each way) and on the 8 x 8 grids of the several-block cells
+    (Ouro's packed operands, JoyAI's q/k of 192 lanes on v of 128, SDAR's
+    block-diffusion mask, Laguna's band), where the walk is static too."""
+    import importlib
+
+    att = importlib.import_module("deepspeed_tpu.ops.attention")
+    entry, (b, h, s), d, dv, form = CELL_SHAPES[cell]
     ks = jax.random.split(jax.random.PRNGKey(25), 4)
     q, k, v, w = (
-        jax.random.normal(kk, (b, h, s, d), jnp.float32).astype(jnp.bfloat16)
-        for kk in ks
+        jax.random.normal(kk, (b, h, s, width), jnp.float32).astype(jnp.bfloat16)
+        for kk, width in zip(ks, (d, d, dv, dv))
     )
+    block = min(s, 1024)
+    tiling = att.flash_tiling(
+        s, s, block, block, form.get("causal", False),
+        block_diffusion=form.get("block_diffusion", 0),
+        window=form.get("window", 0))
+    for walk in (tiling, tiling["backward"]):
+        assert walk["walk"] == "static"
+        assert walk["steps"]["fetched"] == walk["steps"]["run"]
 
     def f32(x):
         return x.astype(jnp.float32)
 
     def flash(q, k, v):
-        return f32(flash_attention(q, k, v, causal=True))
+        if entry == "packed":
+            qkv = jnp.concatenate([_merge(q), _merge(k), _merge(v)], -1)
+            out = flash_attention_packed(qkv, h, **form)
+            return f32(out.reshape(b, s, h, dv).transpose(0, 2, 1, 3))
+        return f32(flash_attention(q, k, v, **form))
 
     def reference(q, k, v):
-        return mha_reference(f32(q), f32(k), f32(v), causal=True)
+        mask = None
+        if form.get("block_diffusion"):
+            mask = att.block_diffusion_mask(s, form["block_diffusion"])
+        return mha_reference(
+            f32(q), f32(k), f32(v), mask=mask, causal=form.get("causal", False),
+            window=form.get("window", 0))
 
     def out_and_grads(attn):
         return jax.jit(jax.value_and_grad(

@@ -6,8 +6,10 @@ calls, ms), at the shapes the benchmark's cells run, with the backward that
     chiprun -- python3 tools/flash_kernels_alone.py [shape ...] [--sub QxK ...]
 
 ``--sub 256x128`` also times the key-major kernels at those sub-tiles (query x
-key) instead of the ones ``pick_subtiles`` gives. Writes one JSON line a
-(shape, variant) to stdout and to ``chiprun_out/flash_kernels_alone.jsonl``.
+key) instead of the ones ``pick_subtiles`` gives. A line also says how the
+forward and the backward walk the grid (``flash_tiling``'s ``walk``, ``bodies``
+and ``steps``). Writes one JSON line a (shape, variant) to stdout and to
+``chiprun_out/flash_kernels_alone.jsonl``.
 docs/TESTING.md holds the tables this produced."""
 
 import argparse
@@ -28,8 +30,9 @@ sys.path.insert(0, ROOT)
 att = importlib.import_module("deepspeed_tpu.ops.attention")
 from benchmark.trace import PS, Trace  # noqa: E402
 
-# name: (entry, batch, heads, seq, head width, causal, key mask, bias); a
-# head width (q and k's, v's) where they differ (a latent mixer's)
+# name: (entry, batch, heads, seq, head width, causal, key mask, bias[, the
+# mask form's keywords]); a head width (q and k's, v's) where they differ (a
+# latent mixer's); seq is the row the kernels see (SDAR's [noisy ; clean] 2 L)
 SHAPES = {
     "gpt2": ("packed", 8, 20, 1024, 64, True, False, True),
     "bert512": ("packed", 8, 16, 512, 64, False, True, True),
@@ -38,12 +41,17 @@ SHAPES = {
     "qwen3next": ("split", 2, 16, 16384, 256, True, False, False),
     "joyai": ("split", 2, 32, 8192, (192, 128), True, False, False),
     "joyai_v192": ("split", 2, 32, 8192, 192, True, False, False),
+    "sdar": ("split", 2, 32, 16384, 128, False, False, False,
+             {"block_diffusion": 4}),
+    "laguna_band": ("split", 2, 72, 8192, 128, True, False, False,
+                    {"window": 512}),
+    "laguna_full": ("split", 2, 48, 8192, 128, True, False, False),
 }
 CALLS = 5
 
 
 def build(shape):
-    entry, b, h, s, d, causal, masked, biased = SHAPES[shape]
+    entry, b, h, s, d, causal, masked, biased, *form = SHAPES[shape]
     keys = jax.random.split(jax.random.PRNGKey(33), 4)
     kv_mask = None
     if masked:
@@ -65,7 +73,8 @@ def build(shape):
                   for kk, width in zip(keys, (dqk, dqk, dv, dv)))
 
     def loss(q, k, v):
-        out = att.flash_attention(q, k, v, kv_mask=kv_mask, causal=causal)
+        out = att.flash_attention(
+            q, k, v, kv_mask=kv_mask, causal=causal, **(form[0] if form else {}))
         return jnp.sum(out.astype(jnp.float32) * w.astype(jnp.float32))
 
     return loss, (q, k, v), (0, 1, 2)
@@ -108,6 +117,24 @@ def measure(shape, variant, sub):
     return ms, grads
 
 
+def walks(shape, sub):
+    """How the forward and the key-major backward walk the shape's grid
+    (``flash_tiling``: ``walk``, ``bodies``, ``steps``), for the line."""
+    _, _, _, s, d, causal, _, _, *form = SHAPES[shape]
+    form = form[0] if form else {}
+    block = att._pick_blocks(
+        s, s, att.DEFAULT_BLOCK_Q, att.DEFAULT_BLOCK_K,
+        form.get("block_diffusion", 0))
+    t = att.flash_tiling(
+        s, s, *block, causal, lanes=max(d) if isinstance(d, tuple) else d, **form)
+    if sub:
+        t["backward"] = att.flash_tiling(
+            s, s, *block, causal, key_major=True, sub_q=sub[0], sub_k=sub[1],
+            **form)
+    return {side: {k: w.get(k) for k in ("walk", "bodies", "steps")}
+            for side, w in (("forward", t), ("backward", t["backward"]))}
+
+
 def main():
     parser = argparse.ArgumentParser()
     parser.add_argument("shapes", nargs="*", default=list(SHAPES))
@@ -127,7 +154,8 @@ def main():
             for variant, sub in [("pair", None)][opts.no_pair:] + [
                     ("fused", s) for s in clamped]:
                 line = {"shape": shape, "dims": SHAPES[shape], "variant": variant,
-                        "sub_q_x_k": sub, "device": jax.devices()[0].device_kind}
+                        "sub_q_x_k": sub, "device": jax.devices()[0].device_kind,
+                        **walks(shape, None if variant == "pair" else sub)}
                 try:
                     ms, grads = measure(shape, variant, sub)
                 except Exception as e:  # a variant Mosaic refuses: say so, go on

@@ -48,21 +48,27 @@ def lowered_window(cell, config, devices):
     return text
 
 
+def mosaic_assembly(body):
+    """``(kernel name, assembly without debug locations)`` of one Mosaic
+    module's base64 bytecode (group 2 of ``BODY``)."""
+    ctx = mlir.make_ir_context()
+    tpu.register_dialect(ctx)
+    ctx.allow_unregistered_dialects = True
+    with ctx:
+        asm = ir.Module.parse(base64.b64decode(body)
+                              ).operation.get_asm(enable_debug_info=False)
+    return re.match(r"module @(\w+)", asm).group(1), asm
+
+
 def without_locations(text):
     """``text`` with each Mosaic module's bytecode replaced by the hash of
     its assembly without debug locations, and {kernel: [hash, ...]}."""
     kernels = {}
 
     def decoded(match):
-        ctx = mlir.make_ir_context()
-        tpu.register_dialect(ctx)
-        ctx.allow_unregistered_dialects = True
-        with ctx:
-            asm = ir.Module.parse(base64.b64decode(match.group(2))
-                                  ).operation.get_asm(enable_debug_info=False)
+        name, asm = mosaic_assembly(match.group(2))
         digest = hashlib.sha256(asm.encode()).hexdigest()
-        kernels.setdefault(
-            re.match(r"module @(\w+)", asm).group(1), []).append(digest[:16])
+        kernels.setdefault(name, []).append(digest[:16])
         return match.group(1) + digest + match.group(3)
 
     return BODY.sub(decoded, text), kernels
