@@ -23,6 +23,11 @@ Entry points:
   - ``flash_attention_packed``: the same kernels over the fused qkv
     projection's own result [B, S, 3*H*D]; the context comes back
     [B, S, H*D] (``_Operands`` below).
+  - ``flash_attention_latent``: the same kernels over a latent mixer's
+    operands where its projections wrote them (q in its unrotated and
+    rotated parts, the k | v up-projection's result, the one shared rotated
+    key part), the score as the sum of two products; ``latent_layout``
+    chooses it, ``latent_refusal`` says why not.
   - ``attention`` / ``attention_packed``: dispatchers for the two operand
     forms. Padding-style additive masks (broadcast over the query dim) are
     converted to validity vectors and sent to flash; learned/general
@@ -778,6 +783,19 @@ def _block_diffusion_allowed(shape, q_first, k_first, half, block):
     return (keys < start + above) & (keys >= start - below)
 
 
+def _parts(x):
+    """A q or k operand (a ref, a value, its lanes) as its parts: the LATENT
+    layout's two, (unrotated, rotated), or the one of every other layout."""
+    return x if isinstance(x, tuple) else (x,)
+
+
+def _map(f, x, *more):
+    """``f`` over the parts of ``x`` (and of ``more`` beside them), in
+    ``x``'s form."""
+    out = tuple(f(*each) for each in zip(_parts(x), *map(_parts, more)))
+    return out if isinstance(x, tuple) else out[0]
+
+
 def _scores_t(
     k, q, valid, q_first, k_first, *, sm_scale, fold_scale, diagonal,
     diag_offset, block_diffusion=0, half=0, window=0,
@@ -786,10 +804,16 @@ def _scores_t(
     causal, block-diffusion and key-validity masking. ``diagonal``: the
     causal diagonal (the block-diffusion mask's staircase or block diagonal)
     crosses this sub-tile; the ones wholly under it skip the
-    iota/compare/select. ``valid``: the keys' validity column or None."""
-    s_t = jax.lax.dot_general(
-        k, q, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
+    iota/compare/select. ``valid``: the keys' validity column or None. ``k``
+    and ``q`` in the LATENT layout are their two parts (``_parts``): the score
+    is the sum of the parts' products, in float32."""
+    s_t = None
+    for k_part, q_part in zip(_parts(k), _parts(q)):
+        product = jax.lax.dot_general(
+            k_part, q_part, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32
+        )
+        s_t = product if s_t is None else s_t + product
     if not fold_scale:
         s_t = s_t * sm_scale
     if diagonal and block_diffusion:
@@ -867,6 +891,19 @@ def _dot_t(a_t, b, dtype):
     )
 
 
+# Where one head of a program's block lies in everything the kernels index by
+# lane or, once transposed into scratch, by row. SPLIT and PACKED have ONE
+# answer to all of it (the head's lanes of the block); in the LATENT layout
+# (``_Operands`` below) q and k are two parts each, (unrotated, rotated), in
+# refs of their own, k's unrotated part and v share a ref, and each scratch
+# holds the parts behind one another. ``q``: in q's refs and in dq's; ``k``:
+# in k's refs; ``v``: in v's ref and in dv's; ``o``: in the arrays of v's
+# width (the context, dO) and the rows of ``acc_scr`` and ``vt_scr``; ``kt``
+# and ``dq``: the rows of k's parts in ``kt_scr`` and of dq's in ``dq_scr``;
+# ``dk``: the parts' lanes in the head's ``dk_scr``; ``dk_out``: in dk's refs.
+_HeadLanes = collections.namedtuple("_HeadLanes", "q k v o kt dq dk dk_out")
+
+
 class _Tiles:
     """What the kernels share: the grid position (a Python 0 on an
     axis of one block, so that every bound derived from it is static),
@@ -876,9 +913,14 @@ class _Tiles:
     def __init__(
         self, q_axis, scalars_ref, *, sm_scale, causal, block_q, block_k,
         sub_q, sub_k, nq, nk, diag_offset, dropout_rate, use_mask, head_dim,
-        heads_a_block, use_bias, block_diffusion=0, window=0,
+        heads_a_block, use_bias, block_diffusion=0, window=0, rope_dim=0,
+        v_dim=0,
     ):
         self.group = pl.program_id(0)
+        # LATENT: the last ``rope_dim`` of a head's ``head_dim`` q and k
+        # lanes are its rotated part, and v has ``v_dim``
+        self.rope_dim, self.v_dim = rope_dim, v_dim
+        self.nope_rows = heads_a_block * (head_dim - rope_dim)
         self.block_diffusion, self.half = block_diffusion, nq * block_q // 2
         self.window, self.key_major = window, q_axis == 2
         self.use_bias = use_bias
@@ -1002,23 +1044,76 @@ class _Tiles:
 
     def heads(self):
         """``(hh, lanes)`` of each head a program serves: its place in the
-        block and the static lanes of the block that hold it (all of them
-        where a block is one head). The same slice picks the head's rows
-        out of a block TRANSPOSED into scratch."""
+        block and the static lanes of the block that hold it
+        (``_HeadLanes``; all of them where a block is one head). The same
+        slice picks the head's rows out of a block TRANSPOSED into
+        scratch."""
+        if self.rope_dim:
+            return [(hh, self._latent_lanes(hh))
+                    for hh in range(self.heads_a_block)]
+        def everywhere(lanes):
+            # (a head's ``dk_scr`` is its own: all of it)
+            return _HeadLanes(
+                **dict.fromkeys(_HeadLanes._fields, lanes)
+            )._replace(dk=slice(None))
+
         if self.heads_a_block == 1:
-            return [(0, slice(None))]
+            return [(0, everywhere(slice(None)))]
         d = self.head_dim
         return [
-            (hh, slice(hh * d, (hh + 1) * d))
+            (hh, everywhere(slice(hh * d, (hh + 1) * d)))
             for hh in range(self.heads_a_block)
         ]
+
+    def _latent_lanes(self, hh):
+        rope, v = self.rope_dim, self.v_dim
+        nope, all_nope = self.head_dim - rope, self.nope_rows
+
+        def at(first, width):
+            return slice(first, first + width)
+
+        q = (at(hh * nope, nope), at(hh * rope, rope))
+        k_nope = at(hh * (nope + v), nope)
+        return _HeadLanes(
+            q=q, k=(k_nope, slice(None)), v=at(hh * (nope + v) + nope, v),
+            o=at(hh * v, v), kt=(q[0], at(all_nope, rope)),
+            dq=(q[0], at(all_nope + hh * rope, rope)),
+            dk=(at(0, nope), at(nope, rope)), dk_out=(k_nope, q[1]))
+
+    @property
+    def dq_parts(self):
+        """The rows of ``dq_scr`` that go out to each of dq's refs."""
+        if not self.rope_dim:
+            return slice(None)
+        return (slice(0, self.nope_rows),
+                slice(self.nope_rows, self.heads_a_block * self.head_dim))
 
     def load(self, ref, bias_ref, rows, lanes=slice(None)):
         """``rows`` x ``lanes`` of a q, k or v block, with the projection's
         bias added where the operand arrives without it: the same bf16 sum
-        XLA would have written out."""
+        XLA would have written out. The parts of a LATENT q or k (refs and
+        lanes in pairs) as a tuple."""
+        if isinstance(ref, tuple):
+            return tuple(r[0, rows, at] for r, at in zip(ref, lanes))
         x = ref[0, rows, lanes]
         return x + bias_ref[:, lanes] if self.use_bias else x
+
+    def transpose(self, scr, c, ref, bias_ref, v=False):
+        """Key sub-tile ``c`` of a K block (``v``: of a V block) transposed
+        into ``scr[c]``, every head of it: in one transpose where the block
+        is the heads' lanes side by side, else (LATENT) a head's lanes at a
+        time, and the rotated key part, which every head shares, once."""
+        rows = _rows(c, self.sub_k)
+        if not self.rope_dim:
+            scr[c] = self.load(ref, bias_ref, rows).T.astype(scr.dtype)
+            return
+        for hh, lanes in self.heads():
+            if v:
+                scr[c, lanes.o, :] = ref[0, rows, lanes.v].T.astype(scr.dtype)
+                continue
+            parts = list(zip(ref, lanes.k, lanes.kt))
+            for part, at, to in parts if hh == 0 else parts[:1]:
+                scr[c, to, :] = part[0, rows, at].T.astype(scr.dtype)
 
     def q_first(self, r):
         return self.iq * self.block_q + r * self.sub_q
@@ -1084,23 +1179,21 @@ def _fwd_kernel(
         # once, every head of it together (a head's v^T is the rows
         # ``lanes`` of the result)
         for c in range(t.nsk):
-            vt_scr[c] = t.load(
-                v_ref, bv_ref, _rows(c, t.sub_k)
-            ).T.astype(vt_scr.dtype)
+            t.transpose(vt_scr, c, v_ref, bv_ref, v=True)
 
         for hh, lanes in t.heads():
             for r in range(t.nsq):
                 # matmul operands stay in their storage dtype (MXU-native
                 # bf16 pairs, f32 accumulation); softmax bookkeeping is f32
-                q = t.load(q_ref, bq_ref, _rows(r, t.sub_q), lanes)
+                q = t.load(q_ref, bq_ref, _rows(r, t.sub_q), lanes.q)
                 if t.fold_scale:
-                    q = q * t.sm_scale
+                    q = _map(lambda part: part * t.sm_scale, q)
                 q_first = t.q_first(r)
 
                 def k_step(c, carry, diagonal):
                     m_prev, l_prev, acc = carry
                     s_t = t.scores(
-                        t.load(k_ref, bk_ref, _rows(c, t.sub_k), lanes), q,
+                        t.load(k_ref, bk_ref, _rows(c, t.sub_k), lanes.k), q,
                         t.valid(kvm_ref, c), q_first, t.k_first(c),
                         diagonal=diagonal,
                     )
@@ -1115,11 +1208,12 @@ def _fwd_kernel(
                             seed_ref, hh, q_first, t.k_first(c), p_t.shape
                         )
                         p_t = jnp.where(keep, p_t / (1.0 - t.dropout_rate), 0.0)
-                    pv = _dot_t(vt_scr[c, lanes, :], p_t, v_ref.dtype)
+                    pv = _dot_t(vt_scr[c, lanes.o, :], p_t, v_ref.dtype)
                     return m_new, l_new, acc * alpha + pv
 
-                m_scr[hh, r], l_scr[hh, r], acc_scr[r, lanes, :] = t.over_keys(
-                    spans, r, k_step, (m_scr[hh, r], l_scr[hh, r], acc_scr[r, lanes, :])
+                m_scr[hh, r], l_scr[hh, r], acc_scr[r, lanes.o, :] = t.over_keys(
+                    spans, r, k_step,
+                    (m_scr[hh, r], l_scr[hh, r], acc_scr[r, lanes.o, :])
                 )
 
     @_when(t.last_step)
@@ -1128,7 +1222,7 @@ def _fwd_kernel(
             for hh, lanes in t.heads():
                 l = l_scr[hh, r]
                 l = jnp.where(l == 0.0, 1.0, l)  # fully-masked rows -> zeros
-                acc_scr[r, lanes, :] = acc_scr[r, lanes, :] / l
+                acc_scr[r, lanes.o, :] = acc_scr[r, lanes.o, :] / l
                 lse_ref[hh, r] = m_scr[hh, r] + jnp.log(l)
             # every head of the block in one transpose: whole-lane stores
             o_ref[0, _rows(r, t.sub_q), :] = acc_scr[r].T.astype(o_ref.dtype)
@@ -1149,11 +1243,12 @@ def _bwd_dq_kernel(
     def _body(spans):
         # dq_t += k^T ds_t: transpose the K block once, all its heads
         for c in range(t.nsk):
-            kt_scr[c] = t.load(
-                k_ref, bk_ref, _rows(c, t.sub_k)
-            ).T.astype(kt_scr.dtype)
+            t.transpose(kt_scr, c, k_ref, bk_ref)
 
-        for hh, lanes in t.heads():
+        for hh, head in t.heads():
+            # one answer to all of ``_HeadLanes``: the LATENT layout takes
+            # the fused backward only
+            lanes = head.q
             for r in range(t.nsq):
                 q = t.load(q_ref, bq_ref, _rows(r, t.sub_q), lanes)
                 if t.fold_scale:
@@ -1206,7 +1301,13 @@ def _bwd_dkv_kernel(
     every key block, and cast out in the last one's. Without ``fused`` it
     is the pair's dkv kernel and dq is ``_bwd_dq_kernel``'s."""
     t = _Tiles(2, seed_ref, **static)
-    if fused:
+    if fused and t.rope_dim:
+        # LATENT: dq in q's two layouts, dk's unrotated part and dv in their
+        # halves of ONE block (the k | v product's cotangent), dk's rotated
+        # part a head (the caller sums it over the heads)
+        *dq_ref, dkv_ref, dkr_ref, dq_scr, dk_scr, dv_scr, kt_scr = results
+        dq_ref, dk_ref, dv_ref = tuple(dq_ref), (dkv_ref, dkr_ref), dkv_ref
+    elif fused:
         dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr, kt_scr = results
     else:
         dk_ref, dv_ref, dk_scr, dv_scr = results
@@ -1227,18 +1328,18 @@ def _bwd_dkv_kernel(
         # the K block stays for every Q block of this row of the grid:
         # transposed once for all of them, all its heads together
         for c in range(t.nsk if fused else 0):
-            kt_scr[c] = t.load(
-                k_ref, bk_ref, _rows(c, t.sub_k)
-            ).T.astype(kt_scr.dtype)
+            t.transpose(kt_scr, c, k_ref, bk_ref)
 
     @t.each_body
     def _body(spans):
         for hh, lanes in t.heads():
             for c in range(t.nsk):
                 keys = _rows(c, t.sub_k)
-                k = t.load(k_ref, bk_ref, keys, lanes)
-                v = t.load(v_ref, bv_ref, keys, lanes)
-                k_scaled = k * t.sm_scale if t.fold_scale else k
+                k = t.load(k_ref, bk_ref, keys, lanes.k)
+                v = t.load(v_ref, bv_ref, keys, lanes.v)
+                k_scaled = k
+                if t.fold_scale:
+                    k_scaled = _map(lambda part: part * t.sm_scale, k)
                 valid = t.valid(kvm_ref, c)
                 k_first = t.k_first(c)
 
@@ -1247,8 +1348,8 @@ def _bwd_dkv_kernel(
                 # as rows
                 def q_step(r, carry, diagonal):
                     dk, dv = carry
-                    q = t.load(q_ref, bq_ref, _rows(r, t.sub_q), lanes)
-                    do = do_ref[0, _rows(r, t.sub_q), lanes]
+                    q = t.load(q_ref, bq_ref, _rows(r, t.sub_q), lanes.q)
+                    do = do_ref[0, _rows(r, t.sub_q), lanes.o]
                     q_first = t.q_first(r)
                     s_t = t.scores(
                         k_scaled, q, valid, q_first, k_first, diagonal=diagonal
@@ -1267,32 +1368,46 @@ def _bwd_dkv_kernel(
                         p_drop.astype(do.dtype), do,
                         preferred_element_type=jnp.float32,
                     )
-                    ds_t = (p_t * (dp_t - delta_ref[hh, r])).astype(q.dtype)
-                    dk = dk + jnp.dot(ds_t, q, preferred_element_type=jnp.float32)
+                    ds_t = (p_t * (dp_t - delta_ref[hh, r])).astype(do.dtype)
+                    dk = _map(
+                        lambda dk, q: dk + jnp.dot(
+                            ds_t, q, preferred_element_type=jnp.float32),
+                        dk, q)
                     if fused:
                         at = dq_rows(r)
-                        dq_scr[at, lanes, :] = dq_scr[at, lanes, :] + _dot_t(
-                            kt_scr[c, lanes, :], ds_t, k_ref.dtype
-                        )
+                        for rows, k_rows in zip(
+                                _parts(lanes.dq), _parts(lanes.kt)):
+                            dq_scr[at, rows, :] = dq_scr[at, rows, :] + _dot_t(
+                                kt_scr[c, k_rows, :], ds_t, do.dtype
+                            )
                     return dk, dv
 
-                dk_scr[hh, keys, :], dv_scr[hh, keys, :] = t.over_queries(
-                    spans, c, q_step, (dk_scr[hh, keys, :], dv_scr[hh, keys, :])
+                dk, dv = t.over_queries(
+                    spans, c, q_step,
+                    (_map(lambda at: dk_scr[hh, keys, at], lanes.dk),
+                     dv_scr[hh, keys, :])
                 )
+                for at, part in zip(_parts(lanes.dk), _parts(dk)):
+                    dk_scr[hh, keys, at] = part
+                dv_scr[hh, keys, :] = dv
 
     @_when(t.last_step)
     def _finalize():
         for hh, lanes in t.heads():
-            dk_ref[0, :, lanes] = (dk_scr[hh] * t.sm_scale).astype(dk_ref.dtype)
-            dv_ref[0, :, lanes] = dv_scr[hh].astype(dv_ref.dtype)
+            for ref, at, part in zip(
+                    _parts(dk_ref), _parts(lanes.dk_out), _parts(lanes.dk)):
+                ref[0, :, at] = (
+                    dk_scr[hh, :, part] * t.sm_scale).astype(ref.dtype)
+            dv_ref[0, :, lanes.v] = dv_scr[hh].astype(dv_ref.dtype)
 
     @_when(fused and t.dq_edge(1))
     def _finalize_dq():
         for r in range(t.nsq):
             at = dq_rows(r)
-            dq_ref[0, _rows(at, t.sub_q), :] = (
-                (dq_scr[at] * t.sm_scale).T.astype(dq_ref.dtype)
-            )
+            for ref, rows in zip(_parts(dq_ref), _parts(t.dq_parts)):
+                ref[0, _rows(at, t.sub_q), :] = (
+                    (dq_scr[at, rows, :] * t.sm_scale).T.astype(ref.dtype)
+                )
 
 
 def _reshape_bh(x):
@@ -1300,7 +1415,7 @@ def _reshape_bh(x):
     return x.reshape(b * h, s, d)
 
 
-# Two layouts of the kernels' operands, one set of kernel bodies.
+# Three layouts of the kernels' operands, one set of kernel bodies.
 #
 # SPLIT: q, k, v, dO and every result are ``[B*H, S, D]`` arrays, one head a
 # program (the public ``flash_attention``: sequence parallelism, the
@@ -1314,9 +1429,31 @@ def _reshape_bh(x):
 # A BlockSpec block is 128 lanes wide: one head of 128, or TWO heads of 64,
 # each a static 64-lane half of the block, walked one after the other by the
 # same kernel body. The lane-block index picks q, k or v and the pair.
-# lse and delta are ``[B*H, Sq/sub_q, 1, sub_q]`` rows in both.
 #
-# In both, the fused backward's dq block is a group's WHOLE query length
+# LATENT (PR 47): a latent mixer's operands where ITS projections wrote them.
+# A head's q and k are ``nope`` unrotated lanes then ``rope`` rotated ones,
+# and the rotated key part is ONE vector a position that every head shares.
+# q arrives as two arrays, ``q_nope`` ``[B, S, H*nope]`` and the rotated
+# ``q_r`` ``[B, S, H*rope]``; k's unrotated part and v as the k | v
+# up-projection's own result ``kv`` ``[B, S, H*(nope + v)]`` (a head's
+# ``k_nope`` then its ``v``, both whole 128-lane blocks of it); the rotated
+# key part as ``k_r`` ``[B, S, rope]``, read once a key block. A score
+# sub-tile is the SUM of two products, ``k_nope q_nope^T + k_r q_r^T``, in
+# float32 before the softmax: the contraction over a head's ``nope + rope``
+# lanes, in its two parts. A program serves the TWO heads that share a
+# 128-lane block of ``q_r`` (rope 64), each a static half of it, walked one
+# after the other as PACKED does at width 64. The context comes back ``[B,
+# S, H*v]``, dq in q's two layouts, dk's unrotated part and dv in their
+# halves of ONE ``[B, S, H*(nope + v)]`` array (the up-projection's
+# cotangent), and dk's rotated part a head, ``[B, S, H*rope]``, for the
+# caller to sum over the heads (the grid runs a batch row's head pairs
+# outermost, so no program sees them all). No head-major array, broadcast,
+# concatenation or transpose exists on either side of the kernels. The fused
+# backward only (``latent_refusal``).
+#
+# lse and delta are ``[B*H, Sq/sub_q, 1, sub_q]`` rows in all three.
+#
+# In all, the fused backward's dq block is a group's WHOLE query length
 # (``spec(None, ..)``): its grid runs key blocks outermost and dq sums over
 # keys, so the block and its float32 accumulator stay in VMEM for all the
 # steps of a group and go back to HBM once (no partial dq a key block
@@ -1333,6 +1470,9 @@ class _Operands:
     # lanes of a head of v, of the context and of dO and dv: split operands
     # say them (they may differ from q's and k's ``head_dim``); 0: the same
     v_dim: int = 0
+    # LATENT: the last ``rope_dim`` of a head's ``head_dim`` q and k lanes
+    # are its rotated part; 0: another layout
+    rope_dim: int = 0
 
     @property
     def v_width(self):
@@ -1340,7 +1480,16 @@ class _Operands:
 
     @property
     def heads_a_block(self):
+        if self.rope_dim:
+            return LANES // self.rope_dim
         return LANES // self.head_dim if self.packed else 1
+
+    @property
+    def _latent_widths(self):
+        """Lanes of a program's block of q_nope, q_r, kv and k_r."""
+        hb, nope = self.heads_a_block, self.head_dim - self.rope_dim
+        return (hb * nope, hb * self.rope_dim, hb * (nope + self.v_width),
+                self.rope_dim)
 
     @property
     def groups_a_batch(self):
@@ -1371,9 +1520,13 @@ class _Operands:
         (``part`` 0). ``rows`` None: ``block`` is ALL the group's rows,
         wherever the grid stands in them, so it stays in VMEM for every
         step of the group and is written back once. ``v``: an array of v's
-        width (the context, dO, dv), as ``part`` 2 is."""
+        width (the context, dO, dv), as ``part`` 2 is. LATENT: those arrays
+        only (``in_specs``, ``dq_results`` and ``dkv_results`` have q's and
+        k's)."""
         at = self._at(rows, key_major, needed) if rows else (lambda g: 0)
 
+        if self.rope_dim:
+            return self._lanes_spec(block, self.v_lanes, at)
         if not self.packed:
             width = self.v_width if v or part == 2 else self.head_dim
             return pl.BlockSpec((1, block, width), lambda *g: (g[0], at(g), 0))
@@ -1381,6 +1534,68 @@ class _Operands:
         return pl.BlockSpec(
             (1, block, LANES),
             lambda *g: (g[0] // per, at(g), part * per + g[0] % per),
+        )
+
+    def _lanes_spec(self, block, width, at, shared=False):
+        """BlockSpec of ``block`` rows of a group's ``width`` lanes of a
+        ``[B, S, groups a batch row * width]`` array (``shared``: of a ``[B,
+        S, width]`` array that every group reads)."""
+        per = self.groups_a_batch
+        return pl.BlockSpec(
+            (1, block, width),
+            lambda *g: (g[0] // per, at(g), 0 if shared else g[0] % per),
+        )
+
+    def in_specs(self, block_q, block_k, key_major=False, qs=None, ks=None,
+                 use_bias=False):
+        """BlockSpecs of the operands the kernels read q, k and v out of, in
+        the kernels' order: q, k and v with the three biases, or (LATENT)
+        q_nope, q_r, kv and k_r. ``qs`` / ``ks``: ``spec``'s ``needed`` of
+        the queries' and of the keys' operands."""
+        if not self.rope_dim:
+            return [
+                self.spec("q", block_q, key_major, part=0, needed=qs),
+                self.spec("k", block_k, key_major, part=1, needed=ks),
+                self.spec("k", block_k, key_major, part=2, needed=ks),
+                *(self.bias_spec(use_bias, part) for part in range(3)),
+            ]
+        q_at = self._at("q", key_major, qs)
+        k_at = self._at("k", key_major, ks)
+        nope, rope, kv, shared = self._latent_widths
+        return [
+            self._lanes_spec(block_q, nope, q_at),
+            self._lanes_spec(block_q, rope, q_at),
+            self._lanes_spec(block_k, kv, k_at),
+            self._lanes_spec(block_k, shared, k_at, shared=True),
+        ]
+
+    def dq_results(self, sq, dtype):
+        """``(specs, shapes)`` of the fused backward's dq: a group's whole
+        query length (``spec``'s ``rows`` None), in q's layout."""
+        if not self.rope_dim:
+            return [self.spec(None, sq)], [self.result(sq, dtype)]
+        widths = self._latent_widths[:2]
+        return (
+            [self._lanes_spec(sq, w, lambda g: 0) for w in widths],
+            [jax.ShapeDtypeStruct((self.batch, sq, self.groups_a_batch * w),
+                                  dtype) for w in widths],
+        )
+
+    def dkv_results(self, block_k, sk, dtype):
+        """``(specs, shapes)`` of dk and dv, a key block each: two arrays
+        in k's and v's layouts, or (LATENT) dk's unrotated part and dv in
+        kv's layout, then dk's rotated part a head."""
+        if not self.rope_dim:
+            return [
+                self.spec("k", block_k, key_major=True),
+                self.spec("k", block_k, key_major=True, v=True),
+            ], [self.result(sk, dtype), self.result(sk, dtype, v=True)]
+        _, rope, kv, _ = self._latent_widths
+        widths, at = (kv, rope), self._at("k", True)
+        return (
+            [self._lanes_spec(block_k, w, at) for w in widths],
+            [jax.ShapeDtypeStruct((self.batch, sk, self.groups_a_batch * w),
+                                  dtype) for w in widths],
         )
 
     def bias_spec(self, use_bias, part):
@@ -1414,6 +1629,9 @@ class _Operands:
 
     def result(self, seq, dtype, v=False):
         """Shape of the context or of one gradient (``v``: of v's width)."""
+        if self.rope_dim:
+            return jax.ShapeDtypeStruct(
+                (self.batch, seq, self.heads * self.v_width), dtype)
         if self.packed:
             return jax.ShapeDtypeStruct(
                 (self.batch, seq, self.heads * self.head_dim), dtype
@@ -1430,6 +1648,14 @@ class _Operands:
     @property
     def v_lanes(self):
         return self.heads_a_block * self.v_width
+
+    @property
+    def kt_lanes(self):
+        """Rows of a K block transposed into scratch: every head's lanes,
+        or (LATENT) their unrotated parts and the ONE rotated part."""
+        if self.rope_dim:
+            return self._latent_widths[0] + self.rope_dim
+        return self.block_lanes
 
 
 def _kvm_column(kv_mask):
@@ -1450,6 +1676,7 @@ def _static(
         dropout_rate=dropout_rate, use_mask=use_mask, use_bias=use_bias,
         head_dim=ops.head_dim, heads_a_block=ops.heads_a_block,
         block_diffusion=block_diffusion, window=window,
+        rope_dim=ops.rope_dim, v_dim=ops.v_width if ops.rope_dim else 0,
     )
 
 
@@ -1482,9 +1709,10 @@ def _forward_call(
 ):
     """``flash_fwd`` over all B x H heads. ``q``/``k``/``v``: three
     ``[B*H, S, D]`` arrays, or the packed projection three times, then
-    with the ``bias`` [3*H*D] it still lacks (or None). Returns the context
-    in the operands' layout and lse ``[B*H, Sq]`` float32."""
-    d, dtype = ops.head_dim, q.dtype
+    with the ``bias`` [3*H*D] it still lacks (or None), or (LATENT) the
+    pairs ``(q_nope, q_r)`` and ``(kv, k_r)`` and no ``v``. Returns the
+    context in the operands' layout and lse ``[B*H, Sq]`` float32."""
+    d, dtype = ops.head_dim, _parts(q)[0].dtype
     use_bias = bias is not None
     common = _static(
         ops, sq, sk, block_q, block_k, causal, sm_scale, dropout_rate,
@@ -1501,14 +1729,12 @@ def _forward_call(
     keys, steps = _needed_blocks(False, *_grid_form(common))
     plan = _walk_plan(False, sub_q, sub_k, *_grid_form(common))
     out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, sub_q=sub_q, sub_k=sub_k, **common),
+        functools.partial(
+            _kernel_of(ops, _fwd_kernel), sub_q=sub_q, sub_k=sub_k, **common),
         grid=(ops.groups, nq, steps),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            ops.spec("q", block_q, part=0),
-            ops.spec("k", block_k, part=1, needed=keys),
-            ops.spec("k", block_k, part=2, needed=keys),
-            *(ops.bias_spec(use_bias, part) for part in range(3)),
+            *ops.in_specs(block_q, block_k, ks=keys, use_bias=use_bias),
             ops.kvm_spec(kv_mask is not None, block_k, needed=keys),
         ],
         out_specs=[ops.spec("q", block_q, v=True), ops.row_spec(block_q, sub_q)],
@@ -1528,9 +1754,31 @@ def _forward_call(
         ],
         interpret=interpret,
         name="flash_fwd",
-    )(_scalars(seed, plan), q, k, v, *[_bias_row(bias, dtype)] * 3,
+    )(_scalars(seed, plan), *_operand_arrays(ops, q, k, v, bias, dtype),
       _kvm_column(kv_mask))
     return out, lse.reshape(ops.batch * ops.heads, sq)
+
+
+def _operand_arrays(ops, q, k, v, bias, dtype):
+    """The arrays behind ``_Operands.in_specs``."""
+    if ops.rope_dim:
+        return (*q, *k)
+    return (q, k, v, *[_bias_row(bias, dtype)] * 3)
+
+
+def _kernel_of(ops, kernel):
+    """``kernel`` as the operands' layout calls it: in the LATENT layout q
+    and k reach it as pairs of refs (``q_nope``, ``q_r``) and (``kv``,
+    ``k_r``), v as the ``kv`` ref again, and there is no bias."""
+    if not ops.rope_dim:
+        return kernel
+
+    def over_latent(seed_ref, qn_ref, qr_ref, kv_ref, kr_ref, *rest, **static):
+        return kernel(
+            seed_ref, (qn_ref, qr_ref), (kv_ref, kr_ref), kv_ref, None, None,
+            None, *rest, **static)
+
+    return over_latent
 
 
 def _backward_calls(
@@ -1541,8 +1789,9 @@ def _backward_calls(
     ``backward_plan`` chooses: the fused kernel, which runs as
     ``flash_bwd_dkv`` (its walk, now also putting out dq), or the pair
     ``flash_bwd_dq`` and ``flash_bwd_dkv``. ``lse``/``delta``:
-    ``[B*H, Sq]`` float32."""
-    dtype = q.dtype
+    ``[B*H, Sq]`` float32. LATENT (the fused kernel only): ``(dq_nope,
+    dq_r, dkv, dk_r a head)``."""
+    dtype = _parts(q)[0].dtype
     use_mask, use_bias = kv_mask is not None, bias is not None
     common = _static(
         ops, sq, sk, block_q, block_k, causal, sm_scale, dropout_rate,
@@ -1568,14 +1817,12 @@ def _backward_calls(
         walk = _walk_plan(key_major, sub_q, sub_k, *_grid_form(common))
         qs, ks = (inner, None) if key_major else (None, inner)
         return pl.pallas_call(
-            functools.partial(kernel, sub_q=sub_q, sub_k=sub_k, **common),
+            functools.partial(
+                _kernel_of(ops, kernel), sub_q=sub_q, sub_k=sub_k, **common),
             grid=(ops.groups, nk if key_major else nq, steps),
             in_specs=[
                 pl.BlockSpec(memory_space=pltpu.SMEM),
-                ops.spec("q", block_q, key_major, part=0, needed=qs),
-                ops.spec("k", block_k, key_major, part=1, needed=ks),
-                ops.spec("k", block_k, key_major, part=2, needed=ks),
-                *(ops.bias_spec(use_bias, part) for part in range(3)),
+                *ops.in_specs(block_q, block_k, key_major, qs, ks, use_bias),
                 ops.kvm_spec(use_mask, block_k, key_major, needed=ks),
                 ops.spec("q", block_q, key_major, needed=qs, v=True),
                 ops.row_spec(block_q, sub_q, key_major, needed=qs),
@@ -1583,30 +1830,27 @@ def _backward_calls(
             ],
             out_specs=out_specs, out_shape=out_shape, scratch_shapes=scratch,
             interpret=interpret, name=name, **params,
-        )(_scalars(seed, walk), q, k, v, *[_bias_row(bias, dtype)] * 3,
+        )(_scalars(seed, walk), *_operand_arrays(ops, q, k, v, bias, dtype),
           _kvm_column(kv_mask), do, lse.reshape(rows), delta.reshape(rows))
 
-    dkv_specs = [
-        ops.spec("k", block_k, key_major=True),
-        ops.spec("k", block_k, key_major=True, v=True),
-    ]
-    dkv_shapes = [ops.result(sk, dtype), ops.result(sk, dtype, v=True)]
+    dkv_specs, dkv_shapes = ops.dkv_results(block_k, sk, dtype)
     dkv_scratch = [
         pltpu.VMEM((hb, block_k, ops.head_dim), jnp.float32),
         pltpu.VMEM((hb, block_k, ops.v_width), jnp.float32),
     ]
     sub_q, sub_k = sub = plan["sub_q"], plan["sub_k"]
     if plan["backward"] == "fused":
+        dq_specs, dq_shapes = ops.dq_results(sq, dtype)
         return call(
             functools.partial(_bwd_dkv_kernel, fused=True), "flash_bwd_dkv",
             True, sub,
-            [ops.spec(None, sq), *dkv_specs],
-            [ops.result(sq, dtype), *dkv_shapes],
+            [*dq_specs, *dkv_specs],
+            [*dq_shapes, *dkv_shapes],
             [
                 pltpu.VMEM((sq // sub_q, lanes, sub_q), jnp.float32),
                 *dkv_scratch,
                 _transposed_scratch(
-                    block_k // sub_k, lanes, sub_k, dtype, interpret
+                    block_k // sub_k, ops.kt_lanes, sub_k, dtype, interpret
                 ),
             ],
             compiler_params=pltpu.CompilerParams(
@@ -1744,20 +1988,8 @@ def _flash_packed_bwd(
 ):
     qkv, bias, kv_mask, seed, out, lse = residuals
     ops = _packed_operands(qkv, heads)
-    b, s, d = ops.batch, qkv.shape[1], ops.head_dim
-    # delta_i = rowsum(dO * O) over each head's D lanes, as a product with
-    # a 0/1 matrix: one pass over dO and O as they lie, [B, H, S] out. (A
-    # reshape to [.., H, D] would lay both out anew for a 64-wide minor
-    # dimension.) A product of two bf16 numbers is exact in float32 and
-    # splits into two bf16 terms, so ``HIGHEST`` sums exactly what the
-    # split path's float32 reduction sums.
-    own = jnp.arange(heads)[:, None] == jnp.arange(heads * d)[None, :] // d
-    delta = jnp.einsum(
-        "hk,bsk->bhs", own.astype(jnp.float32),
-        g.astype(jnp.float32) * out.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST,
-        preferred_element_type=jnp.float32,
-    ).reshape(b * heads, s)
+    s = qkv.shape[1]
+    delta = _delta_by_head(g, out, heads)
     dq, dk, dv = _backward_calls(
         ops, qkv, qkv, qkv, bias, kv_mask, seed, g, lse, delta, s, s, causal,
         sm_scale, dropout_rate, block_q, block_k,
@@ -1777,6 +2009,75 @@ def _flash_packed_bwd(
 
 
 _flash_packed.defvjp(_flash_packed_fwd, _flash_packed_bwd)
+
+
+def _delta_by_head(g, out, heads):
+    """delta_i = rowsum(dO * O) over each head's D lanes of ``g`` and ``out``
+    [B, S, H*D], [B*H, S] float32, as a product with a 0/1 matrix: one pass
+    over dO and O as they lie, [B, H, S] out. (A reshape to [.., H, D] would
+    lay both out anew for a 64-wide minor dimension.) A product of two bf16
+    numbers is exact in float32 and splits into two bf16 terms, so
+    ``HIGHEST`` sums exactly what the split path's float32 reduction sums."""
+    b, s, width = g.shape
+    d = width // heads
+    own = jnp.arange(heads)[:, None] == jnp.arange(heads * d)[None, :] // d
+    return jnp.einsum(
+        "hk,bsk->bhs", own.astype(jnp.float32),
+        g.astype(jnp.float32) * out.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    ).reshape(b * heads, s)
+
+
+# ---- latent operands: q_nope, q_r, kv [B, S, H*.] and the shared k_r ------
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _flash_latent(q_nope, q_r, kv, k_r, heads, sm_scale, block_q, block_k):
+    return _flash_latent_fwd(
+        q_nope, q_r, kv, k_r, heads, sm_scale, block_q, block_k)[0]
+
+
+def _latent_operands(q_nope, kv, k_r, heads):
+    b, _, width = q_nope.shape
+    nope, rope = width // heads, k_r.shape[-1]
+    return _Operands(
+        b, heads, nope + rope, packed=False,
+        v_dim=kv.shape[-1] // heads - nope, rope_dim=rope)
+
+
+def _flash_latent_fwd(q_nope, q_r, kv, k_r, heads, sm_scale, block_q, block_k):
+    s, seed = q_nope.shape[1], jnp.asarray(0, jnp.int32)
+    out, lse = _forward_call(
+        _latent_operands(q_nope, kv, k_r, heads), (q_nope, q_r), (kv, k_r),
+        None, None, None, seed, s, s, True, sm_scale, 0.0, block_q, block_k,
+    )
+    out, lse = _name_residuals(out, lse)
+    return out, (q_nope, q_r, kv, k_r, out, lse)
+
+
+def _flash_latent_bwd(heads, sm_scale, block_q, block_k, residuals, g):
+    q_nope, q_r, kv, k_r, out, lse = residuals
+    b, s, rope = k_r.shape
+    dq_nope, dq_r, dkv, dk_r = _backward_calls(
+        _latent_operands(q_nope, kv, k_r, heads), (q_nope, q_r), (kv, k_r),
+        None, None, None, jnp.asarray(0, jnp.int32), g, lse,
+        _delta_by_head(g, out, heads), s, s, True, sm_scale, 0.0, block_q,
+        block_k,
+    )
+    # the rotated key part is every head's: its gradient is the heads' sum
+    # (a program holds one pair of heads and the grid runs the pairs
+    # outermost: no step sees all of a key block's). As a product with a 0/1
+    # matrix, summed in float32: one pass over [B, S, H*rope] as it lies (a
+    # reshape to [.., H, rope] would lay it out anew for a 64-wide minor
+    # dimension, as in ``_delta_by_head``)
+    own = jnp.arange(heads * rope)[:, None] % rope == jnp.arange(rope)[None, :]
+    dk_r = jnp.einsum(
+        "bsk,kj->bsj", dk_r, own.astype(dk_r.dtype),
+        preferred_element_type=jnp.float32,
+    ).astype(k_r.dtype)
+    return dq_nope, dq_r, dkv, dk_r
+
+
+_flash_latent.defvjp(_flash_latent_fwd, _flash_latent_bwd)
 
 
 def additive_mask_to_kv_valid(mask):
@@ -1943,6 +2244,85 @@ def packed_refusal(heads, head_dim, width=None):
             f"{heads} heads of {head_dim} do not pair into {LANES}-lane blocks"
         )
     return None
+
+
+def flash_attention_latent(
+    q_nope, q_r, kv, k_r, heads, sm_scale=None,
+    block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
+):
+    """Causal ``flash_attention`` over a latent mixer's operands where its
+    projections wrote them (the LATENT layout above ``_Operands``).
+
+    ``q_nope`` [B, S, H*nope] and the rotated ``q_r`` [B, S, H*rope]: a head's
+    unrotated and rotated query lanes; ``kv`` [B, S, H*(nope + v)]: a head's
+    unrotated key lanes then its value; ``k_r`` [B, S, rope]: the ONE rotated
+    key part every head shares. Returns the context [B, S, H*v]. The scores
+    are ``(q_nope k_nope^T + q_r k_r^T) * sm_scale`` (None: ``1 / sqrt(nope
+    + rope)``), summed in float32; the same kernels, tiling and arithmetic
+    as ``flash_attention`` on the assembled ``[B, H, S, .]`` operands;
+    ``latent_refusal`` says which shapes it takes."""
+    b, s, rope = k_r.shape
+    nope = q_nope.shape[-1] // heads
+    why = _latent_shape_refusal(
+        s, heads, nope, rope, kv.shape[-1] // heads - nope)
+    if why:
+        raise ValueError(f"flash_attention_latent: {why}")
+    if sm_scale is None:
+        sm_scale = 1.0 / ((nope + rope) ** 0.5)
+    return _flash_latent(
+        q_nope, q_r, kv, k_r, int(heads), float(sm_scale),
+        pick_block(s, block_q), pick_block(s, block_k))
+
+
+def _latent_shape_refusal(seq, heads, nope, rope, v_dim):
+    """``latent_refusal``'s part that the shapes alone decide."""
+    if nope % LANES or nope != v_dim:
+        return (
+            f"{nope} unrotated lanes on {v_dim} of v: not equal whole "
+            f"{LANES}-lane blocks")
+    if rope != LANES // 2:
+        return f"{rope} rotated lanes, not {LANES // 2}: two heads a block"
+    if heads % 2:
+        return f"{heads} heads do not pair into {LANES}-lane blocks"
+    block_q = pick_block(seq, DEFAULT_BLOCK_Q)
+    block_k = pick_block(seq, DEFAULT_BLOCK_K)
+    if not (block_q and block_k):
+        return f"no block divides seq={seq}"
+    return backward_plan(
+        seq, seq, block_q, block_k, True, lanes=2 * (nope + rope))["reason"]
+
+
+def latent_refusal(batch, seq, heads, nope, rope, v_dim, mesh=None):
+    """Why the kernels cannot read a latent mixer's heads out of its
+    projections' own results, or None where they can. A head's unrotated
+    key lanes and its value must be whole 128-lane blocks of one product
+    (and equally wide: the context's blocks are q_nope's), two heads'
+    rotated lanes must fill one, and dq's two parts must fit the fused
+    backward's VMEM (the pair of kernels is not built for this layout). One
+    device or a mesh of one: a kernel is not partitioned over devices, and
+    no ``shard_map`` is built for this layout."""
+    why, *_ = _flash_gate(seq, seq, None, 0.0, None, True)
+    if why:
+        return f"no flash kernel ({why})"
+    route = _flash_route(mesh, batch, heads)
+    if route == "sharded":
+        return "a mesh of several devices: the layout is one device's"
+    if route != "local" and FLASH_MODE != "always":
+        return f"no flash kernel ({route})"
+    return _latent_shape_refusal(seq, heads, nope, rope, v_dim)
+
+
+def latent_layout(batch, seq, heads, nope, rope, v_dim, mesh=None):
+    """``attention_layout`` for a latent mixer, chosen from what the caller
+    sees and nothing else: ``("latent", 2, None)`` (the kernels read q_nope,
+    q_r, kv and k_r as the projections wrote them: ``flash_attention_latent``)
+    or ``("split", 1, reason)``: today's ``[B, H, S, .]`` operands through
+    ``attention(why_split=reason)``, which logs the line then."""
+    why = latent_refusal(batch, seq, heads, nope, rope, v_dim, mesh)
+    if why:
+        return "split", 1, why
+    _log_layout(batch, seq, heads, nope + rope, "latent", LANES // rope, None)
+    return "latent", LANES // rope, None
 
 
 # Flash dispatch mode:
@@ -2187,6 +2567,7 @@ def attention_packed(
 def attention(
     q, k, v, mask=None, causal=False, sm_scale=None, dropout_rate=0.0,
     dropout_rng=None, use_flash=True, mesh=None, block_diffusion=0, window=0,
+    why_split=None,
 ):
     """Dispatcher: flash kernel when shapes tile cleanly and the mask is a
     padding mask; XLA reference otherwise (incl. learned additive biases,
@@ -2200,8 +2581,10 @@ def attention(
     rows, inside the kernels; where no kernel runs, ``block_diffusion_mask``
     as a dense additive mask on the XLA path. ``window=W`` beside ``causal``:
     the band of the last W keys, inside the kernels or as two comparisons of
-    ``mha_reference``."""
-    why = "q, k and v arrive as separate [B, H, S, D] arrays"
+    ``mha_reference``. ``why_split``: why a caller that has another layout
+    (``latent_layout``) hands over split operands, for the
+    ``attention_layout`` line."""
+    why = why_split or "q, k and v arrive as separate [B, H, S, D] arrays"
     refusal = packed_refusal(q.shape[1], q.shape[-1])
     return _attention_split(
         q, k, v, mask, causal, sm_scale, dropout_rate, dropout_rng,

@@ -36,6 +36,7 @@ import numpy as np
 
 from .attention import (
     NEG_INF, additive_mask_to_kv_valid, attention, attention_packed,
+    flash_attention_latent, latent_layout,
 )
 from .qk_prep import qk_prep, qk_prep_in_place, qk_prep_path
 
@@ -561,14 +562,26 @@ def _latent_attention(p, x, spec, rotary, mesh):
     ``rotary``'s positions and frequencies (``apply_rotary``'s): wqa [E,
     q_rank], q_norm [q_rank], wqb [q_rank, heads * head_dim], wkva [E,
     kv_rank + lanes], kv_norm [kv_rank], wkvb [kv_rank, heads * (head_dim -
-    lanes + v_dim)], wo [heads * v_dim, E]. The kernels get q and k [B, heads,
-    S, head_dim] (the one rotated key part repeated over the heads) and v [B,
-    heads, S, v_dim] as they are: nothing is padded to another width. The
-    norms and the rotations are ``rms_norm`` and ``apply_rotary``
+    lanes + v_dim)], wo [heads * v_dim, E]. Nothing is padded to another
+    width. The norms and the rotations are ``rms_norm`` and ``apply_rotary``
     (``qk_prep_path`` refuses a head that does not fill 128-lane blocks: the
     passes of ops/qk_prep.py rotate whole heads on their FIRST lanes, and
     here the rotated lanes are the last 64 of 192 and a lone 64-lane key;
-    the latent norms come with no rotation)."""
+    the latent norms come with no rotation).
+
+    Where ``latent_layout`` says ``latent`` (the published widths on one
+    device) the flash kernels read the projections' own results and nothing
+    is laid out anew on either side of them: ``q_nope`` [B, S, heads * nope]
+    and ``q_r`` [B, S, heads * lanes] are two products of wqb's two column
+    ranges of each head (``q_r`` then rotated), ``kv = rms(c_kv) wkvb`` [B,
+    S, heads * (nope + v_dim)] goes in as the product wrote it (a head's
+    unrotated key lanes, then its value: the buffer a dots-saveable policy
+    keeps is the one the backward kernel reads), the ONE rotated key part
+    ``k_r`` [B, S, lanes] is read once a key block by every head, and the
+    context comes back [B, S, heads * v_dim], what ``wo`` reads. Else (toy
+    widths, several devices; ``latent_refusal`` has the reason) q and k are
+    built [B, heads, S, head_dim], the rotated key part repeated over the
+    heads, with v [B, heads, S, v_dim], for ``attention``."""
     b, s, _ = x.shape
     heads, rope = spec.heads, spec.lanes
     nope = spec.head_dim - rope
@@ -578,8 +591,22 @@ def _latent_attention(p, x, spec, rotary, mesh):
                             factor=spec.rotary_factor, **rotary)
 
     centered = spec.norm == "zero_centered"
-    q = rms_norm(x @ p["wqa"], p["q_norm"], spec.eps, centered) @ p["wqb"]
-    q = q.reshape(b, s, heads, spec.head_dim)
+    layout, _, why_split = latent_layout(
+        b, s, heads, nope, rope, spec.v_dim, mesh)
+    c_q = rms_norm(x @ p["wqa"], p["q_norm"], spec.eps, centered)
+    if layout == "latent":
+        wqb = p["wqb"].reshape(-1, heads, spec.head_dim)
+        q_nope = c_q @ wqb[:, :, :nope].reshape(-1, heads * nope)
+        q_r = c_q @ wqb[:, :, nope:].reshape(-1, heads * rope)
+        q_r = rotated(q_r.reshape(b, s, heads, rope)).reshape(q_r.shape)
+        latent = x @ p["wkva"]
+        k_r = rotated(latent[:, :, None, spec.kv_rank:]).reshape(b, s, rope)
+        kv = rms_norm(latent[..., :spec.kv_rank], p["kv_norm"], spec.eps,
+                      centered) @ p["wkvb"]
+        return flash_attention_latent(
+            q_nope, q_r, kv, k_r, heads,
+            sm_scale=spec.head_dim ** -0.5) @ p["wo"]
+    q = (c_q @ p["wqb"]).reshape(b, s, heads, spec.head_dim)
     q = jnp.concatenate([q[..., :nope], rotated(q[..., nope:])], axis=-1)
     latent = x @ p["wkva"]
     k_rope = rotated(latent[:, :, None, spec.kv_rank:])
@@ -591,7 +618,8 @@ def _latent_attention(p, x, spec, rotary, mesh):
         axis=-1)
     ctx = attention(
         q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-        kv[..., nope:].transpose(0, 2, 1, 3), causal=True, mesh=mesh)
+        kv[..., nope:].transpose(0, 2, 1, 3), causal=True, mesh=mesh,
+        why_split=why_split)
     return ctx.transpose(0, 2, 1, 3).reshape(b, s, heads * spec.v_dim) \
         @ p["wo"]
 

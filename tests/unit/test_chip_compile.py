@@ -967,16 +967,21 @@ def test_latent_window_compiles_at_the_cells_shapes(monkeypatch):
     dense layer, five scanned sparse layers and the multi-token-prediction
     module at the published widths, micro 2 x seq 8,192 x accum 2, ZeRO-2 on
     one described chip), built by the engine as
-    ``benchmark/compile_described.py`` builds it: the flash kernels take q and
-    k 192 lanes wide and v 128 as they are (no operand of the kernels is 256
-    lanes wide, and none of v's side 192), every one of them under
-    ``attn_mla``, the stack's in the scan and the module's under ``mtp``; no
-    O(S^2) array exists (the XLA path's scores at 2 x 32 x 8,192^2 would be
-    8 GiB in bf16); the module's head products are the forward's, beside the
-    main ones; and the window stays inside the 15.07 GiB of the chip's 15.75
-    that it compiled to in PR 44 under the cell's policy, which keeps every
-    projection's output (10.33 under ``nothing_saveable``: PERF.md section
-    6)."""
+    ``benchmark/compile_described.py`` builds it: the flash kernels read the
+    latent mixer's operands where its projections wrote them (the LATENT
+    layout of ops/attention.py, PR 47: ``q_nope`` 32 x 128 lanes, ``q_r`` 32 x
+    64, ``kv`` 32 x 256 and the shared ``k_r`` of 64, all ``bf16[2,8192,.]``,
+    and the context, dq and d kv go back the same way), every one of them
+    under ``attn_mla``, the stack's in the scan and the module's under
+    ``mtp``; no head-major array (``bf16[64,8192,.]``, ``bf16[2,32,8192,.]``:
+    what PR 44's layout built by transposing, and the rotated key part
+    repeated over the heads) exists anywhere in the window; no O(S^2) array
+    exists (the XLA path's scores at 2 x 32 x 8,192^2 would be 8 GiB in
+    bf16); the module's head products are the forward's, beside the main
+    ones; and the window compiles to 14.81 GiB of the chip's 15.75 under the
+    cell's policy, which keeps every projection's output (15.07 with the
+    head-major operands of PR 44; 10.33 under ``nothing_saveable``: PERF.md
+    section 6)."""
     from benchmark import compile_described, harness
     from deepspeed_tpu.runtime.compile_cache import disarm_compile_cache
 
@@ -1001,8 +1006,9 @@ def test_latent_window_compiles_at_the_cells_shapes(monkeypatch):
     assert sum("/mtp/" in l for l in kernels) == 2
     assert sum("/stack_scan/" in l for l in kernels) == 2
     for line in kernels:
-        operands = re.findall(r"bf16\[64,8192,(\d+)\]", line)
-        assert set(operands) == {"192", "128"}, line
+        operands = re.findall(r"bf16\[2,8192,(\d+)\]", line)
+        assert set(operands) == {"4096", "2048", "8192", "64"}, line
+    assert not re.search(r"bf16\[(?:64|2,32),8192,\d+\]", text)
     # (the k | v up-projection's result is [2, 8192, 32 x 256]: not a score)
     assert not re.search(r"\[(?:2,32|64),8192,8192\]", text)
     assert set(_head_products(text, "mtp_head_loss")) == {"forward"}

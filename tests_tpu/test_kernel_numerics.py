@@ -183,6 +183,57 @@ def test_flash_matches_reference_at_the_cells_shape(cell):
         assert rel < 5e-2, f"d{name} rel err {rel:.2e}"
 
 
+def test_latent_layout_matches_the_split_path_at_the_published_widths():
+    """The LATENT layout (ops/attention.py: q_nope, q_r, kv and the shared k_r
+    as a latent mixer's projections write them) against the split path over
+    the same numbers assembled ``[B, H, S, .]``, both compiled, bf16: JoyAI's
+    128 + 64 / 128 lanes at S 8,192 (an 8 x 8 grid of 1,024-blocks), four
+    heads (two programs a row): the context, dq's two parts, both halves of
+    d kv, and dk_r summed over the heads."""
+    import importlib
+
+    att = importlib.import_module("deepspeed_tpu.ops.attention")
+    b, h, s, nope, rope, dv = 1, 4, 8192, 128, 64, 128
+    q_nope, q_r, kv, k_r, w = (
+        jax.random.normal(kk, (b, s, width), jnp.float32).astype(jnp.bfloat16)
+        for kk, width in zip(
+            jax.random.split(jax.random.PRNGKey(47), 5),
+            (h * nope, h * rope, h * (nope + dv), rope, h * dv)))
+    assert att.latent_layout(b, s, h, nope, rope, dv)[0] == "latent"
+
+    def f32(x):
+        return x.astype(jnp.float32)
+
+    def latent(*operands):
+        return f32(att.flash_attention_latent(*operands, h))
+
+    def split(q_nope, q_r, kv, k_r):
+        def heads(t):
+            return t.reshape(b, s, h, -1).transpose(0, 2, 1, 3)
+
+        kv = heads(kv)
+        q = jnp.concatenate([heads(q_nope), heads(q_r)], -1)
+        k = jnp.concatenate(
+            [kv[..., :nope], jnp.broadcast_to(k_r[:, None], (b, h, s, rope))],
+            -1)
+        out = flash_attention(q, k, kv[..., nope:], causal=True)
+        return f32(out.transpose(0, 2, 1, 3).reshape(b, s, h * dv))
+
+    def out_and_grads(attn):
+        return jax.jit(jax.value_and_grad(
+            lambda *o: jnp.sum(attn(*o) * f32(w)), argnums=(0, 1, 2, 3)
+        ))(q_nope, q_r, kv, k_r)[1], jax.jit(attn)(q_nope, q_r, kv, k_r)
+
+    got, out = out_and_grads(latent)
+    want, ref = out_and_grads(split)
+    # the same products in the same order but for the score's sum of two
+    err = float(jnp.max(jnp.abs(out - ref)))
+    assert err < 2e-2, f"max err {err:.2e}"
+    for a, r, name in zip(got, want, ("q_nope", "q_r", "kv", "k_r")):
+        rel = float(jnp.max(jnp.abs(f32(a) - f32(r))) / jnp.max(jnp.abs(f32(r))))
+        assert rel < 2e-2, f"d{name} rel err {rel:.2e}"
+
+
 def test_flash_dropout_deterministic_per_seed():
     q, k, v = _qkv()
     f = jax.jit(

@@ -5,7 +5,8 @@ calls, ms), at the shapes the benchmark's cells run, with the backward that
 
     chiprun -- python3 tools/flash_kernels_alone.py [shape ...] [--sub QxK ...]
 
-``--sub 256x128`` also times the key-major kernels at those sub-tiles (query x
+``joyai_latent`` is ``joyai`` in the LATENT layout (no pair exists for it: pass
+``--no-pair``). ``--sub 256x128`` also times the key-major kernels at those sub-tiles (query x
 key) instead of the ones ``pick_subtiles`` gives. A line also says how the
 forward and the backward walk the grid (``flash_tiling``'s ``walk``, ``bodies``
 and ``steps``). Writes one JSON line a (shape, variant) to stdout and to
@@ -41,6 +42,8 @@ SHAPES = {
     "qwen3next": ("split", 2, 16, 16384, 256, True, False, False),
     "joyai": ("split", 2, 32, 8192, (192, 128), True, False, False),
     "joyai_v192": ("split", 2, 32, 8192, 192, True, False, False),
+    # the same numbers where the latent mixer's projections write them (PR 47)
+    "joyai_latent": ("latent", 2, 32, 8192, (192, 128), True, False, False),
     "sdar": ("split", 2, 32, 16384, 128, False, False, False,
              {"block_diffusion": 4}),
     "laguna_band": ("split", 2, 72, 8192, 128, True, False, False,
@@ -69,6 +72,19 @@ def build(shape):
 
         return loss, (qkv, bias), (0, 1) if biased else (0,)
     dqk, dv = d if isinstance(d, tuple) else (d, d)
+    if entry == "latent":
+        rope = dqk - dv
+        q_nope, q_r, kv, k_r, w = (
+            jax.random.normal(kk, (b, s, width), jnp.bfloat16)
+            for kk, width in zip(
+                jax.random.split(keys[0], 5),
+                (h * dv, h * rope, h * 2 * dv, rope, h * dv)))
+
+        def loss(q_nope, q_r, kv, k_r):
+            out = att.flash_attention_latent(q_nope, q_r, kv, k_r, h)
+            return jnp.sum(out.astype(jnp.float32) * w.astype(jnp.float32))
+
+        return loss, (q_nope, q_r, kv, k_r), (0, 1, 2, 3)
     q, k, v, w = (jax.random.normal(kk, (b, h, s, width), jnp.bfloat16)
                   for kk, width in zip(keys, (dqk, dqk, dv, dv)))
 
