@@ -192,16 +192,25 @@ def device_report(devices):
 
 
 def setup_account(ctx, setup_s):
-    """Where the set-up went: seconds before the loop got control (imports,
-    jax and the backend starting), then by the benchmark's own spans, with
-    the compiles and cache loads counted."""
+    """Where the set-up went. ``setup_s`` is counted from the moment the
+    loop has its devices (``t_loop``): what the program and the cell do
+    before the first timed window, each part under the benchmark's own span
+    of its name, and ``unnamed_s`` what no span covers (the loop's imports
+    and glue). ``before_the_loop_s`` (the interpreter, ``import jax``, the
+    TPU runtime coming up: no line of the program) is NOT in it and is
+    printed beside it, with their sum as ``process_to_first_window_s``."""
     spans = ctx["spans"]
+    before = ctx["t_loop"] - ctx["t_process"]
     out = {"setup_s": setup_s,
-           "before_the_loop_s": ctx["t_loop"] - ctx["t_process"],
+           "before_the_loop_s": before,
+           "process_to_first_window_s": before + setup_s,
            "backend_compiles": ctx["compiles"].compiles,
            "compile_cache_loads": ctx["compiles"].cache_hits}
+    named = 0.0   # no span of a set-up lies inside another
     for name in sorted({r[0] for r in spans.records}):
         out[name + "_s"] = sum(t1 - t0 for _n, t0, t1, _a in spans.named(name))
+        named += out[name + "_s"]
+    out["unnamed_s"] = setup_s - named
     return out
 
 

@@ -174,7 +174,7 @@ def run(ctx):
         warm(engine, cell, size, seed)
     reqs = gen.requests(seed, cell["traffic"], size, seconds)
     compiles_before = counter.total()
-    setup_s = time.perf_counter() - ctx["t_process"]
+    setup_s = time.perf_counter() - ctx["t_loop"]
     harness.say("setup", **harness.setup_account(ctx, setup_s))
 
     # ---- the measured window, then the drain ------------------------------

@@ -6,7 +6,9 @@ Runs one cell of ``BENCHMARK.json`` on the machine it is started on and
 prints, as the last line of its standard output, one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end metrics with
 ``--trace 0``, its per-layer metrics with ``--trace 1``), ``device`` and, in
-a traced run, ``breakdown``. Everything else goes on earlier lines.
+a traced run, ``breakdown``; last in it ``compared``, every number that
+decided ``correct`` beside its limit (also the last lines on standard error).
+Everything else goes on earlier lines.
 
 It exits non-zero and prints no result when jax finds no TPU, or fewer chips
 than the cell asks for. ``--rehearse`` is the only way to a CPU and a toy
@@ -121,35 +123,43 @@ def main(argv=None):
     cell, bench, spans = ctx["cell"], ctx["bench"], ctx["spans"]
     result = harness.plugin("loops", cell["loop"]).run(ctx)
 
-    for c in result["checks"]:
-        harness.say("compared", **c)
-    line = {
-        "correct": all(c["ok"] for c in result["checks"]),
-        "attempted": result["attempted"],
-        "failed": result["failed"],
-        "metrics": {},
-        "device": result["device"],
-    }
-    # a loop offers every end-to-end number it takes; BENCHMARK.json says
-    # which of them this cell reports
-    units = {m["name"]: m["unit"] for m in bench["end_to_end"]
-             if cell["name"] in m.get("workloads", [cell["name"]])}
+    checks = list(result["checks"])
+    device, breakdown = result["device"], {}
     if args.trace:
         from benchmark import trace
 
         ctx["trace"] = trace.Trace(ctx["tracer"], spans)
-        for check in bypassed_kernels(cell, ctx["trace"]):
-            harness.say("compared", **check)
-            line["correct"] = line["correct"] and check["ok"]
-        line["metrics"] = layer_metrics(ctx, result, bench)
-        line["device"].update(ctx["trace"].busy_and_window())
-        line["breakdown"] = ctx["trace"].breakdown()
+        checks += bypassed_kernels(cell, ctx["trace"])
+        reported = layer_metrics(ctx, result, bench)
+        device.update(ctx["trace"].busy_and_window())
+        breakdown = {"breakdown": ctx["trace"].breakdown()}
     else:
-        line["metrics"] = {
+        # a loop offers every end-to-end number it takes; BENCHMARK.json
+        # says which of them this cell reports
+        units = {m["name"]: m["unit"] for m in bench["end_to_end"]
+                 if cell["name"] in m.get("workloads", [cell["name"]])}
+        reported = {
             name: {"value": value, "unit": units[name]}
             for name, value in result["end_to_end"].items() if name in units
         }
-    print(json.dumps(line), flush=True)
+    # every number compared beside its limit: on an earlier line each, as the
+    # result's last key, and as the last lines on standard error (what the
+    # driver keeps of a run at fault)
+    for c in checks:
+        harness.say("compared", **c)
+        print(f"compared {c['name']} {c['value']!r} limit {c['limit']!r}",
+              file=sys.stderr, flush=True)
+    line = {
+        "correct": all(c["ok"] for c in checks),
+        "attempted": result["attempted"],
+        "failed": result["failed"],
+        "metrics": reported,
+        "device": device,
+        **breakdown,
+        "compared": {c["name"]: {"value": c["value"], "limit": c["limit"]}
+                     for c in checks},
+    }
+    print(json.dumps(line, default=float), flush=True)
     return 0
 
 

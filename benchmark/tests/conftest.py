@@ -33,3 +33,26 @@ def serve_toy(monkeypatch):
 
     monkeypatch.setattr(harness, "load_cell", load_cell)
     return cell["name"]
+
+
+@pytest.fixture(scope="session")
+def sound_training():
+    """ONE sound rehearsal of cell 1 through the training loop, in this
+    process, for every test that reads a sound run: its context, what the
+    loop returned and its earlier lines. The process is made to look 1,000 s
+    old when the loop gets its devices, so a set-up counted from the
+    process's start would show."""
+    import contextlib
+    import io
+
+    from benchmark import run
+    from benchmark.loops import train
+
+    ctx = run.context("gpt2-large.train-seq1024", 11, 1.0, rehearse=True)
+    ctx["t_process"] -= 1000.0
+    said = io.StringIO()
+    with contextlib.redirect_stdout(said):
+        result = train.run(ctx)
+    notes = [json.loads(line) for line in said.getvalue().splitlines()
+             if line.startswith("{")]
+    return {"ctx": ctx, "result": result, "notes": notes}

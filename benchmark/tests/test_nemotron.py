@@ -31,6 +31,20 @@ def test_rehearsal_runs_to_a_result(cell):
     assert line["failed"] == 0 and line["attempted"] > 0
     assert set(line["metrics"]) == {"setup_s", "train_tokens_per_s_per_chip"}
     assert line["device"]["platform"] == "cpu"
+    # through the command itself: set-up as the earlier line accounts for it,
+    # and every number compared beside its limit, last in the line and on
+    # standard error
+    (setup,) = [json.loads(n) for n in out.stdout.splitlines()
+                if n.startswith('{"note": "setup"')]
+    assert line["metrics"]["setup_s"]["value"] == setup["setup_s"]
+    assert setup["process_to_first_window_s"] == pytest.approx(
+        setup["setup_s"] + setup["before_the_loop_s"], abs=1e-9)
+    assert list(line)[-1] == "compared" and "steps_taken" in line["compared"]
+    # (in this order; whatever else jax or the interpreter says on the way
+    # out is not the benchmark's)
+    assert [n for n in out.stderr.splitlines() if n.startswith("compared ")] == [
+        f"compared {name} {c['value']!r} limit {c['limit']!r}"
+        for name, c in line["compared"].items()]
 
 
 def test_lower_precision_moves_the_new_reference(capsys):

@@ -21,13 +21,24 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 MODULE, WINDOW = "train_window", "window_fwd_bwd"
 LAYERS = ["h", "ln_f", "jit(blocked_lm_head_loss)"]
 
+# the eight cells of PR 35, and which of them each of its metrics listed
+# then: a later cell joins a list, and none of these leaves one
+CELLS_35 = [
+    "gpt2-large.train-seq1024", "bert-large.pretrain-seq128",
+    "gpt2-large.zero2-dp4", "nemotron3-super-120b-a12b.train-seq8192",
+    "gpt2-large.train-accum1", "qwen3-next-80b-a3b.train-seq16384",
+    "bert-large.pretrain-seq512", "ouro-2.6b.train-seq8192"]
+DENSE, ALL = (0, 1, 2, 4, 6), tuple(range(8))
 NEW = {
-    "dense_attn_ms.train": ("scope_time", 5), "dense_ffn_ms.train": ("scope_time", 5),
-    "embed_ms.train": ("scope_time", 8), "head_loss_ms.train": ("scope_time", 7),
-    "stack_norms_ms.train": ("scope_time", 3),
-    "forward_ms.train": ("pass_time", 8), "recompute_ms.train": ("pass_time", 8),
-    "backward_ms.train": ("pass_time", 8), "unscoped_ms.train": ("unscoped_time", 8),
-    "grad_accum_ms.train": ("scope_time", 7), "stack_scan_ms.train": ("unscoped_time", 6),
+    "dense_attn_ms.train": ("scope_time", DENSE),
+    "dense_ffn_ms.train": ("scope_time", DENSE),
+    "embed_ms.train": ("scope_time", ALL),
+    "head_loss_ms.train": ("scope_time", ALL[:7]),
+    "stack_norms_ms.train": ("scope_time", (3, 5, 7)),
+    "forward_ms.train": ("pass_time", ALL), "recompute_ms.train": ("pass_time", ALL),
+    "backward_ms.train": ("pass_time", ALL), "unscoped_ms.train": ("unscoped_time", ALL),
+    "grad_accum_ms.train": ("scope_time", (0, 1, 2, 3, 5, 6, 7)),
+    "stack_scan_ms.train": ("unscoped_time", DENSE + (7,)),
 }
 
 
@@ -99,17 +110,20 @@ def test_the_scopes_are_read_from_every_file_of_the_folder(tmp_path, monkeypatch
     assert named["around"] == ["stack_scan"] and "loop_pass" in named["other"]
     everything = [s for kind in named.values() for s in kind]
     assert len(everything) == len(set(everything))
-    # a later PR's file joins the lists; a scope stays where it was first put
+    # a later PR's file joins the lists; a scope stays where it was first
+    # put. Shown on a stand-in folder against ITSELF: the real folder's lists
+    # grow with every PR that opens a scope
     folder = tmp_path / "scopes"
     folder.mkdir()
-    for name, spec in [("program.json", harness.load_json("scopes", "program.json")),
-                       ("zz_later.json", {"layers": ["new_mixer", "embed"],
-                                          "other": ["dense_attn"]})]:
-        (folder / name).write_text(json.dumps(spec))
+    (folder / "program.json").write_text(json.dumps(
+        harness.load_json("scopes", "program.json")))
     monkeypatch.setattr(harness, "HERE", str(tmp_path))
+    alone = pass_time.listed()
+    (folder / "zz_later.json").write_text(json.dumps(
+        {"layers": ["new_mixer", "embed"], "other": ["dense_attn"]}))
     later = pass_time.listed()
-    assert later["layers"] == named["layers"] + ["new_mixer"]
-    assert later["around"] == named["around"] and later["other"] == named["other"]
+    assert later["layers"] == alone["layers"] + ["new_mixer"]
+    assert later["around"] == alone["around"] and later["other"] == alone["other"]
 
 
 def test_the_layers_and_what_is_left_add_up_to_the_window(ctx, capsys):
@@ -213,19 +227,20 @@ def test_every_new_metric_has_its_file_its_reader_and_its_cells():
     bench = harness.load_benchmark()
     entries = {e["name"]: e for e in bench["per_layer"]}
     cells = {w["name"]: w for w in bench["workloads"]}
-    for name, (reader, n_cells) in NEW.items():
+    for name, (reader, at_pr_35) in NEW.items():
         entry = entries[name]
         assert (entry["unit"], entry["better"], entry["source"],
                 entry["moves"]) == ("ms", "lower", "device_trace",
                                     "train_tokens_per_s_per_chip")
-        assert len(entry["workloads"]) == n_cells
+        assert {CELLS_35[i] for i in at_pr_35} <= set(entry["workloads"])
         assert set(entry["workloads"]) <= set(cells)
         spec = harness.load_json("layer_metrics", name + ".json")
         assert set(spec) == {"reader", "args"} and spec["reader"] == reader
         assert spec["args"]["module"] == MODULE
         assert hasattr(harness.plugin("readers", reader), "read")
-    # appended: nothing that stood before them moved
-    assert list(entries)[-len(NEW):] == list(NEW)
+    # appended together and in this order; later PRs' metrics follow them
+    first = list(entries).index("dense_attn_ms.train")
+    assert list(entries)[first:first + len(NEW)] == list(NEW)
     dense = {c for c in cells if c.startswith(("gpt2-large.", "bert-large."))}
     assert set(entries["dense_attn_ms.train"]["workloads"]) == dense
     assert set(entries["stack_norms_ms.train"]["workloads"]) == set(cells) - dense
@@ -238,7 +253,9 @@ def test_every_new_metric_has_its_file_its_reader_and_its_cells():
              if harness.load_json("layer_metrics", n + ".json")["reader"]
              == "scope_time" and e["layer"] in ("model blocks", "training engine")}
     listed = pass_time.listed()
-    assert named - set(listed["other"]) == set(listed["layers"])
+    # (ONE listed layer has no metric of its own: PR 44's mtp_proj)
+    assert named - set(listed["other"]) == set(listed["layers"]) - {"mtp_proj"}
+    assert "mtp_proj" in listed["layers"]
     for name, under in [("unscoped_ms.train", WINDOW),
                         ("stack_scan_ms.train", "stack_scan")]:
         assert harness.load_json("layer_metrics", name + ".json")["args"] == {
