@@ -3,6 +3,14 @@ cells run at real size, and print each one's ``memory_analysis()``.
 
     JAX_PLATFORMS=cpu python3 benchmark/compile_described.py [cell ...]
 
+A training cell's two peaks end side by side on one ``peaks`` line: the
+reference's gradient step (the follower's own function, ``reference/
+train.py:block_step``: what decides whether ``correct`` can be read) and the
+program's window. Hold ``peak_GiB`` against the chip's 15.75 GiB yourself:
+the compiler ACCEPTS some programs that read above it, and the chip then
+refuses to load them (PERF.md, section 6, PR 49: 15.87 was accepted and
+failed to load by 0.16 GiB).
+
 Costs no chip time (on-chip-measurement guide, section 2.3). Nothing runs,
 so this says whether a program compiles and fits, never how fast it is. The
 program builds its mesh from ``jax.devices()`` and places its own arrays, so
@@ -33,10 +41,10 @@ from benchmark import harness, program  # noqa: E402
 GIB = 2.0 ** 30
 
 
-def report(label, compiled, t0):
+def report(label, compiled, t0, **more):
     m = compiled.memory_analysis()
     print(json.dumps({
-        "program": label, "compile_s": round(time.time() - t0, 1),
+        "program": label, "compile_s": round(time.time() - t0, 1), **more,
         "peak_GiB": round(m.peak_memory_in_bytes / GIB, 2),
         "arguments_GiB": round(m.argument_size_in_bytes / GIB, 2),
         "temp_GiB": round(m.temp_size_in_bytes / GIB, 2),
@@ -99,7 +107,7 @@ def described(devices):
 def reference_programs(cell, config, size, one_chip):
     """The reference's gradient step (training cells) or forward pass
     (serving cells), in float32 at the cell's own block of rows."""
-    from benchmark.reference import ops
+    from benchmark.reference import ops, train
 
     ref = harness.plugin("reference", config["reference"])
     dot = ops.make_dot("float32")
@@ -109,10 +117,9 @@ def reference_programs(cell, config, size, one_chip):
         length = cell["engine"]["prefill_len"] + cell["traffic"]["output_tokens"]["max"]
         toks = jax.ShapeDtypeStruct((1, length), jnp.int32, sharding=one_chip)
         t0 = time.time()
-        report(f"{cell['name']}: reference forward, 1 x {length}",
-               jax.jit(lambda p, t: ref.logits(p, t, size, dot)).lower(
-                   params, toks).compile(), t0)
-        return
+        return report(f"{cell['name']}: reference forward, 1 x {length}",
+                      jax.jit(lambda p, t: ref.logits(p, t, size, dot)).lower(
+                          params, toks).compile(), t0)
     gen = harness.plugin("traffic", cell["traffic"]["generator"])
     batch = next(gen.micro_batches(0, dict(cell, chips=1), size))
     rows = {k: jax.ShapeDtypeStruct(
@@ -122,17 +129,14 @@ def reference_programs(cell, config, size, one_chip):
     weights = tuple(jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
                     for _ in range(n_terms))
 
-    def step(p, acc, r, w):
-        loss, g = jax.value_and_grad(
-            lambda q: sum(s * x for s, x in zip(ref.loss_sums(q, r, size, dot), w))
-        )(p)
-        return jax.tree_util.tree_map(jnp.add, acc, g), loss
-
     t0 = time.time()
-    report(f"{cell['name']}: reference gradient step, "
-           f"{cell['check']['block_rows']} rows (params + sum + one gradient)",
-           jax.jit(step, donate_argnums=(1,)).lower(
-               params, params, rows, weights).compile(), t0)
+    return report(
+        f"{cell['name']}: reference gradient step, "
+        f"{cell['check']['block_rows']} rows (params + sum + one gradient)",
+        train.block_step(ref, size, dot).lower(
+            params, params, rows, weights).compile(), t0,
+        parameters_M=round(sum(
+            int(np.prod(s)) for s in ref.shapes(size).values()) / 1e6, 1))
 
 
 def zeros_like_shapes(ref, size):
@@ -171,6 +175,7 @@ def program_window(cell, config, size, devices):
                 for op in ("all-reduce", "all-gather", "reduce-scatter",
                            "collective-permute")}}), flush=True)
         program.close_train(engine)
+    return compiled
 
 
 def program_serving(cell, config, size, devices):
@@ -211,9 +216,14 @@ def main(argv):
         cell = harness.load_json("workloads", name + ".json")
         config = harness.load_json("configs", cell["config"] + ".json")
         size = harness.sizes(config, False)
-        reference_programs(cell, config, size, one_chip)
+        reference = reference_programs(cell, config, size, one_chip)
         if cell["loop"] == "train":
-            program_window(cell, config, size, topo.devices[:cell["chips"]])
+            window = program_window(
+                cell, config, size, topo.devices[:cell["chips"]])
+            print(json.dumps({"peaks": name, **{
+                label: round(c.memory_analysis().peak_memory_in_bytes / GIB, 2)
+                for label, c in (("reference_gradient_step_GiB", reference),
+                                 ("training_window_GiB", window))}}), flush=True)
         elif cell["loop"] == "serve":
             program_serving(cell, config, size, topo.devices[:cell["chips"]])
     return 0

@@ -55,11 +55,27 @@ def plugin(kind, name):
     return importlib.import_module(f"benchmark.{kind}.{name}")
 
 
+# a configuration file's sections that are the harness's own, not the model's
+OWN_SECTIONS = frozenset((
+    "name", "source", "reduced", "published", "reduced_why", "deployment",
+    "assumed", "reference", "program", "train", "serve", "toy", "toy_why"))
+
+
 def sizes(config, rehearse):
-    """The configuration's numbers, with the toy overrides in a rehearsal."""
-    out = {k: v for k, v in config.items() if isinstance(v, (int, float))}
-    out.update({k: v for k, v in config.get("assumed", {}).items()
-                if isinstance(v, (int, float))})
+    """What a published config holds, as program and reference get it: the
+    numbers, strings, lists and dicts of the configuration's top level, where
+    the published keys live, then the numbers, lists and dicts of ``assumed``
+    (a string there is a note, whatever its key, and is not handed over; nor
+    are the harness's own sections, any ``*_why`` and a null), with the toy
+    overrides in a rehearsal. A list does not hash: hand this to ``jax.jit``
+    inside a closure, never as a static argument."""
+    def handed(section, kinds):
+        return {k: v for k, v in section.items()
+                if k not in OWN_SECTIONS and not k.endswith("_why")
+                and isinstance(v, kinds)}
+
+    out = handed(config, (int, float, str, list, dict))
+    out.update(handed(config.get("assumed", {}), (int, float, list, dict)))
     if rehearse:
         out.update(config["toy"])
     return out

@@ -9,9 +9,19 @@ You et al. 2019 with the trust ratio clamped as DeepSpeed's FusedLamb
 does). The window's loss is the mean over its micro-batches of each
 micro-batch's own mean loss: gradient accumulation as the program does it.
 
-The moments live on the host between steps (numpy) and visit the device a
-leaf at a time for the update, so that parameters, a gradient sum and one
-block's gradient are all the device has to hold beside the activations.
+What lives where. On the device: the float32 parameters, the gradient sum
+(donated to every block's step and written in place) and, inside that step,
+the block's gradient piece by piece with the activations: 8 bytes a
+parameter between blocks, 12 and the activations at a step's end, as the
+compiler counts them (``compile_described.py`` prints the peak; PERF.md
+section 6, PR 49, has what a sum kept on the host does and does not save).
+On the host (numpy): the moments between steps, which visit the device a
+leaf at a time for the update.
+
+The sum's order is fixed: float32 adds of the blocks' gradients, block
+after block in the order of the rows, from zeros at the start of a step. A
+float32 sum depends on its order, and every limit of every cell was set
+from readings that added in this one.
 """
 
 import jax
@@ -65,6 +75,21 @@ def _update(kind, o):
     return jax.jit(adam, donate_argnums=(0, 2, 3))
 
 
+def block_step(model, cfg, dot):
+    """Jitted ``(params, sum, rows, weights) -> (sum + gradient, loss)`` over
+    one block of rows, the sum donated: the follower's one program that holds
+    activations, which ``compile_described.py`` compiles at real size."""
+    def block_loss(p, rows, weights):
+        sums = model.loss_sums(p, rows, cfg, dot)
+        return sum(s * w for s, w in zip(sums, weights))
+
+    def accumulate(p, acc, rows, weights):
+        loss, g = jax.value_and_grad(block_loss)(p, rows, weights)
+        return jax.tree_util.tree_map(jnp.add, acc, g), loss
+
+    return jax.jit(accumulate, donate_argnums=(1,))
+
+
 def follow(model, cfg, make_params, steps, optimizer, dot, block_rows):
     """``steps``: one list of micro-batches ({name: numpy array}) per step;
     ``make_params()`` gives the seeded weights, anew at each call. Returns
@@ -79,15 +104,7 @@ def follow(model, cfg, make_params, steps, optimizer, dot, block_rows):
         return host(leaf_norms(
             model, {k: params[k] - start[k] for k in names}))
 
-    def block_loss(p, rows, weights):
-        sums = model.loss_sums(p, rows, cfg, dot)
-        return sum(s * w for s, w in zip(sums, weights))
-
-    def accumulate(p, acc, rows, weights):
-        loss, g = jax.value_and_grad(block_loss)(p, rows, weights)
-        return jax.tree_util.tree_map(jnp.add, acc, g), loss
-
-    accumulate = jax.jit(accumulate, donate_argnums=(1,))
+    accumulate = block_step(model, cfg, dot)
     update = _update(optimizer["type"], optimizer)
     moments = None
     losses, first_grad, first_change = [], None, None
