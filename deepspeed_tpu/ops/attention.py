@@ -155,8 +155,8 @@ def pick_block(seq, maximum):
 # reductions of the forward's softmax were 40% of that kernel.
 #
 # Where a grid has ONE block each way (every seq up to DEFAULT_BLOCK),
-# every loop bound is known at trace time and the walk unrolls: the
-# scheduler overlaps one sub-tile's matmuls with its neighbour's softmax.
+# every loop bound is known at trace time and the walk unrolls into one
+# straight-line body that the scheduler packs as a whole (how well, below).
 # With more blocks the bounds depend on the grid position, but only through
 # the few CLASSES of step a grid has (under ``causal`` a step lies ON the
 # diagonal or UNDER it): a kernel lowers one body a class, each with its
@@ -173,29 +173,73 @@ def pick_block(seq, maximum):
 # and one do. The pair is kept for the shapes whose dq does not fit VMEM
 # (``backward_plan``).
 #
+# The forward carries a CHAIN a query stripe of a head (the running max, the
+# sum, the accumulator) from one key sub-tile to the next; a grid step holds
+# ``heads_a_block x block_q / sub_q`` of them and they do not depend on each
+# other. Until PR 50 a step lowered them one after another, in 512-square
+# sub-tiles, and the compiler's schedule of such a body (``tools/
+# flash_schedule.py``, no chip) ALTERNATES: stretches where all four of the
+# matrix unit's slots are full, then 400-600 bundles a sub-tile of max / exp /
+# sum with the unit idle, the ``exp`` pass at one vector a bundle through the
+# one ``exp`` slot and the one store slot (every one of a sub-tile's 256
+# score registers is spilled as it is popped and again as it is exponentiated).
+# 40% of a body's bundles held no product, and on the chip the kernel ran at
+# 43-51% of its roofline where the backward, whose sub-tiles are independent,
+# fills 98% of those slots and runs at 70-76%. Since PR 50 a step walks
+# KEY-MAJOR (``_fwd_kernel``): the key sub-tile outermost, every chain that
+# sees it advancing by one link, each link's score product emitted
+# ``FWD_SCORES_AHEAD`` links before its softmax; each chain meets its
+# sub-tiles in the order it always did, so the bits are the same. In
+# 256-square sub-tiles the schedule then keeps the unit's slots 75-95% full
+# through the whole body. A step of one chain, and a walk by ``fori_loop``,
+# keep the old order (``_forward_order``).
+#
 # Sub-tile sizes, measured alone on "TPU v5 lite" (docs/TESTING.md; ms a
-# call, bf16). At [8, 20, 1024, 64] causal, one block each way, 128 / 256
-# / 512 square (PR 25): forward 0.83 / 0.67 / 0.43, dq 1.35 / 0.57 / 0.48,
-# dkv 0.60 / 0.63 / 0.78; the fused backward (PR 33, packed operands with
-# the bias) 0.92 / 0.74 / 0.87, 256 q x 128 k 0.89, 128 q x 256 k 0.98,
-# against the pair's 0.49 + 0.59. The query-major kernels (forward, dq)
-# carry a chain from one key sub-tile to the next (the running max; the
-# accumulator) and want few large steps even at 3/4 of the causal square;
-# dkv's sub-tiles are independent, so it takes the 128-steps that visit
-# 9/16 of it; the fused kernel adds every sub-tile's product into dq's
-# accumulator in VMEM, which 128-steps do four times as often as 256-steps
-# (5/8 of the square). BERT's [8, 16, 512, 64] with a key mask reads the
-# same: 0.36 / 0.26 / 0.28 against the pair's 0.16 + 0.24. On a grid of
-# several blocks the fused kernel under a ``fori_loop`` walk had nothing to
-# choose ([1, 16, 8192, 128]: 5.60 at 512 square, 5.55 to 6.06 elsewhere;
-# PR 33); with one static body a class of step (PR 46) the same shape reads
-# 4.21 at 512 square, 4.21 at 512 q x 256 k and 256 q x 512 k, 4.10 at 256
-# square, and 256 square wins at every cell's shape: 61.28 -> 60.46 at
-# [2, 16, 16384, 256], 24.33 -> 23.74 at q/k 192 on v 128, 39.37 -> 37.65
-# under the block-diffusion mask, 10.00 -> 7.96 under a band of 512 keys
-# (three quarter-size sub-tiles a stripe where two whole ones were visited).
-# So the fused kernel takes 256 on every grid; the pair's dkv (no cell runs
-# it on several blocks) keeps 512 there, not measured since.
+# call, bf16). The FORWARD (PR 50; query x key sub-tile, old order -> new): at
+# [2, 32, 16384, 128] under the block-diffusion mask 512 square 25.88 -> 23.09,
+# 256 x 512 26.45 -> 22.61, 512 x 256 27.46 -> 21.97, 256 square 23.80 ->
+# 20.97, 128 square 20.34 -> 20.63; at packed [1, 8192, 3*16*128] causal 2.773
+# -> 2.450, 2.823 -> 2.375, -, 2.570 -> 2.247, 2.186 -> 2.223; at packed
+# [8, 1024, 3*20*64] causal with the bias (one block, two heads a block: four
+# chains at 512) 0.428 -> 0.358, 0.638 -> 0.349, -, 0.695 -> 0.325, 0.817 ->
+# 0.458: the old order wanted few large steps wherever two heads share a block
+# (PR 25 read 0.83 / 0.67 / 0.43 at 128 / 256 / 512 square there) and the new
+# one wants 256 (128 square reads level at 128 lanes and costs four times the
+# unrolled links to compile). In the new order at every shape of the table
+# (256 square | 512 square | 512 q x 128 k | 256 q x 128 k): GPT-2's 0.322 |
+# 0.358 | 0.327 | 0.365; [2, 16, 16384, 256] causal 29.35 | 29.77 | 27.46 |
+# 30.38; the LATENT layout at q/k 128 + 64 on v 128 11.31 | 12.35 | 11.82 |
+# 11.27; a band of 512 keys 4.76 | 5.71 | 5.61 | 4.49; [2, 48, 8192, 128]
+# causal 13.30 | 14.60 | 12.73 | 12.92; and BERT's packed [8, 512, 3*16*64]
+# with a key mask, ONE block of 512 rows, 0.150 | 0.150 | 0.125 | 0.150. So
+# the forward takes 256 square under blocks of 1,024 (level with the best or
+# within 7% of it at every shape, and the band needs no rule of its own), and
+# a block of up to 512 rows stays ONE stripe a head over key sub-tiles of 128
+# where a block's two heads are its chains (where it holds one head, a step
+# has one chain and the parent's 512-steps).
+# The BACKWARD at [8, 20, 1024, 64] causal, one
+# block each way, 128 / 256 / 512 square (PR 25): dq 1.35 / 0.57 / 0.48, dkv
+# 0.60 / 0.63 / 0.78; the fused backward (PR 33, packed operands with the
+# bias) 0.92 / 0.74 / 0.87, 256 q x 128 k 0.89, 128 q x 256 k 0.98, against
+# the pair's 0.49 + 0.59. dq carries a chain as the forward does and keeps its
+# large steps (no cell runs the pair); dkv's sub-tiles are independent, so it
+# takes the 128-steps that visit 9/16 of the causal square; the fused kernel
+# adds every sub-tile's product into dq's accumulator in VMEM, which
+# 128-steps do four times as often as 256-steps (5/8 of the square). BERT's
+# [8, 16, 512, 64] with a key mask reads the same: 0.36 / 0.26 / 0.28
+# against the pair's 0.16 + 0.24. On a grid of several blocks the fused
+# kernel under a ``fori_loop`` walk had nothing to choose ([1, 16, 8192,
+# 128]: 5.60 at 512 square, 5.55 to 6.06 elsewhere; PR 33); with one static
+# body a class of step (PR 46) the same shape reads 4.21 at 512 square, 4.21
+# at 512 q x 256 k and 256 q x 512 k, 4.10 at 256 square, and 256 square wins
+# at every cell's shape: 61.28 -> 60.46 at [2, 16, 16384, 256], 24.33 -> 23.74
+# at q/k 192 on v 128, 39.37 -> 37.65 under the block-diffusion mask, 10.00 ->
+# 7.96 under a band of 512 keys (three quarter-size sub-tiles a stripe where
+# two whole ones were visited). So the fused kernel takes 256 on every grid;
+# the pair's dkv (no cell runs it on several blocks) keeps 512 there, not
+# measured since.
+SUB_FORWARD = 256
+SUB_FORWARD_SHORT = 128
 SUB_QUERY_MAJOR = 512
 SUB_KEY_MAJOR = 128
 SUB_FUSED = 256
@@ -217,13 +261,21 @@ def pick_subtile(block, target):
     return block
 
 
-def pick_subtiles(block_q, block_k, nq, nk, key_major, fused=False):
-    """(sub_q, sub_k) of the forward and dq kernels, or of the key-major
-    ones (``key_major``: dkv of the pair, or the ``fused`` backward), for
-    blocks on an ``nq x nk`` grid: the fused kernel's 256 on every grid, the
-    pair's dkv 128 on one block."""
+def pick_subtiles(block_q, block_k, nq, nk, key_major, fused=False,
+                  forward=False, heads_a_block=1):
+    """(sub_q, sub_k) of the ``forward``, of dq (neither flag), or of the
+    key-major kernels (``key_major``: dkv of the pair, or the ``fused``
+    backward), for blocks on an ``nq x nk`` grid: the fused kernel's 256 on
+    every grid, the pair's dkv 128 on one block; the forward's 256 where a Q
+    block is longer than 512 rows, else the block as ONE stripe, a head, over
+    key sub-tiles of 128 where the ``heads_a_block`` are several chains and
+    of 512 where a step has the one."""
     target = SUB_QUERY_MAJOR
-    if key_major and fused:
+    if forward and block_q > SUB_QUERY_MAJOR:
+        target = SUB_FORWARD
+    elif forward and heads_a_block > 1:
+        return block_q, pick_subtile(block_k, SUB_FORWARD_SHORT)
+    elif key_major and fused:
         target = SUB_FUSED
     elif key_major and nq == nk == 1:
         target = SUB_KEY_MAJOR
@@ -667,6 +719,19 @@ def backward_plan(
     }
 
 
+def _forward_order(walk, nsq, heads_a_block):
+    """How ``flash_fwd`` walks ONE grid step: ``chains``, the softmax chains
+    (a head's query stripe of ``sub_q`` rows each, ``nsq`` a head) that the
+    step advances side by side, and ``order``: ``key_major`` (the key sub-tile
+    outermost, every chain that sees it advancing by one link) where a step
+    has several chains and its spans are ints (``walk`` ``static``), else
+    ``query_major`` (one chain after another: nothing to put side by side, or
+    a ``fori_loop`` a stripe)."""
+    chains = nsq * heads_a_block if walk == "static" else 1
+    return {"order": "key_major" if chains > 1 else "query_major",
+            "chains": chains}
+
+
 def _walk_report(*walk):
     """``walk`` (``static`` | ``loop``), ``bodies`` (classes of step lowered)
     and ``steps`` (``run``, ``skipped``, ``fetched``) of ``_walk_plan``."""
@@ -677,28 +742,34 @@ def _walk_report(*walk):
 
 def flash_tiling(
     sq, sk, block_q, block_k, causal, key_major=False, sub_q=None, sub_k=None,
-    block_diffusion=0, window=0, **plan,
+    block_diffusion=0, window=0, heads_a_block=1, **plan,
 ):
     """Outer blocks, sub-tiles and the share of the ``sq x sk`` score
-    square whose sub-tiles a kernel visits (the forward and dq, or dkv
-    with ``key_major``), from the same bounds that set its loops; how the
-    kernel walks the grid (``_walk_report``); and under ``backward`` what
-    ``backward_plan`` chooses for the call (``plan``: its ``lanes``,
-    ``itemsize`` and ``budget``)."""
+    square whose sub-tiles a kernel visits (the forward, or dkv with
+    ``key_major``), from the same bounds that set its loops; how the
+    kernel walks the grid (``_walk_report``) and, the forward, a grid step
+    (``_forward_order``, for programs of ``heads_a_block`` heads); and under
+    ``backward`` what ``backward_plan`` chooses for the call (``plan``: its
+    ``lanes``, ``itemsize`` and ``budget``)."""
     picked = pick_subtiles(
-        block_q, block_k, sq // block_q, sk // block_k, key_major
+        block_q, block_k, sq // block_q, sk // block_k, key_major,
+        forward=not key_major, heads_a_block=heads_a_block,
     )
     sub_q = picked[0] if sub_q is None else sub_q
     sub_k = picked[1] if sub_k is None else sub_k
+    walk = _walk_report(
+        key_major, sub_q, sub_k, block_q, block_k, sq // block_q,
+        sk // block_k, causal, sk - sq, block_diffusion, window)
+    if not key_major:
+        walk.update(_forward_order(
+            walk["walk"], block_q // sub_q, heads_a_block))
     return {
         "block_q": block_q, "block_k": block_k, "sub_q": sub_q,
         "sub_k": sub_k,
         "visited_share": _visited_share(
             sq, sk, block_k, sub_q, sub_k, causal, block_diffusion, window
         ),
-        **_walk_report(
-            key_major, sub_q, sub_k, block_q, block_k, sq // block_q,
-            sk // block_k, causal, sk - sq, block_diffusion, window),
+        **walk,
         "backward": backward_plan(
             sq, sk, block_q, block_k, causal, block_diffusion=block_diffusion,
             window=window, **plan,
@@ -709,12 +780,12 @@ def flash_tiling(
 @functools.lru_cache(maxsize=None)
 def _log_tiling(
     sq, sk, d, lanes, dtype, block_q, block_k, causal, use_mask, dropout,
-    block_diffusion=0, window=0,
+    block_diffusion=0, window=0, heads_a_block=1,
 ):
     t = flash_tiling(
         sq, sk, block_q, block_k, causal, lanes=lanes,
         itemsize=jnp.dtype(dtype).itemsize, block_diffusion=block_diffusion,
-        window=window,
+        window=window, heads_a_block=heads_a_block,
     )
     b = t["backward"]
 
@@ -724,11 +795,12 @@ def _log_tiling(
 
     logger.debug(
         "flash_tiling sq=%d sk=%d d=%d %s causal=%s mask=%s dropout=%s "
-        "block=%dx%d sub=%dx%d visited_share=%.4f walk=%s "
+        "block=%dx%d sub=%dx%d visited_share=%.4f walk=%s order=%s chains=%d "
         "backward=%s bwd_sub=%dx%d bwd_visited_share=%.4f bwd_walk=%s "
         "dq_vmem_bytes=%d%s%s%s",
         sq, sk, d, dtype, causal, use_mask, dropout, block_q, block_k,
-        t["sub_q"], t["sub_k"], t["visited_share"], walk(t),
+        t["sub_q"], t["sub_k"], t["visited_share"], walk(t), t["order"],
+        t["chains"],
         b["backward"], b["sub_q"], b["sub_k"], b["visited_share"], walk(b),
         b["dq_vmem_bytes"], f" reason={b['reason']!r}" if b["reason"] else "",
         f" block_diffusion={block_diffusion}" if block_diffusion else "",
@@ -963,6 +1035,8 @@ class _Tiles:
         plan = _walk_plan(
             self.key_major, sub_q, sub_k, block_q, block_k, nq, nk, causal,
             diag_offset, block_diffusion, window)
+        self.chains = _forward_order(
+            plan["walk"], self.nsq, heads_a_block)["chains"]
         if plan["walk"] == "loop":
             # one body, its bounds from the grid position as it runs
             self.run = self._runs()
@@ -1155,10 +1229,31 @@ class _Tiles:
         return self._over(spans[c], step, carry)
 
     @staticmethod
+    def by_key(spans):
+        """A class's ``spans`` (``_key_spans`` a query stripe) seen from the
+        keys: ``(c, ((r, diagonal), ...))`` for each key sub-tile that some
+        stripe sees, ascending: the order each stripe's own walk has."""
+        seen = {}
+        for r, runs in enumerate(spans):
+            for lo, hi, diagonal in runs:
+                for c in range(lo, hi):
+                    seen.setdefault(c, []).append((r, diagonal))
+        return sorted(seen.items())
+
+    @staticmethod
     def _over(spans, step, carry):
         for lo, hi, diagonal in spans:
             carry = _span(lo, hi, functools.partial(step, diagonal=diagonal), carry)
         return carry
+
+
+# In ``flash_fwd``'s key-major order a link's score product is emitted this
+# many links before its softmax, so that in the order of emission every softmax
+# pass lies between products that do not wait on it (measured alone on "TPU v5
+# lite", docs/TESTING.md, PR 50: at [2, 32, 16384, 128] under the block-diffusion
+# mask and 256-square sub-tiles, the chains' links one after another 23.84 ms,
+# the products one link ahead 22.17, two ahead 20.97).
+FWD_SCORES_AHEAD = 2
 
 
 def _fwd_kernel(
@@ -1166,12 +1261,89 @@ def _fwd_kernel(
     m_scr, l_scr, acc_scr, vt_scr, **static,
 ):
     t = _Tiles(1, seed_ref, **static)
+    lanes_of = dict(t.heads())
 
     @_when(t.first_step)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    # A CHAIN is one head's query stripe ``(hh, r)``: its running maximum, sum
+    # and accumulator (``m_scr[hh, r]``, ``l_scr[hh, r]``, ``acc_scr[r,
+    # lanes.o]`` between grid steps, values inside one) pass from one key
+    # sub-tile to the next, one LINK each.
+    def load_q(hh, r):
+        """The stripe's rows of q, scaled, and its first row's position."""
+        # matmul operands stay in their storage dtype (MXU-native bf16
+        # pairs, f32 accumulation); softmax bookkeeping is f32
+        q = t.load(q_ref, bq_ref, _rows(r, t.sub_q), lanes_of[hh].q)
+        if t.fold_scale:
+            q = _map(lambda part: part * t.sm_scale, q)
+        return q, t.q_first(r)
+
+    def scores(hh, q, q_first, c, diagonal):
+        return t.scores(
+            t.load(k_ref, bk_ref, _rows(c, t.sub_k), lanes_of[hh].k), q,
+            t.valid(kvm_ref, c), q_first, t.k_first(c), diagonal=diagonal,
+        )
+
+    def link(hh, q_first, c, s_t, carry, diagonal):
+        """Key sub-tile ``c``'s scores into the chain's carry."""
+        m_prev, l_prev, acc = carry
+        m_new = jnp.maximum(m_prev, jnp.max(s_t, axis=0, keepdims=True))
+        p_t = t.exp(s_t, m_new, diagonal)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = alpha * l_prev + jnp.sum(p_t, axis=0, keepdims=True)
+        if t.dropout_rate > 0.0:
+            keep = t.keep(seed_ref, hh, q_first, t.k_first(c), p_t.shape)
+            p_t = jnp.where(keep, p_t / (1.0 - t.dropout_rate), 0.0)
+        pv = _dot_t(vt_scr[c, lanes_of[hh].o, :], p_t, v_ref.dtype)
+        return m_new, l_new, acc * alpha + pv
+
+    def held(hh, r):
+        return m_scr[hh, r], l_scr[hh, r], acc_scr[r, lanes_of[hh].o, :]
+
+    def hold(hh, r, carry):
+        m_scr[hh, r], l_scr[hh, r], acc_scr[r, lanes_of[hh].o, :] = carry
+
+    def query_major(spans):
+        # one chain after another, each a walk over its key sub-tiles (a
+        # ``fori_loop`` where ``spans`` is None)
+        for hh in lanes_of:
+            for r in range(t.nsq):
+                q, q_first = load_q(hh, r)
+
+                def k_step(c, carry, diagonal):
+                    return link(
+                        hh, q_first, c, scores(hh, q, q_first, c, diagonal),
+                        carry, diagonal)
+
+                hold(hh, r, t.over_keys(spans, r, k_step, held(hh, r)))
+
+    def key_major(spans):
+        # the key sub-tile outermost: every chain that sees it advances by
+        # one link, side by side, and each chain meets its sub-tiles in the
+        # order ``query_major`` gives it (the same bits). The unrolled body
+        # then holds independent products and softmax passes next to each
+        # other, and the scheduler fills the matrix unit under one chain's
+        # max / exp / sum with another's products.
+        links = [(hh, r, c, diagonal) for c, seen in t.by_key(spans)
+                 for hh in lanes_of for r, diagonal in seen]
+        last = {(hh, r): i for i, (hh, r, _, _) in enumerate(links)}
+        qs, carries, scored = {}, {}, []
+        for i, (hh, r, c, diagonal) in enumerate(links):
+            for hh_, r_, c_, diagonal_ in links[
+                    len(scored):i + 1 + FWD_SCORES_AHEAD]:
+                if (hh_, r_) not in qs:
+                    qs[hh_, r_] = load_q(hh_, r_)
+                    carries[hh_, r_] = held(hh_, r_)
+                scored.append(scores(hh_, *qs[hh_, r_], c_, diagonal_))
+            carries[hh, r] = link(
+                hh, qs[hh, r][1], c, scored[i], carries[hh, r], diagonal)
+            scored[i] = None
+            if last[hh, r] == i:
+                hold(hh, r, carries.pop((hh, r)))
 
     @t.each_body
     def _body(spans):
@@ -1180,46 +1352,12 @@ def _fwd_kernel(
         # ``lanes`` of the result)
         for c in range(t.nsk):
             t.transpose(vt_scr, c, v_ref, bv_ref, v=True)
-
-        for hh, lanes in t.heads():
-            for r in range(t.nsq):
-                # matmul operands stay in their storage dtype (MXU-native
-                # bf16 pairs, f32 accumulation); softmax bookkeeping is f32
-                q = t.load(q_ref, bq_ref, _rows(r, t.sub_q), lanes.q)
-                if t.fold_scale:
-                    q = _map(lambda part: part * t.sm_scale, q)
-                q_first = t.q_first(r)
-
-                def k_step(c, carry, diagonal):
-                    m_prev, l_prev, acc = carry
-                    s_t = t.scores(
-                        t.load(k_ref, bk_ref, _rows(c, t.sub_k), lanes.k), q,
-                        t.valid(kvm_ref, c), q_first, t.k_first(c),
-                        diagonal=diagonal,
-                    )
-                    m_new = jnp.maximum(
-                        m_prev, jnp.max(s_t, axis=0, keepdims=True)
-                    )
-                    p_t = t.exp(s_t, m_new, diagonal)
-                    alpha = jnp.exp(m_prev - m_new)
-                    l_new = alpha * l_prev + jnp.sum(p_t, axis=0, keepdims=True)
-                    if t.dropout_rate > 0.0:
-                        keep = t.keep(
-                            seed_ref, hh, q_first, t.k_first(c), p_t.shape
-                        )
-                        p_t = jnp.where(keep, p_t / (1.0 - t.dropout_rate), 0.0)
-                    pv = _dot_t(vt_scr[c, lanes.o, :], p_t, v_ref.dtype)
-                    return m_new, l_new, acc * alpha + pv
-
-                m_scr[hh, r], l_scr[hh, r], acc_scr[r, lanes.o, :] = t.over_keys(
-                    spans, r, k_step,
-                    (m_scr[hh, r], l_scr[hh, r], acc_scr[r, lanes.o, :])
-                )
+        (key_major if t.chains > 1 else query_major)(spans)
 
     @_when(t.last_step)
     def _finalize():
         for r in range(t.nsq):
-            for hh, lanes in t.heads():
+            for hh, lanes in lanes_of.items():
                 l = l_scr[hh, r]
                 l = jnp.where(l == 0.0, 1.0, l)  # fully-masked rows -> zeros
                 acc_scr[r, lanes.o, :] = acc_scr[r, lanes.o, :] / l
@@ -1719,11 +1857,14 @@ def _forward_call(
         kv_mask is not None, use_bias, block_diffusion, window,
     )
     nq, nk = common["nq"], common["nk"]
-    sub_q, sub_k = pick_subtiles(block_q, block_k, nq, nk, key_major=False)
+    sub_q, sub_k = pick_subtiles(
+        block_q, block_k, nq, nk, key_major=False, forward=True,
+        heads_a_block=ops.heads_a_block)
     interpret = not device.on_tpu()
     _log_tiling(
         sq, sk, d, ops.block_lanes, str(dtype), block_q, block_k, causal,
         kv_mask is not None, dropout_rate > 0.0, block_diffusion, window,
+        ops.heads_a_block,
     )
     hb, nsq = ops.heads_a_block, block_q // sub_q
     keys, steps = _needed_blocks(False, *_grid_form(common))
@@ -2143,7 +2284,9 @@ def window_visited_share(seq, window):
         return 1.0
     return _visited_share(
         seq, seq, block_k,
-        *pick_subtiles(block_q, block_k, seq // block_q, seq // block_k, False),
+        *pick_subtiles(
+            block_q, block_k, seq // block_q, seq // block_k, False,
+            forward=True),
         True, window=window if window < seq else 0)
 
 

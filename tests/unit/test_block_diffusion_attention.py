@@ -201,18 +201,20 @@ def test_refusals(kwargs, why):
 
 def test_visited_share_at_the_cell_shape_is_logged():
     """micro 2 x 32 heads x (2 x 8,192) x 128: 16 blocks a side; the forward
-    in 512 square sub-tiles, 288 of 1,024 visited: 28% of the (2L)^2 square,
-    where a causal walk over 2L visits 52%; the fused backward in 256 square
-    sub-tiles since PR 46 (its walk is static on the 16 x 16 grid): 1,088 of
-    4,096, 27%."""
+    (since PR 50; 512 square until then: 288 of 1,024) and the fused backward
+    (since PR 46) in 256 square sub-tiles, 1,088 of 4,096 visited: 27% of the
+    (2L)^2 square, where a causal walk over 2L visits 51%; both walks static
+    on the 16 x 16 grid, the forward's steps key-major over four chains."""
     tiling = A.flash_tiling(
         16384, 16384, 1024, 1024, False, lanes=128, block_diffusion=4)
-    assert tiling["visited_share"] == 288 / 1024
+    assert tiling["visited_share"] == 1088 / 4096
+    assert (tiling["sub_q"], tiling["sub_k"]) == (256, 256)
+    assert (tiling["order"], tiling["chains"]) == ("key_major", 4)
     assert tiling["backward"]["visited_share"] == 1088 / 4096
     assert (tiling["backward"]["sub_q"], tiling["backward"]["sub_k"]) == (256, 256)
     assert tiling["backward"]["backward"] == "fused"
     assert A.flash_tiling(
-        16384, 16384, 1024, 1024, True, lanes=128)["visited_share"] == 528 / 1024
+        16384, 16384, 1024, 1024, True, lanes=128)["visited_share"] == 2080 / 4096
     seen = []
     handler = logging.Handler()
     handler.emit = lambda record: seen.append(record.getMessage())
@@ -228,7 +230,8 @@ def test_visited_share_at_the_cell_shape_is_logged():
         logger.removeHandler(handler)
         logger.setLevel(level)
     line, = [m for m in seen if m.startswith("flash_tiling")]
-    assert "visited_share=0.2812" in line and "bwd_visited_share=0.2656" in line
+    assert " visited_share=0.2656" in line and "bwd_visited_share=0.2656" in line
+    assert " order=key_major chains=4 " in line
     assert line.count("walk=static bodies=3 steps=80/176/80 ") == 2
     assert "backward=fused" in line and line.endswith("block_diffusion=4")
 

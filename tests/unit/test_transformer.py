@@ -501,10 +501,11 @@ def test_flash_loop_bounds_stop_at_the_diagonal():
     assert t["visited_share"] == 0.5625
     assert flash_tiling(1024, 1024, 512, 512, False, sub_q=128, sub_k=128)[
         "visited_share"] == 1.0
-    # the cells' shape as the kernels walk it: the forward and dq in 512
-    # sub-tiles (3 of 4, as the one-level 512-tiles before), dkv in 128s
+    # the cells' shape as the kernels walk it: the forward in 256 sub-tiles
+    # since PR 50 (10 of 16; until then 512: 3 of 4, as the one-level
+    # 512-tiles before), dkv in 128s
     t = flash_tiling(1024, 1024, 1024, 1024, True)
-    assert (t["sub_q"], t["sub_k"], t["visited_share"]) == (512, 512, 0.75)
+    assert (t["sub_q"], t["sub_k"], t["visited_share"]) == (256, 256, 0.625)
     t = flash_tiling(1024, 1024, 1024, 1024, True, key_major=True)
     assert (t["sub_q"], t["sub_k"], t["visited_share"]) == (128, 128, 0.5625)
     # on a grid of several blocks the pair's dkv walks in the large sub-tile
@@ -638,18 +639,31 @@ def test_flash_loop_bounds_stop_at_the_diagonal():
 # included, function addresses stripped): (entry, seq, heads, head width,
 # causal, key mask, bias, the mask form's keywords, block).
 WALK_PROGRAMS = {
-    # one block each way, the six GPT-2 and BERT cells' calls at two heads: the
-    # parent's text (sha256 of bd1970c's, PR 46: the classes must not reach it)
+    # one block each way, the six GPT-2 and BERT cells' calls at two heads, and
+    # two stripes of one head: several chains a grid step, so since PR 50 the
+    # forward walks them key-major (the text as that PR left it, sha256; the
+    # backward's half of it is bd1970c's, PR 46)
     "gpt2_packed_1024": (
-        "packed", 1024, 2, 64, True, False, True, {}, 1024, "3f5fc0c0372a70c5"),
+        "packed", 1024, 2, 64, True, False, True, {}, 1024, "98cf0ad46ea35b3a"),
     "bert_packed_512_masked": (
-        "packed", 512, 2, 64, False, True, True, {}, 1024, "653f50cce8b25de8"),
+        "packed", 512, 2, 64, False, True, True, {}, 1024, "a7691d7c7b10c12f"),
     "bert_packed_384_masked": (
-        "packed", 384, 2, 64, False, True, True, {}, 1024, "b68fbe756e2b1649"),
+        "packed", 384, 2, 64, False, True, True, {}, 1024, "76de485be8b35fb2"),
     "split_causal_1024_d64": (
-        "split", 1024, 1, 64, True, False, False, {}, 1024, "e48e444c7b019763"),
+        "split", 1024, 1, 64, True, False, False, {}, 1024, "9455c11ae9b7eb28"),
     "split_noncausal_1024_d128": (
-        "split", 1024, 1, 128, False, False, False, {}, 1024, "922bc84e5265d3de"),
+        "split", 1024, 1, 128, False, False, False, {}, 1024, "6a56c3aecdc9c21a"),
+    # ONE chain a grid step (one stripe of one head): nothing to put side by
+    # side, and the whole text is the parent's (sha256 of c9ea1f4's, PR 50),
+    # on one block and on a 4 x 4 grid, whose classes ride behind the seed
+    "split_causal_512_d128": (
+        "split", 512, 1, 128, True, False, False, {}, 1024, "c3c1284b7d065495"),
+    "split_masked_384_d64": (
+        "split", 384, 1, 64, False, True, False, {}, 1024, "115c783b2f6e285b"),
+    "packed_causal_512_d128": (
+        "packed", 512, 1, 128, True, False, False, {}, 1024, "1a9635e82548e70f"),
+    "split_causal_1024_blocks256": (
+        "split", 1024, 1, 128, True, False, False, {}, 256, "1fed9f354f05fbb9"),
     # grids of several blocks: no loop in either kernel
     "causal_2048": ("split", 2048, 1, 128, True, False, False, {}, 1024, None),
     "causal_512_blocks128_masked": (
@@ -738,7 +752,9 @@ def test_flash_tiling_is_logged_once_per_shape():
     assert f" visited_share={t['visited_share']:.4f} " in lines[0]
     b = att.flash_tiling(1024, 1024, 1024, 1024, True, lanes=64, itemsize=4)[
         "backward"]
-    assert " walk=static bodies=1 steps=1/0/1 backward=fused " in lines[0]
+    assert (t["order"], t["chains"]) == ("key_major", 1024 // t["sub_q"])
+    assert (f" walk=static bodies=1 steps=1/0/1 order={t['order']} "
+            f"chains={t['chains']} backward=fused ") in lines[0]
     assert lines[0].endswith(
         f" backward=fused bwd_sub={b['sub_q']}x{b['sub_k']} "
         f"bwd_visited_share={b['visited_share']:.4f} "

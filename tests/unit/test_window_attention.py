@@ -274,21 +274,21 @@ def test_older_callers_and_a_window_past_the_row_trace_as_before(
 
 def test_visited_share_at_the_cell_shape_is_logged(monkeypatch):
     """micro 2 x 72 heads x 8,192 x 128 under W 512: 8 blocks a side; the
-    forward in 512 square sub-tiles, 31 of 256 visited (a stripe's own
-    sub-tile and the one before), 12% of the square where the allowed pairs
-    are 6% and a causal walk visits 53%; the fused backward in 256 square
-    sub-tiles since PR 46 (its walk is static on the 8 x 2 grid): 93 of 1,024
-    (a stripe's own and the two before), 9%; the inner axes take 2 steps of
-    8."""
+    forward (since PR 50; 512 square until then: 31 of 256, 12%) and the
+    fused backward (since PR 46) in 256 square sub-tiles, 93 of 1,024 visited
+    (a stripe's own and the two before), 9% of the square where the allowed
+    pairs are 6% and a causal walk visits 52%; both walks static on the 8 x 2
+    grid; the inner axes take 2 steps of 8."""
     tiling = A.flash_tiling(
         8192, 8192, 1024, 1024, True, lanes=128, window=512)
-    assert tiling["visited_share"] == 31 / 256
+    assert tiling["visited_share"] == 93 / 1024
     assert tiling["backward"]["visited_share"] == 93 / 1024
     assert tiling["backward"]["backward"] == "fused"
-    assert (tiling["sub_q"], tiling["sub_k"]) == (512, 512)
+    assert (tiling["sub_q"], tiling["sub_k"]) == (256, 256)
+    assert (tiling["order"], tiling["chains"]) == ("key_major", 4)
     assert (tiling["backward"]["sub_q"], tiling["backward"]["sub_k"]) == (256, 256)
     assert A.flash_tiling(
-        8192, 8192, 1024, 1024, True, lanes=128)["visited_share"] == 136 / 256
+        8192, 8192, 1024, 1024, True, lanes=128)["visited_share"] == 528 / 1024
     band = (1024, 1024, 8, 8, 0, 512)
     assert A._band_inner_steps(*band, False) == 2
     assert A._band_inner_steps(*band, True) == 2
@@ -317,8 +317,8 @@ def test_visited_share_at_the_cell_shape_is_logged(monkeypatch):
         f"attention_layout b=2 s=8192 heads={heads} d=128 layout=split "
         "heads_a_block=1 reason='q, k and v arrive as separate [B, H, S, D] "
         "arrays'" for heads in (72, 48)]
-    assert "visited_share=0.1211" in banded
+    assert " visited_share=0.0908" in banded
     assert "bwd_visited_share=0.0908" in banded
     assert banded.count("walk=static bodies=2 steps=15/1/15 ") == 2
     assert "backward=fused" in banded and banded.endswith("window=512")
-    assert "visited_share=0.5312" in causal and "window" not in causal
+    assert " visited_share=0.5156" in causal and "window" not in causal

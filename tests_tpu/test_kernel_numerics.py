@@ -183,6 +183,81 @@ def test_flash_matches_reference_at_the_cells_shape(cell):
         assert rel < 5e-2, f"d{name} rel err {rel:.2e}"
 
 
+# (entry, [b, h, s], head width, the call's keywords): compiled, the forward's
+# two orders of one grid step (PR 50) give the same bits
+ORDER_SHAPES = {
+    "gpt2_one_block_two_heads": (
+        "packed", (2, 4, 1024), 64, {"causal": True, "bias": True}),
+    "bert_key_mask_two_heads": ("packed", (2, 4, 512), 64, {"kv_mask": True}),
+    "ouro_packed_4x4": ("packed", (1, 2, 4096), 128, {"causal": True}),
+    "sdar_mask_4x4": ("split", (1, 2, 4096), 128, {"block_diffusion": 4}),
+    "laguna_band_4x2": ("split", (1, 2, 4096), 128, {"causal": True, "window": 512}),
+    "dropout_2x2": ("split", (1, 2, 2048), 128, {
+        "causal": True, "dropout_rate": 0.3, "dropout_seed": 5}),
+    "dropout_two_heads_a_block": ("packed", (2, 4, 1024), 64, {
+        "causal": True, "dropout_rate": 0.3, "dropout_seed": 5}),
+    "latent_4x4": ("latent", (1, 4, 4096), 128, {}),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(ORDER_SHAPES))
+def test_flash_forward_key_major_is_query_major_bit_for_bit(shape, monkeypatch):
+    """``flash_fwd`` with the key sub-tile outermost (every chain that sees it
+    advancing side by side) against the old order (one chain after another),
+    both compiled at the sub-tiles ``pick_subtiles`` gives: each chain meets
+    the same sub-tiles in the same order, so the context is the same bits,
+    dropout's (by global head and granule) among them."""
+    import importlib
+
+    att = importlib.import_module("deepspeed_tpu.ops.attention")
+    entry, (b, h, s), d, form = ORDER_SHAPES[shape]
+    form = dict(form)
+    ks = jax.random.split(jax.random.PRNGKey(50), 5)
+
+    def normal(key, *dims):
+        return jax.random.normal(key, dims, jnp.float32).astype(jnp.bfloat16)
+
+    if form.pop("kv_mask", False):
+        form["kv_mask"] = (
+            jnp.arange(s)[None, :] < s - 37 * jnp.arange(b)[:, None]
+        ).astype(jnp.int32)
+    if entry == "latent":
+        operands = (normal(ks[0], b, s, h * d), normal(ks[1], b, s, h * 64),
+                    normal(ks[2], b, s, h * 2 * d), normal(ks[3], b, s, 64))
+
+        def forward():
+            return att.flash_attention_latent(*operands, h)
+    elif entry == "packed":
+        qkv = normal(ks[0], b, s, 3 * h * d)
+        if form.pop("bias", False):
+            form["bias"] = normal(ks[1], 3 * h * d)
+
+        def forward():
+            return flash_attention_packed(qkv, h, **form)
+    else:
+        q, k, v = (normal(kk, b, h, s, d) for kk in ks[:3])
+
+        def forward():
+            return flash_attention(q, k, v, **form)
+
+    seen, real = [], att._forward_order
+
+    def order(*a):
+        seen.append(real(*a))
+        return seen[-1]
+
+    monkeypatch.setattr(att, "_forward_order", order)
+    # (a new function each time: jit's cache is keyed by it)
+    new = np.asarray(jax.jit(lambda: forward())().astype(jnp.float32))
+    assert seen and all(
+        o["order"] == "key_major" and o["chains"] > 1 for o in seen), seen
+    monkeypatch.setattr(
+        att, "_forward_order", lambda *a: {"order": "query_major", "chains": 1})
+    old = np.asarray(jax.jit(lambda: forward())().astype(jnp.float32))
+    assert np.isfinite(new).all() and new.any()
+    np.testing.assert_array_equal(new, old)
+
+
 def test_latent_layout_matches_the_split_path_at_the_published_widths():
     """The LATENT layout (ops/attention.py: q_nope, q_r, kv and the shared k_r
     as a latent mixer's projections write them) against the split path over

@@ -7,10 +7,11 @@ calls, ms), at the shapes the benchmark's cells run, with the backward that
 
 ``joyai_latent`` is ``joyai`` in the LATENT layout (no pair exists for it: pass
 ``--no-pair``). ``--sub 256x128`` also times the key-major kernels at those sub-tiles (query x
-key) instead of the ones ``pick_subtiles`` gives. A line also says how the
+key) instead of the ones ``pick_subtiles`` gives, and ``--fwd-sub 256x256`` the
+FORWARD alone (variant ``forward``: only it is jitted). A line also says how the
 forward and the backward walk the grid (``flash_tiling``'s ``walk``, ``bodies``
-and ``steps``). Writes one JSON line a (shape, variant) to stdout and to
-``chiprun_out/flash_kernels_alone.jsonl``.
+and ``steps``; the forward's ``order`` and ``chains`` too). Writes one JSON line
+a (shape, variant) to stdout and to ``chiprun_out/flash_kernels_alone.jsonl``.
 docs/TESTING.md holds the tables this produced."""
 
 import argparse
@@ -37,6 +38,7 @@ from benchmark.trace import PS, Trace  # noqa: E402
 SHAPES = {
     "gpt2": ("packed", 8, 20, 1024, 64, True, False, True),
     "bert512": ("packed", 8, 16, 512, 64, False, True, True),
+    "bert384": ("packed", 8, 16, 384, 64, False, True, True),
     "ouro": ("packed", 1, 16, 8192, 128, True, False, False),
     "nemotron": ("split", 2, 4, 8192, 128, True, False, False),
     "qwen3next": ("split", 2, 16, 16384, 256, True, False, False),
@@ -103,12 +105,15 @@ def measure(shape, variant, sub):
     if variant == "pair":
         att.FUSED_DQ_VMEM_BUDGET = 0
     if sub:
-        att.pick_subtiles = lambda bq, bk, nq, nk, key_major, fused=False: (
-            (min(sub[0], bq), min(sub[1], bk)) if key_major
-            else real_pick(bq, bk, nq, nk, key_major, fused))
+        # the kernels of the variant's side at ``sub``, the other side's as picked
+        att.pick_subtiles = lambda bq, bk, nq, nk, key_major, *more, **kw: (
+            (min(sub[0], bq), min(sub[1], bk))
+            if key_major == (variant != "forward")
+            else real_pick(bq, bk, nq, nk, key_major, *more, **kw))
     try:
         loss, args, argnums = build(shape)
-        step = jax.jit(jax.grad(lambda *a: loss(*a), argnums=argnums))
+        step = jax.jit(loss if variant == "forward" else jax.grad(
+            lambda *a: loss(*a), argnums=argnums))
         grads = jax.block_until_ready(step(*args))
         with tempfile.TemporaryDirectory(dir=os.path.join(ROOT, "chiprun_out")) as tmp:
             jax.profiler.start_trace(tmp)
@@ -133,45 +138,59 @@ def measure(shape, variant, sub):
     return ms, grads
 
 
-def walks(shape, sub):
+def walks(shape, sub, fwd_sub=None):
     """How the forward and the key-major backward walk the shape's grid
-    (``flash_tiling``: ``walk``, ``bodies``, ``steps``), for the line."""
-    _, _, _, s, d, causal, _, _, *form = SHAPES[shape]
-    form = form[0] if form else {}
+    (``flash_tiling``: ``walk``, ``bodies``, ``steps``; the forward's ``order``
+    and ``chains``), for the line."""
+    entry, _, _, s, d, causal, _, _, *form = SHAPES[shape]
+    form = dict(form[0] if form else {})
+    lanes = max(d) if isinstance(d, tuple) else d
+    form["heads_a_block"] = {"packed": 128 // lanes, "latent": 2}.get(entry, 1)
     block = att._pick_blocks(
         s, s, att.DEFAULT_BLOCK_Q, att.DEFAULT_BLOCK_K,
         form.get("block_diffusion", 0))
+    fwd_sub = fwd_sub or (None, None)
     t = att.flash_tiling(
-        s, s, *block, causal, lanes=max(d) if isinstance(d, tuple) else d, **form)
+        s, s, *block, causal, lanes=lanes, sub_q=fwd_sub[0], sub_k=fwd_sub[1],
+        **form)
     if sub:
         t["backward"] = att.flash_tiling(
             s, s, *block, causal, key_major=True, sub_q=sub[0], sub_k=sub[1],
             **form)
-    return {side: {k: w.get(k) for k in ("walk", "bodies", "steps")}
-            for side, w in (("forward", t), ("backward", t["backward"]))}
+    keys = ("sub_q", "sub_k", "walk", "bodies", "steps")
+    return {"forward": {k: t[k] for k in keys + ("order", "chains")},
+            "backward": {k: t["backward"][k] for k in keys}}
 
 
 def main():
     parser = argparse.ArgumentParser()
     parser.add_argument("shapes", nargs="*", default=list(SHAPES))
     parser.add_argument("--sub", action="append", default=[])
+    parser.add_argument("--fwd-sub", action="append", default=[])
     parser.add_argument("--no-pair", action="store_true")
     opts = parser.parse_args()
     if jax.devices()[0].platform != "tpu":
         raise SystemExit("flash_kernels_alone: no TPU; a CPU gives no time")
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
-    subs = [None] + [tuple(int(x) for x in s.split("x")) for s in opts.sub]
+    def sizes(given):
+        return [tuple(int(x) for x in s.split("x")) for s in given]
+
+    subs, fwd_subs = [None] + sizes(opts.sub), sizes(opts.fwd_sub)
     with open(os.path.join(ROOT, "chiprun_out", "flash_kernels_alone.jsonl"), "a") as out:
         for shape in opts.shapes:
             pair = None
             block = min(SHAPES[shape][3], 1024)
-            clamped = dict.fromkeys(
-                s and (min(s[0], block), min(s[1], block)) for s in subs)
+            def clamped(subs):
+                return dict.fromkeys(
+                    s and (min(s[0], block), min(s[1], block)) for s in subs)
+
             for variant, sub in [("pair", None)][opts.no_pair:] + [
-                    ("fused", s) for s in clamped]:
+                    ("fused", s) for s in clamped(subs)] + [
+                    ("forward", s) for s in clamped(fwd_subs)]:
                 line = {"shape": shape, "dims": SHAPES[shape], "variant": variant,
                         "sub_q_x_k": sub, "device": jax.devices()[0].device_kind,
-                        **walks(shape, None if variant == "pair" else sub)}
+                        **walks(shape, sub if variant == "fused" else None,
+                                sub if variant == "forward" else None)}
                 try:
                     ms, grads = measure(shape, variant, sub)
                 except Exception as e:  # a variant Mosaic refuses: say so, go on
@@ -182,7 +201,7 @@ def main():
                         sum(v for k, v in ms.items() if k != "flash_fwd"), 4)
                     if variant == "pair":
                         pair = grads
-                    elif pair is not None:
+                    elif pair is not None and variant == "fused":
                         # largest difference from the pair's gradient, as a
                         # share of its largest entry
                         line["vs_pair"] = max(
